@@ -49,19 +49,6 @@ def _env_int(names: Sequence[str]) -> Optional[int]:
     return None
 
 
-def _find_native_lib() -> Optional[str]:
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    candidate = os.path.join(here, "libhorovod_core.so")
-    if os.path.exists(candidate):
-        return candidate
-    # Primary locations + self-healing compile from the shipped sources
-    # (install-time build is setup.py's job; this covers source checkouts
-    # and compiler-at-runtime installs).
-    from horovod_tpu.common.native_build import ensure_native_lib
-
-    return ensure_native_lib()
-
-
 class HorovodBasics:
     """init/shutdown/rank/size lifecycle, optionally backed by the C++ core.
 
@@ -242,20 +229,8 @@ class HorovodBasics:
                 import jax
 
                 # A retried init() after a failure elsewhere finds the JAX
-                # runtime already up — that is fine.  Ask the runtime's own
-                # API rather than parsing exception text (which is brittle
-                # across JAX versions); jax < 0.5 has no public
-                # is_initialized, so fall back to the distributed client
-                # singleton it tracks internally.
-                is_init = getattr(jax.distributed, "is_initialized", None)
-                if callable(is_init):
-                    already = is_init()
-                else:
-                    from jax._src import distributed as _jax_dist
-
-                    already = getattr(_jax_dist.global_state, "client",
-                                      None) is not None
-                if not already:
+                # runtime already up — that is fine.
+                if not jax.distributed.is_initialized():
                     jax.distributed.initialize(
                         coordinator_address=jaddr,
                         num_processes=size,
@@ -463,7 +438,9 @@ class HorovodBasics:
     def _load_native(self) -> None:
         if self._lib is not None:
             return
-        path = _find_native_lib()
+        from horovod_tpu.common.native_build import ensure_native_lib
+
+        path = ensure_native_lib()
         if path is None:
             return
         try:

@@ -11,6 +11,12 @@ Build location: next to the sources when that directory is writable
 (source checkout), else ``$XDG_CACHE_HOME/horovod_tpu`` (installed
 site-packages are often read-only).  A file lock serializes concurrent
 builders (the launcher starts N ranks at once).
+
+A library is trusted only when the stamp beside it
+(``libhorovod_core.so.digest``) equals the digest of the sources on
+disk: ``*.so`` and ``*.o`` are not tracked by git, so a copied tree can
+carry a library built from other sources, with mtimes ``make`` cannot
+judge.  On a mismatch everything is rebuilt (``make -B``) and re-stamped.
 """
 
 from __future__ import annotations
@@ -60,13 +66,23 @@ def _cache_dir() -> str:
     return os.path.join(base, "horovod_tpu", _source_digest())
 
 
+def _is_current(lib: str) -> bool:
+    """True when ``lib`` exists and its stamp names the sources on disk."""
+    try:
+        with open(lib + ".digest") as f:
+            stamp = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(lib) and stamp == _source_digest()
+
+
 def native_lib_path() -> Optional[str]:
-    """Path of an already-built engine library, or None."""
+    """Path of an engine library built from the current sources, or None."""
     for candidate in (
         os.path.join(_cpp_dir(), _LIB_NAME),
         os.path.join(_cache_dir(), _LIB_NAME),
     ):
-        if os.path.exists(candidate):
+        if _is_current(candidate):
             return candidate
     return None
 
@@ -111,8 +127,10 @@ def ensure_native_lib(timeout: float = 300.0) -> Optional[str]:
         if path is not None:
             return path
         try:
+            # -B: object files beside the sources may predate them with
+            # mtimes a copy has reset.
             subprocess.run(
-                ["make", "-C", build_dir],
+                ["make", "-B", "-C", build_dir],
                 check=True,
                 capture_output=True,
                 timeout=timeout,
@@ -124,4 +142,9 @@ def ensure_native_lib(timeout: float = 300.0) -> Optional[str]:
         built = os.path.join(build_dir, _LIB_NAME)
         if built != out and os.path.exists(built):
             shutil.copy2(built, out)
+        # Stamp last, atomically: a reader that sees the stamp sees the
+        # finished library.
+        with open(out + ".digest.tmp", "w") as f:
+            f.write(_source_digest() + "\n")
+        os.replace(out + ".digest.tmp", out + ".digest")
     return native_lib_path()

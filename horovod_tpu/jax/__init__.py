@@ -29,16 +29,14 @@ Typical use::
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
 from jax.sharding import Mesh, PartitionSpec
 
-from horovod_tpu.common.jax_compat import shard_map
-
 from horovod_tpu.common import (
     epoch,
-    init,
     is_initialized,
     local_rank,
     local_size,
@@ -47,6 +45,8 @@ from horovod_tpu.common import (
     shutdown,
     size,
 )
+from horovod_tpu.common import init as _init
+from horovod_tpu.common.compile_cache import enable_compile_cache
 from horovod_tpu.ops import collective_ops as _cops
 from horovod_tpu.ops.collective_ops import (
     Average,
@@ -78,6 +78,15 @@ __all__ = [
     "build_mesh", "data_parallel_mesh", "default_mesh", "use_mesh",
     "make_train_step",
 ]
+
+
+@functools.wraps(_init)
+def init(*args, **kwargs) -> None:
+    # The common init, then the persistent compile cache
+    # (common/compile_cache.py): this is the frontend whose programs are
+    # jitted.  After, because the cache helper asks JAX for its backend.
+    _init(*args, **kwargs)
+    enable_compile_cache()
 
 
 def num_chips() -> int:
@@ -839,7 +848,7 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
     # builder: its broadcast-from-last-stage pins its own vjp, so it
     # differentiates identically with VMA checking on or off
     # (parallel/pipeline.py).
-    step = shard_map(
+    step = jax.shard_map(
         _sharded_step_aux if has_aux else _sharded_step,
         mesh=mesh,
         in_specs=(replicated,) * n_state + (batch_spec,),

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-import horovod_tpu.common.jax_compat  # noqa: F401  (lax.axis_size shim)
-
 from horovod_tpu.ops.compression import Compression
 
 __all__ = [

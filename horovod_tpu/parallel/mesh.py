@@ -8,7 +8,7 @@ allreduce (operations.cc:1025-1187).
 
 TPU-native design: the communicator hierarchy becomes a ``jax.sharding.Mesh``.
 The local/cross split maps onto ICI-within-slice vs DCN-across-slices: when
-multiple processes (hosts/slices) are present we build a *hybrid* device mesh
+the devices span several slices we build a *hybrid* device mesh
 (``mesh_utils.create_hybrid_device_mesh``) so that the innermost mesh axes
 ride ICI and only the outermost crosses DCN — the exact analogue of
 NCCL-reduce-scatter → cross-node-MPI-allreduce → NCCL-all-gather, except XLA
@@ -30,7 +30,7 @@ import threading
 from typing import Optional, Sequence
 
 import jax
-import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 __all__ = [
@@ -94,8 +94,8 @@ def build_mesh(
     significant: later axes are innermost (most-contiguous on ICI), so put
     the most communication-hungry axis (tensor/seq) last.
 
-    Multi-process topologies get a hybrid mesh whose outermost axis spans
-    processes (DCN) — the TPU-native "cross communicator".
+    Multi-slice topologies get a hybrid mesh whose outermost axes span
+    slices (DCN) — the TPU-native "cross communicator".
     """
     if devices is None:
         devices = jax.devices()
@@ -106,39 +106,34 @@ def build_mesh(
     names = tuple(shape.keys())
     sizes = tuple(shape[k] for k in names)
 
-    n_proc = getattr(jax, "process_count", lambda: 1)()
-    mesh_devices = None
-    if n_proc > 1 and n % n_proc == 0:
-        try:
-            from jax.experimental import mesh_utils
-
-            per_proc = n // n_proc
-            # Split each mesh axis into a DCN (across-process) component and
-            # an ICI component, outermost-first, mirroring cross/local comms.
-            dcn_left = n_proc
-            dcn_shape, ici_shape = [], []
-            for s in sizes:
-                g = math.gcd(s, dcn_left)
-                dcn_shape.append(g)
-                ici_shape.append(s // g)
-                dcn_left //= g
-            if dcn_left == 1 and math.prod(ici_shape) == per_proc:
-                mesh_devices = mesh_utils.create_hybrid_device_mesh(
-                    ici_shape, dcn_shape, devices=devices,
-                    allow_split_physical_axes=allow_split_physical_axes,
-                )
-        except Exception:
-            mesh_devices = None
-    if mesh_devices is None:
-        try:
-            from jax.experimental import mesh_utils
-
-            mesh_devices = mesh_utils.create_device_mesh(
-                sizes, devices=np.asarray(devices),
-                allow_split_physical_axes=allow_split_physical_axes,
-            )
-        except Exception:
-            mesh_devices = np.asarray(devices).reshape(sizes)
+    # The DCN granule is the slice, which TPU devices report themselves
+    # (CPU/GPU devices have no slice_index: one granule).  mesh_utils
+    # orders devices along the physical ICI torus; its errors are real —
+    # a reshape in enumeration order would silently drop that order.
+    n_slices = len({getattr(d, "slice_index", 0) for d in devices})
+    if n_slices > 1:
+        # Split each mesh axis into a DCN (across-slice) component and an
+        # ICI component, outermost-first, mirroring cross/local comms.
+        dcn_left = n_slices
+        dcn_shape, ici_shape = [], []
+        for s in sizes:
+            g = math.gcd(s, dcn_left)
+            dcn_shape.append(g)
+            ici_shape.append(s // g)
+            dcn_left //= g
+        if dcn_left != 1:
+            raise ValueError(
+                f"mesh shape {shape} cannot be laid over {n_slices} slices: "
+                "the outer axes must absorb the slice count")
+        mesh_devices = mesh_utils.create_hybrid_device_mesh(
+            ici_shape, dcn_shape, devices=devices,
+            allow_split_physical_axes=allow_split_physical_axes,
+        )
+    else:
+        mesh_devices = mesh_utils.create_device_mesh(
+            sizes, devices=devices,
+            allow_split_physical_axes=allow_split_physical_axes,
+        )
     return Mesh(mesh_devices, names)
 
 

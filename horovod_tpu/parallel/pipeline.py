@@ -18,12 +18,8 @@ No reference equivalent (Horovod 0.15.1 is data-parallel only, SURVEY.md
   ``stage_fn`` in ``jax.checkpoint`` to trade FLOPs for memory).
 
 The final broadcast-from-last-stage pins its own vjp
-(``_broadcast_from_last``): relying on AD's psum transpose there is
-version-sensitive — the check_rep jax line conservatively sums the
-replicated cotangents (inflating every stage gradient by the stage
-count), the VMA line transposes correctly — so the rule is written by
-hand and ``pipeline_apply`` differentiates identically under
-``check_vma=True`` AND ``check_vma=False`` on both lines (verified
+(``_broadcast_from_last``), so ``pipeline_apply`` differentiates
+identically under ``check_vma=True`` AND ``check_vma=False`` (verified
 against sequential-execution gradients in tests/test_pipeline.py).
 """
 
@@ -34,8 +30,6 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from horovod_tpu.common.jax_compat import shard_map
 
 from horovod_tpu.ops.losses import softmax_cross_entropy
 
@@ -60,11 +54,10 @@ def unstack_pytree(tree, n: int):
 
 # Broadcast-from-last-stage with an EXPLICIT vjp.  The forward is the
 # masked psum; the correct cotangent is simply the (replicated) output
-# cotangent delivered to the last stage and zero elsewhere.  Relying on
-# AD's psum transpose here is version-sensitive — jax's shard_map AD
-# changed the replicated-cotangent convention between the check_rep line
-# (0.4.x: transpose sums the replicas, inflating every stage gradient by
-# the stage count) and the VMA line — so the rule is pinned by hand.
+# cotangent delivered to the last stage and zero elsewhere.  Under
+# check_vma=False (the explicit-grad-psum builders) AD's own psum
+# transpose would sum the replicas, inflating every stage gradient by
+# the stage count — so the rule is pinned by hand.
 from functools import partial as _partial  # noqa: E402
 
 
@@ -288,7 +281,7 @@ def make_pipelined_llama_train_step(cfg, optimizer, mesh, *,
         return jax.tree.map(leaf, opt_state)
 
     def step(params, opt_state, inputs, targets):
-        loss, grads = shard_map(
+        loss, grads = jax.shard_map(
             _grads, mesh=mesh,
             in_specs=(
                 jax.tree.map(lambda _: stage_specs, params["stages"]),

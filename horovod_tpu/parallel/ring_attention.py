@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-import horovod_tpu.common.jax_compat  # noqa: F401  (lax.axis_size shim)
-
 __all__ = ["ring_attention", "make_ring_attention_fn", "ulysses_attention"]
 
 _NEG_INF = jnp.finfo(jnp.float32).min

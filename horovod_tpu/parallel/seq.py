@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from horovod_tpu.common.jax_compat import shard_map
-
 from horovod_tpu.models.llama import LlamaConfig, LlamaModel
 from horovod_tpu.ops.losses import softmax_cross_entropy
 from horovod_tpu.parallel.ring_attention import make_ring_attention_fn
@@ -100,7 +98,7 @@ def make_context_parallel_train_step(cfg: LlamaConfig, optimizer,
         return params, opt_state, loss
 
     batch_spec = P(tuple(batch_axes) if batch_axes else None, seq_axis)
-    step = shard_map(
+    step = jax.shard_map(
         _step,
         mesh=mesh,
         in_specs=(P(), P(), batch_spec, batch_spec),

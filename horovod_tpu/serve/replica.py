@@ -103,6 +103,7 @@ def main(argv=None) -> int:
     parser.add_argument("--host", default="127.0.0.1")
     args = parser.parse_args(argv)
 
+    from horovod_tpu.common.compile_cache import enable_compile_cache
     from horovod_tpu.serve.config import ServeConfig
     from horovod_tpu.serve.engine import ModelRunner
     from horovod_tpu.serve.scheduler import Scheduler
@@ -118,6 +119,7 @@ def main(argv=None) -> int:
 
         hvd.init()
 
+    enable_compile_cache()
     runner = ModelRunner(cfg)
     if cfg.warmup_tokens:
         n = runner.warmup()

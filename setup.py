@@ -4,14 +4,15 @@ Reference parity: the reference's 765-line setup.py exists to probe
 MPI/CUDA/NCCL/TF/torch toolchains and build four C++ extensions
 (reference setup.py:32-35, 244-465).  None of that probing applies here —
 the TPU-native engine (``horovod_tpu/cpp``) depends only on a C++17
-compiler and pthreads — so the build step is a ``make`` invocation that
-produces ``libhorovod_core.so`` inside the package tree.  If the compile
-fails (no compiler on the install host) the install still succeeds and the
-runtime falls back to the lazy build in
-``horovod_tpu/common/native_build.py`` or pure-Python single-process mode.
+compiler and pthreads — so the build step runs the package's own builder
+(``horovod_tpu/common/native_build.py``: ``make`` plus the source-digest
+stamp the runtime checks before it trusts a library) and leaves
+``libhorovod_core.so`` inside the package tree.  If the compile fails (no
+compiler on the install host) the install still succeeds and the runtime
+retries lazily or runs in pure-Python single-process mode.
 """
 
-import subprocess
+import importlib.util
 import sys
 from pathlib import Path
 
@@ -21,13 +22,18 @@ from setuptools.command.build_py import build_py
 
 class BuildPyWithNative(build_py):
     def run(self):
-        cpp = Path(__file__).parent / "horovod_tpu" / "cpp"
-        try:
-            subprocess.run(["make", "-C", str(cpp)], check=True)
-        except (OSError, subprocess.CalledProcessError) as exc:
+        # Loaded by path: importing the package would need its run-time
+        # dependencies at build time.
+        spec = importlib.util.spec_from_file_location(
+            "native_build",
+            Path(__file__).parent / "horovod_tpu" / "common"
+            / "native_build.py")
+        native_build = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(native_build)
+        if native_build.ensure_native_lib() is None:
             print(
-                f"warning: native engine build failed ({exc}); "
-                "the runtime will retry lazily or run without the C++ core",
+                "warning: native engine build failed; the runtime will "
+                "retry lazily or run without the C++ core",
                 file=sys.stderr,
             )
         super().run()

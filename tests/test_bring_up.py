@@ -1,0 +1,123 @@
+"""What PR 21 (chip bring-up) left behind that a CPU can check: the chip
+smoke refuses to run without a TPU, the compile cache can be placed from
+outside, state placed on the mesh compiles the step once, and a native
+library is trusted only with a stamp that names its sources."""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_chip_smoke_refuses_cpu():
+    """No TPU, no run: non-zero, the platform named, nothing built and no
+    result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                          env=env, cwd=REPO, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode != 0
+    assert "'cpu'" in proc.stderr and "needs a TPU" in proc.stderr
+    assert proc.stdout == ""
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    from horovod_tpu.common import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # Placed from outside: JAX reads the variable itself; the helper
+        # must set no other directory.
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "out"))
+        assert compile_cache.enable_compile_cache() == str(tmp_path / "out")
+        assert jax.config.jax_compilation_cache_dir == before
+
+        # Not placed, on the CPU: left off (XLA:CPU executables do not
+        # travel between hosts, and the cache sits in the tree).
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == before
+
+        # Not placed, on an accelerator: <checkout>/.jax_cache, the same
+        # from any working directory.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        dirs = []
+        for cwd in (tmp_path, REPO):
+            monkeypatch.chdir(cwd)
+            dirs.append(compile_cache.enable_compile_cache())
+            assert jax.config.jax_compilation_cache_dir == dirs[-1]
+        assert dirs[0] == dirs[1] == os.path.join(REPO, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_quick_start_compiles_the_step_once():
+    """README quick start on four devices: state replicated on the mesh
+    before step 1 leaves ONE compilation (arrays left on device 0 compile
+    the step again for its own mesh-replicated outputs)."""
+    import horovod_tpu.jax as hvd
+
+    hvd.init()
+    mesh = hvd.data_parallel_mesh(jax.devices()[:4])
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P("data"))
+
+    def loss_fn(params, batch):
+        x, y = batch
+        return jnp.mean((jnp.tanh(x @ params["w"]) @ params["v"] - y) ** 2)
+
+    rng = np.random.default_rng(0)
+    params = {"w": jnp.asarray(rng.standard_normal((8, 16)), jnp.float32),
+              "v": jnp.asarray(rng.standard_normal((16, 1)), jnp.float32)}
+    opt = hvd.DistributedOptimizer(optax.adam(1e-2))
+    step = hvd.make_train_step(loss_fn, opt, mesh)
+    params = hvd.broadcast_parameters(params, root_rank=0)
+    params = jax.device_put(params, replicated)
+    opt_state = jax.device_put(opt.init(params), replicated)
+    losses = []
+    for _ in range(3):
+        batch = (rng.standard_normal((16, 8)).astype(np.float32),
+                 np.ones((16, 1), np.float32))
+        params, opt_state, loss = step(
+            params, opt_state, jax.device_put(batch, sharded))
+        losses.append(float(loss))
+    assert step._cache_size() == 1
+    assert all(np.isfinite(losses))
+
+
+def test_native_lib_rebuilt_when_stamp_mismatches(monkeypatch, tmp_path):
+    """``*.so`` is not tracked by git: a library on disk is loaded only
+    when the digest stamped beside it names the sources beside it."""
+    from horovod_tpu.common import native_build
+
+    cpp = tmp_path / "cpp"
+    cpp.mkdir()
+    (cpp / "Makefile").write_text(
+        "libhorovod_core.so: engine.cc\n\tcp engine.cc libhorovod_core.so\n")
+    (cpp / "engine.cc").write_text("v1\n")
+    monkeypatch.setattr(native_build, "_cpp_dir", lambda: str(cpp))
+    monkeypatch.setattr(native_build, "_build_failed", False)
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "xdg"))
+    lib = cpp / "libhorovod_core.so"
+
+    # A library with no stamp (built by hand, or copied in) is not trusted.
+    lib.write_text("stale\n")
+    assert native_build.native_lib_path() is None
+    assert native_build.ensure_native_lib() == str(lib)
+    assert lib.read_text() == "v1\n"
+    assert native_build.native_lib_path() == str(lib)
+
+    # Sources change under a library whose mtime says it is newer: the
+    # stamp no longer matches, so it is rebuilt from what is on disk.
+    (cpp / "engine.cc").write_text("v2\n")
+    os.utime(lib, (2**31 - 1, 2**31 - 1))
+    assert native_build.native_lib_path() is None
+    assert native_build.ensure_native_lib() == str(lib)
+    assert lib.read_text() == "v2\n"

@@ -263,6 +263,24 @@ def test_fused_decode_deterministic_and_batch_invariant(tiny_model):
         assert row == one, f"fused row varies at batch width {b}"
 
 
+def test_pallas_failure_is_not_swapped_for_xla(monkeypatch):
+    """impl == pallas means the kernel or an error — never a silent walk
+    down the XLA twin that hides a device the kernel does not run on."""
+    from horovod_tpu.ops import paged_attention as pa
+
+    def boom(*_):
+        raise RuntimeError("mosaic said no")
+
+    monkeypatch.setenv("HOROVOD_PAGED_ATTN_IMPL", "pallas")
+    monkeypatch.setattr(pa, "_decode_pallas", boom)
+    z = jnp.zeros((1, 1, 2, 8), jnp.float32)
+    pool = jnp.zeros((2, 4, 2, 8), jnp.float32)
+    with pytest.raises(RuntimeError, match="mosaic said no"):
+        pa.paged_attention_decode(z, pool, pool,
+                                  jnp.zeros((1, 1), jnp.int32),
+                                  jnp.zeros((1,), jnp.int32))
+
+
 def test_fused_impls_bitwise_equal_and_near_oracle(monkeypatch):
     """Ops-level: the Pallas kernel (interpret mode off-TPU) and the XLA
     blockwise path are BITWISE equal on the same inputs, and both sit
