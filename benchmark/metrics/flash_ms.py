@@ -1,0 +1,9 @@
+"""Trace: device time a step of the Mosaic calls (the flash kernel's
+forward, dq and dkv calls)."""
+
+
+def read(ctx):
+    if ctx["trace"] is None or "flash" not in ctx["job"][
+            "kernel_work_per_step"]:
+        return None
+    return ctx["trace"]["mosaic_ms_per_step"]
