@@ -46,7 +46,13 @@ from horovod_tpu.common import (
     size,
 )
 from horovod_tpu.common import init as _init
-from horovod_tpu.common.compile_cache import enable_compile_cache
+from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.common.compile_cache import (
+    compile_log,
+    enable_compile_cache,
+    enable_compile_log,
+)
+from horovod_tpu.common.scopes import TRAIN_STEP_PROGRAM
 from horovod_tpu.ops import collective_ops as _cops
 from horovod_tpu.ops.collective_ops import (
     Average,
@@ -76,17 +82,18 @@ __all__ = [
     "DistributedOptimizer", "allreduce_gradients",
     "broadcast_parameters", "broadcast_optimizer_state",
     "build_mesh", "data_parallel_mesh", "default_mesh", "use_mesh",
-    "make_train_step",
+    "make_train_step", "compile_log", "TRAIN_STEP_PROGRAM",
 ]
 
 
 @functools.wraps(_init)
 def init(*args, **kwargs) -> None:
-    # The common init, then the persistent compile cache
-    # (common/compile_cache.py): this is the frontend whose programs are
-    # jitted.  After, because the cache helper asks JAX for its backend.
+    # The common init, then the persistent compile cache and the compile
+    # log (common/compile_cache.py): this is the frontend whose programs
+    # are jitted.  After, because the cache helper asks JAX for its backend.
     _init(*args, **kwargs)
     enable_compile_cache()
+    enable_compile_log()
 
 
 def num_chips() -> int:
@@ -495,7 +502,8 @@ class DistributedOptimizer:
                 compression=self._compression,
                 fusion_threshold_bytes=self._fusion_threshold,
             )
-        return self._inner.update(grads, state, params, **extra)
+        with jax.named_scope(_scopes.OPTIMIZER):
+            return self._inner.update(grads, state, params, **extra)
 
     # -- ZeRO-1 sharded path (host-driven; see docs/zero.md) --
 
@@ -806,6 +814,14 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
     (with ``has_aux``: ``step(params, opt_state, aux_state, batch) ->
     (params, opt_state, aux_state, loss)``); params/opt_state replicated,
     batch sharded on the data axes.
+
+    ``step`` is the ``jax.jit`` object itself (``.lower``, no Python runs
+    around a call).  JAX reports it as ``hvd.TRAIN_STEP_PROGRAM``
+    (``hvd.compile_log(hvd.TRAIN_STEP_PROGRAM)``; ``jit_hvd_train_step`` in
+    a profile), and its device operations carry the scopes of
+    ``horovod_tpu/common/scopes.py`` in their names: ``hvd.loss`` (forward
+    under ``jvp``, backward under ``transpose``), ``hvd.optimizer``,
+    ``hvd.apply``, and those of the fused all-reduce (docs/timeline.md).
     """
     mesh = mesh or default_mesh()
     axes = _mesh.data_axes(mesh) or mesh.axis_names
@@ -819,22 +835,27 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
     import optax
 
     def _sharded_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope(_scopes.LOSS):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(_scopes.APPLY):
+            params = optax.apply_updates(params, updates)
         loss = _cops.allreduce(loss, axis_name=axes, op=Average)
         return params, opt_state, loss
 
     def _sharded_step_aux(params, opt_state, aux_state, batch):
-        (loss, aux_state), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, aux_state, batch)
-        aux_state = jax.tree.map(
-            lambda x: _cops.allreduce(x, axis_name=axes, op=Average)
-            if _is_inexact(x) else x,
-            aux_state,
-        )
+        with jax.named_scope(_scopes.LOSS):
+            (loss, aux_state), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, aux_state, batch)
+        with jax.named_scope(_scopes.AUX_ALLREDUCE):
+            aux_state = jax.tree.map(
+                lambda x: _cops.allreduce(x, axis_name=axes, op=Average)
+                if _is_inexact(x) else x,
+                aux_state,
+            )
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(_scopes.APPLY):
+            params = optax.apply_updates(params, updates)
         loss = _cops.allreduce(loss, axis_name=axes, op=Average)
         return params, opt_state, aux_state, loss
 
@@ -855,6 +876,9 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
         out_specs=(replicated,) * n_state + (replicated,),
         check_vma=False,
     )
+    # One name for both bodies: what JAX reports the program under
+    # (hvd.compile_log(), the profiler's XLA Modules line).
+    step.__name__ = step.__qualname__ = _scopes.TRAIN_STEP_PROGRAM
     donate_args = tuple(range(n_state)) if donate else ()
     return jax.jit(step, donate_argnums=donate_args)
 

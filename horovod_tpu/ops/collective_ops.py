@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.ops.compression import Compression
 
 __all__ = [
@@ -77,19 +78,20 @@ def axis_size(axis_name) -> int:
 
 
 def _reduce(tensor: jax.Array, axis_name, op: ReduceOp) -> jax.Array:
-    if op is ReduceOp.SUM:
-        return lax.psum(tensor, axis_name)
-    if op is ReduceOp.AVERAGE:
-        return lax.pmean(tensor, axis_name)
-    if op is ReduceOp.MIN:
-        return lax.pmin(tensor, axis_name)
-    if op is ReduceOp.MAX:
-        return lax.pmax(tensor, axis_name)
-    if op is ReduceOp.PRODUCT:
-        # XLA has no product collective; gather-then-multiply is exact for
-        # every dtype (a log/exp trick would lose integer exactness).
-        gathered = lax.all_gather(tensor, axis_name, axis=0, tiled=False)
-        return jnp.prod(gathered, axis=0)
+    with jax.named_scope(_scopes.allreduce_scope(axis_name)):
+        if op is ReduceOp.SUM:
+            return lax.psum(tensor, axis_name)
+        if op is ReduceOp.AVERAGE:
+            return lax.pmean(tensor, axis_name)
+        if op is ReduceOp.MIN:
+            return lax.pmin(tensor, axis_name)
+        if op is ReduceOp.MAX:
+            return lax.pmax(tensor, axis_name)
+        if op is ReduceOp.PRODUCT:
+            # XLA has no product collective; gather-then-multiply is exact
+            # for every dtype (a log/exp trick would lose integer exactness).
+            gathered = lax.all_gather(tensor, axis_name, axis=0, tiled=False)
+            return jnp.prod(gathered, axis=0)
     raise ValueError(f"unknown op {op}")
 
 

@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from horovod_tpu.common import scopes as _scopes
+
 __all__ = ["flash_attention", "flash_attention_fn", "flash_attention_lse",
            "flash_lse_supported", "fallback_count"]
 
@@ -244,7 +246,7 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
     kernel = _with_extras(_fwd_kernel, 2, names, causal=causal,
                           sm_scale=sm_scale, block_k=bk)
     inputs = (q, k, v, *arrays)
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -261,7 +263,9 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
             jax.ShapeDtypeStruct((bh, 8, s), jnp.float32),
         ],
         interpret=_interpret(),
-    )(*inputs)
+    )
+    with jax.named_scope(_scopes.FLASH_FWD):
+        out, lse = call(*inputs)
     return out, lse
 
 
@@ -418,7 +422,7 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
 
     dq_kernel = _with_extras(_bwd_dq_kernel, 1, names, causal=causal,
                              sm_scale=sm_scale, block_k=bk)
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         dq_kernel,
         grid=(bh, s // bq),
         in_specs=[
@@ -432,11 +436,13 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
         out_specs=pl.BlockSpec((None, bq, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta, *bias_inputs)
+    )
+    with jax.named_scope(_scopes.FLASH_DQ):
+        dq = dq_call(q, k, v, do, lse, delta, *bias_inputs)
 
     dkv_kernel = _with_extras(_bwd_dkv_kernel, 2, names, causal=causal,
                               sm_scale=sm_scale, block_q=bq)
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         dkv_kernel,
         grid=(bh, s // bk),
         in_specs=[
@@ -456,7 +462,9 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta, *bias_inputs)
+    )
+    with jax.named_scope(_scopes.FLASH_DKV):
+        dk, dv = dkv_call(q, k, v, do, lse, delta, *bias_inputs)
     return dq, dk, dv
 
 

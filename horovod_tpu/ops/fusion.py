@@ -11,9 +11,23 @@ memcpy — fusion is *flattening the gradient pytree at trace time*.  We
 ravel + concatenate same-dtype leaves into flat buffers up to the threshold,
 run one ``psum`` per buffer (a single large ICI collective keeps the links
 saturated, which is where scaling efficiency is won — SURVEY.md §7 "Fusion on
-TPU"), then slice + reshape back.  XLA fuses the pack/unpack copies into the
-collective's prologue/epilogue, so unlike the reference there is no extra HBM
-round-trip.  The plan is shape-static, so it traces once per pytree structure.
+TPU"), then slice + reshape back.  The plan is shape-static, so it traces once
+per pytree structure.
+
+What the packing costs (TPU v5e, the 664M-parameter decoder of PERF.md,
+bf16 gradients, measured under the ``hvd.fusion.pack`` / ``hvd.fusion.unpack``
+scopes below; PERF.md, PR 24).  XLA does NOT fold the copies into the
+collective: the concatenates and slices it cannot simplify away run as
+operations of their own, an extra HBM round-trip of the buffers they touch.
+On four chips that is 5.8 ms of a 276 ms step (``fusion_pack_ms``: 3.8 ms of
+concatenates and slices, 2.1 ms for the average's division of the fused
+buffers), beside ~5 ms of layout copies XLA inserts around the buffers without
+a name, and the all-reduce between backward pass and optimizer keeps XLA from
+fusing each weight's update into its gradient matmul, as it does on one chip
+(there the optimizer's own fusions take 13 ms, here 37, the backward pass
+17 ms less).  On ONE chip XLA removes the ``psum`` and most of the packing
+with it, but not all: 4.0-4.8 ms a step of concatenate and slice remain for
+a collective that no longer exists.
 """
 
 from __future__ import annotations
@@ -26,6 +40,8 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from horovod_tpu.common import scopes as _scopes
 
 __all__ = [
     "DEFAULT_FUSION_THRESHOLD",
@@ -158,12 +174,16 @@ def fuse_apply(
             i = bucket.indices[0]
             out[i] = fn(leaves[i])
             continue
-        flat = jnp.concatenate(
-            [jnp.ravel(leaves[i]) for i in bucket.indices], axis=0
-        )
+        with jax.named_scope(_scopes.FUSION_PACK):
+            flat = jnp.concatenate(
+                [jnp.ravel(leaves[i]) for i in bucket.indices], axis=0
+            )
         reduced = fn(flat)
         offset = 0
-        for i, size, shape in zip(bucket.indices, bucket.sizes, bucket.shapes):
-            out[i] = jax.lax.slice_in_dim(reduced, offset, offset + size).reshape(shape)
-            offset += size
+        with jax.named_scope(_scopes.FUSION_UNPACK):
+            for i, size, shape in zip(bucket.indices, bucket.sizes,
+                                      bucket.shapes):
+                out[i] = jax.lax.slice_in_dim(
+                    reduced, offset, offset + size).reshape(shape)
+                offset += size
     return jax.tree.unflatten(treedef, out)

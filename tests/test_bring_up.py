@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -56,6 +57,40 @@ def test_compile_cache_placement(monkeypatch, tmp_path):
         assert dirs[0] == dirs[1] == os.path.join(REPO, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("placed", ["environment", "checkout", "cpu"])
+def test_compile_cache_keeps_small_programs(monkeypatch, tmp_path, placed):
+    """Where the cache is on, every program goes into it (JAX's defaults
+    leave out what compiles in under a second: a job's small programs
+    compile again in every run); where it is left off, nothing is set."""
+    from horovod_tpu.common import compile_cache
+
+    options = ("jax_compilation_cache_dir",
+               "jax_persistent_cache_min_compile_time_secs",
+               "jax_persistent_cache_min_entry_size_bytes")
+    before = {name: getattr(jax.config, name) for name in options}
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    if placed == "environment":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    elif placed == "checkout":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        path = compile_cache.enable_compile_cache()
+        if placed == "cpu":
+            assert path is None
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 1
+            assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+        else:
+            assert path == (str(tmp_path) if placed == "environment"
+                            else compile_cache.default_cache_dir())
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+            assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+    finally:
+        for name, value in before.items():
+            jax.config.update(name, value)
 
 
 def test_quick_start_compiles_the_step_once():
