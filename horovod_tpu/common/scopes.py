@@ -27,7 +27,35 @@ scope                 what falls under it
 ``hvd.flash.fwd``     the flash kernel's forward Mosaic call
 ``hvd.flash.dq``      its backward call for dq
 ``hvd.flash.dkv``     its backward call for dk and dv
+``hvd.loop.pass``     a looped model's passes over its layer stack
+                      (``LlamaModel`` with ``total_ut_steps`` > 1): the
+                      scan whole, so the walks over the layers and the
+                      scan's own work (stacking what the backward pass
+                      needs, adding up a weight's gradient over passes)
+``hvd.loop.exit``     what ends a pass, inside ``hvd.loop.pass``: the final
+                      norm and the exit gate; and in
+                      ``expected_exit_loss`` each exit's head and
+                      cross-entropy and the exit distribution.  A reader
+                      asks for this scope first
 ====================  ====================================================
+
+Recomputed work needs no scope of the program's: JAX names it.  What
+``jax.checkpoint`` / ``nn.remat`` runs again in the backward pass carries
+the component ``rematted_computation`` (``REMATTED``; JAX 0.9.0, read from
+the compiled step's ``op_name``s and from a v5e trace, PR 26), and the
+checkpointed function's own name stack a second time.  The passes are a
+``scan``, so with ``P`` for ``hvd.loop.pass/while/body/closed_call/
+LlamaModel.pass_and_exit`` a layer's first forward is ``hvd.loss/
+jvp(LlamaModel)/P/layer_0/...``; its repeated forward ``hvd.loss/
+transpose(jvp(LlamaModel))/P/LlamaModel.pass_and_exit/checkpoint/
+rematted_computation/layer_0/...``; the backward work around it the same
+path without that component.  An exit's head again: ``hvd.loss/
+transpose(jvp(hvd.loop.exit))/while/body/closed_call/checkpoint/
+rematted_computation/LlamaModel.head/lm_head/dot_general``.
+
+``FLASH_OUT_NAME`` and ``FLASH_LSE_NAME`` are no scopes but
+``checkpoint_name``s: the flash kernel's output and row statistics, for a
+recomputation policy that keeps them (``LlamaConfig.remat``).
 """
 
 from __future__ import annotations
@@ -35,7 +63,8 @@ from __future__ import annotations
 __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_DQ", "FLASH_DKV",
-    "TRAIN_STEP_PROGRAM", "allreduce_scope",
+    "LOOP_PASS", "LOOP_EXIT", "REMATTED", "FLASH_OUT_NAME",
+    "FLASH_LSE_NAME", "TRAIN_STEP_PROGRAM", "allreduce_scope",
 ]
 
 LOSS = "hvd.loss"
@@ -48,6 +77,11 @@ APPLY = "hvd.apply"
 FLASH_FWD = "hvd.flash.fwd"
 FLASH_DQ = "hvd.flash.dq"
 FLASH_DKV = "hvd.flash.dkv"
+LOOP_PASS = "hvd.loop.pass"
+LOOP_EXIT = "hvd.loop.exit"
+REMATTED = "rematted_computation"    # JAX's own component, not a scope
+FLASH_OUT_NAME = "hvd.flash.out"
+FLASH_LSE_NAME = "hvd.flash.lse"
 
 #: The name JAX reports for the program ``make_train_step`` builds: in its
 #: monitoring events (``hvd.compile_log()``: tracing under this name,

@@ -6,6 +6,15 @@ benchmark) plus the transformer families (BERT, Llama) used by the
 FSDP-style baseline workloads.  All models are flax.linen modules designed
 TPU-first: bfloat16 compute with float32 params, channels-last layouts,
 MXU-friendly dimensions.
+
+``LlamaConfig.total_ut_steps`` > 1 makes ``LlamaModel`` a looped LM (Ouro /
+LoopLM): the one layer stack applied that many times with shared weights,
+an exit gate after every pass, and ``(hidden, gate_logits)`` returned for
+``ops.losses.expected_exit_loss``.  It is a training path:
+``generation`` (``prefill``, ``decode_step``, ``generate`` and the paged
+forms), the serve plane and the pipelined step refuse it, and
+``ring_attention`` as ``attention_fn`` and the MoE block are untested with
+it.  ``LlamaConfig.remat`` recomputes each layer in the backward pass.
 """
 
 from horovod_tpu.models.mnist import MnistConvNet, MnistMLP

@@ -106,13 +106,22 @@ def _params(variables):
     return variables["params"] if "params" in variables else variables
 
 
+def _check_supported(cfg: LlamaConfig) -> None:
+    if cfg.num_experts > 1:
+        raise NotImplementedError("KV-cache decode supports dense (non-MoE)"
+                                  " configs")
+    if cfg.total_ut_steps > 1:
+        raise NotImplementedError(
+            f"KV-cache decode walks the layer stack once; a looped model "
+            f"(total_ut_steps={cfg.total_ut_steps}) needs a cache for "
+            f"every pass and an exit rule, which are not built")
+
+
 def prefill(cfg: LlamaConfig, variables, prompt_ids, *, cache_len: int):
     """Run the prompt [B, S0] through the model once, returning
     (last-position logits [B, V], kv_cache) with caches sized
     ``cache_len`` (>= S0 + tokens to generate)."""
-    if cfg.num_experts > 1:
-        raise NotImplementedError("KV-cache decode supports dense (non-MoE)"
-                                  " configs")
+    _check_supported(cfg)
     p = _params(variables)
     B, S0 = prompt_ids.shape
     shape = (cfg.num_layers, B, cache_len, cfg.num_kv_heads, cfg.head_dim)
@@ -125,6 +134,7 @@ def prefill(cfg: LlamaConfig, variables, prompt_ids, *, cache_len: int):
 def decode_step(cfg: LlamaConfig, variables, token, cache, *, pos):
     """One token [B] in, next-position logits [B, V] out; ``pos`` is the
     token's global position (traced ok)."""
+    _check_supported(cfg)
     p = _params(variables)
     ck, cv = cache
     logits, ck, cv = _forward(cfg, p, token[:, None], ck, cv,
@@ -311,6 +321,7 @@ def paged_decode_step(cfg: LlamaConfig, variables, tokens, pool_k, pool_v,
     NOT bitwise identical (online softmax re-associates the key
     reduction) — ``HOROVOD_SERVE_FUSED_ATTN=0`` keeps the oracle.
     """
+    _check_supported(cfg)
     p = _params(variables)
     x = jnp.take(p["tok_emb"]["embedding"], tokens[:, None],
                  axis=0).astype(cfg.dtype)
@@ -360,9 +371,7 @@ def paged_prefill(cfg: LlamaConfig, variables, prompt_ids, pool_k, pool_v,
     exp → 0), so the hit path is bit-identical to the full prefill
     (tests/test_serve.py pins it).
     """
-    if cfg.num_experts > 1:
-        raise NotImplementedError("KV-cache decode supports dense (non-MoE)"
-                                  " configs")
+    _check_supported(cfg)
     p = _params(variables)
     B, S_pad = prompt_ids.shape
     bs = pool_k.shape[2]
@@ -423,9 +432,7 @@ def paged_prefill_suffix(cfg: LlamaConfig, variables, prompt_ids, pool_k,
     (a clamped ``dynamic_update_slice`` would silently shift the
     writes); the engine falls back to the static path otherwise.
     """
-    if cfg.num_experts > 1:
-        raise NotImplementedError("KV-cache decode supports dense (non-MoE)"
-                                  " configs")
+    _check_supported(cfg)
     p = _params(variables)
     bs = pool_k.shape[2]
     nb = cache_len // bs

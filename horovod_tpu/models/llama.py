@@ -18,6 +18,10 @@ TPU-first design:
   substitutes a ppermute-ring blockwise kernel for sequence parallelism.
 * Optional MoE (``num_experts > 1``): top-k routed experts via einsum
   dispatch/combine, the expert-parallel workload.
+* Optional weight-shared passes over the stack (``total_ut_steps > 1``,
+  the looped LM of Ouro / LoopLM) with a per-token exit gate, and
+  recomputation of each layer in the backward pass (``remat``); see
+  ``LlamaModel``.
 """
 
 from __future__ import annotations
@@ -28,13 +32,49 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_policies
+
+from horovod_tpu.common import scopes as _scopes
 
 __all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "apply_rope",
            "causal_attention"]
 
 
+REMAT_POLICIES = {
+    # Nothing of a layer is kept but its input: the backward pass runs the
+    # layer's forward again, the flash kernel's forward call included.
+    "layer": None,
+    # The flash kernel's output and row statistics are kept as well (34 MB
+    # a layer at 8192 tokens x 2048), so its forward call is not repeated.
+    "layer_keep_attention": checkpoint_policies.save_only_these_names(
+        _scopes.FLASH_OUT_NAME, _scopes.FLASH_LSE_NAME),
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
+    """Sizes and options of ``LlamaModel``.
+
+    ``total_ut_steps`` (the published key of Ouro's ``config.json``) is the
+    number of weight-shared passes over the layer stack.  1 is the plain
+    decoder: one walk over the layers, logits out.  With T > 1 the same
+    ``num_layers`` modules (one parameter set) are applied T times, the
+    final norm ends every pass, an exit gate reads every pass's output,
+    and the model returns the T normalised hidden states and gate logits
+    in place of logits (``LlamaModel``; the loss is
+    ``ops.losses.expected_exit_loss``).  ``models/generation.py`` and the
+    serve plane refuse T > 1 (a served looped model keeps a cache a pass),
+    and so does the pipelined step, which walks the stack once.
+    ``ring_attention`` as ``attention_fn`` and the MoE block are untested
+    with T > 1.
+
+    ``remat`` names what the backward pass recomputes: ``"none"``;
+    ``"layer"`` (each layer application keeps its input alone);
+    ``"layer_keep_attention"`` (and the flash kernel's output and row
+    statistics).  Four passes hold four times one pass's activations,
+    so a looped model at a long sequence needs one of the last two.
+    """
+
     vocab_size: int = 32000
     hidden_size: int = 4096
     num_layers: int = 32
@@ -56,6 +96,16 @@ class LlamaConfig:
     # Fused Pallas RMSNorm (see RMSNorm.fused): enable on shard_map /
     # single-device paths; leave off under GSPMD.
     fused_rmsnorm: bool = False
+    total_ut_steps: int = 1
+    remat: str = "none"
+
+    def __post_init__(self):
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps is {self.total_ut_steps}: "
+                             f"at least one pass over the stack")
+        if self.remat != "none" and self.remat not in REMAT_POLICIES:
+            raise ValueError(f"remat is {self.remat!r}: 'none' or one of "
+                             f"{sorted(REMAT_POLICIES)}")
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -238,6 +288,29 @@ class LlamaLayer(nn.Module):
 
 
 class LlamaModel(nn.Module):
+    """Decoder-only LM; with ``config.total_ut_steps`` T > 1 a looped one.
+
+    T = 1: ``tokens [B, S] -> logits [B, S, V]``.
+
+    T > 1 (Ouro / LoopLM; Zhu et al., arXiv:2510.25741).  With E the
+    embedding, Stack the ``num_layers`` layers in order, N the final
+    RMSNorm, W the untied head::
+
+        h(0) = E[tokens];   h(t) = N(Stack(h(t-1)))        t = 1..T
+        g(t) = h(t) . w_g + b_g                            (float32)
+
+    The same layer modules, so the same weights and the same rotary
+    positions, in every pass: autodiff sums a weight's gradient over the
+    passes.  The normalised h(t) is what pass t + 1 reads, what exit t's
+    head reads and what the gate reads.  ``__call__`` returns ``(hidden
+    [T, B, S, H], gate_logits [T, B, S])`` and never a logits tensor:
+    ``head`` turns one exit's hidden states into logits, and
+    ``ops.losses.expected_exit_loss`` applies it exit by exit so that one
+    logits tensor is alive at a time.  The gate (``exit_gate``: a Linear
+    H -> 1 with bias, zero-initialised, shared by all passes) gives
+    sigma(g(t)), the probability of leaving at pass t having reached it.
+    """
+
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
 
@@ -249,11 +322,61 @@ class LlamaModel(nn.Module):
                      name="tok_emb")(input_ids)
         cos, sin = rope_freqs(cfg.head_dim, S, cfg.rope_theta,
                               offset=positions_offset)
-        for i in range(cfg.num_layers):
-            x = LlamaLayer(cfg, attention_fn=self.attention_fn,
-                           name=f"layer_{i}")(x, cos, sin)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.fused_rmsnorm,
-                    name="norm_f")(x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.logits_dtype, name="lm_head")(x)
-        return logits
+        layer_cls = LlamaLayer
+        if cfg.remat != "none":
+            layer_cls = nn.remat(LlamaLayer,
+                                 policy=REMAT_POLICIES[cfg.remat])
+
+        def one_pass(mdl, x):
+            """Stack(x), its modules made under ``mdl`` by name."""
+            for i in range(cfg.num_layers):
+                x = layer_cls(cfg, attention_fn=self.attention_fn,
+                              name=f"layer_{i}", parent=mdl)(x, cos, sin)
+            return x
+
+        def norm_f(mdl, x):
+            return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.fused_rmsnorm,
+                           name="norm_f", parent=mdl)(x)
+
+        if cfg.total_ut_steps == 1:
+            return self.head(norm_f(self, one_pass(self, x)))
+
+        def norm_and_gate(mdl, x):
+            x = norm_f(mdl, x)
+            gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate",
+                            kernel_init=nn.initializers.zeros,
+                            parent=mdl)(x.astype(jnp.float32))
+            return x, gate[..., 0]
+
+        if cfg.remat != "none":
+            # Else a pass keeps three float32 copies of its state for the
+            # norm's and the gate's backward (201 MB at 8192 x 2048).
+            norm_and_gate = nn.remat(norm_and_gate)
+
+        def pass_and_exit(mdl, x, _):
+            x = one_pass(mdl, x)
+            with jax.named_scope(_scopes.LOOP_EXIT):
+                x, gate = norm_and_gate(mdl, x)
+            return x, (x, gate)
+
+        # A scan, not a Python loop: its backward pass is a loop that adds
+        # each pass's weight gradients to one accumulator.  Unrolled, XLA
+        # fuses the optimizer's update into ONE pass's weight-gradient
+        # matmuls and holds their operands until every other pass has
+        # contributed: 4.9 GB at 8192 tokens x 2048 (PERF.md, PR 26).
+        with jax.named_scope(_scopes.LOOP_PASS):
+            _, (hidden, gate_logits) = nn.scan(
+                pass_and_exit, variable_broadcast="params",
+                split_rngs={"params": False},
+                length=cfg.total_ut_steps)(self, x, None)
+        if self.is_initializing():
+            self.head(hidden[-1])
+        return hidden, gate_logits
+
+    @nn.compact
+    def head(self, hidden):
+        """Normalised hidden states ``[..., H]`` -> logits ``[..., V]``:
+        the one output head, which every exit shares."""
+        cfg = self.config
+        return nn.Dense(cfg.vocab_size, use_bias=False,
+                        dtype=cfg.logits_dtype, name="lm_head")(hidden)

@@ -44,6 +44,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from horovod_tpu.common import scopes as _scopes
@@ -484,6 +485,10 @@ def _flash(q, k, v, causal, sm_scale):
 
 def _flash_fwd(q, k, v, causal, sm_scale):
     out, lse = _fwd(q, k, v, causal, sm_scale)
+    # Named for recomputation policies (LlamaConfig.remat): a policy that
+    # keeps both spares the backward pass this call.  Identity otherwise.
+    out = checkpoint_name(out, _scopes.FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, _scopes.FLASH_LSE_NAME)
     return out, (q, k, v, out, lse)
 
 

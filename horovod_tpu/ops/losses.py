@@ -22,6 +22,12 @@ Versus plain autodiff of the lse form (which stores an f32 copy of the
 logits and emits an f32 cotangent), this halves every logits-sized
 tensor's bytes when the head computes in bf16.  Same math; gradients
 match autodiff to bf16 rounding (tests/test_losses.py).
+
+``expected_exit_loss`` is the objective of a looped LM
+(``LlamaModel`` with ``total_ut_steps`` > 1): every exit's cross-entropy
+through the one shared head, weighted token by token with the learned
+exit distribution, less an entropy term.  It is built on the same
+``_nll``, one exit at a time.
 """
 
 from __future__ import annotations
@@ -29,7 +35,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["softmax_cross_entropy"]
+from horovod_tpu.common import scopes as _scopes
+
+__all__ = ["softmax_cross_entropy", "exit_log_distribution",
+           "expected_exit_loss"]
 
 
 def _nll_impl(logits, targets):
@@ -97,3 +106,50 @@ def softmax_cross_entropy(logits, targets, *, where=None,
     if where is not None:
         return jnp.sum(nll) / jnp.maximum(jnp.sum(where), 1)
     return jnp.mean(nll)
+
+
+def exit_log_distribution(gate_logits):
+    """``ln p_t`` of leaving a looped model at pass t, from the gate's
+    logits ``[T, ...]``, in float32.
+
+    With ``lambda_t = sigmoid(g_t)`` the chance of leaving at pass t once
+    there: ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for t < T, and the
+    last pass takes what is left, ``p_T = prod_{j<T} (1 - lambda_j)``
+    (``g_T`` is not read).  The ``p_t`` add up to 1.
+    """
+    g = gate_logits.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g[:-1]), axis=0)   # ln prod(1 - l)
+    reached = jnp.concatenate([jnp.zeros_like(g[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(g[:-1]) + reached, stay[-1:]], axis=0)
+
+
+def expected_exit_loss(head, hidden, gate_logits, targets, *,
+                       beta: float = 0.1):
+    """Mean over all positions of ``sum_t p_t CE_t - beta H(p)``: the
+    expected cross-entropy under the exit distribution, with an entropy
+    regulariser (a uniform prior over exits; stage I of Zhu et al.,
+    arXiv:2510.25741).
+
+    ``hidden [T, ..., H]`` and ``gate_logits [T, ...]`` are what
+    ``LlamaModel`` returns with ``total_ut_steps`` = T > 1; ``head`` maps
+    one exit's hidden states to logits (``lambda h: model.apply(params, h,
+    method="head")``); ``targets``: integer ``[...]``.  The exits are
+    walked by ``lax.map``, each exit's head and cross-entropy under
+    ``jax.checkpoint``: the forward pass keeps an exit's ``[...]`` losses
+    and no logits, and the backward pass is a loop that makes one exit's
+    logits again, adds its part of the head's gradient to one accumulator
+    and frees them, so one logits tensor and one cotangent of that size
+    are alive at a time.  Distribution and entropy in float32.
+    """
+
+    @jax.checkpoint
+    def exit_nll(h):
+        return _nll(head(h), targets)
+
+    with jax.named_scope(_scopes.LOOP_EXIT):
+        log_p = exit_log_distribution(gate_logits)
+        p = jnp.exp(log_p)
+        nll = jax.lax.map(exit_nll, hidden)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        return jnp.mean(jnp.sum(p * nll, axis=0) - beta * entropy)

@@ -126,6 +126,14 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *,
 # Pipelined Llama (the framework's PP training path)
 # ---------------------------------------------------------------------------
 
+def _refuse_looped(cfg) -> None:
+    if cfg.total_ut_steps > 1:
+        raise NotImplementedError(
+            f"the pipelined step sends a microbatch through the stages "
+            f"once; total_ut_steps={cfg.total_ut_steps} would run one "
+            f"pass silently")
+
+
 def init_pipelined_llama(cfg, rng, n_stages: int):
     """Init Llama params in pipeline layout.
 
@@ -139,6 +147,7 @@ def init_pipelined_llama(cfg, rng, n_stages: int):
     if cfg.num_layers % n_stages != 0:
         raise ValueError(
             f"{cfg.num_layers} layers not divisible into {n_stages} stages")
+    _refuse_looped(cfg)
     model = LlamaModel(cfg)
     ids = jnp.zeros((1, 8), jnp.int32)
     params = model.init(rng, ids)["params"]
@@ -197,6 +206,7 @@ def make_pipelined_llama_train_step(cfg, optimizer, mesh, *,
         # Gradients are already data-psum'd inside the shard_map below.
         optimizer = optimizer.inner
 
+    _refuse_looped(cfg)
     batch_axes = tuple(data_axes(mesh)) or ()
     layer_mod = LlamaLayer(cfg)
 

@@ -88,6 +88,11 @@ def build_model_config(serve_cfg: ServeConfig):
             raise ValueError(f"unsupported HOROVOD_SERVE_DTYPE "
                              f"{serve_cfg.dtype!r}")
         cfg = dataclasses.replace(cfg, dtype=dt, logits_dtype=dt)
+    if cfg.total_ut_steps > 1:
+        raise ValueError(
+            f"serve model {serve_cfg.model!r} is a looped model "
+            f"(total_ut_steps={cfg.total_ut_steps}): the paged KV cache "
+            f"holds one pass over the stack, so it cannot be served")
     return cfg
 
 
