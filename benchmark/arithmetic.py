@@ -38,25 +38,53 @@ def decoder_train_flops_per_token(*, hidden: int, layers: int, heads: int,
     return 3.0 * (matmul + attention)
 
 
-def flash_step_flops(*, batch: int, seq: int, heads: int,
-                     head_dim: int) -> float:
-    """Operations causal flash attention needs for one layer's forward and
-    backward over ``batch`` sequences.  Forward: QK^T and PV.  Backward,
-    with the probabilities not stored: QK^T again, dP = dO V^T, dV = P^T
-    dO, dQ = dS K, dK = dS^T Q.  Seven products of ``head_dim``
-    multiply-adds per kept pair -- the least any split into kernels can
-    do; a split that repeats products does not raise the count."""
-    return 7 * 2 * head_dim * batch * heads * causal_pairs(seq)
+def _flash_flops(products: int, *, batch: int, seq: int, heads: int,
+                 head_dim: int) -> float:
+    """``products`` of ``head_dim`` multiply-adds a kept query-key pair."""
+    return products * 2 * head_dim * batch * heads * causal_pairs(seq)
 
 
-def flash_step_bytes(*, batch: int, seq: int, heads: int, head_dim: int,
-                     itemsize: int = 2) -> float:
-    """Bytes one layer's flash calls must move through HBM: forward reads
-    q, k, v and writes o; backward reads q, k, v, o, dO and writes dq,
-    dk, dv.  The fp32 row statistics (lse, delta) are 1/head_dim of a
-    tensor each and are left out."""
-    tensor = batch * seq * heads * head_dim * itemsize
-    return (4 + 8) * tensor
+def _flash_bytes(tensors: int, *, batch: int, seq: int, heads: int,
+                 head_dim: int, itemsize: int = 2) -> float:
+    """``tensors`` whole ``[batch, seq, heads, head_dim]`` arrays."""
+    return tensors * batch * seq * heads * head_dim * itemsize
+
+
+def flash_forward_flops(**shape) -> float:
+    """Operations the forward pass of causal flash attention needs for one
+    layer over ``batch`` sequences: QK^T and PV, two products of
+    ``head_dim`` multiply-adds per kept pair."""
+    return _flash_flops(2, **shape)
+
+
+def flash_backward_flops(**shape) -> float:
+    """The backward pass, with the probabilities not stored: QK^T again,
+    dP = dO V^T, dV = P^T dO, dQ = dS K, dK = dS^T Q.  Five products per
+    kept pair -- the least any split into kernels can do; a split that
+    repeats products does not raise the count."""
+    return _flash_flops(5, **shape)
+
+
+def flash_forward_bytes(**shape) -> float:
+    """Bytes the forward pass must move through HBM: it reads q, k, v and
+    writes o.  The fp32 row statistics (lse, delta) are 1/head_dim of a
+    tensor each and are left out, here and in the backward pass."""
+    return _flash_bytes(4, **shape)
+
+
+def flash_backward_bytes(**shape) -> float:
+    """The backward pass reads q, k, v, o, dO and writes dq, dk, dv."""
+    return _flash_bytes(8, **shape)
+
+
+def flash_step_flops(**shape) -> float:
+    """Forward and backward of one layer: seven products per kept pair."""
+    return flash_forward_flops(**shape) + flash_backward_flops(**shape)
+
+
+def flash_step_bytes(**shape) -> float:
+    """Forward and backward of one layer: twelve tensors."""
+    return flash_forward_bytes(**shape) + flash_backward_bytes(**shape)
 
 
 def roofline_seconds(flops: float, nbytes: float, peaks: dict) -> tuple:

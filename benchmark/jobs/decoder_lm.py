@@ -95,13 +95,23 @@ class DecoderLM:
             ffn=c.intermediate_size, vocab=c.vocab_size, seq=self.seq)
 
     def kernel_work_per_step(self) -> dict:
-        """Operations and bytes a chip's Mosaic calls need in one step."""
+        """Operations and bytes a chip's Mosaic calls need in one step:
+        the flash kernel's ``forward`` and ``backward`` pass over every
+        layer, and the two together."""
         c = self.llama
         shape = dict(batch=self.batch // self.chips, seq=self.seq,
                      heads=c.num_heads, head_dim=c.head_dim)
+
+        def work(flops, nbytes):
+            return {"flops": c.num_layers * flops(**shape),
+                    "bytes": c.num_layers * nbytes(**shape)}
+
         return {"flash": {
-            "flops": c.num_layers * arithmetic.flash_step_flops(**shape),
-            "bytes": c.num_layers * arithmetic.flash_step_bytes(**shape)}}
+            **work(arithmetic.flash_step_flops, arithmetic.flash_step_bytes),
+            "forward": work(arithmetic.flash_forward_flops,
+                            arithmetic.flash_forward_bytes),
+            "backward": work(arithmetic.flash_backward_flops,
+                             arithmetic.flash_backward_bytes)}}
 
     # -- checks ---------------------------------------------------------
 

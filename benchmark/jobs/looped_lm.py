@@ -56,10 +56,14 @@ class LoopedLM(DecoderLM):
         return self.passes * super().flops_per_unit()
 
     def kernel_work_per_step(self) -> dict:
-        """``passes x layers`` applications of flash; nothing recomputed."""
-        return {kernel: {what: self.passes * count
-                         for what, count in work.items()}
-                for kernel, work in super().kernel_work_per_step().items()}
+        """``passes x layers`` applications of flash, each pass of the
+        kernel's and their sum alike; nothing recomputed."""
+        def scaled(work):
+            return {what: scaled(count) if isinstance(count, dict)
+                    else self.passes * count
+                    for what, count in work.items()}
+
+        return scaled(super().kernel_work_per_step())
 
     # -- checks ---------------------------------------------------------
 

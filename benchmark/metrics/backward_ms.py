@@ -1,6 +1,6 @@
 """Trace, by the program's scopes: self time a step on the ``XLA Ops`` line
 of the operations under ``hvd.loss`` and a ``transpose(...)``: the backward
-pass, the flash kernel's dq and dkv calls included.  A weight-gradient
+pass, the flash kernel's backward calls included.  A weight-gradient
 matmul into which XLA fused the optimizer's update counts here: the
 fusion has one ``op_name``, its root's."""
 

@@ -1,5 +1,5 @@
 """Trace: device time a step of the Mosaic calls (the flash kernel's
-forward, dq and dkv calls)."""
+forward and backward calls)."""
 
 
 def read(ctx):

@@ -1,8 +1,8 @@
 """Where a step's device time goes, told by the program's own names.
 
 The program puts ``jax.named_scope``s around its forward and backward
-pass, its optimizer, its gradient packing, its all-reduces and the three
-calls of its flash kernel (``horovod_tpu/common/scopes.py`` is the table),
+pass, its optimizer, its gradient packing, its all-reduces and the calls
+of its flash kernel (``horovod_tpu/common/scopes.py`` is the table),
 and keeps a log of what JAX compiled (``hvd.compile_log()``).  This module
 reads both for the per-layer readers of ``benchmark/metrics``: it opens the
 traced run's own file, reduces it once and keeps the result for the other
@@ -33,8 +33,13 @@ Every operation falls into exactly one class:
 * ``unscoped``    the rest: what the scopes miss.
 
 A fusion that XLA built across a boundary has one ``op_name``, its root's,
-and counts where that puts it.  A program without the scopes (the parent
-of the PR that added them) gives no number, not a wrong one.
+and counts where that puts it.
+
+The flash kernel is read by pass, not by call: a Mosaic call under the
+forward call's scope is ``fwd``, wherever it runs; one under any other
+scope of the kernel's is ``bwd``, however many calls the backward pass is
+made of and whatever the table names them.  A program without the scopes
+(the parent of the PR that added them) gives no number, not a wrong one.
 """
 
 from __future__ import annotations
@@ -43,11 +48,12 @@ import functools
 import os
 from collections import defaultdict
 
-from benchmark import manifest, trace, xspace
+from benchmark import arithmetic, manifest, trace, xspace
 
 OP_NAME_STAT = "tf_op"
 CLASSES = ("forward", "backward", "optimizer", "packing", "collective",
            "unscoped")
+FLASH_PASSES = {"fwd": "forward", "bwd": "backward"}   # a job's name for it
 TRACE_DIR = os.path.join(manifest.ROOT, ".bench_trace")
 
 
@@ -117,14 +123,19 @@ def classify(name: str, op_name: str, names) -> str:
 
 
 def flash_call(name: str, op_name: str, names):
-    """Which of the flash kernel's calls a Mosaic call is, or None."""
+    """``(pass, scope)`` of a Mosaic call of the flash kernel's, or None:
+    the pass is ``fwd`` under ``names.FLASH_FWD`` -- a forward call that a
+    recomputation policy repeats inside ``transpose(...)`` is a forward
+    call still -- and ``bwd`` under any other scope that shares its prefix
+    (``hvd.flash.``); the scope is named by its last component (``dq``)."""
     if trace.op_kind(name) != "mosaic":
         return None
-    scopes = _path(op_name)[1]
-    for call, scope in (("fwd", names.FLASH_FWD), ("dq", names.FLASH_DQ),
-                        ("dkv", names.FLASH_DKV)):
-        if scope in scopes:
-            return call
+    prefix = names.FLASH_FWD[:names.FLASH_FWD.rindex(".") + 1]
+    scopes = [s for s in _path(op_name)[1] if s.startswith(prefix)]
+    if names.FLASH_FWD in scopes:
+        return "fwd", names.FLASH_FWD[len(prefix):]
+    if scopes:
+        return "bwd", scopes[0][len(prefix):]
     return None
 
 
@@ -169,33 +180,39 @@ def read_events(path: str) -> dict:
 # -- the reduction -----------------------------------------------------------
 
 def partition_device(ops, modules, names) -> dict:
-    """One chip's self time on the ``XLA Ops`` line by class, by flash
-    call and by collective axes, in seconds over the window of the whole
-    steps it traced (``trace.step_window``, as the other reduction)."""
+    """One chip's self time on the ``XLA Ops`` line by class, by the flash
+    kernel's pass and scope and by collective axes, in seconds over the
+    window of the whole steps it traced (``trace.step_window``, as the
+    other reduction)."""
     start, end, steps = trace.step_window(modules)
     by_class = dict.fromkeys(CLASSES, 0.0)
-    flash, axes, unscoped = defaultdict(float), defaultdict(float), \
-        defaultdict(float)
+    flash, flash_scopes, axes, unscoped = (defaultdict(float)
+                                           for _ in range(4))
     for (name, op_name), own in trace.self_times(
             trace.clip(ops, start, end)):
         kind = classify(name, op_name, names)
         by_class[kind] += own
         call = flash_call(name, op_name, names)
         if call:
-            flash[call] += own
+            flash[call[0]] += own
+            flash_scopes[call[1]] += own
         if kind == "collective":
             axes[collective_axes(op_name, names) or "unscoped"] += own
         elif kind == "unscoped":
             unscoped[trace.family(name)] += own
     return {"steps": steps, "classes": by_class, "flash": dict(flash),
+            "flash_scopes": dict(flash_scopes),
             "collective_axes": dict(axes), "unscoped": dict(unscoped)}
 
 
 def partition(events: dict, names) -> dict | None:
     """Milliseconds a step, averaged over the chips that ran operations:
-    ``{"classes": {class: ms}, "flash": {call: ms}, "collective_axes":
-    {axes: ms}, "unscoped": [[family, ms], ...]}``.  None if no operation
-    is under any scope of the program's: it has none."""
+    ``{"classes": {class: ms}, "flash": {"fwd": ms, "bwd": ms},
+    "flash_scopes": {scope: ms}, "collective_axes": {axes: ms},
+    "unscoped": [[family, ms], ...]}``; ``flash`` is for the metrics,
+    ``flash_scopes`` (the same time by the scope's last component) for
+    people.  None if no operation is under any scope of the program's: it
+    has none."""
     chips = [partition_device(d["ops"], d["modules"], names)
              for _, d in sorted(events["devices"].items())
              if d["ops"] and d["modules"]]
@@ -213,6 +230,7 @@ def partition(events: dict, names) -> dict | None:
 
     unscoped = sorted(total("unscoped").items(), key=lambda kv: -kv[1])
     return {"classes": total("classes"), "flash": total("flash"),
+            "flash_scopes": total("flash_scopes"),
             "collective_axes": total("collective_axes"),
             "unscoped": [list(kv) for kv in unscoped[:8]]}
 
@@ -230,7 +248,10 @@ def _reduce_file(path: str, _stamp: float) -> dict | None:
         f"{name} {ms:.3f}" for name, ms in reduced["classes"].items()))
     if reduced["flash"]:
         say("flash calls, ms a step: " + ", ".join(
-            f"{call} {ms:.3f}" for call, ms in reduced["flash"].items()))
+            f"{scope} {ms:.3f}"
+            for scope, ms in reduced["flash_scopes"].items())
+            + "; by pass: " + ", ".join(
+            f"{which} {ms:.3f}" for which, ms in reduced["flash"].items()))
     if reduced["collective_axes"]:
         say("collectives by mesh axes, ms a step: " + ", ".join(
             f"{axes} {ms:.3f}"
@@ -260,11 +281,27 @@ def class_ms(ctx, kind: str):
     return None if reduced is None else reduced["classes"][kind]
 
 
-def flash_ms(ctx, call: str):
+def flash_ms(ctx, which: str):
+    """Device time a step of the flash kernel's ``fwd`` or ``bwd`` pass."""
     reduced = traced(ctx)
     if reduced is None or "flash" not in ctx["job"]["kernel_work_per_step"]:
         return None
-    return reduced["flash"].get(call)
+    return reduced["flash"].get(which)
+
+
+def flash_roofline(ctx, which: str):
+    """The least time the chip could take for that pass of a step's flash
+    calls (``arithmetic.roofline_seconds`` of what the job says the pass
+    needs) over the time the trace shows the pass take, in per cent."""
+    ms = flash_ms(ctx, which)
+    if not ms or ctx["peaks"] is None:
+        return None
+    work = ctx["job"]["kernel_work_per_step"]["flash"][FLASH_PASSES[which]]
+    least_s, bound = arithmetic.roofline_seconds(
+        work["flops"], work["bytes"], ctx["peaks"])
+    say(f"flash {which} roofline: {bound} bound, least "
+        f"{least_s * 1e3:.3f} ms a step")
+    return 100.0 * least_s * 1e3 / ms
 
 
 @functools.lru_cache(maxsize=1)

@@ -73,8 +73,16 @@ def test_job_counts_four_passes_and_four_heads_and_nothing_recomputed():
     assert job.units_per_step == plain.units_per_step
     flash, plain_flash = (j.kernel_work_per_step()["flash"]
                           for j in (job, plain))
-    assert flash == {"flops": 4 * plain_flash["flops"],
-                     "bytes": 4 * plain_flash["bytes"]}
+    assert set(flash) == {"flops", "bytes", "forward", "backward"}
+    for work, plain_work in ((flash, plain_flash),
+                             (flash["forward"], plain_flash["forward"]),
+                             (flash["backward"], plain_flash["backward"])):
+        assert work["flops"] == 4 * plain_work["flops"] > 0
+        assert work["bytes"] == 4 * plain_work["bytes"] > 0
+    assert flash["flops"] == (flash["forward"]["flops"]
+                              + flash["backward"]["flops"])
+    assert flash["bytes"] == (flash["forward"]["bytes"]
+                              + flash["backward"]["bytes"])
     assert job.expected_first_loss() == pytest.approx(
         plain.expected_first_loss() - 0.1 * 1.75 * np.log(2))
     with pytest.raises(ValueError, match="looped"):
@@ -253,7 +261,9 @@ def test_recorded_trace_holds_the_loops_scopes_and_jaxs_name(recorded):
     held = {scopes.bare(part) for n in op_names
             for part in scopes.components(n)}
     assert {names.LOSS, names.LOOP_PASS, names.LOOP_EXIT, names.REMATTED,
-            names.FLASH_FWD, names.FLASH_DQ, names.FLASH_DKV} <= held
+            names.FLASH_FWD} <= held
+    assert {scope for scope in held if scope.startswith("hvd.flash.")
+            } - {names.FLASH_FWD}                # and a backward pass
     again = [n for n in op_names
              if names.REMATTED in scopes.components(n)]
     assert again and all("transpose(" in n for n in again)

@@ -4,6 +4,7 @@ whole train step.  The TPU compiler is loaded by this file alone, inside a
 fixture: see the on-chip-measurement guide."""
 
 import os
+import re
 import sys
 
 import jax
@@ -151,34 +152,70 @@ def _decoder_cells():
                 w["name"])["traffic"]]
 
 
-@pytest.mark.parametrize("workload", _decoder_cells())
-def test_flash_kernel_compiles_for_v5e_at_the_cells_shape(compiled_for_tpu,
-                                                          workload):
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+def _compiled_attention(topo, cell, attention) -> str:
+    """The compiled text of one forward + backward of ``attention`` at the
+    cell's shape, one chip's rows."""
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops.flash_attention import flash_attention
-
-    cell = manifest.cell(workload)
     config, traffic = cell["config"], cell["traffic"]
-    one_chip = SingleDeviceSharding(compiled_for_tpu.devices[0])
     shape = jax.ShapeDtypeStruct(
         (traffic["batch_per_chip"], traffic["sequence"],
          config["num_attention_heads"], config["head_dim"]),
-        jnp.bfloat16, sharding=one_chip)
+        jnp.bfloat16, sharding=SingleDeviceSharding(topo.devices[0]))
 
     def forward_and_backward(q, k, v):
-        out, vjp = jax.vjp(flash_attention, q, k, v)
+        out, vjp = jax.vjp(attention, q, k, v)
         return out, vjp(out)
 
-    text = jax.jit(forward_and_backward).lower(
+    return jax.jit(forward_and_backward).lower(
         shape, shape, shape).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def _square_arrays(text: str, sequence: int) -> list:
+    """Arrays whose two trailing dimensions are both ``sequence``: the
+    scores or probabilities of attention that did not go through the
+    kernel."""
+    return re.findall(rf"\w+\[(?:\d+,)*{sequence},{sequence}\]", text)
+
+
+@pytest.mark.parametrize("workload", _decoder_cells())
+def test_flash_kernel_compiles_for_v5e_at_the_cells_shape(compiled_for_tpu,
+                                                          workload):
+    """What every correct implementation has, however it splits its work:
+    a forward and a backward pass took the kernel path, and nothing built
+    an ``[S, S]`` array."""
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    cell = manifest.cell(workload)
+    text = _compiled_attention(compiled_for_tpu, cell, flash_attention)
+    assert text.count(MOSAIC_CALL) >= 2
+    assert _square_arrays(text, cell["traffic"]["sequence"]) == []
+
+
+def test_dense_attention_at_the_cells_shape_would_be_caught(
+        compiled_for_tpu):
+    """The control of the test above: the model's own dense attention, at
+    the shortest decoder cell's shape, is no Mosaic call and builds the
+    ``[S, S]`` scores."""
+    from horovod_tpu.models.llama import causal_attention
+
+    cell = min((manifest.cell(name) for name in _decoder_cells()),
+               key=lambda c: c["traffic"]["sequence"])
+    text = _compiled_attention(compiled_for_tpu, cell, causal_attention)
+    assert text.count(MOSAIC_CALL) == 0
+    assert _square_arrays(text, cell["traffic"]["sequence"])
 
 
 def test_whole_step_compiles_for_v5e_and_fits(compiled_for_tpu):
-    """The first cell's train step at full size: 3 Mosaic calls a layer,
-    and arguments + temporaries inside one chip's memory."""
+    """The first cell's train step at full size: every layer takes the
+    kernel path (as many Mosaic calls a layer as one forward + backward of
+    ``flash_attention`` at that shape compiles to, however many that is),
+    and arguments + temporaries fit inside one chip's memory."""
     import horovod_tpu.jax as hvd
+    from horovod_tpu.ops.flash_attention import flash_attention
 
     cell = manifest.cell(MANIFEST["workloads"][0]["name"])
     config, traffic = cell["config"], cell["traffic"]
@@ -201,9 +238,11 @@ def test_whole_step_compiles_for_v5e_and_fits(compiled_for_tpu):
     compiled = step.lower(*placed(state, P()),
                           placed(batch, P("data"))).compile()
     memory = compiled.memory_analysis()
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"'
-    ) == 3 * config["num_hidden_layers"]
+    calls_a_layer = _compiled_attention(
+        compiled_for_tpu, cell, flash_attention).count(MOSAIC_CALL)
+    assert calls_a_layer >= 2
+    assert compiled.as_text().count(MOSAIC_CALL) == (
+        config["num_hidden_layers"] * calls_a_layer)
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < manifest.peaks("TPU v5 lite")["hbm_bytes"])
     assert memory.argument_size_in_bytes > 0.25 * 16e9

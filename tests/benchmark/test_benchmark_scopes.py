@@ -218,16 +218,87 @@ def test_classify(name, op_name, kind):
 
 def test_flash_calls_and_collective_axes_by_scope():
     fwd = STEP + "hvd.loss/jvp(M)/attn/hvd.flash.fwd/pallas_call"
-    assert scopes.flash_call(MOSAIC, fwd, names) == "fwd"
+    assert scopes.flash_call(MOSAIC, fwd, names) == ("fwd", "fwd")
     assert scopes.flash_call(
         MOSAIC, STEP + "hvd.loss/transpose(hvd.loss)/jvp(hvd.flash.dq)/"
-        "pallas_call", names) == "dq"
-    assert scopes.flash_call(MOSAIC, STEP + "other/pallas_call",
-                             names) is None
+        "pallas_call", names) == ("bwd", "dq")
+    # A backward call under a name this reader has never seen.
+    assert scopes.flash_call(
+        MOSAIC, STEP + "hvd.loss/transpose(jvp(M))/attn/hvd.flash.anything/"
+        "pallas_call", names) == ("bwd", "anything")
+    # Not the kernel's: another prefix, and the prefix without its dot.
+    for other in ("other/pallas_call", "hvd.flashy.fwd/pallas_call",
+                  "hvd.flash/pallas_call", "hvd.loss/pallas_call"):
+        assert scopes.flash_call(MOSAIC, STEP + other, names) is None
     assert scopes.flash_call(FUSION, fwd, names) is None
     assert scopes.collective_axes(
         STEP + "hvd.allreduce.data+fsdp/psum", names) == "data+fsdp"
     assert scopes.collective_axes(STEP + "psum", names) is None
+
+
+# -- the flash kernel by pass, on hand-built events --------------------------
+
+BACKWARD = STEP + "hvd.loss/transpose(jvp(M))/layer_0/attn/"
+
+
+def _flash_step(backward_calls, extra=()):
+    """One step of 10 ms on one chip: a forward call of 1 ms, then the
+    backward pass's Mosaic calls ``(scope, ms)`` back to back from 4 ms,
+    then whatever ``extra`` ``(op_name, ms)`` holds from 8 ms."""
+    ops = [((FUSION, STEP + "hvd.loss/jvp(M)/mul"), 0.0, _ms(2)),
+           ((MOSAIC, STEP + "hvd.loss/jvp(M)/layer_0/attn/hvd.flash.fwd/"
+             "pallas_call"), _ms(2), _ms(3))]
+    at = 4.0
+    for scope, ms in backward_calls:
+        ops.append(((MOSAIC, BACKWARD + scope + "/pallas_call"),
+                    _ms(at), _ms(at + ms)))
+        at += ms
+    at = 8.0
+    for op_name, ms in extra:
+        ops.append(((MOSAIC, STEP + op_name), _ms(at), _ms(at + ms)))
+        at += ms
+    return scopes.partition({"devices": {0: {
+        "ops": ops, "modules": [("jit_hvd_train_step(1)", 0.0, _ms(10))]}}},
+        names)
+
+
+def test_a_backward_pass_of_one_call_reads_as_one_of_two():
+    """However the program splits its backward pass, and whatever it names
+    the calls, ``bwd`` is the time under the kernel's scopes but ``fwd``."""
+    two = _flash_step([("hvd.flash.dq", 1.25), ("hvd.flash.dkv", 1.75)])
+    one = _flash_step([("hvd.flash.anything", 3.0)])
+    assert two["flash"] == pytest.approx({"fwd": 1.0, "bwd": 3.0})
+    assert one["flash"] == pytest.approx(two["flash"])
+    assert two["flash_scopes"] == pytest.approx(
+        {"fwd": 1.0, "dq": 1.25, "dkv": 1.75})
+    assert one["flash_scopes"] == pytest.approx(
+        {"fwd": 1.0, "anything": 3.0})
+    assert one["classes"] == pytest.approx(two["classes"])
+
+
+def test_a_forward_call_repeated_in_the_backward_pass_is_forward():
+    """A recomputation policy that does not keep the flash output runs the
+    forward call again inside ``transpose(...)``: backward by class, and
+    the kernel's forward pass still."""
+    again = ("hvd.loss/transpose(jvp(M))/checkpoint/rematted_computation/"
+             "layer_0/attn/hvd.flash.fwd/pallas_call")
+    reduced = _flash_step([("hvd.flash.dq", 1.0), ("hvd.flash.dkv", 2.0)],
+                          extra=[(again, 1.0)])
+    assert reduced["flash"] == pytest.approx({"fwd": 2.0, "bwd": 3.0})
+    assert reduced["classes"]["backward"] == pytest.approx(3.0 + 1.0)
+    assert reduced["classes"]["forward"] == pytest.approx(2.0 + 1.0)
+
+
+def test_a_mosaic_call_under_no_flash_scope_is_in_neither_pass():
+    reduced = _flash_step(
+        [("hvd.flash.dkv", 2.0)],
+        extra=[("hvd.loss/transpose(jvp(M))/mlp/grouped_matmul/pallas_call",
+                1.5), ("pallas_call", 0.25)])
+    assert reduced["flash"] == pytest.approx({"fwd": 1.0, "bwd": 2.0})
+    assert sum(reduced["flash_scopes"].values()) == pytest.approx(3.0)
+    # The plain reduction's Mosaic time holds them all the same.
+    assert reduced["classes"]["backward"] == pytest.approx(2.0 + 1.5)
+    assert reduced["classes"]["unscoped"] == pytest.approx(0.25)
 
 
 # -- the partition, on hand-built events -------------------------------------
@@ -277,7 +348,8 @@ def test_partition_on_hand_built_events():
         "forward": 3.0, "backward": 0.5 + 1.0 + 1.5 + 1.0,
         "optimizer": 0.5, "packing": 0.25, "collective": 1.0,
         "unscoped": 0.25})
-    assert reduced["flash"] == pytest.approx({"fwd": 1.0, "dkv": 1.5})
+    assert reduced["flash"] == pytest.approx({"fwd": 1.0, "bwd": 1.5})
+    assert reduced["flash_scopes"] == pytest.approx({"fwd": 1.0, "dkv": 1.5})
     assert reduced["collective_axes"] == pytest.approx({"data": 1.0})
     assert reduced["unscoped"] == [["copy bf16[8]", pytest.approx(0.25)]]
     # The same sum as the reduction that knows no scopes.
@@ -319,16 +391,27 @@ def test_recorded_trace_holds_the_scopes(recorded):
     held = {scopes.bare(part) for n in op_names
             for part in scopes.components(n)}
     assert {names.LOSS, names.FUSION_PACK, names.FUSION_UNPACK,
-            names.APPLY, names.FLASH_FWD, names.FLASH_DQ, names.FLASH_DKV,
+            names.APPLY, names.FLASH_FWD,
             names.allreduce_scope("data")} <= held
+    # And a backward pass of the flash kernel's, under whatever names.
+    assert {scope for scope in held if scope.startswith("hvd.flash.")
+            } - {names.FLASH_FWD}
     assert os.path.getsize(RECORDED) < 400_000
+
+
+def _plain_reduction(events):
+    """The reduction that knows no scopes, on the same events."""
+    return trace.reduce_events({"host": {}, "devices": {
+        n: {"ops": [(name, s, e) for (name, _), s, e in device["ops"]],
+            "modules": device["modules"]}
+        for n, device in events["devices"].items()}})
 
 
 def test_recorded_partition_adds_up_to_the_plain_reduction(recorded):
     """Forward + backward + optimizer + packing + unscoped + collective is
-    the existing reduction's mosaic + xla + collective, and the three
-    flash calls its mosaic.  To a part in a million on the same events;
-    to 0.2 % against ``trace.read_xplane``, because JAX's ProfileData cuts
+    the existing reduction's mosaic + xla + collective, and the flash
+    kernel's two passes its mosaic.  To a part in a million on the same
+    events; to 0.2 % against ``trace.read_xplane``, because JAX's ProfileData cuts
     every start and duration to whole nanoseconds where the file has
     picoseconds, and this step's operations take a few nanoseconds each."""
     events = scopes.read_events(recorded)
@@ -336,10 +419,7 @@ def test_recorded_partition_adds_up_to_the_plain_reduction(recorded):
     classes = reduced["classes"]
     assert set(classes) == set(scopes.CLASSES)
     assert all(ms > 0 for ms in classes.values())
-    same_events = trace.reduce_events({"host": {}, "devices": {
-        n: {"ops": [(name, s, e) for (name, _), s, e in device["ops"]],
-            "modules": device["modules"]}
-        for n, device in events["devices"].items()}})
+    same_events = _plain_reduction(events)
     for plain, rel in ((same_events, 1e-6),
                        (trace.reduce_trace(recorded), 2e-3)):
         assert plain["chips"] == 4 and plain["steps"] == 3
@@ -350,7 +430,12 @@ def test_recorded_partition_adds_up_to_the_plain_reduction(recorded):
             plain["collective_ms_per_step"], rel=rel)
         assert sum(reduced["flash"].values()) == pytest.approx(
             plain["mosaic_ms_per_step"], rel=rel)
-    assert set(reduced["flash"]) == {"fwd", "dq", "dkv"}
+    assert set(reduced["flash"]) == {"fwd", "bwd"}
+    by_scope = reduced["flash_scopes"]
+    assert reduced["flash"]["fwd"] == pytest.approx(by_scope["fwd"])
+    assert reduced["flash"]["bwd"] == pytest.approx(
+        sum(ms for scope, ms in by_scope.items() if scope != "fwd"))
+    assert reduced["flash"]["bwd"] > reduced["flash"]["fwd"] > 0
     assert set(reduced["collective_axes"]) == {"data"}
     assert reduced["collective_axes"]["data"] == pytest.approx(
         classes["collective"])
@@ -361,28 +446,53 @@ def test_recorded_partition_adds_up_to_the_plain_reduction(recorded):
 
 NEW_TRACE_METRICS = ["forward_ms", "backward_ms", "optimizer_ms",
                      "fusion_pack_ms", "unscoped_ms", "flash_fwd_ms",
-                     "flash_dq_ms", "flash_dkv_ms"]
+                     "flash_bwd_ms"]
+FLASH_SHARES = ["flash_fwd_roofline", "flash_bwd_roofline"]
+FLASH_METRICS = ["flash_ms", "flash_roofline", "flash_fwd_ms",
+                 "flash_bwd_ms"] + FLASH_SHARES
 NEW_LOG_METRICS = ["step_trace_ms", "step_lower_ms", "step_backend_ms"]
 
 
+def _cells_whose_job_reports_flash_work(bench):
+    found = []
+    for workload in bench["workloads"]:
+        cell = manifest.cell(workload["name"], bench)
+        job = manifest.load_job(cell["config"]["job"]).build(
+            cell["config"], cell["traffic"], cell["chips"])
+        if "flash" in job.kernel_work_per_step():
+            found.append(workload["name"])
+    return found
+
+
 def test_new_metrics_are_in_the_manifest_as_the_issue_put_them():
-    per_layer = {m["name"]: m for m in manifest.load()["per_layer"]}
+    bench = manifest.load()
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_TRACE_METRICS:
         assert per_layer[name]["source"] == "program_span"
         assert per_layer[name]["moves"] == "step_ms_p90"
         assert per_layer[name]["unit"] == "ms"
+    for name in FLASH_SHARES:
+        assert per_layer[name]["source"] == "device_trace"
+        assert per_layer[name]["moves"] == "step_ms_p90"
+        assert (per_layer[name]["unit"], per_layer[name]["better"]) == (
+            "%", "higher")
     for name in NEW_LOG_METRICS:
         assert per_layer[name]["source"] == "program_counter"
         assert per_layer[name]["moves"] == "setup_s"
         assert per_layer[name]["unit"] == "ms"
         assert per_layer[name]["layer"] == "entry and init"
         assert "workloads" not in per_layer[name]
-    flash = [per_layer[n] for n in NEW_TRACE_METRICS if "flash" in n]
-    assert all(len(m["workloads"]) == 3 and m["layer"] == "kernels"
-               for m in flash)
+    # Every metric of the flash kernel's is reported where there is flash
+    # work to read, and nowhere else: the cells are derived, not counted.
+    flash_cells = _cells_whose_job_reports_flash_work(bench)
+    assert flash_cells and len(flash_cells) < len(bench["workloads"])
+    assert [n for n in per_layer if "flash" in n] == FLASH_METRICS
+    for name in FLASH_METRICS:
+        assert per_layer[name]["workloads"] == flash_cells
+        assert per_layer[name]["layer"] == "kernels"
 
 
-@pytest.mark.parametrize("metric", NEW_TRACE_METRICS)
+@pytest.mark.parametrize("metric", NEW_TRACE_METRICS + FLASH_SHARES)
 def test_trace_readers_give_nothing_without_a_device_trace(metric):
     ctx = {"trace": None, "job": {"kernel_work_per_step": {"flash": {}}}}
     assert manifest.load_reader(metric)(ctx) is None
@@ -406,10 +516,10 @@ def test_trace_readers_read_the_traced_run_once(monkeypatch, recorded,
     printed = capsys.readouterr().out
     assert "[benchmark] a step by the program's scopes" in printed
     assert "collectives by mesh axes, ms a step: data" in printed
-    assert manifest.load_reader("flash_dq_ms")(ctx) == pytest.approx(
-        expected["flash"]["dq"])
+    assert manifest.load_reader("flash_bwd_ms")(ctx) == pytest.approx(
+        expected["flash"]["bwd"])
     no_kernel = {**ctx, "job": {"kernel_work_per_step": {}}}
-    assert manifest.load_reader("flash_dq_ms")(no_kernel) is None
+    assert manifest.load_reader("flash_bwd_ms")(no_kernel) is None
     assert capsys.readouterr().out == ""     # reduced once, said once
     assert scopes._reduce_file.cache_info().misses == 1
 
@@ -424,6 +534,60 @@ def test_readers_give_nothing_for_a_program_without_scopes(monkeypatch,
     assert manifest.load_reader("forward_ms")(ctx) is None
     assert manifest.load_reader("flash_fwd_ms")(ctx) is None
     scopes._reduce_file.cache_clear()
+
+
+@pytest.mark.parametrize("which, work", [("fwd", "forward"),
+                                         ("bwd", "backward")])
+def test_flash_shares_are_the_pass_s_least_time_over_its_time(
+        monkeypatch, recorded, capsys, which, work):
+    """A pass's share is ``arithmetic.roofline_seconds`` of what the job
+    says that pass needs over the pass's traced time, and no share without
+    a table of peaks (a CPU run) or without the kernel."""
+    monkeypatch.setattr(trace, "find_xplane", lambda trace_dir: recorded)
+    peaks = manifest.peaks("TPU v5 lite")
+    took_ms = scopes.partition(scopes.read_events(recorded),
+                               names)["flash"][which]
+    need_s = 0.25 * took_ms * 1e-3             # so the share reads 25 %
+    job = {"kernel_work_per_step": {"flash": {
+        "flops": 1.0, "bytes": 1.0,
+        work: {"flops": need_s * peaks["bf16_flops_per_s"], "bytes": 8.0}}}}
+    ctx = {"trace": {"not": "read"}, "peaks": peaks, "job": job}
+    scopes._reduce_file.cache_clear()
+    read = manifest.load_reader(f"flash_{which}_roofline")
+    assert read(ctx) == pytest.approx(25.0)
+    assert f"flash {which} roofline: flops bound" in capsys.readouterr().out
+    assert read({**ctx, "peaks": None}) is None
+    assert read({**ctx, "job": {"kernel_work_per_step": {}}}) is None
+    scopes._reduce_file.cache_clear()
+
+
+@pytest.mark.parametrize("recording", sorted(
+    name for name in os.listdir(os.path.join(manifest.HERE, "testdata"))
+    if ".xplane.pb" in name))
+def test_flash_passes_add_up_to_the_mosaic_time_of_every_recording(
+        recording, tmp_path):
+    """``fwd`` + ``bwd`` is the plain reduction's Mosaic time on each trace
+    recorded on the chip: no call of the kernel's falls between the
+    passes.  The oldest recording (PR 23) is of a program without scopes
+    and gives no split."""
+    path = os.path.join(manifest.HERE, "testdata", recording)
+    if recording.endswith(".gz"):
+        with gzip.open(path, "rb") as f:
+            path = tmp_path / "recording.xplane.pb"
+            path.write_bytes(f.read())
+    events = scopes.read_events(str(path))
+    reduced = scopes.partition(events, names)
+    plain = _plain_reduction(events)
+    assert plain["mosaic_ms_per_step"] > 0
+    if reduced is None:
+        assert recording.startswith("tiny-decoder-v5e")
+        return
+    flash = reduced["flash"]
+    assert set(flash) == {"fwd", "bwd"}
+    assert flash["fwd"] + flash["bwd"] == pytest.approx(
+        plain["mosaic_ms_per_step"], rel=1e-6)
+    assert sum(reduced["flash_scopes"].values()) == pytest.approx(
+        plain["mosaic_ms_per_step"], rel=1e-6)
 
 
 def test_compile_log_readers_sum_the_step_s_records(monkeypatch, capsys):

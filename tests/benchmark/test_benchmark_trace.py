@@ -59,8 +59,8 @@ def test_per_operation_sums(events):
     end = max(e for _, _, e in device["modules"])
     ops = trace.clip(device["ops"], start, end)
     mosaic = [(s, e) for name, s, e in ops if "tpu_custom_call" in name]
-    # One layer: the flash kernel's forward, dq and dkv calls, six steps.
-    assert len(mosaic) == 3 * 6
+    # One layer: the flash kernel's calls, the same in each of six steps.
+    assert mosaic and len(mosaic) % 6 == 0
     assert reduced["mosaic_ms_per_step"] == pytest.approx(
         sum(e - s for s, e in mosaic) / 6 * 1e3)
     # No operation of this program nests, so self times add up to the sum.
@@ -184,6 +184,53 @@ def test_one_flash_call_by_hand():
     assert bound == "flops"
     assert seconds == pytest.approx(16 * 7 * 256 * 33558528 / 197e12)
     assert arithmetic.roofline_seconds(1.0, 1e6, peaks)[1] == "bytes"
+
+
+SHAPES = [dict(batch=1, seq=8192, heads=16, head_dim=128),
+          dict(batch=4, seq=2048, heads=16, head_dim=128),
+          dict(batch=3, seq=7, heads=2, head_dim=64)]
+
+
+@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: f"s{s['seq']}")
+def test_flash_passes_add_up_to_the_step_and_split_2_to_5_and_4_to_8(shape):
+    """Forward: QK^T and PV, q k v read and o written.  Backward: five
+    products, q k v o dO read and dq dk dv written.  The step's counts,
+    which ``flash_roofline`` has read since PR 23, are their sums."""
+    forward = arithmetic.flash_forward_flops(**shape)
+    backward = arithmetic.flash_backward_flops(**shape)
+    assert forward + backward == arithmetic.flash_step_flops(**shape)
+    assert 5 * forward == 2 * backward > 0
+    pairs = shape["batch"] * shape["heads"] * arithmetic.causal_pairs(
+        shape["seq"])
+    assert forward == 2 * 2 * shape["head_dim"] * pairs
+    moved = arithmetic.flash_forward_bytes(**shape)
+    moved_back = arithmetic.flash_backward_bytes(**shape)
+    assert moved + moved_back == arithmetic.flash_step_bytes(**shape)
+    assert 8 * moved == 4 * moved_back > 0
+    tensor = (shape["batch"] * shape["seq"] * shape["heads"]
+              * shape["head_dim"])
+    assert moved == 4 * tensor * 2
+    assert arithmetic.flash_backward_bytes(**shape, itemsize=4) == (
+        8 * tensor * 4)
+
+
+def test_each_flash_pass_is_bound_by_operations_at_the_cells_shapes():
+    """At 8k and at 2k both passes are ``flops`` bound on a v5e, so a
+    pass's share of its roofline is its share of the MXU's peak on the
+    products counted: 12.56 and 31.40 ms a step of nine layers at 8k."""
+    peaks = manifest.peaks("TPU v5 lite")
+    for shape in SHAPES[:2]:
+        for flops, nbytes in ((arithmetic.flash_forward_flops,
+                               arithmetic.flash_forward_bytes),
+                              (arithmetic.flash_backward_flops,
+                               arithmetic.flash_backward_bytes)):
+            assert arithmetic.roofline_seconds(
+                flops(**shape), nbytes(**shape), peaks)[1] == "flops"
+    least = [9e3 * arithmetic.roofline_seconds(
+        flops(**SHAPES[0]), 1.0, peaks)[0]
+        for flops in (arithmetic.flash_forward_flops,
+                      arithmetic.flash_backward_flops)]
+    assert least == pytest.approx([12.56, 31.40], abs=0.005)
 
 
 def test_one_bottleneck_block_by_hand():
