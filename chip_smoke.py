@@ -239,10 +239,10 @@ def train(cfg: LlamaConfig, batch_per_chip: int, seq: int, steps: int) -> dict:
     require(losses[-1] < losses[0], f"loss did not fall: {losses}")
     require(compiles_after_first == 0,
             f"the step compiled {compiles_after_first + 1} times")
-    # One flash forward and two backward kernels a layer.
-    require(mosaic_calls == 3 * cfg.num_layers,
+    # One flash forward and one backward kernel a layer.
+    require(mosaic_calls == 2 * cfg.num_layers,
             f"{mosaic_calls} Mosaic calls in the step, expected "
-            f"{3 * cfg.num_layers}: a kernel fell off the path")
+            f"{2 * cfg.num_layers}: a kernel fell off the path")
     require(n_chips == 1 or all_reduces >= 1,
             f"no all-reduce in a step over {n_chips} chips")
     require(max(in_use) <= 1.1 * min(in_use),

@@ -25,8 +25,7 @@ scope                 what falls under it
 ``hvd.optimizer``     the wrapped optax transformation's ``update``
 ``hvd.apply``         ``optax.apply_updates``
 ``hvd.flash.fwd``     the flash kernel's forward Mosaic call
-``hvd.flash.dq``      its backward call for dq
-``hvd.flash.dkv``     its backward call for dk and dv
+``hvd.flash.bwd``     its backward Mosaic call (dq, dk and dv from one call)
 ``hvd.loop.pass``     a looped model's passes over its layer stack
                       (``LlamaModel`` with ``total_ut_steps`` > 1): the
                       scan whole, so the walks over the layers and the
@@ -38,6 +37,10 @@ scope                 what falls under it
                       cross-entropy and the exit distribution.  A reader
                       asks for this scope first
 ====================  ====================================================
+
+The benchmark (``benchmark/scopes.py``) reads ``hvd.flash.fwd`` by name and
+every other ``hvd.flash.*`` scope as the backward pass, however many calls
+make that pass and whatever this table names them.
 
 Recomputed work needs no scope of the program's: JAX names it.  What
 ``jax.checkpoint`` / ``nn.remat`` runs again in the backward pass carries
@@ -62,7 +65,7 @@ from __future__ import annotations
 
 __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
-    "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_DQ", "FLASH_DKV",
+    "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD",
     "LOOP_PASS", "LOOP_EXIT", "REMATTED", "FLASH_OUT_NAME",
     "FLASH_LSE_NAME", "TRAIN_STEP_PROGRAM", "allreduce_scope",
 ]
@@ -75,8 +78,7 @@ AUX_ALLREDUCE = "hvd.aux_allreduce"
 OPTIMIZER = "hvd.optimizer"
 APPLY = "hvd.apply"
 FLASH_FWD = "hvd.flash.fwd"
-FLASH_DQ = "hvd.flash.dq"
-FLASH_DKV = "hvd.flash.dkv"
+FLASH_BWD = "hvd.flash.bwd"
 LOOP_PASS = "hvd.loop.pass"
 LOOP_EXIT = "hvd.loop.exit"
 REMATTED = "rematted_computation"    # JAX's own component, not a scope

@@ -4,34 +4,45 @@ No reference equivalent (the reference has no attention at all, SURVEY.md
 §5.7); this is the framework's hot-op kernel for transformer training
 (/opt/skills/guides/pallas_guide.md is the API playbook).
 
-Design (FlashAttention-2 style, causal):
-* forward: grid over (batch*heads, query blocks); K/V live in VMEM for
-  the whole row of the grid; online softmax (running max + normalizer)
-  in fp32 scratch, so the [S, S] score matrix never exists and HBM
-  traffic is O(S·D) instead of O(S²);
-* backward: two kernels — dQ (grid over query blocks, loop over KV
-  blocks) and dK/dV (grid over KV blocks, loop over query blocks) — both
-  recompute probabilities from the saved log-sum-exp, the standard
-  FLOPs-for-memory trade;
+Design (FlashAttention-2 style):
+* forward: one Mosaic call, grid over (batch*heads, query blocks); K/V live
+  in VMEM for the whole row of the grid; online softmax (running max +
+  normalizer) in fp32 scratch, so the [S, S] score matrix never exists and
+  HBM traffic is O(S·D) instead of O(S²);
+* backward: ONE Mosaic call for dq, dk and dv, grid over (batch*heads, key
+  blocks), a loop over the live query blocks.  A (query block, key block)
+  pair recomputes its probabilities from the saved log-sum-exp (the
+  standard FLOPs-for-memory trade) and forms scores, p, dp and ds once:
+  five ``head_dim``-deep products a pair (q·kᵀ, pᵀ·do, do·vᵀ, dsᵀ·q, ds·k).
+  dk and dv of the key block leave with the grid step; dq adds up in an
+  fp32 ``[S, D]`` VMEM scratch that stays over the key blocks of a head
+  (zeroed at the first, written out at the last), so that grid axis runs
+  in order (``arbitrary``) and the head axis is ``parallel``.  Q, dO and dq
+  are whole rows in VMEM; the call states its own scoped-VMEM limit from
+  its shapes (``_bwd_vmem_limit``);
 * fp32 accumulation on the MXU via ``preferred_element_type``; bf16 in /
   bf16 out;
-* causal masking is block-aware: KV blocks entirely above the diagonal
+* causal masking is block-aware: block pairs entirely above the diagonal
   are skipped (the loop bound, not a mask), the diagonal block gets the
-  intra-block triangle.
+  intra-block triangle; packed rows skip the pairs before a segment's start
+  the same way.
 
 ``flash_attention`` is a drop-in for the model zoo's ``attention_fn``
 seam ([B, S, H, D] layout, GQA via KV-head repetition).  Shapes off the
 kernel's tiling are zero-padded onto it (sequence to the next 128,
-head dim to the next 64 with the softmax scale folded into q) and
-sliced back, so models keep the kernel — and its O(S) memory contract —
-unchanged on any shape; ``interpret=True`` is used automatically
-off-TPU so tests exercise the same kernel logic on CPU.
+head dim to the next 64 with the TRUE head dim's softmax scale riding as
+the kernel's fp32 ``sm_scale``) and sliced back, so models keep the kernel
+— and its O(S) memory contract — unchanged on any shape;
+``interpret=True`` is used automatically off-TPU so tests exercise the
+same kernel logic on CPU.  The longest row is bounded by VMEM: the forward
+call holds K and V whole and refuses S = 16384 at D = 128 in bf16 under
+the compiler's default limit.
 
-Measured on one v5e (bf16, B=4 H=16 D=128, vs XLA's fused dense
-attention): S=4096 1.8x faster (31 TF/s), S=8192 3.2x (66 TF/s, ~59% of
-the chip's 112 TF/s matmul peak); fwd+bwd 1.9x at S=4096.  Crossover is
-around S≈2048 — below that XLA's dense fusion wins on latency (flash
-still wins on memory).
+On one v5e (197 TFLOP/s bf16), in the benchmark's decoder step (bf16,
+16 heads of 128, causal; PERF.md §5 keeps the current figures): the
+forward call runs at 57 % of the MXU's peak on its two products a kept pair
+at S = 8192 and 41 % at 2048; the backward call at 70 % and 57 % on its
+five (52 % and 42 % when two calls executed seven).
 """
 
 from __future__ import annotations
@@ -46,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.common import scopes as _scopes
 
@@ -102,7 +114,7 @@ def _interpret() -> bool:
 
 def _seg_mask(scores, seg_start, ki, block_k):
     """Mask keys below each query's segment start (packed causal
-    attention); shared by the forward and both backward kernels."""
+    attention); shared by the forward and the backward kernel."""
     block_q = scores.shape[0]
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
@@ -187,7 +199,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
 
     m, l, acc = jax.lax.fori_loop(kv_first, n_kv_live, body, (m, l, acc))
     o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    # log-sum-exp per row, consumed by the backward kernels.  lse_ref holds
+    # log-sum-exp per row, consumed by the backward kernel.  lse_ref holds
     # the full row (TPU blocks must tile (8, 128)); write this q-block's
     # slice dynamically.
     lse_row = m + jnp.log(jnp.maximum(l, 1e-30))
@@ -271,69 +283,18 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels
+# Backward kernel
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, causal, sm_scale, block_k, bias_ref=None,
-                   seg_ref=None):
-    qi = pl.program_id(1)
-    block_q, d = q_ref.shape
-    s = k_ref.shape[0]
-    q = q_ref[:]
-    do = do_ref[:]
-    lse = lse_ref[0, pl.dslice(qi * block_q, block_q)]
-    delta = delta_ref[0, pl.dslice(qi * block_q, block_q)]
-    seg_start = None
-    if seg_ref is not None:
-        seg_start = seg_ref[0, pl.dslice(qi * block_q, block_q)]
-
-    n_kv = s // block_k
-    if causal:
-        # ceil((qi+1)*bq / bk): every KV block touching or below the
-        # diagonal, valid for ANY bq/bk ratio (bq < bk included).
-        n_kv_live = jnp.minimum(
-            ((qi + 1) * block_q + block_k - 1) // block_k, n_kv)
-    else:
-        n_kv_live = n_kv
-    kv_first = 0
-    if seg_start is not None:
-        kv_first = jnp.min(seg_start) // block_k
-
-    def body(ki, dq):
-        k_blk = k_ref[pl.dslice(ki * block_k, block_k), :]
-        v_blk = v_ref[pl.dslice(ki * block_k, block_k), :]
-        scores = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            scores = jnp.where(q_pos >= k_pos, scores, -1e30)
-        if bias_ref is not None:
-            scores = scores + bias_ref[0, pl.dslice(ki * block_k,
-                                                    block_k)][None, :]
-        if seg_start is not None:
-            scores = _seg_mask(scores, seg_start, ki, block_k)
-        p = jnp.exp(scores - lse[:, None])
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * sm_scale).astype(k_blk.dtype)
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    dq = jax.lax.fori_loop(kv_first, n_kv_live, body,
-                           jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[:] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, causal, sm_scale, block_q,
-                    bias_ref=None, seg_ref=None):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, causal, sm_scale,
+                block_q, bias_ref=None, seg_ref=None):
+    # Grid (head, key block).  q_ref/do_ref: [S, D], the head's whole row;
+    # k_ref/v_ref, dk_ref/dv_ref: [block_k, D], this step's key block;
+    # dq_ref: [S, D], the same block at every key block of a head, so it
+    # stays in VMEM until the head changes; dq_acc: [S, D] fp32 scratch.
+    # One walk over the live (query block, key block) pairs: each forms
+    # scores, p, dp and ds once and feeds all three gradients.
     ki = pl.program_id(1)
     block_k, d = k_ref.shape
     s = q_ref.shape[0]
@@ -341,8 +302,24 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     v_blk = v_ref[:]
 
     n_q = s // block_q
+
+    def each_query_block(fn):
+        # Block by block, so that no whole-row [S, D] value has to exist
+        # beside the scratch.
+        def body(qi, _):
+            fn(pl.dslice(qi * block_q, block_q))
+        jax.lax.fori_loop(0, n_q, body, None)
+
+    @pl.when(ki == 0)
+    def _():
+        # Every block, not the live ones: a query block that some key
+        # block skips (causal, packed) still starts from zero.
+        def zero(rows):
+            dq_acc[rows, :] = jnp.zeros((block_q, d), jnp.float32)
+        each_query_block(zero)
+
     if causal:
-        # Query blocks strictly below the KV block's diagonal start.
+        # Query blocks touching or below the KV block's diagonal start.
         first_q = (ki * block_k) // block_q
     else:
         first_q = 0
@@ -351,7 +328,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # Packed rows: segment starts are NONDECREASING, so queries that
         # can see this KV block (seg_start <= kv block end) are a prefix
         # of rows — bound the loop instead of iterating fully-masked
-        # blocks (the dkv twin of the fwd/dq kv_first skip).
+        # blocks (the twin of the forward call's kv_first skip).
         kv_end = (ki + 1) * block_k - 1
         valid_rows = jnp.sum(
             (seg_ref[0, :] <= kv_end).astype(jnp.int32))
@@ -359,13 +336,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def body(qi, carry):
         dk, dv = carry
-        q_blk = q_ref[pl.dslice(qi * block_q, block_q), :]
-        do_blk = do_ref[pl.dslice(qi * block_q, block_q), :]
-        lse_blk = lse_ref[0, pl.dslice(qi * block_q, block_q)]
-        delta_blk = delta_ref[0, pl.dslice(qi * block_q, block_q)]
-        seg_blk = None
-        if seg_ref is not None:
-            seg_blk = seg_ref[0, pl.dslice(qi * block_q, block_q)]
+        rows = pl.dslice(qi * block_q, block_q)
+        q_blk = q_ref[rows, :]
+        do_blk = do_ref[rows, :]
+        lse_blk = lse_ref[0, rows]
+        delta_blk = delta_ref[0, rows]
         scores = jax.lax.dot_general(
             q_blk, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale   # [bq, bk]
@@ -376,16 +351,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 1)
             scores = jnp.where(q_pos >= k_pos, scores, -1e30)
         if bias_ref is not None:
-            # The KV grid owns a fixed key block: bias slice at this
-            # kernel's own block index.
+            # The grid owns a fixed key block: bias slice at this step's
+            # own block index.
             scores = scores + bias_ref[0, pl.dslice(ki * block_k,
                                                     block_k)][None, :]
-        if seg_blk is not None:
-            scores = _seg_mask(scores, seg_blk, ki, block_k)
+        if seg_ref is not None:
+            scores = _seg_mask(scores, seg_ref[0, rows], ki, block_k)
         p = jnp.exp(scores - lse_blk[:, None])
-        pc = p.astype(do_blk.dtype)
         dv = dv + jax.lax.dot_general(
-            pc, do_blk, (((0,), (0,)), ((), ())),
+            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do_blk, v_blk, (((1,), (1,)), ((), ())),
@@ -393,6 +367,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = (p * (dp - delta_blk[:, None]) * sm_scale).astype(q_blk.dtype)
         dk = dk + jax.lax.dot_general(
             ds, q_blk, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # Key blocks arrive in ascending order, so a query block's dq adds
+        # up in the order a loop over its key blocks would.
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk, dv
 
@@ -403,6 +382,36 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
+    @pl.when(ki == pl.num_programs(1) - 1)
+    def _():
+        def write(rows):
+            dq_ref[rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+        each_query_block(write)
+
+
+# What Mosaic gives a call that asks for nothing (v5e; no chip gives less).
+_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+
+
+def _bwd_vmem_limit(s, d, bq, bk, itemsize, n_sidebands):
+    """``vmem_limit_bytes`` of the backward call, from the shapes it sees:
+    twice the bytes of its blocks, its scratch and the pair's live fp32
+    ``[bq, bk]`` arrays (scores, p, dp, ds), and never under the compiler's
+    default.  Twice, because the pipeline may hold every block two times
+    (it fetches the next head's while this one is at work), and because
+    inside a train step XLA puts operands of its own choosing (lse and
+    delta whole, at 8k) into the call's scope: compiled alone the call
+    took 12 MiB at ``[S, D]`` = [8192, 128] in bf16, inside the decoder's
+    step 19.8, of the 30 asked for here; the default holds neither."""
+    d = -(-d // 128) * 128                   # VMEM pads the lanes
+    rows = 3 * s * d * itemsize              # q, do in and dq out: whole rows
+    key_blocks = 4 * bk * d * itemsize       # k, v in; dk, dv out
+    stats = (2 + n_sidebands) * 8 * s * 4    # lse, delta, bias / seg
+    dq_acc = s * d * 4
+    live = 4 * bq * bk * 4
+    return max(_DEFAULT_SCOPED_VMEM,
+               2 * (rows + key_blocks + stats + dq_acc + live))
+
 
 def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
     q, k, v, out, lse = res
@@ -412,7 +421,7 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
     if g_lse is not None:
         # lse cotangent folds into delta: dL/ds_ij = p_ij * (dp_ij -
         # delta_i + g_lse_i), so delta_eff = delta - g_lse feeds the
-        # UNCHANGED backward kernels (dv = p^T do has no lse term).
+        # UNCHANGED backward kernel (dv = p^T do has no lse term).
         delta = delta - g_lse.astype(jnp.float32)
     # Same sublane-replicated [BH, 8, S] layout as lse (TPU block tiling).
     delta = jnp.broadcast_to(delta[:, None, :], delta.shape[:1] + (8,)
@@ -421,51 +430,32 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
     bk = _pick_block(s, BLOCK_K)
     names, bias_inputs, bias_specs = _extras(bh, s, bias, seg)
 
-    dq_kernel = _with_extras(_bwd_dq_kernel, 1, names, causal=causal,
-                             sm_scale=sm_scale, block_k=bk)
-    dq_call = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, s // bq),
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0)),
-        ] + bias_specs,
-        out_specs=pl.BlockSpec((None, bq, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        interpret=_interpret(),
-    )
-    with jax.named_scope(_scopes.FLASH_DQ):
-        dq = dq_call(q, k, v, do, lse, delta, *bias_inputs)
-
-    dkv_kernel = _with_extras(_bwd_dkv_kernel, 2, names, causal=causal,
-                              sm_scale=sm_scale, block_q=bq)
-    dkv_call = pl.pallas_call(
-        dkv_kernel,
+    # The scratch ref follows the outputs, so it counts among them here.
+    kernel = _with_extras(_bwd_kernel, 4, names, causal=causal,
+                          sm_scale=sm_scale, block_q=bq)
+    row = pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0))
+    key_block = pl.BlockSpec((None, bk, d), lambda b, i: (b, i, 0))
+    stat = pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0))
+    call = pl.pallas_call(
+        kernel,
         grid=(bh, s // bk),
-        in_specs=[
-            pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, bk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, bk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0)),
-        ] + bias_specs,
-        out_specs=[
-            pl.BlockSpec((None, bk, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, bk, d), lambda b, i: (b, i, 0)),
-        ],
+        in_specs=[row, key_block, key_block, row, stat, stat] + bias_specs,
+        out_specs=[row, key_block, key_block],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
+        # dq adds up over the key blocks of a head: that axis runs in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_limit(
+                s, d, bq, bk, q.dtype.itemsize, len(names))),
         interpret=_interpret(),
     )
-    with jax.named_scope(_scopes.FLASH_DKV):
-        dk, dv = dkv_call(q, k, v, do, lse, delta, *bias_inputs)
+    with jax.named_scope(_scopes.FLASH_BWD):
+        dq, dk, dv = call(q, k, v, do, lse, delta, *bias_inputs)
     return dq, dk, dv
 
 
@@ -556,7 +546,7 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     consumers (ring attention over a sequence-sharded mesh) merge per-
     block results as ``out = sum_t exp(lse_t - logsumexp_t(lse)) out_t``
     and AD flows through both outputs (the lse cotangent folds into the
-    backward kernels' delta sideband — see ``_bwd_impl``).
+    backward kernel's delta sideband — see ``_bwd_impl``).
 
     Kernel-only surface: requires S % 128 == 0 (no dense fallback, no
     sequence padding — a blockwise caller owns the sequence layout, so

@@ -59,7 +59,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal):
     ``flash_attention_lse`` and the hops merge by log-sum-exp weights —
     so no [B, H, S_loc, S_loc] fp32 score block ever materializes, per
     hop memory is O(S_loc * D), and AD flows through both kernel outputs
-    (the lse cotangent rides the backward kernels' delta sideband).
+    (the lse cotangent rides the backward kernel's delta sideband).
 
     Hop visibility under causality is BLOCK-level: hop t carries the KV
     block of shard ``src = (my - t) mod n``; t == 0 is the causal

@@ -428,3 +428,285 @@ def test_flash_padded_tail_segment_ids():
         np.testing.assert_allclose(
             np.asarray(b), np.asarray(a), atol=5e-4, rtol=5e-4,
             err_msg=f"d{name} mismatch")
+
+
+# -- the backward pass is ONE call --------------------------------------------
+#
+# ``_bwd_impl`` made two calls until PR 29: one for dq (grid over query
+# blocks, loop over key blocks) and one for dk and dv (grid over key blocks,
+# loop over query blocks), each forming scores, p, dp and ds for itself.
+# ``_two_call_bwd_impl`` is that pair in plain ``jnp``, block for block: the
+# same products on the same operands, added up in the same order.  Against
+# the parent commit's two Pallas calls themselves the fused call's dq, dk and
+# dv were bit-identical in interpret mode (PR 29: 28 cases, fp32 and bf16,
+# every variant below).  This copy holds to rounding only: whether XLA:CPU
+# folds an accumulator's add into the dot before it differs between a
+# kernel's loop and straight-line code, a unit in the last place.
+
+def _assert_same_to_rounding(got, want, what):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    # At the peak's size: one unit in the last place of bf16 (a result
+    # rounded the other way), eight of fp32.
+    ulp = 2.0 ** -8 if what.endswith("bfloat16") else 2.0 ** -20
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=ulp * np.abs(want).max(), err_msg=what)
+
+
+def _pair(q_blk, k_blk, v_blk, do_blk, lse_blk, delta_blk, qi, ki, bq, bk,
+          causal, sm_scale, bias, seg_blk):
+    """p and ds of one (query block, key block) pair of one head, as the
+    kernels form them."""
+    scores = jax.lax.dot_general(
+        q_blk, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    q_pos = qi * bq + jnp.arange(bq)[:, None]
+    k_pos = ki * bk + jnp.arange(bk)[None, :]
+    if causal:
+        scores = jnp.where(q_pos >= k_pos, scores, -1e30)
+    if bias is not None:
+        scores = scores + bias[None, ki * bk:(ki + 1) * bk]
+    if seg_blk is not None:
+        scores = jnp.where(k_pos >= seg_blk[:, None], scores, -1e30)
+    p = jnp.exp(scores - lse_blk[:, None])
+    dp = jax.lax.dot_general(
+        do_blk, v_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = (p * (dp - delta_blk[:, None]) * sm_scale).astype(q_blk.dtype)
+    return p, ds
+
+
+def _two_call_bwd_impl(causal, sm_scale, res, do, bias=None, seg=None,
+                       g_lse=None):
+    from horovod_tpu.ops import flash_attention as fa
+
+    q, k, v, out, lse = res
+    bh, s, d = q.shape
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32)
+    bq, bk = fa._pick_block(s, fa.BLOCK_Q), fa._pick_block(s, fa.BLOCK_K)
+    sideband = bias if bias is not None else seg      # [B, 8, S], or none
+    heads = 1 if sideband is None else bh // sideband.shape[0]
+
+    def one_head(q, k, v, do, lse, delta, bias, seg):
+        def blk(x, i, b):
+            return x[i * b:(i + 1) * b]
+
+        def pair(qi, ki):
+            return _pair(blk(q, qi, bq), blk(k, ki, bk), blk(v, ki, bk),
+                         blk(do, qi, bq), blk(lse, qi, bq),
+                         blk(delta, qi, bq), qi, ki, bq, bk, causal,
+                         sm_scale, bias, None if seg is None
+                         else blk(seg, qi, bq))
+
+        def live(qi, ki):      # the causal loop bounds of both kernels
+            return not causal or ki * bk < (qi + 1) * bq
+
+        dq = []
+        for qi in range(s // bq):                     # the dq call
+            acc = jnp.zeros((bq, d), jnp.float32)
+            for ki in range(s // bk):
+                if live(qi, ki):
+                    _, ds = pair(qi, ki)
+                    acc = acc + jax.lax.dot_general(
+                        ds, blk(k, ki, bk), (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+            dq.append(acc.astype(q.dtype))
+        dk, dv = [], []
+        for ki in range(s // bk):                     # the dkv call
+            dk_acc = jnp.zeros((bk, d), jnp.float32)
+            dv_acc = jnp.zeros((bk, d), jnp.float32)
+            for qi in range(s // bq):
+                if live(qi, ki):
+                    p, ds = pair(qi, ki)
+                    dv_acc = dv_acc + jax.lax.dot_general(
+                        p.astype(do.dtype), blk(do, qi, bq),
+                        (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    dk_acc = dk_acc + jax.lax.dot_general(
+                        ds, blk(q, qi, bq), (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+            dk.append(dk_acc.astype(k.dtype))
+            dv.append(dv_acc.astype(v.dtype))
+        return jnp.concatenate(dq), jnp.concatenate(dk), jnp.concatenate(dv)
+
+    per_head = lambda x: None if x is None else jnp.repeat(
+        x[:, 0, :], heads, axis=0)
+    grads = [one_head(q[b], k[b], v[b], do[b], lse[b, 0], delta[b],
+                      None if bias is None else per_head(bias)[b],
+                      None if seg is None else per_head(seg)[b])
+             for b in range(bh)]
+    return tuple(jnp.stack(g) for g in zip(*grads))
+
+
+def _dense(q, k, v, *, causal=True, key_padding_mask=None, segment_ids=None):
+    """Attention with the whole [S, S] score matrix, fp32."""
+    B, S, H, D = q.shape
+    if k.shape[2] != H:
+        k = jnp.repeat(k, H // k.shape[2], axis=2)
+        v = jnp.repeat(v, H // v.shape[2], axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+    mask = jnp.ones((B, 1, S, S), bool)
+    if causal:
+        mask = mask & jnp.tril(jnp.ones((S, S), bool))[None, None]
+    if key_padding_mask is not None:
+        mask = mask & key_padding_mask[:, None, None, :]
+    if segment_ids is not None:
+        mask = mask & (segment_ids[:, None, :, None]
+                       == segment_ids[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _segments(S, *bounds):
+    return jnp.sum(jnp.arange(S)[:, None] >= jnp.asarray(bounds)[None, :],
+                   axis=-1)
+
+
+S_BWD = 1024     # 512-row blocks: a 2 x 2 grid of block pairs
+BWD_CASES = {
+    "causal": dict(),
+    "bidirectional": dict(kwargs=dict(causal=False)),
+    "key_bias": dict(kwargs=dict(
+        causal=False, key_padding_mask=jnp.arange(S_BWD)[None, :]
+        < jnp.array([S_BWD - 37, S_BWD // 2 + 5])[:, None])),
+    # A boundary inside a block (300), one on a block edge (512), and in
+    # the second row a segment of one token just behind the edge.
+    "packed": dict(kwargs=dict(segment_ids=jnp.stack([
+        _segments(S_BWD, 300, 512, 900), _segments(S_BWD, 512, 513)]))),
+    "gqa": dict(shape=dict(H=4, Hkv=2)),
+    "padded_tail": dict(shape=dict(S=S_BWD - 100)),
+    "head_dim_64": dict(shape=dict(D=64)),
+    "block_q_over_block_k": dict(blocks=(512, 256)),
+    "block_q_under_block_k": dict(blocks=(128, 512)),
+}
+
+
+def _bwd_case(name, monkeypatch, dtype):
+    from horovod_tpu.ops import flash_attention as fa
+
+    case = BWD_CASES[name]
+    if "blocks" in case:
+        monkeypatch.setattr(fa, "BLOCK_Q", case["blocks"][0])
+        monkeypatch.setattr(fa, "BLOCK_K", case["blocks"][1])
+        assert (fa._pick_block(S_BWD, fa.BLOCK_Q),
+                fa._pick_block(S_BWD, fa.BLOCK_K)) == case["blocks"]
+    shape = dict(B=2, S=S_BWD, H=2, Hkv=2, D=128)
+    shape.update(case.get("shape", {}))
+    q, k, v = _qkv(**shape, dtype=dtype)
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+    kwargs = case.get("kwargs", {})
+    if "key_padding_mask" in kwargs:    # a masked-out row's output is undefined
+        w = w * kwargs["key_padding_mask"][:, :, None, None]
+    return q, k, v, w, kwargs
+
+
+def _grads(attn, q, k, v, w, **kwargs):
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v, **kwargs).astype(jnp.float32) * w)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+
+@pytest.mark.parametrize("name", list(BWD_CASES))
+def test_fused_backward_matches_dense(name, monkeypatch):
+    q, k, v, w, kwargs = _bwd_case(name, monkeypatch, jnp.float32)
+    got = _grads(flash_attention, q, k, v, w, **kwargs)
+    want = _grads(_dense, q, k, v, w, **kwargs)
+    for g, r, which in zip(got, want, "qkv"):
+        np.testing.assert_allclose(g, r, atol=5e-4, rtol=5e-4,
+                                   err_msg=f"d{which} of {name}")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", list(BWD_CASES))
+def test_fused_backward_is_the_two_calls_result(name, dtype, monkeypatch):
+    from horovod_tpu.ops import flash_attention as fa
+
+    q, k, v, w, kwargs = _bwd_case(name, monkeypatch, dtype)
+    got = _grads(flash_attention, q, k, v, w, **kwargs)
+    monkeypatch.setattr(fa, "_bwd_impl", _two_call_bwd_impl)
+    want = _grads(flash_attention, q, k, v, w, **kwargs)
+    for g, r, which in zip(got, want, "qkv"):
+        _assert_same_to_rounding(g, r, f"d{which} of {name}, {g.dtype.name}")
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_fused_backward_takes_the_lse_cotangent(causal, monkeypatch):
+    """``flash_attention_lse`` (ring attention's hop): a cotangent on lse
+    folds into delta.  Against dense, and against the two calls."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(B=1, S=S_BWD, H=2, Hkv=2)
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+
+    def grads(attn_lse):
+        def loss(q, k, v):
+            out, lse = attn_lse(q, k, v)
+            return jnp.sum(out * w) + jnp.sum(jnp.sin(lse))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    def dense_lse(q, k, v):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((S_BWD, S_BWD), bool)),
+                               scores, -1e30)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v),
+                jax.nn.logsumexp(scores, -1))
+
+    flash_lse = lambda q, k, v: fa.flash_attention_lse(q, k, v, causal=causal)
+    got = grads(flash_lse)
+    for g, r, which in zip(got, grads(dense_lse), "qkv"):
+        np.testing.assert_allclose(g, r, atol=5e-4, rtol=5e-4,
+                                   err_msg=f"d{which}")
+    monkeypatch.setattr(fa, "_bwd_impl", _two_call_bwd_impl)
+    for g, r, which in zip(got, grads(flash_lse), "qkv"):
+        _assert_same_to_rounding(g, r, f"d{which}, float32")
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_pallas_calls(sub))
+    return found
+
+
+@pytest.mark.parametrize("name", ["causal", "key_bias", "packed"])
+def test_forward_and_backward_are_one_call_each(name, monkeypatch):
+    q, k, v, w, kwargs = _bwd_case(name, monkeypatch, jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, **kwargs) * w)
+
+    closed = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    calls = _pallas_calls(closed.jaxpr)
+    assert sorted(len(eqn.outvars) for eqn in calls) == [2, 3], calls
+    backward, = (eqn for eqn in calls if len(eqn.outvars) == 3)   # dq, dk, dv
+    assert [tuple(var.aval.shape) for var in backward.outvars] == [
+        (q.shape[0] * q.shape[2], q.shape[1], q.shape[3])] * 3
+
+
+def test_backward_vmem_limit_follows_the_shapes():
+    """Twice the call's blocks, scratch and live arrays, never under the
+    compiler's default: 30 MiB at the benchmark's 8k shape (bf16, D = 128),
+    where the decoder's step was seen to use 19.8; the default at 2k."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    mib = 2 ** 20
+    assert fa._bwd_vmem_limit(8192, 128, 512, 512, 2, 0) == 30 * mib
+    assert fa._bwd_vmem_limit(2048, 128, 512, 512, 2, 0) == 16 * mib
+    assert fa._bwd_vmem_limit(128, 128, 128, 128, 2, 0) == 16 * mib
+    # A sideband is one more [8, S] fp32 block.
+    assert (fa._bwd_vmem_limit(8192, 128, 512, 512, 2, 1)
+            - fa._bwd_vmem_limit(8192, 128, 512, 512, 2, 0)) == mib // 2
+    # Lanes are padded to 128; fp32 rows are twice bf16's.
+    assert fa._bwd_vmem_limit(8192, 64, 512, 512, 2, 0) == 30 * mib
+    assert fa._bwd_vmem_limit(8192, 128, 512, 512, 4, 0) == 43 * mib
+    at_16k = fa._bwd_vmem_limit(16384, 128, 512, 512, 2, 0)
+    assert 2 * 16384 * 128 * (3 * 2 + 4) < at_16k < 64 * mib
+    assert at_16k < fa._bwd_vmem_limit(32768, 128, 512, 512, 2, 0) < 128 * mib

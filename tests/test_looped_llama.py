@@ -225,12 +225,12 @@ def test_recomputation_changes_no_value(remat, attention):
 
 
 @pytest.mark.parametrize("remat, calls_a_layer", [
-    ("none", 3), ("layer", 4), ("layer_keep_attention", 3)])
+    ("none", 2), ("layer", 3), ("layer_keep_attention", 2)])
 def test_keeping_the_flash_output_spares_its_forward_call(remat,
                                                           calls_a_layer):
-    """Forward, dq and dkv a layer; a recomputed layer calls the forward
-    kernel again unless the policy keeps its output.  The passes are a
-    scan, so the program holds one pass's calls."""
+    """A forward and a backward call a layer; a recomputed layer calls the
+    forward kernel again unless the policy keeps its output.  The passes are
+    a scan, so the program holds one pass's calls."""
     model = LlamaModel(dataclasses.replace(LOOPED, remat=remat),
                        attention_fn=flash_attention_fn)
     params = _init(LOOPED)

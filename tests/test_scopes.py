@@ -20,7 +20,7 @@ from horovod_tpu.ops.flash_attention import flash_attention
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TABLE = [scopes.LOSS, scopes.FUSION_PACK, scopes.FUSION_UNPACK,
          scopes.ALLREDUCE, scopes.AUX_ALLREDUCE, scopes.OPTIMIZER,
-         scopes.APPLY, scopes.FLASH_FWD, scopes.FLASH_DQ, scopes.FLASH_DKV]
+         scopes.APPLY, scopes.FLASH_FWD, scopes.FLASH_BWD]
 
 
 def _mesh():
@@ -136,12 +136,14 @@ def test_flash_calls_have_their_scopes():
     names = _op_names(step, params, opt.init(params), _batch())
     forward = _under(names, scopes.FLASH_FWD)
     assert forward and all("transpose(" not in n for n in forward)
-    for scope in (scopes.FLASH_DQ, scopes.FLASH_DKV):
-        backward = _under(names, scope)
-        assert backward and all("/transpose(" in n for n in backward)
-        assert all(n.index(scopes.LOSS) < n.index("transpose(")
-                   for n in backward)
+    backward = _under(names, scopes.FLASH_BWD)
+    assert backward and all("/transpose(" in n for n in backward)
+    assert all(n.index(scopes.LOSS) < n.index("transpose(")
+               for n in backward)
     assert all(_under({n}, scopes.LOSS) for n in forward)
+    # One backward scope: no operation sits under another ``hvd.flash.*``.
+    prefix = scopes.FLASH_FWD.rsplit(".", 1)[0] + "."
+    assert {n for n in names if prefix in n} == set(forward) | set(backward)
 
 
 @pytest.mark.parametrize("has_aux", [False, True])
