@@ -36,7 +36,7 @@ RUN pip install --no-cache-dir \
         --index-url https://download.pytorch.org/whl/cpu
 
 # Source + editable install + native engine build (mirrors ci.sh).
-COPY pyproject.toml setup.py README.md ci.sh bench.py bench_engine.py \
+COPY pyproject.toml setup.py README.md ci.sh bench_engine.py \
      __graft_entry__.py ./
 COPY horovod_tpu ./horovod_tpu
 COPY tests ./tests

@@ -3,8 +3,8 @@ under the torch and TF frontends at 2 and 4 ranks.
 
 Role parity with the reference's benchmark methodology
 (``examples/pytorch_synthetic_benchmark.py:96-110`` — timed fwd+bwd+step
-loops, img/sec), applied to the part of THIS stack the main ``bench.py``
-does not exercise: the native TCP engine serving the host frontends
+loops, img/sec), applied to the part of THIS stack the chip's benchmark
+(``benchmark/``) does not exercise: the native TCP engine serving the host frontends
 (torch hooks, TF grouped allreduce).  The numbers are CPU-host numbers by
 design — they track frontend + negotiation + ring-collective overhead,
 so hot-path regressions (e.g. a fusion/batching break) become visible as
@@ -58,8 +58,7 @@ measure the default plane (shm flat ring + size-based algorithm
 selection), and ``algo_threshold_sweep`` interleaves the star and ring
 paths per payload size so the crossover is visible.
 
-``bench.py`` merges these keys into the bench artifact under an
-``engine_`` prefix; standalone use: ``python bench_engine.py``.
+Use: ``python bench_engine.py`` prints the keys as one JSON line.
 
 ``python bench_engine.py --gate`` runs the CI data-plane gate instead:
 one 4-rank worker set alternates channels=4 / channels=1 in-process

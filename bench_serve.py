@@ -6,8 +6,7 @@ so the generator measures the system under load rather than pacing
 itself to it.  Each request records submit → first-token (TTFT) and
 submit → done latency from the client's side of the socket.
 
-Prints ONE JSON line (``bench.py`` merges it into the bench artifact
-under a ``serve_`` prefix, next to the ``engine_`` keys)::
+Prints ONE JSON line::
 
     {"metric": "serve", "tokens_per_sec": .., "req_latency_ms_p50": ..,
      "req_latency_ms_p99": .., "ttft_ms_p50": .., "ttft_ms_p99": ..,

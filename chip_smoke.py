@@ -4,7 +4,7 @@
 
 One process.  ``hvd.init()`` -> ``hvd.data_parallel_mesh()`` ->
 ``hvd.DistributedOptimizer`` -> ``hvd.make_train_step`` -> a few steps of
-the 400M decoder of ``bench.py`` (hidden 1024, 16 layers, 8 heads x 128,
+a 400M decoder (hidden 1024, 16 layers, 8 heads x 128,
 FFN 4096, vocab 32000; 8 sequences of 2048 tokens a chip; flash attention,
 bf16 params + fp32 masters + AdamW) over every chip JAX reports, after a
 short agreement check of the two Pallas kernels against their XLA
@@ -43,7 +43,7 @@ from horovod_tpu.ops.flash_attention import flash_attention, flash_attention_fn
 from horovod_tpu.ops.losses import softmax_cross_entropy
 from horovod_tpu.ops.mixed_precision import cast_compute, master_weights
 
-#: The decoder of bench.py, whole: no width or depth cut.
+#: A 400M decoder, whole: no width or depth cut.
 CONFIG = dict(vocab_size=32000, hidden_size=1024, num_layers=16, num_heads=8,
               num_kv_heads=8, intermediate_size=4096, max_seq_len=2048)
 BATCH_PER_CHIP, SEQ, STEPS = 8, 2048, 8

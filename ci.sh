@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # CI entry point: build the native engine, run the full test suite (incl.
 # example smokes) on an 8-device virtual CPU mesh, then the multichip
-# dry run.  Chip runs (chip_smoke.py, bench.py) are not CI: a CPU run
+# dry run.  Chip runs (chip_smoke.py, benchmark.run) are not CI: a CPU run
 # gives no rate.
 #
 # Reference parity: .travis.yml:101-137 builds the wheel and runs

@@ -7,8 +7,8 @@ flag (:34-35, 97 — fp16 there), warmup + staircase LR schedule
 (:147-153), 1/N data sharding (:161-173), final allreduce of the eval
 score (:176), rank-0-only checkpoints (:156-158).
 
-Synthetic ImageNet (see examples/common.py); bench.py measures the same
-model's throughput against BASELINE.md.
+Synthetic ImageNet (see examples/common.py); the benchmark's cell
+``resnet50-v1.5.train-b256`` measures the same model's throughput.
 """
 
 import os

@@ -4,7 +4,7 @@ Role parity with reference ``examples/pytorch_synthetic_benchmark.py``:
 timed fwd+bwd+step loop over synthetic batches, img/sec per device and
 total with ±1.96σ (ref :96-110); broadcast at start (:66-67); fp16
 compression flag (:33, here bf16 too).  The torch path runs on host CPU
-(the TPU benchmark is bench.py); its numbers measure the frontend + ring
+(the TPU benchmark is ``python3 -m benchmark.run``); its numbers measure the frontend + ring
 collective overhead, not TPU compute.
 """
 

@@ -17,8 +17,8 @@ runs under ``transpose(jvp(...))``.  So one scope around
 scope                 what falls under it
 ====================  ====================================================
 ``hvd.loss``          ``jax.value_and_grad(loss_fn)`` in ``make_train_step``
-``hvd.fusion.pack``   ravel + concatenate into a fused gradient buffer
-``hvd.fusion.unpack`` slice + reshape out of it
+``hvd.fusion.pack``   nothing: no code of the program enters these two
+``hvd.fusion.unpack`` since PR 30 (see below)
 ``hvd.allreduce.<a>`` every traced all-reduce over mesh axes ``<a>``
                       (``hvd.allreduce.data``; several axes joined by ``+``)
 ``hvd.aux_allreduce`` the ``has_aux`` state's per-leaf all-reduces
@@ -37,6 +37,12 @@ scope                 what falls under it
                       cross-entropy and the exit distribution.  A reader
                       asks for this scope first
 ====================  ====================================================
+
+``FUSION_PACK`` and ``FUSION_UNPACK`` named the copies of a trace-time
+gradient packer that is gone.  The constants stay because the benchmark's
+reader (``benchmark/scopes.py``) looks them up; they go when a ``benchmark``
+PR retires ``fusion_pack_ms`` (ROADMAP.md D15), which still counts what an
+averaging all-reduce adds that is no collective, under ``hvd.allreduce.<a>``.
 
 The benchmark (``benchmark/scopes.py``) reads ``hvd.flash.fwd`` by name and
 every other ``hvd.flash.*`` scope as the backward pass, however many calls

@@ -5,7 +5,7 @@ rank queries, ``allreduce``, ``broadcast_global_variables``,
 ``DistributedOptimizer`` — re-thought for JAX's functional model:
 
 * ``DistributedOptimizer`` wraps an *optax* ``GradientTransformation``; the
-  wrapped ``update`` fuses and psums gradients over the mesh's data axes
+  wrapped ``update`` psums each gradient over the mesh's data axes
   before the inner optimizer sees them.  This is the exact analogue of the
   reference overriding ``compute_gradients`` to allreduce each grad
   (tensorflow/__init__.py:183-209), but it happens inside ``jit`` where XLA
@@ -63,7 +63,6 @@ from horovod_tpu.ops.collective_ops import (
     Sum,
 )
 from horovod_tpu.ops.compression import Compression
-from horovod_tpu.ops.fusion import fuse_apply
 from horovod_tpu.parallel import mesh as _mesh
 from horovod_tpu.parallel.mesh import (
     build_mesh,
@@ -247,15 +246,17 @@ def alltoall(tensor, *, axis_name="seq", split_axis=0, concat_axis=0,
 # ---------------------------------------------------------------------------
 
 def allreduce_gradients(grads, *, axis_name=None, op=Average,
-                        compression=Compression.none,
-                        fusion_threshold_bytes=None, wire_policy=None):
-    """Fused allreduce of a gradient pytree over the data axes.
+                        compression=Compression.none, wire_policy=None):
+    """Allreduce of a gradient pytree over the data axes.
 
     ``axis_name`` may be a name, tuple of names, or None (= every data-like
     axis of the default mesh: ``data`` and ``fsdp``).
 
-    Traced gradients (inside jit/shard_map) reduce as fused XLA
-    collectives.  CONCRETE gradients — the host-driven DCN path — go
+    Traced gradients (inside jit/shard_map) are all-reduced leaf by leaf,
+    each as it is.  Nothing is packed: XLA's all-reduce combiner batches
+    the leaves into a few variadic all-reduces in the layout their
+    producers wrote, with no copy (PERF.md §6, PR 24, PR 25 and PR 30).
+    CONCRETE gradients — the host-driven DCN path — go
     through the eager engine per leaf, with stable tree-path names: that
     is what lets ``compression=Compression.topk(...)`` keep one
     error-feedback residual per gradient leaf, and the wire-level
@@ -327,11 +328,10 @@ def allreduce_gradients(grads, *, axis_name=None, op=Average,
     if axis_name is None:
         axis_name = _mesh.data_axes() or ("data",)
 
-    def _reduce_buffer(buf):
-        return _cops.allreduce(buf, axis_name=axis_name, op=op,
-                               compression=compression)
-
-    return fuse_apply(grads, _reduce_buffer, fusion_threshold_bytes)
+    return jax.tree.map(
+        lambda leaf: _cops.allreduce(leaf, axis_name=axis_name, op=op,
+                                     compression=compression),
+        grads)
 
 
 class DistributedOptimizer:
@@ -395,10 +395,9 @@ class DistributedOptimizer:
     """
 
     def __init__(self, optimizer, *, axis_name=None, op=Average,
-                 compression=Compression.none, fusion_threshold_bytes=None,
-                 reduce_gradients=True, name=None, local_sgd_steps=None,
-                 sharded=None, fsdp=None, fsdp_units=None,
-                 fsdp_prefetch=None):
+                 compression=Compression.none, reduce_gradients=True,
+                 name=None, local_sgd_steps=None, sharded=None, fsdp=None,
+                 fsdp_units=None, fsdp_prefetch=None):
         from horovod_tpu.elastic.state import (LocalSGD,
                                                default_local_sgd_steps)
         from horovod_tpu.runtime.fsdp import fsdp_default
@@ -408,7 +407,6 @@ class DistributedOptimizer:
         self._axis_name = axis_name
         self._op = op
         self._compression = compression
-        self._fusion_threshold = fusion_threshold_bytes
         self._reduce = reduce_gradients
         self.name = name or "DistributedOptimizer"
         self._local_sgd_steps = (default_local_sgd_steps()
@@ -461,7 +459,6 @@ class DistributedOptimizer:
         copy = DistributedOptimizer(
             self._inner, axis_name=axis_name, op=self._op,
             compression=self._compression,
-            fusion_threshold_bytes=self._fusion_threshold,
             reduce_gradients=self._reduce, name=self.name,
             local_sgd_steps=self._local_sgd_steps,
             sharded=self._sharded, fsdp=self._fsdp,
@@ -500,7 +497,6 @@ class DistributedOptimizer:
                 axis_name=self._axis_name,
                 op=self._op,
                 compression=self._compression,
-                fusion_threshold_bytes=self._fusion_threshold,
             )
         with jax.named_scope(_scopes.OPTIMIZER):
             return self._inner.update(grads, state, params, **extra)
@@ -776,10 +772,10 @@ def broadcast_parameters(params, root_rank=0, *, axis_name=None):
         if axis_name is None:
             axis_name = _mesh.data_axes() or ("data",)
 
-        def _bcast_buffer(buf):
-            return _cops.broadcast(buf, root_rank, axis_name=axis_name)
-
-        return fuse_apply(params, _bcast_buffer)
+        return jax.tree.map(
+            lambda leaf: _cops.broadcast(leaf, root_rank,
+                                         axis_name=axis_name),
+            params)
     from horovod_tpu.runtime import eager
 
     return jax.tree.map(
@@ -790,7 +786,7 @@ def broadcast_parameters(params, root_rank=0, *, axis_name=None):
 def broadcast_optimizer_state(opt_state, root_rank=0, *, axis_name=None):
     """Broadcast optimizer state from root (reference torch/__init__.py:
     185-301).  Optax states are pytrees of arrays, so no scalar
-    tensor-ization dance is needed — one fused broadcast covers it."""
+    tensor-ization dance is needed — one broadcast a leaf covers it."""
     return broadcast_parameters(opt_state, root_rank, axis_name=axis_name)
 
 
@@ -801,7 +797,7 @@ def broadcast_optimizer_state(opt_state, root_rank=0, *, axis_name=None):
 def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
                     *, donate=True, has_aux=False):
     """Build a jitted SPMD train step: shard batch over data axes, compute
-    grads, fused-allreduce them, apply the optimizer.
+    grads, all-reduce them leaf by leaf, apply the optimizer.
 
     ``loss_fn(params, batch) -> scalar loss``, or with ``has_aux=True``
     ``loss_fn(params, aux_state, batch) -> (loss, new_aux_state)`` where
@@ -821,7 +817,7 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
     a profile), and its device operations carry the scopes of
     ``horovod_tpu/common/scopes.py`` in their names: ``hvd.loss`` (forward
     under ``jvp``, backward under ``transpose``), ``hvd.optimizer``,
-    ``hvd.apply``, and those of the fused all-reduce (docs/timeline.md).
+    ``hvd.apply``, and ``hvd.allreduce.<axes>`` (docs/timeline.md).
     """
     mesh = mesh or default_mesh()
     axes = _mesh.data_axes(mesh) or mesh.axis_names

@@ -93,9 +93,6 @@ class LlamaConfig:
     # (ops/losses.py) upcasts per-tile inside its reductions, so lse and
     # loss stay f32-accurate.  Set to jnp.float32 to save f32 logits.
     logits_dtype: Any = jnp.bfloat16
-    # Fused Pallas RMSNorm (see RMSNorm.fused): enable on shard_map /
-    # single-device paths; leave off under GSPMD.
-    fused_rmsnorm: bool = False
     total_ut_steps: int = 1
     remat: str = "none"
 
@@ -129,22 +126,10 @@ class LlamaConfig:
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # Fused Pallas kernel (ops/rms_norm.py).  Opt-in twice over: (a)
-    # pallas_call cannot lower under non-Manual mesh axes, so it must
-    # stay off for GSPMD (plain jit + sharded params) paths — shard_map
-    # paths (make_train_step, ring attention, pipeline) are safe; (b) on
-    # the 400M bench config it measured only ~0.5% end-to-end (XLA's norm
-    # fusions were already fused with neighboring converts/residuals, and
-    # the kernel boundary forfeits that), so the default stays off.
-    fused: bool = False
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        if self.fused:
-            from horovod_tpu.ops.rms_norm import rms_norm
-
-            return rms_norm(x, scale, eps=self.eps, out_dtype=self.dtype)
         x32 = x.astype(jnp.float32)
         x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
                                            keepdims=True) + self.eps)
@@ -274,12 +259,10 @@ class LlamaLayer(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin):
         cfg = self.config
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.fused_rmsnorm,
-                    name="norm_attn")(x)
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_attn")(x)
         x = x + LlamaAttention(cfg, attention_fn=self.attention_fn,
                                name="attn")(y, cos, sin)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.fused_rmsnorm,
-                    name="norm_mlp")(x)
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_mlp")(x)
         if cfg.num_experts > 1:
             x = x + MoEBlock(cfg, name="moe")(y)
         else:
@@ -335,8 +318,8 @@ class LlamaModel(nn.Module):
             return x
 
         def norm_f(mdl, x):
-            return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.fused_rmsnorm,
-                           name="norm_f", parent=mdl)(x)
+            return RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f",
+                           parent=mdl)(x)
 
         if cfg.total_ut_steps == 1:
             return self.head(norm_f(self, one_pass(self, x)))

@@ -4,7 +4,7 @@ Capability parity: the reference's headline workload is ResNet-50 ImageNet
 training (``examples/keras_imagenet_resnet50.py``,
 ``examples/pytorch_imagenet_resnet50.py``) and its published benchmark is
 ResNet-101 under tf_cnn_benchmarks (``docs/benchmarks.md:22-37``).  This is
-the model the bench harness (`bench.py`) runs.
+the model of the benchmark's cell ``resnet50-v1.5.train-b256``.
 
 TPU-first design choices:
 * NHWC activations — XLA TPU's native convolution layout.

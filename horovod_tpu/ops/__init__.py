@@ -1,4 +1,4 @@
-"""Collective ops, compression, and fusion."""
+"""Collective ops and compression."""
 
 from horovod_tpu.ops.collective_ops import (
     Average,
@@ -23,13 +23,6 @@ from horovod_tpu.ops.ragged import (
     pad_rows,
     ragged_allgather,
 )
-from horovod_tpu.ops.fusion import (
-    DEFAULT_FUSION_THRESHOLD,
-    FusionPlan,
-    fuse_apply,
-    fusion_threshold_bytes,
-    plan_fusion,
-)
 
 __all__ = [
     "Average",
@@ -48,11 +41,6 @@ __all__ = [
     "reducescatter",
     "Compression",
     "Compressor",
-    "DEFAULT_FUSION_THRESHOLD",
-    "FusionPlan",
-    "fuse_apply",
-    "fusion_threshold_bytes",
-    "plan_fusion",
     "bucket_rows",
     "compact",
     "pad_rows",

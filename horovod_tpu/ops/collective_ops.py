@@ -123,18 +123,11 @@ def grouped_allreduce(
     op: ReduceOp = Average,
     compression=Compression.none,
 ) -> list[jax.Array]:
-    """Allreduce a list of tensors as one fused collective per dtype.
-
-    Reference parity: response fusion (operations.cc:1815-1842).  Uses the
-    trace-time fusion planner, so many small gradients become one large ICI
-    ring transfer.
-    """
-    from horovod_tpu.ops.fusion import fuse_apply
-
-    def _fn(buf):
-        return allreduce(buf, axis_name=axis_name, op=op, compression=compression)
-
-    return fuse_apply(list(tensors), _fn)
+    """Allreduce a list of tensors, each as it is; XLA's all-reduce
+    combiner batches them (reference parity: response fusion,
+    operations.cc:1815-1842)."""
+    return [allreduce(t, axis_name=axis_name, op=op, compression=compression)
+            for t in tensors]
 
 
 def allgather(

@@ -2,8 +2,7 @@
 
 Why: with fp32-stored params and bf16 compute, XLA inserts a
 convert-and-retile of every weight on every step — profiled at ~9% of
-the 400M Llama step (docs/perf-notes.md methodology;
-`convert_bitcast_fusion` ops).  Storing params in bf16 removes that
+the 400M Llama step (`convert_bitcast_fusion` ops in a device trace).  Storing params in bf16 removes that
 traffic (measured 283 -> 267 ms/step, +5.7% tokens/s), but naive bf16
 optimizer state loses update precision.  ``master_weights`` keeps the
 standard solution: the optimizer state carries an fp32 master copy of

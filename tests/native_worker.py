@@ -1174,35 +1174,40 @@ def scenario_wire_tune(rank, size, eng):
     # The wire dtype as the 6th live-tunable knob: a TUNE frame flips the
     # default between cycles on EVERY rank; enqueues after it negotiate
     # (and execute) under the new wire; stats()["config"] tracks it.
-    assert eng.stats()["config"]["wire_dtype"] == "fp32"
+    import time
+
+    # Every baseline is read HERE, before the first collective: rank 0
+    # cannot set the knob, and no rank can enqueue under the new wire (which
+    # evicts the slot on every rank, through the coordinator's frame), until
+    # that collective has had this rank's part.  Read after it, a baseline
+    # on a rank a few ms late already holds what it is to be compared with.
+    base = eng.stats()
+    assert base["config"]["wire_dtype"] == "fp32"
     x = np.ones(1 << 16, dtype=np.float32)
     assert np.allclose(eng.allreduce(x.copy(), name="wt.t"), float(size))
-    tt = eng.stats()["tune_trials"]
+
+    def wait_for_wire(name):
+        # The value, not a count of TUNE frames above a late baseline.
+        deadline = time.time() + 20
+        while eng.stats()["config"]["wire_dtype"] != name:
+            assert time.time() < deadline, "TUNE frame never applied"
+            time.sleep(0.002)
+
     if rank == 0:
         assert eng.autotune_set(wire_dtype=3)  # int8
-    import time
-    deadline = time.time() + 20
-    while eng.stats()["tune_trials"] <= tt:
-        assert time.time() < deadline, "TUNE frame never applied"
-        time.sleep(0.002)
-    assert eng.stats()["config"]["wire_dtype"] == "int8"
-    s0 = eng.stats()
+    wait_for_wire("int8")
     # Same name, new signature (wire changed): the slot evicts and the
     # collective renegotiates + executes under int8.
     out = eng.allreduce(x.copy(), name="wt.t")
     assert np.allclose(out, float(size), atol=1e-2)
     s1 = eng.stats()
-    assert s1["wire_int8_count"] - s0["wire_int8_count"] == 1, s1
-    assert s1["cache_evictions"] > s0["cache_evictions"], s1
+    assert s1["wire_int8_count"] - base["wire_int8_count"] == 1, s1
+    assert s1["cache_evictions"] > base["cache_evictions"], s1
     # ... and back to fp32: bitwise-identical to an untouched run.
-    tt = s1["tune_trials"]
     if rank == 0:
         assert eng.autotune_set(wire_dtype=0)
-    deadline = time.time() + 20
-    while eng.stats()["tune_trials"] <= tt:
-        assert time.time() < deadline, "TUNE frame never applied"
-        time.sleep(0.002)
-    assert eng.stats()["config"]["wire_dtype"] == "fp32"
+    wait_for_wire("fp32")
+    assert eng.stats()["tune_trials"] >= base["tune_trials"] + 2
     out = eng.allreduce(x.copy(), name="wt.t")
     assert np.array_equal(out, np.full_like(x, float(size))), out[:4]
 

@@ -29,11 +29,24 @@ WORKER = os.path.join(REPO, "tests", "link_heal_worker.py")
 # Multichannel TCP data plane (the healing surface) + a tight failure-
 # detection bound so an accidental regression to the abort path fails the
 # test quickly instead of burning the default 120 s socket patience.
+#
+# Small socket buffers, so that a segment cannot sit in the kernel whole.
+# The sender re-dials a broken edge only while it is still inside its send
+# cursor (engine.cc's liveness probe stops at ``ss == nsteps``).  With the
+# kernel's default buffers (4 MB) the 128-170 KB segments of these payloads
+# fit whole: in a world of two, rank 0 could receive rank 1's whole first
+# segment, reduce it and queue its LAST segment before rank 1's injected
+# ``conn-reset:prev`` discarded those queued bytes; rank 0 then had nothing
+# left to send, never saw the break, and rank 1 escalated after the heal
+# budget ("gave up after 0 reconnect attempts", ~1 run in 3 on a loaded
+# machine).  With 16 KB buffers the shooter cannot have pushed a whole
+# segment when it fires, so its peer is still owed bytes and heals.
 HEAL_ENV = {
     "HOROVOD_SHM_DISABLE": "1",
     "HOROVOD_NUM_CHANNELS": "3",
     "HOROVOD_LINK_RETRIES": "4",
     "HOROVOD_LINK_HEAL_TIMEOUT_MS": "8000",
+    "HOROVOD_SOCKET_BUF_BYTES": "16384",
 }
 
 

@@ -2,6 +2,7 @@
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from horovod_tpu.models import (
@@ -97,3 +98,62 @@ def test_word2vec_nce_loss():
     loss = nce_loss(model, params, center, labels, negatives)
     assert loss.shape == ()
     assert jnp.isfinite(loss)
+
+
+# -- RMSNorm, the norm every decoder cell runs, against the formula ----------
+
+def _rms_norm_case(dtype, seed=0, eps=1e-6):
+    from horovod_tpu.models.llama import RMSNorm
+
+    rng = np.random.RandomState(seed)
+    x = jnp.asarray(rng.randn(3, 5, 256) * 2.0, dtype)
+    scale = jnp.asarray(1.0 + 0.5 * rng.randn(256), jnp.float32)
+    cotangent = jnp.asarray(rng.randn(3, 5, 256), jnp.float32)
+    module = RMSNorm(eps, dtype)
+    variables = module.init(jax.random.key(0), x)
+    assert jax.tree.map(jnp.shape, variables) == {"params": {"scale": (256,)}}
+    assert bool(jnp.all(variables["params"]["scale"] == 1.0))
+
+    def apply(x, scale):
+        return module.apply({"params": {"scale": scale}}, x)
+
+    # The formula in float64 on the values the module is given.
+    x64, s64 = np.asarray(x, np.float64), np.asarray(scale, np.float64)
+    rstd = 1.0 / np.sqrt((x64 * x64).mean(-1, keepdims=True) + eps)
+    return apply, (x, scale, cotangent), (x64, s64, rstd)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rms_norm_forward_is_the_formula(dtype):
+    apply, (x, scale, _), (x64, s64, rstd) = _rms_norm_case(dtype)
+    got = apply(x, scale)
+    assert got.dtype == dtype and got.shape == x.shape
+    # fp32 arithmetic inside whatever comes in; bf16 rounds once, at the end.
+    tolerance = 2e-6 if dtype == jnp.float32 else 2.0 ** -8
+    np.testing.assert_allclose(np.asarray(got, np.float64), x64 * rstd * s64,
+                               rtol=tolerance, atol=tolerance)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rms_norm_gradient_is_the_formulas(dtype):
+    """d/dx and d/dscale of sum(y * c), derived by hand: with g = c * scale
+    and r = rstd, dx = r * g - x * r^3 * mean(g * x), dscale = sum(c * x * r)
+    over the rows."""
+    apply, (x, scale, c), (x64, s64, rstd) = _rms_norm_case(dtype, seed=1)
+
+    def loss(x, scale):
+        return jnp.sum(apply(x, scale).astype(jnp.float32) * c)
+
+    dx, dscale = jax.grad(loss, argnums=(0, 1))(x, scale)
+    assert dx.dtype == dtype and dscale.dtype == jnp.float32
+    c64 = np.asarray(c, np.float64)
+    g = c64 * s64
+    want_dx = rstd * g - x64 * rstd ** 3 * (g * x64).mean(-1, keepdims=True)
+    want_dscale = (c64 * x64 * rstd).sum(axis=(0, 1))
+    loose = dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(dx, np.float64), want_dx,
+                               rtol=2.0 ** -7 if loose else 1e-5,
+                               atol=2.0 ** -7 if loose else 1e-5)
+    np.testing.assert_allclose(np.asarray(dscale, np.float64), want_dscale,
+                               rtol=2e-2 if loose else 1e-5,
+                               atol=5e-2 if loose else 1e-4)
