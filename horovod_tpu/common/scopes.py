@@ -33,8 +33,9 @@ scope                 what falls under it
                       needs, adding up a weight's gradient over passes)
 ``hvd.loop.exit``     what ends a pass, inside ``hvd.loop.pass``: the final
                       norm and the exit gate; and in
-                      ``expected_exit_loss`` each exit's head and
-                      cross-entropy and the exit distribution.  A reader
+                      ``expected_exit_loss`` each exit's head, its
+                      cross-entropy and both of the head's gradient
+                      products, and the exit distribution.  A reader
                       asks for this scope first
 ====================  ====================================================
 
@@ -58,9 +59,14 @@ LlamaModel.pass_and_exit`` a layer's first forward is ``hvd.loss/
 jvp(LlamaModel)/P/layer_0/...``; its repeated forward ``hvd.loss/
 transpose(jvp(LlamaModel))/P/LlamaModel.pass_and_exit/checkpoint/
 rematted_computation/layer_0/...``; the backward work around it the same
-path without that component.  An exit's head again: ``hvd.loss/
-transpose(jvp(hvd.loop.exit))/while/body/closed_call/checkpoint/
-rematted_computation/LlamaModel.head/lm_head/dot_general``.
+path without that component.  Under ``hvd.loop.exit`` only the norm and
+the gate that end a pass are recomputed.  An exit's head is not: its
+forward product is ``hvd.loss/jvp(hvd.loop.exit)/while/body/closed_call/
+jvp(LlamaModel.head)/lm_head/dot_general``, and its two gradient products
+run in that same loop body as ``.../closed_call/transpose(jvp(
+LlamaModel.head))/lm_head/dot_general`` (``expected_exit_loss`` takes the
+head's ``jax.vjp`` inside its forward walk), so a reader that tells
+backward work by a ``transpose(`` component still counts them there.
 
 ``FLASH_OUT_NAME`` and ``FLASH_LSE_NAME`` are no scopes but
 ``checkpoint_name``s: the flash kernel's output and row statistics, for a

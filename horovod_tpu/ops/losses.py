@@ -26,11 +26,20 @@ match autodiff to bf16 rounding (tests/test_losses.py).
 ``expected_exit_loss`` is the objective of a looped LM
 (``LlamaModel`` with ``total_ut_steps`` > 1): every exit's cross-entropy
 through the one shared head, weighted token by token with the learned
-exit distribution, less an entropy term.  It is built on the same
-``_nll``, one exit at a time.
+exit distribution, less an entropy term.  It walks the exits one at a
+time with the same ``_nll_impl`` and ``_nll_bwd``, under a custom VJP of
+its own: an exit's weight is known before the walk, so the cotangent of
+its logits is known as soon as its loss is, and the walk takes both
+gradient products of the head while those logits are alive.  Nothing
+logits-sized is kept for the backward pass and no head product is made
+again there; the one-pass loss above keeps its logits and has nothing to
+gain from this.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +133,54 @@ def exit_log_distribution(gate_logits):
         [jax.nn.log_sigmoid(g[:-1]) + reached, stay[-1:]], axis=0)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _weighted_exit_nll(head, consts, hidden, weights, targets):
+    """``sum_t sum_tok weights_t nll_t``, exit t's logits ``head(hidden_t,
+    *consts)``: one head product an exit, and nothing else, where it is
+    not differentiated."""
+    nll = jax.lax.map(
+        lambda h: _nll_impl(head(h, *consts), targets)[0], hidden)
+    return jnp.sum(weights * nll)
+
+
+def _weighted_exit_nll_fwd(head, consts, hidden, weights, targets):
+    # The cotangent of an exit's logits is known once its loss is:
+    # weights_t (softmax - onehot).  So both gradient products are taken
+    # here, while the logits are alive, and the backward rule is left with
+    # three scalings.  Only dh, the head's summed gradient and the losses
+    # leave an iteration: no logits, no lse, no cotangent of their size.
+    def exit_and_gradients(head_grads, exit_):
+        h, w = exit_
+        # This exit's hidden states as a buffer of their own.  Without it
+        # XLA:TPU feeds the head's weight-gradient product from a
+        # transposed copy of all exits' states that it carries through the
+        # loop, which costs that product 1.5 ms an exit at 8192 x 2048 x
+        # 49152 on a v5e (PERF.md section 6, PR 31).
+        h = jax.lax.optimization_barrier(h)
+        logits, pullback = jax.vjp(lambda h, c: head(h, *c), h, consts)
+        nll, lse = _nll_impl(logits, targets)
+        d_logits, _ = _nll_bwd((logits, targets, lse), w)
+        dh, d_consts = pullback(d_logits)
+        return jax.tree.map(jnp.add, head_grads, d_consts), (dh, nll)
+
+    head_grads, (dh, nll) = jax.lax.scan(
+        exit_and_gradients, jax.tree.map(jnp.zeros_like, consts),
+        (hidden, weights))
+    return jnp.sum(weights * nll), (head_grads, dh, nll)
+
+
+def _weighted_exit_nll_bwd(head, res, g):
+    head_grads, dh, nll = res
+
+    def scaled(x):
+        return (g * x).astype(x.dtype)
+
+    return jax.tree.map(scaled, head_grads), scaled(dh), g * nll, None
+
+
+_weighted_exit_nll.defvjp(_weighted_exit_nll_fwd, _weighted_exit_nll_bwd)
+
+
 def expected_exit_loss(head, hidden, gate_logits, targets, *,
                        beta: float = 0.1):
     """Mean over all positions of ``sum_t p_t CE_t - beta H(p)``: the
@@ -134,22 +191,26 @@ def expected_exit_loss(head, hidden, gate_logits, targets, *,
     ``hidden [T, ..., H]`` and ``gate_logits [T, ...]`` are what
     ``LlamaModel`` returns with ``total_ut_steps`` = T > 1; ``head`` maps
     one exit's hidden states to logits (``lambda h: model.apply(params, h,
-    method="head")``); ``targets``: integer ``[...]``.  The exits are
-    walked by ``lax.map``, each exit's head and cross-entropy under
-    ``jax.checkpoint``: the forward pass keeps an exit's ``[...]`` losses
-    and no logits, and the backward pass is a loop that makes one exit's
-    logits again, adds its part of the head's gradient to one accumulator
-    and frees them, so one logits tensor and one cotangent of that size
-    are alive at a time.  Distribution and entropy in float32.
+    method="head")``: any callable, and what it closes over is
+    differentiated too); ``targets``: integer ``[...]``.  The exits are
+    walked one at a time, so one logits tensor and one cotangent of that
+    size are alive at a time.  Differentiated, the same walk forms each
+    exit's gradients while its logits are there (``_weighted_exit_nll``):
+    with ``w_t = p_t / N`` the logits' cotangent is ``w_t (softmax -
+    onehot)``, in the logits' dtype, and through the head's own ``jax.vjp``
+    it gives the hidden states' gradient and the head's, the latter added
+    to one accumulator over the exits in the parameters' dtype.  No logits
+    are kept and none are made again; the backward pass scales what the
+    walk left.  The gate's gradient flows through ``w`` and the entropy by
+    plain autodiff.  Distribution, losses and entropy in float32.
     """
-
-    @jax.checkpoint
-    def exit_nll(h):
-        return _nll(head(h), targets)
-
     with jax.named_scope(_scopes.LOOP_EXIT):
         log_p = exit_log_distribution(gate_logits)
         p = jnp.exp(log_p)
-        nll = jax.lax.map(exit_nll, hidden)
+        # What ``head`` closes over (the parameters being differentiated)
+        # becomes an explicit argument of the custom rule.
+        head, consts = jax.closure_convert(head, hidden[0])
+        expected_nll = _weighted_exit_nll(
+            head, consts, hidden, p / math.prod(targets.shape), targets)
         entropy = -jnp.sum(p * log_p, axis=0)
-        return jnp.mean(jnp.sum(p * nll, axis=0) - beta * entropy)
+        return expected_nll - beta * jnp.mean(entropy)

@@ -241,7 +241,10 @@ def test_keeping_the_flash_output_spares_its_forward_call(remat,
 def test_recomputed_work_carries_jaxs_own_name():
     """The spelling ``common/scopes.py`` records, in the compiled step's
     ``op_name``s: what the backward pass runs again is under
-    ``rematted_computation``, the loop's scope and a ``transpose(...)``."""
+    ``rematted_computation``, the loop's scope and a ``transpose(...)``:
+    each layer, and the norm and gate that end a pass.  No exit's head is
+    among it: its gradient products run beside its forward product, under
+    ``transpose(jvp(LlamaModel.head))`` inside the forward walk."""
     model = LlamaModel(dataclasses.replace(LOOPED, remat="layer"))
     params = _init(LOOPED)
 
@@ -254,7 +257,11 @@ def test_recomputed_work_carries_jaxs_own_name():
     op_names = set(re.findall(r'op_name="(jit\([^"]*)"', text))
     again = [n for n in op_names if f"/{scopes.REMATTED}/" in n]
     assert any(scopes.LOOP_PASS in n and "/layer_0/" in n for n in again)
-    assert any(scopes.LOOP_EXIT in n and "/lm_head/" in n for n in again)
+    assert any(scopes.LOOP_EXIT in n and "/norm_f/" in n for n in again)
+    assert not [n for n in again if "/lm_head/" in n]
+    head = {n for n in op_names if n.endswith("/lm_head/dot_general")}
+    assert head and all(f"jvp({scopes.LOOP_EXIT})" in n for n in head)
+    assert any("/transpose(jvp(LlamaModel.head))/" in n for n in head)
     assert all("transpose(" in n and scopes.LOSS in n for n in again)
     first = [n for n in op_names if "/layer_0/" in n and scopes.LOSS in n
              and scopes.REMATTED not in n and "transpose(" not in n]
