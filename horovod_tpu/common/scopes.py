@@ -37,7 +37,33 @@ scope                 what falls under it
                       cross-entropy and both of the head's gradient
                       products, and the exit distribution.  A reader
                       asks for this scope first
+``hvd.mla.latent``    latent attention's way from x to the kernel's k and
+                      v (``models/llama.py::LatentAttention``): the joint
+                      down-projection, the latent's norm, the
+                      up-projection, the rotation of the one shared rotary
+                      key, its broadcast over the heads and the
+                      concatenation -- what a plain attention layer does
+                      not have
+``hvd.moe.route``     the routed layer (``RoutedExperts``) before its
+                      products: router, softmax, top-k, the balance loss,
+                      the sort by expert and the gather of the held
+                      experts' rows
+``hvd.moe.experts``   the grouped gate-up and down products over the held
+                      experts, the SiLU gate between them, and their
+                      gradient products (see below)
+``hvd.moe.combine``   the rows back in token order, weighted with their
+                      gates and added up over a token's choices
+``hvd.moe.shared``    the shared experts' SwiGLU, which every chip computes
 ====================  ====================================================
+
+XLA:TPU executes ``jax.lax.ragged_dot`` -- forward, and both gradient
+products -- as Mosaic calls of its own, and names each by what it made, not
+by where it came from: the call's ``op_name`` is ``ragged-dot-none`` (a
+small ``ragged-dot-metadata`` call beside it turns the group sizes into
+tiles), with no scope of the program's in it (read from a step compiled for
+a described v5e, PR 32).  ``RAGGED_DOT_PREFIX`` is that prefix: a reader of
+``hvd.moe.experts`` counts operations so named as the scope's, since the
+routed layer is the program's only user of ``ragged_dot``.
 
 ``FUSION_PACK`` and ``FUSION_UNPACK`` named the copies of a trace-time
 gradient packer that is gone.  The constants stay because the benchmark's
@@ -78,7 +104,9 @@ from __future__ import annotations
 __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD",
-    "LOOP_PASS", "LOOP_EXIT", "REMATTED", "FLASH_OUT_NAME",
+    "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
+    "MOE_COMBINE", "MOE_SHARED", "RAGGED_DOT_PREFIX", "REMATTED",
+    "FLASH_OUT_NAME",
     "FLASH_LSE_NAME", "TRAIN_STEP_PROGRAM", "allreduce_scope",
 ]
 
@@ -93,6 +121,12 @@ FLASH_FWD = "hvd.flash.fwd"
 FLASH_BWD = "hvd.flash.bwd"
 LOOP_PASS = "hvd.loop.pass"
 LOOP_EXIT = "hvd.loop.exit"
+MLA_LATENT = "hvd.mla.latent"
+MOE_ROUTE = "hvd.moe.route"
+MOE_EXPERTS = "hvd.moe.experts"
+MOE_COMBINE = "hvd.moe.combine"
+MOE_SHARED = "hvd.moe.shared"
+RAGGED_DOT_PREFIX = "ragged-dot"     # XLA:TPU's own name for its calls
 REMATTED = "rematted_computation"    # JAX's own component, not a scope
 FLASH_OUT_NAME = "hvd.flash.out"
 FLASH_LSE_NAME = "hvd.flash.lse"

@@ -15,6 +15,15 @@ an exit gate after every pass, and ``(hidden, gate_logits)`` returned for
 forms), the serve plane and the pipelined step refuse it, and
 ``ring_attention`` as ``attention_fn`` and the MoE block are untested with
 it.  ``LlamaConfig.remat`` recomputes each layer in the backward pass.
+
+``LlamaConfig.attention_kind = "latent"`` makes every layer's mixer
+multi-head latent attention (DeepSeek-V2's MLA: keys ``qk_nope_head_dim +
+qk_rope_head_dim`` wide, values ``v_head_dim`` wide, YaRN ``rope_scaling``),
+and ``num_experts`` > 1 makes the layers from ``first_dense_layers`` on
+routed ones: a router over all experts, ``held_experts`` of them held here
+(one chip's share under expert parallelism), shared experts beside them,
+rows sorted by expert with none dropped.  Both are training paths too:
+``generation``, the serve plane and the pipelined step refuse them by name.
 """
 
 from horovod_tpu.models.mnist import MnistConvNet, MnistMLP

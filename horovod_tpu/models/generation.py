@@ -107,9 +107,7 @@ def _params(variables):
 
 
 def _check_supported(cfg: LlamaConfig) -> None:
-    if cfg.num_experts > 1:
-        raise NotImplementedError("KV-cache decode supports dense (non-MoE)"
-                                  " configs")
+    cfg.refuse_new_kinds("KV-cache decode")
     if cfg.total_ut_steps > 1:
         raise NotImplementedError(
             f"KV-cache decode walks the layer stack once; a looped model "

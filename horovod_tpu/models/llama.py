@@ -16,8 +16,13 @@ TPU-first design:
 * SwiGLU MLP with fused gate+up projection (one [H, 2F] matmul).
 * Pluggable ``attention_fn`` — ``horovod_tpu.parallel.ring_attention``
   substitutes a ppermute-ring blockwise kernel for sequence parallelism.
-* Optional MoE (``num_experts > 1``): top-k routed experts via einsum
-  dispatch/combine, the expert-parallel workload.
+* One layer stack whose layers take their mixer and their feed-forward
+  by kind from the config: full attention or latent attention (MLA,
+  ``attention_kind``); a dense SwiGLU or routed experts beside shared
+  ones (``num_experts > 1``, after ``first_dense_layers`` dense layers).
+  The routed layer is told which experts it holds, routes over all of
+  them, gathers its own experts' rows sorted by expert -- none dropped --
+  and runs grouped products over them (``RoutedExperts``).
 * Optional weight-shared passes over the stack (``total_ut_steps > 1``,
   the looped LM of Ouro / LoopLM) with a per-token exit gate, and
   recomputation of each layer in the backward pass (``remat``); see
@@ -27,17 +32,21 @@ TPU-first design:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.ops.losses import sequence_balance_loss
 
-__all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "apply_rope",
-           "causal_attention"]
+__all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "YarnScaling",
+           "apply_rope", "causal_attention"]
 
 
 REMAT_POLICIES = {
@@ -49,6 +58,49 @@ REMAT_POLICIES = {
     "layer_keep_attention": checkpoint_policies.save_only_these_names(
         _scopes.FLASH_OUT_NAME, _scopes.FLASH_LSE_NAME),
 }
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 mscale ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """``rope_scaling`` of type ``yarn``, under its published keys."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def correction_range(self, dim: int, theta: float) -> tuple[int, int]:
+        """(low, high): the rotary pairs between which YaRN blends from
+        the published frequency (below ``low``) to the interpolated one
+        (above ``high``)."""
+        def pair_of(rotations):
+            return (dim * math.log(self.original_max_position_embeddings
+                                   / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(pair_of(self.beta_fast)), 0)
+        high = min(math.ceil(pair_of(self.beta_slow)), dim - 1)
+        return low, high
+
+    @property
+    def table_scale(self) -> float:
+        """What multiplies cos and sin."""
+        return (yarn_mscale(self.factor, self.mscale)
+                / yarn_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_scale(self) -> float:
+        """What multiplies ``1 / sqrt(d_qk)``: m squared."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return yarn_mscale(self.factor, self.mscale_all_dim) ** 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +125,24 @@ class LlamaConfig:
     ``"layer_keep_attention"`` (and the flash kernel's output and row
     statistics).  Four passes hold four times one pass's activations,
     so a looped model at a long sequence needs one of the last two.
+
+    ``attention_kind`` is ``"full"`` (``LlamaAttention``) or ``"latent"``
+    (``LatentAttention``, DeepSeek-V2's MLA): then ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim`` are the
+    published keys, ``num_kv_heads`` is not read, and ``rope_scaling`` (a
+    ``YarnScaling``) sets the rotary frequencies and the softmax scale.
+
+    ``num_experts`` > 1 makes every layer from ``first_dense_layers`` on a
+    routed one (``RoutedExperts``): a router over all ``num_experts``,
+    ``experts_per_token`` choices a token, experts of width
+    ``moe_intermediate_size`` (``intermediate_size`` where 0) and
+    ``shared_experts`` always-on experts of that width, as one SwiGLU.
+    ``held_experts`` > 0 says this program holds that many of them, ids
+    ``first_held_expert`` onwards (one chip's share under expert
+    parallelism): only they have weights here, and what the absent ones
+    would add to a token is left out.  ``norm_topk_prob`` renormalises a
+    token's gate weights to sum to one.  Generation, the serve plane and the pipelined step
+    refuse latent attention and routed layers by name.
     """
 
     vocab_size: int = 32000
@@ -84,8 +154,20 @@ class LlamaConfig:
     max_seq_len: int = 8192
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
-    num_experts: int = 1          # >1 enables MoE
+    num_experts: int = 1          # >1 enables routed layers
     experts_per_token: int = 2
+    held_experts: int = 0         # 0: all of them
+    first_held_expert: int = 0
+    moe_intermediate_size: int = 0
+    shared_experts: int = 0
+    first_dense_layers: int = 0
+    norm_topk_prob: bool = True
+    attention_kind: str = "full"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
     dtype: Any = jnp.bfloat16
     # Output-head compute dtype.  bf16 keeps every logits-sized tensor —
     # the forward residual AND the cross-entropy cotangent, 2 GB each in
@@ -103,6 +185,20 @@ class LlamaConfig:
         if self.remat != "none" and self.remat not in REMAT_POLICIES:
             raise ValueError(f"remat is {self.remat!r}: 'none' or one of "
                              f"{sorted(REMAT_POLICIES)}")
+        if self.attention_kind not in ("full", "latent"):
+            raise ValueError(f"attention_kind is {self.attention_kind!r}: "
+                             f"'full' or 'latent'")
+        if self.attention_kind == "latent" and not (
+                self.kv_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim):
+            raise ValueError("latent attention needs kv_lora_rank, "
+                             "qk_nope_head_dim, qk_rope_head_dim, v_head_dim")
+        if not 0 <= self.first_held_expert <= (
+                self.num_experts - self.experts_held):
+            raise ValueError(
+                f"experts {self.first_held_expert} to "
+                f"{self.first_held_expert + self.experts_held - 1} are not "
+                f"among {self.num_experts}")
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -122,6 +218,34 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
 
+    @property
+    def rope_dim(self) -> int:
+        """Width of what the rotary positions turn in a head."""
+        return (self.qk_rope_head_dim if self.attention_kind == "latent"
+                else self.head_dim)
+
+    @property
+    def experts_held(self) -> int:
+        return self.held_experts or self.num_experts
+
+    def is_routed(self, layer: int) -> bool:
+        return self.num_experts > 1 and layer >= self.first_dense_layers
+
+    def refuse_new_kinds(self, who: str) -> None:
+        """For the paths that keep a decoder layer of their own and have
+        learned neither kind (ROADMAP.md D1): raise, naming the kind."""
+        if self.attention_kind == "latent":
+            raise NotImplementedError(
+                f"{who} has no path for latent attention "
+                f"(attention_kind='latent'): its cache would hold the "
+                f"{self.kv_lora_rank}-wide latent and the shared rotary "
+                f"key, and prefill and decode differ; not built")
+        if self.num_experts > 1:
+            raise NotImplementedError(
+                f"{who} has no path for routed experts (num_experts="
+                f"{self.num_experts}): it runs a dense feed-forward in "
+                f"every layer")
+
 
 class RMSNorm(nn.Module):
     eps: float = 1e-5
@@ -136,15 +260,31 @@ class RMSNorm(nn.Module):
         return (x32 * scale).astype(self.dtype)
 
 
-def rope_freqs(head_dim: int, seq_len: int, theta: float,
-               offset=0) -> tuple[jax.Array, jax.Array]:
+def rope_freqs(head_dim: int, seq_len: int, theta: float, offset=0,
+               scaling: Optional[YarnScaling] = None
+               ) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables [S, head_dim/2] in fp32.  ``offset`` may be a traced
-    value (sequence-parallel shards pass ``axis_index * S_local``)."""
+    value (sequence-parallel shards pass ``axis_index * S_local``).
+
+    With ``scaling`` (YaRN; Peng et al., arXiv:2309.00071, as DeepSeek-V2
+    applies it): pair i turns at ``f_i = theta^(-2i/d)`` below
+    ``low``, at ``f_i / factor`` above ``high`` and at a linear blend of
+    the two between (``YarnScaling.correction_range``), and the tables are
+    multiplied by ``table_scale``."""
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                            / head_dim))
+    if scaling is not None:
+        low, high = scaling.correction_range(head_dim, theta)
+        ramp = jnp.clip(
+            (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+            / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv / scaling.factor * ramp + inv * (1.0 - ramp)
     t = jnp.arange(seq_len, dtype=jnp.float32) + offset
     ang = jnp.outer(t, inv)
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.table_scale != 1.0:
+        cos, sin = cos * scaling.table_scale, sin * scaling.table_scale
+    return cos, sin
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -159,18 +299,24 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def causal_attention(q, k, v, *, q_offset: int = 0):
+def causal_attention(q, k, v, *, q_offset: int = 0,
+                     scale: Optional[float] = None):
     """Default causal attention, fp32 logits, GQA-aware.
 
-    q: [B, Sq, Hq, D]; k, v: [B, Sk, Hkv, D] with Hq % Hkv == 0.
+    q: [B, Sq, Hq, D]; k: [B, Sk, Hkv, D]; v: [B, Sk, Hkv, Dv] with
+    Hq % Hkv == 0.
     ``q_offset``: global position of q[0] (for decode / sequence shards).
+    ``scale`` multiplies the scores in place of ``1 / sqrt(D)``.
     """
     B, Sq, Hq, D = q.shape
     Hkv = k.shape[2]
     group = Hq // Hkv
     qg = q.reshape(B, Sq, Hkv, group, D)
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
-    logits = logits / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    if scale is None:
+        logits = logits / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    else:
+        logits = logits * scale
     q_pos = jnp.arange(Sq) + q_offset
     k_pos = jnp.arange(k.shape[1])
     mask = q_pos[:, None] >= k_pos[None, :]
@@ -178,7 +324,7 @@ def causal_attention(q, k, v, *, q_offset: int = 0):
                        jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(B, Sq, Hq, D)
+    return out.reshape(B, Sq, Hq, v.shape[-1])
 
 
 class LlamaAttention(nn.Module):
@@ -206,23 +352,195 @@ class LlamaAttention(nn.Module):
 
 class SwiGLU(nn.Module):
     config: LlamaConfig
+    width: Optional[int] = None     # config.intermediate_size where None
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
         # Fused gate+up: one [H, 2F] matmul.
-        gu = nn.Dense(2 * cfg.intermediate_size, use_bias=False,
-                      dtype=cfg.dtype, name="w_gate_up")(x)
+        gu = nn.Dense(2 * (self.width or cfg.intermediate_size),
+                      use_bias=False, dtype=cfg.dtype, name="w_gate_up")(x)
         gate, up = jnp.split(gu, 2, axis=-1)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="w_down")(nn.silu(gate) * up)
 
 
-class MoEBlock(nn.Module):
-    """Top-k routed mixture of SwiGLU experts (expert-parallel workload).
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA) of DeepSeek-V2 (arXiv:2405.04434),
+    on the training path: keys and values are made from one low-rank
+    latent a token, and one rotary key serves all heads.
 
-    Dense dispatch/combine via einsum — dynamic-shape-free so it shards
-    cleanly over an ``expert`` mesh axis.
+    With n heads, ``d_n = qk_nope_head_dim``, ``d_r = qk_rope_head_dim``,
+    ``d_v = v_head_dim``, ``r = kv_lora_rank`` and x the normed input::
+
+        q = x W_q            [n, d_n + d_r]  ->  q_n, q_r
+        c = x W_kva          [r + d_r]       ->  c_kv, k_r  (k_r: all heads')
+        [k_n | v] = RMSNorm(c_kv) W_kvb      [n, d_n + d_v]
+        q_r, k_r <- RoPE (YaRN frequencies, ``rope_freqs``)
+        scores = [q_n | q_r] . [k_n | k_r] (d_n + d_r)^(-1/2) m^2
+        out = softmax_causal(scores) v W_o
+
+    m squared is ``YarnScaling.softmax_scale``.  No query latent
+    (``q_lora_rank`` null, as DeepSeek-V2-Lite).  ``attention_fn`` is
+    handed keys ``d_n + d_r`` wide, values ``d_v`` wide and the scale.
+    """
+
+    config: LlamaConfig
+    attention_fn: Callable = staticmethod(causal_attention)
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        cfg = self.config
+        B, S, _ = x.shape
+        heads, rank = cfg.num_heads, cfg.kv_lora_rank
+        d_n, d_r, d_v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim)
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        q = dense(heads * (d_n + d_r), "wq")(x).reshape(
+            B, S, heads, d_n + d_r)
+        q = jnp.concatenate(
+            [q[..., :d_n], apply_rope(q[..., d_n:], cos, sin)], axis=-1)
+        with jax.named_scope(_scopes.MLA_LATENT):
+            latent = dense(rank + d_r, "wkv_a")(x)
+            c_kv = RMSNorm(cfg.rms_eps, cfg.dtype,
+                           name="kv_norm")(latent[..., :rank])
+            k_r = apply_rope(latent[..., None, rank:], cos, sin)
+            kv = dense(heads * (d_n + d_v), "wkv_b")(c_kv).reshape(
+                B, S, heads, d_n + d_v)
+            k = jnp.concatenate(
+                [kv[..., :d_n],
+                 jnp.broadcast_to(k_r, (B, S, heads, d_r))], axis=-1)
+            v = kv[..., d_n:]
+        scale = (d_n + d_r) ** -0.5
+        if cfg.rope_scaling is not None:
+            scale *= cfg.rope_scaling.softmax_scale
+        out = self.attention_fn(q, k, v, scale=scale)
+        return dense(cfg.hidden_size, "wo")(out.reshape(B, S, heads * d_v))
+
+
+def _float0(x):
+    return np.zeros(x.shape, dtype=jax.dtypes.float0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_tokens(x, assignments, position, k):
+    """``x[assignments // k]``: row r of the result is the token of
+    assignment ``assignments[r]`` (a token's k assignments are
+    consecutive).  ``position [T * k]`` says where in the result each
+    assignment's row is (outside ``[0, rows)``: nowhere), so the transpose
+    is a gather and a sum over a token's k too, where autodiff would
+    scatter-add row by row."""
+    return x[assignments // k]
+
+
+def _rows_of_tokens_fwd(x, assignments, position, k):
+    return x[assignments // k], (assignments, position)
+
+
+def _rows_of_tokens_bwd(k, res, g):
+    assignments, position = res
+    return (_rows_to_tokens(g, position, k).astype(g.dtype),
+            _float0(assignments), _float0(position))
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+def _rows_to_tokens(rows, position, k, weights=None):
+    """``[T, H]`` in float32: each token's k rows of ``rows`` added up,
+    under ``weights [T, k]`` if given.  An assignment whose row is not
+    among ``rows`` adds nothing."""
+    there = (position >= 0) & (position < rows.shape[0])
+    taken = rows[jnp.clip(position, 0, rows.shape[0] - 1)]
+    scale = there.astype(jnp.float32) if weights is None else jnp.where(
+        there, weights.reshape(-1), 0.0)
+    taken = taken.astype(jnp.float32) * scale[:, None]
+    return jnp.sum(taken.reshape(-1, k, rows.shape[-1]), axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_rows_to_tokens(rows, weights, assignments, position, k):
+    """``_rows_to_tokens`` under the gates, with a transpose that gathers:
+    row r's cotangent is its token's, times the gate of assignment
+    ``assignments[r]``."""
+    return _rows_to_tokens(rows, position, k, weights)
+
+
+def _weighted_rows_to_tokens_fwd(rows, weights, assignments, position, k):
+    return (_rows_to_tokens(rows, position, k, weights),
+            (rows, weights, assignments, position))
+
+
+def _weighted_rows_to_tokens_bwd(k, res, g):
+    rows, weights, assignments, position = res
+    g_rows = g[assignments // k]                              # [rows, H]
+    gate_of_row = weights.reshape(-1)[assignments]
+    d_rows = (g_rows * gate_of_row[:, None]).astype(rows.dtype)
+    # d weights[t, j] = g[t] . rows[position[t, j]], formed row by row and
+    # sent back to the assignment each row came from (a dead row's is 0).
+    dots = jnp.sum(g_rows * rows.astype(jnp.float32), axis=-1)
+    d_weights = jnp.zeros(weights.size, jnp.float32).at[assignments].add(
+        dots).reshape(weights.shape).astype(weights.dtype)
+    return d_rows, d_weights, _float0(assignments), _float0(position)
+
+
+_weighted_rows_to_tokens.defvjp(_weighted_rows_to_tokens_fwd,
+                                _weighted_rows_to_tokens_bwd)
+
+
+def _row_chunk(assignments: int, share: float) -> int:
+    """Rows of the row buffer: twice what a uniform router sends to a chip
+    that holds ``share`` of the experts, in whole tiles of 512; all the
+    assignments where that is no less."""
+    return min(-(-int(2 * share * assignments) // 512) * 512, assignments)
+
+
+class RoutedExperts(nn.Module):
+    """Top-k routed SwiGLU experts, of which this program holds
+    ``config.experts_held`` (ids ``first_held_expert`` onwards), and the
+    shared experts beside them (DeepSeekMoE, arXiv:2401.06066).
+
+    For a token x, in float32 for the router (x and W_r cast up)::
+
+        s = softmax(x W_r)                    over all num_experts
+        e_1..e_K = the K largest of s;  g_k = s[e_k]
+                   (divided by their sum if norm_topk_prob)
+        y = sum_{k: e_k held here} g_k E_{e_k}(x) + S(x)
+
+    E_e a SwiGLU of width ``moe_intermediate_size``, S one SwiGLU of
+    ``shared_experts`` times that width.  What an absent expert would add
+    is left out (the chip that holds it adds it, and the sum over chips is
+    the whole layer); with every expert held nothing is.
+
+    Static shapes and no dropped row, whatever the imbalance: the T * K
+    assignments are sorted by held expert (absent ones last), the tokens'
+    rows gathered in that order into a row buffer, and two grouped
+    products (``jax.lax.ragged_dot``, group sizes the held experts' row
+    counts) run over the rows that are there: XLA:TPU makes each a Mosaic
+    call that visits only tiles that hold rows.  Each token then takes its
+    rows back by the inverse permutation and adds them up under its gates.
+    The buffer's worst case is T * K rows, every choice of every token held
+    here; a chip that holds 8 of 64 experts expects an eighth of that, and
+    gathers, elementwise passes and residuals over the dead seven eighths
+    were 30 % of a step on the v5e (PERF.md, PR 32).  So the buffer has
+    twice the expected rows (``_row_chunk``) and the sorted rows go through
+    it buffer by buffer, as many times as there are rows for (a ``scan``
+    whose body is skipped where no rows are left): once as a rule, as
+    often as the worst case needs at worst, and with every expert held
+    there is one buffer and no loop.
+    Weights are ``w_gate_up [held, H, 2F]`` and ``w_down [held, F, H]``,
+    which ``parallel/api.py`` shards over an ``expert`` axis.
+
+    Outside ``init`` it sows, where the caller makes the collection
+    mutable: ``losses/balance`` (``ops.losses.sequence_balance_loss`` of
+    this layer) and ``moe_stats/rows_per_expert [held]``,
+    ``moe_stats/rows_dropped`` (assignments to held experts that are not
+    in a buffer: 0 by construction) and ``moe_stats/row_buffers_run``
+    (how many buffers held rows).
     """
 
     config: LlamaConfig
@@ -231,40 +549,123 @@ class MoEBlock(nn.Module):
     def __call__(self, x):
         cfg = self.config
         B, S, H = x.shape
-        E, K = cfg.num_experts, cfg.experts_per_token
-        router = nn.Dense(E, use_bias=False, dtype=jnp.float32,
-                          name="router")(x.astype(jnp.float32))   # [B,S,E]
-        weights, sel = jax.lax.top_k(jax.nn.softmax(router, -1), K)
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
-        one_hot = jax.nn.one_hot(sel, E, dtype=cfg.dtype)          # [B,S,K,E]
-        combine = jnp.einsum("bske,bsk->bse", one_hot,
-                             weights.astype(cfg.dtype))            # [B,S,E]
-        # Expert-batched weights: [E, H, 2F] and [E, F, H].
-        w_gu = self.param("w_gate_up", nn.initializers.lecun_normal(),
-                          (E, H, 2 * cfg.intermediate_size)).astype(cfg.dtype)
-        w_down = self.param("w_down", nn.initializers.lecun_normal(),
-                            (E, cfg.intermediate_size, H)).astype(cfg.dtype)
-        sel_mask = (combine != 0).astype(cfg.dtype)                # [B,S,E]
-        xe = jnp.einsum("bsh,bse->ebsh", x, sel_mask)              # masked copy
-        gu = jnp.einsum("ebsh,ehf->ebsf", xe, w_gu)
-        gate, up = jnp.split(gu, 2, axis=-1)
-        ye = jnp.einsum("ebsf,efh->ebsh", nn.silu(gate) * up, w_down)
-        return jnp.einsum("ebsh,bse->bsh", ye, combine)
+        E, K, held = cfg.num_experts, cfg.experts_per_token, cfg.experts_held
+        F = cfg.moe_intermediate_size or cfg.intermediate_size
+        T = B * S
+        per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gu = self.param("w_gate_up", per_expert,
+                          (held, H, 2 * F)).astype(cfg.dtype)
+        w_down = self.param("w_down", per_expert,
+                            (held, F, H)).astype(cfg.dtype)
+
+        with jax.named_scope(_scopes.MOE_ROUTE):
+            router = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+                              name="router")(x.astype(jnp.float32))
+            scores = jax.nn.softmax(router, axis=-1)               # [B,S,E]
+            gates, chosen = jax.lax.top_k(scores, K)               # [B,S,K]
+            if cfg.norm_topk_prob:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            # Assignment a = (token, choice), flat; absent experts sort last.
+            local = chosen.reshape(T * K) - cfg.first_held_expert
+            here = (local >= 0) & (local < held)
+            local = jnp.where(here, local, held)
+            order = jnp.argsort(local, stable=True)
+            inverse = jnp.argsort(order)
+            rows_per_expert = jnp.sum(
+                local[:, None] == jnp.arange(held)[None, :], axis=0,
+                dtype=jnp.int32)
+            n_rows = jnp.sum(rows_per_expert)
+            weights = jnp.where(here.reshape(T, K), gates.reshape(T, K),
+                                0.0)
+            chunk = _row_chunk(T * K, held / E)
+            n_chunks = -(-T * K // chunk)
+            if not self.is_initializing():
+                self.sow("losses", "balance",
+                         sequence_balance_loss(scores, chosen))
+                self.sow("moe_stats", "rows_per_expert", rows_per_expert)
+                self.sow("moe_stats", "rows_dropped",
+                         jnp.maximum(n_rows - n_chunks * chunk, 0))
+                self.sow("moe_stats", "row_buffers_run",
+                         -(-n_rows // chunk))
+
+        last = jnp.cumsum(rows_per_expert)
+        order = jnp.pad(order, (0, n_chunks * chunk - T * K))
+        tokens = x.reshape(T, H)
+
+        def one_buffer(first, tokens, w_gu, w_down, weights):
+            """The part of y that the sorted rows ``first`` to ``first +
+            chunk`` give: gathered, through their experts, and back to
+            their tokens under the gates.  ``[T, H]`` float32."""
+            with jax.named_scope(_scopes.MOE_ROUTE):
+                assignments = jax.lax.dynamic_slice(order, (first,), (chunk,))
+                position = inverse - first
+                # An expert's rows that fall into this buffer.
+                sizes = (jnp.clip(last, first, first + chunk)
+                         - jnp.clip(last - rows_per_expert, first,
+                                    first + chunk))
+                live = (first + jnp.arange(chunk) < n_rows)[:, None]
+                rows = _rows_of_tokens(tokens, assignments, position, K)
+                # Rows past the last group are no expert's: what a grouped
+                # product leaves there is undefined, so it is cut off at
+                # both ends (here for the gradient that comes back).
+                rows = jnp.where(live, rows, 0)
+            with jax.named_scope(_scopes.MOE_EXPERTS):
+                gate, up = jnp.split(
+                    jax.lax.ragged_dot(rows, w_gu, sizes), 2, axis=-1)
+                rows = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
+            with jax.named_scope(_scopes.MOE_COMBINE):
+                rows = jnp.where(live, rows, 0)
+                return _weighted_rows_to_tokens(rows, weights, assignments,
+                                                position, K)
+
+        if n_chunks == 1:
+            y = one_buffer(0, tokens, w_gu, w_down, weights)
+        else:
+            # Buffer by buffer, and only those that hold rows: a chip's
+            # share of the experts gets a fraction of the worst case's rows
+            # and pays for what it gets.  Each buffer keeps its inputs alone
+            # for the backward pass and runs its forward again there; kept,
+            # the residuals of all buffers are the worst case's.
+            @jax.checkpoint
+            def add_buffer(y, first):
+                return jax.lax.cond(
+                    first < n_rows,
+                    lambda y: y + one_buffer(first, tokens, w_gu, w_down,
+                                             weights),
+                    lambda y: y, y), None
+
+            y, _ = jax.lax.scan(add_buffer, jnp.zeros((T, H), jnp.float32),
+                                jnp.arange(n_chunks) * chunk)
+        y = y.astype(cfg.dtype).reshape(B, S, H)
+
+        if cfg.shared_experts:
+            with jax.named_scope(_scopes.MOE_SHARED):
+                y = y + SwiGLU(cfg, width=cfg.shared_experts * F,
+                               name="shared")(x)
+        return y
+
+
+ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention}
 
 
 class LlamaLayer(nn.Module):
+    """Pre-norm mixer and pre-norm feed-forward, both residual; which
+    mixer and which feed-forward, the config says (``attention_kind``;
+    ``LlamaConfig.is_routed`` of the layer's ``index`` in the stack)."""
+
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
+    index: int = 0
 
     @nn.compact
     def __call__(self, x, cos, sin):
         cfg = self.config
         y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_attn")(x)
-        x = x + LlamaAttention(cfg, attention_fn=self.attention_fn,
-                               name="attn")(y, cos, sin)
+        x = x + ATTENTION_KINDS[cfg.attention_kind](
+            cfg, attention_fn=self.attention_fn, name="attn")(y, cos, sin)
         y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_mlp")(x)
-        if cfg.num_experts > 1:
-            x = x + MoEBlock(cfg, name="moe")(y)
+        if cfg.is_routed(self.index):
+            x = x + RoutedExperts(cfg, name="moe")(y)
         else:
             x = x + SwiGLU(cfg, name="mlp")(y)
         return x
@@ -303,8 +704,9 @@ class LlamaModel(nn.Module):
         B, S = input_ids.shape
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      name="tok_emb")(input_ids)
-        cos, sin = rope_freqs(cfg.head_dim, S, cfg.rope_theta,
-                              offset=positions_offset)
+        cos, sin = rope_freqs(cfg.rope_dim, S, cfg.rope_theta,
+                              offset=positions_offset,
+                              scaling=cfg.rope_scaling)
         layer_cls = LlamaLayer
         if cfg.remat != "none":
             layer_cls = nn.remat(LlamaLayer,
@@ -313,7 +715,7 @@ class LlamaModel(nn.Module):
         def one_pass(mdl, x):
             """Stack(x), its modules made under ``mdl`` by name."""
             for i in range(cfg.num_layers):
-                x = layer_cls(cfg, attention_fn=self.attention_fn,
+                x = layer_cls(cfg, attention_fn=self.attention_fn, index=i,
                               name=f"layer_{i}", parent=mdl)(x, cos, sin)
             return x
 

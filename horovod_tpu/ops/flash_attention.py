@@ -25,7 +25,10 @@ Design (FlashAttention-2 style):
 * causal masking is block-aware: block pairs entirely above the diagonal
   are skipped (the loop bound, not a mask), the diagonal block gets the
   intra-block triangle; packed rows skip the pairs before a segment's start
-  the same way.
+  the same way;
+* the values may have a width of their own (``d_v`` != ``d_qk``: latent
+  attention's 192-wide keys and 128-wide values): q, k, dq and dk are
+  ``d_qk`` wide, v, o, dO and dv ``d_v`` wide, in the same two calls.
 
 ``flash_attention`` is a drop-in for the model zoo's ``attention_fn``
 seam ([B, S, H, D] layout, GQA via KV-head repetition).  Shapes off the
@@ -127,7 +130,7 @@ def _seg_mask(scores, seg_start, ki, block_k):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
                 block_k, bias_ref=None, seg_ref=None):
-    # q_ref: [block_q, D]; k_ref/v_ref: [S, D]; o_ref: [block_q, D];
+    # q_ref: [block_q, D]; k_ref: [S, D]; v_ref: [S, Dv]; o_ref: [block_q, Dv];
     # bias_ref (optional): [8, S] additive key bias (0 valid / -1e30
     # masked), sublane-replicated like lse — key-padding masks for
     # bidirectional (BERT-style) attention.
@@ -146,7 +149,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
 
     m = jnp.full((block_q,), -1e30, jnp.float32)
     l = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, d), jnp.float32)
+    acc = jnp.zeros((block_q, v_ref.shape[1]), jnp.float32)
 
     n_kv = s // block_k
     if causal:
@@ -250,8 +253,10 @@ def _with_extras(base_kernel, n_outs, names, **fixed):
 
 
 def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
-    # q, k, v: [BH, S, D]; bias/seg (optional): [B, 8, S] sidebands.
+    # q, k: [BH, S, D]; v: [BH, S, Dv]; bias/seg (optional): [B, 8, S]
+    # sidebands.
     bh, s, d = q.shape
+    dv = v.shape[-1]
     bq = _pick_block(s, BLOCK_Q)
     bk = _pick_block(s, BLOCK_K)
     grid = (bh, s // bq)
@@ -265,14 +270,14 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, s, dv), lambda b, i: (b, 0, 0)),
         ] + bias_specs,
         out_specs=[
-            pl.BlockSpec((None, bq, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, bq, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 8, s), jnp.float32),
         ],
         interpret=_interpret(),
@@ -289,8 +294,9 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *, causal, sm_scale,
                 block_q, bias_ref=None, seg_ref=None):
-    # Grid (head, key block).  q_ref/do_ref: [S, D], the head's whole row;
-    # k_ref/v_ref, dk_ref/dv_ref: [block_k, D], this step's key block;
+    # Grid (head, key block).  q_ref: [S, D] and do_ref: [S, Dv], the head's
+    # whole row; k_ref, dk_ref: [block_k, D] and v_ref, dv_ref: [block_k, Dv],
+    # this step's key block;
     # dq_ref: [S, D], the same block at every key block of a head, so it
     # stays in VMEM until the head changes; dq_acc: [S, D] fp32 scratch.
     # One walk over the live (query block, key block) pairs: each forms
@@ -378,7 +384,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk, dv = jax.lax.fori_loop(
         first_q, n_q_live, body,
         (jnp.zeros((block_k, d), jnp.float32),
-         jnp.zeros((block_k, d), jnp.float32)))
+         jnp.zeros((block_k, v_ref.shape[1]), jnp.float32)))
     dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
@@ -393,7 +399,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 _DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
 
 
-def _bwd_vmem_limit(s, d, bq, bk, itemsize, n_sidebands):
+def _bwd_vmem_limit(s, d, bq, bk, itemsize, n_sidebands, d_v=None):
     """``vmem_limit_bytes`` of the backward call, from the shapes it sees:
     twice the bytes of its blocks, its scratch and the pair's live fp32
     ``[bq, bk]`` arrays (scores, p, dp, ds), and never under the compiler's
@@ -402,10 +408,13 @@ def _bwd_vmem_limit(s, d, bq, bk, itemsize, n_sidebands):
     inside a train step XLA puts operands of its own choosing (lse and
     delta whole, at 8k) into the call's scope: compiled alone the call
     took 12 MiB at ``[S, D]`` = [8192, 128] in bf16, inside the decoder's
-    step 19.8, of the 30 asked for here; the default holds neither."""
+    step 19.8, of the 30 asked for here; the default holds neither.
+    ``d`` is the width of q, k, dq and dk, ``d_v`` that of v, dO and dv
+    (``d`` where it is not given)."""
     d = -(-d // 128) * 128                   # VMEM pads the lanes
-    rows = 3 * s * d * itemsize              # q, do in and dq out: whole rows
-    key_blocks = 4 * bk * d * itemsize       # k, v in; dk, dv out
+    d_v = d if d_v is None else -(-d_v // 128) * 128
+    rows = s * (2 * d + d_v) * itemsize      # q, do in and dq out: whole rows
+    key_blocks = 2 * bk * (d + d_v) * itemsize   # k, v in; dk, dv out
     stats = (2 + n_sidebands) * 8 * s * 4    # lse, delta, bias / seg
     dq_acc = s * d * 4
     live = 4 * bq * bk * 4
@@ -433,25 +442,32 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
     # The scratch ref follows the outputs, so it counts among them here.
     kernel = _with_extras(_bwd_kernel, 4, names, causal=causal,
                           sm_scale=sm_scale, block_q=bq)
-    row = pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0))
-    key_block = pl.BlockSpec((None, bk, d), lambda b, i: (b, i, 0))
+    dv = v.shape[-1]
+
+    def row(width):
+        return pl.BlockSpec((None, s, width), lambda b, i: (b, 0, 0))
+
+    def key_block(width):
+        return pl.BlockSpec((None, bk, width), lambda b, i: (b, i, 0))
+
     stat = pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0))
     call = pl.pallas_call(
         kernel,
         grid=(bh, s // bk),
-        in_specs=[row, key_block, key_block, row, stat, stat] + bias_specs,
-        out_specs=[row, key_block, key_block],
+        in_specs=[row(d), key_block(d), key_block(dv), row(dv), stat,
+                  stat] + bias_specs,
+        out_specs=[row(d), key_block(d), key_block(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
         # dq adds up over the key blocks of a head: that axis runs in order.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_bwd_vmem_limit(
-                s, d, bq, bk, q.dtype.itemsize, len(names))),
+                s, d, bq, bk, q.dtype.itemsize, len(names), d_v=dv)),
         interpret=_interpret(),
     )
     with jax.named_scope(_scopes.FLASH_BWD):
@@ -486,16 +502,17 @@ _flash.defvjp(_flash_fwd, _bwd)
 
 
 def _flat_layout(q, k, v):
-    """[B, S, H, D] -> the kernels' flat [B*H, S, D] operands, GQA KV
-    heads repeated to Hq (shared by both public entry points)."""
-    B, S, Hq, D = q.shape
+    """[B, S, H, D] -> the kernels' flat [B*H, S, D] operands, each with
+    its own D, GQA KV heads repeated to Hq (shared by both public entry
+    points)."""
+    B, S, Hq, _ = q.shape
     Hkv = k.shape[2]
     if Hkv != Hq:
         k = jnp.repeat(k, Hq // Hkv, axis=2)
         v = jnp.repeat(v, Hq // Hkv, axis=2)
 
     def t(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * Hq, S, D)
+        return x.transpose(0, 2, 1, 3).reshape(B * Hq, S, x.shape[-1])
 
     return t(q), t(k), t(v)
 
@@ -531,11 +548,13 @@ def _pad_head_dim(q, k, v):
     ``q.dtype``-rounded ``sqrt(Dpad)/sqrt(D)`` constant perturbs every
     score's softmax temperature in bf16 (~0.4% max), smearing padded vs
     dense parity.  Autodiff slices the grads back through the pad
-    (grad-of-pad = slice).  Returns padded (q, k, v)."""
-    d = q.shape[-1]
-    dp = -(-d // 64) * 64
-    pad = ((0, 0), (0, 0), (0, 0), (0, dp - d))
-    return jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+    (grad-of-pad = slice).  v is padded to the next tile of its own
+    width.  Returns padded (q, k, v)."""
+    def pad(x):
+        d = x.shape[-1]
+        return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, -d % 64)))
+
+    return pad(q), pad(k), pad(v)
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True,
@@ -560,21 +579,22 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     its per-hop kernel for small-head models.
     """
     B, S, Hq, D = q.shape
+    Dv = v.shape[-1]
     if not flash_lse_supported(S, D):
         raise ValueError(
             f"flash_attention_lse requires S % 128 == 0, "
             f"got S={S}, D={D}; gate on flash_lse_supported()")
-    if D % 64 != 0:
+    if D % 64 != 0 or Dv % 64 != 0:
         qp, kp, vp = _pad_head_dim(q, k, v)
         out, lse = flash_attention_lse(
             qp, kp, vp, causal=causal,
             _sm_scale=_sm_scale if _sm_scale is not None
             else 1.0 / math.sqrt(D))
-        return out[..., :D], lse
+        return out[..., :Dv], lse
     sm_scale = _sm_scale if _sm_scale is not None else 1.0 / math.sqrt(D)
     qt, kt, vt = _flat_layout(q, k, v)
     out, lse = _flash_lse(qt, kt, vt, causal, sm_scale)
-    return (out.reshape(B, Hq, S, D).transpose(0, 2, 1, 3),
+    return (out.reshape(B, Hq, S, Dv).transpose(0, 2, 1, 3),
             lse.reshape(B, Hq, S))
 
 
@@ -685,6 +705,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``key_padding_mask``: optional [B, S] boolean (True = attend to that
     key) — BERT-style padding masks; carried through the kernel as an
     additive key bias in the same sublane-replicated layout as the LSE.
+    ``v`` may be ``[B, S, H, Dv]`` with ``Dv != D``: the output then has
+    the values' width.
     ``segment_ids``: optional [B, S] integer ids of contiguous packed
     sequences (causal only, exclusive with the padding mask): each query
     attends only within its own segment — block-diagonal causal attention
@@ -715,6 +737,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     rows (standard BERT practice masks them out of the loss).
     """
     B, S, Hq, D = q.shape
+    Dv = v.shape[-1]        # the values' own width (latent attention)
     if segment_ids is not None:
         if not causal:
             raise NotImplementedError(
@@ -724,14 +747,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
             raise NotImplementedError(
                 "segment_ids and key_padding_mask are mutually exclusive "
                 "(mark padding as its own trailing segment instead)")
-    if not _supported(S, D):
+    if not (_supported(S, D) and _supported(S, Dv)):
         qp, kp, vp = _pad_head_dim(q, k, v)  # see _pad_head_dim
         out = flash_attention(
             qp, kp, vp, causal=causal,
             key_padding_mask=key_padding_mask, segment_ids=segment_ids,
             _sm_scale=_sm_scale if _sm_scale is not None
             else 1.0 / math.sqrt(D))
-        return out[..., :D]
+        return out[..., :Dv]
     if S % 128 != 0:
         q, k, v, key_padding_mask, segment_ids = _pad_to_tile(
             q, k, v, causal, key_padding_mask, segment_ids)
@@ -752,10 +775,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
         bias = jnp.where(key_padding_mask, 0.0, -1e30).astype(jnp.float32)
         bias = jnp.broadcast_to(bias[:, None, :], (B, 8, S))
         out = _flash_biased(qt, kt, vt, bias, causal, sm_scale)
-    return out.reshape(B, Hq, S, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, Hq, S, Dv).transpose(0, 2, 1, 3)
 
 
-def flash_attention_fn(q, k, v, mask=None, **kwargs):
+def flash_attention_fn(q, k, v, mask=None, *, scale=None, **kwargs):
     """Adapter matching the model zoo's pluggable ``attention_fn``.
 
     ``mask`` follows the zoo's convention (broadcastable [B, 1, 1, S]
@@ -763,9 +786,11 @@ def flash_attention_fn(q, k, v, mask=None, **kwargs):
     mask the attention is bidirectional-masked (BERT semantics); without
     one it is causal (decoder semantics).  Richer mask structures
     (arbitrary [B, H, S, S]) are not supported by the kernel — use the
-    dense path for those."""
+    dense path for those.  ``scale`` multiplies the scores in place of
+    ``1 / sqrt(D)`` (latent attention's YaRN-corrected scale); ``v`` may
+    have a width of its own."""
     if mask is None:
-        return flash_attention(q, k, v, causal=True)
+        return flash_attention(q, k, v, causal=True, _sm_scale=scale)
     mask = jnp.asarray(mask)
     if mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1:
         key_mask = mask[:, 0, 0, :]
@@ -777,5 +802,5 @@ def flash_attention_fn(q, k, v, mask=None, **kwargs):
             "[B, 1, 1, S]); got shape " + str(mask.shape) + " — use the "
             "dense attention path for richer mask structures"
         )
-    return flash_attention(q, k, v, causal=False,
+    return flash_attention(q, k, v, causal=False, _sm_scale=scale,
                            key_padding_mask=key_mask.astype(bool))

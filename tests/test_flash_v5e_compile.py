@@ -145,3 +145,111 @@ def test_forward_call_refuses_a_32k_row_as_before(one_chip):
     with pytest.raises(Exception, match="vmem"):
         jax.jit(lambda q, k, v: fa._flash(q, k, v, True, SM_SCALE)).lower(
             x, x, x).compile()
+
+
+# -- a value width of its own: latent attention's 192-wide keys, 128-wide values
+
+def _latent_shapes(one_chip, bh=64, s=4096):
+    wide = jax.ShapeDtypeStruct((bh, s, 192), jnp.bfloat16, sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((bh, s, 128), jnp.bfloat16,
+                                  sharding=one_chip)
+    return wide, narrow
+
+
+def test_forward_and_backward_at_192_and_128_are_two_mosaic_calls(one_chip):
+    """``[4 x 16 heads, 4096, 192 / 128]`` of ``deepseek-v2-lite`` at
+    4 x 4096 tokens: the same two calls, q, k, dq and dk 192 wide and v, o,
+    dO and dv 128 wide, nothing padded to a common width in HBM."""
+    wide, narrow = _latent_shapes(one_chip)
+    scale = 1.5896 * 192 ** -0.5
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            fa._flash(q, k, v, True, scale).astype(jnp.float32)
+            * w.astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(wide, wide, narrow, narrow).compile()
+    calls = _mosaic_calls(compiled)
+    assert len(calls) == 2, calls
+    forward, = (c for c in calls if scopes.FLASH_FWD in c)
+    backward, = (c for c in calls if scopes.FLASH_BWD in c)
+    assert "bf16[64,4096,128]" in forward.split(" custom-call(")[0]
+    result = backward.split(" custom-call(")[0]
+    assert result.count("bf16[64,4096,192]") == 2       # dq, dk
+    assert result.count("bf16[64,4096,128]") == 1       # dv
+    assert _no_square_array(compiled, 4096)
+    assert [d.shape for d in jax.eval_shape(grads, wide, wide, narrow,
+                                            narrow)] == [
+        (64, 4096, 192), (64, 4096, 192), (64, 4096, 128)]
+
+
+def test_backward_limit_counts_both_widths_and_is_the_old_one_at_one_width():
+    """The limit the backward call states: at ``d_v == d_qk`` the number it
+    stated before it knew of two widths (30 MiB at [8192, 128], which
+    ``test_backward_call_fits_inside_a_decoders_step_at_8k`` holds the
+    compiled step to), and at 192 / 128 what its blocks at both widths
+    need (VMEM pads 192 lanes to 256)."""
+    assert fa._bwd_vmem_limit(8192, 128, 512, 512, 2, 0) == (
+        fa._bwd_vmem_limit(8192, 128, 512, 512, 2, 0, d_v=128)) == 2 * (
+        3 * 8192 * 128 * 2 + 4 * 512 * 128 * 2 + 2 * 8 * 8192 * 4
+        + 8192 * 128 * 4 + 4 * 512 * 512 * 4)
+    assert fa._bwd_vmem_limit(4096, 192, 512, 512, 2, 0, d_v=128) == 2 * (
+        4096 * (2 * 256 + 128) * 2 + 2 * 512 * (256 + 128) * 2
+        + 2 * 8 * 4096 * 4 + 4096 * 256 * 4 + 4 * 512 * 512 * 4)
+
+
+def test_latent_decoders_step_compiles_with_its_calls_inside_their_limit(
+        one_chip):
+    """One dense and one routed layer at ``deepseek-v2-lite``'s widths (8 of
+    64 experts held), 1 x 4096 tokens, each layer recomputed with the flash
+    output kept: a forward and a backward flash call a layer, the backward
+    ones inside the limit they state, and the routed layer's grouped
+    products as XLA:TPU's own Mosaic calls (``ragged-dot``: forward,
+    recomputed and the four gradient products, eight a routed layer), which
+    carry no scope of the program's."""
+    import flax.linen as nn
+
+    from horovod_tpu.models.llama import (LlamaConfig, LlamaModel,
+                                          YarnScaling)
+    from horovod_tpu.ops.losses import balance_loss
+
+    config = LlamaConfig(
+        vocab_size=12800, hidden_size=2048, num_layers=2, num_heads=16,
+        num_kv_heads=16, intermediate_size=10944, max_seq_len=4096,
+        rms_eps=1e-6, num_experts=64, experts_per_token=6, held_experts=8,
+        moe_intermediate_size=1408, shared_experts=2, first_dense_layers=1,
+        norm_topk_prob=False, attention_kind="latent", kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling=YarnScaling(40, 4096, 32, 1, 0.707, 0.707),
+        remat="layer_keep_attention")
+    model = LlamaModel(config, attention_fn=fa.flash_attention_fn)
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        nn.meta.unbox(jax.eval_shape(
+            LlamaModel(config).init, jax.random.key(0),
+            jnp.zeros((1, 8), jnp.int32))))
+
+    def loss(params, tokens):
+        logits, sown = model.apply(params, tokens, mutable=["losses"])
+        logits = logits.astype(jnp.float32)
+        return (jnp.mean(jax.nn.logsumexp(logits, -1) - logits[..., 0])
+                + 0.001 * balance_loss(sown))
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+    calls = _mosaic_calls(compiled)
+    flash = [c for c in calls if "hvd.flash." in c]
+    assert sum(scopes.FLASH_FWD in c for c in flash) == 2
+    assert sum(scopes.FLASH_BWD in c for c in flash) == 2
+    used = [int(n) for call in flash if scopes.FLASH_BWD in call
+            for n in re.findall(r'"used_scoped_memory_configs":\[\{[^}]*'
+                                r'"size":"(\d+)"', call)]
+    assert used and max(used) <= fa._bwd_vmem_limit(
+        4096, 192, 512, 512, 2, 0, d_v=128)
+    grouped = [c for c in calls if c not in flash]
+    names = [re.search(r'op_name="([^"]*)"', c).group(1) for c in grouped]
+    assert all(n.startswith(scopes.RAGGED_DOT_PREFIX) for n in names), names
+    assert sum(n == "ragged-dot-none" for n in names) == 8
+    # No score matrix: the only [.., 4096, 4096] is W_kvb's output, sixteen
+    # heads of 128 + 128.
+    assert not re.search(r"\[(\d+,)*16,4096,4096\]", compiled.as_text())
