@@ -432,8 +432,8 @@ def _rows_of_tokens(x, assignments, position, k):
     assignment ``assignments[r]`` (a token's k assignments are
     consecutive).  ``position [T * k]`` says where in the result each
     assignment's row is (outside ``[0, rows)``: nowhere), so the transpose
-    is a gather and a sum over a token's k too, where autodiff would
-    scatter-add row by row."""
+    gathers too, slot by slot into a float32 sum (``_rows_to_tokens``),
+    where autodiff would scatter-add and XLA:TPU scatters row by row."""
     return x[assignments // k]
 
 
@@ -451,15 +451,26 @@ _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 def _rows_to_tokens(rows, position, k, weights=None):
-    """``[T, H]`` in float32: each token's k rows of ``rows`` added up,
-    under ``weights [T, k]`` if given.  An assignment whose row is not
-    among ``rows`` adds nothing."""
+    """``[T, H]`` in float32: each token's k rows of ``rows`` added up in
+    slot order, under ``weights [T, k]`` if given.  An assignment whose row
+    is not among ``rows`` adds nothing.
+
+    Slot by slot: the j-th rows of all tokens are gathered (``[T, H]``, the
+    rows' dtype), cast, scaled and added to one float32 accumulator, which
+    XLA:TPU compiles to k gathers and one fusion that reads them.  No
+    ``[T * k, H]`` array exists: gathered whole and summed over a
+    ``[T, k, H]`` reshape, it was written in float32 with k = 6 padded to
+    8, 1.07 GB for 0.2 GB of rows that are there, and a pass took 5 ms of
+    which the gather was 0.7 (PERF.md §6, PR 33).  No scatter-add of the
+    rows either: XLA:TPU scatters them one by one (PR 32)."""
+    position = position.reshape(-1, k)
     there = (position >= 0) & (position < rows.shape[0])
-    taken = rows[jnp.clip(position, 0, rows.shape[0] - 1)]
     scale = there.astype(jnp.float32) if weights is None else jnp.where(
-        there, weights.reshape(-1), 0.0)
-    taken = taken.astype(jnp.float32) * scale[:, None]
-    return jnp.sum(taken.reshape(-1, k, rows.shape[-1]), axis=1)
+        there, weights, 0.0)
+    position = jnp.clip(position, 0, rows.shape[0] - 1)
+    return functools.reduce(jnp.add, (
+        rows[position[:, j]].astype(jnp.float32) * scale[:, j, None]
+        for j in range(k)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -522,7 +533,10 @@ class RoutedExperts(nn.Module):
     products (``jax.lax.ragged_dot``, group sizes the held experts' row
     counts) run over the rows that are there: XLA:TPU makes each a Mosaic
     call that visits only tiles that hold rows.  Each token then takes its
-    rows back by the inverse permutation and adds them up under its gates.
+    rows back by the inverse permutation and adds them up under its gates:
+    slot by slot, K gathers of ``[T, H]`` into a float32 sum, and so does
+    the gradient that comes back to the tokens (``_rows_to_tokens``; no
+    ``[T, K, H]`` tensor and no scatter, PERF.md §6, PR 33).
     The buffer's worst case is T * K rows, every choice of every token held
     here; a chip that holds 8 of 64 experts expects an eighth of that, and
     gathers, elementwise passes and residuals over the dead seven eighths

@@ -592,3 +592,113 @@ def test_as_many_row_buffers_run_as_there_are_rows_for(case, buffers):
     for g, w in zip(jax.tree.leaves(got_grads["params"]),
                     jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+
+# -- rows back to their tokens, slot by slot ----------------------------------
+
+def _slots(seed, tokens, k, rows):
+    """``position [tokens * k]`` over a buffer of ``rows`` rows: token 0 has
+    all its k slots in the buffer and token 1 none, the others some; the
+    slots that are not lie below 0, at ``rows`` and beyond."""
+    rng = np.random.default_rng(seed)
+    position = rng.integers(0, rows, size=(tokens, k))
+    outside = np.array([-rows - 1, -3, -1, rows, rows + 1, 4 * rows])
+    away = rng.random((tokens, k)) < 0.5
+    away[0] = False
+    position = np.where(away, rng.choice(outside, size=(tokens, k)), position)
+    position[1] = outside[:k]
+    return position.reshape(-1).astype(np.int32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("k", [1, 2, 6])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["gates", "liveness"])
+def test_rows_to_tokens_is_the_plain_sum_over_a_tokens_slots(weighted, k,
+                                                             dtype):
+    """``y[t] = sum_j scale[t, j] rows[position[t, j]]`` over the slots whose
+    position is in ``[0, rows)``, written out in numpy in float64 from the
+    rows as they are (a bf16 row cast up is exact)."""
+    tokens, n_rows, hidden = 37, 23, 16
+    rng = np.random.default_rng(k)
+    # On a grid of sixteenths, so that bf16 holds them and a float32 sum of
+    # six is exact.
+    rows = jnp.asarray(np.round(rng.normal(size=(n_rows, hidden)) * 16) / 16,
+                       dtype)
+    if dtype == jnp.float32:
+        rows = rows + jnp.asarray(rng.normal(size=rows.shape), dtype) * 1e-3
+    position = _slots(k, tokens, k, n_rows)
+    weights = (jnp.asarray(rng.random((tokens, k)), jnp.float32)
+               if weighted else None)
+    got = jax.jit(llama._rows_to_tokens, static_argnums=2)(
+        rows, jnp.asarray(position), k, weights)
+    assert got.dtype == jnp.float32 and got.shape == (tokens, hidden)
+
+    plain = np.asarray(rows.astype(jnp.float32), np.float64)
+    scale = (np.ones((tokens, k)) if weights is None
+             else np.asarray(weights, np.float64))
+    want = np.zeros((tokens, hidden))
+    live = np.zeros(tokens, int)
+    for t in range(tokens):
+        for j in range(k):
+            p = position[t * k + j]
+            if 0 <= p < n_rows:
+                want[t] += scale[t, j] * plain[p]
+                live[t] += 1
+    assert live[0] == k and live[1] == 0 and not np.any(got[1])
+    if dtype == jnp.bfloat16 and not weighted:
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def _one_buffer(seed, tokens, k, first, chunk):
+    """What ``RoutedExperts`` hands the two functions for the buffer that
+    holds sorted rows ``first`` to ``first + chunk``: which assignment each
+    row is, and where in the buffer each assignment's row is."""
+    order = np.random.default_rng(seed).permutation(tokens * k)
+    inverse = np.argsort(order)
+    return (jnp.asarray(order[first:first + chunk], jnp.int32),
+            jnp.asarray(inverse - first, jnp.int32))
+
+
+@pytest.mark.parametrize("k", [1, 2, 6])
+def test_gradient_through_rows_of_tokens_is_autodiffs_of_the_gather(k):
+    tokens, hidden, first, chunk = 19, 8, 5, 11
+    assignments, position = _one_buffer(k, tokens, k, first, chunk)
+    x = jax.random.normal(jax.random.key(k), (tokens, hidden))
+    weight = jax.random.normal(jax.random.key(9), (chunk, hidden))
+    np.testing.assert_array_equal(
+        llama._rows_of_tokens(x, assignments, position, k),
+        x[assignments // k])
+    got = jax.grad(lambda x: jnp.sum(llama._rows_of_tokens(
+        x, assignments, position, k) * weight))(x)
+    want = jax.grad(lambda x: jnp.sum(x[assignments // k] * weight))(x)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("k", [1, 2, 6])
+def test_gradient_through_the_weighted_sum_is_autodiffs_of_the_scatter(k):
+    tokens, hidden, first, chunk = 19, 8, 5, 11
+    assignments, position = _one_buffer(k, tokens, k, first, chunk)
+    rows = jax.random.normal(jax.random.key(k), (chunk, hidden))
+    weights = jax.random.uniform(jax.random.key(7), (tokens, k))
+    weight = jax.random.normal(jax.random.key(9), (tokens, hidden))
+
+    def plain(rows, weights):
+        gated = rows * weights.reshape(-1)[assignments][:, None]
+        return jnp.zeros((tokens, hidden)).at[assignments // k].add(gated)
+
+    def ours(rows, weights):
+        return llama._weighted_rows_to_tokens(rows, weights, assignments,
+                                              position, k)
+
+    np.testing.assert_allclose(ours(rows, weights), plain(rows, weights),
+                               rtol=1e-6, atol=1e-6)
+    got = jax.grad(lambda *a: jnp.sum(ours(*a) * weight), argnums=(0, 1))(
+        rows, weights)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * weight), argnums=(0, 1))(
+        rows, weights)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
