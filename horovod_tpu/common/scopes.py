@@ -54,7 +54,23 @@ scope                 what falls under it
 ``hvd.moe.combine``   the rows back in token order, weighted with their
                       gates and added up over a token's choices
 ``hvd.moe.shared``    the shared experts' SwiGLU, which every chip computes
+``hvd.sparse.index``  learned sparse attention's indexer
+                      (``models/llama.py::SparseAttention``,
+                      ``ops/sparse_index.py``): its three projections and
+                      the rotation of its queries and its key, and the
+                      Mosaic call that forms the indexer's loss and the
+                      gradients of its queries, key and weights in one
+                      walk over the block pairs (index scores again, the
+                      attention heads' second q k^T for the target)
+``hvd.sparse.select`` the Mosaic call that forms a query block's index
+                      scores in VMEM, finds each query's ``topk``-th
+                      largest exactly and writes the selection as int8:
+                      the forward scores AND the top-k (they share the
+                      VMEM block, so they cannot be told apart)
 ====================  ====================================================
+
+The attention over the selected keys itself runs in the flash kernel's two
+calls, under ``hvd.flash.fwd`` and ``hvd.flash.bwd``.
 
 XLA:TPU executes ``jax.lax.ragged_dot`` -- forward, and both gradient
 products -- as Mosaic calls of its own, and names each by what it made, not
@@ -96,7 +112,11 @@ backward work by a ``transpose(`` component still counts them there.
 
 ``FLASH_OUT_NAME`` and ``FLASH_LSE_NAME`` are no scopes but
 ``checkpoint_name``s: the flash kernel's output and row statistics, for a
-recomputation policy that keeps them (``LlamaConfig.remat``).
+recomputation policy that keeps them (``LlamaConfig.remat``).  So are
+``SPARSE_SELECTED_NAME`` (the selection, its log-sum-exp and its counts)
+and ``SPARSE_INDEX_LOSS_NAME`` (the indexer's loss and the three gradients
+its walk left): kept, the recomputed forward neither scores nor selects nor
+walks again.
 """
 
 from __future__ import annotations
@@ -105,9 +125,10 @@ __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD",
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
-    "MOE_COMBINE", "MOE_SHARED", "RAGGED_DOT_PREFIX", "REMATTED",
-    "FLASH_OUT_NAME",
-    "FLASH_LSE_NAME", "TRAIN_STEP_PROGRAM", "allreduce_scope",
+    "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
+    "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
+    "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
+    "TRAIN_STEP_PROGRAM", "allreduce_scope",
 ]
 
 LOSS = "hvd.loss"
@@ -126,10 +147,14 @@ MOE_ROUTE = "hvd.moe.route"
 MOE_EXPERTS = "hvd.moe.experts"
 MOE_COMBINE = "hvd.moe.combine"
 MOE_SHARED = "hvd.moe.shared"
+SPARSE_INDEX = "hvd.sparse.index"
+SPARSE_SELECT = "hvd.sparse.select"
 RAGGED_DOT_PREFIX = "ragged-dot"     # XLA:TPU's own name for its calls
 REMATTED = "rematted_computation"    # JAX's own component, not a scope
 FLASH_OUT_NAME = "hvd.flash.out"
 FLASH_LSE_NAME = "hvd.flash.lse"
+SPARSE_SELECTED_NAME = "hvd.sparse.selected"
+SPARSE_INDEX_LOSS_NAME = "hvd.sparse.index_loss"
 
 #: The name JAX reports for the program ``make_train_step`` builds: in its
 #: monitoring events (``hvd.compile_log()``: tracing under this name,

@@ -22,7 +22,10 @@ qk_rope_head_dim`` wide, values ``v_head_dim`` wide, YaRN ``rope_scaling``),
 and ``num_experts`` > 1 makes the layers from ``first_dense_layers`` on
 routed ones: a router over all experts, ``held_experts`` of them held here
 (one chip's share under expert parallelism), shared experts beside them,
-rows sorted by expert with none dropped.  Both are training paths too:
+rows sorted by expert with none dropped.  ``attention_kind = "sparse"``
+makes it learned sparse attention over grouped-query heads (an indexer
+picks ``index_topk`` of each query's causal keys, ``ops/sparse_index.py``;
+docs/sparse_attention.md).  All three are training paths too:
 ``generation``, the serve plane and the pipelined step refuse them by name.
 """
 

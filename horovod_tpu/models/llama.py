@@ -43,7 +43,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
-from horovod_tpu.ops.losses import sequence_balance_loss
+from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
+from horovod_tpu.ops.sparse_index import index_loss, select_keys
 
 __all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "YarnScaling",
            "apply_rope", "causal_attention"]
@@ -57,6 +58,13 @@ REMAT_POLICIES = {
     # a layer at 8192 tokens x 2048), so its forward call is not repeated.
     "layer_keep_attention": checkpoint_policies.save_only_these_names(
         _scopes.FLASH_OUT_NAME, _scopes.FLASH_LSE_NAME),
+    # And what learned sparse attention found: the selection (int8, S x S a
+    # sequence), and the indexer's loss with the gradients its one walk
+    # left, so that the recomputed forward neither scores nor selects nor
+    # walks again.
+    "layer_keep_selection": checkpoint_policies.save_only_these_names(
+        _scopes.FLASH_OUT_NAME, _scopes.FLASH_LSE_NAME,
+        _scopes.SPARSE_SELECTED_NAME, _scopes.SPARSE_INDEX_LOSS_NAME),
 }
 
 
@@ -123,14 +131,22 @@ class LlamaConfig:
     ``remat`` names what the backward pass recomputes: ``"none"``;
     ``"layer"`` (each layer application keeps its input alone);
     ``"layer_keep_attention"`` (and the flash kernel's output and row
-    statistics).  Four passes hold four times one pass's activations,
-    so a looped model at a long sequence needs one of the last two.
+    statistics); ``"layer_keep_selection"`` (and a sparse layer's
+    selection and indexer loss).  Four passes hold four times one pass's
+    activations, so a looped model at a long sequence needs one of these.
 
     ``attention_kind`` is ``"full"`` (``LlamaAttention``) or ``"latent"``
     (``LatentAttention``, DeepSeek-V2's MLA): then ``kv_lora_rank``,
     ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim`` are the
     published keys, ``num_kv_heads`` is not read, and ``rope_scaling`` (a
     ``YarnScaling``) sets the rotary frequencies and the softmax scale.
+    ``"sparse"`` (``SparseAttention``, DeepSeek-V3.2-Exp's DSA over
+    grouped-query heads): an indexer of ``index_heads`` heads of
+    ``index_head_dim`` and one key a token picks ``index_topk`` of each
+    query's causal keys, the heads attend over those alone, and the
+    indexer learns from a loss of its own.  ``qk_norm`` puts an RMSNorm
+    with a learned scale over each head of q and k before the rotation
+    (full and sparse attention).
 
     ``num_experts`` > 1 makes every layer from ``first_dense_layers`` on a
     routed one (``RoutedExperts``): a router over all ``num_experts``,
@@ -141,8 +157,11 @@ class LlamaConfig:
     ``first_held_expert`` onwards (one chip's share under expert
     parallelism): only they have weights here, and what the absent ones
     would add to a token is left out.  ``norm_topk_prob`` renormalises a
-    token's gate weights to sum to one.  Generation, the serve plane and the pipelined step
-    refuse latent attention and routed layers by name.
+    token's gate weights to sum to one.  ``balance_over`` says over what
+    a routed layer's balance loss counts its assignments: each
+    ``"sequence"`` (DeepSeek-V2's ``seq_aux``) or the whole ``"batch"``.
+    Generation, the serve plane and the pipelined step refuse latent
+    attention, sparse attention and routed layers by name.
     """
 
     vocab_size: int = 32000
@@ -162,7 +181,13 @@ class LlamaConfig:
     shared_experts: int = 0
     first_dense_layers: int = 0
     norm_topk_prob: bool = True
+    balance_over: str = "sequence"
     attention_kind: str = "full"
+    attention_head_dim: int = 0   # 0: hidden_size / num_heads
+    qk_norm: bool = False
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -185,9 +210,17 @@ class LlamaConfig:
         if self.remat != "none" and self.remat not in REMAT_POLICIES:
             raise ValueError(f"remat is {self.remat!r}: 'none' or one of "
                              f"{sorted(REMAT_POLICIES)}")
-        if self.attention_kind not in ("full", "latent"):
+        if self.attention_kind not in ("full", "latent", "sparse"):
             raise ValueError(f"attention_kind is {self.attention_kind!r}: "
-                             f"'full' or 'latent'")
+                             f"'full', 'latent' or 'sparse'")
+        if self.attention_kind == "sparse" and not (
+                self.index_heads and self.index_head_dim
+                and self.index_topk):
+            raise ValueError("sparse attention needs index_heads, "
+                             "index_head_dim and index_topk")
+        if self.balance_over not in ("sequence", "batch"):
+            raise ValueError(f"balance_over is {self.balance_over!r}: "
+                             f"'sequence' or 'batch'")
         if self.attention_kind == "latent" and not (
                 self.kv_lora_rank and self.qk_nope_head_dim
                 and self.qk_rope_head_dim and self.v_head_dim):
@@ -216,7 +249,7 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attention_head_dim or self.hidden_size // self.num_heads
 
     @property
     def rope_dim(self) -> int:
@@ -240,6 +273,13 @@ class LlamaConfig:
                 f"(attention_kind='latent'): its cache would hold the "
                 f"{self.kv_lora_rank}-wide latent and the shared rotary "
                 f"key, and prefill and decode differ; not built")
+        if self.attention_kind == "sparse":
+            raise NotImplementedError(
+                f"{who} has no path for learned sparse attention "
+                f"(attention_kind='sparse'): its cache would hold the "
+                f"indexer's {self.index_head_dim}-wide key a token beside "
+                f"K and V, and a decode step would pick {self.index_topk} "
+                f"of the cached keys before it attends; not built")
         if self.num_experts > 1:
             raise NotImplementedError(
                 f"{who} has no path for routed experts (num_experts="
@@ -300,13 +340,17 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def causal_attention(q, k, v, *, q_offset: int = 0,
-                     scale: Optional[float] = None):
+                     scale: Optional[float] = None, selected=None):
     """Default causal attention, fp32 logits, GQA-aware.
 
     q: [B, Sq, Hq, D]; k: [B, Sk, Hkv, D]; v: [B, Sk, Hkv, Dv] with
     Hq % Hkv == 0.
     ``q_offset``: global position of q[0] (for decode / sequence shards).
     ``scale`` multiplies the scores in place of ``1 / sqrt(D)``.
+    ``selected [B, Sq, Sk]`` (nonzero = in) keeps those of a query's
+    causal keys alone, for every head, and makes the result ``(out, lse
+    [B, Hq, Sq])``, the log-sum-exp of each query's kept scores with no
+    gradient: the seam's contract for learned sparse attention.
     """
     B, Sq, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -319,12 +363,17 @@ def causal_attention(q, k, v, *, q_offset: int = 0,
         logits = logits * scale
     q_pos = jnp.arange(Sq) + q_offset
     k_pos = jnp.arange(k.shape[1])
-    mask = q_pos[:, None] >= k_pos[None, :]
-    logits = jnp.where(mask[None, None, None], logits,
-                       jnp.finfo(jnp.float32).min)
+    mask = (q_pos[:, None] >= k_pos[None, :])[None, None, None]
+    if selected is not None:
+        mask = mask & (selected != 0)[:, None, None]
+    logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(B, Sq, Hq, v.shape[-1])
+    out = out.reshape(B, Sq, Hq, v.shape[-1])
+    if selected is None:
+        return out
+    lse = jax.nn.logsumexp(logits, axis=-1).reshape(B, Hq, Sq)
+    return out, jax.lax.stop_gradient(lse)
 
 
 class LlamaAttention(nn.Module):
@@ -342,12 +391,75 @@ class LlamaAttention(nn.Module):
                      name="wk")(x).reshape(B, S, cfg.num_kv_heads, D)
         v = nn.Dense(cfg.num_kv_heads * D, use_bias=False, dtype=cfg.dtype,
                      name="wv")(x).reshape(B, S, cfg.num_kv_heads, D)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        out = self.attention_fn(q, k, v)
+        out = self.attend(x, q, k, v, cos, sin)
         out = out.reshape(B, S, cfg.num_heads * D)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="wo")(out)
+
+    def attend(self, x, q, k, v, cos, sin):
+        return self.attention_fn(q, k, v)
+
+
+class SparseAttention(LlamaAttention):
+    """Learned sparse attention over grouped-query heads: DeepSeek-V3.2-
+    Exp's DSA (its lightning indexer and its sparse training stage), as
+    Keye-VL-2.0's ``sa_config`` sizes it.
+
+    q, k and v as ``LlamaAttention`` makes them (``qk_norm`` and the
+    rotation included).  Beside them an indexer reads the same normed
+    state x WITHOUT a gradient back into it: n = ``index_heads`` queries
+    and one key a token, d = ``index_head_dim`` wide, rotated like q and k
+    by the first d / 2 of the layer's frequencies, and n weights::
+
+        q_j = W_iq x   k = W_ik x   w = W_iw x
+        I[t, s] = sum_j w[t, j] relu(q_j[t] . k[s])
+        S_t = the index_topk keys s <= t of largest I[t, s]
+        o[t, h] = sum_{s in S_t} softmax_{S_t}(q[t, h] . k[s, h // g]
+                                               / sqrt(D)) v[s, h // g]
+
+    ``ops/sparse_index.py`` finds S_t exactly (``select_keys``) and forms
+    the indexer's loss ``KL(mean over heads of the attention's
+    probabilities || softmax_{S_t}((n d)^(-1/2) I))`` with its gradients
+    (``index_loss``); ``attention_fn(q, k, v, selected=...)`` attends and
+    returns the row statistics the target needs.  The indexer's three
+    matrices learn from that loss alone (sown as ``index_losses/kl``; the
+    caller adds it to its loss), everything else from the caller's loss
+    alone: the selection has no gradient.  Also sown, where the caller
+    makes ``sparse_stats`` mutable: ``keys_taken [B, S]`` and ``selected
+    [B, S, S]``.
+    """
+
+    def attend(self, x, q, k, v, cos, sin):
+        cfg = self.config
+        B, S, _ = x.shape
+        n, d = cfg.index_heads, cfg.index_head_dim
+        scale = (n * d) ** -0.5
+        with jax.named_scope(_scopes.SPARSE_INDEX):
+            u = jax.lax.stop_gradient(x)
+            half = (cos[:, :d // 2], sin[:, :d // 2])
+            q_i = apply_rope(nn.Dense(
+                n * d, use_bias=False, dtype=cfg.dtype,
+                name="index_wq")(u).reshape(B, S, n, d), *half)
+            k_i = apply_rope(nn.Dense(
+                d, use_bias=False, dtype=cfg.dtype,
+                name="index_wk")(u)[:, :, None], *half)[:, :, 0]
+            w_i = nn.Dense(n, use_bias=False, dtype=jnp.float32,
+                           name="index_ww")(u.astype(jnp.float32))
+        selected, lse_i, taken = select_keys(q_i, k_i, w_i, cfg.index_topk,
+                                             scale=scale)
+        out, lse = self.attention_fn(q, k, v, selected=selected)
+        if not self.is_initializing():
+            self.sow("index_losses", "kl", index_loss(
+                q, k, lse, q_i, k_i, w_i, selected, lse_i,
+                sm_scale=q.shape[-1] ** -0.5, scale=scale))
+            self.sow("sparse_stats", "keys_taken", taken)
+            self.sow("sparse_stats", "selected", selected)
+        return out
 
 
 class SwiGLU(nn.Module):
@@ -594,8 +706,9 @@ class RoutedExperts(nn.Module):
             chunk = _row_chunk(T * K, held / E)
             n_chunks = -(-T * K // chunk)
             if not self.is_initializing():
-                self.sow("losses", "balance",
-                         sequence_balance_loss(scores, chosen))
+                balance = (batch_balance_loss if cfg.balance_over == "batch"
+                           else sequence_balance_loss)
+                self.sow("losses", "balance", balance(scores, chosen))
                 self.sow("moe_stats", "rows_per_expert", rows_per_expert)
                 self.sow("moe_stats", "rows_dropped",
                          jnp.maximum(n_rows - n_chunks * chunk, 0))
@@ -659,7 +772,8 @@ class RoutedExperts(nn.Module):
         return y
 
 
-ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention}
+ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention,
+                   "sparse": SparseAttention}
 
 
 class LlamaLayer(nn.Module):

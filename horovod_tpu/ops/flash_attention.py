@@ -65,7 +65,8 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.common import scopes as _scopes
 
 __all__ = ["flash_attention", "flash_attention_fn", "flash_attention_lse",
-           "flash_lse_supported", "fallback_count"]
+           "flash_attention_selected", "flash_lse_supported",
+           "fallback_count"]
 
 # Non-kernel-path observability: a production config losing a Pallas
 # kernel should not do so silently.  flash_attention itself pads any
@@ -124,12 +125,20 @@ def _seg_mask(scores, seg_start, ki, block_k):
     return jnp.where(k_pos >= seg_start[:, None], scores, -1e30)
 
 
+def _selected(scores, mask):
+    """Scores of the keys a selection leaves out go to -1e30.  A query's
+    block may hold none of its keys: the running max then stays where it
+    was (or at -1e30, and what that block added is wiped by the first block
+    that holds one, as for a padded key)."""
+    return jnp.where(mask.astype(jnp.int32) != 0, scores, -1e30)
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
-                block_k, bias_ref=None, seg_ref=None):
+                block_k, bias_ref=None, seg_ref=None, mask_ref=None):
     # q_ref: [block_q, D]; k_ref: [S, D]; v_ref: [S, Dv]; o_ref: [block_q, Dv];
     # bias_ref (optional): [8, S] additive key bias (0 valid / -1e30
     # masked), sublane-replicated like lse — key-padding masks for
@@ -139,6 +148,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
     # >= their segment start.  With the causal upper bound this yields
     # block-diagonal attention for PACKED sequences (row i attends
     # [seg_start[i], i]) without a [S, S] mask.
+    # mask_ref (optional): [block_q, S] int8, this query block's rows of a
+    # per-batch selection (nonzero = the key is in), shared by every head:
+    # learned sparse attention, whose mask is data and differs by query.
     qi = pl.program_id(1)
     block_q, d = q_ref.shape
     s = k_ref.shape[0]
@@ -191,6 +203,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
                                                     block_k)][None, :]
         if seg_start is not None:
             scores = _seg_mask(scores, seg_start, ki, block_k)
+        if mask_ref is not None:
+            scores = _selected(scores, mask_ref[:, pl.dslice(ki * block_k,
+                                                             block_k)])
         new_m = jnp.maximum(m, jnp.max(scores, axis=1))
         alpha = jnp.exp(m - new_m)
         p = jnp.exp(scores - new_m[:, None])
@@ -220,10 +235,17 @@ def _bias_spec(bias, bh, s):
     return pl.BlockSpec((None, 8, s), lambda b, i: (b // heads, 0, 0))
 
 
-def _extras(bh, s, bias, seg):
-    """(kwarg names, arrays, BlockSpecs) for the optional per-batch [B,8,S]
-    sidebands — additive key bias and/or per-query segment starts."""
+def _extras(bh, s, bias, seg, mask=None, mask_spec=None):
+    """(kwarg names, arrays, BlockSpecs) for the optional per-batch
+    sidebands: additive key bias and/or per-query segment starts, each
+    [B, 8, S], and a selection [B, S, S] int8 whose block the caller
+    gives (rows of a query block forward, columns of a key block
+    backward)."""
     names, arrays, specs = [], [], []
+    if mask is not None:
+        names.append("mask_ref")
+        arrays.append(mask)
+        specs.append(mask_spec)
     if bias is not None:
         names.append("bias_ref")
         arrays.append(bias)
@@ -252,25 +274,62 @@ def _with_extras(base_kernel, n_outs, names, **fixed):
     return kernel
 
 
-def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
-    # q, k: [BH, S, D]; v: [BH, S, Dv]; bias/seg (optional): [B, 8, S]
-    # sidebands.
+# What Mosaic gives a call that asks for nothing (v5e; no chip gives less).
+_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+
+
+def _kv_block(group, index):
+    """Index map of a key-value block for the grid's (query head, step):
+    ``index`` of the head's own block, at the key-value head its ``group``
+    of query heads shares (grouped-query attention reads K and V where
+    they are; nothing is repeated in HBM)."""
+    if group == 1:
+        return index
+    return lambda b, i: (b // group,) + index(b, i)[1:]
+
+
+def _fwd_vmem_limit(s, d, d_v, bq, bk, itemsize):
+    """``vmem_limit_bytes`` of the forward call that reads a selection:
+    K and V whole, the query block's ``[bq, S]`` int8 rows of the mask, q,
+    o and lse, each held twice by the pipeline, and the pair's live
+    ``[bq, bk]`` float32 arrays; never under the compiler's default."""
+    d, d_v = -(-d // 128) * 128, -(-d_v // 128) * 128
+    blocks = (s * (d + d_v) * itemsize + bq * s
+              + bq * (d + d_v) * itemsize + 8 * s * 4)
+    return max(_DEFAULT_SCOPED_VMEM, 2 * blocks + 6 * bq * bk * 4)
+
+
+def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None, mask=None):
+    # q: [BH, S, D]; k: [BHkv, S, D]; v: [BHkv, S, Dv] (BH a multiple of
+    # BHkv: query head b reads key-value head b // group); bias/seg
+    # (optional): [B, 8, S] sidebands; mask (optional): [B, S, S] int8.
     bh, s, d = q.shape
     dv = v.shape[-1]
+    group = bh // k.shape[0]
     bq = _pick_block(s, BLOCK_Q)
     bk = _pick_block(s, BLOCK_K)
     grid = (bh, s // bq)
-    names, arrays, bias_specs = _extras(bh, s, bias, seg)
+    mask_spec = None
+    params = {}
+    if mask is not None:
+        heads = bh // mask.shape[0]
+        mask_spec = pl.BlockSpec((None, bq, s),
+                                 lambda b, i: (b // heads, i, 0))
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_fwd_vmem_limit(s, d, dv, bq, bk,
+                                             q.dtype.itemsize))
+    names, arrays, bias_specs = _extras(bh, s, bias, seg, mask, mask_spec)
     kernel = _with_extras(_fwd_kernel, 2, names, causal=causal,
                           sm_scale=sm_scale, block_k=bk)
     inputs = (q, k, v, *arrays)
+    whole_row = _kv_block(group, lambda b, i: (b, 0, 0))
     call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, s, dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, s, d), whole_row),
+            pl.BlockSpec((None, s, dv), whole_row),
         ] + bias_specs,
         out_specs=[
             pl.BlockSpec((None, bq, dv), lambda b, i: (b, i, 0)),
@@ -281,6 +340,7 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
             jax.ShapeDtypeStruct((bh, 8, s), jnp.float32),
         ],
         interpret=_interpret(),
+        **params,
     )
     with jax.named_scope(_scopes.FLASH_FWD):
         out, lse = call(*inputs)
@@ -293,7 +353,7 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *, causal, sm_scale,
-                block_q, bias_ref=None, seg_ref=None):
+                block_q, bias_ref=None, seg_ref=None, mask_ref=None):
     # Grid (head, key block).  q_ref: [S, D] and do_ref: [S, Dv], the head's
     # whole row; k_ref, dk_ref: [block_k, D] and v_ref, dv_ref: [block_k, Dv],
     # this step's key block;
@@ -301,6 +361,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # stays in VMEM until the head changes; dq_acc: [S, D] fp32 scratch.
     # One walk over the live (query block, key block) pairs: each forms
     # scores, p, dp and ds once and feeds all three gradients.
+    # mask_ref (optional): [S, block_k] int8, this key block's columns of
+    # the selection the forward call read by rows.
     ki = pl.program_id(1)
     block_k, d = k_ref.shape
     s = q_ref.shape[0]
@@ -363,6 +425,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                                     block_k)][None, :]
         if seg_ref is not None:
             scores = _seg_mask(scores, seg_ref[0, rows], ki, block_k)
+        if mask_ref is not None:
+            scores = _selected(scores, mask_ref[rows, :])
         p = jnp.exp(scores - lse_blk[:, None])
         dv = dv + jax.lax.dot_general(
             p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
@@ -395,11 +459,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         each_query_block(write)
 
 
-# What Mosaic gives a call that asks for nothing (v5e; no chip gives less).
-_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
-
-
-def _bwd_vmem_limit(s, d, bq, bk, itemsize, n_sidebands, d_v=None):
+def _bwd_vmem_limit(s, d, bq, bk, itemsize, n_sidebands, d_v=None,
+                    masked=False):
     """``vmem_limit_bytes`` of the backward call, from the shapes it sees:
     twice the bytes of its blocks, its scratch and the pair's live fp32
     ``[bq, bk]`` arrays (scores, p, dp, ds), and never under the compiler's
@@ -416,15 +477,19 @@ def _bwd_vmem_limit(s, d, bq, bk, itemsize, n_sidebands, d_v=None):
     rows = s * (2 * d + d_v) * itemsize      # q, do in and dq out: whole rows
     key_blocks = 2 * bk * (d + d_v) * itemsize   # k, v in; dk, dv out
     stats = (2 + n_sidebands) * 8 * s * 4    # lse, delta, bias / seg
+    if masked:
+        stats += s * bk                      # the key block's int8 columns
     dq_acc = s * d * 4
     live = 4 * bq * bk * 4
     return max(_DEFAULT_SCOPED_VMEM,
                2 * (rows + key_blocks + stats + dq_acc + live))
 
 
-def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
+def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None,
+              mask=None):
     q, k, v, out, lse = res
     bh, s, d = q.shape
+    group = bh // k.shape[0]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)  # [BH, S]
     if g_lse is not None:
@@ -437,7 +502,13 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
                              + delta.shape[1:])
     bq = _pick_block(s, BLOCK_Q)
     bk = _pick_block(s, BLOCK_K)
-    names, bias_inputs, bias_specs = _extras(bh, s, bias, seg)
+    mask_spec = None
+    if mask is not None:
+        heads = bh // mask.shape[0]
+        mask_spec = pl.BlockSpec((None, s, bk),
+                                 lambda b, i: (b // heads, 0, i))
+    names, bias_inputs, bias_specs = _extras(bh, s, bias, seg, mask,
+                                             mask_spec)
 
     # The scratch ref follows the outputs, so it counts among them here.
     kernel = _with_extras(_bwd_kernel, 4, names, causal=causal,
@@ -447,16 +518,19 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
     def row(width):
         return pl.BlockSpec((None, s, width), lambda b, i: (b, 0, 0))
 
-    def key_block(width):
-        return pl.BlockSpec((None, bk, width), lambda b, i: (b, i, 0))
+    def key_block(width, group=1):
+        return pl.BlockSpec((None, bk, width),
+                            _kv_block(group, lambda b, i: (b, i, 0)))
 
     stat = pl.BlockSpec((None, 8, s), lambda b, i: (b, 0, 0))
     call = pl.pallas_call(
         kernel,
         grid=(bh, s // bk),
-        in_specs=[row(d), key_block(d), key_block(dv), row(dv), stat,
-                  stat] + bias_specs,
+        in_specs=[row(d), key_block(d, group), key_block(dv, group),
+                  row(dv), stat, stat] + bias_specs,
         out_specs=[row(d), key_block(d), key_block(dv)],
+        # dk and dv leave a query head at a time: the heads of a group add
+        # theirs up below.
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
@@ -467,11 +541,17 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_bwd_vmem_limit(
-                s, d, bq, bk, q.dtype.itemsize, len(names), d_v=dv)),
+                s, d, bq, bk, q.dtype.itemsize, len(names), d_v=dv,
+                masked=mask is not None)),
         interpret=_interpret(),
     )
     with jax.named_scope(_scopes.FLASH_BWD):
         dq, dk, dv = call(q, k, v, do, lse, delta, *bias_inputs)
+    if group > 1:
+        def over_group(x):
+            return jnp.sum(x.reshape(-1, group, *x.shape[1:]),
+                           axis=1, dtype=jnp.float32).astype(x.dtype)
+        dk, dv = over_group(dk), over_group(dv)
     return dq, dk, dv
 
 
@@ -503,16 +583,15 @@ _flash.defvjp(_flash_fwd, _bwd)
 
 def _flat_layout(q, k, v):
     """[B, S, H, D] -> the kernels' flat [B*H, S, D] operands, each with
-    its own D, GQA KV heads repeated to Hq (shared by both public entry
-    points)."""
-    B, S, Hq, _ = q.shape
-    Hkv = k.shape[2]
-    if Hkv != Hq:
-        k = jnp.repeat(k, Hq // Hkv, axis=2)
-        v = jnp.repeat(v, Hq // Hkv, axis=2)
+    its own D and its own H: with fewer key-value heads than query heads
+    (GQA) k and v stay ``[B*Hkv, S, D]`` and the calls' index maps send
+    query head h to key-value head h // (Hq / Hkv) (shared by the public
+    entry points)."""
+    B, S = q.shape[:2]
 
     def t(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * Hq, S, x.shape[-1])
+        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S,
+                                               x.shape[-1])
 
     return t(q), t(k), t(v)
 
@@ -651,6 +730,61 @@ def _flash_seg_bwd(causal, sm_scale, res, do):
 _flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_selected(q, k, v, mask, causal, sm_scale):
+    """Attention over the keys ``mask [B, S, S]`` (int8, nonzero = in)
+    selects for each query, the same for every head: ``(out, lse [BH, S])``.
+    The lse is for a consumer that does not differentiate it (the target of
+    an indexer's loss): its cotangent is dropped."""
+    out, lse = _fwd(q, k, v, causal, sm_scale, mask=mask)
+    return out, lse[:, 0, :]
+
+
+def _flash_selected_fwd(q, k, v, mask, causal, sm_scale):
+    out, lse = _fwd(q, k, v, causal, sm_scale, mask=mask)
+    out = checkpoint_name(out, _scopes.FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, _scopes.FLASH_LSE_NAME)
+    return (out, lse[:, 0, :]), (q, k, v, mask, out, lse)
+
+
+def _flash_selected_bwd(causal, sm_scale, res, cts):
+    import numpy as np
+
+    q, k, v, mask, out, lse = res
+    dq, dk, dv = _bwd_impl(causal, sm_scale, (q, k, v, out, lse), cts[0],
+                           mask=mask)
+    return dq, dk, dv, np.zeros(mask.shape, dtype=jax.dtypes.float0)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
+def flash_attention_selected(q, k, v, selected, *,
+                             _sm_scale: Optional[float] = None):
+    """Causal attention over a selection of each query's keys (learned
+    sparse attention): ``q [B, S, Hq, D]``, ``k``, ``v [B, S, Hkv, D]`` and
+    ``selected [B, S, S]`` int8, nonzero where query t takes key s (a
+    subset of s <= t that holds at least one key a query), shared by all
+    heads.  Returns ``(out [B, S, Hq, Dv], lse [B, Hq, S])``: the
+    log-sum-exp of each query's scores over ITS keys, with no gradient (an
+    indexer's loss forms its target from it).  The two calls of
+    ``flash_attention``, under the same scopes, each reading the selection
+    by blocks: no score or probability leaves VMEM.  A block pair above
+    the diagonal is skipped; one below it runs whatever it holds.
+    S % 128 == 0 and D % 64 == 0: the caller owns the layout."""
+    B, S, Hq, D = q.shape
+    if S % 128 or not (_supported(S, D) and _supported(S, v.shape[-1])):
+        raise ValueError(f"flash_attention_selected needs S % 128 == 0 and "
+                         f"head widths in whole tiles of 64; got S={S}, "
+                         f"D={D}, Dv={v.shape[-1]}")
+    sm_scale = _sm_scale if _sm_scale is not None else 1.0 / math.sqrt(D)
+    qt, kt, vt = _flat_layout(q, k, v)
+    out, lse = _flash_selected(qt, kt, vt, selected.astype(jnp.int8), True,
+                               sm_scale)
+    return (out.reshape(B, Hq, S, -1).transpose(0, 2, 1, 3),
+            jax.lax.stop_gradient(lse.reshape(B, Hq, S)))
+
+
 def _segment_starts(segment_ids):
     """[B, S] segment ids (contiguous runs) -> [B, S] int32 index of each
     position's segment start, via a cummax over run boundaries."""
@@ -778,7 +912,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return out.reshape(B, Hq, S, Dv).transpose(0, 2, 1, 3)
 
 
-def flash_attention_fn(q, k, v, mask=None, *, scale=None, **kwargs):
+def flash_attention_fn(q, k, v, mask=None, *, scale=None, selected=None,
+                       **kwargs):
     """Adapter matching the model zoo's pluggable ``attention_fn``.
 
     ``mask`` follows the zoo's convention (broadcastable [B, 1, 1, S]
@@ -788,7 +923,11 @@ def flash_attention_fn(q, k, v, mask=None, *, scale=None, **kwargs):
     (arbitrary [B, H, S, S]) are not supported by the kernel — use the
     dense path for those.  ``scale`` multiplies the scores in place of
     ``1 / sqrt(D)`` (latent attention's YaRN-corrected scale); ``v`` may
-    have a width of its own."""
+    have a width of its own.  ``selected [B, S, S]`` (the keys each query
+    of a causal decoder takes, ``flash_attention_selected``) makes the
+    result ``(out, lse)``."""
+    if selected is not None:
+        return flash_attention_selected(q, k, v, selected, _sm_scale=scale)
     if mask is None:
         return flash_attention(q, k, v, causal=True, _sm_scale=scale)
     mask = jnp.asarray(mask)

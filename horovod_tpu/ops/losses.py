@@ -52,7 +52,8 @@ import jax.numpy as jnp
 from horovod_tpu.common import scopes as _scopes
 
 __all__ = ["softmax_cross_entropy", "exit_log_distribution",
-           "expected_exit_loss", "sequence_balance_loss", "balance_loss"]
+           "expected_exit_loss", "sequence_balance_loss",
+           "batch_balance_loss", "balance_loss", "indexer_loss"]
 
 
 def _nll_impl(logits, targets):
@@ -241,6 +242,25 @@ def sequence_balance_loss(scores, chosen):
     share = jax.lax.stop_gradient(counts * (experts / (k * seq)))
     mean_score = jnp.mean(scores.astype(jnp.float32), axis=1)      # [B, E]
     return jnp.mean(jnp.sum(share * mean_score, axis=-1))
+
+
+def batch_balance_loss(scores, chosen):
+    """Batch-wise balance loss of one routed layer: ``E sum_e f[e] P[e]``
+    with f[e] the share of ALL the batch's assignments that chose expert e
+    and P[e] its mean router probability over the batch --
+    ``sequence_balance_loss`` of the batch as one sequence.  A sequence may
+    be lopsided as long as the batch is not."""
+    experts = scores.shape[-1]
+    return sequence_balance_loss(scores.reshape(1, -1, experts),
+                                 chosen.reshape(1, -1, chosen.shape[-1]))
+
+
+def indexer_loss(collection):
+    """Mean over the sparse-attention layers of what each sowed into the
+    ``index_losses`` collection: the indexers' loss, whose gradient reaches
+    the indexers' matrices alone."""
+    collection = collection.get("index_losses", collection)
+    return jnp.mean(jnp.stack(jax.tree.leaves(collection)))
 
 
 def balance_loss(collection):

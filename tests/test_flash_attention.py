@@ -481,6 +481,20 @@ def _two_call_bwd_impl(causal, sm_scale, res, do, bias=None, seg=None,
 
     q, k, v, out, lse = res
     bh, s, d = q.shape
+    # Grouped-query heads: the program's calls read a group's K and V where
+    # they are; this copy repeats them, and adds a group's dk and dv up as
+    # the program does, in float32.
+    group = bh // k.shape[0]
+    if group > 1:
+        dq, dk, dv = _two_call_bwd_impl(
+            causal, sm_scale, (q, jnp.repeat(k, group, axis=0),
+                               jnp.repeat(v, group, axis=0), out, lse),
+            do, bias=bias, seg=seg, g_lse=g_lse)
+
+        def over_group(x):
+            return jnp.sum(x.reshape(-1, group, *x.shape[1:]), axis=1,
+                           dtype=jnp.float32).astype(x.dtype)
+        return dq, over_group(dk), over_group(dv)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
