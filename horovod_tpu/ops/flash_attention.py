@@ -25,7 +25,11 @@ Design (FlashAttention-2 style):
 * causal masking is block-aware: block pairs entirely above the diagonal
   are skipped (the loop bound, not a mask), the diagonal block gets the
   intra-block triangle; packed rows skip the pairs before a segment's start
-  the same way;
+  the same way.  The pair at the diagonal's end of a causal walk (the last
+  key block of a query block, the first query block of a key block) is
+  straight-line code and the loop holds the others (``_walk``;
+  ``pair_counts``: 136 live pairs a head at S = 8192, 16 of them on the
+  diagonal; 36 / 8 at 4096; 10 / 4 at 2048);
 * the values may have a width of their own (``d_v`` != ``d_qk``: latent
   attention's 192-wide keys and 128-wide values): q, k, dq and dk are
   ``d_qk`` wide, v, o, dO and dv ``d_v`` wide, in the same two calls.
@@ -43,9 +47,10 @@ the compiler's default limit.
 
 On one v5e (197 TFLOP/s bf16), in the benchmark's decoder step (bf16,
 16 heads of 128, causal; PERF.md §5 keeps the current figures): the
-forward call runs at 57 % of the MXU's peak on its two products a kept pair
-at S = 8192 and 41 % at 2048; the backward call at 70 % and 57 % on its
-five (52 % and 42 % when two calls executed seven).
+forward call runs at 59 % of the MXU's peak on its two products a kept pair
+at S = 8192 and 46 % at 2048 (57 % and 41 % with the diagonal's pair inside
+the loop); the backward call at 71 % and 59 % on its five (70 % and 57 %
+so; 52 % and 42 % when two calls executed seven).
 """
 
 from __future__ import annotations
@@ -112,8 +117,45 @@ def _pick_block(s: int, cap: int) -> int:
     return 0
 
 
+def pair_counts(s: int, block_q: int, block_k: int, causal: bool = True):
+    """``(pairs, diagonal)`` of one head's row of ``s``: the block pairs the
+    two walks run, and those of them the diagonal crosses (S = 8192 at
+    512-row blocks: 136 / 16; 4096: 36 / 8; 2048: 10 / 4; not causal: every
+    pair, none crossed).  With equal blocks the crossed pairs are ``ki ==
+    qi``, the ones ``_walk`` takes out of the loop."""
+    n_q, n_k = s // block_q, s // block_k
+    if not causal:
+        return n_q * n_k, 0
+    pairs = diagonal = 0
+    for qi in range(n_q):
+        live = min(-(-(qi + 1) * block_q // block_k), n_k)
+        # Wholly under the diagonal: the block's last key is no later than
+        # the query block's first row.
+        under = min((qi * block_q + 1) // block_k, live)
+        pairs += live
+        diagonal += live - under
+    return pairs, diagonal
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _walk(first, end, body, carry, apart=None):
+    """``body`` over the block pairs ``first .. end - 1`` in ascending order.
+
+    ``apart`` names the end of a causal walk that lies on the diagonal
+    (``"last"`` key block of a query block, ``"first"`` query block of a key
+    block).  That pair is live whatever the segments (a token's segment
+    starts at or before it), so it runs as straight-line code after or before
+    the loop over the others: same pairs, same order, same sums, the
+    parent's bits.  On the v5e the pair costs less beside the loop than as
+    one more iteration of it (PERF.md section 6, PR 35)."""
+    if apart == "last":
+        return body(end - 1, jax.lax.fori_loop(first, end - 1, body, carry))
+    if apart == "first":
+        return jax.lax.fori_loop(first + 1, end, body, body(first, carry))
+    return jax.lax.fori_loop(first, end, body, carry)
 
 
 def _seg_mask(scores, seg_start, ki, block_k):
@@ -215,7 +257,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
             preferred_element_type=jnp.float32)
         return new_m, new_l, new_acc
 
-    m, l, acc = jax.lax.fori_loop(kv_first, n_kv_live, body, (m, l, acc))
+    m, l, acc = _walk(kv_first, n_kv_live, body, (m, l, acc),
+                      apart="last" if causal else None)
     o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     # log-sum-exp per row, consumed by the backward kernel.  lse_ref holds
     # the full row (TPU blocks must tile (8, 128)); write this q-block's
@@ -445,10 +488,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         return dk, dv
 
-    dk, dv = jax.lax.fori_loop(
+    dk, dv = _walk(
         first_q, n_q_live, body,
         (jnp.zeros((block_k, d), jnp.float32),
-         jnp.zeros((block_k, v_ref.shape[1]), jnp.float32)))
+         jnp.zeros((block_k, v_ref.shape[1]), jnp.float32)),
+        apart="first" if causal else None)
     dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 

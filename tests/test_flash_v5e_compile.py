@@ -54,6 +54,12 @@ def _mosaic_calls(compiled):
             if _MOSAIC_CALL.search(line)]
 
 
+def _used_scoped_vmem(calls):
+    """Bytes of scoped VMEM the compiler gave each of these calls."""
+    return [int(n) for call in calls for n in re.findall(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', call)]
+
+
 def _no_square_array(compiled, s):
     """No array whose two trailing dimensions are both the sequence."""
     return not re.search(rf"\[(\d+,)*{s},{s}\]", compiled.as_text())
@@ -76,6 +82,10 @@ def test_forward_and_backward_are_two_mosaic_calls_at_the_cells_shapes(
     assert sum(scopes.FLASH_FWD in c for c in calls) == 1
     assert sum(scopes.FLASH_BWD in c for c in calls) == 1
     assert _no_square_array(compiled, s)
+    # The forward call states no limit: the loop and the diagonal's pair
+    # beside it fit the default.
+    used = _used_scoped_vmem(c for c in calls if scopes.FLASH_FWD in c)
+    assert used and max(used) <= fa._DEFAULT_SCOPED_VMEM, used
 
 
 @pytest.mark.parametrize("sideband", [None, "bias", "seg"])
@@ -131,9 +141,7 @@ def test_backward_call_fits_inside_a_decoders_step_at_8k(one_chip, heads):
     calls = _mosaic_calls(compiled)
     assert len(calls) == 2 * config.num_layers
     assert sum(scopes.FLASH_BWD in call for call in calls) == config.num_layers
-    used = [int(n) for call in calls if scopes.FLASH_BWD in call
-            for n in re.findall(r'"used_scoped_memory_configs":\[\{[^}]*'
-                                r'"size":"(\d+)"', call)]
+    used = _used_scoped_vmem(c for c in calls if scopes.FLASH_BWD in c)
     assert used and max(used) <= fa._bwd_vmem_limit(
         8192, 2048 // heads, 512, 512, 2, 0)
 
@@ -241,9 +249,7 @@ def test_latent_decoders_step_compiles_with_its_calls_inside_their_limit(
     flash = [c for c in calls if "hvd.flash." in c]
     assert sum(scopes.FLASH_FWD in c for c in flash) == 2
     assert sum(scopes.FLASH_BWD in c for c in flash) == 2
-    used = [int(n) for call in flash if scopes.FLASH_BWD in call
-            for n in re.findall(r'"used_scoped_memory_configs":\[\{[^}]*'
-                                r'"size":"(\d+)"', call)]
+    used = _used_scoped_vmem(c for c in flash if scopes.FLASH_BWD in c)
     assert used and max(used) <= fa._bwd_vmem_limit(
         4096, 192, 512, 512, 2, 0, d_v=128)
     grouped = [c for c in calls if c not in flash]
