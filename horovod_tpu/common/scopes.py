@@ -67,6 +67,23 @@ scope                 what falls under it
                       largest exactly and writes the selection as int8:
                       the forward scores AND the top-k (they share the
                       VMEM block, so they cannot be told apart)
+``hvd.block.attn``    a layer's mixer block whole
+                      (``models/llama.py::LlamaLayer``): ``norm_attn``,
+                      the mixer (``LlamaAttention``, ``LatentAttention``
+                      or ``SparseAttention``: projections, QK-norm,
+                      rotation, the ``attention_fn`` call, ``wo``) and the
+                      residual add.  ``hvd.flash.*``, ``hvd.mla.latent``
+                      and ``hvd.sparse.*`` nest inside it
+``hvd.block.ffn``     a layer's feed-forward block whole: ``norm_mlp``,
+                      ``SwiGLU`` or ``RoutedExperts`` (``hvd.moe.*`` nest
+                      inside it) and the residual add
+``hvd.head``          what turns the stack's output into a loss: the final
+                      norm (with a looped model's exit gate, nested in
+                      ``hvd.loop.exit``), ``LlamaModel.head``'s product,
+                      the cross-entropy on its logits
+                      (``ops/losses.py``: ``softmax_cross_entropy``, and
+                      each exit's inside ``expected_exit_loss``) and
+                      their gradient products (see below)
 ====================  ====================================================
 
 The attention over the selected keys itself runs in the flash kernel's two
@@ -86,6 +103,22 @@ gradient packer that is gone.  The constants stay because the benchmark's
 reader (``benchmark/scopes.py``) looks them up; they go when a ``benchmark``
 PR retires ``fusion_pack_ms`` (ROADMAP.md D15), which still counts what an
 averaging all-reduce adds that is no collective, under ``hvd.allreduce.<a>``.
+
+The three block scopes are for the ordinary work, which flax's module names
+(``layer_3/attn/wq``, ``mlp/w_gate_up``, ``lm_head``) would otherwise leave
+for a reader to spell.  The norm and the residual add are INSIDE a block's
+scope on purpose: XLA fuses ``wo`` and ``w_down`` with the residual add and
+the next norm, and a fusion has one ``op_name``, so nothing of a layer may
+lie between the scopes.  With them inside, every operation under
+``hvd.loss`` that is no collective lies in exactly one of the three, or is
+the embedding's lookup and its scatter-add, the rotary tables, a looped
+model's exit distribution and its scan's own work, or what XLA hoists.
+(Read from v5e traces, PR 36: XLA:TPU names a matmul fusion by its
+``dot_general``, so every product counts in its own block, and what a norm's
+scope holds is the elementwise remainder.)  ``hvd.head`` is entered once on any path (the norm, the head's
+product and the loss each enter it side by side; the head's two gradient
+products in ``expected_exit_loss`` carry it from the forward they transpose),
+never ``hvd.head/hvd.head``.  ``benchmark/dense_scopes.py`` reads the three.
 
 The benchmark (``benchmark/scopes.py``) reads ``hvd.flash.fwd`` by name and
 every other ``hvd.flash.*`` scope as the backward pass, however many calls
@@ -126,6 +159,7 @@ __all__ = [
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD",
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
+    "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
     "TRAIN_STEP_PROGRAM", "allreduce_scope",
@@ -149,6 +183,9 @@ MOE_COMBINE = "hvd.moe.combine"
 MOE_SHARED = "hvd.moe.shared"
 SPARSE_INDEX = "hvd.sparse.index"
 SPARSE_SELECT = "hvd.sparse.select"
+BLOCK_ATTN = "hvd.block.attn"
+BLOCK_FFN = "hvd.block.ffn"
+HEAD = "hvd.head"
 RAGGED_DOT_PREFIX = "ragged-dot"     # XLA:TPU's own name for its calls
 REMATTED = "rematted_computation"    # JAX's own component, not a scope
 FLASH_OUT_NAME = "hvd.flash.out"

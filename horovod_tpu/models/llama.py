@@ -788,14 +788,18 @@ class LlamaLayer(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin):
         cfg = self.config
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_attn")(x)
-        x = x + ATTENTION_KINDS[cfg.attention_kind](
-            cfg, attention_fn=self.attention_fn, name="attn")(y, cos, sin)
-        y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_mlp")(x)
-        if cfg.is_routed(self.index):
-            x = x + RoutedExperts(cfg, name="moe")(y)
-        else:
-            x = x + SwiGLU(cfg, name="mlp")(y)
+        # Norm and residual add inside each block's scope: XLA fuses them
+        # with the neighbouring products (common/scopes.py).
+        with jax.named_scope(_scopes.BLOCK_ATTN):
+            y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_attn")(x)
+            x = x + ATTENTION_KINDS[cfg.attention_kind](
+                cfg, attention_fn=self.attention_fn, name="attn")(y, cos, sin)
+        with jax.named_scope(_scopes.BLOCK_FFN):
+            y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_mlp")(x)
+            if cfg.is_routed(self.index):
+                x = x + RoutedExperts(cfg, name="moe")(y)
+            else:
+                x = x + SwiGLU(cfg, name="mlp")(y)
         return x
 
 
@@ -852,14 +856,18 @@ class LlamaModel(nn.Module):
                            parent=mdl)(x)
 
         if cfg.total_ut_steps == 1:
-            return self.head(norm_f(self, one_pass(self, x)))
+            x = one_pass(self, x)
+            with jax.named_scope(_scopes.HEAD):
+                x = norm_f(self, x)
+            return self.head(x)
 
         def norm_and_gate(mdl, x):
-            x = norm_f(mdl, x)
-            gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate",
-                            kernel_init=nn.initializers.zeros,
-                            parent=mdl)(x.astype(jnp.float32))
-            return x, gate[..., 0]
+            with jax.named_scope(_scopes.HEAD):
+                x = norm_f(mdl, x)
+                gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate",
+                                kernel_init=nn.initializers.zeros,
+                                parent=mdl)(x.astype(jnp.float32))
+                return x, gate[..., 0]
 
         if cfg.remat != "none":
             # Else a pass keeps three float32 copies of its state for the
@@ -891,5 +899,6 @@ class LlamaModel(nn.Module):
         """Normalised hidden states ``[..., H]`` -> logits ``[..., V]``:
         the one output head, which every exit shares."""
         cfg = self.config
-        return nn.Dense(cfg.vocab_size, use_bias=False,
-                        dtype=cfg.logits_dtype, name="lm_head")(hidden)
+        with jax.named_scope(_scopes.HEAD):
+            return nn.Dense(cfg.vocab_size, use_bias=False,
+                            dtype=cfg.logits_dtype, name="lm_head")(hidden)

@@ -113,14 +113,15 @@ def softmax_cross_entropy(logits, targets, *, where=None,
         raise ValueError(f"unknown reduction {reduction!r}")
     # Reverse-mode only: the custom_vjp that keeps the residuals bf16
     # forfeits forward-mode AD (jax.jvp/jax.hessian over this op raise).
-    nll = _nll(logits, targets)
-    if where is not None:
-        nll = jnp.where(where, nll, 0.0)
-    if reduction == "sum":
-        return jnp.sum(nll)
-    if where is not None:
-        return jnp.sum(nll) / jnp.maximum(jnp.sum(where), 1)
-    return jnp.mean(nll)
+    with jax.named_scope(_scopes.HEAD):
+        nll = _nll(logits, targets)
+        if where is not None:
+            nll = jnp.where(where, nll, 0.0)
+        if reduction == "sum":
+            return jnp.sum(nll)
+        if where is not None:
+            return jnp.sum(nll) / jnp.maximum(jnp.sum(where), 1)
+        return jnp.mean(nll)
 
 
 def exit_log_distribution(gate_logits):
@@ -144,9 +145,12 @@ def _weighted_exit_nll(head, consts, hidden, weights, targets):
     """``sum_t sum_tok weights_t nll_t``, exit t's logits ``head(hidden_t,
     *consts)``: one head product an exit, and nothing else, where it is
     not differentiated."""
-    nll = jax.lax.map(
-        lambda h: _nll_impl(head(h, *consts), targets)[0], hidden)
-    return jnp.sum(weights * nll)
+    def exit_nll(h):
+        logits = head(h, *consts)
+        with jax.named_scope(_scopes.HEAD):
+            return _nll_impl(logits, targets)[0]
+
+    return jnp.sum(weights * jax.lax.map(exit_nll, hidden))
 
 
 def _weighted_exit_nll_fwd(head, consts, hidden, weights, targets):
@@ -163,11 +167,17 @@ def _weighted_exit_nll_fwd(head, consts, hidden, weights, targets):
         # loop, which costs that product 1.5 ms an exit at 8192 x 2048 x
         # 49152 on a v5e (PERF.md section 6, PR 31).
         h = jax.lax.optimization_barrier(h)
+        # ``head`` enters ``hvd.head`` itself (``LlamaModel.head``), and
+        # the pullback's products carry it from there: the scope is
+        # entered beside them, never around them.
         logits, pullback = jax.vjp(lambda h, c: head(h, *c), h, consts)
-        nll, lse = _nll_impl(logits, targets)
-        d_logits, _ = _nll_bwd((logits, targets, lse), w)
+        with jax.named_scope(_scopes.HEAD):
+            nll, lse = _nll_impl(logits, targets)
+            d_logits, _ = _nll_bwd((logits, targets, lse), w)
         dh, d_consts = pullback(d_logits)
-        return jax.tree.map(jnp.add, head_grads, d_consts), (dh, nll)
+        with jax.named_scope(_scopes.HEAD):
+            head_grads = jax.tree.map(jnp.add, head_grads, d_consts)
+        return head_grads, (dh, nll)
 
     head_grads, (dh, nll) = jax.lax.scan(
         exit_and_gradients, jax.tree.map(jnp.zeros_like, consts),

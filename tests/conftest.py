@@ -134,17 +134,31 @@ def pytest_configure(config):
         "also marked slow — the serve-fleet pushes are slow-marked)")
 
 
+# The accepted tests that pin their PR's entries as the LAST of every list
+# of ``BENCHMARK.json`` (ROADMAP.md D17), and the last cell and per-layer
+# metric each was written against.
+_MANIFEST_THEN = {
+    "test_benchmark_moe.py::test_the_manifests_new_entries":
+        ("deepseek-v2-lite.train-s4k", "mla_latent_ms"),
+    "test_benchmark_sparse.py::test_the_manifests_new_entries":
+        ("keye-vl-2.0-30b-a3b.train-s8k-b2", "index_loss_roofline"),
+}
+
+
 @pytest.fixture(autouse=True)
 def _manifest_as_its_test_knew_it(request, monkeypatch):
     """``tests/benchmark/test_benchmark_moe.py::test_the_manifests_new_
-    entries`` (PR 32) pins the ``deepseek-v2-lite`` entries as the LAST of
-    every list of ``BENCHMARK.json`` and the cells at six, and a PR that
-    adds a cell may neither edit that file nor put its entries anywhere
-    but last.  So that one test reads the manifest cut back to the entries
-    it was written against; every other test reads the file as it is."""
-    if not request.node.nodeid.endswith(
-            "test_benchmark_moe.py::test_the_manifests_new_entries"):
+    entries`` (PR 32) and its namesake in ``test_benchmark_sparse.py``
+    (PR 34) pin their PR's entries as the LAST of every list of
+    ``BENCHMARK.json`` and count the cells, and a later PR may neither
+    edit those files nor put its entries anywhere but last.  So each of
+    those tests reads the manifest cut back to the entries it was written
+    against; every other test reads the file as it is."""
+    then = next((cut for test, cut in _MANIFEST_THEN.items()
+                 if request.node.nodeid.endswith(test)), None)
+    if then is None:
         return
+    last_cell, last_metric = then
     from benchmark import manifest
 
     whole = manifest.load()
@@ -153,7 +167,7 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
         names = [entry["name"] for entry in entries]
         return entries[:names.index(last) + 1]
 
-    cells = upto(whole["workloads"], "deepseek-v2-lite.train-s4k")
+    cells = upto(whole["workloads"], last_cell)
     known = {cell["name"] for cell in cells}
 
     def cut(metrics, last):
@@ -162,10 +176,11 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
                          if "workloads" in m else {})}
                 for m in upto(metrics, last)]
 
-    then = {**whole, "configs": upto(whole["configs"], "deepseek-v2-lite"),
+    then = {**whole,
+            "configs": upto(whole["configs"], cells[-1]["config"]),
             "workloads": cells,
             "end_to_end": cut(whole["end_to_end"], "setup_s"),
-            "per_layer": cut(whole["per_layer"], "mla_latent_ms")}
+            "per_layer": cut(whole["per_layer"], last_metric)}
     real_cell = manifest.cell
     monkeypatch.setattr(manifest, "load", lambda: then)
     monkeypatch.setattr(manifest, "cell",
