@@ -73,13 +73,31 @@ scope                 what falls under it
                       largest exactly and writes the selection as int8:
                       the forward scores AND the top-k (they share the
                       VMEM block, so they cannot be told apart)
+``hvd.gdn.conv``      a gated delta-rule layer's way from its projections
+                      to the rule's q, k and v
+                      (``models/llama.py::GatedDeltaNet``): the three
+                      causal depthwise convolutions, their SiLU, the L2
+                      norms of q and k and q's scale
+``hvd.gdn.gates``     the same layer's gates: the two narrow projections,
+                      log alpha and beta in float32; and behind the rule
+                      the per-head RMSNorm of its output and the SiLU gate
+                      on it
+``hvd.gdn.scan``      the chunkwise rule itself
+                      (``ops/gated_delta.py::gated_delta_rule``): cutting
+                      the sequence into chunks, the products and the
+                      triangular solve of every chunk, the walk over the
+                      chunks that carries the state, forward, run again
+                      under recomputation and backward; Mosaic calls and
+                      XLA operations alike, should a later kernel replace
+                      part of it
 ``hvd.block.attn``    a layer's mixer block whole
                       (``models/llama.py::LlamaLayer``): ``norm_attn``,
-                      the mixer (``LlamaAttention``, ``LatentAttention``
-                      or ``SparseAttention``: projections, QK-norm,
-                      rotation, the ``attention_fn`` call, ``wo``) and the
-                      residual add.  ``hvd.flash.*``, ``hvd.rope``,
-                      ``hvd.mla.latent`` and ``hvd.sparse.*`` nest inside it
+                      the mixer (``LlamaAttention``, ``LatentAttention``,
+                      ``SparseAttention`` or ``GatedDeltaNet``:
+                      projections, QK-norm, rotation, the ``attention_fn``
+                      call or the rule, ``wo``) and the residual add.
+                      ``hvd.flash.*``, ``hvd.rope``, ``hvd.mla.latent``,
+                      ``hvd.sparse.*`` and ``hvd.gdn.*`` nest inside it
 ``hvd.block.ffn``     a layer's feed-forward block whole: ``norm_mlp``,
                       ``SwiGLU`` or ``RoutedExperts`` (``hvd.moe.*`` nest
                       inside it) and the residual add
@@ -165,6 +183,7 @@ __all__ = [
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD", "ROPE",
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
+    "GDN_CONV", "GDN_GATES", "GDN_SCAN",
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
@@ -190,6 +209,9 @@ MOE_COMBINE = "hvd.moe.combine"
 MOE_SHARED = "hvd.moe.shared"
 SPARSE_INDEX = "hvd.sparse.index"
 SPARSE_SELECT = "hvd.sparse.select"
+GDN_CONV = "hvd.gdn.conv"
+GDN_GATES = "hvd.gdn.gates"
+GDN_SCAN = "hvd.gdn.scan"
 BLOCK_ATTN = "hvd.block.attn"
 BLOCK_FFN = "hvd.block.ffn"
 HEAD = "hvd.head"

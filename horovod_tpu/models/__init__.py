@@ -25,8 +25,14 @@ routed ones: a router over all experts, ``held_experts`` of them held here
 rows sorted by expert with none dropped.  ``attention_kind = "sparse"``
 makes it learned sparse attention over grouped-query heads (an indexer
 picks ``index_topk`` of each query's causal keys, ``ops/sparse_index.py``;
-docs/sparse_attention.md).  All three are training paths too:
-``generation``, the serve plane and the pipelined step refuse them by name.
+docs/sparse_attention.md).  ``layer_types`` names the mixer a layer:
+``"linear_attention"`` is a ``GatedDeltaNet`` (short causal convolutions,
+decay and beta gates, the chunkwise gated delta rule of
+``ops/gated_delta.py``) among ``"full_attention"`` layers, with OLMo 2's
+block (``norm_placement="post"``, ``qk_norm_over="all"``) and softmax
+layers that do not rotate (``rope_theta=None``).  All of these are
+training paths too: ``generation``, the serve plane and the pipelined step
+refuse them by name.
 """
 
 from horovod_tpu.models.mnist import MnistConvNet, MnistMLP

@@ -18,7 +18,9 @@ TPU-first design:
   substitutes a ppermute-ring blockwise kernel for sequence parallelism.
 * One layer stack whose layers take their mixer and their feed-forward
   by kind from the config: full attention or latent attention (MLA,
-  ``attention_kind``); a dense SwiGLU or routed experts beside shared
+  ``attention_kind``), or, a layer at a time, a gated delta-rule
+  linear-attention mixer among softmax ones (``layer_types``,
+  ``GatedDeltaNet``); a dense SwiGLU or routed experts beside shared
   ones (``num_experts > 1``, after ``first_dense_layers`` dense layers).
   The routed layer is told which experts it holds, routes over all of
   them, gathers its own experts' rows sorted by expert -- none dropped --
@@ -43,6 +45,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.ops.gated_delta import (gated_delta_rule,
+                                         gated_delta_states)
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops.rope import rotate_pairs, rotates_in_place
 from horovod_tpu.ops.sparse_index import index_loss, select_keys
@@ -67,6 +71,8 @@ REMAT_POLICIES = {
         _scopes.FLASH_OUT_NAME, _scopes.FLASH_LSE_NAME,
         _scopes.SPARSE_SELECTED_NAME, _scopes.SPARSE_INDEX_LOSS_NAME),
 }
+
+LAYER_TYPES = ("full_attention", "linear_attention")
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -133,8 +139,11 @@ class LlamaConfig:
     ``"layer"`` (each layer application keeps its input alone);
     ``"layer_keep_attention"`` (and the flash kernel's output and row
     statistics); ``"layer_keep_selection"`` (and a sparse layer's
-    selection and indexer loss).  Four passes hold four times one pass's
-    activations, so a looped model at a long sequence needs one of these.
+    selection and indexer loss).  A linear-attention layer keeps its
+    input alone under each of them (its rule's chunk states are made again:
+    at the size they were built for there is no room to keep them, PERF.md
+    section 4).  Four passes hold four times one pass's activations, so a
+    looped model at a long sequence needs one of these.
 
     ``attention_kind`` is ``"full"`` (``LlamaAttention``) or ``"latent"``
     (``LatentAttention``, DeepSeek-V2's MLA): then ``kv_lora_rank``,
@@ -163,6 +172,24 @@ class LlamaConfig:
     ``"sequence"`` (DeepSeek-V2's ``seq_aux``) or the whole ``"batch"``.
     Generation, the serve plane and the pipelined step refuse latent
     attention, sparse attention and routed layers by name.
+
+    ``layer_types`` (the published key of hybrid stacks; None: every layer
+    is of ``attention_kind``) names the mixer a layer: ``"full_attention"``
+    is ``attention_kind``'s, ``"linear_attention"`` a ``GatedDeltaNet``
+    (the gated delta rule, ``ops/gated_delta.py``) of
+    ``linear_num_key_heads`` heads of ``linear_key_head_dim`` for q and k
+    and ``linear_num_value_heads`` of ``linear_value_head_dim`` for v, a
+    causal depthwise convolution of ``linear_conv_kernel_dim`` taps before
+    each, and beta in (0, 2) where ``linear_allow_neg_eigval`` (else in
+    (0, 1)).  ``norm_placement`` is ``"pre"`` (each sublayer reads the
+    normed state: ``x + Mixer(Norm(x))``) or ``"post"`` (OLMo 2's: the norm
+    is on each sublayer's OUTPUT inside the residual, ``x +
+    Norm(Mixer(x))``).  ``qk_norm_over`` says what ``qk_norm`` normalises:
+    each ``"head"`` of q and k (a ``[head_dim]`` scale) or ``"all"`` heads
+    of a token together (OLMo 2's: a ``[heads * head_dim]`` scale).
+    ``rope_theta`` None: the softmax layers do not rotate (position comes
+    from the linear layers' recurrence and convolutions).  Generation, the
+    serve plane and the pipelined step refuse all four by name too.
     """
 
     vocab_size: int = 32000
@@ -172,7 +199,7 @@ class LlamaConfig:
     num_kv_heads: int = 8
     intermediate_size: int = 11008
     max_seq_len: int = 8192
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0     # None: no rotation
     rms_eps: float = 1e-5
     num_experts: int = 1          # >1 enables routed layers
     experts_per_token: int = 2
@@ -186,6 +213,15 @@ class LlamaConfig:
     attention_kind: str = "full"
     attention_head_dim: int = 0   # 0: hidden_size / num_heads
     qk_norm: bool = False
+    qk_norm_over: str = "head"
+    norm_placement: str = "pre"
+    layer_types: Optional[tuple] = None
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -233,6 +269,28 @@ class LlamaConfig:
                 f"experts {self.first_held_expert} to "
                 f"{self.first_held_expert + self.experts_held - 1} are not "
                 f"among {self.num_experts}")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"norm_placement is {self.norm_placement!r}: "
+                             f"'pre' or 'post'")
+        if self.qk_norm_over not in ("head", "all"):
+            raise ValueError(f"qk_norm_over is {self.qk_norm_over!r}: "
+                             f"'head' or 'all'")
+        if self.layer_types is not None:
+            if (len(self.layer_types) != self.num_layers
+                    or set(self.layer_types) - set(LAYER_TYPES)):
+                raise ValueError(
+                    f"layer_types is {self.layer_types!r}: one of "
+                    f"{LAYER_TYPES} for each of {self.num_layers} layers")
+            if self.has_linear_layers and (
+                    not (self.linear_num_key_heads
+                         and self.linear_key_head_dim
+                         and self.linear_value_head_dim)
+                    or self.linear_num_value_heads
+                    % self.linear_num_key_heads):
+                raise ValueError(
+                    "linear attention needs linear_num_key_heads, "
+                    "linear_key_head_dim, linear_value_head_dim and "
+                    "linear_num_value_heads, a multiple of the key heads")
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -265,6 +323,15 @@ class LlamaConfig:
     def is_routed(self, layer: int) -> bool:
         return self.num_experts > 1 and layer >= self.first_dense_layers
 
+    @property
+    def has_linear_layers(self) -> bool:
+        return "linear_attention" in (self.layer_types or ())
+
+    def is_linear(self, layer: int) -> bool:
+        """Whether ``layer``'s mixer is the gated delta rule."""
+        return (self.layer_types is not None
+                and self.layer_types[layer] == "linear_attention")
+
     def refuse_new_kinds(self, who: str) -> None:
         """For the paths that keep a decoder layer of their own and have
         learned neither kind (ROADMAP.md D1): raise, naming the kind."""
@@ -286,6 +353,23 @@ class LlamaConfig:
                 f"{who} has no path for routed experts (num_experts="
                 f"{self.num_experts}): it runs a dense feed-forward in "
                 f"every layer")
+        if self.has_linear_layers:
+            raise NotImplementedError(
+                f"{who} has no path for gated delta-rule linear attention "
+                f"(layer_types holds 'linear_attention'): beside K and V "
+                f"its cache would hold a recurrent state of "
+                f"{self.linear_value_head_dim} x {self.linear_key_head_dim} "
+                f"a head and the convolutions' last "
+                f"{self.linear_conv_kernel_dim - 1} inputs, and a decode "
+                f"step would update them in place; not built")
+        if (self.norm_placement != "pre" or self.qk_norm_over != "head"
+                or self.rope_theta is None):
+            raise NotImplementedError(
+                f"{who} keeps a pre-norm layer of its own that rotates q "
+                f"and k and norms them a head (norm_placement="
+                f"{self.norm_placement!r}, qk_norm_over="
+                f"{self.qk_norm_over!r}, rope_theta={self.rope_theta!r} "
+                f"have no path there)")
 
 
 class RMSNorm(nn.Module):
@@ -419,12 +503,18 @@ class LlamaAttention(nn.Module):
                      name="wk")(x).reshape(B, S, cfg.num_kv_heads, D)
         v = nn.Dense(cfg.num_kv_heads * D, use_bias=False, dtype=cfg.dtype,
                      name="wv")(x).reshape(B, S, cfg.num_kv_heads, D)
-        if cfg.qk_norm:
+        if cfg.qk_norm and cfg.qk_norm_over == "all":
+            q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(
+                q.reshape(B, S, -1)).reshape(q.shape)
+            k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(
+                k.reshape(B, S, -1)).reshape(k.shape)
+        elif cfg.qk_norm:
             q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
-        in_place = _reads_in_place(self.attention_fn)
-        q = apply_rope(q, cos, sin, in_place=in_place)
-        k = apply_rope(k, cos, sin, in_place=in_place)
+        if cos is not None:
+            in_place = _reads_in_place(self.attention_fn)
+            q = apply_rope(q, cos, sin, in_place=in_place)
+            k = apply_rope(k, cos, sin, in_place=in_place)
         out = self.attend(x, q, k, v, cos, sin)
         out = out.reshape(B, S, cfg.num_heads * D)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
@@ -801,14 +891,179 @@ class RoutedExperts(nn.Module):
         return y
 
 
+def _short_convolution(x, taps):
+    """Causal depthwise convolution along the sequence, one filter a
+    channel and zero history before position 0: ``y[t] = sum_i taps[i] *
+    x[t - (K - 1) + i]``.  x ``[B, S, C]``, taps ``[K, C]``; float32 out.
+    K shifted multiply-adds, which XLA fuses into one pass."""
+    seq, k = x.shape[1], taps.shape[0]
+    x = x.astype(jnp.float32)
+    y = x * taps[k - 1]
+    for back in range(1, k):
+        y = y + jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :seq] * taps[
+            k - 1 - back]
+    return y
+
+
+def _over_heads(x, heads):
+    """For ``x [.., heads * d]``: each head's sum ``[.., heads]``, and the
+    function that spreads a value a head back over its d lanes.  Both are
+    products with the heads' 0/1 indicator ``[heads * d, heads]`` (exact in
+    float32 at ``highest``, and nothing beside the other products): a
+    reshape to ``[.., heads, d]`` where d is no multiple of the 128 lanes
+    (96, 192) has XLA:TPU relay the tensor, in float32, either side of
+    every reduction (PERF.md, PR 38)."""
+    width = x.shape[-1]
+    of_head = (jnp.arange(width)[:, None] // (width // heads)
+               == jnp.arange(heads)[None, :]).astype(jnp.float32)
+    precision = jax.lax.Precision.HIGHEST
+    return (jnp.matmul(x, of_head, precision=precision),
+            lambda a_head: jnp.matmul(a_head, of_head.T, precision=precision))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(2, 3))
+def _convolved(y, taps, heads, scale):
+    """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
+    head L2-normed and multiplied by ``scale`` where that is not None.
+    Under a checkpoint: the backward pass keeps y and makes the float32
+    values between again."""
+    out = nn.silu(_short_convolution(y, taps))
+    if scale is not None:
+        squares, spread = _over_heads(out * out, heads)
+        out = out * spread(scale * jax.lax.rsqrt(squares + 1e-6))
+    return out.astype(y.dtype)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _gated_norm(o, z, scale, heads, eps):
+    """``rms_norm(o) * scale * silu(z)``: o and z ``[B, S, heads * d_v]``,
+    o normed a head, ``scale [d_v]`` shared by the heads; float32 inside,
+    the dtype of z out, and under a checkpoint as ``_convolved``."""
+    o = o.astype(jnp.float32)
+    squares, spread = _over_heads(o * o, heads)
+    o = o * spread(jax.lax.rsqrt(squares * (heads / o.shape[-1]) + eps))
+    return (o * jnp.tile(scale, heads) * nn.silu(z.astype(jnp.float32))
+            ).astype(z.dtype)
+
+
+def _conv_taps_init(key, shape, dtype=jnp.float32):
+    """Uniform in +-1 / sqrt(K): a depthwise filter's fan-in is its K taps
+    (what ``torch.nn.Conv1d`` draws)."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log A`` with A uniform in (0, 16): Mamba-2's and Gated DeltaNet's
+    own default."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a step log-uniform in [0.001, 0.1] (the
+    same defaults): ``softplus(dt_bias)`` is that step."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
+                                    math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class GatedDeltaNet(nn.Module):
+    """The gated delta-rule linear-attention mixer (Gated DeltaNet; Yang,
+    Kautz, Hatamizadeh, arXiv:2412.06464) of a ``"linear_attention"``
+    layer.  A head, with x the block's input and ``*`` the causal depthwise
+    convolution of ``linear_conv_kernel_dim`` taps::
+
+        q = l2norm(silu(conv_q * (x W_q))) d_k^-1/2    k = l2norm(silu(conv_k * (x W_k)))
+        v = silu(conv_v * (x W_v))
+        beta_t  = c sigmoid(x_t W_b)      c = 2 where linear_allow_neg_eigval, else 1
+        alpha_t = exp(-exp(A_log) softplus(x_t W_a + dt_bias))        float32
+        S_t = alpha_t S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T    S_0 = 0
+        o_t = S_t q_t
+        y   = (RMSNorm(o) * silu(x W_g)) W_o        one learned [d_v] scale for all heads
+
+    The recurrence runs chunk by chunk (``ops/gated_delta.py``), its state
+    ``[d_v, d_k]`` a head in float32.  With more value heads than key
+    heads a key head serves ``value / key`` of them.  Parameters: ``wq wk
+    [H, key heads * d_k]``, ``wv wg [H, value heads * d_v]``, ``wa wb [H,
+    value heads]``, ``conv_q conv_k conv_v [K, channels]``, ``a_log
+    dt_bias [value heads]``, ``o_norm [d_v]``, ``wo``.
+
+    Sown where the caller makes ``gdn_stats`` mutable: ``alpha_mean``,
+    ``alpha_min``, ``beta_over_one`` (the share), ``state_max`` (the
+    largest |S| a chunk started from) and ``out_max`` (the largest |o|).
+    """
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        B, S, _ = x.shape
+        h_k, h_v = cfg.linear_num_key_heads, (cfg.linear_num_value_heads
+                                              or cfg.linear_num_key_heads)
+        d_k, d_v = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        taps = cfg.linear_conv_kernel_dim
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            name=name)(x)
+
+        def conv(y, name, heads, scale):
+            return _convolved(y, self.param(
+                name, _conv_taps_init, (taps, y.shape[-1])), heads, scale)
+
+        q, k, v = dense(h_k * d_k, "wq"), dense(h_k * d_k, "wk"), dense(
+            h_v * d_v, "wv")
+        z = dense(h_v * d_v, "wg")
+        with jax.named_scope(_scopes.GDN_CONV):
+            q = conv(q, "conv_q", h_k, d_k ** -0.5).reshape(B, S, h_k, d_k)
+            k = conv(k, "conv_k", h_k, 1.0).reshape(B, S, h_k, d_k)
+            v = conv(v, "conv_v", h_v, None).reshape(B, S, h_v, d_v)
+        with jax.named_scope(_scopes.GDN_GATES):
+            x32 = x.astype(jnp.float32)
+            a = nn.Dense(h_v, use_bias=False, dtype=jnp.float32,
+                         name="wa")(x32)
+            b = nn.Dense(h_v, use_bias=False, dtype=jnp.float32,
+                         name="wb")(x32)
+            a_log = self.param("a_log", _a_log_init, (h_v,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (h_v,))
+            g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+            beta = jax.nn.sigmoid(b) * (2.0 if cfg.linear_allow_neg_eigval
+                                        else 1.0)
+        with jax.named_scope(_scopes.GDN_SCAN):
+            if h_v != h_k:
+                q, k = (jnp.repeat(t, h_v // h_k, axis=2) for t in (q, k))
+            o = gated_delta_rule(q, k, v, g, beta)
+        if (self.is_mutable_collection("gdn_stats")
+                and not self.is_initializing()):
+            alpha = jnp.exp(g)
+            for name, value in (
+                    ("alpha_mean", jnp.mean(alpha)),
+                    ("alpha_min", jnp.min(alpha)),
+                    ("beta_over_one", jnp.mean(beta > 1.0)),
+                    ("state_max", jnp.max(jnp.abs(gated_delta_states(
+                        q, k, v, g, beta)))),
+                    ("out_max", jnp.max(jnp.abs(o.astype(jnp.float32))))):
+                self.sow("gdn_stats", name, value)
+        with jax.named_scope(_scopes.GDN_GATES):
+            o = _gated_norm(o.reshape(z.shape), z, self.param(
+                "o_norm", nn.initializers.ones, (d_v,)), h_v, cfg.rms_eps)
+        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                        name="wo")(o)
+
+
 ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention,
                    "sparse": SparseAttention}
 
 
 class LlamaLayer(nn.Module):
-    """Pre-norm mixer and pre-norm feed-forward, both residual; which
-    mixer and which feed-forward, the config says (``attention_kind``;
-    ``LlamaConfig.is_routed`` of the layer's ``index`` in the stack)."""
+    """A mixer and a feed-forward, both residual, each with one RMSNorm:
+    on the sublayer's input (``norm_placement`` ``"pre"``: ``x +
+    Mixer(Norm(x))``) or on its output inside the residual (``"post"``,
+    OLMo 2's: ``x + Norm(Mixer(x))``).  Which mixer and which
+    feed-forward, the config says of the layer's ``index`` in the stack:
+    ``LlamaConfig.is_linear`` (a ``GatedDeltaNet`` as ``"linear"``, else
+    ``attention_kind``'s as ``"attn"``) and ``LlamaConfig.is_routed``."""
 
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
@@ -817,18 +1072,29 @@ class LlamaLayer(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin):
         cfg = self.config
+        if cfg.is_linear(self.index):
+            mixer = GatedDeltaNet(cfg, name="linear")
+        else:
+            mixer = functools.partial(ATTENTION_KINDS[cfg.attention_kind](
+                cfg, attention_fn=self.attention_fn, name="attn"),
+                cos=cos, sin=sin)
+        if cfg.is_routed(self.index):
+            ffn = RoutedExperts(cfg, name="moe")
+        else:
+            ffn = SwiGLU(cfg, name="mlp")
+
+        def residual(x, sublayer, norm):
+            norm = RMSNorm(cfg.rms_eps, cfg.dtype, name=norm)
+            if cfg.norm_placement == "pre":
+                return x + sublayer(norm(x))
+            return x + norm(sublayer(x))
+
         # Norm and residual add inside each block's scope: XLA fuses them
         # with the neighbouring products (common/scopes.py).
         with jax.named_scope(_scopes.BLOCK_ATTN):
-            y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_attn")(x)
-            x = x + ATTENTION_KINDS[cfg.attention_kind](
-                cfg, attention_fn=self.attention_fn, name="attn")(y, cos, sin)
+            x = residual(x, mixer, "norm_attn")
         with jax.named_scope(_scopes.BLOCK_FFN):
-            y = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_mlp")(x)
-            if cfg.is_routed(self.index):
-                x = x + RoutedExperts(cfg, name="moe")(y)
-            else:
-                x = x + SwiGLU(cfg, name="mlp")(y)
+            x = residual(x, ffn, "norm_mlp")
         return x
 
 
@@ -865,9 +1131,11 @@ class LlamaModel(nn.Module):
         B, S = input_ids.shape
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      name="tok_emb")(input_ids)
-        cos, sin = rope_freqs(cfg.rope_dim, S, cfg.rope_theta,
-                              offset=positions_offset,
-                              scaling=cfg.rope_scaling)
+        cos = sin = None
+        if cfg.rope_theta is not None:
+            cos, sin = rope_freqs(cfg.rope_dim, S, cfg.rope_theta,
+                                  offset=positions_offset,
+                                  scaling=cfg.rope_scaling)
         layer_cls = LlamaLayer
         if cfg.remat != "none":
             layer_cls = nn.remat(LlamaLayer,

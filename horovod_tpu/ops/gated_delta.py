@@ -1,0 +1,294 @@
+"""The gated delta rule in its chunkwise form: the recurrent mixer of a
+Gated DeltaNet layer (Yang, Kautz, Hatamizadeh, arXiv:2412.06464) on the
+training path.
+
+A head keeps a state ``S`` (``[d_v, d_k]``, float32) and reads it with a
+query.  With ``alpha_t = exp(g_t)`` in (0, 1] the decay and ``beta_t`` the
+writing strength (in (0, 2) where negative eigenvalues are allowed)::
+
+    S_t = alpha_t S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T     S_0 = 0
+    o_t = S_t q_t
+
+Token by token that is 8192 dependent steps of rank-one updates.  Here the
+sequence is cut into chunks of ``CHUNK`` = 64 tokens (section 3 of the
+paper).  With ``gamma_i`` the log-decay summed from the chunk's start to
+its row i (so every decay below is ``exp(gamma_i - gamma_j)`` with i >= j,
+at most 1), ``S`` the state the chunk starts from and ``u_i = beta_i (v_i -
+alpha_i S_{i-1} k_i)`` the value a row really writes::
+
+    A[i, j] = beta_i exp(gamma_i - gamma_j) (k_i . k_j)       j < i, else 0
+    T       = (I + A)^-1                          the chunk's system, solved once
+    W = T (beta e^gamma K)     U0 = T (beta V)         U = U0 - W S^T
+    O  = (e^gamma Q) S^T + (M * Q K^T) U          M[i, j] = exp(gamma_i - gamma_j), j <= i
+    S' = e^{gamma_C} S + U^T (e^{gamma_C - gamma} K)
+
+Everything but the last three lines is the same for every chunk and runs
+for a slab of ``_SLAB`` = 8 chunks at once (``_prepare``); those three
+carry the state from chunk to chunk in float32 (``_walk``, a ``lax.scan``
+over the slab's chunks).  The rule is a ``custom_vjp`` (``_rule``): the
+forward pass keeps q, k, v, the gates and the state each chunk started
+from; the backward pass goes over the slabs in reverse, prepares a slab
+again, walks its chunks in reverse from those states (U made again), and
+sends the cotangents of what was prepared back through the preparation.
+No step of either walk is a single token.
+
+``T`` is the inverse of a unit lower-triangular matrix, formed exactly by
+block forward substitution in six merges, ``T <- T - T L_b T`` for blocks
+of b = 1, 2, .., 32 rows (``L_b``: the part of A under the diagonal of each
+pair of b-blocks), twelve 64 x 64 products in float32 at ``highest``
+precision and no series that could cancel (``_tril_inverse``; its
+transpose is ``-T^T dT T^T`` under the diagonal).  Cumulative log-decays
+and the solve stay in float32 whatever the inputs' dtype; the other
+products take their inputs in the dtype of q (bf16 on the training path)
+and accumulate in float32, and the state is cast to that dtype where a
+product reads it and carried in float32.
+
+All of it is ``jax.numpy``: XLA:TPU runs the products on the MXU and the
+scan as a ``while``.  What a Mosaic call would buy is in PERF.md (the tiles
+are 64 x 96 and 64 x 192 against the MXU's 128 x 128).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["CHUNK", "gated_delta_rule", "gated_delta_states"]
+
+CHUNK = 64
+_SLAB = 8              # chunks prepared together, then walked one by one
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _merge_masks(chunk: int):
+    """For b = 1, 2, 4, .. < chunk: where A lies under the diagonal of a
+    pair of b-blocks (rows of the pair's second block, columns of its
+    first)."""
+    rows = jnp.arange(chunk)[:, None]
+    cols = jnp.arange(chunk)[None, :]
+    b, masks = 1, []
+    while b < chunk:
+        masks.append(((rows // b) % 2 == 1) & (cols // b == rows // b - 1))
+        b *= 2
+    return masks
+
+
+def _tril_inverse_impl(a):
+    chunk = a.shape[-1]
+    t = jnp.broadcast_to(jnp.eye(chunk, dtype=a.dtype), a.shape)
+    for mask in _merge_masks(chunk):
+        lower = jnp.where(mask, a, 0.0)
+        t = t - jnp.matmul(t, jnp.matmul(lower, t, precision=_HIGHEST),
+                           precision=_HIGHEST)
+    return t
+
+
+@jax.custom_vjp
+def _tril_inverse(a):
+    """``(I + a)^-1`` for ``a [.., C, C]`` float32, zero on and above the
+    diagonal, C a power of two."""
+    return _tril_inverse_impl(a)
+
+
+def _tril_inverse_fwd(a):
+    t = _tril_inverse_impl(a)
+    return t, t
+
+
+def _tril_inverse_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    da = -jnp.matmul(tt, jnp.matmul(dt, tt, precision=_HIGHEST),
+                     precision=_HIGHEST)
+    return (jnp.tril(da, -1),)
+
+
+_tril_inverse.defvjp(_tril_inverse_fwd, _tril_inverse_bwd)
+
+
+def _dot(spec, x, y):
+    """A product on the MXU: inputs as they are, float32 out."""
+    return jnp.einsum(spec, x, y, preferred_element_type=jnp.float32)
+
+
+def _prepare(q, k, v, g, beta):
+    """What every chunk needs and no chunk's state enters.  q, k ``[N, B,
+    H, C, d_k]``, v ``[.., d_v]``, g and beta ``[N, B, H, C]`` float32.
+    Returns W, U0, P = M * Q K^T, e^gamma Q and e^{gamma_C - gamma} K (the
+    dtype of q) and e^{gamma_C} ``[N, B, H]`` (float32)."""
+    dtype = q.dtype
+    chunk = q.shape[-2]
+    gamma = jnp.cumsum(g, axis=-1)
+    last = gamma[..., -1:]
+    rows = jnp.arange(chunk)[:, None]
+    cols = jnp.arange(chunk)[None, :]
+    # exp of what is masked away never runs: above the diagonal the
+    # difference is positive and may overflow.
+    decay = jnp.exp(jnp.where(rows >= cols,
+                              gamma[..., :, None] - gamma[..., None, :],
+                              -jnp.inf))
+    a = jnp.where(rows > cols,
+                  beta[..., None] * decay * _dot("...id,...jd->...ij", k, k),
+                  0.0)
+    t = _tril_inverse(a).astype(dtype)
+    into = jnp.exp(gamma)
+    w = _dot("...ij,...jd->...id", t,
+             (k * (beta * into)[..., None]).astype(dtype)).astype(dtype)
+    u0 = _dot("...ij,...jd->...id", t,
+              (v * beta[..., None]).astype(dtype)).astype(dtype)
+    p = (decay * _dot("...id,...jd->...ij", q, k)).astype(dtype)
+    qg = (q * into[..., None]).astype(dtype)
+    kg = (k * jnp.exp(last - gamma)[..., None]).astype(dtype)
+    return w, u0, p, qg, kg, jnp.exp(last[..., 0])
+
+
+def _chunk_values(w, u0, state):
+    """U = U0 - W S^T, float32; ``state`` in the dtype of W."""
+    return u0.astype(jnp.float32) - _dot("bhck,bhvk->bhcv", w, state)
+
+
+def _walk(prepared, state):
+    """The chunks of one slab in order, from ``state`` (float32): the
+    state the slab leaves, O ``[n, B, H, C, d_v]`` and the state each chunk
+    started from ``[n, B, H, d_v, d_k]``, both in the dtype of W (what is
+    kept of a state is the rounding that the chunk's products read)."""
+
+    def step(state, chunk):
+        w, u0, p, qg, kg, a_last = chunk
+        read = state.astype(w.dtype)
+        u = _chunk_values(w, u0, read).astype(w.dtype)
+        o = _dot("bhck,bhvk->bhcv", qg, read) + _dot("bhcj,bhjv->bhcv", p, u)
+        new = (a_last[..., None, None] * state
+               + _dot("bhcv,bhck->bhvk", u, kg))
+        return new, (o.astype(w.dtype), read)
+
+    return jax.lax.scan(step, state, prepared)
+
+
+def _walk_back(prepared, states, d_o, d_state):
+    """The chunks of one slab in reverse.  A step gets the cotangent of
+    the state its chunk left and gives that of the state it started from;
+    U is made again from that state.  Returns the cotangent of the state
+    the slab started from and those of ``prepared``."""
+    dtype = d_o.dtype
+
+    def step(d_new, chunk):
+        w, u0, p, qg, kg, a_last, state, d_o = chunk
+        u = _chunk_values(w, u0, state).astype(dtype)
+        d_new_t = d_new.astype(dtype)
+        d_u = (_dot("bhcj,bhcv->bhjv", p, d_o)
+               + _dot("bhck,bhvk->bhcv", kg, d_new_t)).astype(dtype)
+        d_state = (a_last[..., None, None] * d_new
+                   + _dot("bhcv,bhck->bhvk", d_o, qg)
+                   - _dot("bhcv,bhck->bhvk", d_u, w))
+        return d_state, (
+            (-_dot("bhcv,bhvk->bhck", d_u, state)).astype(dtype),    # W
+            d_u,                                                    # U0
+            _dot("bhcv,bhjv->bhcj", d_o, u).astype(dtype),          # P
+            _dot("bhcv,bhvk->bhck", d_o, state).astype(dtype),      # e^g Q
+            _dot("bhcv,bhvk->bhck", u, d_new_t).astype(dtype),      # .. K
+            jnp.sum(d_new * state, axis=(-1, -2)))                  # e^g_C
+
+    return jax.lax.scan(step, d_state, (*prepared, states, d_o),
+                        reverse=True)
+
+
+def _slabs(x):
+    """``[N, ..] -> [N / n, n, ..]``: the chunks in slabs of n, at most
+    ``_SLAB``.  What ``_prepare`` makes beside its results (a dozen
+    ``[.., C, C]`` float32 arrays, float32 copies of k and v) is made a
+    slab at a time.  Eight chunks: at 30 heads such an array is 4 MB, and
+    on the v5e the rule at 8192 tokens takes 21.9 ms forward and backward
+    where slabs of 32 (16 MB an array, 0.5 GB more of temporaries) take
+    32.3 and all 128 chunks together would hold 1.5 GB; 2 to 8 read alike
+    (PERF.md, PR 38).  The slab batches chunks and changes no bit."""
+    n = math.gcd(x.shape[0], _SLAB)
+    return x.reshape(x.shape[0] // n, n, *x.shape[1:])
+
+
+def _rule_walk(q, k, v, g, beta):
+    """O and the chunks' starting states for chunked inputs, slab by slab:
+    a slab is prepared, then walked."""
+    _, batch, heads, _, d_k = q.shape
+
+    def slab(state, inputs):
+        return _walk(_prepare(*inputs), state)
+
+    _, (o, states) = jax.lax.scan(
+        slab, jnp.zeros((batch, heads, v.shape[-1], d_k), jnp.float32),
+        tuple(_slabs(x) for x in (q, k, v, g, beta)))
+    return (o.reshape(-1, *o.shape[2:]),
+            states.reshape(-1, *states.shape[2:]))
+
+
+@jax.custom_vjp
+def _rule(q, k, v, g, beta):
+    return _rule_walk(q, k, v, g, beta)[0]
+
+
+def _rule_fwd(q, k, v, g, beta):
+    o, states = _rule_walk(q, k, v, g, beta)
+    return o, (q, k, v, g, beta, states)
+
+
+def _rule_bwd(res, d_o):
+    """The slabs in reverse: a slab is prepared again (with what its
+    transpose needs), its chunks are walked in reverse from the states the
+    forward walk kept, and the cotangents of what was prepared go back
+    through the preparation to q, k, v and the gates."""
+    *inputs, states = res
+
+    def slab(d_state, xs):
+        *inputs, states, d_o = xs
+        prepared, pull = jax.vjp(_prepare, *inputs)
+        d_state, d_prepared = _walk_back(prepared, states, d_o, d_state)
+        return d_state, pull(d_prepared)
+
+    _, grads = jax.lax.scan(
+        slab, jnp.zeros(states.shape[1:], jnp.float32),
+        tuple(_slabs(x) for x in (*inputs, states, d_o)), reverse=True)
+    return tuple(x.reshape(-1, *x.shape[2:]) for x in grads)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def _chunked(x, chunk):
+    """``[B, S, H, ..] -> [N, B, H, C, ..]``."""
+    batch, seq, heads = x.shape[:3]
+    x = x.reshape(batch, seq // chunk, chunk, heads, *x.shape[3:])
+    return jnp.moveaxis(x, (1, 3), (0, 2))
+
+
+def _chunks(q, k, v, g, beta):
+    """The sequence cut into chunks, padded to whole ones with rows that
+    neither write (beta 0) nor decay (g 0)."""
+    pad = -q.shape[1] % CHUNK
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    return tuple(_chunked(x, CHUNK)
+                 for x in (q, k, v.astype(q.dtype), g, beta))
+
+
+def gated_delta_rule(q, k, v, g, beta):
+    """``o [B, S, H, d_v]`` of the recurrence above, in the dtype of v.
+
+    q, k ``[B, S, H, d_k]`` (as the rule reads them: normed and scaled by
+    the caller), v ``[B, S, H, d_v]``, ``g = log alpha <= 0`` and beta
+    ``[B, S, H]`` (taken to float32).  The state starts at zero and ends
+    with the sequence; a length that is no multiple of ``CHUNK`` is
+    padded."""
+    batch, seq, heads, d_v = v.shape
+    o = _rule(*_chunks(q, k, v, g, beta))
+    o = jnp.moveaxis(o, (0, 2), (1, 3))                  # [B, N, C, H, d_v]
+    return o.reshape(batch, -1, heads, d_v)[:, :seq].astype(v.dtype)
+
+
+def gated_delta_states(q, k, v, g, beta):
+    """The state each chunk started from, ``[N, B, H, d_v, d_k]`` in the
+    dtype of q: for counters and tests, no gradient of its own."""
+    return _rule_walk(*_chunks(q, k, v, g, beta))[1]

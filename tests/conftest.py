@@ -28,7 +28,8 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 # The tiny sizes at which tests/benchmark runs the jobs of the
-# ``deepseek-v2-lite`` and ``keye-vl-2.0-30b-a3b`` configurations on the CPU.  They belong beside
+# ``deepseek-v2-lite``, ``keye-vl-2.0-30b-a3b`` and ``olmo-hybrid-7b``
+# configurations on the CPU.  They belong beside
 # ``tests/benchmark/tiny_sizes.py``'s, whose table every test of that
 # directory reads by the job's name; the files there are the accepted
 # benchmark's, which a PR that adds a cell may not edit, so the entry is
@@ -80,6 +81,34 @@ TINY.setdefault("sparse_moe_lm", {
                           "reference": {"parameters": "initial",
                                         "loss_abs": 0.02,
                                         "grad_rel": 0.5}}},
+    "traffic": {"sequence": 256, "batch_per_chip": 2},
+})
+
+TINY.setdefault("hybrid_lm", {
+    # Hidden 128; three linear layers of 2 heads, keys 32 and values 64 wide
+    # (the published 1 : 2), 4 taps, and one softmax layer of 2 heads of 64
+    # that do not rotate: the published pattern, one period.
+    "config": {"hidden_size": 128, "num_attention_heads": 2,
+               "num_key_value_heads": 2, "head_dim": 64,
+               "linear_num_key_heads": 2, "linear_num_value_heads": 2,
+               "linear_key_head_dim": 32, "linear_value_head_dim": 64,
+               "intermediate_size": 256, "vocab_size": 512,
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up move too little to show in one second
+                          # on the CPU.
+                          "loss_must_fall": False,
+                          # bf16 at these widths: each normalisation's
+                          # backward (the post-norms, the L2 norms of q
+                          # and k) projects the signal's main part out and
+                          # leaves the rounding, 3.5 % behind the last
+                          # layer and 11 % behind the first; float32
+                          # through the same code agrees to 2e-3
+                          # (test_benchmark_reference.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
     "traffic": {"sequence": 256, "batch_per_chip": 2},
 })
 
@@ -142,6 +171,9 @@ _MANIFEST_THEN = {
         ("deepseek-v2-lite.train-s4k", "mla_latent_ms"),
     "test_benchmark_sparse.py::test_the_manifests_new_entries":
         ("keye-vl-2.0-30b-a3b.train-s8k-b2", "index_loss_roofline"),
+    "test_benchmark_dense.py::"
+    "test_the_four_entries_are_in_the_manifest_as_the_issue_put_them":
+        ("keye-vl-2.0-30b-a3b.train-s8k-b2", "dense_roofline"),
 }
 
 
