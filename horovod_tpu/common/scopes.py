@@ -77,7 +77,10 @@ scope                 what falls under it
                       to the rule's q, k and v
                       (``models/llama.py::GatedDeltaNet``): the three
                       causal depthwise convolutions, their SiLU, the L2
-                      norms of q and k and q's scale
+                      norms of q and k and q's scale: the ``jnp`` body, or
+                      ``ops/short_conv.py``'s Mosaic calls (forward, again
+                      under recomputation, backward) where the model's
+                      ``attention_fn`` reads its operands in place
 ``hvd.gdn.gates``     the same layer's gates: the two narrow projections,
                       log alpha and beta in float32; and behind the rule
                       the per-head RMSNorm of its output and the SiLU gate

@@ -45,6 +45,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.gated_delta import (gated_delta_rule,
                                          gated_delta_states)
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
@@ -895,7 +896,12 @@ def _short_convolution(x, taps):
     """Causal depthwise convolution along the sequence, one filter a
     channel and zero history before position 0: ``y[t] = sum_i taps[i] *
     x[t - (K - 1) + i]``.  x ``[B, S, C]``, taps ``[K, C]``; float32 out.
-    K shifted multiply-adds, which XLA fuses into one pass."""
+    K shifted multiply-adds.  XLA:TPU does NOT make one pass of them and
+    what follows: with the SiLU, the heads' norm and their gradients the
+    trace shows chains of float32 fusions over ``[B, S, C]``, 51 ms of a
+    594 ms step at 8192 x 11,520 channels (PERF.md, PR 38).  The one pass
+    is ``ops/short_conv.py``, which ``_convolved`` takes where it may; this
+    is the body of every other path, and the tests' yardstick."""
     seq, k = x.shape[1], taps.shape[0]
     x = x.astype(jnp.float32)
     y = x * taps[k - 1]
@@ -922,16 +928,32 @@ def _over_heads(x, heads):
 
 
 @functools.partial(jax.checkpoint, static_argnums=(2, 3))
-def _convolved(y, taps, heads, scale):
-    """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
-    head L2-normed and multiplied by ``scale`` where that is not None.
-    Under a checkpoint: the backward pass keeps y and makes the float32
-    values between again."""
+def _convolved_plain(y, taps, heads, scale):
+    """``_convolved`` in ``jnp``: any shape, any partitioning.  Under a
+    checkpoint: the backward pass keeps y and makes the float32 values
+    between again."""
     out = nn.silu(_short_convolution(y, taps))
     if scale is not None:
         squares, spread = _over_heads(out * out, heads)
         out = out * spread(scale * jax.lax.rsqrt(squares + 1e-6))
     return out.astype(y.dtype)
+
+
+def _convolved(y, taps, heads, scale, in_place=False):
+    """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
+    head L2-normed and multiplied by ``scale`` where that is not None.
+    ``in_place`` is for a mixer whose model's ``attention_fn`` reads its
+    operands where the projections left them (``_reads_in_place``): the
+    chain is then ``ops/short_conv.py``'s one Mosaic pass forward and one
+    backward, where the shape is one it takes.  Elsewhere the ``jnp`` body:
+    a Mosaic call is the caller's choice, as in ``apply_rope``.  Which body
+    a trace took, and why, ``short_conv.body_counts()`` says."""
+    why = (short_conv.why_not(y.shape, taps.shape, heads) if in_place
+           else short_conv.NOT_IN_PLACE)
+    short_conv.note_body(why)
+    if why is None:
+        return short_conv.short_conv(y, taps, heads, scale)
+    return _convolved_plain(y, taps, heads, scale)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
@@ -994,6 +1016,7 @@ class GatedDeltaNet(nn.Module):
     """
 
     config: LlamaConfig
+    in_place: bool = False      # the model's attention_fn reads in place
 
     @nn.compact
     def __call__(self, x):
@@ -1010,7 +1033,8 @@ class GatedDeltaNet(nn.Module):
 
         def conv(y, name, heads, scale):
             return _convolved(y, self.param(
-                name, _conv_taps_init, (taps, y.shape[-1])), heads, scale)
+                name, _conv_taps_init, (taps, y.shape[-1])), heads, scale,
+                self.in_place)
 
         q, k, v = dense(h_k * d_k, "wq"), dense(h_k * d_k, "wk"), dense(
             h_v * d_v, "wv")
@@ -1073,7 +1097,9 @@ class LlamaLayer(nn.Module):
     def __call__(self, x, cos, sin):
         cfg = self.config
         if cfg.is_linear(self.index):
-            mixer = GatedDeltaNet(cfg, name="linear")
+            mixer = GatedDeltaNet(
+                cfg, in_place=_reads_in_place(self.attention_fn),
+                name="linear")
         else:
             mixer = functools.partial(ATTENTION_KINDS[cfg.attention_kind](
                 cfg, attention_fn=self.attention_fn, name="attn"),

@@ -1,4 +1,10 @@
-"""Collective ops and compression."""
+"""Collective ops and compression.
+
+The kernels are modules of their own, imported where they are used:
+``flash_attention``, ``paged_attention``, ``sparse_index``, ``rope`` (the
+rotation as one Mosaic pass), ``short_conv`` (a linear-attention layer's
+convolution, SiLU and L2 norm as one Mosaic pass each way) and
+``gated_delta`` (the chunkwise gated delta rule, ``jax.numpy``)."""
 
 from horovod_tpu.ops.collective_ops import (
     Average,

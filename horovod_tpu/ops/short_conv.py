@@ -1,0 +1,539 @@
+"""A linear-attention layer's short convolution, its SiLU and its heads' L2
+norm as one Mosaic pass forward and one backward.
+
+``models/llama.py::GatedDeltaNet`` sends q, k and v through a causal
+depthwise convolution of a few taps, a SiLU and (q and k) a per-head L2 norm:
+elementwise work on a row and the rows just before it.  Written in ``jnp``
+(``llama.py::_convolved``'s plain body, which stays for every path that may
+hold no Mosaic call, and as the tests' yardstick) XLA:TPU runs it as chains
+of float32 fusions that write and read ``[B, S, channels]`` float32 arrays
+between them: 51 ms of a 594 ms step at 8192 x 11,520 channels, where the
+bytes of the bf16 tensors allow 5 (PERF.md, PR 38 and PR 40).  Here the
+chain crosses HBM once each way: ``short_conv`` is a ``jax.custom_vjp`` of
+two calls that read and write the ``[B, S, heads * d]`` tensors where the
+projections leave them, whatever d is (96 and 192 are no lane tiles: a
+block takes the whole last axis).
+
+**Forward.**  A grid over (batch row, block of rows).  A step holds a block
+of rows of y, all channels, and the sublane tile of rows just before it (the
+same operand a second time; zero before position 0 of every batch row).  In
+VMEM, in float32: K shifted multiply-adds (the shift is a sublane rotate of
+the block with its history in front), SiLU, each head's sum of squares,
+``rsqrt(. + 1e-6)``, the scale, one cast, one store.
+
+**A head's sum.**  Heads are runs of d lanes that straddle the 128-lane
+tiles, so a head's sum and its way back to the head's lanes are products
+with the heads' 0/1 indicator on the MXU, inside the body.  To float32
+rounding, not one bf16 pass: the float32 operand is cut into three bf16
+pieces (``_pieces``: 24 bits of mantissa between them), each piece's
+product with the 0/1 matrix is exact in the float32 accumulator, and the
+three are added.  Three passes where ``highest`` takes six.
+
+**Backward.**  The same walk.  It reads y with a tile of rows before AND
+after the block and the cotangent g with a tile after, makes ``c = conv(y)``,
+``s = silu(c)`` and the norm again for the block's rows and the K - 1 behind
+them, and with ``u = s n`` (n the head's ``rsqrt``)::
+
+    ds = scale n (g - u sum_head(g u))
+    dc = ds sigma(c) (1 + c (1 - sigma(c)))
+    dy[t] = sum_i taps[i] dc[t + K - 1 - i]          nothing from beyond the row's end
+    dtaps[i] = sum_t dc[t] y[t - (K - 1) + i]
+
+The taps' gradient is a ``[K, 8, channels]`` float32 output whose block stays
+put while the grid walks a batch row's blocks (eight partial sums a tap: the
+adds stay on the VPU; XLA adds the eight and the batch rows).  No float32
+array of the activations' shape is written to HBM in either call, and the
+residuals are y and the taps: what ``jax.checkpoint`` kept for the plain
+body.
+
+Which body a trace took is counted (``body_counts``), as
+``ops/flash_attention.py::layout_counts`` counts layouts.  A Mosaic call is
+the caller's choice (the partitioner cannot split one): ``GatedDeltaNet``
+asks for this pass only where the model's ``attention_fn`` reads its
+operands in place (``llama.py::_reads_in_place``), as for the rotation.
+Off-TPU the calls run in interpret mode.
+"""
+
+from __future__ import annotations
+
+import functools
+import threading
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["short_conv", "why_not", "body_counts", "note_body",
+           "NOT_IN_PLACE"]
+
+_LANES = 128
+_TILE = 8         # rows of a float32 sublane tile: the history the body uses
+_HALO = 16        # rows of the block that brings it: a bf16 sublane tile
+_GROUP = 16       # rows a step of a body's walk works on: divides every block
+_FWD_LANES = 1024  # lanes a step of the forward body's walk works on
+_BWD_LANES = 512   # and of the backward body's, which carries five arrays
+_EPS = 1e-6
+# Upper bound on a block of y in either call, counted at four bytes an element
+# (its float32 values are what the body works on): all channels of as many rows.
+_BLOCK_BYTES = 6 * 1024 * 1024
+_VMEM_LIMIT = 100 * 1024 * 1024
+
+# Why ``llama.py::_convolved`` took its plain body, by reason.
+NOT_IN_PLACE = "the attention_fn does not read its operands in place"
+_NO_ROW_BLOCK = "no block of rows divides the sequence"
+_TOO_MANY_TAPS = "more taps than a sublane tile of history holds"
+_NOT_WHOLE_HEADS = "the channels are not whole heads"
+
+_counts_lock = threading.Lock()
+_counts: dict = {}
+
+
+def body_counts() -> dict:
+    """``{"fused": n, "plain": {reason: n}}``: how many traced calls of
+    ``llama.py::_convolved`` took the Mosaic pass, and how many the
+    ``jnp`` body, by reason.  Process-global, counted once a TRACE."""
+    with _counts_lock:
+        plain = {why: n for why, n in _counts.items() if why is not None}
+        return {"fused": _counts.get(None, 0), "plain": plain}
+
+
+def note_body(why) -> None:
+    """Count one traced call: ``why`` is None for the Mosaic pass, else the
+    reason for the plain body."""
+    with _counts_lock:
+        _counts[why] = _counts.get(why, 0) + 1
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _pick_rows(s: int, width: int) -> int:
+    """The largest block of rows that divides ``s`` and keeps a block of y
+    under ``_BLOCK_BYTES``; 0 if none of the tiling's sizes divides it."""
+    for rows in (512, 256, 128, 64, 32, _GROUP):
+        if s % rows == 0 and rows * width * 4 <= _BLOCK_BYTES:
+            return rows
+    return 0
+
+
+def why_not(shape, taps_shape, heads: int):
+    """None where ``short_conv`` takes ``y`` of ``shape [B, S, channels]``
+    with ``taps_shape [K, channels]``, else the reason it does not."""
+    if len(shape) != 3 or shape[2] % heads or taps_shape[1] != shape[2]:
+        return _NOT_WHOLE_HEADS
+    if taps_shape[0] - 1 > _TILE:
+        return _TOO_MANY_TAPS
+    if not _pick_rows(shape[1], shape[2]):
+        return _NO_ROW_BLOCK
+    return None
+
+
+# -- what both bodies share ------------------------------------------------
+#
+# A body walks its block in pieces that stay in vector registers: a loop
+# over chunks of lanes, and inside it a loop over groups of _GROUP rows (a
+# bf16 sublane tile).  Written as operations on the whole block, every
+# intermediate is a block-sized array that the compiler stores and loads
+# again, and the ONE vector-store slot paces the pass (0.65 ms a forward
+# call of q's on the v5e where the pieces take 0.32: my chip runs, PR 40).
+
+def _each_chunk(width: int, lanes: int, chunk) -> None:
+    """``chunk(which lanes, how many)`` for every chunk of ``lanes`` lanes:
+    the whole ones in a loop (one copy of the body in the program, one trace
+    of it), what is left of the width beside it."""
+    whole = width // lanes
+    if whole == 1:
+        chunk(slice(0, lanes), lanes)
+    elif whole:
+        def step(at, carry):
+            chunk(pl.ds(pl.multiple_of(at * lanes, _LANES), lanes), lanes)
+            return carry
+
+        jax.lax.fori_loop(0, whole, step, 0)
+    if width % lanes:
+        chunk(slice(whole * lanes, width), width % lanes)
+
+
+_AFTER = "after"      # in place of a group's number: the rows after the block
+
+
+def _group(j, rows: int):
+    """The rows of group ``j`` (traced) of a block of ``rows``, or with
+    ``_AFTER`` the ``_HALO`` rows that follow the block in the backward
+    body's scratch."""
+    if j is _AFTER:
+        return pl.ds(rows, _HALO)
+    return pl.ds(pl.multiple_of(j * _GROUP, _GROUP), _GROUP)
+
+
+def _rows_of(ref, j, lanes):
+    return ref[_group(j, ref.shape[0]), lanes].astype(jnp.float32)
+
+
+def _shifted(prev, cur, k):
+    """``cur [m, L]`` and the ``_TILE`` rows before it: cur as it is and
+    moved down by 1 .. k - 1 rows, the rows before it moving in."""
+    ext = jnp.concatenate([prev, cur], axis=0)
+    # roll(x, b)[t] = x[t - b]: the row b before.
+    return [cur] + [pltpu.roll(ext, back, 0)[_TILE:] for back in range(1, k)]
+
+
+def _conv(seen, taps_ref, lanes):
+    """The causal convolution from ``_shifted``'s list."""
+    k = taps_ref.shape[0]
+    c = seen[0] * taps_ref[k - 1:k, lanes]
+    for back in range(1, k):
+        c = c + seen[back] * taps_ref[k - 1 - back:k - back, lanes]
+    return c
+
+
+def _pieces(x):
+    """float32 x as three bf16 pieces whose sum is x to float32 rounding."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, low
+
+
+def _sigmoid(c):
+    """``1 / (1 + exp(-c))`` to float32 rounding: the transcendental unit's
+    approximate reciprocal and two Newton steps on it.  Two, because the
+    interpreted call's approximate reciprocal is a bfloat16 quotient (nine
+    bits, 36 after two steps); on the v5e the second moves no digit of a
+    float32 result (PERF.md, PR 40).  The exact quotient costs a dozen
+    operations more (its special cases; the denominator here is in
+    [1, 1e35]), and ``tanh`` on that unit is good to five digits only."""
+    x = 1.0 + jnp.exp(-jnp.maximum(c, -80.0))
+    r = pl.reciprocal(x, approx=True)
+    for _ in range(2):
+        r = r * (2.0 - x * r)
+    return r
+
+
+def _head_sums(p_ref, first, to_head_ref):
+    """The heads' sums ``[n, Kp]`` of the float32 array whose three pieces
+    are ``p_ref[first:first + 3]``: each piece's product with the 0/1
+    indicator is exact in the float32 accumulator.  A head's sum arrives
+    three times, once a group of ``to_head_ref``'s columns."""
+    return sum(jnp.dot(p_ref[first + j], to_head_ref[...],
+                       preferred_element_type=jnp.float32) for j in range(3))
+
+
+def _over_lanes(a_head, to_lanes_ref, heads):
+    """``a_head [n, Kp]`` float32, a head's value in each of the three
+    groups of columns (as ``_head_sums`` leaves it), on the head's lanes
+    ``[n, C]``: group g keeps piece g of the value, so ONE product with the
+    indicator's transpose adds the three pieces up."""
+    hi, mid, low = _pieces(a_head)
+    group = jax.lax.broadcasted_iota(jnp.int32, a_head.shape, 1) // (
+        _group_width(heads))
+    pieces = jnp.where(group == 0, hi, jnp.where(group == 1, mid, low))
+    return jnp.dot(pieces, to_lanes_ref[...],
+                   preferred_element_type=jnp.float32)
+
+
+def _before(y_ref, before_ref, j, lanes, at_start):
+    """The ``_TILE`` rows before group j of the block: the end of group j - 1,
+    or for the block's first group the end of the rows that came with it
+    (zero at a batch row's start).  One expression for every group, so that
+    a walk is one loop with one copy of its body."""
+    inside = _rows_of(y_ref, jnp.maximum(j - 1, 0), lanes)[_TILE:]
+    outside = before_ref[:, lanes].astype(jnp.float32)[_HALO - _TILE:]
+    return jnp.where(j > 0, inside, jnp.where(at_start, 0.0, outside))
+
+
+def _for_groups(n_groups: int, group) -> None:
+    def step(j, carry):
+        group(j)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, step, 0)
+
+
+def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads):
+    # y_ref, o_ref: [rows, C]; before_ref: the _HALO rows before the block;
+    # taps_ref: [K, C] float32.  With a norm (heads is not None): scale_ref
+    # [1, 1] in SMEM (an operand, so that q's call and k's are one program),
+    # to_head_ref [C, Kp] and to_lanes_ref [Kp, C], the heads' 0/1 indicator
+    # (_indicators) and its transpose; scratch s_ref [rows, C] float32 and
+    # p_ref [3, rows, C] bf16.
+    rows, width = y_ref.shape
+    k = taps_ref.shape[0]
+    normed = heads is not None
+    if normed:
+        scale_ref, to_head_ref, to_lanes_ref, o_ref, s_ref, p_ref = rest
+    else:
+        o_ref, = rest
+    at_start = pl.program_id(1) == 0
+
+    def chunk(lanes, _):
+        def group(j):
+            prev = _before(y_ref, before_ref, j, lanes, at_start)
+            cur = _rows_of(y_ref, j, lanes)
+            c = _conv(_shifted(prev, cur, k), taps_ref, lanes)
+            s = c * _sigmoid(c)
+            here = _group(j, rows)
+            if not normed:
+                o_ref[here, lanes] = s.astype(o_ref.dtype)
+                return
+            s_ref[here, lanes] = s
+            for piece, part in enumerate(_pieces(s * s)):
+                p_ref[piece, here, lanes] = part
+
+        _for_groups(rows // _GROUP, group)
+
+    _each_chunk(width, _FWD_LANES, chunk)
+    if normed:
+        inv = scale_ref[0, 0] * jax.lax.rsqrt(
+            _head_sums(p_ref, 0, to_head_ref) + _EPS)
+        o_ref[...] = (s_ref[...] * _over_lanes(
+            inv, to_lanes_ref, heads)).astype(o_ref.dtype)
+
+
+def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
+                *rest, heads):
+    # As _fwd_kernel, with the _HALO rows after the block of y and of the
+    # cotangent g; dy_ref: [rows, C]; dtaps_ref: [K * _TILE, C] float32,
+    # eight partial sums a tap, the same block for every step of a batch
+    # row.  With a norm, scratch over the block's rows AND the group after
+    # them: p_ref [6, n, C] bf16 (the pieces of s s and of g s), norm_ref and
+    # back_ref [n, C] float32 (the two sums on their way back).
+    rows, width = y_ref.shape
+    k = taps_ref.shape[0]
+    groups = rows // _GROUP    # and then the rows after
+    normed = heads is not None
+    if normed:
+        (scale_ref, to_head_ref, to_lanes_ref, dy_ref, dtaps_ref,
+         p_ref, norm_ref, back_ref) = rest
+    else:
+        dy_ref, dtaps_ref = rest
+    i = pl.program_id(1)
+    at_start, at_end = i == 0, i == pl.num_programs(1) - 1
+
+    @pl.when(at_start)
+    def _():
+        dtaps_ref[...] = jnp.zeros_like(dtaps_ref)
+
+    def operands(j, lanes):
+        """Group j's rows of y, the tile of rows before them, and its rows
+        of g; ``_AFTER`` is the ``_HALO`` rows after the block, from where no
+        cotangent comes back at a batch row's end."""
+        if j is _AFTER:
+            cur = after_ref[:, lanes].astype(jnp.float32)
+            g = jnp.where(at_end, 0.0,
+                          g_after_ref[:, lanes].astype(jnp.float32))
+            return cur, _rows_of(y_ref, groups - 1, lanes)[_TILE:], g
+        return (_rows_of(y_ref, j, lanes),
+                _before(y_ref, before_ref, j, lanes, at_start),
+                _rows_of(g_ref, j, lanes))
+
+    if normed:
+        # With n the head's rsqrt and u = s n: sum_head(g u) = n sum_head(g
+        # s), so both sums are of products the first walk can form.
+        def chunk_sums(lanes, _):
+            def sums(j):
+                cur, prev, g = operands(j, lanes)
+                c = _conv(_shifted(prev, cur, k), taps_ref, lanes)
+                s = c * _sigmoid(c)
+                here = _group(j, rows)
+                for piece, part in enumerate(_pieces(s * s) + _pieces(g * s)):
+                    p_ref[piece, here, lanes] = part
+
+            _for_groups(groups, sums)
+            sums(_AFTER)
+
+        _each_chunk(width, _FWD_LANES, chunk_sums)
+        inv = jax.lax.rsqrt(_head_sums(p_ref, 0, to_head_ref) + _EPS)
+        norm_ref[...] = _over_lanes(inv, to_lanes_ref, heads)
+        back_ref[...] = _over_lanes(
+            inv * inv * inv * _head_sums(p_ref, 3, to_head_ref),
+            to_lanes_ref, heads)
+
+    def chunk(lanes, size):
+        def dc_of(j):
+            cur, prev, g = operands(j, lanes)
+            seen = _shifted(prev, cur, k)
+            c = _conv(seen, taps_ref, lanes)
+            sig = _sigmoid(c)
+            if normed:
+                here = _group(j, rows)
+                ds = scale_ref[0, 0] * (norm_ref[here, lanes] * g
+                                        - c * sig * back_ref[here, lanes])
+            else:
+                ds = g
+            return ds * sig * (1.0 + c * (1.0 - sig)), seen
+
+        def group(j, carry):
+            # From the block's last group to its first: dc of the rows
+            # behind a group is the carry.  dy[t] = sum_a taps[K-1-a] dc[t+a].
+            behind, sums = carry
+            dc, seen = dc_of(j)
+            ext = jnp.concatenate([dc, behind], axis=0)
+            dy = dc * taps_ref[k - 1:k, lanes]
+            for ahead in range(1, k):
+                # roll(x, n - a)[t] = x[t + a]: the row a behind.
+                dy = dy + pltpu.roll(ext, ext.shape[0] - ahead, 0)[
+                    :dc.shape[0]] * taps_ref[k - 1 - ahead:k - ahead, lanes]
+            dy_ref[_group(j, rows), lanes] = dy.astype(dy_ref.dtype)
+            # dtaps[K-1-b] = sum_t dc[t] y[t - b], eight partial sums a tap.
+            sums = tuple(
+                acc + (dc * y_back).reshape(-1, _TILE, size).sum(axis=0)
+                for acc, y_back in zip(sums, seen))
+            return dc[:_TILE], sums
+
+        carry = (dc_of(_AFTER)[0][:_TILE],
+                 (jnp.zeros((_TILE, size), jnp.float32),) * k)
+        _, sums = jax.lax.fori_loop(
+            0, groups, lambda t, carry: group(groups - 1 - t, carry), carry)
+        for back, acc in enumerate(sums):
+            at = (k - 1 - back) * _TILE
+            dtaps_ref[at:at + _TILE, lanes] += acc
+
+    _each_chunk(width, _BWD_LANES, chunk)
+
+
+# -- the two calls ---------------------------------------------------------
+
+def _group_width(heads: int) -> int:
+    """Columns a group of the indicator takes: the heads, to a multiple of
+    eight."""
+    return -(-heads // _TILE) * _TILE
+
+
+def _indicators(width: int, heads: int):
+    """The heads' 0/1 indicator three times side by side ``[width, Kp]``
+    (column ``g * _group_width + h`` is head h, for g in 0, 1, 2; Kp whole
+    lane tiles, the columns left over belong to no lane), and its transpose:
+    bf16.  Three times because a float32's three bf16 pieces then meet the
+    transpose in ONE product (``_over_lanes``); 30 heads are 96 columns of
+    one 128-lane tile, so the second and third copy cost nothing."""
+    group = _group_width(heads)
+    padded = -(-3 * group // _LANES) * _LANES
+    column = jnp.arange(padded)
+    head = jnp.where(column < 3 * group, column % group, -1)
+    to_head = (jnp.arange(width)[:, None] // (width // heads)
+               == head[None, :]).astype(jnp.bfloat16)
+    return to_head, to_head.T
+
+
+def _specs(rows: int, width: int, n_blocks: int):
+    """A block of rows, and the ``_HALO`` rows before and after it (the
+    sequence's first and last where there are none: the body masks them)."""
+    per = rows // _HALO
+    block = pl.BlockSpec((None, rows, width), lambda b, i: (b, i, 0))
+    before = pl.BlockSpec((None, _HALO, width), lambda b, i: (
+        b, jnp.maximum(i * per - 1, 0), 0))
+    after = pl.BlockSpec((None, _HALO, width), lambda b, i: (
+        b, jnp.minimum((i + 1) * per, n_blocks * per - 1), 0))
+    return block, before, after
+
+
+def _with_constants(operands, specs, taps, scale, heads, width):
+    """The operands every step sees whole: the taps in float32 and, with a
+    norm, the scale (a scalar in SMEM) and the two indicator matrices."""
+    def whole(x):
+        operands.append(x)
+        specs.append(pl.BlockSpec(x.shape, lambda b, i: (0, 0)))
+
+    whole(taps.astype(jnp.float32))
+    if heads is not None:
+        operands.append(scale.astype(jnp.float32).reshape(1, 1))
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        for indicator in _indicators(width, heads):
+            whole(indicator)
+
+
+# (Jits: a step traces each body once a shape, and lowers it once for the
+# forward pass, once for the recomputation and once backward, not once a
+# layer and call: a body is a few hundred operations, and 27 of them cost
+# the cell 10 s of set-up.  q's call and k's are one program: the scale is
+# an operand, and ``heads`` is None where there is no norm.  ``interpret``
+# is static, so the cached trace is of the mode asked for.)
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _forward(y, taps, scale, heads, interpret):
+    b, s, width = y.shape
+    rows = _pick_rows(s, width)
+    block, before, _ = _specs(rows, width, s // rows)
+    operands, specs = [y, y], [block, before]
+    _with_constants(operands, specs, taps, scale, heads, width)
+    scratch = [] if heads is None else [
+        pltpu.VMEM((rows, width), jnp.float32),
+        pltpu.VMEM((3, rows, width), jnp.bfloat16)]
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads),
+        grid=(b, s // rows),
+        in_specs=specs,
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _backward(y, taps, g, scale, heads, interpret):
+    b, s, width = y.shape
+    k = taps.shape[0]
+    rows = _pick_rows(s, width)
+    block, before, after = _specs(rows, width, s // rows)
+    operands, specs = [y, y, y, g, g], [block, before, after, block, after]
+    _with_constants(operands, specs, taps, scale, heads, width)
+    n = rows + _HALO                  # the block's rows and those after
+    scratch = [] if heads is None else [
+        pltpu.VMEM((6, n, width), jnp.bfloat16),
+        pltpu.VMEM((n, width), jnp.float32),
+        pltpu.VMEM((n, width), jnp.float32)]
+    dy, dtaps = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads),
+        grid=(b, s // rows),
+        in_specs=specs,
+        out_specs=[block, pl.BlockSpec((None, k * _TILE, width),
+                                       lambda b, i: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
+                   jax.ShapeDtypeStruct((b, k * _TILE, width), jnp.float32)],
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(*operands)
+    dtaps = dtaps.reshape(b, k, _TILE, width).sum(axis=(0, 2))
+    return dy, dtaps.astype(taps.dtype)
+
+
+def _norm_of(heads, scale):
+    """The two calls' ``scale`` and ``heads``: no heads where no norm."""
+    if scale is None:
+        return jnp.float32(0.0), None
+    return jnp.float32(scale), heads
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def short_conv(y, taps, heads, scale):
+    """``silu(taps * y)`` for ``y [B, S, heads * d]`` and ``taps [K, heads *
+    d]`` (``*`` the causal depthwise convolution, zero history before
+    position 0 of every batch row), each head L2-normed (``rsqrt(sum of
+    squares + 1e-6)``) and multiplied by ``scale`` where that is not None;
+    float32 inside, the dtype of y out.  One Mosaic call, and one for both
+    gradients; ``why_not`` says which shapes it takes."""
+    scale, heads = _norm_of(heads, scale)
+    return _forward(y, taps, scale, heads=heads, interpret=_interpret())
+
+
+def _short_conv_fwd(y, taps, heads, scale):
+    return short_conv(y, taps, heads, scale), (y, taps)
+
+
+def _short_conv_bwd(heads, scale, kept, g):
+    y, taps = kept
+    scale, heads = _norm_of(heads, scale)
+    return _backward(y, taps, g, scale, heads=heads, interpret=_interpret())
+
+
+short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)

@@ -22,6 +22,7 @@ from horovod_tpu.models import LlamaConfig, LlamaModel
 from horovod_tpu.models.llama import (GatedDeltaNet, _convolved,
                                       _short_convolution)
 from horovod_tpu.ops import gated_delta
+from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.flash_attention import flash_attention_fn
 from horovod_tpu.ops.gated_delta import (gated_delta_rule,
                                          gated_delta_states)
@@ -177,24 +178,40 @@ def test_bf16_inputs_keep_a_float32_state():
 
 # -- the mixer -----------------------------------------------------------------
 
-def test_convolution_has_no_history_before_position_zero():
-    x = jax.random.normal(jax.random.key(7), (1, 6, 3))
+@pytest.mark.parametrize("in_place, seq", [(False, 6), (True, 32)],
+                         ids=["jnp", "mosaic"])
+def test_convolution_has_no_history_before_position_zero(in_place, seq):
+    """On both of ``_convolved``'s bodies; the Mosaic pass (interpreted
+    here) wants whole blocks of 16 rows, so its sequence is two."""
+    x = jax.random.normal(jax.random.key(7), (1, seq, 3))
     taps = jax.random.normal(jax.random.key(8), (4, 3))
-    y = _short_convolution(x, taps)
-    np.testing.assert_allclose(y[0, 0], taps[3] * x[0, 0], rtol=1e-6)
+    if in_place:
+        assert short_conv.why_not(x.shape, taps.shape, 1) is None
+
+        def convolution(x, taps):
+            return _convolved(x, taps, 1, None, True)
+        first = jax.nn.silu
+    else:
+        convolution, first = _short_convolution, lambda c: c
+    y = convolution(x, taps)
+    np.testing.assert_allclose(y[0, 0], first(taps[3] * x[0, 0]), rtol=1e-5)
     np.testing.assert_allclose(
-        y[0, 1], taps[3] * x[0, 1] + taps[2] * x[0, 0], rtol=1e-6)
+        y[0, 1], first(taps[3] * x[0, 1] + taps[2] * x[0, 0]), rtol=1e-5,
+        atol=1e-6)
     np.testing.assert_allclose(
-        y[0, 2], taps[3] * x[0, 2] + taps[2] * x[0, 1] + taps[1] * x[0, 0],
-        rtol=1e-6)
+        y[0, 2], first(taps[3] * x[0, 2] + taps[2] * x[0, 1]
+                       + taps[1] * x[0, 0]), rtol=1e-5, atol=1e-6)
+    for t in (5, seq - 1):      # and, in place, across the blocks' seam
+        np.testing.assert_allclose(
+            y[0, t], first(sum(taps[i] * x[0, t - 3 + i] for i in range(4))),
+            rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(
-        y[0, 5], sum(taps[i] * x[0, 2 + i] for i in range(4)), rtol=1e-5)
-    np.testing.assert_allclose(y, olmo_hybrid.short_convolution(x, taps),
-                               rtol=1e-5, atol=1e-6)
+        y, first(olmo_hybrid.short_convolution(x, taps)), rtol=1e-5,
+        atol=1e-6)
     # Causal: a later token changes nothing before it.
-    later = _short_convolution(x.at[0, 4].add(1.0), taps)
+    later = convolution(x.at[0, 4].add(1.0), taps)
     np.testing.assert_array_equal(later[0, :4], y[0, :4])
-    unit = _convolved(x, taps, 1, 1.0)
+    unit = _convolved(x, taps, 1, 1.0, in_place)
     np.testing.assert_allclose(jnp.linalg.norm(unit, axis=-1), 1.0,
                                atol=1e-3)
 
