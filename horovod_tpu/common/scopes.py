@@ -32,6 +32,20 @@ scope                 what falls under it
                       ``[B, S, H * D]``) and the expansion of its two tables
                       to a head's lanes.  Inside ``hvd.block.attn``, beside
                       the flash calls and never under ``hvd.flash.*``
+``hvd.attn.window``   a sliding-window layer's attention
+                      (``models/llama.py::LlamaAttention`` where
+                      ``LlamaConfig.window_of`` gives the layer a window):
+                      the rotation of its q and k and the ``attention_fn``
+                      call over the band, so ``hvd.rope`` and the two
+                      ``hvd.flash.*`` calls nest inside it, forward, run
+                      again under recomputation and backward.  Inside
+                      ``hvd.block.attn``; a full-attention layer does not
+                      enter it
+``hvd.attn.gate``     the per-head output gate (``gating="per-head"``): the
+                      ``[hidden, heads]`` projection, the sigmoid, the
+                      gate's way to its head's lanes and the multiply on
+                      the attention's output, and their gradients.  Inside
+                      ``hvd.block.attn``, in full and sliding layers alike
 ``hvd.loop.pass``     a looped model's passes over its layer stack
                       (``LlamaModel`` with ``total_ut_steps`` > 1): the
                       scan whole, so the walks over the layers and the
@@ -99,8 +113,9 @@ scope                 what falls under it
                       ``SparseAttention`` or ``GatedDeltaNet``:
                       projections, QK-norm, rotation, the ``attention_fn``
                       call or the rule, ``wo``) and the residual add.
-                      ``hvd.flash.*``, ``hvd.rope``, ``hvd.mla.latent``,
-                      ``hvd.sparse.*`` and ``hvd.gdn.*`` nest inside it
+                      ``hvd.flash.*``, ``hvd.rope``, ``hvd.attn.*``,
+                      ``hvd.mla.latent``, ``hvd.sparse.*`` and ``hvd.gdn.*``
+                      nest inside it
 ``hvd.block.ffn``     a layer's feed-forward block whole: ``norm_mlp``,
                       ``SwiGLU`` or ``RoutedExperts`` (``hvd.moe.*`` nest
                       inside it) and the residual add
@@ -184,6 +199,7 @@ from __future__ import annotations
 __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD", "ROPE",
+    "ATTN_WINDOW", "ATTN_GATE",
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN",
@@ -203,6 +219,8 @@ APPLY = "hvd.apply"
 FLASH_FWD = "hvd.flash.fwd"
 FLASH_BWD = "hvd.flash.bwd"
 ROPE = "hvd.rope"
+ATTN_WINDOW = "hvd.attn.window"
+ATTN_GATE = "hvd.attn.gate"
 LOOP_PASS = "hvd.loop.pass"
 LOOP_EXIT = "hvd.loop.exit"
 MLA_LATENT = "hvd.mla.latent"

@@ -30,7 +30,13 @@ docs/sparse_attention.md).  ``layer_types`` names the mixer a layer:
 decay and beta gates, the chunkwise gated delta rule of
 ``ops/gated_delta.py``) among ``"full_attention"`` layers, with OLMo 2's
 block (``norm_placement="post"``, ``qk_norm_over="all"``) and softmax
-layers that do not rotate (``rope_theta=None``).  All of these are
+layers that do not rotate (``rope_theta=None``).  ``"sliding_attention"``
+layers attend through a window of ``sliding_window`` keys (the flash
+kernel's two calls walk the band alone), and a stack may give each layer its
+own count of query heads (``num_attention_heads_per_layer``), each layer
+type its own rotary table, partial or YaRN-scaled (``rope_parameters``), a
+per-head sigmoid gate on the attention's output (``gating="per-head"``) and
+the routed gates a scale (``routed_scaling_factor``).  All of these are
 training paths too: ``generation``, the serve plane and the pipelined step
 refuse them by name.
 """

@@ -20,7 +20,9 @@ TPU-first design:
   by kind from the config: full attention or latent attention (MLA,
   ``attention_kind``), or, a layer at a time, a gated delta-rule
   linear-attention mixer among softmax ones (``layer_types``,
-  ``GatedDeltaNet``); a dense SwiGLU or routed experts beside shared
+  ``GatedDeltaNet``), or softmax layers that attend through a sliding
+  window (``"sliding_attention"``) with a head count, a rotary table and
+  an output gate of their own; a dense SwiGLU or routed experts beside shared
   ones (``num_experts > 1``, after ``first_dense_layers`` dense layers).
   The routed layer is told which experts it holds, routes over all of
   them, gathers its own experts' rows sorted by expert -- none dropped --
@@ -33,6 +35,7 @@ TPU-first design:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -52,8 +55,8 @@ from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops.rope import rotate_pairs, rotates_in_place
 from horovod_tpu.ops.sparse_index import index_loss, select_keys
 
-__all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "YarnScaling",
-           "apply_rope", "causal_attention"]
+__all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "RopeParameters",
+           "YarnScaling", "apply_rope", "causal_attention"]
 
 
 REMAT_POLICIES = {
@@ -73,7 +76,7 @@ REMAT_POLICIES = {
         _scopes.SPARSE_SELECTED_NAME, _scopes.SPARSE_INDEX_LOSS_NAME),
 }
 
-LAYER_TYPES = ("full_attention", "linear_attention")
+LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention")
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -91,6 +94,7 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    attention_factor: Optional[float] = None    # stated: the table's scale
 
     def correction_range(self, dim: int, theta: float) -> tuple[int, int]:
         """(low, high): the rotary pairs between which YaRN blends from
@@ -107,7 +111,10 @@ class YarnScaling:
 
     @property
     def table_scale(self) -> float:
-        """What multiplies cos and sin."""
+        """What multiplies cos and sin: ``attention_factor`` where the
+        configuration states it, else the ratio of the two temperatures."""
+        if self.attention_factor is not None:
+            return self.attention_factor
         return (yarn_mscale(self.factor, self.mscale)
                 / yarn_mscale(self.factor, self.mscale_all_dim))
 
@@ -117,6 +124,18 @@ class YarnScaling:
         if not self.mscale_all_dim:
             return 1.0
         return yarn_mscale(self.factor, self.mscale_all_dim) ** 2
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeParameters:
+    """One entry of ``rope_parameters``, under its published keys: the
+    rotation of one layer type.  ``partial_rotary_factor`` is the share of
+    a head's width that turns (its first lanes; the rest pass untouched);
+    ``scaling`` a ``YarnScaling`` over that rotary width, or None."""
+
+    rope_theta: float
+    scaling: Optional[YarnScaling] = None
+    partial_rotary_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,6 +210,22 @@ class LlamaConfig:
     ``rope_theta`` None: the softmax layers do not rotate (position comes
     from the linear layers' recurrence and convolutions).  Generation, the
     serve plane and the pipelined step refuse all four by name too.
+
+    ``"sliding_attention"`` in ``layer_types`` is a softmax layer of
+    ``attention_kind`` ``"full"`` whose query t attends the keys ``0 <= t -
+    s < sliding_window`` (the published key; ``attention_fn`` is handed
+    ``window=``).  ``num_attention_heads_per_layer`` (a tuple; None:
+    ``num_heads`` everywhere) gives each layer its own count of query heads
+    over the same ``num_kv_heads``; ``gating`` ``"per-head"`` multiplies
+    each head's attention output by ``sigmoid(x W_g)``, ``W_g`` hidden x
+    heads (the head-wise output gate of arXiv:2505.06708); ``rope_parameters``
+    (pairs ``(layer type, RopeParameters)``; None: ``rope_theta`` and
+    ``rope_scaling`` for every layer) gives each layer type its own theta,
+    YaRN scaling and ``partial_rotary_factor``; ``routed_scaling_factor``
+    multiplies the routed experts' renormalised gates.  A layer's kind,
+    head count, window and table follow from its index (``heads_of``,
+    ``window_of``, ``rope_of``); generation, the serve plane and the
+    pipelined step refuse each by name.
     """
 
     vocab_size: int = 32000
@@ -217,6 +252,11 @@ class LlamaConfig:
     qk_norm_over: str = "head"
     norm_placement: str = "pre"
     layer_types: Optional[tuple] = None
+    sliding_window: Optional[int] = None
+    num_attention_heads_per_layer: Optional[tuple] = None
+    gating: Optional[str] = None              # "per-head"
+    rope_parameters: Optional[tuple] = None   # ((layer type, RopeParameters),)
+    routed_scaling_factor: float = 1.0
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
     linear_key_head_dim: int = 0
@@ -292,6 +332,38 @@ class LlamaConfig:
                     "linear attention needs linear_num_key_heads, "
                     "linear_key_head_dim, linear_value_head_dim and "
                     "linear_num_value_heads, a multiple of the key heads")
+        sliding = "sliding_attention" in (self.layer_types or ())
+        if sliding != (self.sliding_window is not None) or (
+                sliding and (self.sliding_window < 1
+                             or self.attention_kind != "full")):
+            raise ValueError(
+                f"sliding_window is {self.sliding_window!r} and layer_types "
+                f"{self.layer_types!r}: a window of at least 1 goes with "
+                f"'sliding_attention' layers of attention_kind 'full', and "
+                f"with nothing else")
+        heads = self.num_attention_heads_per_layer
+        if heads is not None and (
+                len(heads) != self.num_layers
+                or any(n < 1 or n % self.num_kv_heads for n in heads)):
+            raise ValueError(
+                f"num_attention_heads_per_layer is {heads!r}: a multiple of "
+                f"the {self.num_kv_heads} key-value heads for each of "
+                f"{self.num_layers} layers")
+        if self.gating not in (None, "per-head"):
+            raise ValueError(f"gating is {self.gating!r}: 'per-head' or "
+                             f"None")
+        if self.rope_parameters is not None:
+            kinds = {kind for kind, _ in self.rope_parameters}
+            used = set(self.layer_types or ("full_attention",)) - {
+                "linear_attention"}
+            if not used <= kinds <= set(LAYER_TYPES) or any(
+                    not 0.0 < r.partial_rotary_factor <= 1.0
+                    or int(r.partial_rotary_factor * self.head_dim) % 2
+                    for _, r in self.rope_parameters):
+                raise ValueError(
+                    f"rope_parameters names {sorted(kinds)}: an entry for "
+                    f"each softmax layer type in use ({sorted(used)}), each "
+                    f"turning a whole number of pairs of a head")
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -333,6 +405,30 @@ class LlamaConfig:
         return (self.layer_types is not None
                 and self.layer_types[layer] == "linear_attention")
 
+    def layer_type(self, layer: int) -> str:
+        return ("full_attention" if self.layer_types is None
+                else self.layer_types[layer])
+
+    def heads_of(self, layer: int) -> int:
+        """Query heads of ``layer``'s softmax mixer."""
+        if self.num_attention_heads_per_layer is None:
+            return self.num_heads
+        return self.num_attention_heads_per_layer[layer]
+
+    def window_of(self, layer: int) -> Optional[int]:
+        """The keys a query of ``layer`` sees behind it, itself included;
+        None: all of them."""
+        return (self.sliding_window
+                if self.layer_type(layer) == "sliding_attention" else None)
+
+    def rope_of(self, layer: int) -> Optional[RopeParameters]:
+        """The rotation of ``layer``'s q and k; None: they do not turn."""
+        if self.rope_parameters is not None:
+            return dict(self.rope_parameters)[self.layer_type(layer)]
+        if self.rope_theta is None:
+            return None
+        return RopeParameters(self.rope_theta, self.rope_scaling)
+
     def refuse_new_kinds(self, who: str) -> None:
         """For the paths that keep a decoder layer of their own and have
         learned neither kind (ROADMAP.md D1): raise, naming the kind."""
@@ -363,6 +459,32 @@ class LlamaConfig:
                 f"a head and the convolutions' last "
                 f"{self.linear_conv_kernel_dim - 1} inputs, and a decode "
                 f"step would update them in place; not built")
+        if self.sliding_window is not None:
+            raise NotImplementedError(
+                f"{who} has no path for sliding-window layers (layer_types "
+                f"holds 'sliding_attention', sliding_window="
+                f"{self.sliding_window}): such a layer's cache would hold "
+                f"its last {self.sliding_window} tokens alone, a geometry "
+                f"of its own beside the full layers', and prefill would "
+                f"mask the band; not built")
+        if self.num_attention_heads_per_layer is not None:
+            raise NotImplementedError(
+                f"{who} has no path for a head count a layer "
+                f"(num_attention_heads_per_layer="
+                f"{self.num_attention_heads_per_layer}): its layers share "
+                f"one shape of q and one stage's weights; not built")
+        if self.gating is not None:
+            raise NotImplementedError(
+                f"{who} has no path for the {self.gating} output gate "
+                f"(gating={self.gating!r}): its layer would project a gate "
+                f"a head from the normed state and scale the attention "
+                f"output before W_o; not built")
+        if self.rope_parameters is not None:
+            raise NotImplementedError(
+                f"{who} has no path for a rotation a layer type or a "
+                f"partial one (rope_parameters names "
+                f"{[kind for kind, _ in self.rope_parameters]}): it builds "
+                f"one table for all layers and turns whole heads; not built")
         if (self.norm_placement != "pre" or self.qk_norm_over != "head"
                 or self.rope_theta is None):
             raise NotImplementedError(
@@ -387,16 +509,28 @@ class RMSNorm(nn.Module):
 
 
 def rope_freqs(head_dim: int, seq_len: int, theta: float, offset=0,
-               scaling: Optional[YarnScaling] = None
+               scaling: Optional[YarnScaling] = None,
+               rotary_dim: Optional[int] = None
                ) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables [S, head_dim/2] in fp32.  ``offset`` may be a traced
     value (sequence-parallel shards pass ``axis_index * S_local``).
+
+    With ``rotary_dim`` < ``head_dim`` (``partial_rotary_factor``) only the
+    first ``rotary_dim`` lanes of a head turn, by frequencies (and YaRN
+    ranges) reckoned over that width; the other pairs' rows of the tables
+    are the identity, cos 1 and sin 0, which ``apply_rope`` and
+    ``ops/rope.py``'s pass turn into the lanes' own bits (x * 1 - y * 0 in
+    float32, one rounding back), so one table and one pass serve a head
+    that rotates by half.
 
     With ``scaling`` (YaRN; Peng et al., arXiv:2309.00071, as DeepSeek-V2
     applies it): pair i turns at ``f_i = theta^(-2i/d)`` below
     ``low``, at ``f_i / factor`` above ``high`` and at a linear blend of
     the two between (``YarnScaling.correction_range``), and the tables are
     multiplied by ``table_scale``."""
+    still = 0
+    if rotary_dim is not None and rotary_dim != head_dim:
+        still, head_dim = (head_dim - rotary_dim) // 2, rotary_dim
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                            / head_dim))
     if scaling is not None:
@@ -410,6 +544,9 @@ def rope_freqs(head_dim: int, seq_len: int, theta: float, offset=0,
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if scaling is not None and scaling.table_scale != 1.0:
         cos, sin = cos * scaling.table_scale, sin * scaling.table_scale
+    if still:
+        cos = jnp.pad(cos, ((0, 0), (0, still)), constant_values=1.0)
+        sin = jnp.pad(sin, ((0, 0), (0, still)))
     return cos, sin
 
 
@@ -453,7 +590,8 @@ def _reads_in_place(attention_fn) -> bool:
 
 
 def causal_attention(q, k, v, *, q_offset: int = 0,
-                     scale: Optional[float] = None, selected=None):
+                     scale: Optional[float] = None, selected=None,
+                     window: Optional[int] = None):
     """Default causal attention, fp32 logits, GQA-aware.
 
     q: [B, Sq, Hq, D]; k: [B, Sk, Hkv, D]; v: [B, Sk, Hkv, Dv] with
@@ -464,6 +602,8 @@ def causal_attention(q, k, v, *, q_offset: int = 0,
     causal keys alone, for every head, and makes the result ``(out, lse
     [B, Hq, Sq])``, the log-sum-exp of each query's kept scores with no
     gradient: the seam's contract for learned sparse attention.
+    ``window`` keeps the keys ``0 <= q_pos - k_pos < window`` alone (a
+    sliding-window layer's band).
     """
     B, Sq, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -476,7 +616,10 @@ def causal_attention(q, k, v, *, q_offset: int = 0,
         logits = logits * scale
     q_pos = jnp.arange(Sq) + q_offset
     k_pos = jnp.arange(k.shape[1])
-    mask = (q_pos[:, None] >= k_pos[None, :])[None, None, None]
+    mask = q_pos[:, None] >= k_pos[None, :]
+    if window is not None:
+        mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
+    mask = mask[None, None, None]
     if selected is not None:
         mask = mask & (selected != 0)[:, None, None]
     logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
@@ -490,16 +633,26 @@ def causal_attention(q, k, v, *, q_offset: int = 0,
 
 
 class LlamaAttention(nn.Module):
+    """Softmax attention over grouped-query heads.  The layer's ``index``
+    in the stack decides what the config lets differ by layer: the count
+    of query heads (``LlamaConfig.heads_of``), the window its queries see
+    (``window_of``: handed to ``attention_fn`` as ``window=``, under
+    ``hvd.attn.window``) and, with ``gating`` ``"per-head"``, the gate
+    ``sigmoid(x W_g)`` a head on the attention's output (under
+    ``hvd.attn.gate``); ``cos``, ``sin`` are the layer's own tables."""
+
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
+    index: int = 0
 
     @nn.compact
     def __call__(self, x, cos, sin):
         cfg = self.config
         B, S, _ = x.shape
         D = cfg.head_dim
-        q = nn.Dense(cfg.num_heads * D, use_bias=False, dtype=cfg.dtype,
-                     name="wq")(x).reshape(B, S, cfg.num_heads, D)
+        heads = cfg.heads_of(self.index)
+        q = nn.Dense(heads * D, use_bias=False, dtype=cfg.dtype,
+                     name="wq")(x).reshape(B, S, heads, D)
         k = nn.Dense(cfg.num_kv_heads * D, use_bias=False, dtype=cfg.dtype,
                      name="wk")(x).reshape(B, S, cfg.num_kv_heads, D)
         v = nn.Dense(cfg.num_kv_heads * D, use_bias=False, dtype=cfg.dtype,
@@ -512,17 +665,46 @@ class LlamaAttention(nn.Module):
         elif cfg.qk_norm:
             q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
-        if cos is not None:
-            in_place = _reads_in_place(self.attention_fn)
-            q = apply_rope(q, cos, sin, in_place=in_place)
-            k = apply_rope(k, cos, sin, in_place=in_place)
-        out = self.attend(x, q, k, v, cos, sin)
-        out = out.reshape(B, S, cfg.num_heads * D)
+        window = cfg.window_of(self.index)
+        with (jax.named_scope(_scopes.ATTN_WINDOW) if window is not None
+              else contextlib.nullcontext()):
+            if cos is not None:
+                in_place = _reads_in_place(self.attention_fn)
+                q = apply_rope(q, cos, sin, in_place=in_place)
+                k = apply_rope(k, cos, sin, in_place=in_place)
+            out = self.attend(x, q, k, v, cos, sin)
+        out = out.reshape(B, S, heads * D)
+        if cfg.gating == "per-head":
+            with jax.named_scope(_scopes.ATTN_GATE):
+                out = _gated_heads(out, nn.Dense(
+                    heads, use_bias=False, dtype=cfg.dtype, name="wg")(x))
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="wo")(out)
 
     def attend(self, x, q, k, v, cos, sin):
-        return self.attention_fn(q, k, v)
+        window = self.config.window_of(self.index)
+        if window is None:
+            return self.attention_fn(q, k, v)
+        return self.attention_fn(q, k, v, window=window)
+
+
+def _gated_heads(out, logits):
+    """``out [B, S, heads * D]`` with each head's D lanes multiplied by
+    ``sigmoid(logits [B, S, heads])`` of its head, in the dtype of out.
+    The sigmoid is taken in float32 and rounded once; a head's gate reaches
+    its lanes by a product with the heads' 0/1 indicator ``[heads, heads *
+    D]`` (exact: one term a lane), for the reason ``_over_heads`` gives: a
+    reshape to ``[.., heads, D]`` and a broadcast have XLA:TPU relay the
+    tensor."""
+    heads = logits.shape[-1]
+    gate = jax.nn.sigmoid(logits.astype(jnp.float32)).astype(out.dtype)
+    of_head = (jnp.arange(heads)[:, None]
+               == jnp.arange(out.shape[-1])[None, :] // (
+                   out.shape[-1] // heads)).astype(out.dtype)
+    # (At float32 the MXU's default is one bf16 pass: ask for all of it.)
+    return out * jnp.matmul(
+        gate, of_head, precision=jax.lax.Precision.HIGHEST
+        if out.dtype == jnp.float32 else None)
 
 
 class SparseAttention(LlamaAttention):
@@ -619,6 +801,7 @@ class LatentAttention(nn.Module):
 
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
+    index: int = 0      # every mixer is told its layer; this one is the same in all
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -751,7 +934,8 @@ class RoutedExperts(nn.Module):
 
         s = softmax(x W_r)                    over all num_experts
         e_1..e_K = the K largest of s;  g_k = s[e_k]
-                   (divided by their sum if norm_topk_prob)
+                   (divided by their sum if norm_topk_prob, then times
+                   routed_scaling_factor)
         y = sum_{k: e_k held here} g_k E_{e_k}(x) + S(x)
 
     E_e a SwiGLU of width ``moe_intermediate_size``, S one SwiGLU of
@@ -811,6 +995,8 @@ class RoutedExperts(nn.Module):
             gates, chosen = jax.lax.top_k(scores, K)               # [B,S,K]
             if cfg.norm_topk_prob:
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            if cfg.routed_scaling_factor != 1.0:
+                gates = gates * cfg.routed_scaling_factor
             # Assignment a = (token, choice), flat; absent experts sort last.
             local = chosen.reshape(T * K) - cfg.first_held_expert
             here = (local >= 0) & (local < held)
@@ -1087,7 +1273,10 @@ class LlamaLayer(nn.Module):
     OLMo 2's: ``x + Norm(Mixer(x))``).  Which mixer and which
     feed-forward, the config says of the layer's ``index`` in the stack:
     ``LlamaConfig.is_linear`` (a ``GatedDeltaNet`` as ``"linear"``, else
-    ``attention_kind``'s as ``"attn"``) and ``LlamaConfig.is_routed``."""
+    ``attention_kind``'s as ``"attn"``, which is told the index too: its
+    head count, window and gate may differ by layer) and
+    ``LlamaConfig.is_routed``.  ``cos``, ``sin`` are the tables of this
+    layer's type."""
 
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
@@ -1102,8 +1291,8 @@ class LlamaLayer(nn.Module):
                 name="linear")
         else:
             mixer = functools.partial(ATTENTION_KINDS[cfg.attention_kind](
-                cfg, attention_fn=self.attention_fn, name="attn"),
-                cos=cos, sin=sin)
+                cfg, attention_fn=self.attention_fn, index=self.index,
+                name="attn"), cos=cos, sin=sin)
         if cfg.is_routed(self.index):
             ffn = RoutedExperts(cfg, name="moe")
         else:
@@ -1157,11 +1346,17 @@ class LlamaModel(nn.Module):
         B, S = input_ids.shape
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      name="tok_emb")(input_ids)
-        cos = sin = None
-        if cfg.rope_theta is not None:
-            cos, sin = rope_freqs(cfg.rope_dim, S, cfg.rope_theta,
-                                  offset=positions_offset,
-                                  scaling=cfg.rope_scaling)
+        # One table a distinct rotation, made once and handed to the layers
+        # of its type (a stack with one rotation: one table, as before).
+        tables = {None: (None, None)}
+        for i in range(cfg.num_layers):
+            rope = cfg.rope_of(i)
+            if rope not in tables:
+                tables[rope] = rope_freqs(
+                    cfg.rope_dim, S, rope.rope_theta,
+                    offset=positions_offset, scaling=rope.scaling,
+                    rotary_dim=int(rope.partial_rotary_factor
+                                   * cfg.rope_dim))
         layer_cls = LlamaLayer
         if cfg.remat != "none":
             layer_cls = nn.remat(LlamaLayer,
@@ -1171,7 +1366,8 @@ class LlamaModel(nn.Module):
             """Stack(x), its modules made under ``mdl`` by name."""
             for i in range(cfg.num_layers):
                 x = layer_cls(cfg, attention_fn=self.attention_fn, index=i,
-                              name=f"layer_{i}", parent=mdl)(x, cos, sin)
+                              name=f"layer_{i}", parent=mdl)(
+                                  x, *tables[cfg.rope_of(i)])
             return x
 
         def norm_f(mdl, x):

@@ -30,6 +30,21 @@ Design (FlashAttention-2 style):
   straight-line code and the loop holds the others (``_walk``;
   ``pair_counts``: 136 live pairs a head at S = 8192, 16 of them on the
   diagonal; 36 / 8 at 4096; 10 / 4 at 2048);
+* a sliding window (``window=W``, static: query t sees the keys ``0 <= t -
+  s < W``) is a second bound on the same two walks and a second mask beside
+  the causal one: a query block's walk starts at the first key block the
+  band reaches (``max(qi bq - (W - 1), 0) // bk``, joined with a segment's
+  start by ``max``), a key block's backward walk ends at the last query
+  block that still sees it (``((ki + 1) bk + W - 2) // bq``), and only the
+  pairs the band's lower edge crosses build ``q_pos - k_pos < W``
+  (``_window_walk``: they come first in a forward walk and last in a
+  backward one; with equal blocks no longer than the window the diagonal's
+  pair still stands beside the loop).  At S = 8192, W = 512 and 512-row
+  blocks a head runs 31 pairs where a causal one runs 136, each crossed by
+  the diagonal or the edge (``pair_counts(s, bq, bk, causal, window)``).
+  Without a window both calls trace to the program they were, and ``W >=
+  S`` is that program; K and V are still whole rows in VMEM, so a window
+  does not lengthen the longest row;
 * the values may have a width of their own (``d_v`` != ``d_qk``: latent
   attention's 192-wide keys and 128-wide values): q, k, dq and dk are
   ``d_qk`` wide, v, o, dO and dv ``d_v`` wide, in the same two calls.
@@ -156,24 +171,40 @@ def _pick_block(s: int, cap: int) -> int:
     return 0
 
 
-def pair_counts(s: int, block_q: int, block_k: int, causal: bool = True):
-    """``(pairs, diagonal)`` of one head's row of ``s``: the block pairs the
-    two walks run, and those of them the diagonal crosses (S = 8192 at
+def pair_counts(s: int, block_q: int, block_k: int, causal: bool = True,
+                window: Optional[int] = None):
+    """``(pairs, crossed)`` of one head's row of ``s``: the block pairs the
+    two walks run, and those of them an edge of the mask crosses (S = 8192 at
     512-row blocks: 136 / 16; 4096: 36 / 8; 2048: 10 / 4; not causal: every
-    pair, none crossed).  With equal blocks the crossed pairs are ``ki ==
-    qi``, the ones ``_walk`` takes out of the loop."""
+    pair, none crossed).  With equal blocks the pairs the diagonal crosses
+    are ``ki == qi``, the ones ``_walk`` takes out of the loop.
+
+    With ``window`` W (a query sees the keys ``0 <= t - s < W``) a query
+    block's walk starts at the first key block the band reaches, and a pair
+    is crossed where the diagonal or the band's lower edge passes through
+    it: 8192 / 512 / 512 at W = 512 runs 31 pairs a head, every one
+    crossed (the query block's own, by the diagonal; the one before it, by
+    the lower edge); ``W >= s`` is the causal walk."""
     n_q, n_k = s // block_q, s // block_k
     if not causal:
         return n_q * n_k, 0
-    pairs = diagonal = 0
+    pairs = crossed = 0
     for qi in range(n_q):
         live = min(-(-(qi + 1) * block_q // block_k), n_k)
         # Wholly under the diagonal: the block's last key is no later than
         # the query block's first row.
         under = min((qi * block_q + 1) // block_k, live)
-        pairs += live
-        diagonal += live - under
-    return pairs, diagonal
+        first, edge = 0, 0
+        if window is not None:
+            first = max(qi * block_q - (window - 1), 0) // block_k
+            # The lower edge passes through a key block that holds a key
+            # the query block's last row no longer sees.
+            edge = min(max((qi + 1) * block_q - 1 - window + block_k, 0)
+                       // block_k, live)
+        pairs += live - first
+        # Between the edge's key blocks and the diagonal's, no mask bites.
+        crossed += live - first - max(under - max(edge, first), 0)
+    return pairs, crossed
 
 
 def _interpret() -> bool:
@@ -195,6 +226,26 @@ def _walk(first, end, body, carry, apart=None):
     if apart == "first":
         return jax.lax.fori_loop(first + 1, end, body, body(first, carry))
     return jax.lax.fori_loop(first, end, body, carry)
+
+
+def _window_walk(first, edge_end, end, body, carry, diagonal, peel):
+    """A windowed causal walk over the block pairs ``first .. end - 1``:
+    ``first .. edge_end - 1`` are the pairs the band's lower edge crosses
+    and run ``body(..., edge=True)`` (both masks), the others the causal
+    walk's own body, in ascending order.  ``diagonal`` names the end at
+    which the diagonal lies, as ``_walk``'s ``apart``; forward the edge's
+    pairs come first, backward (``diagonal="first"``) they come last, so
+    ``first .. edge_end - 1`` are then the pairs WITHOUT the edge.
+    ``peel`` (static) says that the diagonal's pair is never one of the
+    edge's, so that it can stand beside the loop as in ``_walk``: equal
+    blocks no longer than the window."""
+    apart = diagonal if peel else None
+    with_edge = functools.partial(body, edge=True)
+    if diagonal == "last":
+        carry = jax.lax.fori_loop(first, edge_end, with_edge, carry)
+        return _walk(edge_end, end, body, carry, apart)
+    carry = _walk(first, edge_end, body, carry, apart)
+    return jax.lax.fori_loop(edge_end, end, with_edge, carry)
 
 
 def _seg_mask(scores, seg_start, ki, block_k):
@@ -219,7 +270,8 @@ def _selected(scores, mask):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
-                block_k, bias_ref=None, seg_ref=None, mask_ref=None):
+                block_k, bias_ref=None, seg_ref=None, mask_ref=None,
+                window=None):
     # q_ref: [block_q, D]; k_ref: [S, D]; v_ref: [S, Dv]; o_ref: [block_q, Dv];
     # bias_ref (optional): [8, S] additive key bias (0 valid / -1e30
     # masked), sublane-replicated like lse — key-padding masks for
@@ -232,6 +284,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
     # mask_ref (optional): [block_q, S] int8, this query block's rows of a
     # per-batch selection (nonzero = the key is in), shared by every head:
     # learned sparse attention, whose mask is data and differs by query.
+    # window (optional, static, causal only): a query sees the keys
+    # ``0 <= q_pos - k_pos < window``; the walk starts at the first key block
+    # the band reaches and only the pairs its lower edge crosses build that
+    # mask (``_window_walk``).
     qi = pl.program_id(1)
     block_q, d = q_ref.shape
     s = k_ref.shape[0]
@@ -261,8 +317,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
         # segment start are 100% masked — skip them (the lower-bound twin
         # of the causal upper bound), preserving packing's FLOP savings.
         kv_first = jnp.min(seg_start) // block_k
+    if window is not None:
+        # The first key block that holds a key the query block's first row
+        # still sees: the segment's bound turned into a static one.
+        kv_first = jnp.maximum(
+            kv_first, jnp.maximum(qi * block_q - (window - 1), 0) // block_k)
 
-    def body(ki, carry):
+    def body(ki, carry, edge=False):
         m, l, acc = carry
         k_blk = k_ref[pl.dslice(ki * block_k, block_k), :]
         v_blk = v_ref[pl.dslice(ki * block_k, block_k), :]
@@ -278,7 +339,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
                 jnp.int32, (block_q, block_k), 1)
             # Large-negative (not -inf) keeps exp() finite with no NaN
             # guards on the hot path.
-            scores = jnp.where(q_pos >= k_pos, scores, -1e30)
+            keep = q_pos >= k_pos
+            if edge:
+                # A row that sees no key of this block leaves garbage that
+                # the diagonal's block, which comes later and holds a key
+                # of every row, wipes (as for a selection: ``_selected``).
+                keep = keep & (q_pos - k_pos < window)
+            scores = jnp.where(keep, scores, -1e30)
         if bias_ref is not None:
             scores = scores + bias_ref[0, pl.dslice(ki * block_k,
                                                     block_k)][None, :]
@@ -296,8 +363,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, sm_scale,
             preferred_element_type=jnp.float32)
         return new_m, new_l, new_acc
 
-    m, l, acc = _walk(kv_first, n_kv_live, body, (m, l, acc),
-                      apart="last" if causal else None)
+    if window is None:
+        m, l, acc = _walk(kv_first, n_kv_live, body, (m, l, acc),
+                          apart="last" if causal else None)
+    else:
+        # The key blocks that hold a key the query block's LAST row no
+        # longer sees come first.
+        edge_end = jnp.clip(
+            jnp.maximum((qi + 1) * block_q - 1 - window + block_k, 0)
+            // block_k, kv_first, n_kv_live)
+        m, l, acc = _window_walk(
+            kv_first, edge_end, n_kv_live, body, (m, l, acc), "last",
+            block_q == block_k and window >= block_q)
     o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     # log-sum-exp per row, consumed by the backward kernel.  lse_ref holds
     # the full row (TPU blocks must tile (8, 128)); write this q-block's
@@ -399,6 +476,12 @@ def _rows(shape, heads):
     return shape if heads == 1 else (shape[0] * shape[1], shape[2])
 
 
+def _windowed(window):
+    """The keyword for a band, for the kernels and for ``_bwd_impl``: none
+    without one, so that a call without a window is the call it was."""
+    return {} if window is None else {"window": window}
+
+
 def _fwd_vmem_limit(s, d, d_v, bq, bk, itemsize):
     """``vmem_limit_bytes`` of the forward call that reads a selection:
     K and V whole, the query block's ``[bq, S]`` int8 rows of the mask, q,
@@ -411,7 +494,7 @@ def _fwd_vmem_limit(s, d, d_v, bq, bk, itemsize):
 
 
 def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None, mask=None,
-         heads=1):
+         heads=1, window=None):
     # q: [Bq, S, heads * D]; k: [Bk, S, kv_heads * D]; v: [Bk, S, kv_heads *
     # Dv], heads side by side on the last axis (``heads`` of them in q; one:
     # the flat [BH, S, D] form).  Bq * heads query heads, a multiple of the
@@ -439,7 +522,7 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None, mask=None,
                                              q.dtype.itemsize))
     names, arrays, bias_specs = _extras(bh, s, bias, seg, mask, mask_spec)
     kernel = _with_extras(_fwd_kernel, 2, names, causal=causal,
-                          sm_scale=sm_scale, block_k=bk)
+                          sm_scale=sm_scale, block_k=bk, **_windowed(window))
     query_block = lambda b, i: (b, i, 0)
     whole_row = _kv_block(group, lambda b, i: (b, 0, 0))
     call = pl.pallas_call(
@@ -474,7 +557,8 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None, mask=None,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *, causal, sm_scale,
-                block_q, bias_ref=None, seg_ref=None, mask_ref=None):
+                block_q, bias_ref=None, seg_ref=None, mask_ref=None,
+                window=None):
     # Grid (head, key block).  q_ref: [S, D] and do_ref: [S, Dv], the head's
     # whole row; k_ref, dk_ref: [block_k, D] and v_ref, dv_ref: [block_k, Dv],
     # this step's key block;
@@ -484,6 +568,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # scores, p, dp and ds once and feeds all three gradients.
     # mask_ref (optional): [S, block_k] int8, this key block's columns of
     # the selection the forward call read by rows.
+    # window (optional, static): the walk ends at the last query block that
+    # still sees a key of this block, and the pairs the band's lower edge
+    # crosses, which come last, build its mask.
     ki = pl.program_id(1)
     block_k, d = k_ref.shape
     s = q_ref.shape[0]
@@ -522,8 +609,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         valid_rows = jnp.sum(
             (seg_ref[0, :] <= kv_end).astype(jnp.int32))
         n_q_live = jnp.minimum(n_q, (valid_rows + block_q - 1) // block_q)
+    if window is not None:
+        # The last query that sees this block's last key is ``window - 1``
+        # rows after it.
+        n_q_live = jnp.minimum(
+            n_q_live, ((ki + 1) * block_k + window - 2) // block_q + 1)
 
-    def body(qi, carry):
+    def body(qi, carry, edge=False):
         dk, dv = carry
         rows = pl.dslice(qi * block_q, block_q)
         q_blk = q_ref[rows, :]
@@ -538,7 +630,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            scores = jnp.where(q_pos >= k_pos, scores, -1e30)
+            keep = q_pos >= k_pos
+            if edge:
+                keep = keep & (q_pos - k_pos < window)
+            scores = jnp.where(keep, scores, -1e30)
         if bias_ref is not None:
             # The grid owns a fixed key block: bias slice at this step's
             # own block index.
@@ -566,11 +661,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         return dk, dv
 
-    dk, dv = _walk(
-        first_q, n_q_live, body,
-        (jnp.zeros((block_k, d), jnp.float32),
-         jnp.zeros((block_k, v_ref.shape[1]), jnp.float32)),
-        apart="first" if causal else None)
+    zeros = (jnp.zeros((block_k, d), jnp.float32),
+             jnp.zeros((block_k, v_ref.shape[1]), jnp.float32))
+    if window is None:
+        dk, dv = _walk(first_q, n_q_live, body, zeros,
+                       apart="first" if causal else None)
+    else:
+        # From the first query block whose last row no longer sees this
+        # block's first key, the lower edge crosses the pair.
+        edge_start = jnp.clip((ki * block_k + window) // block_q, first_q,
+                              n_q_live)
+        dk, dv = _window_walk(
+            first_q, edge_start, n_q_live, body, zeros, "first",
+            block_q == block_k and window >= block_q)
     dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
@@ -617,7 +720,7 @@ def _sum_in_order(parts):
 
 
 def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None,
-              mask=None, heads=1):
+              mask=None, heads=1, window=None):
     # Operands as ``_fwd``'s; do and out like q at the values' width.
     q, k, v, out, lse = res
     rows, s = q.shape[:2]
@@ -662,7 +765,7 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None,
 
     # The scratch ref follows the outputs, so it counts among them here.
     kernel = _with_extras(_bwd_kernel, 4, names, causal=causal,
-                          sm_scale=sm_scale, block_q=bq)
+                          sm_scale=sm_scale, block_q=bq, **_windowed(window))
 
     def row(width):
         return _head_spec(s, width, s, heads, lambda b, i: (b, 0, 0))
@@ -717,8 +820,9 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None,
     return dq, dk, dv
 
 
-def _bwd(causal, sm_scale, heads, res, do):
-    return _bwd_impl(causal, sm_scale, res, do, heads=heads)
+def _bwd(causal, sm_scale, heads, window, res, do):
+    return _bwd_impl(causal, sm_scale, res, do, heads=heads,
+                     **_windowed(window))
 
 
 # ---------------------------------------------------------------------------
@@ -727,15 +831,16 @@ def _bwd(causal, sm_scale, heads, res, do):
 
 # ``heads`` (static, last) is how many heads q keeps side by side on its last
 # axis: ``[B, S, heads * D]`` operands in place, or the flat ``[B * H, S, D]``
-# with one (``_fwd``).
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, sm_scale, heads=1):
-    out, _ = _fwd(q, k, v, causal, sm_scale, heads=heads)
+# with one (``_fwd``).  ``window`` (static, after it) is the band's width in
+# keys, None for all the causal ones.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, heads=1, window=None):
+    out, _ = _fwd(q, k, v, causal, sm_scale, heads=heads, window=window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, heads):
-    out, lse = _fwd(q, k, v, causal, sm_scale, heads=heads)
+def _flash_fwd(q, k, v, causal, sm_scale, heads, window):
+    out, lse = _fwd(q, k, v, causal, sm_scale, heads=heads, window=window)
     # Named for recomputation policies (LlamaConfig.remat): a policy that
     # keeps both spares the backward pass this call.  Identity otherwise.
     out = checkpoint_name(out, _scopes.FLASH_OUT_NAME)
@@ -902,23 +1007,25 @@ def _flash_biased_bwd(causal, sm_scale, heads, res, do):
 _flash_biased.defvjp(_flash_biased_fwd, _flash_biased_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_seg(q, k, v, seg, causal, sm_scale, heads=1):
-    out, _ = _fwd(q, k, v, causal, sm_scale, seg=seg, heads=heads)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_seg(q, k, v, seg, causal, sm_scale, heads=1, window=None):
+    out, _ = _fwd(q, k, v, causal, sm_scale, seg=seg, heads=heads,
+                  window=window)
     return out
 
 
-def _flash_seg_fwd(q, k, v, seg, causal, sm_scale, heads):
-    out, lse = _fwd(q, k, v, causal, sm_scale, seg=seg, heads=heads)
+def _flash_seg_fwd(q, k, v, seg, causal, sm_scale, heads, window):
+    out, lse = _fwd(q, k, v, causal, sm_scale, seg=seg, heads=heads,
+                    window=window)
     return out, (q, k, v, seg, out, lse)
 
 
-def _flash_seg_bwd(causal, sm_scale, heads, res, do):
+def _flash_seg_bwd(causal, sm_scale, heads, window, res, do):
     import numpy as np
 
     q, k, v, seg, out, lse = res
     dq, dk, dv = _bwd_impl(causal, sm_scale, (q, k, v, out, lse), do,
-                           seg=seg, heads=heads)
+                           seg=seg, heads=heads, **_windowed(window))
     # Integer input: JAX requires a float0 cotangent.
     return dq, dk, dv, np.zeros(seg.shape, dtype=jax.dtypes.float0)
 
@@ -1028,6 +1135,7 @@ def _pad_to_tile(q, k, v, causal, key_padding_mask, segment_ids):
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     key_padding_mask=None, segment_ids=None,
+                    window: Optional[int] = None,
                     _sm_scale: Optional[float] = None):
     """Flash attention on [B, S, H, D] tensors (the model zoo seam).
 
@@ -1042,6 +1150,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     for packed pretraining, at O(S) sideband cost instead of an [S, S]
     mask.  With fewer key-value heads than query heads (GQA) nothing is
     repeated: the calls' index maps send a query head to its group's K and V.
+    ``window``: optional static band width W of a causal layer (sliding-
+    window attention): query t attends the keys ``0 <= t - s < W``, within
+    its segment where ``segment_ids`` are given.  The walks skip the block
+    pairs outside the band (``pair_counts``); ``W >= S`` is plain causal
+    attention, the same program.
 
     Layout: this function hands the two calls ``_flat_layout``'s
     ``[B * H, S, D]`` operands, at every width.  The same attention through
@@ -1074,11 +1187,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     rows (standard BERT practice masks them out of the loss).
     """
     return _attend(q, k, v, causal, key_padding_mask, segment_ids, _sm_scale,
-                   in_place=False)
+                   in_place=False, window=window)
 
 
 def _attend(q, k, v, causal, key_padding_mask, segment_ids, sm_scale,
-            in_place):
+            in_place, window=None):
     """``flash_attention``'s work, for it (``in_place=False``) and for the
     seam (``True``).  In place, where ``D % 128 == 0`` and ``Dv % 128 == 0``,
     the two calls take their operands as ``[B, S, H * D]``, where the
@@ -1093,6 +1206,16 @@ def _attend(q, k, v, causal, key_padding_mask, segment_ids, sm_scale,
     23)."""
     B, S, Hq, D = q.shape
     Dv = v.shape[-1]        # the values' own width (latent attention)
+    if window is not None:
+        if not causal or key_padding_mask is not None:
+            raise NotImplementedError(
+                "window is a causal layer's band (0 <= t - s < window); "
+                "bidirectional or padded attention has no path for it")
+        if window < 1:
+            raise ValueError(f"window is {window}: at least the query's "
+                             f"own position")
+        if window >= S:
+            window = None       # every causal key: the causal program
     if segment_ids is not None:
         if not causal:
             raise NotImplementedError(
@@ -1107,13 +1230,13 @@ def _attend(q, k, v, causal, key_padding_mask, segment_ids, sm_scale,
         out = _attend(
             qp, kp, vp, causal, key_padding_mask, segment_ids,
             sm_scale if sm_scale is not None else 1.0 / math.sqrt(D),
-            in_place)
+            in_place, window)
         return out[..., :Dv]
     if S % 128 != 0:
         q, k, v, key_padding_mask, segment_ids = _pad_to_tile(
             q, k, v, causal, key_padding_mask, segment_ids)
         return _attend(q, k, v, causal, key_padding_mask, segment_ids,
-                       sm_scale, in_place)[:, :S]
+                       sm_scale, in_place, window)[:, :S]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     qt, kt, vt, heads, restore = _kernel_layout(q, k, v, in_place)
@@ -1122,9 +1245,9 @@ def _attend(q, k, v, causal, key_padding_mask, segment_ids, sm_scale,
         # [B, S] -> [B, 8, S]: sublane-replicated (TPU tiling); heads are
         # folded away in the kernels' sideband BlockSpec.
         seg = jnp.broadcast_to(starts[:, None, :], (B, 8, S))
-        out = _flash_seg(qt, kt, vt, seg, causal, sm_scale, heads)
+        out = _flash_seg(qt, kt, vt, seg, causal, sm_scale, heads, window)
     elif key_padding_mask is None:
-        out = _flash(qt, kt, vt, causal, sm_scale, heads)
+        out = _flash(qt, kt, vt, causal, sm_scale, heads, window)
     else:
         bias = jnp.where(key_padding_mask, 0.0, -1e30).astype(jnp.float32)
         bias = jnp.broadcast_to(bias[:, None, :], (B, 8, S))
@@ -1133,7 +1256,7 @@ def _attend(q, k, v, causal, key_padding_mask, segment_ids, sm_scale,
 
 
 def flash_attention_fn(q, k, v, mask=None, *, scale=None, selected=None,
-                       segment_ids=None, **kwargs):
+                       segment_ids=None, window=None, **kwargs):
     """Adapter matching the model zoo's pluggable ``attention_fn``.
 
     ``mask`` follows the zoo's convention (broadcastable [B, 1, 1, S]
@@ -1147,17 +1270,22 @@ def flash_attention_fn(q, k, v, mask=None, *, scale=None, selected=None,
     of a causal decoder takes, ``flash_attention_selected``) makes the
     result ``(out, lse)``.  ``segment_ids [B, S]`` (packed causal
     sequences, ``flash_attention``'s; bind them with ``functools.partial``)
-    go with no mask.
+    go with no mask.  ``window`` (static; a sliding-window layer binds its
+    own the same way) keeps the keys ``0 <= t - s < window`` of a causal
+    decoder's queries.
 
     Heads of whole 128-lane tiles reach the two calls where the projections
     left them (``_attend``).  The attribute ``in_place`` says so to the
     model, which then turns q and k by ``ops/rope.py``'s Mosaic pass in
     that layout too (``models/llama.py::_reads_in_place``)."""
     if selected is not None:
+        if window is not None:
+            raise NotImplementedError("a selection and a window together "
+                                      "have no path")
         return flash_attention_selected(q, k, v, selected, _sm_scale=scale)
     if mask is None:
         return _attend(q, k, v, True, None, segment_ids, scale,
-                       in_place=True)
+                       in_place=True, window=window)
     mask = jnp.asarray(mask)
     if mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1:
         key_mask = mask[:, 0, 0, :]
@@ -1170,7 +1298,7 @@ def flash_attention_fn(q, k, v, mask=None, *, scale=None, selected=None,
             "dense attention path for richer mask structures"
         )
     return _attend(q, k, v, False, key_mask.astype(bool), segment_ids,
-                   scale, in_place=True)
+                   scale, in_place=True, window=window)
 
 
 flash_attention_fn.in_place = True

@@ -28,8 +28,8 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 # The tiny sizes at which tests/benchmark runs the jobs of the
-# ``deepseek-v2-lite``, ``keye-vl-2.0-30b-a3b`` and ``olmo-hybrid-7b``
-# configurations on the CPU.  They belong beside
+# ``deepseek-v2-lite``, ``keye-vl-2.0-30b-a3b``, ``olmo-hybrid-7b`` and
+# ``laguna-s-2.1`` configurations on the CPU.  They belong beside
 # ``tests/benchmark/tiny_sizes.py``'s, whose table every test of that
 # directory reads by the job's name; the files there are the accepted
 # benchmark's, which a PR that adds a cell may not edit, so the entry is
@@ -112,6 +112,42 @@ TINY.setdefault("hybrid_lm", {
     "traffic": {"sequence": 256, "batch_per_chip": 2},
 })
 
+TINY.setdefault("window_moe_lm", {
+    # Hidden 128; the published pattern's first five layers (full, sliding,
+    # sliding, sliding, full) at 4 and 6 query heads of 64 over 2 key-value
+    # heads (groups of 2 and 3), a window of 64 keys, the full layers'
+    # heads turning by half under YaRN; a dense layer and four routed ones
+    # that hold experts 4 to 7 of 16, 3 choices a token, one shared expert.
+    "config": {"hidden_size": 128, "num_attention_heads": 4,
+               "num_key_value_heads": 2, "head_dim": 64,
+               "num_attention_heads_per_layer": [4, 6, 6, 6, 4],
+               "sliding_window": 64,
+               "rope_parameters": {
+                   "full_attention": {
+                       "rope_theta": 500000, "rope_type": "yarn",
+                       "factor": 8, "original_max_position_embeddings": 64,
+                       "beta_slow": 1, "beta_fast": 32,
+                       "attention_factor": 1.2079441541679836,
+                       "partial_rotary_factor": 0.5},
+                   "sliding_attention": {
+                       "rope_type": "default", "rope_theta": 10000,
+                       "partial_rotary_factor": 1}},
+               "intermediate_size": 256, "moe_intermediate_size": 32,
+               "shared_expert_intermediate_size": 32, "vocab_size": 512,
+               "num_experts": 4, "num_experts_per_tok": 3,
+               "deployment": {"num_experts_published": 16,
+                              "first_held_expert": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up move a unit-variance embedding too
+                          # little to show in one second on the CPU.
+                          "loss_must_fall": False,
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.1}}},
+    "traffic": {"sequence": 256, "batch_per_chip": 2},
+})
 
 def pytest_configure(config):
     config.addinivalue_line(
@@ -174,14 +210,16 @@ _MANIFEST_THEN = {
     "test_benchmark_dense.py::"
     "test_the_four_entries_are_in_the_manifest_as_the_issue_put_them":
         ("keye-vl-2.0-30b-a3b.train-s8k-b2", "dense_roofline"),
+    "test_benchmark_hybrid.py::test_the_manifests_new_entries":
+        ("olmo-hybrid-7b.train-s8k", "gdn_scan_roofline"),
 }
 
 
 @pytest.fixture(autouse=True)
 def _manifest_as_its_test_knew_it(request, monkeypatch):
     """``tests/benchmark/test_benchmark_moe.py::test_the_manifests_new_
-    entries`` (PR 32) and its namesake in ``test_benchmark_sparse.py``
-    (PR 34) pin their PR's entries as the LAST of every list of
+    entries`` (PR 32) and its namesakes in ``test_benchmark_sparse.py``
+    (PR 34) and ``test_benchmark_hybrid.py`` (PR 38) pin their PR's entries as the LAST of every list of
     ``BENCHMARK.json`` and count the cells, and a later PR may neither
     edit those files nor put its entries anywhere but last.  So each of
     those tests reads the manifest cut back to the entries it was written
