@@ -925,6 +925,95 @@ def _row_chunk(assignments: int, share: float) -> int:
     return min(-(-int(2 * share * assignments) // 512) * 512, assignments)
 
 
+@functools.partial(jax.jit, static_argnums=(9, 10), inline=True)
+def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
+                rows_per_expert, n_rows, chunk, k, first=0):
+    """The part of a routed layer's y that the sorted rows ``first`` to
+    ``first + chunk`` give: gathered, through their experts, and back to
+    their tokens under the gates.  ``[T, H]`` float32; all zeros, and so is
+    every gradient, where no row is held from ``first`` on.  ``order`` the
+    assignments sorted by held expert, ``inverse`` each assignment's place
+    among them, ``last`` the running sum of ``rows_per_expert``.
+
+    Under an inlined ``jit`` so that it is traced once a shape: a step
+    calls it ten times a routed layer (the first buffer and the loop's, in
+    the walk, its forward rule and twice under ``jax.vjp`` backward, and
+    again where the layer is recomputed), which cost the ``laguna-s-2.1``
+    cell's step a second of tracing at every start (PERF.md §6, PR 43).
+    Inlined, the operations keep the names and scopes they have without
+    it."""
+    with jax.named_scope(_scopes.MOE_ROUTE):
+        assignments = jax.lax.dynamic_slice(order, (first,), (chunk,))
+        position = inverse - first
+        # An expert's rows that fall into this buffer.
+        sizes = (jnp.clip(last, first, first + chunk)
+                 - jnp.clip(last - rows_per_expert, first, first + chunk))
+        live = (first + jnp.arange(chunk) < n_rows)[:, None]
+        rows = _rows_of_tokens(tokens, assignments, position, k)
+        # Rows past the last group are no expert's: what a grouped
+        # product leaves there is undefined, so it is cut off at
+        # both ends (here for the gradient that comes back).
+        rows = jnp.where(live, rows, 0)
+    with jax.named_scope(_scopes.MOE_EXPERTS):
+        gate, up = jnp.split(
+            jax.lax.ragged_dot(rows, w_gu, sizes), 2, axis=-1)
+        rows = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
+    with jax.named_scope(_scopes.MOE_COMBINE):
+        rows = jnp.where(live, rows, 0)
+        return _weighted_rows_to_tokens(rows, weights, assignments,
+                                        position, k)
+
+
+def _over_live_buffers(of_buffer, n_rows, chunk):
+    """``of_buffer(first)`` added up over the buffers that hold rows: the
+    first whatever it holds, so that where one buffer does (the rule) the
+    result is its own and no sum is started from zeros, and those behind
+    it in a loop whose trip count is the data's."""
+    return jax.lax.fori_loop(
+        1, -(-n_rows // chunk),
+        lambda i, total: jax.tree.map(jnp.add, total, of_buffer(i * chunk)),
+        of_buffer(np.int32(0)))     # of the loop's type: one trace serves
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _live_buffers(tokens, w_gu, w_down, weights, order, inverse, last,
+                  rows_per_expert, n_rows, chunk, k):
+    """``_one_buffer`` summed over ``ceil(n_rows / chunk)`` buffers, forward
+    and backward: a chip's share of the experts gets a fraction of the worst
+    case's rows and pays for what it gets.
+
+    The backward pass is its own because autodiff's is not that: it cannot
+    cross a loop of a traced length, and through a ``scan`` of ``cond`` s
+    over all the buffers it adds every buffer's cotangent of the weights,
+    tokens and gates into accumulators, zeros it writes out for each buffer
+    that was skipped (fifteen of sixteen at a 1/32 share: 108 ms of a 449
+    ms step, PERF.md §6, PR 43).  It keeps the layer's inputs alone and runs
+    each live buffer's forward again (``jax.vjp``): kept, the residuals of
+    all buffers are the worst case's."""
+    return _over_live_buffers(
+        lambda first: _one_buffer(tokens, w_gu, w_down, weights, order,
+                                  inverse, last, rows_per_expert, n_rows,
+                                  chunk, k, first), n_rows, chunk)
+
+
+def _live_buffers_fwd(*inputs):
+    return _live_buffers(*inputs), inputs[:9]
+
+
+def _live_buffers_bwd(chunk, k, inputs, g):
+    differentiable, indices = inputs[:4], inputs[4:]
+
+    def cotangents(first):
+        return jax.vjp(lambda *operands: _one_buffer(
+            *operands, *indices, chunk, k, first), *differentiable)[1](g)
+
+    return (*_over_live_buffers(cotangents, indices[-1], chunk),
+            *map(_float0, indices))
+
+
+_live_buffers.defvjp(_live_buffers_fwd, _live_buffers_bwd)
+
+
 class RoutedExperts(nn.Module):
     """Top-k routed SwiGLU experts, of which this program holds
     ``config.experts_held`` (ids ``first_held_expert`` onwards), and the
@@ -958,10 +1047,12 @@ class RoutedExperts(nn.Module):
     gathers, elementwise passes and residuals over the dead seven eighths
     were 30 % of a step on the v5e (PERF.md, PR 32).  So the buffer has
     twice the expected rows (``_row_chunk``) and the sorted rows go through
-    it buffer by buffer, as many times as there are rows for (a ``scan``
-    whose body is skipped where no rows are left): once as a rule, as
-    often as the worst case needs at worst, and with every expert held
-    there is one buffer and no loop.
+    it buffer by buffer, as many times as there are rows for, forward and
+    backward (``_live_buffers``: the first buffer, then a loop whose length
+    is the data's, and a backward pass of its own that walks the same
+    buffers and writes nothing for the others): once as a rule, as often
+    as the worst case needs at worst, and with every expert held there is
+    one buffer and no loop.
     Weights are ``w_gate_up [held, H, 2F]`` and ``w_down [held, F, H]``,
     which ``parallel/api.py`` shards over an ``expert`` axis.
 
@@ -1021,54 +1112,11 @@ class RoutedExperts(nn.Module):
                 self.sow("moe_stats", "row_buffers_run",
                          -(-n_rows // chunk))
 
-        last = jnp.cumsum(rows_per_expert)
         order = jnp.pad(order, (0, n_chunks * chunk - T * K))
-        tokens = x.reshape(T, H)
-
-        def one_buffer(first, tokens, w_gu, w_down, weights):
-            """The part of y that the sorted rows ``first`` to ``first +
-            chunk`` give: gathered, through their experts, and back to
-            their tokens under the gates.  ``[T, H]`` float32."""
-            with jax.named_scope(_scopes.MOE_ROUTE):
-                assignments = jax.lax.dynamic_slice(order, (first,), (chunk,))
-                position = inverse - first
-                # An expert's rows that fall into this buffer.
-                sizes = (jnp.clip(last, first, first + chunk)
-                         - jnp.clip(last - rows_per_expert, first,
-                                    first + chunk))
-                live = (first + jnp.arange(chunk) < n_rows)[:, None]
-                rows = _rows_of_tokens(tokens, assignments, position, K)
-                # Rows past the last group are no expert's: what a grouped
-                # product leaves there is undefined, so it is cut off at
-                # both ends (here for the gradient that comes back).
-                rows = jnp.where(live, rows, 0)
-            with jax.named_scope(_scopes.MOE_EXPERTS):
-                gate, up = jnp.split(
-                    jax.lax.ragged_dot(rows, w_gu, sizes), 2, axis=-1)
-                rows = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
-            with jax.named_scope(_scopes.MOE_COMBINE):
-                rows = jnp.where(live, rows, 0)
-                return _weighted_rows_to_tokens(rows, weights, assignments,
-                                                position, K)
-
-        if n_chunks == 1:
-            y = one_buffer(0, tokens, w_gu, w_down, weights)
-        else:
-            # Buffer by buffer, and only those that hold rows: a chip's
-            # share of the experts gets a fraction of the worst case's rows
-            # and pays for what it gets.  Each buffer keeps its inputs alone
-            # for the backward pass and runs its forward again there; kept,
-            # the residuals of all buffers are the worst case's.
-            @jax.checkpoint
-            def add_buffer(y, first):
-                return jax.lax.cond(
-                    first < n_rows,
-                    lambda y: y + one_buffer(first, tokens, w_gu, w_down,
-                                             weights),
-                    lambda y: y, y), None
-
-            y, _ = jax.lax.scan(add_buffer, jnp.zeros((T, H), jnp.float32),
-                                jnp.arange(n_chunks) * chunk)
+        walk = _one_buffer if n_chunks == 1 else _live_buffers
+        y = walk(x.reshape(T, H), w_gu, w_down, weights, order, inverse,
+                 jnp.cumsum(rows_per_expert), rows_per_expert, n_rows, chunk,
+                 K)
         y = y.astype(cfg.dtype).reshape(B, S, H)
 
         if cfg.shared_experts:

@@ -548,50 +548,119 @@ def test_config_refuses_what_it_cannot_be():
         tiny(held_experts=8, first_held_expert=60)
 
 
-@pytest.mark.parametrize("case, buffers", [("uniform", 1), ("twice", 2),
-                                           ("all_held", 4)])
-def test_as_many_row_buffers_run_as_there_are_rows_for(case, buffers):
-    """1024 tokens x 6 choices, 8 of 64 experts held: a buffer of 1536 rows
-    (twice the 768 expected), four of which are the worst case.  A router
-    that spreads its choices fills one; one that sends two tokens in five to
-    the held eight two, with experts' rows split across buffers; every
-    token all four; and each is the reference, gradient and all."""
+def _scans(jaxpr, length):
+    """The ``lax.scan`` equations of that length, sub-jaxprs walked."""
+    found = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "scan"
+             and eqn.params["length"] == length]
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _scans(sub, length)
+    return found
+
+
+# case: (experts, tokens a batch row, the tokens lifted (of 1 in `every`),
+#        the lift of the held experts' logits, buffers that hold rows)
+ROW_BUFFER_CASES = {
+    "uniform": (64, 512, 0, 60.0, 1),
+    "twice": (64, 512, (2, 5), 60.0, 2),
+    "all_held": (64, 512, (1, 1), 60.0, 4),
+    "a_32nd_uniform": (256, 680, 0, 60.0, 1),
+    "a_32nd_thrice": (256, 680, (5, 34), 60.0, 3),
+    "a_32nd_all_held": (256, 680, (1, 1), 60.0, 16),
+    "a_32nd_none_held": (256, 680, (1, 1), -60.0, 0),
+}
+
+
+@pytest.mark.parametrize("case", ROW_BUFFER_CASES)
+def test_as_many_row_buffers_run_as_there_are_rows_for(case, monkeypatch):
+    """Experts 8 to 15 held, 6 choices a token.  Of 64, 1024 tokens: a
+    buffer of 1536 rows (twice the 768 expected), four of which are the
+    worst case.  A router that spreads its choices fills one; one that sends
+    two tokens in five to the held eight two, with experts' rows split
+    across buffers; every token all four.  Of 256, 1360 tokens (the
+    ``laguna-s-2.1`` cell's share): sixteen buffers of 512 rows for 8160
+    assignments, the last cut short, of which one, three, all sixteen and
+    none hold rows.  Each is the reference, output and gradients with
+    respect to parameters and input; the backward pass runs a buffer's body
+    as often as the forward pass did, and no loop over all the buffers is
+    left in it."""
     assert llama._row_chunk(98304, 8 / 64) == 24576
     assert llama._row_chunk(6144, 8 / 64) == 1536
     assert llama._row_chunk(6144, 1.0) == 6144
     assert llama._row_chunk(192, 8 / 64) == 192
-    cfg = tiny(held_experts=8, first_held_expert=8)
-    x = jax.random.normal(jax.random.key(3), (2, 512, cfg.hidden_size))
+    assert llama._row_chunk(81920, 8 / 256) == 5120
+    assert llama._row_chunk(8160, 8 / 256) == 512
+    experts, seq, lifted, lift, buffers = ROW_BUFFER_CASES[case]
+    chunk, n_chunks = (1536, 4) if experts == 64 else (512, 16)
+    cfg = tiny(num_experts=experts, held_experts=8, first_held_expert=8)
+    x = jax.random.normal(jax.random.key(3), (2, seq, cfg.hidden_size))
     params = seeded(RoutedExperts(cfg), x, scale=2.0)
-    if case != "uniform":
-        # A constant feature that lifts the held experts' logits: for every
-        # token, or for the two tokens in five that make 1536 < rows < 3072.
-        lift = jnp.ones((2, 512)) if case == "all_held" else (
-            jnp.arange(512) % 5 < 2).astype(jnp.float32)[None].repeat(2, 0)
-        x = x.at[..., 2].set(lift)
+    if lifted:
+        # A constant feature that lifts (or sinks) the held experts' logits
+        # for some tokens in every so many: all six of their choices held.
+        some, every = lifted
+        x = x.at[..., 2].set(jnp.tile(
+            (jnp.arange(seq) % every < some).astype(jnp.float32), (2, 1)))
         kernel = params["params"]["router"]["kernel"]
         params["params"]["router"]["kernel"] = kernel.at[2].set(
-            jnp.where((jnp.arange(EXPERTS) >= 8)
-                      & (jnp.arange(EXPERTS) < 16), 60.0, 0.0))
+            jnp.where((jnp.arange(experts) >= 8)
+                      & (jnp.arange(experts) < 16), lift, 0.0))
     y, sown = RoutedExperts(cfg).apply(params, x, mutable=["moe_stats"])
     rows = int(np.asarray(sown["moe_stats"]["rows_per_expert"][0]).sum())
     assert int(sown["moe_stats"]["row_buffers_run"][0]) == buffers, rows
     assert int(sown["moe_stats"]["rows_dropped"][0]) == 0
-    assert -(-rows // 1536) == buffers
+    assert -(-rows // chunk) == buffers
+
+    # A mark on each buffer's tokens whose transpose counts the executions
+    # of the buffer's backward body (outside the body's own ``jit``, whose
+    # cache outlives a test).
+    backward_bodies = []
+
+    def counted(_, g):
+        jax.debug.callback(lambda: backward_bodies.append(1))
+        return (g,)
+
+    mark = jax.custom_vjp(lambda tokens: tokens)
+    mark.defvjp(lambda tokens: (tokens, None), counted)
+    one_buffer = llama._one_buffer
+    monkeypatch.setattr(
+        llama, "_one_buffer",
+        lambda tokens, *others: one_buffer(mark(tokens), *others))
+
     config = {**REF, "deployment": {"first_held_expert": 8}}
     weight = jax.random.normal(jax.random.key(8), x.shape)
+
+    def weighted_sum(cfg):
+        return jax.grad(lambda p, x: jnp.sum(
+            RoutedExperts(cfg).apply(p, x) * weight), argnums=(0, 1))
+
     with jax.default_matmul_precision("highest"):
         want, _, _ = ref.routed_experts(
             x, routed_reference_params(params["params"], 16), config)
-        got_grads = jax.grad(lambda p: jnp.sum(
-            RoutedExperts(cfg).apply(p, x) * weight))(params)
-        want_grads = jax.grad(lambda moe: jnp.sum(ref.routed_experts(
-            x, routed_reference_params(moe, 16), config)[0] * weight))(
-            params["params"])
+        got_grads, got_dx = weighted_sum(cfg)(params, x)
+        want_grads, want_dx = jax.grad(lambda moe, x: jnp.sum(
+            ref.routed_experts(x, routed_reference_params(moe, 16),
+                               config)[0] * weight), argnums=(0, 1))(
+            params["params"], x)
+    jax.effects_barrier()
+    # The first buffer's body runs whatever it holds.
+    assert len(backward_bodies) == max(buffers, 1)
+    assert not _scans(jax.make_jaxpr(weighted_sum(cfg))(params, x).jaxpr,
+                      n_chunks)
     np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(got_dx, want_dx, rtol=2e-4, atol=2e-4)
     for g, w in zip(jax.tree.leaves(got_grads["params"]),
                     jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+    if not buffers:
+        # Without the shared experts the layer is the routed sum alone:
+        # nothing of it, and nothing that is not a number.
+        alone = dataclasses.replace(cfg, shared_experts=0)
+        routed = {"params": {name: leaf for name, leaf in
+                             params["params"].items() if name != "shared"}}
+        for leaf in jax.tree.leaves((RoutedExperts(alone).apply(routed, x),
+                                     weighted_sum(alone)(routed, x))):
+            np.testing.assert_array_equal(leaf, 0.0)
 
 
 # -- rows back to their tokens, slot by slot ----------------------------------

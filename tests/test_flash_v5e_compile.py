@@ -219,9 +219,11 @@ def test_latent_decoders_step_compiles_with_its_calls_inside_their_limit(
     64 experts held), 1 x 4096 tokens, each layer recomputed with the flash
     output kept: a forward and a backward flash call a layer, the backward
     ones inside the limit they state, and the routed layer's grouped
-    products as XLA:TPU's own Mosaic calls (``ragged-dot``: forward,
-    recomputed and the four gradient products, eight a routed layer), which
-    carry no scope of the program's."""
+    products as XLA:TPU's own Mosaic calls (``ragged-dot``: two forward,
+    the two again and the four gradient products backward, for the first
+    row buffer and once more in the loop over those behind it, sixteen a
+    routed layer; the layer's recomputation adds none, the walk keeps its
+    inputs alone), which carry no scope of the program's."""
     import flax.linen as nn
 
     from horovod_tpu.models.llama import (LlamaConfig, LlamaModel,
@@ -262,7 +264,7 @@ def test_latent_decoders_step_compiles_with_its_calls_inside_their_limit(
     grouped = [c for c in calls if c not in flash]
     names = [re.search(r'op_name="([^"]*)"', c).group(1) for c in grouped]
     assert all(n.startswith(scopes.RAGGED_DOT_PREFIX) for n in names), names
-    assert sum(n == "ragged-dot-none" for n in names) == 8
+    assert sum(n == "ragged-dot-none" for n in names) == 16
     # No score matrix: the only [.., 4096, 4096] is W_kvb's output, sixteen
     # heads of 128 + 128.
     assert not re.search(r"\[(\d+,)*16,4096,4096\]", compiled.as_text())

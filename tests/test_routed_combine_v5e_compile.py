@@ -3,7 +3,10 @@ described ``v5e:2x2`` (no chip attached) at ``deepseek-v2-lite.train-s4k``'s
 shape: ``_rows_to_tokens`` alone, and one routed layer's forward and
 backward pass under ``jax.checkpoint``.  Neither holds the gathered
 ``[T * K, H]`` rows nor their ``[T, K, H]`` float32 reshape, and the
-temporaries are what the slot loop needs (PERF.md §6, PR 33).  The TPU
+temporaries are what the slot loop needs (PERF.md §6, PR 33).  And the
+layer at ``laguna-s-2.1.train-s8k``'s shape, sixteen row buffers of which
+one holds rows: its backward pass writes no zeros of a weight's shape
+(PERF.md §6, PR 43).  The TPU
 compiler is loaded inside a fixture (the on-chip-measurement guide says
 why); the recipe is ``tests/test_flash_v5e_compile.py``'s."""
 
@@ -109,3 +112,46 @@ def test_one_routed_layer_forward_and_backward(one_chip):
     assert not _GATHERED.search(text), sorted(set(_GATHERED.findall(text)))
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries <= 2.6e9 < GATHERED_LAYER_TEMPORARIES, temporaries
+
+
+#: ``temp_size_in_bytes`` of the layer below at the parent of PR 43, f6fe42f
+#: (a ``scan`` of ``cond`` s over the sixteen buffers under
+#: ``jax.checkpoint``, whose transpose adds each buffer's weight gradients,
+#: zeros for a buffer skipped, into accumulators), compiled with this
+#: installation.  The backward pass over the live buffers alone: 930,774,528.
+SCANNED_SIXTEEN_BUFFERS_TEMPORARIES = 1_500_559_872
+
+
+def test_a_32nd_of_the_experts_writes_no_zeros_of_a_weights_shape(one_chip):
+    """``laguna-s-2.1.train-s8k``'s routed layer: 8192 tokens x 10 choices
+    of 256 experts of 1024 at hidden 3072, 8 held and one shared, so sixteen
+    buffers of 5120 rows.  The parent's compiled backward pass held three
+    ``broadcast`` s each of ``bf16[8,3072,2048]`` and ``bf16[8,1024,3072]``,
+    two of them in the loop's body; none is left anywhere, loop or not: the
+    first buffer's weight gradients start the sum."""
+    tokens, hidden, width, k, held = 8192, 3072, 1024, 10, 8
+    cfg = LlamaConfig(
+        vocab_size=128, hidden_size=hidden, num_layers=1, num_heads=48,
+        num_kv_heads=8, intermediate_size=12288, max_seq_len=tokens,
+        num_experts=256, experts_per_token=k, held_experts=held,
+        moe_intermediate_size=width, shared_experts=1, norm_topk_prob=True,
+        routed_scaling_factor=2.5, dtype=jnp.bfloat16)
+    layer = RoutedExperts(cfg)
+    assert llama._row_chunk(tokens * k, held / 256) == 5120
+    x = _shape((1, tokens, hidden), jnp.bfloat16, one_chip)
+    params = jax.tree.map(
+        lambda s: _shape(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda x: layer.init(jax.random.key(0), x), x))
+
+    def loss(params, x):
+        y = jax.checkpoint(layer.apply)(params, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    zeros = re.findall(
+        rf"bf16\[{held},(?:{hidden},{2 * width}|{width},{hidden})\]"
+        r"[^ ]* broadcast\(", compiled.as_text())
+    assert not zeros, zeros
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries <= SCANNED_SIXTEEN_BUFFERS_TEMPORARIES, temporaries
