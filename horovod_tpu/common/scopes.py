@@ -22,7 +22,10 @@ scope                 what falls under it
 ``hvd.allreduce.<a>`` every traced all-reduce over mesh axes ``<a>``
                       (``hvd.allreduce.data``; several axes joined by ``+``)
 ``hvd.aux_allreduce`` the ``has_aux`` state's per-leaf all-reduces
-``hvd.optimizer``     the wrapped optax transformation's ``update``
+``hvd.optimizer``     the wrapped optax transformation's ``update``; with
+                      ``hvd.apply`` the whole update of each large weight
+                      matrix, which ``DistributedOptimizer.update`` keeps
+                      out of its gradient's matmul (a barrier a leaf)
 ``hvd.apply``         ``optax.apply_updates``
 ``hvd.flash.fwd``     the flash kernel's forward Mosaic call
 ``hvd.flash.bwd``     its backward Mosaic call (dq, dk and dv from one call)

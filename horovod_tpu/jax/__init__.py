@@ -30,9 +30,11 @@ Typical use::
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Callable, Optional
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
 from horovod_tpu.common import (
@@ -78,7 +80,7 @@ __all__ = [
     "allreduce", "grouped_allreduce", "allgather", "broadcast",
     "reducescatter", "alltoall",
     "Average", "Sum", "Min", "Max", "Product", "ReduceOp", "Compression",
-    "DistributedOptimizer", "allreduce_gradients",
+    "DistributedOptimizer", "allreduce_gradients", "update_counts",
     "broadcast_parameters", "broadcast_optimizer_state",
     "build_mesh", "data_parallel_mesh", "default_mesh", "use_mesh",
     "make_train_step", "compile_log", "TRAIN_STEP_PROGRAM",
@@ -334,6 +336,53 @@ def allreduce_gradients(grads, *, axis_name=None, op=Average,
         grads)
 
 
+#: A weight matrix of this many elements or more has its update taken out of
+#: its gradient's matmul (``_alone``).  XLA:TPU fuses a weight's whole update
+#: (for ``master_weights(adamw)`` the master, mu, nu and the bf16 weight, ~30
+#: bytes a weight) into the product that forms its gradient.  Up to ~23M
+#: weights such a fusion costs its parts, FLOP time + byte time; from ~42M it
+#: runs at 1.5-2x its parts (PERF.md §5, "The update inside the gradient
+#: matmuls": the by-size table and the sweep over this constant on the v5e,
+#: PR 44).
+ALONE_FROM_ELEMENTS = 40_000_000
+
+_counts_lock = threading.Lock()
+_counts = {"alone": 0, "fused": 0}
+
+
+def update_counts() -> dict:
+    """``{"alone": n, "fused": n}``: how the last traced
+    ``DistributedOptimizer.update`` split its gradient leaves.  ``alone``:
+    behind a barrier of its own, so the leaf's update is one loop fusion
+    under ``hvd.optimizer`` / ``hvd.apply`` after a plain gradient matmul; ``fused``: left
+    to the compiler, which may put the update into the gradient's fusion.
+    Counted at trace time (readable after ``step.lower``), process-global,
+    like ``ops/short_conv.py::body_counts``."""
+    with _counts_lock:
+        return dict(_counts)
+
+
+def _alone(grads):
+    """``grads`` with every large weight matrix's gradient behind its own
+    ``optimization_barrier`` (the identity; XLA fuses nothing across it).
+    A leaf at a time, never the tree: a barrier over all of them would keep
+    every gradient alive until the last is formed.  The rule reads the leaf
+    alone: floating, rank 2 or more, ``ALONE_FROM_ELEMENTS`` or more.
+    Concrete gradients (the host-driven path) pass as they are: there is no
+    compiler to hold back."""
+    leaves, treedef = jax.tree.flatten(grads)
+    if not any(map(_is_traced, leaves)):
+        return grads
+    engaged = [_is_traced(g) and g.ndim >= 2
+               and g.size >= ALONE_FROM_ELEMENTS
+               and jnp.issubdtype(g.dtype, jnp.floating) for g in leaves]
+    with _counts_lock:
+        _counts.update(alone=sum(engaged), fused=len(leaves) - sum(engaged))
+    return jax.tree.unflatten(treedef, [
+        jax.lax.optimization_barrier(g) if taken else g
+        for g, taken in zip(leaves, engaged)])
+
+
 class DistributedOptimizer:
     """Wrap an optax ``GradientTransformation`` so that ``update`` averages
     gradients across the mesh before applying the inner optimizer.
@@ -346,6 +395,16 @@ class DistributedOptimizer:
     pmap); under plain pjit-with-sharded-batch XLA already inserts the psum,
     in which case wrap with ``reduce_gradients=False`` to keep only the
     bookkeeping.
+
+    Inside ``jit`` the gradient of every large weight matrix (a floating
+    leaf of rank 2 or more with ``ALONE_FROM_ELEMENTS`` elements or more)
+    passes its own ``jax.lax.optimization_barrier`` on its way to the
+    all-reduce and the inner ``update``: on one chip XLA would otherwise fuse
+    the leaf's whole update into the matmul that forms its gradient, which
+    for a matrix that large costs up to twice the two apart.  The barrier
+    is the identity; the leaf's update then runs as one loop fusion under
+    ``hvd.optimizer`` / ``hvd.apply`` behind a plain gradient matmul, and
+    :func:`update_counts` says how the last traced update split its leaves.
 
     ``local_sgd_steps=H`` (default: ``HOROVOD_LOCAL_SGD_STEPS``, 1)
     switches the host-driven (eager/DCN) path to communication-relaxed
@@ -488,6 +547,11 @@ class DistributedOptimizer:
         # ZeRO path: RS(flat grads) → shard-local inner update → AG.
         if self._sharded and self._reduce:
             return self._sharded_update(grads, state, params, **extra)
+        # Ahead of the all-reduce, not behind it: on one chip the two places
+        # compile to one module; over several chips a gradient is whole at
+        # its all-reduce anyway, and a barrier behind it would only take the
+        # average's division out of the update's fusion (PERF.md §6, PR 44).
+        grads = _alone(grads)
         # Local-SGD phase: gradients apply purely locally; the policy's
         # maybe_sync (called by the training loop on the params) is the
         # only wire traffic — H× fewer syncs by construction.

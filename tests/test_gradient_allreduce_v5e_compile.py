@@ -110,7 +110,8 @@ def _placed(mesh, tree, spec):
 
 @pytest.fixture(scope="module")
 def decoder_step(four_chips):
-    """(compiled step, parameter shapes) of the benchmark's four-chip cell."""
+    """(compiled step, parameter shapes, how the traced update split its
+    leaves) of the benchmark's four-chip cell."""
     import horovod_tpu.jax as hvd
 
     job = _job(DECODER)
@@ -124,11 +125,11 @@ def decoder_step(four_chips):
                                has_aux=job.has_aux)
     compiled = step.lower(*_placed(four_chips, state, P()),
                           _placed(four_chips, batch, P("data"))).compile()
-    return compiled, jax.tree.leaves(state[0])
+    return compiled, jax.tree.leaves(state[0]), hvd.update_counts()
 
 
 def test_four_chip_step_reduces_large_gradients_in_place(decoder_step):
-    compiled, params = decoder_step
+    compiled, params, _ = decoder_step
     lines = _instructions(compiled)
     leaf_shapes = {tuple(leaf.shape) for leaf in params}
 
@@ -150,7 +151,7 @@ def test_four_chip_step_leaves_the_batching_to_xlas_combiner(decoder_step):
     """75 gradient leaves and the loss enter XLA as 76 all-reduces and
     leave its combiner as 11 (the same 11 as with the 19 norm scales
     packed into one buffer: builder's compiles of both trees, PR 30)."""
-    compiled, params = decoder_step
+    compiled, params, _ = decoder_step
     reduces = _all_reduces(_instructions(compiled))
     assert len(params) == 75
     assert 10 <= len(reduces) <= 12, len(reduces)
@@ -164,6 +165,22 @@ def test_four_chip_step_leaves_the_batching_to_xlas_combiner(decoder_step):
         assert len(_result_arrays(line)) > 1, line[:200]
     assert sum(_result_arrays(line).count(((2048,), 4096))
                for line in carrying) == len(scales)
+
+
+def test_four_chip_step_holds_no_update_inside_a_matmul(decoder_step):
+    """An all-reduce sits between a gradient and its update, so no matmul
+    fusion (``kOutput``) holds the float32 master and moments of a weight's
+    shape, with or without ``DistributedOptimizer``'s barrier (PR 44),
+    which stands ahead of the all-reduce on the two leaves of 100.7M and
+    changes nothing of the compiled step there."""
+    compiled, params, counts = decoder_step
+    matrices = {tuple(leaf.shape) for leaf in params if leaf.ndim >= 2}
+    for line in _instructions(compiled):
+        if " fusion(" in line and "kind=kOutput" in line:
+            float32 = [shape for shape, nbytes in _result_arrays(line)
+                       if shape in matrices and nbytes == 4 * math.prod(shape)]
+            assert len(float32) < 2, line[:300]
+    assert counts == {"alone": 2, "fused": len(params) - 2}
 
 
 def test_resnet50_gradient_tree_needs_no_packer(four_chips):

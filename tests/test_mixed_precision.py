@@ -94,3 +94,34 @@ def test_integer_leaves_pass_through():
     u, s = opt.update(g, s, p)
     assert u["step"].dtype == jnp.int32
     assert float(jnp.sum(jnp.abs(u["step"]))) == 0.0
+
+
+@pytest.mark.parametrize("compute", [jnp.bfloat16, jnp.float16])
+def test_master_reads_the_compute_dtypes_gradient_behind_a_barrier(
+        monkeypatch, compute):
+    """A large leaf's gradient reaches ``master_weights`` through
+    ``DistributedOptimizer``'s barrier (PR 44) in the compute dtype the
+    program states, and master, moments and the rounded weight are, to the
+    last bit, what the bare transformation gives from that gradient."""
+    monkeypatch.setattr(hvd, "ALONE_FROM_ELEMENTS", 32)
+    loss_fn, params, data = _problem(seed=2)
+    inner = master_weights(optax.adamw(0.05))
+    opt = hvd.DistributedOptimizer(inner, reduce_gradients=False)
+    low = cast_compute(params, compute)
+
+    def step(update):
+        def run(p, s):
+            grads = jax.grad(loss_fn)(p, data)
+            assert grads["w"].dtype == compute
+            updates, s = update(grads, s, p)
+            return optax.apply_updates(p, updates), s
+        return jax.jit(run)
+
+    got = step(opt.update)(low, inner.init(low))
+    assert hvd.update_counts() == {"alone": 1, "fused": 0}
+    want = step(inner.update)(low, inner.init(low))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert got[0]["w"].dtype == compute
+    assert got[1].master["w"].dtype == jnp.float32

@@ -5,8 +5,15 @@ walks are ``while`` loops over chunks and never over tokens; and the cell's
 whole train step, which fits the chip with every head of both mixers (the
 test that ISSUE 38 made the condition of halving them) and whose linear
 layers' convolutions are ``ops/short_conv.py``'s Mosaic calls; and a hybrid
-model under the GSPMD step over all four chips, which holds none."""
+model under the GSPMD step over all four chips, which holds none.  Since
+PR 44 also where each weight's optimizer update sits in the compiled step of
+this cell and of ``ouro-2.6b.train-s2k``: alone behind its gradient's matmul
+for a leaf of ``hvd.ALONE_FROM_ELEMENTS`` elements or more, inside the
+matmul's fusion for the others."""
 
+import collections
+import contextlib
+import math
 import os
 import re
 
@@ -25,9 +32,14 @@ from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 
 CELL = "olmo-hybrid-7b.train-s8k"
+OURO = "ouro-2.6b.train-s2k"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
 B, S, HEADS, D_K, D_V = 1, 8192, 30, 96, 192
 HBM = 15.75 * 2 ** 30      # what the compiler has of the chip's 16 GB
+# An instruction's result type is all between "= " and the opcode.
+_RESULT = re.compile(r" = (.*?)\s[a-z][\w-]*\(")
+_F32 = re.compile(r"\bf32\[([0-9,]+)\]")
+_KIND = re.compile(r"kind=(k\w+)")
 
 
 @pytest.fixture(scope="module")
@@ -41,19 +53,30 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
 
 
-@pytest.fixture
-def one_chip(topo, monkeypatch):
+@contextlib.contextmanager
+def _compiling_for_the_chip():
+    """The three kernels' non-interpreted bodies, and no persistent cache
+    (a deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(fa, "_interpret", lambda: False)
-    monkeypatch.setattr(rope, "_interpret", lambda: False)
-    monkeypatch.setattr(short_conv, "_interpret", lambda: False)
+    patch = pytest.MonkeyPatch()
+    for module in (fa, rope, short_conv):
+        patch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        patch.undo()
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def one_chip(topo):
+    with _compiling_for_the_chip():
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 def test_the_rule_walks_chunks_not_tokens_at_the_cells_shape(one_chip):
@@ -89,20 +112,13 @@ def test_the_rule_walks_chunks_not_tokens_at_the_cells_shape(one_chip):
     assert memory.temp_size_in_bytes < 2e9
 
 
-def test_the_cells_whole_step_fits_with_every_head(topo, one_chip):
-    """Four layers of the published widths at 1 x 8192 tokens, all 30
-    heads of both mixers: 13.0 GB of state, and arguments + temporaries
-    under what the compiler has of the chip.  The softmax layer is two
-    flash calls (its forward call is not run again: the policy keeps its
-    output).  A linear layer is nine Mosaic calls, all under
-    ``hvd.gdn.conv``: q's, k's and v's convolution forward, again under
-    recomputation, and backward; the rule is none, and no float32 array of
-    an activation's shape is left under that scope."""
-    cell = manifest.cell(CELL)
+def _compiled_step(topo, workload):
+    """The cell's whole step compiled for one described chip, the job, and
+    what the trace counted: the convolutions' bodies and the update's
+    split (``hvd.update_counts``)."""
+    cell = manifest.cell(workload)
     job = manifest.load_job(cell["config"]["job"]).build(
         cell["config"], cell["traffic"], 1)
-    assert cell["config"]["num_attention_heads"] == 30
-    assert cell["config"]["linear_num_value_heads"] == 30
     mesh = Mesh([topo.devices[0]], ("data",))
     replicated = NamedSharding(mesh, P())
 
@@ -116,12 +132,69 @@ def test_the_cells_whole_step_fits_with_every_head(topo, one_chip):
     step = hvd.make_train_step(job.loss_fn, job.optimizer, mesh)
     before = short_conv.body_counts()
     compiled = step.lower(*described(state), described(batch)).compile()
+    after = short_conv.body_counts()
+    bodies = {"fused": after["fused"] - before["fused"],
+              "plain": after["plain"] == before["plain"]}
+    return (compiled, job, jax.tree.leaves(state[0]), bodies,
+            hvd.update_counts())
+
+
+def _updates(text, leaves):
+    """``{(shape, fusion kind): [op_name, ...]}`` of the fusions that hold a
+    weight's optimizer update: their result tuple holds two or more float32
+    arrays of a parameter matrix's shape (master, mu, nu beside the bf16
+    weight).  ``kOutput`` is a matmul's fusion with the update in its
+    epilogue, ``kLoop`` the update alone."""
+    shapes = {tuple(leaf.shape) for leaf in leaves if leaf.ndim >= 2}
+    found = collections.defaultdict(list)
+    for line in text.splitlines():
+        result = _RESULT.search(line) if " fusion(" in line else None
+        if not result or not result[1].startswith("("):
+            continue
+        held = collections.Counter(
+            tuple(map(int, dims.split(","))) for dims in _F32.findall(
+                result[1]))
+        for shape, n in held.items():
+            if n >= 2 and shape in shapes:
+                name = re.search(r'op_name="([^"]*)"', line)
+                found[shape, _KIND.search(line)[1]].append(
+                    name[1] if name else "")
+    return found
+
+
+def _engaged(leaves):
+    """The shapes of the leaves the rule takes, with their number."""
+    return collections.Counter(
+        tuple(leaf.shape) for leaf in leaves
+        if leaf.ndim >= 2 and math.prod(leaf.shape) >= hvd.ALONE_FROM_ELEMENTS
+        and jnp.issubdtype(leaf.dtype, jnp.floating))
+
+
+@pytest.fixture(scope="module")
+def hybrid_step(topo):
+    """The hybrid cell's step, compiled once for this module's two tests
+    of it (~1 min)."""
+    with _compiling_for_the_chip():
+        yield _compiled_step(topo, CELL)
+
+
+def test_the_cells_whole_step_fits_with_every_head(hybrid_step):
+    """Four layers of the published widths at 1 x 8192 tokens, all 30
+    heads of both mixers: 13.0 GB of state, and arguments + temporaries
+    under what the compiler has of the chip.  The softmax layer is two
+    flash calls (its forward call is not run again: the policy keeps its
+    output).  A linear layer is nine Mosaic calls, all under
+    ``hvd.gdn.conv``: q's, k's and v's convolution forward, again under
+    recomputation, and backward; the rule is none, and no float32 array of
+    an activation's shape is left under that scope."""
+    compiled, job, _, bodies, _ = hybrid_step
+    config = manifest.cell(CELL)["config"]
+    assert config["num_attention_heads"] == 30
+    assert config["linear_num_value_heads"] == 30
     # The trace took the pass for q, k and v of each linear layer, and the
     # plain body for none.
-    after = short_conv.body_counts()
     linear = sum(map(job.llama.is_linear, range(job.llama.num_layers)))
-    assert after["fused"] - before["fused"] == 3 * linear == 9
-    assert after["plain"] == before["plain"]
+    assert bodies == {"fused": 3 * linear, "plain": True} and linear == 3
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
     assert sum(scopes.FLASH_FWD in c for c in calls) == 1
@@ -144,6 +217,60 @@ def test_the_cells_whole_step_fits_with_every_head(topo, one_chip):
     assert memory.argument_size_in_bytes == pytest.approx(13.005e9, rel=1e-3)
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < HBM
+
+
+def test_the_cells_large_weights_are_updated_alone(hybrid_step):
+    """``w_gate_up`` (84.5M) and ``w_down`` (42.3M) of the four layers, the
+    head and the embedding (48.2M each) pass ``DistributedOptimizer``'s
+    barrier: no matmul fusion (``kOutput``) holds the float32 master and
+    moments of such a shape, one loop fusion each does, named by what
+    ``optimizer_ms`` reads (its root is ``optax.apply_updates``' add, so
+    ``hvd.apply``; the moments inside it are ``hvd.optimizer``'s).  The
+    18 projections of 11-22M and the six ``[3840, 30]`` gates stay in
+    their matmuls.  The gradients' longer lives cost 0.11 GB of
+    temporaries (2.987 GB with no leaf engaged; ledger, PR 43)."""
+    compiled, _, leaves, _, counts = hybrid_step
+    engaged = _engaged(leaves)
+    assert engaged == {(3840, 22016): 4, (11008, 3840): 4,
+                       (3840, 12544): 1, (12544, 3840): 1}
+    assert counts == {"alone": 10, "fused": len(leaves) - 10}
+    updates = _updates(compiled.as_text(), leaves)
+    for shape, n in engaged.items():
+        assert (shape, "kOutput") not in updates, shape
+        alone = updates[shape, "kLoop"]
+        assert len(alone) == n, (shape, alone)
+        for name in alone:
+            assert scopes.OPTIMIZER in name or scopes.APPLY in name, name
+    inside = {shape: len(names) for (shape, kind), names in updates.items()
+              if kind == "kOutput"}
+    assert inside == {(3840, 2880): 6, (3840, 5760): 6, (3840, 3840): 4,
+                      (5760, 3840): 3, (3840, 30): 6}
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= 3.15e9
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < HBM
+
+
+def test_ouro_s2k_keeps_every_update_in_its_matmul_but_the_heads(
+        topo, one_chip):
+    """``ouro-2.6b.train-s2k``'s 55 weight matrices: the head's
+    ``[2048, 49152]`` (100.7M) leaves its matmul, the 54 of the nine
+    layers (23.1M, 11.5M and 4.2M, which cost their parts fused: PERF.md
+    §5) stay; the embedding's update was alone before.  The four-chip step
+    has no fused update at all:
+    ``tests/test_gradient_allreduce_v5e_compile.py``."""
+    compiled, _, leaves, _, counts = _compiled_step(topo, OURO)
+    assert _engaged(leaves) == {(2048, 49152): 1, (49152, 2048): 1}
+    assert len(leaves) == 75 and counts == {"alone": 2, "fused": 73}
+    updates = {key: len(names) for key, names in _updates(
+        compiled.as_text(), leaves).items()}
+    assert updates == {((2048, 2048), "kOutput"): 36,
+                       ((2048, 11264), "kOutput"): 9,
+                       ((5632, 2048), "kOutput"): 9,
+                       ((2048, 49152), "kLoop"): 1,
+                       ((49152, 2048), "kLoop"): 1}
+    # 4.624 GB with the head's update fused (ledger, PR 43).
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4.63e9
 
 
 def test_gspmd_step_of_a_hybrid_model_holds_no_mosaic_call(one_chip, topo):
