@@ -30,7 +30,6 @@ Typical use::
 from __future__ import annotations
 
 import functools
-import threading
 from typing import Callable, Optional
 
 import jax
@@ -49,6 +48,7 @@ from horovod_tpu.common import (
 )
 from horovod_tpu.common import init as _init
 from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.common import trace_counts as _trace_counts
 from horovod_tpu.common.compile_cache import (
     compile_log,
     enable_compile_cache,
@@ -346,8 +346,7 @@ def allreduce_gradients(grads, *, axis_name=None, op=Average,
 #: PR 44).
 ALONE_FROM_ELEMENTS = 40_000_000
 
-_counts_lock = threading.Lock()
-_counts = {"alone": 0, "fused": 0}
+_UPDATE = "optimizer.update"     # its kind in common/trace_counts.py
 
 
 def update_counts() -> dict:
@@ -358,8 +357,7 @@ def update_counts() -> dict:
     to the compiler, which may put the update into the gradient's fusion.
     Counted at trace time (readable after ``step.lower``), process-global,
     like ``ops/short_conv.py::body_counts``."""
-    with _counts_lock:
-        return dict(_counts)
+    return {"alone": 0, "fused": 0} | _trace_counts.counts(_UPDATE)
 
 
 def _alone(grads):
@@ -376,8 +374,8 @@ def _alone(grads):
     engaged = [_is_traced(g) and g.ndim >= 2
                and g.size >= ALONE_FROM_ELEMENTS
                and jnp.issubdtype(g.dtype, jnp.floating) for g in leaves]
-    with _counts_lock:
-        _counts.update(alone=sum(engaged), fused=len(leaves) - sum(engaged))
+    _trace_counts.note_last(_UPDATE, {
+        "alone": sum(engaged), "fused": len(leaves) - sum(engaged)})
     return jax.tree.unflatten(treedef, [
         jax.lax.optimization_barrier(g) if taken else g
         for g, taken in zip(leaves, engaged)])

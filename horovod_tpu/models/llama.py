@@ -48,11 +48,11 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
-from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.gated_delta import (gated_delta_rule,
                                          gated_delta_states)
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
-from horovod_tpu.ops.rope import rotate_pairs, rotates_in_place
+from horovod_tpu.ops import rope as _rope
+from horovod_tpu.ops.short_conv import convolved, over_heads
 from horovod_tpu.ops.sparse_index import index_loss, select_keys
 
 __all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "RopeParameters",
@@ -554,27 +554,15 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, *,
                in_place: bool = False) -> jax.Array:
     """Rotate pairs (x[..., ::2], x[..., 1::2]).  x: [B, S, H, D].
 
-    In ``jnp`` the pairs are taken apart by stride-2 slices (a gather and
-    copies to XLA:TPU), turned in float32 and interleaved again: any width,
-    any partitioning.  ``in_place`` is for q and k on their way to an
-    ``attention_fn`` that reads them where the projections left them
-    (``_reads_in_place``): heads of whole 128-lane tiles, and a sequence in
-    whole blocks of rows, are then turned by ``ops/rope.py``'s one Mosaic
-    pass over the ``[B, S, H * D]`` view, which gives the same bits and
-    leaves the layout alone.  A Mosaic call is the caller's choice, as the
-    flash kernel is: the partitioner cannot split one, so under a plain
-    ``jit`` over several chips it needs a ``shard_map`` around it."""
-    B, S, H, D = x.shape
-    if in_place and rotates_in_place((B, S, H * D), D):
-        return rotate_pairs(x.reshape(B, S, H * D), cos, sin).reshape(x.shape)
-    x1 = x[..., 0::2].astype(jnp.float32)
-    x2 = x[..., 1::2].astype(jnp.float32)
-    c = cos[None, :, None, :]
-    s = sin[None, :, None, :]
-    r1 = x1 * c - x2 * s
-    r2 = x1 * s + x2 * c
-    out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
+    ``ops/rope.py::rotate``: in ``jnp``, any width and any partitioning,
+    unless ``in_place`` says that the trace may hold Mosaic calls on operands
+    where they lie (q and k on their way to an ``attention_fn`` that reads
+    them where the projections left them, ``_reads_in_place``) and the shape
+    is one its Mosaic pass takes; the same bits either way.  A Mosaic call
+    is the caller's choice, as the flash kernel is: the partitioner cannot
+    split one, so under a plain ``jit`` over several chips it needs a
+    ``shard_map`` around it."""
+    return _rope.rotate(x, cos, sin, in_place)
 
 
 def _reads_in_place(attention_fn) -> bool:
@@ -644,6 +632,7 @@ class LlamaAttention(nn.Module):
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
     index: int = 0
+    in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -669,9 +658,8 @@ class LlamaAttention(nn.Module):
         with (jax.named_scope(_scopes.ATTN_WINDOW) if window is not None
               else contextlib.nullcontext()):
             if cos is not None:
-                in_place = _reads_in_place(self.attention_fn)
-                q = apply_rope(q, cos, sin, in_place=in_place)
-                k = apply_rope(k, cos, sin, in_place=in_place)
+                q = apply_rope(q, cos, sin, in_place=self.in_place)
+                k = apply_rope(k, cos, sin, in_place=self.in_place)
             out = self.attend(x, q, k, v, cos, sin)
         out = out.reshape(B, S, heads * D)
         if cfg.gating == "per-head":
@@ -693,9 +681,9 @@ def _gated_heads(out, logits):
     ``sigmoid(logits [B, S, heads])`` of its head, in the dtype of out.
     The sigmoid is taken in float32 and rounded once; a head's gate reaches
     its lanes by a product with the heads' 0/1 indicator ``[heads, heads *
-    D]`` (exact: one term a lane), for the reason ``_over_heads`` gives: a
-    reshape to ``[.., heads, D]`` and a broadcast have XLA:TPU relay the
-    tensor."""
+    D]`` (exact: one term a lane), for the reason
+    ``ops/short_conv.py::over_heads`` gives: a reshape to ``[.., heads, D]``
+    and a broadcast have XLA:TPU relay the tensor."""
     heads = logits.shape[-1]
     gate = jax.nn.sigmoid(logits.astype(jnp.float32)).astype(out.dtype)
     of_head = (jnp.arange(heads)[:, None]
@@ -802,6 +790,7 @@ class LatentAttention(nn.Module):
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
     index: int = 0      # every mixer is told its layer; this one is the same in all
+    in_place: bool = False      # and ``LlamaLayer``'s reading of attention_fn
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -818,12 +807,14 @@ class LatentAttention(nn.Module):
         q = dense(heads * (d_n + d_r), "wq")(x).reshape(
             B, S, heads, d_n + d_r)
         q = jnp.concatenate(
-            [q[..., :d_n], apply_rope(q[..., d_n:], cos, sin)], axis=-1)
+            [q[..., :d_n], apply_rope(q[..., d_n:], cos, sin,
+                                      in_place=self.in_place)], axis=-1)
         with jax.named_scope(_scopes.MLA_LATENT):
             latent = dense(rank + d_r, "wkv_a")(x)
             c_kv = RMSNorm(cfg.rms_eps, cfg.dtype,
                            name="kv_norm")(latent[..., :rank])
-            k_r = apply_rope(latent[..., None, rank:], cos, sin)
+            k_r = apply_rope(latent[..., None, rank:], cos, sin,
+                             in_place=self.in_place)
             kv = dense(heads * (d_n + d_v), "wkv_b")(c_kv).reshape(
                 B, S, heads, d_n + d_v)
             k = jnp.concatenate(
@@ -1126,77 +1117,14 @@ class RoutedExperts(nn.Module):
         return y
 
 
-def _short_convolution(x, taps):
-    """Causal depthwise convolution along the sequence, one filter a
-    channel and zero history before position 0: ``y[t] = sum_i taps[i] *
-    x[t - (K - 1) + i]``.  x ``[B, S, C]``, taps ``[K, C]``; float32 out.
-    K shifted multiply-adds.  XLA:TPU does NOT make one pass of them and
-    what follows: with the SiLU, the heads' norm and their gradients the
-    trace shows chains of float32 fusions over ``[B, S, C]``, 51 ms of a
-    594 ms step at 8192 x 11,520 channels (PERF.md, PR 38).  The one pass
-    is ``ops/short_conv.py``, which ``_convolved`` takes where it may; this
-    is the body of every other path, and the tests' yardstick."""
-    seq, k = x.shape[1], taps.shape[0]
-    x = x.astype(jnp.float32)
-    y = x * taps[k - 1]
-    for back in range(1, k):
-        y = y + jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :seq] * taps[
-            k - 1 - back]
-    return y
-
-
-def _over_heads(x, heads):
-    """For ``x [.., heads * d]``: each head's sum ``[.., heads]``, and the
-    function that spreads a value a head back over its d lanes.  Both are
-    products with the heads' 0/1 indicator ``[heads * d, heads]`` (exact in
-    float32 at ``highest``, and nothing beside the other products): a
-    reshape to ``[.., heads, d]`` where d is no multiple of the 128 lanes
-    (96, 192) has XLA:TPU relay the tensor, in float32, either side of
-    every reduction (PERF.md, PR 38)."""
-    width = x.shape[-1]
-    of_head = (jnp.arange(width)[:, None] // (width // heads)
-               == jnp.arange(heads)[None, :]).astype(jnp.float32)
-    precision = jax.lax.Precision.HIGHEST
-    return (jnp.matmul(x, of_head, precision=precision),
-            lambda a_head: jnp.matmul(a_head, of_head.T, precision=precision))
-
-
-@functools.partial(jax.checkpoint, static_argnums=(2, 3))
-def _convolved_plain(y, taps, heads, scale):
-    """``_convolved`` in ``jnp``: any shape, any partitioning.  Under a
-    checkpoint: the backward pass keeps y and makes the float32 values
-    between again."""
-    out = nn.silu(_short_convolution(y, taps))
-    if scale is not None:
-        squares, spread = _over_heads(out * out, heads)
-        out = out * spread(scale * jax.lax.rsqrt(squares + 1e-6))
-    return out.astype(y.dtype)
-
-
-def _convolved(y, taps, heads, scale, in_place=False):
-    """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
-    head L2-normed and multiplied by ``scale`` where that is not None.
-    ``in_place`` is for a mixer whose model's ``attention_fn`` reads its
-    operands where the projections left them (``_reads_in_place``): the
-    chain is then ``ops/short_conv.py``'s one Mosaic pass forward and one
-    backward, where the shape is one it takes.  Elsewhere the ``jnp`` body:
-    a Mosaic call is the caller's choice, as in ``apply_rope``.  Which body
-    a trace took, and why, ``short_conv.body_counts()`` says."""
-    why = (short_conv.why_not(y.shape, taps.shape, heads) if in_place
-           else short_conv.NOT_IN_PLACE)
-    short_conv.note_body(why)
-    if why is None:
-        return short_conv.short_conv(y, taps, heads, scale)
-    return _convolved_plain(y, taps, heads, scale)
-
-
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
 def _gated_norm(o, z, scale, heads, eps):
     """``rms_norm(o) * scale * silu(z)``: o and z ``[B, S, heads * d_v]``,
     o normed a head, ``scale [d_v]`` shared by the heads; float32 inside,
-    the dtype of z out, and under a checkpoint as ``_convolved``."""
+    the dtype of z out, and under a checkpoint as
+    ``ops/short_conv.py::convolved``'s plain body."""
     o = o.astype(jnp.float32)
-    squares, spread = _over_heads(o * o, heads)
+    squares, spread = over_heads(o * o, heads)
     o = o * spread(jax.lax.rsqrt(squares * (heads / o.shape[-1]) + eps))
     return (o * jnp.tile(scale, heads) * nn.silu(z.astype(jnp.float32))
             ).astype(z.dtype)
@@ -1250,7 +1178,7 @@ class GatedDeltaNet(nn.Module):
     """
 
     config: LlamaConfig
-    in_place: bool = False      # the model's attention_fn reads in place
+    in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
 
     @nn.compact
     def __call__(self, x):
@@ -1266,7 +1194,7 @@ class GatedDeltaNet(nn.Module):
                             name=name)(x)
 
         def conv(y, name, heads, scale):
-            return _convolved(y, self.param(
+            return convolved(y, self.param(
                 name, _conv_taps_init, (taps, y.shape[-1])), heads, scale,
                 self.in_place)
 
@@ -1333,14 +1261,14 @@ class LlamaLayer(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin):
         cfg = self.config
+        # The one reading of the rule: every mixer is handed the answer.
+        in_place = _reads_in_place(self.attention_fn)
         if cfg.is_linear(self.index):
-            mixer = GatedDeltaNet(
-                cfg, in_place=_reads_in_place(self.attention_fn),
-                name="linear")
+            mixer = GatedDeltaNet(cfg, in_place=in_place, name="linear")
         else:
             mixer = functools.partial(ATTENTION_KINDS[cfg.attention_kind](
                 cfg, attention_fn=self.attention_fn, index=self.index,
-                name="attn"), cos=cos, sin=sin)
+                in_place=in_place, name="attn"), cos=cos, sin=sin)
         if cfg.is_routed(self.index):
             ffn = RoutedExperts(cfg, name="moe")
         else:

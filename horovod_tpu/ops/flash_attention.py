@@ -81,17 +81,19 @@ from __future__ import annotations
 
 import functools
 import math
-import threading
-import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.custom_derivatives import (SymbolicZero,
+                                    custom_vjp_primal_tree_values)
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.common import trace_counts as _trace_counts
 
 __all__ = ["flash_attention", "flash_attention_fn", "flash_attention_lse",
            "flash_attention_selected", "flash_lse_supported",
@@ -99,14 +101,18 @@ __all__ = ["flash_attention", "flash_attention_fn", "flash_attention_lse",
 
 # Non-kernel-path observability: a production config losing a Pallas
 # kernel should not do so silently.  flash_attention itself pads any
-# shape to the kernel, so the counter tracks COMPOSING callers choosing
+# shape to the kernel, so the first kind tracks COMPOSING callers choosing
 # a non-kernel implementation (e.g. ring attention's XLA online-softmax
-# hop when the strict lse kernel's tiling is off).  Each distinct reason
-# warns once per process; the counter counts every fallback TRACE (not
-# execution — under jit the choice is made at trace time).  Guarded by a
-# lock: jax tracing can run on multiple threads.
-_fallbacks: dict = {}
-_fallbacks_lock = threading.Lock()
+# hop when the strict lse kernel's tiling is off): each distinct reason
+# warns once per process, and every fallback TRACE is counted (not
+# execution — under jit the choice is made at trace time).  The second is
+# the layout the public entry points handed the two calls.  Both live in
+# ``common/trace_counts.py``.
+_FALLBACK = "flash.fallback"
+_LAYOUT = "flash.layout"
+_IN_PLACE = "in place"
+_OFF_TILING = "head width off the lane tiling"
+_OWN_OPERANDS = "flash_attention called with operands of the caller's own"
 
 
 def fallback_count() -> int:
@@ -116,25 +122,7 @@ def fallback_count() -> int:
     summed over every reason and call site in this process (the counter
     is process-global, incremented once per traced fallback, not per
     kernel execution)."""
-    with _fallbacks_lock:
-        return sum(_fallbacks.values())
-
-
-def _note_fallback(reason: str) -> None:
-    with _fallbacks_lock:
-        first = reason not in _fallbacks
-        _fallbacks[reason] = _fallbacks.get(reason, 0) + 1
-    if first:
-        warnings.warn("flash kernel not used: " + reason,
-                      RuntimeWarning, stacklevel=3)
-
-
-# Which layout the public entry points handed the two calls, counted like
-# the fallbacks: once a TRACE, process-global, under the same lock.
-_IN_PLACE = "in place"
-_OFF_TILING = "head width off the lane tiling"
-_OWN_OPERANDS = "flash_attention called with operands of the caller's own"
-_layouts: dict = {}
+    return sum(_trace_counts.counts(_FALLBACK).values())
 
 
 def layout_counts() -> dict:
@@ -147,15 +135,8 @@ def layout_counts() -> dict:
     128; ``flash_attention called with operands of the caller's own``: not
     through the model zoo's seam, ``flash_attention_fn``).  Counted at trace
     time, like ``fallback_count``."""
-    with _fallbacks_lock:
-        flat = {reason: n for reason, n in _layouts.items()
-                if reason != _IN_PLACE}
-        return {"in_place": _layouts.get(_IN_PLACE, 0), "flat": flat}
-
-
-def _note_layout(which: str) -> None:
-    with _fallbacks_lock:
-        _layouts[which] = _layouts.get(which, 0) + 1
+    flat = _trace_counts.counts(_LAYOUT)
+    return {"in_place": flat.pop(_IN_PLACE, 0), "flat": flat}
 
 
 _NEG_INF = float("-inf")
@@ -820,35 +801,62 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None,
     return dq, dk, dv
 
 
-def _bwd(causal, sm_scale, heads, window, res, do):
-    return _bwd_impl(causal, sm_scale, res, do, heads=heads,
-                     **_windowed(window))
-
-
 # ---------------------------------------------------------------------------
 # custom_vjp plumbing + public API
 # ---------------------------------------------------------------------------
 
-# ``heads`` (static, last) is how many heads q keeps side by side on its last
-# axis: ``[B, S, heads * D]`` operands in place, or the flat ``[B * H, S, D]``
-# with one (``_fwd``).  ``window`` (static, after it) is the band's width in
-# keys, None for all the causal ones.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, heads=1, window=None):
-    out, _ = _fwd(q, k, v, causal, sm_scale, heads=heads, window=window)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _flash(q, k, v, bias, seg, mask, causal, sm_scale, heads=1, window=None):
+    """The two calls as one differentiable function, for every public entry
+    point: ``(out, lse [BH, 8, S])``, the log-sum-exp as the kernels keep it
+    (sublane-replicated: row 0 is the value, the only one a caller reads).
+    ``bias``, ``seg`` and ``mask`` are ``_fwd``'s sidebands, None where
+    absent; ``heads`` (static) is how many heads q keeps side by side on its
+    last axis (``_fwd``), ``window`` (static) the band's width in keys, None
+    for all the causal ones.  Blockwise consumers (ring attention)
+    differentiate through the lse; a caller that drops it, or stops its
+    gradient, pays nothing for it in the backward pass."""
+    return _fwd(q, k, v, causal, sm_scale, bias, seg, mask, heads, window)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, heads, window):
-    out, lse = _fwd(q, k, v, causal, sm_scale, heads=heads, window=window)
+def _flash_fwd(q, k, v, bias, seg, mask, causal, sm_scale, heads, window):
+    # (symbolic_zeros: each operand arrives with whether it is perturbed.)
+    q, k, v, bias, seg, mask = custom_vjp_primal_tree_values(
+        (q, k, v, bias, seg, mask))
+    out, lse = _fwd(q, k, v, causal, sm_scale, bias, seg, mask, heads, window)
     # Named for recomputation policies (LlamaConfig.remat): a policy that
     # keeps both spares the backward pass this call.  Identity otherwise.
     out = checkpoint_name(out, _scopes.FLASH_OUT_NAME)
     lse = checkpoint_name(lse, _scopes.FLASH_LSE_NAME)
-    return out, (q, k, v, out, lse)
+    return (out, lse), (q, k, v, bias, seg, mask, out, lse)
 
 
-_flash.defvjp(_flash_fwd, _bwd)
+def _flash_bwd(causal, sm_scale, heads, window, res, cts):
+    q, k, v, bias, seg, mask, out, lse = res
+    do, g_lse = cts
+    # Nobody differentiates the lse: delta as it is.  Else row 0's cotangent
+    # folds into it (``_bwd_impl``).
+    g_lse = None if isinstance(g_lse, SymbolicZero) else g_lse[:, 0, :]
+    if isinstance(do, SymbolicZero):
+        do = jnp.zeros(do.shape, do.dtype)
+    dq, dk, dv = _bwd_impl(causal, sm_scale, (q, k, v, out, lse), do,
+                           bias=bias, seg=seg, g_lse=g_lse, mask=mask,
+                           heads=heads, window=window)
+
+    def no_gradient(x):
+        # The bias is a constant mask encoding (0 / -1e30): zeros.  An
+        # integer input (segment starts, a selection): JAX requires float0.
+        if x is None:
+            return None
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            return jnp.zeros_like(x)
+        return np.zeros(x.shape, dtype=jax.dtypes.float0)
+
+    return (dq, dk, dv, no_gradient(bias), no_gradient(seg),
+            no_gradient(mask))
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd, symbolic_zeros=True)
 
 
 def _flat_layout(q, k, v):
@@ -886,38 +894,16 @@ def _kernel_layout(q, k, v, in_place=True):
     B, S, Hq, D = q.shape
     Dv = v.shape[-1]
     if in_place and D % 128 == 0 and Dv % 128 == 0:
-        _note_layout(_IN_PLACE)
+        _trace_counts.note(_LAYOUT, _IN_PLACE)
 
         def side_by_side(x):
             return x.reshape(B, S, -1)
 
         return (side_by_side(q), side_by_side(k), side_by_side(v), Hq,
                 lambda out: out.reshape(B, S, Hq, Dv))
-    _note_layout(_OFF_TILING if in_place else _OWN_OPERANDS)
+    _trace_counts.note(_LAYOUT, _OFF_TILING if in_place else _OWN_OPERANDS)
     return (*_flat_layout(q, k, v), 1,
             lambda out: out.reshape(B, Hq, S, Dv).transpose(0, 2, 1, 3))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_lse(q, k, v, causal, sm_scale, heads=1):
-    """Like ``_flash`` but ALSO returns the per-row log-sum-exp [BH, S]
-    as a differentiable output — the merge statistic blockwise consumers
-    (ring attention) need to combine partial attentions."""
-    out, lse = _fwd(q, k, v, causal, sm_scale, heads=heads)
-    return out, lse[:, 0, :]
-
-
-def _flash_lse_fwd(q, k, v, causal, sm_scale, heads):
-    out, lse = _fwd(q, k, v, causal, sm_scale, heads=heads)
-    return (out, lse[:, 0, :]), (q, k, v, out, lse)
-
-
-def _flash_lse_bwd(causal, sm_scale, heads, res, cts):
-    do, g_lse = cts
-    return _bwd_impl(causal, sm_scale, res, do, g_lse=g_lse, heads=heads)
-
-
-_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def _pad_head_dim(q, k, v):
@@ -974,8 +960,8 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
         return out[..., :Dv], lse
     sm_scale = _sm_scale if _sm_scale is not None else 1.0 / math.sqrt(D)
     qt, kt, vt, heads, restore = _kernel_layout(q, k, v)
-    out, lse = _flash_lse(qt, kt, vt, causal, sm_scale, heads)
-    return restore(out), lse.reshape(B, Hq, S)
+    out, lse = _flash(qt, kt, vt, None, None, None, causal, sm_scale, heads)
+    return restore(out), lse[:, 0, :].reshape(B, Hq, S)
 
 
 def flash_lse_supported(S: int, D: int) -> bool:
@@ -983,83 +969,6 @@ def flash_lse_supported(S: int, D: int) -> bool:
     padded internally; S stays strict — the blockwise caller owns the
     sequence layout)."""
     return S % 128 == 0 and _pick_block(S, BLOCK_Q) > 0
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_biased(q, k, v, bias, causal, sm_scale, heads=1):
-    out, _ = _fwd(q, k, v, causal, sm_scale, bias, heads=heads)
-    return out
-
-
-def _flash_biased_fwd(q, k, v, bias, causal, sm_scale, heads):
-    out, lse = _fwd(q, k, v, causal, sm_scale, bias, heads=heads)
-    return out, (q, k, v, bias, out, lse)
-
-
-def _flash_biased_bwd(causal, sm_scale, heads, res, do):
-    q, k, v, bias, out, lse = res
-    dq, dk, dv = _bwd_impl(causal, sm_scale, (q, k, v, out, lse), do,
-                           bias=bias, heads=heads)
-    # The bias is a constant mask encoding (0 / -1e30); no useful gradient.
-    return dq, dk, dv, jnp.zeros_like(bias)
-
-
-_flash_biased.defvjp(_flash_biased_fwd, _flash_biased_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_seg(q, k, v, seg, causal, sm_scale, heads=1, window=None):
-    out, _ = _fwd(q, k, v, causal, sm_scale, seg=seg, heads=heads,
-                  window=window)
-    return out
-
-
-def _flash_seg_fwd(q, k, v, seg, causal, sm_scale, heads, window):
-    out, lse = _fwd(q, k, v, causal, sm_scale, seg=seg, heads=heads,
-                    window=window)
-    return out, (q, k, v, seg, out, lse)
-
-
-def _flash_seg_bwd(causal, sm_scale, heads, window, res, do):
-    import numpy as np
-
-    q, k, v, seg, out, lse = res
-    dq, dk, dv = _bwd_impl(causal, sm_scale, (q, k, v, out, lse), do,
-                           seg=seg, heads=heads, **_windowed(window))
-    # Integer input: JAX requires a float0 cotangent.
-    return dq, dk, dv, np.zeros(seg.shape, dtype=jax.dtypes.float0)
-
-
-_flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_selected(q, k, v, mask, causal, sm_scale, heads=1):
-    """Attention over the keys ``mask [B, S, S]`` (int8, nonzero = in)
-    selects for each query, the same for every head: ``(out, lse [BH, S])``.
-    The lse is for a consumer that does not differentiate it (the target of
-    an indexer's loss): its cotangent is dropped."""
-    out, lse = _fwd(q, k, v, causal, sm_scale, mask=mask, heads=heads)
-    return out, lse[:, 0, :]
-
-
-def _flash_selected_fwd(q, k, v, mask, causal, sm_scale, heads):
-    out, lse = _fwd(q, k, v, causal, sm_scale, mask=mask, heads=heads)
-    out = checkpoint_name(out, _scopes.FLASH_OUT_NAME)
-    lse = checkpoint_name(lse, _scopes.FLASH_LSE_NAME)
-    return (out, lse[:, 0, :]), (q, k, v, mask, out, lse)
-
-
-def _flash_selected_bwd(causal, sm_scale, heads, res, cts):
-    import numpy as np
-
-    q, k, v, mask, out, lse = res
-    dq, dk, dv = _bwd_impl(causal, sm_scale, (q, k, v, out, lse), cts[0],
-                           mask=mask, heads=heads)
-    return dq, dk, dv, np.zeros(mask.shape, dtype=jax.dtypes.float0)
-
-
-_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
 
 
 def flash_attention_selected(q, k, v, selected, *,
@@ -1082,9 +991,10 @@ def flash_attention_selected(q, k, v, selected, *,
                          f"D={D}, Dv={v.shape[-1]}")
     sm_scale = _sm_scale if _sm_scale is not None else 1.0 / math.sqrt(D)
     qt, kt, vt, heads, restore = _kernel_layout(q, k, v)
-    out, lse = _flash_selected(qt, kt, vt, selected.astype(jnp.int8), True,
-                               sm_scale, heads)
-    return restore(out), jax.lax.stop_gradient(lse.reshape(B, Hq, S))
+    out, lse = _flash(qt, kt, vt, None, None, selected.astype(jnp.int8),
+                      True, sm_scale, heads)
+    return restore(out), jax.lax.stop_gradient(
+        lse[:, 0, :].reshape(B, Hq, S))
 
 
 def _segment_starts(segment_ids):
@@ -1240,18 +1150,17 @@ def _attend(q, k, v, causal, key_padding_mask, segment_ids, sm_scale,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     qt, kt, vt, heads, restore = _kernel_layout(q, k, v, in_place)
+    bias = seg = None
     if segment_ids is not None:
         starts = _segment_starts(jnp.asarray(segment_ids))
         # [B, S] -> [B, 8, S]: sublane-replicated (TPU tiling); heads are
         # folded away in the kernels' sideband BlockSpec.
         seg = jnp.broadcast_to(starts[:, None, :], (B, 8, S))
-        out = _flash_seg(qt, kt, vt, seg, causal, sm_scale, heads, window)
-    elif key_padding_mask is None:
-        out = _flash(qt, kt, vt, causal, sm_scale, heads, window)
-    else:
+    elif key_padding_mask is not None:
         bias = jnp.where(key_padding_mask, 0.0, -1e30).astype(jnp.float32)
         bias = jnp.broadcast_to(bias[:, None, :], (B, 8, S))
-        out = _flash_biased(qt, kt, vt, bias, causal, sm_scale, heads)
+    out, _ = _flash(qt, kt, vt, bias, seg, None, causal, sm_scale, heads,
+                    window)
     return restore(out)
 
 

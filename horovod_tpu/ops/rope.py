@@ -1,30 +1,33 @@
-"""The rotary rotation as one lane-local Mosaic pass.
+"""The rotary rotation: one lane-local Mosaic pass, or its ``jnp`` body.
 
-``models/llama.py::apply_rope`` turns interleaved pairs ``(x[2i], x[2i+1])``
-by the angle of their position.  Written in ``jnp`` it takes the pairs apart
-with stride-2 slices on the lane axis, which XLA:TPU compiles to a gather
-(its transpose: a scatter-add) and to copies of q and k either side of it:
-XLA has no lane rotate.  Mosaic has (``pltpu.roll``, the XLU), so here a
+``rotate`` (what ``models/llama.py::apply_rope`` calls) turns interleaved
+pairs ``(x[2i], x[2i+1])`` by the angle of their position.  Written in
+``jnp`` (``_rotate_plain``) it takes the pairs apart with stride-2 slices on
+the lane axis, which XLA:TPU compiles to a gather (its transpose: a
+scatter-add) and to copies of q and k either side of it: XLA has no lane
+rotate.  Mosaic has (``pltpu.roll``, the XLU), so in ``rotate_pairs`` a
 pair's partner comes from its neighbouring lane and nothing leaves the layout
-the projection's matmul wrote: ``rotate_pairs`` reads and writes the
-``[B, S, H * D]`` view that the flash calls index heads in
-(``ops/flash_attention.py``), one pass over HBM.
+the projection's matmul wrote: it reads and writes the ``[B, S, H * D]`` view
+that the flash calls index heads in (``ops/flash_attention.py``), one pass
+over HBM.
 
-The arithmetic is ``apply_rope``'s, product for product: to float32,
-``x1 * c - x2 * s`` on a pair's even lane and ``x1 * s + x2 * c`` on its odd
-one, one rounding to the input's dtype.  On both lanes that is
-``x * c + partner * t`` with ``t = -s`` (even) or ``+s`` (odd), so the call
-takes the two tables expanded to a head's lanes, ``[S, D]`` float32 each
-(never ``[S, H * D]``: a block's heads share them).  The transpose of a
-rotation is the rotation by the opposite angle: the same call with ``-sin``,
-and the tables are the only residuals.
+The arithmetic is the same, product for product: to float32, ``x1 * c - x2 *
+s`` on a pair's even lane and ``x1 * s + x2 * c`` on its odd one, one
+rounding to the input's dtype.  On both lanes that is ``x * c + partner * t``
+with ``t = -s`` (even) or ``+s`` (odd), so the call takes the two tables
+expanded to a head's lanes, ``[S, D]`` float32 each (never ``[S, H * D]``: a
+block's heads share them).  The transpose of a rotation is the rotation by
+the opposite angle: the same call with ``-sin``, and the tables are the only
+residuals.
 
-``apply_rope`` takes this pass only for q and k on their way to an
-``attention_fn`` that reads that layout (the flash seam: a Mosaic call is
-the caller's choice, since the partitioner cannot split one), and only at
-widths on the 128-lane tiling: an indexer's 64, latent attention's 64
-rotating dims, and every model with dense or ring attention keep its ``jnp``
-body.  Off-TPU the call runs in interpret mode, as the flash calls do.
+``rotate`` takes the pass only where its caller says that the trace may hold
+Mosaic calls on operands where they lie (``in_place``: q and k on their way
+to the flash seam; the partitioner cannot split a Mosaic call, so it is the
+caller's choice), and only at widths on the 128-lane tiling: an indexer's 64,
+latent attention's 64 rotating dims, and every model with dense or ring
+attention keep the ``jnp`` body.  Which a trace took, and why, is noted in
+``common/trace_counts.py`` under ``rope.body``.  Off-TPU the call runs in
+interpret mode, as the flash calls do.
 """
 
 from __future__ import annotations
@@ -37,8 +40,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.common import trace_counts as _trace_counts
 
-__all__ = ["rotate_pairs", "rotates_in_place"]
+__all__ = ["rotate", "rotate_pairs"]
+
+# Which body ``rotate`` took, by reason (``common/trace_counts.py``).
+BODY = "rope.body"
+IN_PLACE = "one Mosaic pass"
+NOT_IN_PLACE = "the caller's trace may hold no Mosaic call"
+OFF_TILING = "head width off the lane tiling, or no block of rows"
 
 _LANES = 128
 # Upper bound on a block of x, counted at four bytes an element (its float32
@@ -59,7 +69,7 @@ def _pick_rows(s: int, width: int) -> int:
     return 0
 
 
-def rotates_in_place(shape, head_dim: int) -> bool:
+def _rotates_in_place(shape, head_dim: int) -> bool:
     """Whether ``rotate_pairs`` takes ``x [B, S, H * head_dim]``: whole lane
     tiles a head, and a block of rows that divides S."""
     return (len(shape) == 3 and head_dim % _LANES == 0
@@ -120,10 +130,10 @@ def _rotate(x, cos, sin, interpret):
 @jax.custom_vjp
 def rotate_pairs(x, cos, sin):
     """Rotate the interleaved pairs of every head of ``x [B, S, H * D]`` by
-    ``cos``, ``sin`` ``[S, D / 2]`` (``rope_freqs``): ``apply_rope`` on the
+    ``cos``, ``sin`` ``[S, D / 2]`` (``rope_freqs``): the rotation on the
     layout the projections write and the flash calls read, as one Mosaic
     call under ``hvd.rope``.  ``D % 128 == 0`` and S a multiple of 16
-    (``rotates_in_place``).  Differentiable in x alone: the tables get zero
+    (``_rotates_in_place``).  Differentiable in x alone: the tables get zero
     cotangents."""
     return _rotate(x, cos, sin, interpret=_interpret())
 
@@ -139,3 +149,34 @@ def _rotate_bwd(tables, g):
 
 
 rotate_pairs.defvjp(_rotate_fwd, _rotate_bwd)
+
+
+def _rotate_plain(x, cos, sin):
+    """The rotation in ``jnp``, ``x [B, S, H, D]``: the pairs are taken apart
+    by stride-2 slices (a gather and copies to XLA:TPU), turned in float32
+    and interleaved again: any width, any partitioning.  The body of every
+    path that may hold no Mosaic call, and the tests' yardstick."""
+    x1 = x[..., 0::2].astype(jnp.float32)
+    x2 = x[..., 1::2].astype(jnp.float32)
+    c = cos[None, :, None, :]
+    s = sin[None, :, None, :]
+    r1 = x1 * c - x2 * s
+    r2 = x1 * s + x2 * c
+    out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
+    return out.astype(x.dtype)
+
+
+def rotate(x, cos, sin, in_place: bool):
+    """Rotate the pairs ``(x[..., ::2], x[..., 1::2])`` of ``x [B, S, H, D]``
+    by ``cos``, ``sin`` ``[S, D / 2]``.  ``in_place`` is the caller's word
+    that this trace may hold Mosaic calls on operands where they lie: a
+    shape ``rotate_pairs`` takes is then turned by its one pass over the
+    ``[B, S, H * D]`` view (the same bits, the layout left alone); else,
+    and at every other shape, ``_rotate_plain``."""
+    B, S, H, D = x.shape
+    why = (NOT_IN_PLACE if not in_place else
+           None if _rotates_in_place((B, S, H * D), D) else OFF_TILING)
+    _trace_counts.note(BODY, why or IN_PLACE)
+    if why is None:
+        return rotate_pairs(x.reshape(B, S, H * D), cos, sin).reshape(x.shape)
+    return _rotate_plain(x, cos, sin)

@@ -3,10 +3,10 @@ norm as one Mosaic pass forward and one backward.
 
 ``models/llama.py::GatedDeltaNet`` sends q, k and v through a causal
 depthwise convolution of a few taps, a SiLU and (q and k) a per-head L2 norm:
-elementwise work on a row and the rows just before it.  Written in ``jnp``
-(``llama.py::_convolved``'s plain body, which stays for every path that may
-hold no Mosaic call, and as the tests' yardstick) XLA:TPU runs it as chains
-of float32 fusions that write and read ``[B, S, channels]`` float32 arrays
+elementwise work on a row and the rows just before it (``convolved``, this
+module's one entry).  Written in ``jnp`` (``_convolved_plain``, which stays
+for every path that may hold no Mosaic call, and as the tests' yardstick)
+XLA:TPU runs it as chains of float32 fusions that write and read ``[B, S, channels]`` float32 arrays
 between them: 51 ms of a 594 ms step at 8192 x 11,520 channels, where the
 bytes of the bf16 tensors allow 5 (PERF.md, PR 38 and PR 40).  Here the
 chain crosses HBM once each way: ``short_conv`` is a ``jax.custom_vjp`` of
@@ -48,24 +48,25 @@ body.
 
 Which body a trace took is counted (``body_counts``), as
 ``ops/flash_attention.py::layout_counts`` counts layouts.  A Mosaic call is
-the caller's choice (the partitioner cannot split one): ``GatedDeltaNet``
-asks for this pass only where the model's ``attention_fn`` reads its
-operands in place (``llama.py::_reads_in_place``), as for the rotation.
-Off-TPU the calls run in interpret mode.
+the caller's choice (the partitioner cannot split one): ``convolved`` takes
+this pass only where its caller says that the trace may hold Mosaic calls
+on operands where they lie (``in_place``: ``llama.py::LlamaLayer`` reads it
+off the model's ``attention_fn``), as for the rotation.  Off-TPU the calls
+run in interpret mode.
 """
 
 from __future__ import annotations
 
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["short_conv", "why_not", "body_counts", "note_body",
-           "NOT_IN_PLACE"]
+from horovod_tpu.common import trace_counts as _trace_counts
+
+__all__ = ["convolved", "short_conv", "body_counts", "NOT_IN_PLACE"]
 
 _LANES = 128
 _TILE = 8         # rows of a float32 sublane tile: the history the body uses
@@ -79,30 +80,22 @@ _EPS = 1e-6
 _BLOCK_BYTES = 6 * 1024 * 1024
 _VMEM_LIMIT = 100 * 1024 * 1024
 
-# Why ``llama.py::_convolved`` took its plain body, by reason.
+# Which body ``convolved`` took (``common/trace_counts.py``): the Mosaic
+# pass, or the plain one by reason.
+_BODY = "short_conv.body"
+_FUSED = "one Mosaic pass each way"
 NOT_IN_PLACE = "the attention_fn does not read its operands in place"
 _NO_ROW_BLOCK = "no block of rows divides the sequence"
 _TOO_MANY_TAPS = "more taps than a sublane tile of history holds"
 _NOT_WHOLE_HEADS = "the channels are not whole heads"
 
-_counts_lock = threading.Lock()
-_counts: dict = {}
-
 
 def body_counts() -> dict:
     """``{"fused": n, "plain": {reason: n}}``: how many traced calls of
-    ``llama.py::_convolved`` took the Mosaic pass, and how many the
-    ``jnp`` body, by reason.  Process-global, counted once a TRACE."""
-    with _counts_lock:
-        plain = {why: n for why, n in _counts.items() if why is not None}
-        return {"fused": _counts.get(None, 0), "plain": plain}
-
-
-def note_body(why) -> None:
-    """Count one traced call: ``why`` is None for the Mosaic pass, else the
-    reason for the plain body."""
-    with _counts_lock:
-        _counts[why] = _counts.get(why, 0) + 1
+    ``convolved`` took the Mosaic pass, and how many the ``jnp`` body, by
+    reason.  Process-global, counted once a TRACE."""
+    plain = _trace_counts.counts(_BODY)
+    return {"fused": plain.pop(_FUSED, 0), "plain": plain}
 
 
 def _interpret() -> bool:
@@ -118,7 +111,7 @@ def _pick_rows(s: int, width: int) -> int:
     return 0
 
 
-def why_not(shape, taps_shape, heads: int):
+def _why_not(shape, taps_shape, heads: int):
     """None where ``short_conv`` takes ``y`` of ``shape [B, S, channels]``
     with ``taps_shape [K, channels]``, else the reason it does not."""
     if len(shape) != 3 or shape[2] % heads or taps_shape[1] != shape[2]:
@@ -521,7 +514,7 @@ def short_conv(y, taps, heads, scale):
     position 0 of every batch row), each head L2-normed (``rsqrt(sum of
     squares + 1e-6)``) and multiplied by ``scale`` where that is not None;
     float32 inside, the dtype of y out.  One Mosaic call, and one for both
-    gradients; ``why_not`` says which shapes it takes."""
+    gradients; ``_why_not`` says which shapes it takes."""
     scale, heads = _norm_of(heads, scale)
     return _forward(y, taps, scale, heads=heads, interpret=_interpret())
 
@@ -537,3 +530,67 @@ def _short_conv_bwd(heads, scale, kept, g):
 
 
 short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+
+
+# -- the plain body, and the one entry --------------------------------------
+
+def _short_convolution(x, taps):
+    """Causal depthwise convolution along the sequence, one filter a
+    channel and zero history before position 0: ``y[t] = sum_i taps[i] *
+    x[t - (K - 1) + i]``.  x ``[B, S, C]``, taps ``[K, C]``; float32 out.
+    K shifted multiply-adds.  XLA:TPU does NOT make one pass of them and
+    what follows: with the SiLU, the heads' norm and their gradients the
+    trace shows chains of float32 fusions over ``[B, S, C]``, 51 ms of a
+    594 ms step at 8192 x 11,520 channels (PERF.md, PR 38).  The one pass
+    is ``short_conv``, which ``convolved`` takes where it may; this is the
+    body of every other path, and the tests' yardstick."""
+    seq, k = x.shape[1], taps.shape[0]
+    x = x.astype(jnp.float32)
+    y = x * taps[k - 1]
+    for back in range(1, k):
+        y = y + jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :seq] * taps[
+            k - 1 - back]
+    return y
+
+
+def over_heads(x, heads):
+    """For ``x [.., heads * d]``: each head's sum ``[.., heads]``, and the
+    function that spreads a value a head back over its d lanes.  Both are
+    products with the heads' 0/1 indicator ``[heads * d, heads]`` (exact in
+    float32 at ``highest``, and nothing beside the other products): a
+    reshape to ``[.., heads, d]`` where d is no multiple of the 128 lanes
+    (96, 192) has XLA:TPU relay the tensor, in float32, either side of
+    every reduction (PERF.md, PR 38)."""
+    width = x.shape[-1]
+    of_head = (jnp.arange(width)[:, None] // (width // heads)
+               == jnp.arange(heads)[None, :]).astype(jnp.float32)
+    precision = jax.lax.Precision.HIGHEST
+    return (jnp.matmul(x, of_head, precision=precision),
+            lambda a_head: jnp.matmul(a_head, of_head.T, precision=precision))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(2, 3))
+def _convolved_plain(y, taps, heads, scale):
+    """``convolved`` in ``jnp``: any shape, any partitioning.  Under a
+    checkpoint: the backward pass keeps y and makes the float32 values
+    between again."""
+    out = jax.nn.silu(_short_convolution(y, taps))
+    if scale is not None:
+        squares, spread = over_heads(out * out, heads)
+        out = out * spread(scale * jax.lax.rsqrt(squares + 1e-6))
+    return out.astype(y.dtype)
+
+
+def convolved(y, taps, heads, scale, in_place: bool):
+    """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
+    head L2-normed and multiplied by ``scale`` where that is not None.
+    ``in_place`` is the caller's word that this trace may hold Mosaic calls
+    on operands where they lie: the chain is then ``short_conv``'s one pass
+    forward and one backward, where the shape is one it takes
+    (``_why_not``).  Elsewhere ``_convolved_plain``.  Which body a trace
+    took, and why, ``body_counts()`` says."""
+    why = _why_not(y.shape, taps.shape, heads) if in_place else NOT_IN_PLACE
+    _trace_counts.note(_BODY, why or _FUSED)
+    if why is None:
+        return short_conv(y, taps, heads, scale)
+    return _convolved_plain(y, taps, heads, scale)

@@ -113,7 +113,8 @@ def ring_attention(q, k, v, *, axis_name: str = "seq", causal: bool = True):
     (see :func:`_ring_attention_flash`); otherwise the XLA
     online-softmax path below runs.
     """
-    from horovod_tpu.ops.flash_attention import (_note_fallback,
+    from horovod_tpu.common import trace_counts
+    from horovod_tpu.ops.flash_attention import (_FALLBACK,
                                                  flash_lse_supported)
 
     if flash_lse_supported(q.shape[1], q.shape[3]) \
@@ -124,14 +125,14 @@ def ring_attention(q, k, v, *, axis_name: str = "seq", causal: bool = True):
     # (ops.flash_attention.fallback_count telemetry) whichever condition
     # failed.
     if not flash_lse_supported(q.shape[1], q.shape[3]):
-        _note_fallback(
-            f"ring attention hop uses the XLA online-softmax path: "
-            f"local shard length {q.shape[1]} is off the lse-kernel "
-            f"tiling (needs a multiple of 128)")
+        why = (f"local shard length {q.shape[1]} is off the lse-kernel "
+               f"tiling (needs a multiple of 128)")
     else:
-        _note_fallback(
-            f"ring attention hop uses the XLA online-softmax path: KV "
-            f"shard length {k.shape[1]} != Q shard length {q.shape[1]}")
+        why = f"KV shard length {k.shape[1]} != Q shard length {q.shape[1]}"
+    trace_counts.note(
+        _FALLBACK,
+        "ring attention hop uses the XLA online-softmax path: " + why,
+        warn="flash kernel not used: ")
 
     axis_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from horovod_tpu.common import scopes
+from horovod_tpu.common import scopes, trace_counts
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import rope
 
@@ -51,6 +51,11 @@ def one_chip(topo, monkeypatch):
     compilation_cache.reset_cache()
 
 
+def _flash(q, k, v, scale, heads=1):
+    """The two calls, causal and with no sideband: the output alone."""
+    return fa._flash(q, k, v, None, None, None, True, scale, heads)[0]
+
+
 def _mosaic_calls(compiled):
     return [line for line in compiled.as_text().splitlines()
             if _MOSAIC_CALL.search(line)]
@@ -75,7 +80,7 @@ def test_forward_and_backward_are_two_mosaic_calls_at_the_cells_shapes(
 
     def grads(q, k, v, w):
         return jax.grad(lambda q, k, v: jnp.sum(
-            fa._flash(q, k, v, True, SM_SCALE).astype(jnp.float32)
+            _flash(q, k, v, SM_SCALE).astype(jnp.float32)
             * w.astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
 
     compiled = jax.jit(grads).lower(x, x, x, x).compile()
@@ -158,7 +163,7 @@ def test_forward_call_refuses_a_32k_row_as_before(one_chip):
     x = jax.ShapeDtypeStruct((16, 32768, 128), jnp.bfloat16,
                              sharding=one_chip)
     with pytest.raises(Exception, match="vmem"):
-        jax.jit(lambda q, k, v: fa._flash(q, k, v, True, SM_SCALE)).lower(
+        jax.jit(lambda q, k, v: _flash(q, k, v, SM_SCALE)).lower(
             x, x, x).compile()
 
 
@@ -180,7 +185,7 @@ def test_forward_and_backward_at_192_and_128_are_two_mosaic_calls(one_chip):
 
     def grads(q, k, v, w):
         return jax.grad(lambda q, k, v: jnp.sum(
-            fa._flash(q, k, v, True, scale).astype(jnp.float32)
+            _flash(q, k, v, scale).astype(jnp.float32)
             * w.astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
 
     compiled = jax.jit(grads).lower(wide, wide, narrow, narrow).compile()
@@ -296,7 +301,7 @@ def test_calls_on_operands_in_place_are_two_and_nothing_is_relaid(
 
     def grads(q, k, v, w):
         return jax.grad(lambda q, k, v: jnp.sum(
-            fa._flash(q, k, v, True, SM_SCALE, heads).astype(jnp.float32)
+            _flash(q, k, v, SM_SCALE, heads).astype(jnp.float32)
             * w.astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
 
     compiled = jax.jit(grads).lower(q, k, k, q).compile()
@@ -370,6 +375,7 @@ def test_attention_block_relays_nothing_between_projections_and_calls(
     flash calls, ``wo``."""
     import flax.linen as nn
 
+    from horovod_tpu.models import llama
     from horovod_tpu.models.llama import (LlamaAttention, LlamaConfig,
                                           rope_freqs)
 
@@ -399,8 +405,10 @@ def test_attention_block_relays_nothing_between_projections_and_calls(
             attend.in_place = fa.flash_attention_fn.in_place
         cos, sin = rope_freqs(d, seq, 1e6)
         with jax.named_scope(scopes.BLOCK_ATTN):
-            out = LlamaAttention(config, attention_fn=attend).apply(
-                params, x, cos, sin)
+            out = LlamaAttention(
+                config, attention_fn=attend,
+                in_place=llama._reads_in_place(attend)).apply(
+                    params, x, cos, sin)
         return jnp.sum(out.astype(jnp.float32) * weight.astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
@@ -474,7 +482,8 @@ def test_gspmd_step_with_the_models_own_attention_holds_no_mosaic_call(
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.models.llama import LlamaConfig, LlamaModel
+    from horovod_tpu.models.llama import (LlamaConfig, LlamaModel,
+                                          rope_freqs)
     from horovod_tpu.parallel.api import (make_parallel_train_step,
                                           param_shardings)
 
@@ -483,7 +492,12 @@ def test_gspmd_step_with_the_models_own_attention_holds_no_mosaic_call(
         num_kv_heads=2, intermediate_size=1024, max_seq_len=64,
         dtype=jnp.bfloat16)
     assert config.head_dim == 128
-    assert rope.rotates_in_place((8, 64, 4 * 128), 128)
+    # (A shape the rotation's pass takes where it is asked to.)
+    taken = trace_counts.counts(rope.BODY).get(rope.IN_PLACE, 0)
+    jax.eval_shape(
+        lambda x: rope.rotate(x, *rope_freqs(128, 64, 1e4), in_place=True),
+        jax.ShapeDtypeStruct((8, 64, 4, 128), jnp.bfloat16))
+    assert trace_counts.counts(rope.BODY)[rope.IN_PLACE] == taken + 1
     mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2),
                 ("data", "fsdp", "tensor"))
     model = LlamaModel(config)

@@ -19,8 +19,7 @@ import pytest
 from benchmark import manifest
 from benchmark.reference import olmo_hybrid
 from horovod_tpu.models import LlamaConfig, LlamaModel
-from horovod_tpu.models.llama import (GatedDeltaNet, _convolved,
-                                      _short_convolution)
+from horovod_tpu.models.llama import GatedDeltaNet
 from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.flash_attention import flash_attention_fn
@@ -181,19 +180,19 @@ def test_bf16_inputs_keep_a_float32_state():
 @pytest.mark.parametrize("in_place, seq", [(False, 6), (True, 32)],
                          ids=["jnp", "mosaic"])
 def test_convolution_has_no_history_before_position_zero(in_place, seq):
-    """On both of ``_convolved``'s bodies; the Mosaic pass (interpreted
+    """On both of ``convolved``'s bodies; the Mosaic pass (interpreted
     here) wants whole blocks of 16 rows, so its sequence is two."""
     x = jax.random.normal(jax.random.key(7), (1, seq, 3))
     taps = jax.random.normal(jax.random.key(8), (4, 3))
+    before = short_conv.body_counts()["fused"]
     if in_place:
-        assert short_conv.why_not(x.shape, taps.shape, 1) is None
-
         def convolution(x, taps):
-            return _convolved(x, taps, 1, None, True)
+            return short_conv.convolved(x, taps, 1, None, True)
         first = jax.nn.silu
     else:
-        convolution, first = _short_convolution, lambda c: c
+        convolution, first = short_conv._short_convolution, lambda c: c
     y = convolution(x, taps)
+    assert short_conv.body_counts()["fused"] == before + int(in_place)
     np.testing.assert_allclose(y[0, 0], first(taps[3] * x[0, 0]), rtol=1e-5)
     np.testing.assert_allclose(
         y[0, 1], first(taps[3] * x[0, 1] + taps[2] * x[0, 0]), rtol=1e-5,
@@ -211,7 +210,7 @@ def test_convolution_has_no_history_before_position_zero(in_place, seq):
     # Causal: a later token changes nothing before it.
     later = convolution(x.at[0, 4].add(1.0), taps)
     np.testing.assert_array_equal(later[0, :4], y[0, :4])
-    unit = _convolved(x, taps, 1, 1.0, in_place)
+    unit = short_conv.convolved(x, taps, 1, 1.0, in_place)
     np.testing.assert_allclose(jnp.linalg.norm(unit, axis=-1), 1.0,
                                atol=1e-3)
 

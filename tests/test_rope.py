@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.common import scopes
+from horovod_tpu.common import scopes, trace_counts
 from horovod_tpu.models import llama
 from horovod_tpu.models.llama import YarnScaling, apply_rope, rope_freqs
 from horovod_tpu.ops import rope
@@ -30,6 +30,11 @@ in_place = functools.partial(apply_rope, in_place=True)
 
 B, S, H = 2, 64, 3
 YARN = YarnScaling(40, 4096, 32, 1, 0.707, 0.707)
+
+
+def _taken(reason):
+    """How many traces of ``ops/rope.py::rotate`` went ``reason``'s way."""
+    return trace_counts.counts(rope.BODY).get(reason, 0)
 
 
 def _bits(x):
@@ -73,8 +78,9 @@ def test_rotate_pairs_gives_the_jnp_bodys_bits(dtype, D, tables):
     x = jax.random.normal(kx, (B, S, H, D), jnp.bfloat16).astype(dtype)
     g = jax.random.normal(kg, (B, S, H, D), jnp.bfloat16).astype(dtype)
     offset = jnp.int32(5)
-    assert rope.rotates_in_place((B, S, H * D), D)
+    before = _taken(rope.IN_PLACE)
     got = _rotated_and_cotangent(in_place, x, g, offset, tables, True)
+    assert _taken(rope.IN_PLACE) == before + 1
     want = _rotated_and_cotangent(apply_rope, x, g, offset,
                                   tables, True)
     for a, b, what in zip(got, want, ("rotated", "cotangent")):
@@ -133,9 +139,9 @@ def test_the_pass_is_one_mosaic_call_under_its_own_scope():
 def test_other_widths_keep_the_jnp_body(shape, why):
     x = jax.random.normal(jax.random.key(0), shape, jnp.bfloat16)
     cos, sin = rope_freqs(shape[-1], shape[1], 1e4)
-    assert not rope.rotates_in_place((shape[0], shape[1],
-                                      shape[2] * shape[3]), shape[3]), why
+    before = _taken(rope.OFF_TILING)
     closed = jax.make_jaxpr(lambda x: in_place(x, cos, sin))(x)
+    assert _taken(rope.OFF_TILING) == before + 1, why
     assert _pallas_calls(closed.jaxpr) == []
     np.testing.assert_array_equal(
         _bits(in_place(x, cos, sin)), _bits(apply_rope(x, cos, sin)))

@@ -1,5 +1,5 @@
 """``ops/short_conv.py::short_conv`` (interpret mode on CPU, the Mosaic pass
-on TPU; ``llama.py::_convolved(..., in_place=True)``) against ``_convolved``'s
+on TPU; ``convolved(..., in_place=True)``) against ``convolved``'s
 ``jnp`` body, which stays the form for every model whose ``attention_fn``
 does not read its operands in place and for every shape the pass refuses.
 
@@ -15,9 +15,10 @@ import pytest
 
 from horovod_tpu.common import scopes
 from horovod_tpu.models import LlamaConfig, LlamaModel
-from horovod_tpu.models.llama import _convolved, causal_attention
+from horovod_tpu.models.llama import causal_attention
 from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.flash_attention import flash_attention_fn
+from horovod_tpu.ops.short_conv import convolved
 
 B, S, H, D, K = 2, 96, 3, 96, 4       # heads of 96: 2.25 lane tiles in all
 ROWS = 32                             # the block of rows S = 96 gets: three
@@ -42,10 +43,10 @@ def _pallas_calls(jaxpr, under=None):
 
 
 def _both_ways(in_place, scale):
-    """Forward and ``jax.vjp`` (dy and dtaps) of ``_convolved``, jitted."""
+    """Forward and ``jax.vjp`` (dy and dtaps) of ``convolved``, jitted."""
     def run(y, taps, g):
         out, vjp = jax.vjp(
-            lambda y, taps: _convolved(y, taps, H, scale, in_place), y, taps)
+            lambda y, taps: convolved(y, taps, H, scale, in_place), y, taps)
         return (out,) + vjp(g)
     return jax.jit(run)
 
@@ -71,10 +72,11 @@ def test_the_pass_gives_the_jnp_bodys_values_and_gradients(dtype, scale):
     y = jax.random.normal(ky, (B, S, H * D), jnp.float32).astype(dtype)
     g = jax.random.normal(kg, (B, S, H * D), jnp.float32).astype(dtype)
     taps = jax.random.uniform(kt, (K, H * D), jnp.float32, -0.5, 0.5)
-    assert short_conv.why_not(y.shape, taps.shape, H) is None
     assert short_conv._pick_rows(S, H * D) == ROWS
     fused, plain = _both_ways(True, scale), _both_ways(False, scale)
+    before = short_conv.body_counts()["fused"]
     got, want = fused(y, taps, g), plain(y, taps, g)
+    assert short_conv.body_counts()["fused"] == before + 1
     for a, b, what in zip(got, want, ("out", "dy", "dtaps")):
         assert a.shape == b.shape and a.dtype == b.dtype, what
         # The taps' gradient is a float32 sum over B * S rows either way.
@@ -110,7 +112,7 @@ def test_each_pass_is_one_mosaic_call_on_the_projections_layout():
     for scale, operands in ((None, 3), (1.0, 6)):
         def both(y, taps):
             out, vjp = jax.vjp(
-                lambda y, taps: _convolved(y, taps, H, scale, True), y, taps)
+                lambda y, taps: convolved(y, taps, H, scale, True), y, taps)
             return out, vjp(out)
         forward, backward = _pallas_calls(jax.make_jaxpr(both)(y, taps).jaxpr)
         assert len(forward.invars) == operands
@@ -133,14 +135,14 @@ def test_a_refused_shape_takes_the_jnp_body_and_the_counter_says_why(
     taps = jax.random.uniform(jax.random.key(1), (taps, shape[2]))
     before = short_conv.body_counts()
     closed = jax.make_jaxpr(
-        lambda y, taps: _convolved(y, taps, H, 1.0, in_place))(y, taps)
+        lambda y, taps: convolved(y, taps, H, 1.0, in_place))(y, taps)
     after = short_conv.body_counts()
     assert _pallas_calls(closed.jaxpr) == []
     assert after["fused"] == before["fused"]
     assert after["plain"][why] == before["plain"].get(why, 0) + 1
     # A shape it takes, asked for in place, counts as fused.
     good = jnp.zeros((B, S, H * D), jnp.bfloat16)
-    jax.make_jaxpr(lambda y: _convolved(
+    jax.make_jaxpr(lambda y: convolved(
         y, jnp.zeros((K, H * D)), H, 1.0, True))(good)
     assert short_conv.body_counts()["fused"] == after["fused"] + 1
     assert short_conv.body_counts()["plain"] == after["plain"]
