@@ -48,7 +48,10 @@ scope                 what falls under it
                       ``[hidden, heads]`` projection, the sigmoid, the
                       gate's way to its head's lanes and the multiply on
                       the attention's output, and their gradients.  Inside
-                      ``hvd.block.attn``, in full and sliding layers alike
+                      ``hvd.block.attn``, in full and sliding layers alike.
+                      The element-wise gate (``gating="elementwise"``) too:
+                      x times the gate's half of ``wq``, the sigmoid and
+                      the multiply, lane by lane
 ``hvd.loop.pass``     a looped model's passes over its layer stack
                       (``LlamaModel`` with ``total_ut_steps`` > 1): the
                       scan whole, so the walks over the layers and the
@@ -76,7 +79,10 @@ scope                 what falls under it
                       gradient products (see below)
 ``hvd.moe.combine``   the rows back in token order, weighted with their
                       gates and added up over a token's choices
-``hvd.moe.shared``    the shared experts' SwiGLU, which every chip computes
+``hvd.moe.shared``    the shared experts' SwiGLU, which every chip computes,
+                      and its gate where it has one (``shared_expert_gate``:
+                      the ``[hidden, 1]`` projection, the sigmoid and the
+                      multiply)
 ``hvd.sparse.index``  learned sparse attention's indexer
                       (``models/llama.py::SparseAttention``,
                       ``ops/sparse_index.py``): its three projections and
@@ -98,6 +104,14 @@ scope                 what falls under it
                       ``ops/short_conv.py``'s Mosaic calls (forward, again
                       under recomputation, backward) where the model's
                       ``attention_fn`` reads its operands in place
+``hvd.gdn.heads``     the same layer's key heads made as many as its value
+                      heads, where it has fewer (``linear_num_key_heads``
+                      < ``linear_num_value_heads``): q and k copied so
+                      that value head j reads key head ``j // (value /
+                      key)``, and the sum over each key head's copies
+                      that is the gradient; between ``hvd.gdn.conv`` and
+                      ``hvd.gdn.scan``, in neither.  A layer with as many
+                      of each does not enter it
 ``hvd.gdn.gates``     the same layer's gates: the two narrow projections,
                       log alpha and beta in float32; and behind the rule
                       the per-head RMSNorm of its output and the SiLU gate
@@ -205,7 +219,7 @@ __all__ = [
     "ATTN_WINDOW", "ATTN_GATE",
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
-    "GDN_CONV", "GDN_GATES", "GDN_SCAN",
+    "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS",
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
@@ -236,6 +250,7 @@ SPARSE_SELECT = "hvd.sparse.select"
 GDN_CONV = "hvd.gdn.conv"
 GDN_GATES = "hvd.gdn.gates"
 GDN_SCAN = "hvd.gdn.scan"
+GDN_HEADS = "hvd.gdn.heads"
 BLOCK_ATTN = "hvd.block.attn"
 BLOCK_FFN = "hvd.block.ffn"
 HEAD = "hvd.head"

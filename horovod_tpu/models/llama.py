@@ -226,6 +226,19 @@ class LlamaConfig:
     head count, window and table follow from its index (``heads_of``,
     ``window_of``, ``rope_of``); generation, the serve plane and the
     pipelined step refuse each by name.
+
+    ``gating`` ``"elementwise"`` is the other output gate of that paper, a
+    lane at a time: ``wq`` is ``[hidden, heads * 2 * head_dim]``, each head's
+    outputs a query and then a gate of ``head_dim`` lanes, and the head's
+    attention output is multiplied by ``sigmoid(gate)`` lane by lane before
+    W_o.  ``zero_centered_norm`` makes every ``RMSNorm`` of the stack (the
+    block norms, the final norm, the QK-norms; not the delta rule's output
+    norm) ``x / rms(x) * (1 + scale)`` with the scale from zeros: under a
+    weight decay on every leaf that is another model, the decay pulls the
+    multiplier to 1 and not to 0.  ``shared_expert_gate`` multiplies the
+    shared experts' output by ``sigmoid(x w_s)``, ``w_s`` hidden x 1.
+    Generation, the serve plane and the pipelined step refuse the three by
+    name.
     """
 
     vocab_size: int = 32000
@@ -254,9 +267,11 @@ class LlamaConfig:
     layer_types: Optional[tuple] = None
     sliding_window: Optional[int] = None
     num_attention_heads_per_layer: Optional[tuple] = None
-    gating: Optional[str] = None              # "per-head"
+    gating: Optional[str] = None              # "per-head", "elementwise"
     rope_parameters: Optional[tuple] = None   # ((layer type, RopeParameters),)
     routed_scaling_factor: float = 1.0
+    zero_centered_norm: bool = False
+    shared_expert_gate: bool = False
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
     linear_key_head_dim: int = 0
@@ -349,9 +364,17 @@ class LlamaConfig:
                 f"num_attention_heads_per_layer is {heads!r}: a multiple of "
                 f"the {self.num_kv_heads} key-value heads for each of "
                 f"{self.num_layers} layers")
-        if self.gating not in (None, "per-head"):
-            raise ValueError(f"gating is {self.gating!r}: 'per-head' or "
-                             f"None")
+        if self.gating not in (None, "per-head", "elementwise"):
+            raise ValueError(f"gating is {self.gating!r}: 'per-head', "
+                             f"'elementwise' or None")
+        if self.gating == "elementwise" and self.attention_kind == "latent":
+            raise ValueError("the element-wise gate is a half of a full or "
+                             "sparse attention layer's wq; latent attention "
+                             "has no such projection")
+        if self.shared_expert_gate and not (self.num_experts > 1
+                                            and self.shared_experts):
+            raise ValueError("shared_expert_gate gates shared experts: "
+                             "num_experts > 1 and shared_experts >= 1")
         if self.rope_parameters is not None:
             kinds = {kind for kind, _ in self.rope_parameters}
             used = set(self.layer_types or ("full_attention",)) - {
@@ -422,9 +445,10 @@ class LlamaConfig:
                 if self.layer_type(layer) == "sliding_attention" else None)
 
     def rope_of(self, layer: int) -> Optional[RopeParameters]:
-        """The rotation of ``layer``'s q and k; None: they do not turn."""
+        """The rotation of ``layer``'s q and k; None: they do not turn (a
+        linear layer has no entry in ``rope_parameters``)."""
         if self.rope_parameters is not None:
-            return dict(self.rope_parameters)[self.layer_type(layer)]
+            return dict(self.rope_parameters).get(self.layer_type(layer))
         if self.rope_theta is None:
             return None
         return RopeParameters(self.rope_theta, self.rope_scaling)
@@ -445,6 +469,12 @@ class LlamaConfig:
                 f"indexer's {self.index_head_dim}-wide key a token beside "
                 f"K and V, and a decode step would pick {self.index_topk} "
                 f"of the cached keys before it attends; not built")
+        if self.shared_expert_gate:
+            raise NotImplementedError(
+                f"{who} has no path for a gated shared expert "
+                f"(shared_expert_gate=True): it runs a dense feed-forward in "
+                f"every layer, and none of them scales a SwiGLU's output by "
+                f"a sigmoid of its input; not built")
         if self.num_experts > 1:
             raise NotImplementedError(
                 f"{who} has no path for routed experts (num_experts="
@@ -473,12 +503,25 @@ class LlamaConfig:
                 f"(num_attention_heads_per_layer="
                 f"{self.num_attention_heads_per_layer}): its layers share "
                 f"one shape of q and one stage's weights; not built")
+        if self.gating == "elementwise":
+            raise NotImplementedError(
+                f"{who} has no path for the element-wise output gate "
+                f"(gating='elementwise'): its layer's wq would be twice as "
+                f"wide, a query and a gate of {self.head_dim} lanes a head, "
+                f"and the attention output multiplied by the gate's sigmoid "
+                f"lane by lane before W_o; not built")
         if self.gating is not None:
             raise NotImplementedError(
                 f"{who} has no path for the {self.gating} output gate "
                 f"(gating={self.gating!r}): its layer would project a gate "
                 f"a head from the normed state and scale the attention "
                 f"output before W_o; not built")
+        if self.zero_centered_norm:
+            raise NotImplementedError(
+                f"{who} has no path for zero-centred norms "
+                f"(zero_centered_norm=True): its layers multiply the "
+                f"normed state by a scale from ones, not by 1 + a scale "
+                f"from zeros; not built")
         if self.rope_parameters is not None:
             raise NotImplementedError(
                 f"{who} has no path for a rotation a layer type or a "
@@ -496,12 +539,22 @@ class LlamaConfig:
 
 
 class RMSNorm(nn.Module):
+    """``x / rms(x) * scale`` in float32, the scale from ones; with
+    ``zero_centered`` (``LlamaConfig.zero_centered_norm``) ``x / rms(x) * (1
+    + scale)``, the scale from zeros: the same function at initialisation,
+    another parameter under weight decay."""
+
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = self.param("scale", nn.initializers.zeros if
+                           self.zero_centered else nn.initializers.ones,
+                           (x.shape[-1],))
+        if self.zero_centered:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
                                            keepdims=True) + self.eps)
@@ -620,6 +673,19 @@ def causal_attention(q, k, v, *, q_offset: int = 0,
     return out, jax.lax.stop_gradient(lse)
 
 
+class _Kernel(nn.Module):
+    """A matrix under ``<name>/kernel``, where ``nn.Dense`` keeps its own,
+    for a caller that multiplies by parts of it."""
+
+    shape: tuple
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape).astype(self.dtype)
+
+
 class LlamaAttention(nn.Module):
     """Softmax attention over grouped-query heads.  The layer's ``index``
     in the stack decides what the config lets differ by layer: the count
@@ -627,7 +693,15 @@ class LlamaAttention(nn.Module):
     (``window_of``: handed to ``attention_fn`` as ``window=``, under
     ``hvd.attn.window``) and, with ``gating`` ``"per-head"``, the gate
     ``sigmoid(x W_g)`` a head on the attention's output (under
-    ``hvd.attn.gate``); ``cos``, ``sin`` are the layer's own tables."""
+    ``hvd.attn.gate``); ``cos``, ``sin`` are the layer's own tables.
+
+    With ``gating`` ``"elementwise"`` ``wq`` is ``[hidden, heads * 2 * D]``,
+    a head's columns its query and then its gate, and the output is
+    multiplied by ``sigmoid(gate)`` lane by lane (under ``hvd.attn.gate``
+    too).  x is multiplied by the query's columns and by the gate's apart
+    (the weight is taken apart, 2 x ``hidden x heads x D``): each product
+    then leaves ``[B, S, heads * D]`` where the flash calls and the multiply
+    read it, and no activation is relaid to split a head's 2 D lanes."""
 
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
@@ -640,20 +714,30 @@ class LlamaAttention(nn.Module):
         B, S, _ = x.shape
         D = cfg.head_dim
         heads = cfg.heads_of(self.index)
-        q = nn.Dense(heads * D, use_bias=False, dtype=cfg.dtype,
-                     name="wq")(x).reshape(B, S, heads, D)
+        if cfg.gating == "elementwise":
+            wq = _Kernel((x.shape[-1], heads * 2 * D), cfg.dtype,
+                         name="wq")().reshape(-1, heads, 2, D)
+            x_q = x.astype(cfg.dtype)
+            q = jnp.dot(x_q, wq[:, :, 0].reshape(-1, heads * D))
+            q = q.reshape(B, S, heads, D)
+        else:
+            q = nn.Dense(heads * D, use_bias=False, dtype=cfg.dtype,
+                         name="wq")(x).reshape(B, S, heads, D)
         k = nn.Dense(cfg.num_kv_heads * D, use_bias=False, dtype=cfg.dtype,
                      name="wk")(x).reshape(B, S, cfg.num_kv_heads, D)
         v = nn.Dense(cfg.num_kv_heads * D, use_bias=False, dtype=cfg.dtype,
                      name="wv")(x).reshape(B, S, cfg.num_kv_heads, D)
+
+        def norm(name):
+            return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.zero_centered_norm,
+                           name=name)
+
         if cfg.qk_norm and cfg.qk_norm_over == "all":
-            q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(
-                q.reshape(B, S, -1)).reshape(q.shape)
-            k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(
-                k.reshape(B, S, -1)).reshape(k.shape)
+            q = norm("q_norm")(q.reshape(B, S, -1)).reshape(q.shape)
+            k = norm("k_norm")(k.reshape(B, S, -1)).reshape(k.shape)
         elif cfg.qk_norm:
-            q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
-            k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
+            q = norm("q_norm")(q)
+            k = norm("k_norm")(k)
         window = cfg.window_of(self.index)
         with (jax.named_scope(_scopes.ATTN_WINDOW) if window is not None
               else contextlib.nullcontext()):
@@ -666,6 +750,10 @@ class LlamaAttention(nn.Module):
             with jax.named_scope(_scopes.ATTN_GATE):
                 out = _gated_heads(out, nn.Dense(
                     heads, use_bias=False, dtype=cfg.dtype, name="wg")(x))
+        elif cfg.gating == "elementwise":
+            with jax.named_scope(_scopes.ATTN_GATE):
+                out = _gated_lanes(out, jnp.dot(
+                    x_q, wq[:, :, 1].reshape(-1, heads * D)))
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="wo")(out)
 
@@ -674,6 +762,12 @@ class LlamaAttention(nn.Module):
         if window is None:
             return self.attention_fn(q, k, v)
         return self.attention_fn(q, k, v, window=window)
+
+
+def _gated_lanes(out, logits):
+    """``out * sigmoid(logits)``, both ``[B, S, heads * D]``: the sigmoid in
+    float32 and rounded once to the dtype of out, as ``_gated_heads``."""
+    return out * jax.nn.sigmoid(logits.astype(jnp.float32)).astype(out.dtype)
 
 
 def _gated_heads(out, logits):
@@ -1019,9 +1113,10 @@ class RoutedExperts(nn.Module):
         y = sum_{k: e_k held here} g_k E_{e_k}(x) + S(x)
 
     E_e a SwiGLU of width ``moe_intermediate_size``, S one SwiGLU of
-    ``shared_experts`` times that width.  What an absent expert would add
-    is left out (the chip that holds it adds it, and the sum over chips is
-    the whole layer); with every expert held nothing is.
+    ``shared_experts`` times that width, times ``sigmoid(x w_s)`` (``w_s
+    [H, 1]``, float32) where ``shared_expert_gate``.  What an absent expert
+    would add is left out (the chip that holds it adds it, and the sum over
+    chips is the whole layer); with every expert held nothing is.
 
     Static shapes and no dropped row, whatever the imbalance: the T * K
     assignments are sorted by held expert (absent ones last), the tokens'
@@ -1112,8 +1207,16 @@ class RoutedExperts(nn.Module):
 
         if cfg.shared_experts:
             with jax.named_scope(_scopes.MOE_SHARED):
-                y = y + SwiGLU(cfg, width=cfg.shared_experts * F,
-                               name="shared")(x)
+                shared = SwiGLU(cfg, width=cfg.shared_experts * F,
+                                name="shared")(x)
+                if cfg.shared_expert_gate:
+                    # One logit a token, float32 as the router's.
+                    gate = nn.Dense(1, use_bias=False, dtype=jnp.float32,
+                                    name="shared_gate")(
+                                        x.astype(jnp.float32))
+                    shared = shared * jax.nn.sigmoid(gate).astype(
+                        shared.dtype)
+                y = y + shared
         return y
 
 
@@ -1167,7 +1270,8 @@ class GatedDeltaNet(nn.Module):
 
     The recurrence runs chunk by chunk (``ops/gated_delta.py``), its state
     ``[d_v, d_k]`` a head in float32.  With more value heads than key
-    heads a key head serves ``value / key`` of them.  Parameters: ``wq wk
+    heads a key head serves ``value / key`` of them (q and k copied to
+    the value heads under ``hvd.gdn.heads``).  Parameters: ``wq wk
     [H, key heads * d_k]``, ``wv wg [H, value heads * d_v]``, ``wa wb [H,
     value heads]``, ``conv_q conv_k conv_v [K, channels]``, ``a_log
     dt_bias [value heads]``, ``o_norm [d_v]``, ``wo``.
@@ -1216,9 +1320,12 @@ class GatedDeltaNet(nn.Module):
             g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
             beta = jax.nn.sigmoid(b) * (2.0 if cfg.linear_allow_neg_eigval
                                         else 1.0)
-        with jax.named_scope(_scopes.GDN_SCAN):
-            if h_v != h_k:
+        if h_v != h_k:
+            # Value head j reads key head j // (h_v / h_k): copied, for a
+            # rule that takes as many of each.
+            with jax.named_scope(_scopes.GDN_HEADS):
                 q, k = (jnp.repeat(t, h_v // h_k, axis=2) for t in (q, k))
+        with jax.named_scope(_scopes.GDN_SCAN):
             o = gated_delta_rule(q, k, v, g, beta)
         if (self.is_mutable_collection("gdn_stats")
                 and not self.is_initializing()):
@@ -1275,7 +1382,8 @@ class LlamaLayer(nn.Module):
             ffn = SwiGLU(cfg, name="mlp")
 
         def residual(x, sublayer, norm):
-            norm = RMSNorm(cfg.rms_eps, cfg.dtype, name=norm)
+            norm = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.zero_centered_norm,
+                           name=norm)
             if cfg.norm_placement == "pre":
                 return x + sublayer(norm(x))
             return x + norm(sublayer(x))
@@ -1347,8 +1455,8 @@ class LlamaModel(nn.Module):
             return x
 
         def norm_f(mdl, x):
-            return RMSNorm(cfg.rms_eps, cfg.dtype, name="norm_f",
-                           parent=mdl)(x)
+            return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.zero_centered_norm,
+                           name="norm_f", parent=mdl)(x)
 
         if cfg.total_ut_steps == 1:
             x = one_pass(self, x)

@@ -66,8 +66,11 @@ the kernel's fp32 ``sm_scale``) and sliced back, so models keep the kernel
 — and its O(S) memory contract — unchanged on any shape;
 ``interpret=True`` is used automatically off-TPU so tests exercise the
 same kernel logic on CPU.  The longest row is bounded by VMEM: the forward
-call holds K and V whole and refuses S = 16384 at D = 128 in bf16 under
-the compiler's default limit.
+call holds K and V whole and twice (the pipeline's second copy), and where
+that passes the compiler's default limit (16 MiB at S = 8192 and D = 256 in
+bf16, or at S = 16384 and D = 128, before q, o and lse) it states the limit
+it computes from its blocks (``_fwd_vmem_limit``); under the default it
+states none.
 
 On one v5e (197 TFLOP/s bf16), in the benchmark's decoder step (bf16,
 16 heads of 128, causal; PERF.md §5 keeps the current figures): the
@@ -463,15 +466,22 @@ def _windowed(window):
     return {} if window is None else {"window": window}
 
 
-def _fwd_vmem_limit(s, d, d_v, bq, bk, itemsize):
-    """``vmem_limit_bytes`` of the forward call that reads a selection:
-    K and V whole, the query block's ``[bq, S]`` int8 rows of the mask, q,
-    o and lse, each held twice by the pipeline, and the pair's live
-    ``[bq, bk]`` float32 arrays; never under the compiler's default."""
+def _fwd_vmem_limit(s, d, d_v, bq, bk, itemsize, masked=True):
+    """``vmem_limit_bytes`` of the forward call: K and V whole, the query
+    block's ``[bq, S]`` int8 rows of the mask where it reads a selection
+    (``masked``), q, o and lse, each held twice by the pipeline, and the
+    pair's live ``[bq, bk]`` float32 arrays; never under the compiler's
+    default, which is what a call whose blocks fit it is left to
+    (``_fwd``).  Without a selection the float32 accumulator and its update
+    (``[bq, d_v]`` each) are counted too: at 256 lanes they are 1 MiB of
+    the 22.1 the call took on the v5e at ``[8192, 256]`` (PR 46), and no
+    mask's rows leave them room; a selection's call keeps the limit it has
+    stated since PR 34."""
     d, d_v = -(-d // 128) * 128, -(-d_v // 128) * 128
-    blocks = (s * (d + d_v) * itemsize + bq * s
+    blocks = (s * (d + d_v) * itemsize + (bq * s if masked else 0)
               + bq * (d + d_v) * itemsize + 8 * s * 4)
-    return max(_DEFAULT_SCOPED_VMEM, 2 * blocks + 6 * bq * bk * 4)
+    carried = 0 if masked else 2 * bq * d_v * 4
+    return max(_DEFAULT_SCOPED_VMEM, 2 * blocks + 6 * bq * bk * 4 + carried)
 
 
 def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None, mask=None,
@@ -498,9 +508,14 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None, mask=None,
         mask_spec = pl.BlockSpec((None, bq, s),
                                  lambda b, i: (jax.lax.div(b, per_batch),
                                                i, 0))
+    # Without a selection the call states a limit only where its blocks
+    # pass the default (K and V of 256 lanes at 8192 rows do: 8 MiB held
+    # twice, before q, o and lse): at [8192, 128] it states none, as ever.
+    limit = _fwd_vmem_limit(s, d, dv, bq, bk, q.dtype.itemsize,
+                            masked=mask is not None)
+    if mask is not None or limit > _DEFAULT_SCOPED_VMEM:
         params["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=_fwd_vmem_limit(s, d, dv, bq, bk,
-                                             q.dtype.itemsize))
+            vmem_limit_bytes=limit)
     names, arrays, bias_specs = _extras(bh, s, bias, seg, mask, mask_spec)
     kernel = _with_extras(_fwd_kernel, 2, names, causal=causal,
                           sm_scale=sm_scale, block_k=bk, **_windowed(window))

@@ -28,8 +28,8 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 # The tiny sizes at which tests/benchmark runs the jobs of the
-# ``deepseek-v2-lite``, ``keye-vl-2.0-30b-a3b``, ``olmo-hybrid-7b`` and
-# ``laguna-s-2.1`` configurations on the CPU.  They belong beside
+# ``deepseek-v2-lite``, ``keye-vl-2.0-30b-a3b``, ``olmo-hybrid-7b``,
+# ``laguna-s-2.1`` and ``qwen3-next-80b-a3b`` configurations on the CPU.  They belong beside
 # ``tests/benchmark/tiny_sizes.py``'s, whose table every test of that
 # directory reads by the job's name; the files there are the accepted
 # benchmark's, which a PR that adds a cell may not edit, so the entry is
@@ -149,6 +149,40 @@ TINY.setdefault("window_moe_lm", {
     "traffic": {"sequence": 256, "batch_per_chip": 2},
 })
 
+TINY.setdefault("hybrid_moe_lm", {
+    # Hidden 128; the published pattern's one period (linear, linear, linear,
+    # full): linear layers of 2 key heads serving 4 value heads, keys and
+    # values 32 wide (the published 1 : 1), 4 taps; a full layer of 4 query
+    # heads over 2 key-value heads of 64 of which a quarter turns, W_q a
+    # query and a gate a head; every layer holds experts 4 to 7 of 16, 3
+    # choices a token, beside a gated shared expert.
+    "config": {"hidden_size": 128, "num_attention_heads": 4,
+               "num_key_value_heads": 2, "head_dim": 64,
+               "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+               "linear_key_head_dim": 32, "linear_value_head_dim": 32,
+               "moe_intermediate_size": 32,
+               "shared_expert_intermediate_size": 32, "vocab_size": 512,
+               "num_experts": 4, "num_experts_per_tok": 3,
+               "deployment": {"num_experts_published": 16,
+                              "first_held_expert": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up move a unit-variance embedding too
+                          # little to show in one second on the CPU.
+                          "loss_must_fall": False,
+                          # bf16 at these widths, as ``hybrid_lm``'s: the
+                          # L2 norms of q and k and the QK-norms project the
+                          # signal's main part out and leave the rounding;
+                          # float32 through the same code agrees to 2e-3
+                          # (tests/test_qwen3_next.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    "traffic": {"sequence": 256, "batch_per_chip": 2},
+})
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running tests excluded from the tier-1 gate")
@@ -212,6 +246,8 @@ _MANIFEST_THEN = {
         ("keye-vl-2.0-30b-a3b.train-s8k-b2", "dense_roofline"),
     "test_benchmark_hybrid.py::test_the_manifests_new_entries":
         ("olmo-hybrid-7b.train-s8k", "gdn_scan_roofline"),
+    "test_benchmark_window.py::test_the_manifests_new_entries":
+        ("laguna-s-2.1.train-s8k", "attn_gate_ms"),
 }
 
 
@@ -219,7 +255,9 @@ _MANIFEST_THEN = {
 def _manifest_as_its_test_knew_it(request, monkeypatch):
     """``tests/benchmark/test_benchmark_moe.py::test_the_manifests_new_
     entries`` (PR 32) and its namesakes in ``test_benchmark_sparse.py``
-    (PR 34) and ``test_benchmark_hybrid.py`` (PR 38) pin their PR's entries as the LAST of every list of
+    (PR 34) and ``test_benchmark_hybrid.py`` (PR 38) pin their PR's entries
+    (``test_benchmark_window.py``'s, PR 42, the cells its new metrics list)
+    as the LAST of every list of
     ``BENCHMARK.json`` and count the cells, and a later PR may neither
     edit those files nor put its entries anywhere but last.  So each of
     those tests reads the manifest cut back to the entries it was written

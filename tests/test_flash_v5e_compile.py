@@ -158,13 +158,21 @@ def test_backward_call_fits_inside_a_decoders_step_at_8k(one_chip, heads):
         8192, 2048 // heads, 512, 512, 2, 0)
 
 
-def test_forward_call_refuses_a_32k_row_as_before(one_chip):
-    """K and V are whole rows in VMEM; walking them in blocks is not done."""
-    x = jax.ShapeDtypeStruct((16, 32768, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    with pytest.raises(Exception, match="vmem"):
-        jax.jit(lambda q, k, v: _flash(q, k, v, SM_SCALE)).lower(
-            x, x, x).compile()
+def test_forward_call_holds_a_32k_row_with_the_limit_it_computes(one_chip):
+    """K and V are whole rows in VMEM, 16 MiB at 32768 x 128 and twice that
+    with the pipeline's second copy: over the compiler's default, which
+    refused the call until PR 46.  A call whose blocks pass the default
+    states the limit it computes from them (as the backward call always
+    has), and compiles with it; walking K and V in blocks is not done."""
+    s, d = 32768, 128
+    x = jax.ShapeDtypeStruct((16, s, d), jnp.bfloat16, sharding=one_chip)
+    assert fa._fwd_vmem_limit(s, d, d, 512, 512, 2, masked=False) > (
+        fa._DEFAULT_SCOPED_VMEM)
+    compiled = jax.jit(lambda q, k, v: _flash(q, k, v, SM_SCALE)).lower(
+        x, x, x).compile()
+    calls = _mosaic_calls(compiled)
+    assert len(calls) == 1 and scopes.FLASH_FWD in calls[0], calls
+    assert _no_square_array(compiled, s)
 
 
 # -- a value width of its own: latent attention's 192-wide keys, 128-wide values

@@ -527,7 +527,7 @@ def test_config_refuses_what_it_cannot_be():
     with pytest.raises(ValueError, match="num_attention_heads_per_layer"):
         tiny(heads=(4, 5, 6, 6, 4))                     # 2 does not divide 5
     with pytest.raises(ValueError, match="gating"):
-        tiny(gating="elementwise")
+        tiny(gating="per-token")        # ("elementwise" is a kind since PR 46)
     with pytest.raises(ValueError, match="rope_parameters"):
         tiny(rope_parameters=(("full_attention", RopeParameters(1e4)),))
     with pytest.raises(ValueError, match="rope_parameters"):

@@ -1,0 +1,183 @@
+"""The ``qwen3-next-80b-a3b.train-s8k-b2`` cell compiled for a described
+``v5e:2x2`` (no chip attached), beside ``tests/test_flash_v5e_compile.py``
+and in its manner: the flash kernel's two calls at the cell's heads (16 query
+heads over 2 key-value heads of 256, in place), whose forward call states the
+limit it computes because K and V of 256 lanes pass the compiler's default;
+the forward call at heads of 128, which still states none; and the cell's
+whole train step at 2 x 8192 tokens, which fits the chip with the
+configuration's ``remat``."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import horovod_tpu.jax as hvd
+from benchmark import manifest
+from horovod_tpu.common import scopes
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import rope
+from horovod_tpu.ops import short_conv
+
+CELL = "qwen3-next-80b-a3b.train-s8k-b2"
+_MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
+_USED = re.compile(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"')
+HBM = 15.75 * 2 ** 30      # what the compiler has of the chip's 16 GB
+B, S, HEADS, KV_HEADS, D = 2, 8192, 16, 2, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as error:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
+
+
+@pytest.fixture
+def one_chip(topo, monkeypatch):
+    """The three kernels' non-interpreted bodies, and no persistent cache
+    (a deviceless executable cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for module in (fa, rope, short_conv):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _mosaic_calls(text):
+    return [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
+
+
+def _stated_limits(fn, *args):
+    """``vmem_limit_bytes`` of every ``pallas_call`` that ``fn`` traces to,
+    None where a call states none."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    limits = []
+    for call in calls(jax.make_jaxpr(fn)(*args).jaxpr):
+        params = call.params["compiler_params"] or {}
+        stated = [getattr(p, "vmem_limit_bytes", None)
+                  for p in params.values()]
+        limits.append(next((x for x in stated if x is not None), None))
+    return limits
+
+
+def test_the_two_calls_compile_at_256_inside_the_limits_they_state(
+        one_chip):
+    """Forward and backward through the seam at 2 x 8192 tokens, 16 query
+    heads over 2 key-value heads of 256, in place: two Mosaic calls, each
+    inside the limit it states, and no array with two sequence-long axes."""
+    def sds(n):
+        return jax.ShapeDtypeStruct((B, S, n, D), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *x: jnp.sum(fa.flash_attention_fn(
+            *x).astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+    forward_limit = fa._fwd_vmem_limit(S, D, D, 512, 512, 2, masked=False)
+    backward_limit = fa._bwd_vmem_limit(S, D, 512, 512, 2, 0)
+    assert forward_limit > fa._DEFAULT_SCOPED_VMEM
+    assert forward_limit == pytest.approx(24.5 * 2 ** 20)
+    assert backward_limit == pytest.approx(53.5e6, rel=0.01)
+    before = fa.layout_counts()
+    text = jax.jit(grads).lower(sds(HEADS), sds(KV_HEADS),
+                                sds(KV_HEADS)).compile().as_text()
+    assert fa.layout_counts()["in_place"] == before["in_place"] + 1
+    calls = _mosaic_calls(text)
+    forward, = (c for c in calls if scopes.FLASH_FWD in c)
+    backward, = (c for c in calls if scopes.FLASH_BWD in c)
+    assert len(calls) == 2
+    assert int(_USED.search(forward).group(1)) <= forward_limit
+    assert int(_USED.search(backward).group(1)) <= backward_limit
+    # K and V whole and twice are the default already.
+    assert int(_USED.search(forward).group(1)) > fa._DEFAULT_SCOPED_VMEM
+    assert not re.findall(rf"\w+\[(?:\d+,)*{S},{S}\]", text)
+
+
+@pytest.mark.parametrize("heads, kv_heads, dim, stated", [
+    (16, 16, 128, False), (16, 2, 256, True)])
+def test_forward_call_states_a_limit_at_256_and_none_at_128(heads, kv_heads,
+                                                            dim, stated):
+    """``test_flash_v5e_compile.py::test_forward_call_states_no_vmem_limit``
+    beside the call it does not cover: at ``[8192, 16 x 128]`` the forward
+    call leaves the scoped-VMEM limit to the compiler's default, as in every
+    cell before this one; at ``[8192, 16 / 2 x 256]`` it states the one it
+    computes."""
+    q = jnp.zeros((1, S, heads * dim), jnp.bfloat16)
+    k = jnp.zeros((1, S, kv_heads * dim), jnp.bfloat16)
+    limit, = _stated_limits(
+        lambda q, k: fa._fwd(q, k, k, True, dim ** -0.5, heads=heads), q, k)
+    if stated:
+        assert limit == fa._fwd_vmem_limit(S, dim, dim, 512, 512, 2,
+                                           masked=False)
+    else:
+        assert limit is None
+
+
+def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
+    """Four layers of the published widths at 2 x 8192 tokens: 8.76 GB of
+    state and 4.03 GB of temporaries under ``layer_keep_attention``
+    (``layer`` compiles to the same bytes and runs the flash forward call
+    twice; ``none`` to 8.54 GB of temporaries, 17.3 GB in all: no room).
+    The full layer is two flash calls (the policy keeps the forward call's
+    output) and six rotations; the three linear layers' convolutions are
+    27 Mosaic calls; the grouped products are XLA:TPU's own."""
+    cell = manifest.cell(CELL)
+    assert cell["config"]["training"]["remat"] == "layer_keep_attention"
+    job = manifest.load_job(cell["config"]["job"]).build(
+        cell["config"], cell["traffic"], 1)
+    mesh = Mesh([topo.devices[0]], ("data",))
+    replicated = NamedSharding(mesh, P())
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=replicated), tree)
+
+    state = jax.eval_shape(job.init_state, jax.random.key(0))
+    batch = jax.eval_shape(job.make_batch, jax.random.key(0))
+    assert batch.shape == (2, 8193)
+    step = hvd.make_train_step(job.loss_fn, job.optimizer, mesh)
+    before = fa.layout_counts(), short_conv.body_counts()
+    compiled = step.lower(*described(state), described(batch)).compile()
+    after = fa.layout_counts(), short_conv.body_counts()
+    assert after[0]["in_place"] - before[0]["in_place"] == 1
+    assert after[0]["flat"] == before[0]["flat"]
+    assert after[1]["fused"] - before[1]["fused"] == 9
+    text = compiled.as_text()
+    calls = _mosaic_calls(text)
+    forward = [c for c in calls if scopes.FLASH_FWD in c]
+    backward = [c for c in calls if scopes.FLASH_BWD in c]
+    assert len(forward) == len(backward) == 1
+    assert not any(scopes.REMATTED in c for c in forward)
+    assert sum(scopes.ROPE in c for c in calls) == 6
+    assert sum(scopes.GDN_CONV in c for c in calls) == 27
+    assert scopes.RAGGED_DOT_PREFIX in text
+    for scope in (scopes.GDN_HEADS, scopes.GDN_SCAN, scopes.GDN_GATES,
+                  scopes.ATTN_GATE, scopes.MOE_SHARED, scopes.MOE_ROUTE):
+        assert scope in text, scope
+    assert not re.findall(rf"\w+\[(?:\d+,)*{S},{S}\]", text)
+    memory = compiled.memory_analysis()
+    print(f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB + "
+          f"temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert memory.argument_size_in_bytes == pytest.approx(8.759e9, abs=0.1e9)
+    assert memory.temp_size_in_bytes == pytest.approx(4.027e9, abs=0.1e9)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) < HBM
