@@ -124,6 +124,13 @@ scope                 what falls under it
                       under recomputation and backward; Mosaic calls and
                       XLA operations alike, should a later kernel replace
                       part of it
+``hvd.gdn.solve``     INSIDE ``hvd.gdn.scan``: the inverse of every chunk's
+                      unit lower-triangular system
+                      (``ops/gated_delta.py::_tril_inverse``: the Mosaic
+                      call and the matrices' way to its lanes and back, or
+                      the ``jnp`` body's products) and its transpose's two
+                      products; a reader that knows ``hvd.gdn.scan`` alone
+                      walks outward to it and counts the solve there
 ``hvd.block.attn``    a layer's mixer block whole
                       (``models/llama.py::LlamaLayer``): ``norm_attn``,
                       the mixer (``LlamaAttention``, ``LatentAttention``,
@@ -219,7 +226,7 @@ __all__ = [
     "ATTN_WINDOW", "ATTN_GATE",
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
-    "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS",
+    "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
@@ -251,6 +258,7 @@ GDN_CONV = "hvd.gdn.conv"
 GDN_GATES = "hvd.gdn.gates"
 GDN_SCAN = "hvd.gdn.scan"
 GDN_HEADS = "hvd.gdn.heads"
+GDN_SOLVE = "hvd.gdn.solve"       # inside GDN_SCAN
 BLOCK_ATTN = "hvd.block.attn"
 BLOCK_FFN = "hvd.block.ffn"
 HEAD = "hvd.head"

@@ -7,9 +7,9 @@ that loses a kernel should not do so silently.  So each such choice is noted
 here, once a TRACE, under a ``kind`` (the op's own name for the choice) and a
 ``reason`` (which way it went, or why not the other), and the op's public
 view (``ops/flash_attention.py::fallback_count`` and ``layout_counts``,
-``ops/short_conv.py::body_counts``, ``hvd.update_counts``) reads its kind
-back.  Process-global, under one lock: tracing can run on several threads.
-Plain Python, no JAX.
+``ops/short_conv.py::body_counts``, ``ops/gated_delta.py::solve_counts``,
+``hvd.update_counts``) reads its kind back.  Process-global, under one lock:
+tracing can run on several threads.  Plain Python, no JAX.
 """
 
 from __future__ import annotations

@@ -48,7 +48,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
-from horovod_tpu.ops.gated_delta import (gated_delta_rule,
+from horovod_tpu.ops.gated_delta import (calls_in_place, gated_delta_rule,
                                          gated_delta_states)
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops import rope as _rope
@@ -1325,7 +1325,7 @@ class GatedDeltaNet(nn.Module):
             # rule that takes as many of each.
             with jax.named_scope(_scopes.GDN_HEADS):
                 q, k = (jnp.repeat(t, h_v // h_k, axis=2) for t in (q, k))
-        with jax.named_scope(_scopes.GDN_SCAN):
+        with jax.named_scope(_scopes.GDN_SCAN), calls_in_place(self.in_place):
             o = gated_delta_rule(q, k, v, g, beta)
         if (self.is_mutable_collection("gdn_stats")
                 and not self.is_initializing()):

@@ -4,7 +4,8 @@ The kernels are modules of their own, imported where they are used:
 ``flash_attention``, ``paged_attention``, ``sparse_index``, ``rope`` (the
 rotation as one Mosaic pass), ``short_conv`` (a linear-attention layer's
 convolution, SiLU and L2 norm as one Mosaic pass each way) and
-``gated_delta`` (the chunkwise gated delta rule, ``jax.numpy``)."""
+``gated_delta`` (the chunkwise gated delta rule: ``jax.numpy`` but for its
+chunks' triangular systems, solved in one Mosaic call a slab)."""
 
 from horovod_tpu.ops.collective_ops import (
     Average,

@@ -33,33 +33,105 @@ sends the cotangents of what was prepared back through the preparation.
 No step of either walk is a single token.
 
 ``T`` is the inverse of a unit lower-triangular matrix, formed exactly by
-block forward substitution in six merges, ``T <- T - T L_b T`` for blocks
-of b = 1, 2, .., 32 rows (``L_b``: the part of A under the diagonal of each
-pair of b-blocks), twelve 64 x 64 products in float32 at ``highest``
-precision and no series that could cancel (``_tril_inverse``; its
-transpose is ``-T^T dT T^T`` under the diagonal).  Cumulative log-decays
-and the solve stay in float32 whatever the inputs' dtype; the other
-products take their inputs in the dtype of q (bf16 on the training path)
-and accumulate in float32, and the state is cast to that dtype where a
-product reads it and carried in float32.
+forward substitution in float32 and no series that could cancel
+(``_tril_inverse``; its transpose is ``-T^T dT T^T`` under the diagonal, two
+products at ``highest``).  Two bodies, one answer to float32 rounding.  The
+``jnp`` one (``_tril_inverse_impl``, every path that may hold no Mosaic
+call, and the tests' yardstick) substitutes by blocks in six merges, ``T <-
+T - T L_b T`` for blocks of b = 1, 2, .., 32 rows (``L_b``: the part of A
+under the diagonal of each pair of b-blocks): twelve 64 x 64 products at
+``highest`` precision, six bf16 passes each on a quarter of the MXU, 33
+times the multiply-adds the entries that change need.  The Mosaic one
+(``_solve``, PR 47) substitutes row by row, ``T[i, :] = e_i - sum_{j < i}
+A[i, j] T[j, :]``, on the VPU with 128 matrices side by side on the lanes:
+one call a slab, 70 us on the v5e where the merges take 505 (PERF.md).
+Cumulative log-decays and the solve stay in float32 whatever the inputs'
+dtype; the other products take their inputs in the dtype of q (bf16 on the
+training path) and accumulate in float32, and the state is cast to that
+dtype where a product reads it and carried in float32.
 
-All of it is ``jax.numpy``: XLA:TPU runs the products on the MXU and the
-scan as a ``while``.  What a Mosaic call would buy is in PERF.md (the tiles
-are 64 x 96 and 64 x 192 against the MXU's 128 x 128).
+A Mosaic call is the caller's choice (the partitioner cannot split one):
+``gated_delta_rule`` takes the call only where its caller says that the
+trace may hold Mosaic calls (``in_place``: ``llama.py::LlamaLayer`` reads it
+off the model's ``attention_fn``, as for the rotation and the convolutions)
+and a TPU runs the trace; which body a trace took is counted
+(``solve_counts``).  Everything else is ``jax.numpy``: XLA:TPU runs the
+products on the MXU and the scans as ``while``s.  What further calls would
+buy is in PERF.md (the tiles are 64 x 96 and 64 x 192 against the MXU's
+128 x 128).
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["CHUNK", "gated_delta_rule", "gated_delta_states"]
+from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.common import trace_counts as _trace_counts
+
+__all__ = ["CHUNK", "gated_delta_rule", "gated_delta_states", "solve_counts",
+           "calls_in_place", "NOT_IN_PLACE", "NO_TPU"]
 
 CHUNK = 64
 _SLAB = 8              # chunks prepared together, then walked one by one
 _HIGHEST = jax.lax.Precision.HIGHEST
+_LANES = 128           # matrices a grid step of the solve's call works on
+_TILE = 8              # rows of a float32 sublane tile
+
+# Which body solved a traced rule's chunk systems (``common/trace_counts.py``):
+# the Mosaic call, or the ``jnp`` one by reason.
+_SOLVE = "gdn_solve"
+_MOSAIC = "mosaic"
+NOT_IN_PLACE = "the attention_fn does not read its operands in place"
+NO_TPU = "no TPU: the call would run interpreted"
+
+
+def solve_counts() -> dict:
+    """``{"mosaic": n, "plain": {reason: n}}``: how many traced calls of
+    ``gated_delta_rule`` solved their chunks' systems in the Mosaic call,
+    and how many in the ``jnp`` body, by reason.  Process-global, counted
+    once a TRACE."""
+    plain = _trace_counts.counts(_SOLVE)
+    return {"mosaic": plain.pop(_MOSAIC, 0), "plain": plain}
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+_around = threading.local()
+
+
+@contextlib.contextmanager
+def calls_in_place(answer: bool):
+    """``gated_delta_rule`` calls traced inside take ``answer`` for the
+    ``in_place`` they are not given.  For ``models/llama.py``, which calls
+    the rule by its module's name with the five operands and nothing else,
+    as the accepted benchmark's tests wrap it
+    (``tests/benchmark/test_benchmark_hybrid.py::_with_rule``)."""
+    was = getattr(_around, "in_place", False)
+    _around.in_place = bool(answer)
+    try:
+        yield
+    finally:
+        _around.in_place = was
+
+
+def _why_not():
+    """None where a rule whose caller said ``in_place`` solves its chunks'
+    systems by ``_solve``'s call, else the reason it does not.  The call
+    takes any number of ``CHUNK`` x ``CHUNK`` systems (whole sublane tiles,
+    ``_solve_kernel``), so the shape never refuses.  Off the TPU the call
+    would run interpreted, many times slower than the six merges it
+    replaces: the ``jnp`` body there, and the bits it always gave."""
+    return NO_TPU if _interpret() else None
 
 
 def _merge_masks(chunk: int):
@@ -85,23 +157,108 @@ def _tril_inverse_impl(a):
     return t
 
 
-@jax.custom_vjp
-def _tril_inverse(a):
+# -- the solve as one Mosaic call --------------------------------------------
+#
+# Row i of T = (I + A)^-1 is e_i - sum_{j < i} A[i, j] T[j, :]: 63 dependent
+# rows, each a sum over the rows before it.  With ONE matrix on the sublanes
+# and lanes that is all broadcasts.  With 128 matrices side by side on the
+# lanes (entry (i, j) of all of them one vector) it is plain multiply-adds
+# on the VPU in float32, 2 * 64^3 / 6 a matrix where the six merges multiply
+# 11 * 2 * 64^3 at six bf16 passes each.  So the call takes ``[C, C, M]``,
+# the matrices on the last axis, and XLA turns A there and T back (a copy
+# each way, 44 of the 70 us a slab of 512 takes on the v5e where the merges
+# take 505; turned inside the call, row by row in VMEM, the same slab takes
+# 116: PERF.md, PR 47).
+
+def _solve_kernel(a_ref, t_ref):
+    """``t[i] = e_i - sum_{j < i} a[i, j] t[j]`` for ``a_ref, t_ref [C, C,
+    L]``: entry (i, j) of L matrices, one a lane; a row of T is ``[C, L]``,
+    its columns on the sublanes.  What A holds on and above the diagonal is
+    not read.  Rows go by sublane tiles of eight: a row's sum over the
+    tiles before its own is straight-line code (T[j, c] is zero for c > j,
+    so row j gives ``j // 8 + 1`` tiles of columns), the rows of its own
+    tile that came before it are masked in."""
+    chunk, _, lanes = a_ref.shape
+    assert chunk % _TILE == 0, chunk
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (_TILE, lanes), 0)
+    t_ref[...] = jnp.zeros_like(t_ref)
+    for tile in range(chunk // _TILE):
+        first = tile * _TILE
+
+        def row(r, carry, tile=tile, first=first):
+            i = first + r
+            # acc[c]: columns 8 c .. 8 c + 7 of row i, from e_i.
+            acc = [jnp.zeros((_TILE, lanes), jnp.float32)] * tile + [
+                jnp.where(sublane == r, 1.0, 0.0)]
+
+            def take(j, coef, tiles):
+                for c in range(tiles):
+                    acc[c] = acc[c] - coef * t_ref[
+                        j, pl.ds(c * _TILE, _TILE), :]
+
+            for j in range(first):
+                take(j, a_ref[i, pl.ds(j, 1), :], j // _TILE + 1)
+            for s in range(_TILE - 1):        # the rows of i's own tile
+                j = first + s
+                take(j, jnp.where(s < r, a_ref[i, pl.ds(j, 1), :], 0.0),
+                     tile + 1)
+            for c in range(tile + 1):
+                t_ref[i, pl.ds(c * _TILE, _TILE), :] = acc[c]
+            return carry
+
+        jax.lax.fori_loop(0, _TILE, row, 0)
+
+
+# (A jit: a step traces the body once a shape, not once a layer and pass, as
+# ``ops/short_conv.py``'s calls; ``interpret`` is static, so the cached trace
+# is of the mode asked for.)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _solve(a, interpret):
+    """``(I + a)^-1`` for ``a [.., C, C]`` float32 by one Mosaic call: 128
+    matrices a grid step (2 MiB a block of A and of T, two buffers each:
+    half the default scoped VMEM, so the call states no limit).  Where the
+    count is no multiple of 128 the last step's spare lanes hold whatever
+    the block brought, are worked on like the others (a lane never reads
+    another) and are not written back."""
+    chunk = a.shape[-1]
+    count = math.prod(a.shape[:-2])
+    lanes = jnp.transpose(a.reshape(count, chunk, chunk), (1, 2, 0))
+    block = pl.BlockSpec((chunk, chunk, _LANES), lambda m: (0, 0, m))
+    t = pl.pallas_call(
+        _solve_kernel,
+        grid=(pl.cdiv(count, _LANES),),
+        in_specs=[block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(lanes.shape, lanes.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(lanes)
+    return jnp.transpose(t, (2, 0, 1)).reshape(a.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _tril_inverse(a, mosaic=False):
     """``(I + a)^-1`` for ``a [.., C, C]`` float32, zero on and above the
-    diagonal, C a power of two."""
-    return _tril_inverse_impl(a)
+    diagonal, C a power of two; by ``_solve``'s call where ``mosaic``, else
+    by the six merges."""
+    with jax.named_scope(_scopes.GDN_SOLVE):
+        if mosaic:
+            return _solve(a, interpret=_interpret())
+        return _tril_inverse_impl(a)
 
 
-def _tril_inverse_fwd(a):
-    t = _tril_inverse_impl(a)
+def _tril_inverse_fwd(a, mosaic):
+    t = _tril_inverse(a, mosaic)
     return t, t
 
 
-def _tril_inverse_bwd(t, dt):
-    tt = jnp.swapaxes(t, -1, -2)
-    da = -jnp.matmul(tt, jnp.matmul(dt, tt, precision=_HIGHEST),
-                     precision=_HIGHEST)
-    return (jnp.tril(da, -1),)
+def _tril_inverse_bwd(mosaic, t, dt):
+    with jax.named_scope(_scopes.GDN_SOLVE):
+        tt = jnp.swapaxes(t, -1, -2)
+        da = -jnp.matmul(tt, jnp.matmul(dt, tt, precision=_HIGHEST),
+                         precision=_HIGHEST)
+        return (jnp.tril(da, -1),)
 
 
 _tril_inverse.defvjp(_tril_inverse_fwd, _tril_inverse_bwd)
@@ -112,11 +269,12 @@ def _dot(spec, x, y):
     return jnp.einsum(spec, x, y, preferred_element_type=jnp.float32)
 
 
-def _prepare(q, k, v, g, beta):
+def _prepare(q, k, v, g, beta, mosaic=False):
     """What every chunk needs and no chunk's state enters.  q, k ``[N, B,
-    H, C, d_k]``, v ``[.., d_v]``, g and beta ``[N, B, H, C]`` float32.
-    Returns W, U0, P = M * Q K^T, e^gamma Q and e^{gamma_C - gamma} K (the
-    dtype of q) and e^{gamma_C} ``[N, B, H]`` (float32)."""
+    H, C, d_k]``, v ``[.., d_v]``, g and beta ``[N, B, H, C]`` float32;
+    ``mosaic``: the chunks' systems by ``_solve``'s call.  Returns W, U0,
+    P = M * Q K^T, e^gamma Q and e^{gamma_C - gamma} K (the dtype of q) and
+    e^{gamma_C} ``[N, B, H]`` (float32)."""
     dtype = q.dtype
     chunk = q.shape[-2]
     gamma = jnp.cumsum(g, axis=-1)
@@ -131,7 +289,7 @@ def _prepare(q, k, v, g, beta):
     a = jnp.where(rows > cols,
                   beta[..., None] * decay * _dot("...id,...jd->...ij", k, k),
                   0.0)
-    t = _tril_inverse(a).astype(dtype)
+    t = _tril_inverse(a, mosaic).astype(dtype)
     into = jnp.exp(gamma)
     w = _dot("...ij,...jd->...id", t,
              (k * (beta * into)[..., None]).astype(dtype)).astype(dtype)
@@ -207,13 +365,13 @@ def _slabs(x):
     return x.reshape(x.shape[0] // n, n, *x.shape[1:])
 
 
-def _rule_walk(q, k, v, g, beta):
+def _rule_walk(q, k, v, g, beta, mosaic=False):
     """O and the chunks' starting states for chunked inputs, slab by slab:
     a slab is prepared, then walked."""
     _, batch, heads, _, d_k = q.shape
 
     def slab(state, inputs):
-        return _walk(_prepare(*inputs), state)
+        return _walk(_prepare(*inputs, mosaic), state)
 
     _, (o, states) = jax.lax.scan(
         slab, jnp.zeros((batch, heads, v.shape[-1], d_k), jnp.float32),
@@ -222,17 +380,17 @@ def _rule_walk(q, k, v, g, beta):
             states.reshape(-1, *states.shape[2:]))
 
 
-@jax.custom_vjp
-def _rule(q, k, v, g, beta):
-    return _rule_walk(q, k, v, g, beta)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule(q, k, v, g, beta, mosaic):
+    return _rule_walk(q, k, v, g, beta, mosaic)[0]
 
 
-def _rule_fwd(q, k, v, g, beta):
-    o, states = _rule_walk(q, k, v, g, beta)
+def _rule_fwd(q, k, v, g, beta, mosaic):
+    o, states = _rule_walk(q, k, v, g, beta, mosaic)
     return o, (q, k, v, g, beta, states)
 
 
-def _rule_bwd(res, d_o):
+def _rule_bwd(mosaic, res, d_o):
     """The slabs in reverse: a slab is prepared again (with what its
     transpose needs), its chunks are walked in reverse from the states the
     forward walk kept, and the cotangents of what was prepared go back
@@ -241,7 +399,8 @@ def _rule_bwd(res, d_o):
 
     def slab(d_state, xs):
         *inputs, states, d_o = xs
-        prepared, pull = jax.vjp(_prepare, *inputs)
+        prepared, pull = jax.vjp(
+            functools.partial(_prepare, mosaic=mosaic), *inputs)
         d_state, d_prepared = _walk_back(prepared, states, d_o, d_state)
         return d_state, pull(d_prepared)
 
@@ -274,16 +433,25 @@ def _chunks(q, k, v, g, beta):
                  for x in (q, k, v.astype(q.dtype), g, beta))
 
 
-def gated_delta_rule(q, k, v, g, beta):
+def gated_delta_rule(q, k, v, g, beta, in_place: bool | None = None):
     """``o [B, S, H, d_v]`` of the recurrence above, in the dtype of v.
 
     q, k ``[B, S, H, d_k]`` (as the rule reads them: normed and scaled by
     the caller), v ``[B, S, H, d_v]``, ``g = log alpha <= 0`` and beta
     ``[B, S, H]`` (taken to float32).  The state starts at zero and ends
     with the sequence; a length that is no multiple of ``CHUNK`` is
-    padded."""
+    padded.  ``in_place`` is the caller's word that this trace may hold
+    Mosaic calls (``models/llama.py::LlamaLayer`` reads it off the model's
+    ``attention_fn``): the chunks' systems are then solved by ``_solve``'s
+    call, else by the six merges.  A caller that cannot say it beside the
+    operands says it around the call (``calls_in_place``).  Which a trace
+    took, and why, ``solve_counts()`` says."""
     batch, seq, heads, d_v = v.shape
-    o = _rule(*_chunks(q, k, v, g, beta))
+    if in_place is None:
+        in_place = getattr(_around, "in_place", False)
+    why = _why_not() if in_place else NOT_IN_PLACE
+    _trace_counts.note(_SOLVE, why or _MOSAIC)
+    o = _rule(*_chunks(q, k, v, g, beta), why is None)
     o = jnp.moveaxis(o, (0, 2), (1, 3))                  # [B, N, C, H, d_v]
     return o.reshape(batch, -1, heads, d_v)[:, :seq].astype(v.dtype)
 

@@ -125,21 +125,159 @@ def test_identical_keys_with_beta_two_stay_bounded():
     _assert_close(found, wanted, tolerance=2e-3)
 
 
-def test_tril_inverse_is_the_inverse_and_transposes():
+@pytest.mark.parametrize("mosaic", [False, True], ids=["merges", "call"])
+def test_tril_inverse_is_the_inverse_and_transposes(mosaic):
+    """By the six merges and by ``_solve``'s call (interpreted here), both
+    through ``_tril_inverse``'s ``custom_vjp``."""
     a = 0.2 * jnp.tril(jax.random.normal(jax.random.key(3), (5, 64, 64)), -1)
     with HIGHEST:
-        t = gated_delta._tril_inverse(a)
+        t = gated_delta._tril_inverse(a, mosaic)
         np.testing.assert_allclose(
             t @ (jnp.eye(64) + a), jnp.broadcast_to(jnp.eye(64), a.shape),
             atol=2e-4)
         weight = jax.random.normal(jax.random.key(4), a.shape)
-        got = jax.grad(lambda a: jnp.sum(gated_delta._tril_inverse(a)
+        got = jax.grad(lambda a: jnp.sum(gated_delta._tril_inverse(a, mosaic)
                                          * weight))(a)
         want = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(
             jnp.eye(64) + jnp.tril(a, -1)) * weight))(a)
     np.testing.assert_allclose(got, want, rtol=2e-3,
                                atol=2e-3 * float(jnp.max(jnp.abs(want))))
     assert not np.asarray(jnp.triu(got)).any()
+
+
+def _systems(seed, lead, scale=1.0):
+    """A ``[*lead, 64, 64]`` as ``_prepare`` forms it, at the rule's own
+    scale (unit keys, beta in (0, 2), decays in (0, 1]) times ``scale``."""
+    keys = jax.random.split(jax.random.key(seed), 3)
+    k = jax.random.normal(keys[0], (*lead, 64, 24))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    beta = 2 * jax.nn.sigmoid(2 * jax.random.normal(keys[1], (*lead, 64)))
+    gamma = jnp.cumsum(-jnp.exp(jax.random.normal(keys[2], (*lead, 64)) - 2),
+                       axis=-1)
+    rows, cols = jnp.arange(64)[:, None], jnp.arange(64)[None, :]
+    decay = jnp.exp(jnp.where(rows >= cols, gamma[..., :, None]
+                              - gamma[..., None, :], -jnp.inf))
+    with HIGHEST:
+        a = beta[..., None] * decay * jnp.einsum("...id,...jd->...ij", k, k)
+    return jnp.where(rows > cols, scale * a, 0.0)
+
+
+@pytest.mark.parametrize("scale", [1.0, 10.0], ids=["rule-scale", "tenfold"])
+@pytest.mark.parametrize("lead", [(5,), (2, 2, 32), (2, 1, 30)], ids=[
+    "five", "qwen3-next-slab-of-2-chunks", "olmo-hybrid-slab-of-2-chunks"])
+def test_solve_call_against_the_merges_and_float64(lead, scale):
+    """``_solve``'s call in interpret mode at the two cells' slab shapes cut
+    to two chunks (2 x 2 rows x 32 heads = 128 matrices, one grid step;
+    2 x 30 = 60, padded) against the inverse in float64: no further from it
+    than the six merges are, by each matrix's largest entry.  Tenfold the
+    rule's scale T's entries reach 1e6 and more."""
+    a = _systems(7, lead, scale)
+    assert not np.asarray(jnp.triu(a)).any()
+    with HIGHEST:       # (for the merges; and one trace a shape of the call)
+        got = np.asarray(gated_delta._solve(a, interpret=True), np.float64)
+        merged = np.asarray(gated_delta._tril_inverse_impl(a), np.float64)
+    want = np.linalg.inv(np.eye(64) + np.asarray(a, np.float64))
+    size = np.abs(want).max(axis=(-1, -2), keepdims=True)
+    assert scale == 1 or size.max() > 1e4
+
+    def error(found):
+        return float((np.abs(found - want) / size).max())
+
+    assert error(got) < max(2 * error(merged), 2e-6), (
+        error(got), error(merged))
+    assert not np.triu(got, 1).any()
+    np.testing.assert_array_equal(np.diagonal(got, axis1=-2, axis2=-1), 1.0)
+
+
+def test_solve_call_reads_nothing_on_or_above_the_diagonal():
+    """As the merges' masks: what A holds there is not the system's."""
+    a = _systems(8, (5,))
+    junk = a + jnp.triu(jnp.full_like(a, 7.0))
+    with HIGHEST:
+        np.testing.assert_array_equal(
+            gated_delta._solve(junk, interpret=True),
+            gated_delta._solve(a, interpret=True))
+
+
+@pytest.mark.parametrize("heads, key_heads", [(30, 30), (32, 16)],
+                         ids=["olmo-hybrid-30", "qwen3-next-32-over-16"])
+def test_rule_with_the_call_agrees_with_the_merges(heads, key_heads,
+                                                   monkeypatch):
+    """``gated_delta_rule(.., in_place=True)`` against ``in_place=False``,
+    forward and the five gradients, at the two cells' head counts (value
+    heads 32 over 16 key heads: q and k copied, as the mixer does) over
+    three chunks in slabs of one.  The CPU takes the ``jnp`` body by rule
+    (``NO_TPU``); lifted here, the call runs interpreted.  In chunks of 16,
+    which is what keeps two traces of the interpreted call a case under ten
+    seconds (a chunk of 64 is 1,300 multiply-adds of straight-line code to
+    the interpreter); the call itself is held to the merges at 64 above."""
+    monkeypatch.setattr(gated_delta, "CHUNK", 16)
+    q, k, v, g, beta = _inputs(11, 48, batch=1, heads=heads, d_k=8, d_v=16)
+    q, k = (jnp.repeat(x[:, :, :key_heads], heads // key_heads, axis=2)
+            for x in (q, k))
+    inputs = (q, k, v, g, beta)
+    weight = jax.random.normal(jax.random.key(98), v.shape)
+
+    def both(in_place):
+        with HIGHEST:
+            return jax.jit(jax.value_and_grad(
+                lambda *x: jnp.sum(gated_delta_rule(*x, in_place=in_place)
+                                   * weight), argnums=tuple(range(5))))(
+                                       *inputs)
+
+    before = gated_delta.solve_counts()
+    merged = both(False)
+    assert both(True)[0] == merged[0]          # off the TPU: the same body
+    monkeypatch.setattr(gated_delta, "_why_not", lambda: None)
+    called = both(True)
+    after = gated_delta.solve_counts()
+    assert after["mosaic"] == before["mosaic"] + 1
+    assert after["plain"][gated_delta.NOT_IN_PLACE] == before["plain"].get(
+        gated_delta.NOT_IN_PLACE, 0) + 1
+    assert after["plain"][gated_delta.NO_TPU] == before["plain"].get(
+        gated_delta.NO_TPU, 0) + 1
+    _assert_close(called, merged, tolerance=1e-4)
+    assert float(called[0]) != float(merged[0]) or any(
+        np.any(np.asarray(x) != np.asarray(y))
+        for x, y in zip(called[1], merged[1]))      # another body did run
+
+
+def test_the_mixer_hands_the_rule_what_its_layer_read(monkeypatch):
+    """``GatedDeltaNet`` says ``in_place`` around the rule's call, which it
+    makes with the five operands alone (the accepted benchmark's tests wrap
+    that name); a rule called inside another's context takes that answer, a
+    rule that is told takes what it is told."""
+    monkeypatch.setattr(gated_delta, "_interpret", lambda: False)
+    cfg = dataclasses.replace(_tiny()[0].llama, dtype=jnp.float32)
+    x = jax.ShapeDtypeStruct((1, 128, cfg.hidden_size), jnp.float32)
+
+    def counted(in_place):
+        mixer = GatedDeltaNet(cfg, in_place=in_place)
+        before = gated_delta.solve_counts()
+        params = jax.eval_shape(mixer.init, jax.random.key(0), x)
+        after = gated_delta.solve_counts()
+        jax.eval_shape(mixer.apply, params, x)
+        return before, after, gated_delta.solve_counts()
+
+    before, _, after = counted(True)
+    assert after["mosaic"] == before["mosaic"] + 2       # init and apply
+    assert after["plain"] == before["plain"]
+    before, _, after = counted(False)
+    assert after["mosaic"] == before["mosaic"]
+    assert after["plain"][gated_delta.NOT_IN_PLACE] == before["plain"].get(
+        gated_delta.NOT_IN_PLACE, 0) + 2
+    inputs = jax.eval_shape(lambda: _inputs(0, 64))
+    before = gated_delta.solve_counts()
+    # (A lambda each: ``eval_shape`` keeps a function's trace.)
+    with gated_delta.calls_in_place(True):
+        jax.eval_shape(lambda *x: gated_delta_rule(*x), *inputs)
+        jax.eval_shape(lambda *x: gated_delta_rule(*x, in_place=False),
+                       *inputs)
+    jax.eval_shape(lambda *x: gated_delta_rule(*x), *inputs)
+    after = gated_delta.solve_counts()
+    assert after["mosaic"] == before["mosaic"] + 1
+    assert after["plain"][gated_delta.NOT_IN_PLACE] == before["plain"].get(
+        gated_delta.NOT_IN_PLACE, 0) + 2
 
 
 def test_states_are_the_recurrences_at_each_chunks_start():

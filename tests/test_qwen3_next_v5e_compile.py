@@ -5,8 +5,10 @@ heads over 2 key-value heads of 256, in place), whose forward call states the
 limit it computes because K and V of 256 lanes pass the compiler's default;
 the forward call at heads of 128, which still states none; and the cell's
 whole train step at 2 x 8192 tokens, which fits the chip with the
-configuration's ``remat``."""
+configuration's ``remat`` and holds the gated delta rule's solve as
+``ops/gated_delta.py``'s Mosaic call (PR 47)."""
 
+import math
 import os
 import re
 
@@ -20,6 +22,7 @@ import horovod_tpu.jax as hvd
 from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import gated_delta
 from horovod_tpu.ops import rope
 from horovod_tpu.ops import short_conv
 
@@ -43,11 +46,11 @@ def topo():
 
 @pytest.fixture
 def one_chip(topo, monkeypatch):
-    """The three kernels' non-interpreted bodies, and no persistent cache
+    """The four kernels' non-interpreted bodies, and no persistent cache
     (a deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for module in (fa, rope, short_conv):
+    for module in (fa, rope, short_conv, gated_delta):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -133,6 +136,31 @@ def test_forward_call_states_a_limit_at_256_and_none_at_128(heads, kv_heads,
         assert limit is None
 
 
+@pytest.mark.parametrize("lead", [(8, 2, 32), (8, 1, 30)],
+                         ids=["the-cells-slab", "olmo-hybrids-slab"])
+def test_the_solve_compiles_at_a_slabs_shape_and_states_no_limit(one_chip,
+                                                                 lead):
+    """``ops/gated_delta.py::_solve`` at a slab's ``[8, 2, 32, 64, 64]`` and
+    at the other hybrid cell's ``[8, 1, 30, 64, 64]`` (240 matrices: two
+    grid steps, the second with 112 of its 128 lanes): ONE Mosaic call
+    between XLA's two transposes, no limit stated, half the default scoped
+    VMEM used."""
+    chunk = gated_delta.CHUNK
+    a = jax.ShapeDtypeStruct((*lead, chunk, chunk), jnp.float32,
+                             sharding=one_chip)
+
+    def solve(a):
+        return gated_delta._solve(a, interpret=False)
+
+    assert _stated_limits(solve, a) == [None]
+    text = jax.jit(solve).lower(a).compile().as_text()
+    calls = [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
+    assert len(calls) == 1
+    assert int(_USED.search(calls[0])[1]) <= 8 * 2 ** 20
+    count = math.prod(lead)
+    assert f"f32[{chunk},{chunk},{count}]" in calls[0]
+
+
 def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     """Four layers of the published widths at 2 x 8192 tokens: 8.76 GB of
     state and 4.03 GB of temporaries under ``layer_keep_attention``
@@ -140,7 +168,8 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     twice; ``none`` to 8.54 GB of temporaries, 17.3 GB in all: no room).
     The full layer is two flash calls (the policy keeps the forward call's
     output) and six rotations; the three linear layers' convolutions are
-    27 Mosaic calls; the grouped products are XLA:TPU's own."""
+    27 Mosaic calls and their chunk systems' solves 9 (PR 47); the grouped
+    products are XLA:TPU's own."""
     cell = manifest.cell(CELL)
     assert cell["config"]["training"]["remat"] == "layer_keep_attention"
     job = manifest.load_job(cell["config"]["job"]).build(
@@ -156,9 +185,13 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     batch = jax.eval_shape(job.make_batch, jax.random.key(0))
     assert batch.shape == (2, 8193)
     step = hvd.make_train_step(job.loss_fn, job.optimizer, mesh)
-    before = fa.layout_counts(), short_conv.body_counts()
+    before = (fa.layout_counts(), short_conv.body_counts(),
+              gated_delta.solve_counts())
     compiled = step.lower(*described(state), described(batch)).compile()
-    after = fa.layout_counts(), short_conv.body_counts()
+    after = (fa.layout_counts(), short_conv.body_counts(),
+             gated_delta.solve_counts())
+    assert after[2]["mosaic"] - before[2]["mosaic"] == 3
+    assert after[2]["plain"] == before[2]["plain"]
     assert after[0]["in_place"] - before[0]["in_place"] == 1
     assert after[0]["flat"] == before[0]["flat"]
     assert after[1]["fused"] - before[1]["fused"] == 9
@@ -170,6 +203,16 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     assert not any(scopes.REMATTED in c for c in forward)
     assert sum(scopes.ROPE in c for c in calls) == 6
     assert sum(scopes.GDN_CONV in c for c in calls) == 27
+    # The slabs' systems (512 matrices: four grid steps) by the solve's call,
+    # forward, again, and in the backward slab's preparation, a linear
+    # layer; each states no limit and takes half the default scoped VMEM.
+    solves = [c for c in calls if scopes.GDN_SOLVE in c]
+    assert len(solves) == 9 and all(scopes.GDN_SCAN in c for c in solves)
+    assert sum(scopes.REMATTED in c for c in solves) == 3
+    assert "f32[64,64,512]" in solves[0]
+    assert max(int(_USED.search(c)[1]) for c in solves) <= 8 * 2 ** 20
+    ours = [c for c in calls if scopes.RAGGED_DOT_PREFIX not in c]
+    assert len(ours) == 2 + 6 + 27 + 9
     assert scopes.RAGGED_DOT_PREFIX in text
     for scope in (scopes.GDN_HEADS, scopes.GDN_SCAN, scopes.GDN_GATES,
                   scopes.ATTN_GATE, scopes.MOE_SHARED, scopes.MOE_ROUTE):
