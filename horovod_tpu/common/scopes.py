@@ -32,9 +32,21 @@ scope                 what falls under it
 ``hvd.rope``          the rotary rotation's Mosaic call
                       (``ops/rope.py::rotate_pairs``: q and k forward, their
                       cotangents backward, each one lane-local pass over
-                      ``[B, S, H * D]``) and the expansion of its two tables
-                      to a head's lanes.  Inside ``hvd.block.attn``, beside
-                      the flash calls and never under ``hvd.flash.*``
+                      ``[B, S, H * D]``; with a per-head QK-norm
+                      ``norm_rotate_pairs``, the same pass with the norm in
+                      it, and backward the norm's transpose too).  Inside
+                      ``hvd.block.attn``, beside the flash calls and never
+                      under ``hvd.flash.*``
+``hvd.attn.qknorm``   the QK-norm and the rotation of q and k
+                      (``models/llama.py::LlamaAttention`` where the config
+                      has ``qk_norm``): the ``RMSNorm`` module's operations
+                      and the rotation behind them, or the one pass that
+                      does both, so ``hvd.rope`` nests inside it; forward,
+                      run again under recomputation and backward, the
+                      scales' gradients among it.  Inside ``hvd.block.attn``
+                      (and inside ``hvd.attn.window`` in a sliding layer); a
+                      model without a QK-norm does not enter it, and its
+                      rotation stays ``hvd.block.attn/../hvd.rope``
 ``hvd.attn.window``   a sliding-window layer's attention
                       (``models/llama.py::LlamaAttention`` where
                       ``LlamaConfig.window_of`` gives the layer a window):
@@ -223,7 +235,7 @@ from __future__ import annotations
 __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD", "ROPE",
-    "ATTN_WINDOW", "ATTN_GATE",
+    "ATTN_WINDOW", "ATTN_GATE", "QK_NORM",
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
@@ -245,6 +257,7 @@ FLASH_BWD = "hvd.flash.bwd"
 ROPE = "hvd.rope"
 ATTN_WINDOW = "hvd.attn.window"
 ATTN_GATE = "hvd.attn.gate"
+QK_NORM = "hvd.attn.qknorm"
 LOOP_PASS = "hvd.loop.pass"
 LOOP_EXIT = "hvd.loop.exit"
 MLA_LATENT = "hvd.mla.latent"

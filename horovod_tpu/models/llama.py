@@ -48,6 +48,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
+from horovod_tpu.common import trace_counts as _trace_counts
 from horovod_tpu.ops.gated_delta import (calls_in_place, gated_delta_rule,
                                          gated_delta_states)
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
@@ -549,12 +550,14 @@ class RMSNorm(nn.Module):
     zero_centered: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, scale_alone: bool = False):
         scale = self.param("scale", nn.initializers.zeros if
                            self.zero_centered else nn.initializers.ones,
                            (x.shape[-1],))
         if self.zero_centered:
             scale = 1.0 + scale
+        if scale_alone:     # for a caller that norms in a pass of its own
+            return scale
         x32 = x.astype(jnp.float32)
         x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
                                            keepdims=True) + self.eps)
@@ -604,18 +607,21 @@ def rope_freqs(head_dim: int, seq_len: int, theta: float, offset=0,
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, *,
-               in_place: bool = False) -> jax.Array:
-    """Rotate pairs (x[..., ::2], x[..., 1::2]).  x: [B, S, H, D].
+               in_place: bool = False, scale=None, eps=None) -> jax.Array:
+    """Rotate pairs (x[..., ::2], x[..., 1::2]).  x: [B, S, H, D].  With
+    ``scale [D]`` and ``eps`` each head is normed first, ``RMSNorm``'s
+    arithmetic with that (effective) scale.
 
     ``ops/rope.py::rotate``: in ``jnp``, any width and any partitioning,
     unless ``in_place`` says that the trace may hold Mosaic calls on operands
     where they lie (q and k on their way to an ``attention_fn`` that reads
     them where the projections left them, ``_reads_in_place``) and the shape
-    is one its Mosaic pass takes; the same bits either way.  A Mosaic call
-    is the caller's choice, as the flash kernel is: the partitioner cannot
-    split one, so under a plain ``jit`` over several chips it needs a
-    ``shard_map`` around it."""
-    return _rope.rotate(x, cos, sin, in_place)
+    is one its Mosaic pass takes: the norm then joins the rotation's pass;
+    the same bits either way, up to the order of the norm's float32 sum.  A
+    Mosaic call is the caller's choice, as the flash kernel is: the
+    partitioner cannot split one, so under a plain ``jit`` over several chips
+    it needs a ``shard_map`` around it."""
+    return _rope.rotate(x, cos, sin, in_place, scale, eps)
 
 
 def _reads_in_place(attention_fn) -> bool:
@@ -732,18 +738,28 @@ class LlamaAttention(nn.Module):
             return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.zero_centered_norm,
                            name=name)
 
-        if cfg.qk_norm and cfg.qk_norm_over == "all":
-            q = norm("q_norm")(q.reshape(B, S, -1)).reshape(q.shape)
-            k = norm("k_norm")(k.reshape(B, S, -1)).reshape(k.shape)
-        elif cfg.qk_norm:
-            q = norm("q_norm")(q)
-            k = norm("k_norm")(k)
+        def normed_and_turned(x, name):
+            """q or k normed as the config says and turned by the tables:
+            a per-head norm goes to the rotation as its scale, so that the
+            two can be one pass over x (``ops/rope.py::rotate``)."""
+            scale = None
+            if cfg.qk_norm and cfg.qk_norm_over == "all":
+                _trace_counts.note(_rope.BODY, _rope.NORM_OVER_ALL)
+                x = norm(name)(x.reshape(B, S, -1)).reshape(x.shape)
+            elif cfg.qk_norm:
+                scale = norm(name)(x, scale_alone=True)
+            if cos is None and scale is None:
+                return x
+            return apply_rope(x, cos, sin, in_place=self.in_place,
+                              scale=scale, eps=cfg.rms_eps)
+
         window = cfg.window_of(self.index)
         with (jax.named_scope(_scopes.ATTN_WINDOW) if window is not None
               else contextlib.nullcontext()):
-            if cos is not None:
-                q = apply_rope(q, cos, sin, in_place=self.in_place)
-                k = apply_rope(k, cos, sin, in_place=self.in_place)
+            with (jax.named_scope(_scopes.QK_NORM) if cfg.qk_norm
+                  else contextlib.nullcontext()):
+                q = normed_and_turned(q, "q_norm")
+                k = normed_and_turned(k, "k_norm")
             out = self.attend(x, q, k, v, cos, sin)
         out = out.reshape(B, S, heads * D)
         if cfg.gating == "per-head":

@@ -248,6 +248,8 @@ _MANIFEST_THEN = {
         ("olmo-hybrid-7b.train-s8k", "gdn_scan_roofline"),
     "test_benchmark_window.py::test_the_manifests_new_entries":
         ("laguna-s-2.1.train-s8k", "attn_gate_ms"),
+    "test_benchmark_gdn_solve.py::test_the_manifests_one_new_entry":
+        ("qwen3-next-80b-a3b.train-s8k-b2", "gdn_solve_ms"),
 }
 
 
@@ -256,7 +258,8 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
     """``tests/benchmark/test_benchmark_moe.py::test_the_manifests_new_
     entries`` (PR 32) and its namesakes in ``test_benchmark_sparse.py``
     (PR 34) and ``test_benchmark_hybrid.py`` (PR 38) pin their PR's entries
-    (``test_benchmark_window.py``'s, PR 42, the cells its new metrics list)
+    (``test_benchmark_window.py``'s, PR 42, the cells its new metrics list;
+    ``test_benchmark_gdn_solve.py``'s, PR 47, its one metric)
     as the LAST of every list of
     ``BENCHMARK.json`` and count the cells, and a later PR may neither
     edit those files nor put its entries anywhere but last.  So each of

@@ -292,6 +292,53 @@ def test_program_is_near_the_reference_in_bfloat16():
     assert abs(loss_off) < 0.02 and whole < 0.1, (loss_off, whole)
 
 
+def test_the_qk_norm_in_the_pass_is_the_modules_in_the_whole_program(
+        dtype=jnp.float32, limit=1e-4):
+    """At heads of 128 the flash seam norms and turns q and k in ONE Mosaic
+    call each (``ops/rope.py::norm_rotate_pairs``, interpreted here); the
+    model's own dense seam keeps the ``RMSNorm`` arithmetic and the jnp
+    rotation.  The same parameters (``q_norm/scale``, ``k_norm/scale``
+    ``[128]``, away from their start), the same loss and every gradient, the
+    two scales' among them, in float32 to the kernels' own distance from
+    dense attention (bf16 is ``tests/test_rope.py``'s to hold, a unit at a
+    time).  One layer: the second would only run the same calls again."""
+    from horovod_tpu.common import trace_counts
+    from horovod_tpu.ops import rope
+
+    job, config, params, batch = _tiny(dtype, head_dim=128,
+                                       num_hidden_layers=1)
+    attn = params["params"]["layer_0"]["attn"]
+    assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape == (
+        128,)
+    assert attn["wq"]["kernel"].shape == (128, 4 * 128)
+
+    def spread(path, leaf):
+        name = jax.tree_util.keystr(path)
+        if "q_norm" in name or "k_norm" in name:
+            return leaf * (1 + 0.3 * jax.random.normal(
+                jax.random.key(len(name)), leaf.shape)).astype(leaf.dtype)
+        return leaf
+
+    params = jax.tree_util.tree_map_with_path(spread, params)
+    normed = trace_counts.counts(rope.BODY).get(rope.NORMED, 0)
+    with HIGHEST:
+        loss, grads = jax.jit(jax.value_and_grad(job.loss_fn))(params, batch)
+        assert trace_counts.counts(rope.BODY)[rope.NORMED] > normed
+        normed = trace_counts.counts(rope.BODY)[rope.NORMED]
+        job.model = LlamaModel(job.llama)       # the dense seam: jnp bodies
+        want_loss, want = jax.jit(jax.value_and_grad(job.loss_fn))(params,
+                                                                   batch)
+        assert trace_counts.counts(rope.BODY)[rope.NORMED] == normed
+    assert abs(float(loss) - float(want_loss)) < limit
+    found = jax.tree_util.tree_flatten_with_path(grads)[0]
+    names = [jax.tree_util.keystr(path) for path, _ in found]
+    assert sum("q_norm" in n or "k_norm" in n for n in names) == 2
+    for (path, leaf), wanted in zip(found, jax.tree.leaves(want)):
+        off = float(jnp.linalg.norm((leaf - wanted).astype(jnp.float32))
+                    / (jnp.linalg.norm(wanted.astype(jnp.float32)) + 1e-30))
+        assert off < limit, (jax.tree_util.keystr(path), off)
+
+
 def test_the_stop_gradients_hold():
     """Under cross-entropy and the balance loss alone the indexer's three
     matrices get exactly nothing; under the indexer's loss alone every

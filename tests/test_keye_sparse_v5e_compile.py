@@ -24,6 +24,8 @@ from horovod_tpu.ops import sparse_index
 
 CELL = "keye-vl-2.0-30b-a3b.train-s8k-b2"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
+_USED = re.compile(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"')
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
 B, S, HEADS, KV_HEADS, D = 2, 8192, 32, 4, 128
 INDEX_HEADS, INDEX_DIM, TOPK = 16, 64, 2048
 SCALE = (INDEX_HEADS * INDEX_DIM) ** -0.5
@@ -129,13 +131,46 @@ def test_index_loss_is_one_mosaic_call_at_the_cells_shape(one_chip):
     assert _square_arrays(compiled, S) == {f"s8[{B},{S},{S}]"}
 
 
+@pytest.mark.parametrize("heads", [HEADS, KV_HEADS], ids=["q", "k"])
+def test_norm_and_rotation_are_one_call_a_pass_at_the_cells_shape(one_chip,
+                                                                   heads):
+    """``ops/rope.py::norm_rotate_pairs`` on ``bf16[2, 8192, heads * 128]``:
+    ONE Mosaic call forward and ONE backward (dx and the scale's partial
+    sums), no limit stated and the default scoped VMEM not filled, and no
+    float32 array of x's size around either."""
+    from horovod_tpu.models.llama import rope_freqs
+
+    x = jax.ShapeDtypeStruct((B, S, heads * D), jnp.bfloat16,
+                             sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((D,), jnp.float32, sharding=one_chip)
+
+    def both(x, scale, g):
+        cos, sin = rope_freqs(D, S, 1e7)
+        out, vjp = jax.vjp(lambda x, scale: rope.norm_rotate_pairs(
+            x, scale, cos, sin, 1e-6), x, scale)
+        return out, vjp(g)
+
+    text = jax.jit(both).lower(x, scale, x).compile().as_text()
+    calls = [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
+    assert len(calls) == 2 and all(scopes.ROPE in c for c in calls)
+    assert max(int(_USED.search(c)[1]) for c in calls) < _DEFAULT_SCOPED_VMEM
+    assert "vmem_limit_bytes" not in "".join(calls)
+    assert f"f32[{B},{S},{heads * D}]" not in text
+    assert f"f32[{B},{S},{heads},{D}]" not in text
+
+
 def test_the_cells_whole_step_fits_and_holds_no_scores(topo, one_chip):
     """Five layers of the published widths at 2 x 8192 tokens: a layer's
     attention is four Mosaic calls (select, flash forward, the indexer's
     loss, flash backward), none of them run again by the recomputing
-    backward pass, and six rotation passes (q and k: forward, recomputed,
-    backward); the only ``[.., S, S]`` array is the int8 selection; and
-    arguments + temporaries are under the chip's 16 GiB."""
+    backward pass, and six passes that norm and turn (q and k: forward,
+    recomputed, backward; under ``hvd.rope`` inside ``hvd.attn.qknorm``),
+    around which no float32 array of q's or k's size is left, heads apart
+    or together (PR 48; the parent held 75 ``f32[2,8192,32,128]``, 155
+    ``f32[2,8192,4096]`` and 30 ``f32[2048,8,32,128]``); the only ``[.., S,
+    S]`` array is the int8 selection; and arguments + temporaries are
+    under the chip's 16 GiB, the temporaries no more than before the norm
+    joined the pass (4.4345 GB)."""
     cell = manifest.cell(CELL)
     job = manifest.load_job(cell["config"]["job"]).build(
         cell["config"], cell["traffic"], 1)
@@ -156,6 +191,17 @@ def test_the_cells_whole_step_fits_and_holds_no_scores(topo, one_chip):
                   scopes.SPARSE_INDEX, scopes.FLASH_BWD):
         assert sum(scope in c for c in calls) == layers, scope
     assert sum(scopes.ROPE in c for c in calls) == 6 * layers
+    assert all(scopes.QK_NORM in c for c in calls if scopes.ROPE in c)
+    text = compiled.as_text()
+    for gone in ("f32[2,8192,32,128]", "f32[2048,8,32,128]",
+                 "f32[2,8192,4,128]", "f32[2,8192,4096]"):
+        assert gone not in text, gone
+    # (What XLA still copies under the scope is bf16: the indexer's loss
+    # reads q and k transposed, ``ops/sparse_index.py``'s own.)
+    under_norm = [line for line in text.splitlines()
+                  if scopes.QK_NORM in line and " = " in line]
+    assert under_norm and not [line for line in under_norm
+                               if re.search(r"= f32\[2,8192,", line)]
     assert not any(scopes.REMATTED in c for c in calls
                    if scopes.RAGGED_DOT_PREFIX not in c
                    and scopes.ROPE not in c)
@@ -163,5 +209,6 @@ def test_the_cells_whole_step_fits_and_holds_no_scores(topo, one_chip):
     assert _square_arrays(compiled, seq) == {f"s8[2,{seq},{seq}]"}
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(7.872e9, rel=1e-3)
+    assert memory.temp_size_in_bytes <= 4.4345e9
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 16 * 2 ** 30

@@ -436,6 +436,57 @@ def test_the_mosaic_pass_turns_a_quarter_of_a_head_of_256(dtype):
         1e-5 if dtype == jnp.float32 else 1e-2)
 
 
+@pytest.mark.parametrize("dtype, limit", [(jnp.float32, 1e-4),
+                                          (jnp.bfloat16, 0.1)],
+                         ids=["f32", "bf16"])
+def test_the_full_layer_norms_heads_of_256_in_the_rotations_pass(dtype,
+                                                                 limit):
+    """A full layer at heads of 256 (2 over 1, zero-centred scales, a
+    quarter of a head turning, the element-wise gate) with the flash seam:
+    q and k are normed and turned by ``ops/rope.py::norm_rotate_pairs``
+    (interpreted), ONE call each; with the model's dense attention by the
+    module's arithmetic and the jnp rotation.  The same tree (``q_norm/
+    scale``, ``k_norm/scale`` ``[256]``), the same loss, every gradient: in
+    float32 to the flash calls' own distance from dense attention, in bf16
+    to its rounding through a routed layer (the router's gradient 7 %)."""
+    from horovod_tpu.common import trace_counts
+    from horovod_tpu.ops import rope
+
+    cfg = tiny(num_layers=1, layer_types=("full_attention",), num_heads=2,
+               num_kv_heads=1, attention_head_dim=256, dtype=dtype,
+               logits_dtype=dtype)
+    tokens = jax.random.randint(jax.random.key(3), (2, 65), 0,
+                                cfg.vocab_size)
+    params = spread(LlamaModel(cfg).init(jax.random.key(0), tokens[:, :8]),
+                    width=0.3)
+    params = jax.tree.map(lambda p: p.astype(dtype), params)
+    attn = params["params"]["layer_0"]["attn"]
+    assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape == (
+        256,)
+    assert attn["wq"]["kernel"].shape == (64, 2 * 2 * 256)
+
+    def loss_and_grads(attention_fn):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(lambda p, t: model_loss(
+                cfg, attention_fn, p, t)))(params, tokens)
+
+    def normed():
+        return trace_counts.counts(rope.BODY).get(rope.NORMED, 0)
+
+    before = normed()
+    loss, grads = loss_and_grads(flash_attention_fn)
+    assert normed() == before + 2
+    want_loss, want = loss_and_grads(causal_attention)
+    assert normed() == before + 2
+    assert abs(float(loss) - float(want_loss)) < limit
+    found = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert sum("_norm" in jax.tree_util.keystr(path) and "attn" in
+               jax.tree_util.keystr(path) for path, _ in found) == 2
+    for (path, leaf), wanted in zip(found, jax.tree.leaves(want)):
+        assert rel(leaf.astype(jnp.float32), wanted.astype(
+            jnp.float32)) < limit, jax.tree_util.keystr(path)
+
+
 @pytest.mark.parametrize("s, d, masked, stated", [
     (8192, 128, False, False), (2048, 128, False, False),
     (4096, 192, False, False), (8192, 256, False, True),

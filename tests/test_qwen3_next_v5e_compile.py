@@ -161,6 +161,37 @@ def test_the_solve_compiles_at_a_slabs_shape_and_states_no_limit(one_chip,
     assert f"f32[{chunk},{chunk},{count}]" in calls[0]
 
 
+@pytest.mark.parametrize("heads", [HEADS, KV_HEADS], ids=["q", "k"])
+def test_norm_and_rotation_are_one_call_a_pass_at_heads_of_256(one_chip,
+                                                               heads):
+    """``ops/rope.py::norm_rotate_pairs`` on ``bf16[2, 8192, heads * 256]``,
+    a quarter of a head turning: a head is two lane tiles whose squares are
+    added before the one reduction; ONE Mosaic call forward and ONE
+    backward, no limit stated, the default scoped VMEM not filled, no
+    float32 array of x's size around either."""
+    from horovod_tpu.models.llama import rope_freqs
+    from horovod_tpu.ops import rope
+
+    x = jax.ShapeDtypeStruct((B, S, heads * D), jnp.bfloat16,
+                             sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((D,), jnp.float32, sharding=one_chip)
+
+    def both(x, scale, g):
+        cos, sin = rope_freqs(D, S, 1e7, rotary_dim=D // 4)
+        out, vjp = jax.vjp(lambda x, scale: rope.norm_rotate_pairs(
+            x, 1.0 + scale, cos, sin, 1e-6), x, scale)
+        return out, vjp(g)
+
+    assert _stated_limits(both, x, scale, x) == [None, None]
+    text = jax.jit(both).lower(x, scale, x).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2 and all(scopes.ROPE in c for c in calls)
+    assert max(int(_USED.search(c)[1]) for c in calls) < (
+        fa._DEFAULT_SCOPED_VMEM)
+    assert f"f32[{B},{S},{heads * D}]" not in text
+    assert f"f32[{B},{S},{heads},{D}]" not in text
+
+
 def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     """Four layers of the published widths at 2 x 8192 tokens: 8.76 GB of
     state and 4.03 GB of temporaries under ``layer_keep_attention``
@@ -201,7 +232,12 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     backward = [c for c in calls if scopes.FLASH_BWD in c]
     assert len(forward) == len(backward) == 1
     assert not any(scopes.REMATTED in c for c in forward)
+    # q and k normed and turned by one call each, forward, again, backward
+    # (PR 48): no float32 array of their size, heads apart or together.
     assert sum(scopes.ROPE in c for c in calls) == 6
+    assert all(scopes.QK_NORM in c for c in calls if scopes.ROPE in c)
+    for gone in ("f32[2,8192,16,256]", "f32[2,8192,2,256]"):
+        assert gone not in text, gone
     assert sum(scopes.GDN_CONV in c for c in calls) == 27
     # The slabs' systems (512 matrices: four grid steps) by the solve's call,
     # forward, again, and in the backward slab's preparation, a linear
