@@ -143,18 +143,37 @@ scope                 what falls under it
                       the ``jnp`` body's products) and its transpose's two
                       products; a reader that knows ``hvd.gdn.scan`` alone
                       walks outward to it and counts the solve there
+``hvd.ssd.conv``      a Mamba-2 layer's (``models/llama.py::Mamba2``) causal
+                      depthwise convolution over its x, B and C channels,
+                      the filter's bias and the SiLU: the ``jnp`` body
+                      (``ops/short_conv.py::_convolved_plain``; its Mosaic
+                      pass takes no bias), forward, recomputed and backward
+``hvd.ssd.gates``     the same layer's elementwise work around the scan:
+                      ``softplus(dt + dt_bias)``; behind the scan the skip
+                      ``D u``, the gate ``silu(z)`` and the grouped RMSNorm
+                      BEHIND the gate
+``hvd.ssd.scan``      the chunked state-space recurrence itself
+                      (``ops/ssd.py::ssd_scan``): the log-decays, cutting
+                      into chunks, every chunk's products, the walk over the
+                      chunks that carries the state; forward, run again
+                      under recomputation and backward; XLA operations, and
+                      Mosaic calls should a later kernel replace part of it
 ``hvd.block.attn``    a layer's mixer block whole
                       (``models/llama.py::LlamaLayer``): ``norm_attn``,
                       the mixer (``LlamaAttention``, ``LatentAttention``,
-                      ``SparseAttention`` or ``GatedDeltaNet``:
+                      ``SparseAttention``, ``GatedDeltaNet`` or ``Mamba2``:
                       projections, QK-norm, rotation, the ``attention_fn``
                       call or the rule, ``wo``) and the residual add.
                       ``hvd.flash.*``, ``hvd.rope``, ``hvd.attn.*``,
-                      ``hvd.mla.latent``, ``hvd.sparse.*`` and ``hvd.gdn.*``
-                      nest inside it
+                      ``hvd.mla.latent``, ``hvd.sparse.*``, ``hvd.gdn.*`` and
+                      ``hvd.ssd.*`` nest inside it.  In a stack whose
+                      layers are ONE sublayer
+                      (``LlamaConfig.hybrid_override_pattern``) a mixer
+                      layer is this block alone, with the layer's one norm
 ``hvd.block.ffn``     a layer's feed-forward block whole: ``norm_mlp``,
                       ``SwiGLU`` or ``RoutedExperts`` (``hvd.moe.*`` nest
-                      inside it) and the residual add
+                      inside it) and the residual add; in a stack of
+                      one-sublayer layers a routed layer is this block alone
 ``hvd.head``          what turns the stack's output into a loss: the final
                       norm (with a looped model's exit gate, nested in
                       ``hvd.loop.exit``), ``LlamaModel.head``'s product,
@@ -239,6 +258,7 @@ __all__ = [
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
+    "SSD_CONV", "SSD_GATES", "SSD_SCAN",
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
@@ -272,6 +292,9 @@ GDN_GATES = "hvd.gdn.gates"
 GDN_SCAN = "hvd.gdn.scan"
 GDN_HEADS = "hvd.gdn.heads"
 GDN_SOLVE = "hvd.gdn.solve"       # inside GDN_SCAN
+SSD_CONV = "hvd.ssd.conv"
+SSD_GATES = "hvd.ssd.gates"
+SSD_SCAN = "hvd.ssd.scan"
 BLOCK_ATTN = "hvd.block.attn"
 BLOCK_FFN = "hvd.block.ffn"
 HEAD = "hvd.head"

@@ -54,6 +54,7 @@ from horovod_tpu.ops.gated_delta import (calls_in_place, gated_delta_rule,
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops import rope as _rope
 from horovod_tpu.ops.short_conv import convolved, over_heads
+from horovod_tpu.ops.ssd import ssd_scan, ssd_states
 from horovod_tpu.ops.sparse_index import index_loss, select_keys
 
 __all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "RopeParameters",
@@ -77,7 +78,15 @@ REMAT_POLICIES = {
         _scopes.SPARSE_SELECTED_NAME, _scopes.SPARSE_INDEX_LOSS_NAME),
 }
 
+# The collection a bias-corrected router keeps its choice bias in: state the
+# load moves and no gradient does (``RoutedExperts``).
+ROUTER_STATE = "router_state"
+
 LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention")
+# ``hybrid_override_pattern``'s characters, as Nemotron-H publishes them: a
+# Mamba-2 layer, a routed feed-forward layer, a softmax attention layer.
+MAMBA, EXPERTS, ATTENTION = PATTERN_KINDS = ("M", "E", "*")
+DENSE_LAYER = "-"       # published too; no model here has one: refused
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -240,6 +249,27 @@ class LlamaConfig:
     shared experts' output by ``sigmoid(x w_s)``, ``w_s`` hidden x 1.
     Generation, the serve plane and the pipelined step refuse the three by
     name.
+
+    ``hybrid_override_pattern`` (Nemotron-H's published key: a string, or a
+    tuple, of one character a layer) makes every layer ONE sublayer behind
+    one norm with one residual add: ``"M"`` a ``Mamba2`` state-space mixer
+    (``mamba_num_heads`` heads of ``mamba_head_dim``, ``ssm_state_size``
+    state entries a lane, B and C shared by ``n_groups`` groups, a biased
+    filter of ``conv_kernel`` taps, the recurrence in chunks of
+    ``chunk_size``: ``ops/ssd.py``), ``"E"`` ``RoutedExperts`` and ``"*"``
+    ``attention_kind``'s mixer; ``"-"``, a dense feed-forward alone, is
+    refused.  ``num_layers`` then counts sublayers, and ``remat``'s
+    ``layer`` policies checkpoint one sublayer.  ``scoring_func``
+    ``"sigmoid"`` scores the router's logits one by one in place of a
+    softmax; ``topk_method`` ``"noaux_tc"`` (DeepSeek-V3's name) adds a bias
+    to the scores for the CHOICE alone, kept in the ``router_state``
+    collection and moved by ``router_bias_update_rate`` against each
+    expert's load where the caller makes that collection mutable, never by
+    a gradient; ``mlp_hidden_act`` ``"relu2"`` makes the experts and the
+    shared expert ``relu(x W_up)^2 W_down``, two matrices and no gate;
+    ``moe_shared_expert_intermediate_size`` is the shared expert's own
+    width.  Generation, the serve plane and the pipelined step refuse a
+    pattern, state-space layers and a bias-corrected router by name.
     """
 
     vocab_size: int = 32000
@@ -287,6 +317,18 @@ class LlamaConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_scaling: Optional[YarnScaling] = None
+    hybrid_override_pattern: Optional[str] = None     # "MEMEM*E.." or a tuple
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 1             # of a Mamba-2 layer's B and C
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    scoring_func: str = "softmax"                 # or "sigmoid"
+    topk_method: str = "greedy"                   # or "noaux_tc"
+    mlp_hidden_act: str = "silu"                  # or "relu2" (the experts')
+    moe_shared_expert_intermediate_size: int = 0  # 0: shared_experts x F
+    router_bias_update_rate: float = 1e-3
     dtype: Any = jnp.bfloat16
     # Output-head compute dtype.  bf16 keeps every logits-sized tensor —
     # the forward residual AND the cross-entropy cotangent, 2 GB each in
@@ -376,6 +418,45 @@ class LlamaConfig:
                                             and self.shared_experts):
             raise ValueError("shared_expert_gate gates shared experts: "
                              "num_experts > 1 and shared_experts >= 1")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func is {self.scoring_func!r}: "
+                             f"'softmax' or 'sigmoid'")
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"topk_method is {self.topk_method!r}: 'greedy' "
+                             f"or 'noaux_tc' (a bias corrects the choice)")
+        if self.mlp_hidden_act not in ("silu", "relu2"):
+            raise ValueError(f"mlp_hidden_act is {self.mlp_hidden_act!r}: "
+                             f"'silu' (gated) or 'relu2' (not gated)")
+        pattern = self.hybrid_override_pattern
+        if pattern is not None:
+            if DENSE_LAYER in pattern:
+                raise ValueError(
+                    f"hybrid_override_pattern {pattern!r} holds a dense "
+                    f"feed-forward layer ({DENSE_LAYER!r}): a layer that is "
+                    f"a dense MLP alone is not built")
+            if (len(pattern) != self.num_layers
+                    or set(pattern) - set(PATTERN_KINDS)):
+                raise ValueError(
+                    f"hybrid_override_pattern is {pattern!r}: one of "
+                    f"{PATTERN_KINDS} for each of {self.num_layers} layers")
+            if (self.layer_types is not None or self.norm_placement != "pre"
+                    or self.first_dense_layers):
+                raise ValueError(
+                    "hybrid_override_pattern names every layer's ONE "
+                    "sublayer behind a pre-norm: layer_types, "
+                    "norm_placement='post' and first_dense_layers do not "
+                    "go with it")
+            if EXPERTS in pattern and self.num_experts < 2:
+                raise ValueError("an 'E' layer needs num_experts > 1")
+            if MAMBA in pattern and (
+                    not (self.mamba_num_heads and self.mamba_head_dim
+                         and self.ssm_state_size)
+                    or self.mamba_num_heads % self.n_groups
+                    or self.mamba_num_heads * self.mamba_head_dim
+                    % self.n_groups):
+                raise ValueError(
+                    "an 'M' layer needs mamba_num_heads, mamba_head_dim and "
+                    "ssm_state_size, the heads a multiple of n_groups")
         if self.rope_parameters is not None:
             kinds = {kind for kind, _ in self.rope_parameters}
             used = set(self.layer_types or ("full_attention",)) - {
@@ -417,8 +498,21 @@ class LlamaConfig:
     def experts_held(self) -> int:
         return self.held_experts or self.num_experts
 
+    def kind_of(self, layer: int) -> Optional[str]:
+        """``layer``'s one sublayer where ``hybrid_override_pattern`` names
+        it (``"M"``, ``"E"`` or ``"*"``); None: a mixer and a feed-forward."""
+        pattern = self.hybrid_override_pattern
+        return None if pattern is None else pattern[layer]
+
     def is_routed(self, layer: int) -> bool:
+        if self.hybrid_override_pattern is not None:
+            return self.kind_of(layer) == EXPERTS
         return self.num_experts > 1 and layer >= self.first_dense_layers
+
+    @property
+    def mamba_inner(self) -> int:
+        """Channels of a Mamba-2 layer's u, z and output: heads x width."""
+        return self.mamba_num_heads * self.mamba_head_dim
 
     @property
     def has_linear_layers(self) -> bool:
@@ -457,6 +551,28 @@ class LlamaConfig:
     def refuse_new_kinds(self, who: str) -> None:
         """For the paths that keep a decoder layer of their own and have
         learned neither kind (ROADMAP.md D1): raise, naming the kind."""
+        pattern = self.hybrid_override_pattern
+        if pattern is not None and MAMBA in pattern:
+            raise NotImplementedError(
+                f"{who} has no path for Mamba-2 state-space layers "
+                f"(hybrid_override_pattern holds {MAMBA!r}): beside K and V "
+                f"its cache would hold a [{self.mamba_head_dim}, "
+                f"{self.ssm_state_size}] float32 state for each of "
+                f"{self.mamba_num_heads} heads and the filter's last "
+                f"{self.conv_kernel - 1} inputs, and a decode step would "
+                f"update them in place; not built")
+        if self.topk_method == "noaux_tc":
+            raise NotImplementedError(
+                f"{who} has no path for a bias-corrected router "
+                f"(topk_method='noaux_tc'): it would read the "
+                f"[{self.num_experts}] choice bias from a collection beside "
+                f"the parameters and never update it; not built")
+        if pattern is not None:
+            raise NotImplementedError(
+                f"{who} has no path for a layer pattern "
+                f"(hybrid_override_pattern={pattern!r}): its layer is "
+                f"always a mixer AND a feed-forward with two norms, and a "
+                f"stage's layers share one shape; not built")
         if self.attention_kind == "latent":
             raise NotImplementedError(
                 f"{who} has no path for latent attention "
@@ -877,6 +993,22 @@ class SwiGLU(nn.Module):
                         name="w_down")(nn.silu(gate) * up)
 
 
+class Relu2MLP(nn.Module):
+    """``relu(x W_up)^2 W_down``: the feed-forward of ``mlp_hidden_act``
+    ``"relu2"``, two matrices and no gate."""
+
+    config: LlamaConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        up = nn.Dense(self.width, use_bias=False, dtype=cfg.dtype,
+                      name="w_up")(x)
+        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                        name="w_down")(_relu2(up))
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (MLA) of DeepSeek-V2 (arXiv:2405.04434),
     on the training path: keys and values are made from one low-rank
@@ -1019,6 +1151,11 @@ _weighted_rows_to_tokens.defvjp(_weighted_rows_to_tokens_fwd,
                                 _weighted_rows_to_tokens_bwd)
 
 
+def _relu2(x):
+    """``relu(x)`` squared (``mlp_hidden_act`` ``"relu2"``)."""
+    return jnp.square(nn.relu(x))
+
+
 def _row_chunk(assignments: int, share: float) -> int:
     """Rows of the row buffer: twice what a uniform router sends to a chip
     that holds ``share`` of the experts, in whole tiles of 512; all the
@@ -1026,15 +1163,18 @@ def _row_chunk(assignments: int, share: float) -> int:
     return min(-(-int(2 * share * assignments) // 512) * 512, assignments)
 
 
-@functools.partial(jax.jit, static_argnums=(9, 10), inline=True)
+@functools.partial(jax.jit, static_argnums=(9, 10, 12), inline=True)
 def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
-                rows_per_expert, n_rows, chunk, k, first=0):
+                rows_per_expert, n_rows, chunk, k, first=0, act="silu"):
     """The part of a routed layer's y that the sorted rows ``first`` to
     ``first + chunk`` give: gathered, through their experts, and back to
     their tokens under the gates.  ``[T, H]`` float32; all zeros, and so is
     every gradient, where no row is held from ``first`` on.  ``order`` the
     assignments sorted by held expert, ``inverse`` each assignment's place
-    among them, ``last`` the running sum of ``rows_per_expert``.
+    among them, ``last`` the running sum of ``rows_per_expert``.  ``act``
+    ``"silu"``: ``w_gu`` is ``[held, H, 2F]``, a SwiGLU's gate and up;
+    ``"relu2"``: it is ``w_up [held, H, F]`` and the rows between the two
+    products are ``relu(.)`` squared, no gate.
 
     Under an inlined ``jit`` so that it is traced once a shape: a step
     calls it ten times a routed layer (the first buffer and the loop's, in
@@ -1056,9 +1196,13 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
         # both ends (here for the gradient that comes back).
         rows = jnp.where(live, rows, 0)
     with jax.named_scope(_scopes.MOE_EXPERTS):
-        gate, up = jnp.split(
-            jax.lax.ragged_dot(rows, w_gu, sizes), 2, axis=-1)
-        rows = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
+        if act == "relu2":
+            rows = _relu2(jax.lax.ragged_dot(rows, w_gu, sizes))
+        else:
+            gate, up = jnp.split(
+                jax.lax.ragged_dot(rows, w_gu, sizes), 2, axis=-1)
+            rows = nn.silu(gate) * up
+        rows = jax.lax.ragged_dot(rows, w_down, sizes)
     with jax.named_scope(_scopes.MOE_COMBINE):
         rows = jnp.where(live, rows, 0)
         return _weighted_rows_to_tokens(rows, weights, assignments,
@@ -1076,9 +1220,9 @@ def _over_live_buffers(of_buffer, n_rows, chunk):
         of_buffer(np.int32(0)))     # of the loop's type: one trace serves
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
 def _live_buffers(tokens, w_gu, w_down, weights, order, inverse, last,
-                  rows_per_expert, n_rows, chunk, k):
+                  rows_per_expert, n_rows, chunk, k, act="silu"):
     """``_one_buffer`` summed over ``ceil(n_rows / chunk)`` buffers, forward
     and backward: a chip's share of the experts gets a fraction of the worst
     case's rows and pays for what it gets.
@@ -1094,19 +1238,20 @@ def _live_buffers(tokens, w_gu, w_down, weights, order, inverse, last,
     return _over_live_buffers(
         lambda first: _one_buffer(tokens, w_gu, w_down, weights, order,
                                   inverse, last, rows_per_expert, n_rows,
-                                  chunk, k, first), n_rows, chunk)
+                                  chunk, k, first, act), n_rows, chunk)
 
 
 def _live_buffers_fwd(*inputs):
     return _live_buffers(*inputs), inputs[:9]
 
 
-def _live_buffers_bwd(chunk, k, inputs, g):
+def _live_buffers_bwd(chunk, k, act, inputs, g):
     differentiable, indices = inputs[:4], inputs[4:]
 
     def cotangents(first):
         return jax.vjp(lambda *operands: _one_buffer(
-            *operands, *indices, chunk, k, first), *differentiable)[1](g)
+            *operands, *indices, chunk, k, first, act),
+            *differentiable)[1](g)
 
     return (*_over_live_buffers(cotangents, indices[-1], chunk),
             *map(_float0, indices))
@@ -1158,8 +1303,24 @@ class RoutedExperts(nn.Module):
     Weights are ``w_gate_up [held, H, 2F]`` and ``w_down [held, F, H]``,
     which ``parallel/api.py`` shards over an ``expert`` axis.
 
+    ``scoring_func`` ``"sigmoid"``: ``s = sigmoid(x W_r)``.  ``topk_method``
+    ``"noaux_tc"``: ``e_1..e_K`` are the K largest of ``s + b`` under a
+    ``stop_gradient`` and the gates stay ``s[e_k]``; ``b [num_experts]``
+    float32 lives in the ``router_state`` collection, zeros at the start, and
+    where the caller makes that collection mutable a call leaves ``b +
+    router_bias_update_rate sign(mean_e c - c_e)`` there, c_e the call's
+    assignments to expert e over ALL the experts (the router is full width
+    on every chip).  That is one chip's update: ``make_train_step``'s
+    ``has_aux`` path AVERAGES such state over a ``data`` axis, the mean of
+    the chips' updates and not the update of the summed counts.
+    ``mlp_hidden_act`` ``"relu2"``: E_e and S are ``relu(x W_up)^2 W_down``
+    (``w_up [held, H, F]`` in place of ``w_gate_up``; S a ``Relu2MLP``), and
+    S is ``moe_shared_expert_intermediate_size`` wide where that is set.
+
     Outside ``init`` it sows, where the caller makes the collection
-    mutable: ``losses/balance`` (``ops.losses.sequence_balance_loss`` of
+    mutable: ``moe_stats/assignments_per_expert [num_experts]``,
+    ``load_max_over_mean`` and (with a bias) ``bias_abs_max``, and always
+    ``losses/balance`` (``ops.losses.sequence_balance_loss`` of
     this layer) and ``moe_stats/rows_per_expert [held]``,
     ``moe_stats/rows_dropped`` (assignments to held experts that are not
     in a buffer: 0 by construction) and ``moe_stats/row_buffers_run``
@@ -1176,16 +1337,32 @@ class RoutedExperts(nn.Module):
         F = cfg.moe_intermediate_size or cfg.intermediate_size
         T = B * S
         per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gu = self.param("w_gate_up", per_expert,
-                          (held, H, 2 * F)).astype(cfg.dtype)
+        act = cfg.mlp_hidden_act
+        if act == "relu2":
+            w_gu = self.param("w_up", per_expert, (held, H, F))
+        else:
+            w_gu = self.param("w_gate_up", per_expert, (held, H, 2 * F))
+        w_gu = w_gu.astype(cfg.dtype)
         w_down = self.param("w_down", per_expert,
                             (held, F, H)).astype(cfg.dtype)
+        corrected = cfg.topk_method == "noaux_tc"
 
         with jax.named_scope(_scopes.MOE_ROUTE):
             router = nn.Dense(E, use_bias=False, dtype=jnp.float32,
                               name="router")(x.astype(jnp.float32))
-            scores = jax.nn.softmax(router, axis=-1)               # [B,S,E]
-            gates, chosen = jax.lax.top_k(scores, K)               # [B,S,K]
+            if cfg.scoring_func == "sigmoid":
+                scores = jax.nn.sigmoid(router)                    # [B,S,E]
+            else:
+                scores = jax.nn.softmax(router, axis=-1)
+            if corrected:
+                # The bias moves the CHOICE; the gates are the scores.
+                bias = self.variable(ROUTER_STATE, "bias", jnp.zeros, (E,),
+                                     jnp.float32)
+                _, chosen = jax.lax.top_k(jax.lax.stop_gradient(
+                    scores + bias.value), K)
+                gates = jnp.take_along_axis(scores, chosen, axis=-1)
+            else:
+                gates, chosen = jax.lax.top_k(scores, K)           # [B,S,K]
             if cfg.norm_topk_prob:
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
             if cfg.routed_scaling_factor != 1.0:
@@ -1207,24 +1384,34 @@ class RoutedExperts(nn.Module):
             if not self.is_initializing():
                 balance = (batch_balance_loss if cfg.balance_over == "batch"
                            else sequence_balance_loss)
-                self.sow("losses", "balance", balance(scores, chosen))
+                # (Sigmoid scores do not sum to one: the loss reads their
+                # shares of a token's sum, DeepSeek-V3's form, 1 when even.)
+                self.sow("losses", "balance", balance(
+                    scores / jnp.sum(scores, axis=-1, keepdims=True)
+                    if cfg.scoring_func == "sigmoid" else scores, chosen))
                 self.sow("moe_stats", "rows_per_expert", rows_per_expert)
                 self.sow("moe_stats", "rows_dropped",
                          jnp.maximum(n_rows - n_chunks * chunk, 0))
                 self.sow("moe_stats", "row_buffers_run",
                          -(-n_rows // chunk))
+                self._count_all(chosen, bias if corrected else None)
 
         order = jnp.pad(order, (0, n_chunks * chunk - T * K))
-        walk = _one_buffer if n_chunks == 1 else _live_buffers
-        y = walk(x.reshape(T, H), w_gu, w_down, weights, order, inverse,
-                 jnp.cumsum(rows_per_expert), rows_per_expert, n_rows, chunk,
-                 K)
+        operands = (x.reshape(T, H), w_gu, w_down, weights, order, inverse,
+                    jnp.cumsum(rows_per_expert), rows_per_expert, n_rows,
+                    chunk, K)
+        if n_chunks == 1:
+            y = _one_buffer(*operands, 0, act)
+        else:
+            y = _live_buffers(*operands, act)
         y = y.astype(cfg.dtype).reshape(B, S, H)
 
         if cfg.shared_experts:
             with jax.named_scope(_scopes.MOE_SHARED):
-                shared = SwiGLU(cfg, width=cfg.shared_experts * F,
-                                name="shared")(x)
+                width = (cfg.moe_shared_expert_intermediate_size
+                         or cfg.shared_experts * F)
+                shared = (Relu2MLP if act == "relu2" else SwiGLU)(
+                    cfg, width=width, name="shared")(x)
                 if cfg.shared_expert_gate:
                     # One logit a token, float32 as the router's.
                     gate = nn.Dense(1, use_bias=False, dtype=jnp.float32,
@@ -1234,6 +1421,28 @@ class RoutedExperts(nn.Module):
                         shared.dtype)
                 y = y + shared
         return y
+
+    def _count_all(self, chosen, bias):
+        """The step's assignments to each of ALL the experts (what the
+        choice bias learns from), sown with what it says of the load, and
+        the bias moved by it where the caller made its collection mutable."""
+        cfg = self.config
+        wanted = self.is_mutable_collection("moe_stats")
+        moving = bias is not None and self.is_mutable_collection(
+            ROUTER_STATE)
+        if not (wanted or moving):
+            return
+        counts = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(
+            cfg.num_experts)[None, :], axis=0, dtype=jnp.int32)
+        mean = chosen.size / cfg.num_experts
+        self.sow("moe_stats", "assignments_per_expert", counts)
+        self.sow("moe_stats", "load_max_over_mean", jnp.max(counts) / mean)
+        if bias is not None:
+            self.sow("moe_stats", "bias_abs_max",
+                     jnp.max(jnp.abs(bias.value)))
+        if moving:
+            bias.value = bias.value + cfg.router_bias_update_rate * jnp.sign(
+                mean - counts.astype(jnp.float32))
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
@@ -1361,6 +1570,105 @@ class GatedDeltaNet(nn.Module):
                         name="wo")(o)
 
 
+def _mamba_a_log_init(key, shape, dtype=jnp.float32):
+    """``log A`` with A uniform in (1, 16): Mamba-2's ``A_init_range``."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _gate_then_norm(y, z, scale, groups, eps):
+    """``rms_norm_g(y * silu(z)) * scale``: the gate FIRST, then the norm
+    over each of ``groups`` runs of lanes (Mamba-2's ``norm_before_gate=
+    False``); y, z ``[B, S, C]``, ``scale [C]``; float32 inside, the dtype
+    of z out, and under a checkpoint as ``_gated_norm``."""
+    y = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+    grouped = y.reshape(*y.shape[:-1], groups, -1)
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
+    return (grouped.reshape(y.shape) * scale).astype(z.dtype)
+
+
+class Mamba2(nn.Module):
+    """The Mamba-2 state-space mixer (state-space duality; Dao & Gu,
+    arXiv:2405.21060) of an ``"M"`` layer, as Nemotron-H sizes it.  With
+    H = ``mamba_num_heads`` heads of P = ``mamba_head_dim``, a state of N =
+    ``ssm_state_size`` a lane, B and C shared by the heads of each of G =
+    ``n_groups`` groups (head h reads group ``h // (H / G)``), x the block's
+    normed input and ``*`` the causal depthwise convolution of
+    ``conv_kernel`` taps, with a bias::
+
+        [z | xBC | dt] = x W_in               z [H P], xBC [H P + 2 G N], dt [H]
+        [u | B | C] = silu(conv * xBC + b_conv)
+        D_t = softplus(dt_t + dt_bias)    a_t = exp(-exp(A_log) D_t)     float32
+        S_t = a_t S_{t-1} + D_t u_t B_t^T     [P, N] a head, float32, S_0 = 0
+        y_t = S_t C_t + D u_t                 D one scalar a head
+        out = (RMSNorm_G(y * silu(z)) * w) W_out
+
+    The gate multiplies BEFORE the norm, which is over each group's ``H P /
+    G`` lanes.  The recurrence runs chunk by chunk (``ops/ssd.py``,
+    ``chunk_size`` rows).  Parameters: ``in_proj [hidden, 2 H P + 2 G N +
+    H]``, ``conv_w [K, H P + 2 G N]``, ``conv_b``, ``a_log dt_bias d [H]``,
+    ``norm [H P]``, ``out_proj``.
+
+    Sown where the caller makes ``ssd_stats`` mutable: ``decay_min``,
+    ``decay_mean`` (of a_t), ``dt_mean``, ``state_max`` (the largest |S| a
+    chunk started from) and ``out_max`` (the largest |y|).
+    """
+
+    config: LlamaConfig
+    in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        B, S, _ = x.shape
+        heads, width, state = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                               cfg.ssm_state_size)
+        groups, inner = cfg.n_groups, cfg.mamba_inner
+        bc = groups * state
+        projected = nn.Dense(2 * inner + 2 * bc + heads, use_bias=False,
+                             dtype=cfg.dtype, name="in_proj")(x)
+        z = projected[..., :inner]
+        xbc = projected[..., inner:2 * inner + 2 * bc]
+        dt = projected[..., 2 * inner + 2 * bc:]
+        with jax.named_scope(_scopes.SSD_CONV):
+            xbc = convolved(
+                xbc, self.param("conv_w", _conv_taps_init,
+                                (cfg.conv_kernel, inner + 2 * bc)),
+                1, None, self.in_place,
+                bias=self.param("conv_b", nn.initializers.zeros,
+                                (inner + 2 * bc,)))
+            u = xbc[..., :inner].reshape(B, S, heads, width)
+            b = xbc[..., inner:inner + bc].reshape(B, S, groups, state)
+            c = xbc[..., inner + bc:].reshape(B, S, groups, state)
+        a_log = self.param("a_log", _mamba_a_log_init, (heads,))
+        with jax.named_scope(_scopes.SSD_GATES):
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + self.param(
+                "dt_bias", _dt_bias_init, (heads,)))
+        with jax.named_scope(_scopes.SSD_SCAN):
+            y = ssd_scan(u, dt, a_log, b, c, chunk=cfg.chunk_size)
+        with jax.named_scope(_scopes.SSD_GATES):
+            d = self.param("d", nn.initializers.ones, (heads,))
+            y = (y.astype(jnp.float32) + d[:, None] * u.astype(jnp.float32)
+                 ).astype(u.dtype)
+        if (self.is_mutable_collection("ssd_stats")
+                and not self.is_initializing()):
+            decay = jnp.exp(-jnp.exp(a_log) * dt)
+            for name, value in (
+                    ("decay_min", jnp.min(decay)),
+                    ("decay_mean", jnp.mean(decay)),
+                    ("dt_mean", jnp.mean(dt)),
+                    ("state_max", jnp.max(jnp.abs(ssd_states(
+                        u, dt, a_log, b, c, chunk=cfg.chunk_size)))),
+                    ("out_max", jnp.max(jnp.abs(y.astype(jnp.float32))))):
+                self.sow("ssd_stats", name, value)
+        with jax.named_scope(_scopes.SSD_GATES):
+            y = _gate_then_norm(y.reshape(B, S, inner), z, self.param(
+                "norm", nn.initializers.ones, (inner,)), groups, cfg.rms_eps)
+        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                        name="out_proj")(y)
+
+
 ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention,
                    "sparse": SparseAttention}
 
@@ -1375,7 +1683,13 @@ class LlamaLayer(nn.Module):
     ``attention_kind``'s as ``"attn"``, which is told the index too: its
     head count, window and gate may differ by layer) and
     ``LlamaConfig.is_routed``.  ``cos``, ``sin`` are the tables of this
-    layer's type."""
+    layer's type.
+
+    Where ``hybrid_override_pattern`` names the layer's kind
+    (``LlamaConfig.kind_of``) the layer is ONE sublayer behind ONE norm
+    (``"norm"``) with one residual add: a ``Mamba2`` (``"mamba"``) or
+    ``attention_kind``'s mixer (``"attn"``) under ``hvd.block.attn``, or
+    ``RoutedExperts`` (``"moe"``) under ``hvd.block.ffn``."""
 
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
@@ -1386,15 +1700,19 @@ class LlamaLayer(nn.Module):
         cfg = self.config
         # The one reading of the rule: every mixer is handed the answer.
         in_place = _reads_in_place(self.attention_fn)
-        if cfg.is_linear(self.index):
+        kind = cfg.kind_of(self.index)
+        mixer = ffn = None
+        if kind == MAMBA:
+            mixer = Mamba2(cfg, in_place=in_place, name="mamba")
+        elif cfg.is_linear(self.index):
             mixer = GatedDeltaNet(cfg, in_place=in_place, name="linear")
-        else:
+        elif kind != EXPERTS:
             mixer = functools.partial(ATTENTION_KINDS[cfg.attention_kind](
                 cfg, attention_fn=self.attention_fn, index=self.index,
                 in_place=in_place, name="attn"), cos=cos, sin=sin)
         if cfg.is_routed(self.index):
             ffn = RoutedExperts(cfg, name="moe")
-        else:
+        elif kind is None:
             ffn = SwiGLU(cfg, name="mlp")
 
         def residual(x, sublayer, norm):
@@ -1406,10 +1724,13 @@ class LlamaLayer(nn.Module):
 
         # Norm and residual add inside each block's scope: XLA fuses them
         # with the neighbouring products (common/scopes.py).
-        with jax.named_scope(_scopes.BLOCK_ATTN):
-            x = residual(x, mixer, "norm_attn")
-        with jax.named_scope(_scopes.BLOCK_FFN):
-            x = residual(x, ffn, "norm_mlp")
+        if mixer is not None:
+            with jax.named_scope(_scopes.BLOCK_ATTN):
+                x = residual(x, mixer, "norm_attn" if kind is None
+                             else "norm")
+        if ffn is not None:
+            with jax.named_scope(_scopes.BLOCK_FFN):
+                x = residual(x, ffn, "norm_mlp" if kind is None else "norm")
         return x
 
 

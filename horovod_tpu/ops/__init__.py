@@ -5,7 +5,9 @@ The kernels are modules of their own, imported where they are used:
 rotation as one Mosaic pass), ``short_conv`` (a linear-attention layer's
 convolution, SiLU and L2 norm as one Mosaic pass each way) and
 ``gated_delta`` (the chunkwise gated delta rule: ``jax.numpy`` but for its
-chunks' triangular systems, solved in one Mosaic call a slab)."""
+chunks' triangular systems, solved in one Mosaic call a slab), ``ssd``
+(Mamba-2's chunked state-space scan, ``jax.numpy``) and ``chunking`` (the
+chunks and slabs the two recurrences share)."""
 
 from horovod_tpu.ops.collective_ops import (
     Average,

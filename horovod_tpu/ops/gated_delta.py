@@ -23,7 +23,7 @@ alpha_i S_{i-1} k_i)`` the value a row really writes::
     S' = e^{gamma_C} S + U^T (e^{gamma_C - gamma} K)
 
 Everything but the last three lines is the same for every chunk and runs
-for a slab of ``_SLAB`` = 8 chunks at once (``_prepare``); those three
+for a slab of 8 chunks at once (``ops/chunking.py``) (``_prepare``); those three
 carry the state from chunk to chunk in float32 (``_walk``, a ``lax.scan``
 over the slab's chunks).  The rule is a ``custom_vjp`` (``_rule``): the
 forward pass keeps q, k, v, the gates and the state each chunk started
@@ -75,12 +75,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
+from horovod_tpu.ops.chunking import chunked as _chunked, slabs as _slabs
 
 __all__ = ["CHUNK", "gated_delta_rule", "gated_delta_states", "solve_counts",
            "calls_in_place", "NOT_IN_PLACE", "NO_TPU"]
 
 CHUNK = 64
-_SLAB = 8              # chunks prepared together, then walked one by one
 _HIGHEST = jax.lax.Precision.HIGHEST
 _LANES = 128           # matrices a grid step of the solve's call works on
 _TILE = 8              # rows of a float32 sublane tile
@@ -352,19 +352,6 @@ def _walk_back(prepared, states, d_o, d_state):
                         reverse=True)
 
 
-def _slabs(x):
-    """``[N, ..] -> [N / n, n, ..]``: the chunks in slabs of n, at most
-    ``_SLAB``.  What ``_prepare`` makes beside its results (a dozen
-    ``[.., C, C]`` float32 arrays, float32 copies of k and v) is made a
-    slab at a time.  Eight chunks: at 30 heads such an array is 4 MB, and
-    on the v5e the rule at 8192 tokens takes 21.9 ms forward and backward
-    where slabs of 32 (16 MB an array, 0.5 GB more of temporaries) take
-    32.3 and all 128 chunks together would hold 1.5 GB; 2 to 8 read alike
-    (PERF.md, PR 38).  The slab batches chunks and changes no bit."""
-    n = math.gcd(x.shape[0], _SLAB)
-    return x.reshape(x.shape[0] // n, n, *x.shape[1:])
-
-
 def _rule_walk(q, k, v, g, beta, mosaic=False):
     """O and the chunks' starting states for chunked inputs, slab by slab:
     a slab is prepared, then walked."""
@@ -411,13 +398,6 @@ def _rule_bwd(mosaic, res, d_o):
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
-
-
-def _chunked(x, chunk):
-    """``[B, S, H, ..] -> [N, B, H, C, ..]``."""
-    batch, seq, heads = x.shape[:3]
-    x = x.reshape(batch, seq // chunk, chunk, heads, *x.shape[3:])
-    return jnp.moveaxis(x, (1, 3), (0, 2))
 
 
 def _chunks(q, k, v, g, beta):

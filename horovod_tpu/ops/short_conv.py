@@ -66,7 +66,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.common import trace_counts as _trace_counts
 
-__all__ = ["convolved", "short_conv", "body_counts", "NOT_IN_PLACE"]
+__all__ = ["convolved", "short_conv", "body_counts", "NOT_IN_PLACE",
+           "HAS_BIAS"]
 
 _LANES = 128
 _TILE = 8         # rows of a float32 sublane tile: the history the body uses
@@ -88,6 +89,7 @@ NOT_IN_PLACE = "the attention_fn does not read its operands in place"
 _NO_ROW_BLOCK = "no block of rows divides the sequence"
 _TOO_MANY_TAPS = "more taps than a sublane tile of history holds"
 _NOT_WHOLE_HEADS = "the channels are not whole heads"
+HAS_BIAS = "the filter has a bias, which the Mosaic pass does not take"
 
 
 def body_counts() -> dict:
@@ -570,27 +572,36 @@ def over_heads(x, heads):
 
 
 @functools.partial(jax.checkpoint, static_argnums=(2, 3))
-def _convolved_plain(y, taps, heads, scale):
+def _convolved_plain(y, taps, heads, scale, bias=None):
     """``convolved`` in ``jnp``: any shape, any partitioning.  Under a
     checkpoint: the backward pass keeps y and makes the float32 values
     between again."""
-    out = jax.nn.silu(_short_convolution(y, taps))
+    out = _short_convolution(y, taps)
+    if bias is not None:
+        out = out + bias
+    out = jax.nn.silu(out)
     if scale is not None:
         squares, spread = over_heads(out * out, heads)
         out = out * spread(scale * jax.lax.rsqrt(squares + 1e-6))
     return out.astype(y.dtype)
 
 
-def convolved(y, taps, heads, scale, in_place: bool):
+def convolved(y, taps, heads, scale, in_place: bool, bias=None):
     """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
     head L2-normed and multiplied by ``scale`` where that is not None.
+    ``bias [heads * d]`` (a Mamba-2 layer's ``use_conv_bias``) is added
+    before the SiLU, in the plain body alone: the Mosaic pass takes none, so
+    that the calls without one lower to what they always did, and a filter
+    with a bias is counted under ``HAS_BIAS``.
     ``in_place`` is the caller's word that this trace may hold Mosaic calls
     on operands where they lie: the chain is then ``short_conv``'s one pass
     forward and one backward, where the shape is one it takes
     (``_why_not``).  Elsewhere ``_convolved_plain``.  Which body a trace
     took, and why, ``body_counts()`` says."""
     why = _why_not(y.shape, taps.shape, heads) if in_place else NOT_IN_PLACE
+    if bias is not None:
+        why = HAS_BIAS
     _trace_counts.note(_BODY, why or _FUSED)
     if why is None:
         return short_conv(y, taps, heads, scale)
-    return _convolved_plain(y, taps, heads, scale)
+    return _convolved_plain(y, taps, heads, scale, bias)

@@ -41,10 +41,11 @@ __all__ = [
 SHARDING_RULES: tuple[tuple[str, tuple[Optional[str], ...]], ...] = (
     (r"tok_emb.*embedding$", ("tensor", "fsdp")),
     (r"(pos_emb|type_emb).*embedding$", (None, "fsdp")),
-    (r"(wq|wk|wv|qkv|mlp_in|w_gate_up|mlm_transform)/kernel$", ("fsdp", "tensor")),
+    (r"(wq|wk|wv|qkv|mlp_in|w_gate_up|w_up|in_proj|mlm_transform)/kernel$", ("fsdp", "tensor")),
     (r"(wo|proj|w_down|mlp_out)/kernel$", ("tensor", "fsdp")),
     (r"(lm_head|mlm_out)/kernel$", ("fsdp", "tensor")),
     (r"moe/w_gate_up$", ("expert", "fsdp", "tensor")),
+    (r"moe/w_up$", ("expert", "fsdp", "tensor")),
     (r"moe/w_down$", ("expert", "tensor", "fsdp")),
     (r"router/kernel$", ("fsdp", None)),
     (r"head/kernel$", ("fsdp", "tensor")),   # resnet classifier

@@ -29,7 +29,8 @@ import pytest  # noqa: E402
 
 # The tiny sizes at which tests/benchmark runs the jobs of the
 # ``deepseek-v2-lite``, ``keye-vl-2.0-30b-a3b``, ``olmo-hybrid-7b``,
-# ``laguna-s-2.1`` and ``qwen3-next-80b-a3b`` configurations on the CPU.  They belong beside
+# ``laguna-s-2.1``, ``qwen3-next-80b-a3b`` and ``nemotron-3-nano-30b-a3b``
+# configurations on the CPU.  They belong beside
 # ``tests/benchmark/tiny_sizes.py``'s, whose table every test of that
 # directory reads by the job's name; the files there are the accepted
 # benchmark's, which a PR that adds a cell may not edit, so the entry is
@@ -180,6 +181,40 @@ TINY.setdefault("hybrid_moe_lm", {
                                         "loss_abs": 0.02,
                                         "grad_rel": 0.2}}},
     "traffic": {"sequence": 256, "batch_per_chip": 2},
+})
+
+TINY.setdefault("ssm_moe_lm", {
+    # Hidden 64; three one-sublayer layers, one of each kind (the suite is
+    # near its time limit): a Mamba-2 layer of 4 heads of 16 with a state of
+    # 32 and 2 groups, chunks of 16; an attention layer of 4 query heads over
+    # 2 key-value heads of 32; a routed layer that holds experts 2 to 5 of
+    # 8, 2 choices a token, relu2 at width 48 beside a shared expert of 96.
+    "config": {"hidden_size": 64, "num_hidden_layers": 3,
+               "hybrid_override_pattern": "M*E",
+               "mamba_num_heads": 4, "mamba_head_dim": 16,
+               "ssm_state_size": 32, "n_groups": 2, "chunk_size": 16,
+               "num_attention_heads": 4, "num_key_value_heads": 2,
+               "head_dim": 32, "intermediate_size": 48,
+               "moe_intermediate_size": 48,
+               "moe_shared_expert_intermediate_size": 96,
+               "vocab_size": 512, "n_routed_experts": 4,
+               "num_experts_per_tok": 2,
+               "deployment": {"num_experts_published": 8,
+                              "first_held_expert": 2,
+                              "num_hidden_layers_published": 3},
+               "assumed": {"d_inner": 64, "aux_loss_alpha": 1e-4,
+                           "bias_update_rate": 1e-3},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up, as ``hybrid_moe_lm``.
+                          "loss_must_fall": False,
+                          # bf16 at these widths; float32 through the same
+                          # code agrees to 1e-4 (tests/test_nemotron_h.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    "traffic": {"sequence": 64, "batch_per_chip": 2},
 })
 
 

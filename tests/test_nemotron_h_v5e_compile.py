@@ -1,0 +1,173 @@
+"""The ``nemotron-3-nano-30b-a3b.train-s8k-b2`` cell's layers compiled for a
+described ``v5e:2x2`` (no chip attached), a LAYER at a time at the cell's
+size, beside ``tests/test_qwen3_next_v5e_compile.py`` and in its manner: the
+flash pair at 32 query heads over 2 key-value heads of 128 without a rotation;
+a routed layer's grouped products (relu2: two matrices an expert); the
+convolution over a Mamba-2 layer's 6,144 channels, whose bias keeps it in the
+plain body while the same shape without one is the Mosaic pass; and a Mamba-2
+layer whole, forward and backward.  The whole step at 2 x 8192 is compiled by
+the builder's study and on the chip, not here (it takes a minute)."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from benchmark import manifest
+from horovod_tpu.common import scopes
+from horovod_tpu.models import llama
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import short_conv
+
+CELL = "nemotron-3-nano-30b-a3b.train-s8k-b2"
+_MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
+B, S = 2, 8192
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as error:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
+
+
+@pytest.fixture
+def one_chip(topo, monkeypatch):
+    """The kernels' non-interpreted bodies, and no persistent cache (a
+    deviceless executable cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for module in (fa, short_conv):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def config():
+    cell = manifest.cell(CELL)
+    job = manifest.load_job(cell["config"]["job"]).build(
+        cell["config"], cell["traffic"], 1)
+    return job.llama
+
+
+def _mosaic_calls(text):
+    return [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
+
+
+def _layer_text(module, one_chip, hidden):
+    """The compiled text of ``module``'s forward and backward pass on
+    ``bf16[2, 8192, hidden]`` with parameters as it initialises them."""
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    x = jax.ShapeDtypeStruct((B, S, hidden), jnp.bfloat16, sharding=one_chip)
+    variables = jax.eval_shape(
+        lambda k: module.init(k, jnp.zeros((1, 8, hidden), jnp.bfloat16)),
+        jax.random.key(0))
+
+    def grads(variables, x):
+        return jax.grad(lambda p, x: jnp.sum(module.apply(
+            {**variables, "params": p}, x).astype(jnp.float32)),
+            argnums=(0, 1))(variables["params"], x)
+
+    return jax.jit(grads).lower(jax.tree.map(sds, variables),
+                                x).compile().as_text()
+
+
+def test_the_flash_pair_compiles_at_32_over_2_heads_of_128(one_chip, config):
+    """Forward and backward through the seam at 2 x 8192 tokens, 32 query
+    heads in groups of 16 over 2 key-value heads of 128, in place, no
+    rotation before it: two Mosaic calls and no ``[S, S]`` array."""
+    assert (config.num_heads, config.num_kv_heads, config.head_dim,
+            config.rope_theta) == (32, 2, 128, None)
+
+    def sds(n):
+        return jax.ShapeDtypeStruct((B, S, n, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *x: jnp.sum(fa.flash_attention_fn(
+            *x).astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+    before = fa.layout_counts()
+    text = jax.jit(grads).lower(sds(32), sds(2), sds(2)).compile().as_text()
+    assert fa.layout_counts()["in_place"] == before["in_place"] + 1
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2
+    assert sum(scopes.FLASH_FWD in c for c in calls) == 1
+    assert sum(scopes.FLASH_BWD in c for c in calls) == 1
+    assert not re.findall(rf"\w+\[(?:\d+,)*{S},{S}\]", text)
+
+
+def test_a_routed_layers_grouped_products(one_chip, config):
+    """8 of 128 relu2 experts at 2 x 8192 tokens, 6 choices a token: 98,304
+    assignments through buffers of 12,288 rows (``_live_buffers``: the first
+    buffer and the loop's body), forward: two ``ragged_dot`` s a buffer where
+    a SwiGLU expert has two as well, over ``w_up [8, 2688, 1856]``, and no
+    gate's split.  LOWERED for the described chip, not compiled: XLA:TPU's
+    own grouped kernels take 23 s to compile, which the suite has not."""
+    module = llama.RoutedExperts(config)
+    variables = jax.eval_shape(
+        lambda k: module.init(k, jnp.zeros((1, 8, 2688), jnp.bfloat16)),
+        jax.random.key(0))
+    x = jax.ShapeDtypeStruct((B, S, 2688), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(module.apply).lower(jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip),
+        variables), x).as_text()
+    lines = [line for line in text.splitlines() if "ragged_dot" in line]
+    assert len(lines) == 4, len(lines)
+    assert "8x2688x1856xbf16" in text and "8x2688x3712xbf16" not in text
+    assert "2688x3712xbf16" in text          # the shared expert's own width
+
+
+@pytest.mark.parametrize("bias", [False, True])
+def test_the_filter_over_6144_channels(one_chip, bias):
+    """Without a bias ``short_conv``'s Mosaic pass takes ``[2, 8192, 6144]``
+    (48 lane tiles, one head, no norm): one call each way.  With one the
+    plain body runs, no Mosaic call, and the count says why."""
+    y = jax.ShapeDtypeStruct((B, S, 6144), jnp.bfloat16, sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((4, 6144), jnp.float32, sharding=one_chip)
+    offset = jax.ShapeDtypeStruct((6144,), jnp.float32, sharding=one_chip)
+
+    def grads(y, taps, offset):
+        return jax.value_and_grad(lambda y, taps, offset: jnp.sum(
+            short_conv.convolved(
+                y, taps, 1, None, True, bias=offset if bias else None
+            ).astype(jnp.float32)), argnums=(0, 1))(y, taps, offset)
+
+    before = short_conv.body_counts()
+    text = jax.jit(grads).lower(y, taps, offset).compile().as_text()
+    after = short_conv.body_counts()
+    if bias:
+        assert after["plain"][short_conv.HAS_BIAS] == before["plain"].get(
+            short_conv.HAS_BIAS, 0) + 1
+        assert not _mosaic_calls(text)
+    else:
+        assert after["fused"] == before["fused"] + 1
+        assert len(_mosaic_calls(text)) == 2
+
+
+def test_a_mamba_layer_compiles_with_no_mosaic_call(one_chip, config):
+    """A ``Mamba2`` layer at 2 x 8192 tokens, forward and backward: all XLA
+    (the scan is ``jax.numpy``, the filter has a bias), its three scopes in
+    the text, and no array of a chunk's ``[128, 128]`` for every chunk at
+    once (a slab of 8 chunks at a time: 2 x 8 x 64 heads)."""
+    text = _layer_text(llama.Mamba2(config, in_place=True), one_chip,
+                       config.hidden_size)
+    assert not _mosaic_calls(text)
+    for scope in (scopes.SSD_CONV, scopes.SSD_GATES, scopes.SSD_SCAN):
+        assert scope in text, scope
+    assert "f32[64,2,8,8,128,128]" not in text
+    assert "f32[8,2,8,8,128,128]" in text
