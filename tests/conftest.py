@@ -10,8 +10,10 @@ before first JAX use so the suite never needs (or takes) a chip.
 import os
 import sys
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 # Make the repo importable when pytest is run from anywhere.
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
 
 N_DEVICES = 8
 
@@ -218,7 +220,63 @@ TINY.setdefault("ssm_moe_lm", {
 })
 
 
+# The files that take over 100 s of the driver's command
+# (``/root/TESTS_LAST_RUN.json``: six workers, ``--dist loadfile``), longest
+# first, with the seconds of each as PR 51 read them (the mean of two runs in
+# a sandbox of eight cores, where a file's seconds differ by a third from run
+# to run; ROADMAP.md D9 says how to read them again).  xdist hands out whole
+# files, and queues them by their number of tests unless it is told not to;
+# the longest files here have the fewest tests, so they started last.  A PR
+# that adds a file of over 100 s adds its row.
+LONGEST_FIRST = (
+    "tests/benchmark/test_benchmark_cells.py",          # 427 s
+    "tests/test_keye_sparse.py",                        # 336 s
+    "tests/benchmark/test_benchmark_reference.py",      # 332 s
+    "tests/test_qwen3_next.py",                         # 287 s
+    "tests/benchmark/test_benchmark_hybrid_moe.py",     # 273 s
+    "tests/test_laguna.py",                             # 271 s
+    "tests/test_flash_walks.py",                        # 235 s
+    "tests/benchmark/test_benchmark_window.py",         # 232 s
+    "tests/test_olmo_hybrid.py",                        # 222 s
+    "tests/test_flash_attention.py",                    # 204 s
+    "tests/benchmark/test_benchmark_hybrid.py",         # 193 s
+    "tests/test_deepseek_moe.py",                       # 182 s
+    "tests/test_serve.py",                              # 181 s
+    "tests/benchmark/test_benchmark_sparse.py",         # 176 s
+    "tests/test_deepseek_model.py",                     # 160 s
+    "tests/test_flash_v5e_compile.py",                  # 157 s
+    "tests/test_looped_llama.py",                       # 146 s
+    "tests/benchmark/test_benchmark_moe.py",            # 131 s
+    "tests/test_nemotron_h.py",                         # 118 s
+    "tests/test_laguna_v5e_compile.py",                 # 116 s
+    "tests/test_olmo_hybrid_v5e_compile.py",            # 108 s
+    "tests/test_keye_sparse_v5e_compile.py",            # 105 s
+    "tests/test_models.py",                             # 100 s
+)
+
+
+def _file(item) -> str:
+    return os.path.relpath(str(item.path), REPO)
+
+
+def longest_first(items: list) -> list:
+    """``items`` with the files of ``LONGEST_FIRST`` ahead in the table's
+    order and every other file behind them as collected; a file's own order
+    is kept (the sort is stable, and its key is the file's alone)."""
+    place = {path: n for n, path in enumerate(LONGEST_FIRST)}
+    return sorted(items, key=lambda item: place.get(_file(item), len(place)))
+
+
+def pytest_collection_modifyitems(config, items):
+    items[:] = longest_first(items)
+
+
 def pytest_configure(config):
+    # The queue of files is the collection's order (``LONGEST_FIRST``): an
+    # xdist that would reorder it by count of tests is told not to.  Without
+    # xdist, or with one that has no such option, there is nothing to set.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers", "slow: long-running tests excluded from the tier-1 gate")
     config.addinivalue_line(

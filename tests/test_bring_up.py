@@ -1,11 +1,14 @@
 """What PR 21 (chip bring-up) left behind that a CPU can check: the chip
 smoke refuses to run without a TPU, the compile cache can be placed from
 outside, state placed on the mesh compiles the step once, and a native
-library is trusted only with a stamp that names its sources."""
+library is trusted only with a stamp that names its sources.  Since PR 51
+also the order in which the suite's files start (``tests/conftest.py::
+LONGEST_FIRST``)."""
 
 import os
 import subprocess
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -156,3 +159,53 @@ def test_native_lib_rebuilt_when_stamp_mismatches(monkeypatch, tmp_path):
     assert native_build.native_lib_path() is None
     assert native_build.ensure_native_lib() == str(lib)
     assert lib.read_text() == "v2\n"
+
+
+# -- the order in which the suite's files start (PR 51) -----------------------
+
+@pytest.fixture
+def harness(request):
+    """``tests/conftest.py`` as pytest loaded it (``tests/benchmark`` has a
+    ``conftest`` of its own, so the module's bare name may be either)."""
+    return request.config.pluginmanager.get_plugin(
+        os.path.join(REPO, "tests", "conftest.py"))
+
+
+def test_every_row_of_longest_first_is_a_file_that_collects(harness):
+    """A file that was renamed or split leaves no dead row: each path of the
+    table is listed once and holds at least one test."""
+    table = harness.LONGEST_FIRST
+    assert table and len(set(table)) == len(table)
+    assert not [path for path in table
+                if not os.path.isfile(os.path.join(REPO, path))]
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "--collect-only", "-q",
+         "-p", "no:cacheprovider", "-p", "no:xdist", *table],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    collected = [line.split("::")[0] for line in proc.stdout.splitlines()
+                 if "::" in line]
+    assert set(collected) == set(table)
+    # The table's order is the order they are queued in.
+    assert list(dict.fromkeys(collected)) == list(table)
+
+
+def test_longest_first_moves_whole_files_and_keeps_their_own_order(harness):
+    first, second = harness.LONGEST_FIRST[0], harness.LONGEST_FIRST[-1]
+
+    def items(path, *names):
+        return [types.SimpleNamespace(path=os.path.join(REPO, path), name=n)
+                for n in names]
+
+    collected = (items("tests/test_zzz.py", "b", "a")
+                 + items(second, "y", "x", "z")
+                 + items("tests/test_aaa.py", "d", "c")
+                 + items(first, "q", "p"))
+    ordered = harness.longest_first(collected)
+    assert [(os.path.relpath(item.path, REPO), item.name)
+            for item in ordered] == (
+        [(first, "q"), (first, "p")] + [(second, n) for n in "yxz"]
+        + [("tests/test_zzz.py", "b"), ("tests/test_zzz.py", "a"),
+           ("tests/test_aaa.py", "d"), ("tests/test_aaa.py", "c")])
+    assert collected[0].name == "b"        # a new list; the old one is as it was

@@ -8,6 +8,7 @@ these tests share."""
 
 import dataclasses
 import math
+import types
 
 import jax
 import jax.numpy as jnp
@@ -91,11 +92,11 @@ def test_latent_attention_forward_and_gradient(attention_fn):
             x, attention_reference_params(attn), REF) * weight)
 
     with jax.default_matmul_precision("highest"):
-        got = module.apply(params, x, cos, sin)
-        want = ref.latent_attention(
-            x, attention_reference_params(params["params"]), REF)
-        grads, grad_x = jax.grad(program, argnums=(0, 1))(params, x)
-        want_grads, want_x = jax.grad(reference, argnums=(0, 1))(
+        got = jax.jit(module.apply)(params, x, cos, sin)
+        want = jax.jit(lambda attn, x: ref.latent_attention(
+            x, attention_reference_params(attn), REF))(params["params"], x)
+        grads, grad_x = jax.jit(jax.grad(program, argnums=(0, 1)))(params, x)
+        want_grads, want_x = jax.jit(jax.grad(reference, argnums=(0, 1)))(
             params["params"], x)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(grad_x, want_x, rtol=2e-4, atol=2e-5)
@@ -171,6 +172,12 @@ def _model_case(dtype=jnp.float32, alpha=0.5, **changes):
     return cfg, model, params, tokens, config, loss_fn
 
 
+def _reference(cfg, params, tokens, config):
+    """The float32 reference's (loss, gradients), as one program."""
+    return jax.jit(lambda p, t: ref.loss_and_grads(p, t, config))(
+        model_reference_params(cfg, params), tokens)
+
+
 def _distance(got, want):
     off = sum(float(jnp.sum(jnp.square(g.astype(jnp.float32) - w)))
               for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)))
@@ -186,9 +193,8 @@ def test_whole_model_loss_and_gradient_are_the_references(remat):
     recomputed (the routed layer's sown loss crosses ``nn.remat``)."""
     cfg, _, params, tokens, config, loss_fn = _model_case(remat=remat)
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
-        want, want_grads = ref.loss_and_grads(
-            model_reference_params(cfg, params), tokens, config)
+        loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params, tokens)
+        want, want_grads = _reference(cfg, params, tokens, config)
     assert float(loss) == pytest.approx(float(want), abs=2e-5)
     assert _distance(model_reference_params(cfg, grads),
                      want_grads) < 2e-4
@@ -213,10 +219,27 @@ DEFECTS = {
 }
 
 
+@pytest.fixture(scope="module")
+def bf16_case():
+    """The bf16 case of the eight tests below, computed once: the model and
+    its parameters (float32 for the reference, bf16 to run), the reference's
+    loss and gradients, and those of the program as it is."""
+    cfg, model, params, tokens, config, loss_fn = _model_case(
+        dtype=jnp.bfloat16, alpha=0.001)
+    want, want_grads = _reference(cfg, params, tokens, config)
+    run_params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
+    loss, grads = jax.value_and_grad(
+        lambda p: loss_fn(p, tokens))(run_params)
+    return types.SimpleNamespace(
+        cfg=cfg, model=model, run_params=run_params, tokens=tokens,
+        loss_fn=loss_fn, want=want, want_grads=want_grads, loss=loss,
+        grads=grads)
+
+
 @pytest.mark.parametrize("defect, least", [
     (None, 0.0), ("an_expert_left_out", 0.055), ("renormalised_gates", 0.055),
     ("shared_experts_left_out", 0.12), ("softmax_scale_without_m2", 0.12)])
-def test_what_the_limits_catch_in_bf16(defect, least):
+def test_what_the_limits_catch_in_bf16(defect, least, bf16_case):
     """bf16 weights and activations against the float32 reference, by the
     benchmark's distance (L2 over all parameters): the model as it is reads
     3.9 % here, inside the tiny cell's 6 %.  The shared experts left out and
@@ -227,51 +250,49 @@ def test_what_the_limits_catch_in_bf16(defect, least):
     ALL parameters moves little when one of them is wrong (in float32, where
     nothing else differs, they read 4.6 % and 4.2 %).  What catches those is
     the share test above and the layer's own counters."""
-    cfg, model, params, tokens, config, loss_fn = _model_case(
-        dtype=jnp.bfloat16, alpha=0.001)
-    reference = model_reference_params(cfg, params)
-    run_params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
-    if defect:
-        change = DEFECTS[defect]
-        if "config" in change:
-            # The same parameters run by a model with the defect.
-            wrong = dataclasses.replace(cfg, **change["config"])
-            model = LlamaModel(wrong, attention_fn=flash_attention_fn)
-        else:
-            run_params = change["params"](cfg, model, run_params)
+    cfg, model, tokens = bf16_case.cfg, bf16_case.model, bf16_case.tokens
+    if defect is None:
+        distance = _distance(model_reference_params(cfg, bf16_case.grads),
+                             bf16_case.want_grads)
+        assert distance < 0.06
+        assert abs(float(bf16_case.loss) - float(bf16_case.want)) < 0.02
+        return
+    # A defect edits dictionaries of its own, not the fixture's.
+    run_params = jax.tree.map(lambda p: p, bf16_case.run_params)
+    change = DEFECTS[defect]
+    if "config" in change:
+        # The same parameters run by a model with the defect.
+        wrong = dataclasses.replace(cfg, **change["config"])
+        model = LlamaModel(wrong, attention_fn=flash_attention_fn)
+    else:
+        run_params = change["params"](cfg, model, run_params)
     shared = {i: run_params["params"][f"layer_{i}"]["moe"]["shared"]
               for i in (1, 2)}
     if defect == "shared_experts_left_out":
         for i in (1, 2):
             run_params["params"][f"layer_{i}"]["moe"].pop("shared")
-    loss, grads = jax.value_and_grad(
-        lambda p: loss_fn(p, tokens, model))(run_params)
+    _, grads = jax.value_and_grad(
+        lambda p: bf16_case.loss_fn(p, tokens, model))(run_params)
     if defect == "shared_experts_left_out":
         for i in (1, 2):
             grads["params"][f"layer_{i}"]["moe"]["shared"] = jax.tree.map(
                 jnp.zeros_like, shared[i])
-    want, want_grads = ref.loss_and_grads(reference, tokens, config)
-    distance = _distance(model_reference_params(cfg, grads), want_grads)
-    if defect is None:
-        assert distance < 0.06 and abs(float(loss) - float(want)) < 0.02
-    else:
-        assert distance > least, (defect, distance)
+    distance = _distance(model_reference_params(cfg, grads),
+                         bf16_case.want_grads)
+    assert distance > least, (defect, distance)
 
 
 @pytest.mark.parametrize("precision, least", [("bfloat16", 0.0),
                                               ("router_bf16", 0.0),
                                               ("float8_e5m2", 0.15)])
 def test_a_precision_below_bf16_is_outside_the_limit(precision, least,
-                                                     monkeypatch):
+                                                     monkeypatch, bf16_case):
     """Matmul inputs rounded to float8 (e5m2, bf16's exponent range and two
     mantissa bits) read several times what bf16 reads; the router's product
     in bf16 (where the configuration states float32) flips choices and
     reads more than the model as it is."""
-    cfg, model, params, tokens, config, loss_fn = _model_case(
-        dtype=jnp.bfloat16, alpha=0.001)
-    reference = model_reference_params(cfg, params)
-    want, want_grads = ref.loss_and_grads(reference, tokens, config)
-    run_params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
+    cfg, tokens, loss_fn = bf16_case.cfg, bf16_case.tokens, bf16_case.loss_fn
+    want_grads, run_params = bf16_case.want_grads, bf16_case.run_params
 
     def rounded(p):
         if precision != "float8_e5m2":
@@ -293,8 +314,8 @@ def test_a_precision_below_bf16_is_outside_the_limit(precision, least,
         lambda p: loss_fn(rounded(p), tokens))(run_params)
     monkeypatch.undo()
     distance = _distance(model_reference_params(cfg, grads), want_grads)
-    _, plain = jax.value_and_grad(lambda p: loss_fn(p, tokens))(run_params)
-    as_it_is = _distance(model_reference_params(cfg, plain), want_grads)
+    as_it_is = _distance(model_reference_params(cfg, bf16_case.grads),
+                         want_grads)
     if precision == "bfloat16":
         assert distance == pytest.approx(as_it_is) and distance < 0.06
     elif precision == "router_bf16":

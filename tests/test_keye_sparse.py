@@ -240,12 +240,18 @@ def _tiny(dtype=jnp.float32, **config_changes):
     return job, config, params, job.make_batch(jax.random.key(4), 1)
 
 
-def _distances(job, config, params, batch):
+def _programs(job, config):
+    """The job's (loss, gradients) and the reference's: a caller that asks
+    about two sets of parameters compiles each once."""
+    return (jax.jit(jax.value_and_grad(job.loss_fn)),
+            jax.jit(lambda p, b: keye_vl2.loss_and_grads(p, b, config)))
+
+
+def _distances(job, config, params, batch, programs=None):
+    program, reference = programs or _programs(job, config)
     with HIGHEST:
-        loss, grads = jax.jit(jax.value_and_grad(job.loss_fn))(params, batch)
-        want_loss, want = jax.jit(
-            lambda p, b: keye_vl2.loss_and_grads(p, b, config))(
-                job.to_reference(params), batch)
+        loss, grads = program(params, batch)
+        want_loss, want = reference(job.to_reference(params), batch)
     off = jax.tree.map(
         lambda g, r: float(jnp.linalg.norm(g.astype(jnp.float32) - r)
                            / (jnp.linalg.norm(r) + 1e-30)),
@@ -281,14 +287,15 @@ def test_program_is_near_the_reference_in_bfloat16():
     at this size; with the selection pinned (an indexer of zeros takes the
     64 lowest positions in both) bf16 alone is left: under a tenth."""
     job, config, params, batch = _tiny(jnp.bfloat16)
-    loss_off, whole, _ = _distances(job, config, params, batch)
+    programs = _programs(job, config)
+    loss_off, whole, _ = _distances(job, config, params, batch, programs)
     assert abs(loss_off) < 0.03 and whole < 0.35, (loss_off, whole)
 
     def zero_the_indexer(path, leaf):
         return leaf * 0 if any("index_w" in str(key) for key in path) else leaf
 
     pinned = jax.tree_util.tree_map_with_path(zero_the_indexer, params)
-    loss_off, whole, _ = _distances(job, config, pinned, batch)
+    loss_off, whole, _ = _distances(job, config, pinned, batch, programs)
     assert abs(loss_off) < 0.02 and whole < 0.1, (loss_off, whole)
 
 

@@ -2,8 +2,10 @@
 (no chip attached), beside ``tests/test_flash_v5e_compile.py`` and in its
 manner: the flash kernel's two calls with a window at the cell's shapes (72
 heads in groups of 9 and 48 in groups of 6 over 8 key-value heads, in
-place), and the cell's whole train step, which fits the chip, walks the
-band in its three sliding layers and holds no ``[.., S, S]`` array."""
+place), and the cell's train step at one layer of each kind, which walks
+the band in its sliding layer and holds no ``[.., S, S]`` array.  That the
+cell's depth fits the chip is the chip's to say (``peak_hbm_gb``, every
+PR)."""
 
 import os
 import re
@@ -22,7 +24,13 @@ from horovod_tpu.ops import rope
 
 CELL = "laguna-s-2.1.train-s8k"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
-HBM = 15.75 * 2 ** 30      # what the compiler has of the chip's 16 GB
+#: The depth the whole step is compiled at, the shortest prefix of the
+#: cell's five layers that holds every kind: a full layer of 48 heads before
+#: a dense MLP, then a sliding one of 72 before routed experts.
+LAYERS = 2
+#: The configuration's lists that state a value a layer.
+A_LAYER = ("layer_types", "mlp_layer_types", "gating_types",
+           "num_attention_heads_per_layer")
 
 
 @pytest.fixture(scope="module")
@@ -78,16 +86,20 @@ def test_the_two_calls_compile_at_the_cells_head_counts(one_chip, heads,
 
 
 def test_the_cells_whole_step_fits_and_walks_the_band(topo, one_chip):
-    """Five layers of the published widths at 1 x 8192 tokens: 11.35 GB of
-    state, and arguments + temporaries under what the compiler has of the
-    chip.  Each layer is two flash calls (the policy keeps the forward
-    call's output, so it is not run again) and the rotation's calls; the
-    three sliding layers' are under ``hvd.attn.window``, the two full
-    layers' are not; the gate is in all five; and no ``[.., S, S]`` array
-    exists anywhere."""
+    """The first two of the cell's five layers at the published widths and
+    1 x 8192 tokens, one of each kind.  Each layer is two flash calls (the
+    policy keeps the forward call's output, so it is not run again) and the
+    rotation's calls; the sliding layer's are under ``hvd.attn.window``,
+    the full layer's are not; the gate is there; and no ``[.., S, S]``
+    array exists anywhere.  That the five layers' 11.35 GB of state and
+    their temporaries fit the chip is no longer summed here: the chip's
+    ``peak_hbm_gb`` in this cell says it in every PR, and
+    ``tests/benchmark/test_benchmark_reference.py::
+    test_whole_step_compiles_for_v5e_and_fits`` compiles a whole step."""
     cell = manifest.cell(CELL)
-    job = manifest.load_job(cell["config"]["job"]).build(
-        cell["config"], cell["traffic"], 1)
+    config = {**cell["config"], "num_hidden_layers": LAYERS,
+              **{key: cell["config"][key][:LAYERS] for key in A_LAYER}}
+    job = manifest.load_job(config["job"]).build(config, cell["traffic"], 1)
     mesh = Mesh([topo.devices[0]], ("data",))
     replicated = NamedSharding(mesh, P())
 
@@ -101,26 +113,29 @@ def test_the_cells_whole_step_fits_and_walks_the_band(topo, one_chip):
     before = fa.layout_counts()
     compiled = step.lower(*described(state), described(batch)).compile()
     after = fa.layout_counts()
-    assert after["in_place"] - before["in_place"] == 5
+    sliding = config["layer_types"].count("sliding_attention")
+    assert (LAYERS, sliding) == (2, 1)
+    assert config["mlp_layer_types"] == ["dense", "sparse"]
+    assert after["in_place"] - before["in_place"] == LAYERS
     assert after["flat"] == before["flat"]
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
     forward = [c for c in calls if scopes.FLASH_FWD in c]
     backward = [c for c in calls if scopes.FLASH_BWD in c]
-    assert len(forward) == len(backward) == 5
-    assert sum(scopes.ATTN_WINDOW in c for c in forward) == 3
-    assert sum(scopes.ATTN_WINDOW in c for c in backward) == 3
+    assert len(forward) == len(backward) == LAYERS
+    assert sum(scopes.ATTN_WINDOW in c for c in forward) == sliding
+    assert sum(scopes.ATTN_WINDOW in c for c in backward) == sliding
     assert not any(scopes.REMATTED in c for c in forward)
     rotations = [c for c in calls if scopes.ROPE in c]
     # q and k forward, again under recomputation, and their cotangents.
-    assert len(rotations) == 6 * 5
-    assert sum(scopes.ATTN_WINDOW in c for c in rotations) == 6 * 3
+    assert len(rotations) == 6 * LAYERS
+    assert sum(scopes.ATTN_WINDOW in c for c in rotations) == 6 * sliding
     assert any(scopes.ATTN_GATE in line for line in text.splitlines())
     seq = job.seq
     assert not re.findall(rf"\w+\[(?:\d+,)*{seq},{seq}\]", text)
     memory = compiled.memory_analysis()
-    assert memory.argument_size_in_bytes == pytest.approx(11.354e9, rel=1e-3)
-    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    print(f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB + "
-          f"temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
-    assert total < HBM
+    print(f"arguments {memory.argument_size_in_bytes} + "
+          f"temporaries {memory.temp_size_in_bytes}")
+    # Read at these two layers (all five: 11.354 GB of arguments).
+    assert memory.argument_size_in_bytes == pytest.approx(5.3673e9, rel=1e-3)
+    assert memory.temp_size_in_bytes <= 2.048e9

@@ -41,7 +41,9 @@ def test_mnist_mlp_forward():
 def test_resnet50_param_count(factory, n_params_expected):
     model = factory(dtype=jnp.float32)
     x = jnp.zeros((1, 224, 224, 3))
-    variables = model.init(jax.random.key(0), x, train=False)
+    # Shapes alone count: nothing is initialised or run.
+    variables = jax.eval_shape(
+        lambda key: model.init(key, x, train=False), jax.random.key(0))
     n = sum(p.size for p in jax.tree.leaves(variables["params"]))
     assert n == n_params_expected
 

@@ -51,8 +51,8 @@ def test_parallel_train_step_runs_and_matches_single_device(mesh):
     opt = optax.sgd(1e-2)
     loss_fn = lm_loss_fn(model)
 
-    # Single-device ground truth.
-    loss0, grads0 = jax.value_and_grad(loss_fn)(params, tokens)
+    # Single-device ground truth (one program, not an operation at a time).
+    loss0, grads0 = jax.jit(jax.value_and_grad(loss_fn))(params, tokens)
     updates0, _ = opt.update(grads0, opt.init(params), params)
     params0 = optax.apply_updates(params, updates0)
 

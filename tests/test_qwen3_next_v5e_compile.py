@@ -4,9 +4,10 @@ and in its manner: the flash kernel's two calls at the cell's heads (16 query
 heads over 2 key-value heads of 256, in place), whose forward call states the
 limit it computes because K and V of 256 lanes pass the compiler's default;
 the forward call at heads of 128, which still states none; and the cell's
-whole train step at 2 x 8192 tokens, which fits the chip with the
-configuration's ``remat`` and holds the gated delta rule's solve as
-``ops/gated_delta.py``'s Mosaic call (PR 47)."""
+train step at 2 x 8192 tokens and one layer of each kind, with the
+configuration's ``remat``, which holds the gated delta rule's solve as
+``ops/gated_delta.py``'s Mosaic call (PR 47).  That the cell's depth fits
+the chip is the chip's to say (``peak_hbm_gb``, every PR)."""
 
 import math
 import os
@@ -29,7 +30,9 @@ from horovod_tpu.ops import short_conv
 CELL = "qwen3-next-80b-a3b.train-s8k-b2"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
 _USED = re.compile(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"')
-HBM = 15.75 * 2 ** 30      # what the compiler has of the chip's 16 GB
+#: The depth the whole step is compiled at: one layer of each of the cell's
+#: two kinds (its four are linear, linear, linear, full).
+LAYERS = 2
 B, S, HEADS, KV_HEADS, D = 2, 8192, 16, 2, 256
 
 
@@ -193,18 +196,29 @@ def test_norm_and_rotation_are_one_call_a_pass_at_heads_of_256(one_chip,
 
 
 def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
-    """Four layers of the published widths at 2 x 8192 tokens: 8.76 GB of
-    state and 4.03 GB of temporaries under ``layer_keep_attention``
-    (``layer`` compiles to the same bytes and runs the flash forward call
+    """One linear and one full layer of the published widths (the cell's
+    period has three linear before the full one) at 2 x 8192 tokens under
+    the configuration's ``layer_keep_attention`` (at the cell's four layers
+    ``layer`` compiled to the same bytes and ran the flash forward call
     twice; ``none`` to 8.54 GB of temporaries, 17.3 GB in all: no room).
     The full layer is two flash calls (the policy keeps the forward call's
-    output) and six rotations; the three linear layers' convolutions are
-    27 Mosaic calls and their chunk systems' solves 9 (PR 47); the grouped
-    products are XLA:TPU's own."""
+    output) and six rotations; a linear layer's convolutions are nine
+    Mosaic calls and its chunk systems' solves three (PR 47); the grouped
+    products are XLA:TPU's own.  That the cell's four layers (8.76 GB of
+    state, 4.03 GB of temporaries) fit the chip is no longer summed here:
+    the chip's ``peak_hbm_gb`` in this cell says it in every PR, and
+    ``tests/benchmark/test_benchmark_reference.py::
+    test_whole_step_compiles_for_v5e_and_fits`` compiles a whole step."""
     cell = manifest.cell(CELL)
     assert cell["config"]["training"]["remat"] == "layer_keep_attention"
-    job = manifest.load_job(cell["config"]["job"]).build(
-        cell["config"], cell["traffic"], 1)
+    # ``full_attention_interval`` is how this configuration states its
+    # pattern: every second layer full gives (linear, full).
+    config = {**cell["config"], "num_hidden_layers": LAYERS,
+              "full_attention_interval": LAYERS}
+    job = manifest.load_job(config["job"]).build(config, cell["traffic"], 1)
+    linear = sum(map(job.llama.is_linear, range(job.llama.num_layers)))
+    full = LAYERS - linear
+    assert (job.llama.num_layers, linear, full) == (LAYERS, 1, 1)
     mesh = Mesh([topo.devices[0]], ("data",))
     replicated = NamedSharding(mesh, P())
 
@@ -221,42 +235,43 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     compiled = step.lower(*described(state), described(batch)).compile()
     after = (fa.layout_counts(), short_conv.body_counts(),
              gated_delta.solve_counts())
-    assert after[2]["mosaic"] - before[2]["mosaic"] == 3
+    assert after[2]["mosaic"] - before[2]["mosaic"] == linear
     assert after[2]["plain"] == before[2]["plain"]
-    assert after[0]["in_place"] - before[0]["in_place"] == 1
+    assert after[0]["in_place"] - before[0]["in_place"] == full
     assert after[0]["flat"] == before[0]["flat"]
-    assert after[1]["fused"] - before[1]["fused"] == 9
+    assert after[1]["fused"] - before[1]["fused"] == 3 * linear
     text = compiled.as_text()
     calls = _mosaic_calls(text)
     forward = [c for c in calls if scopes.FLASH_FWD in c]
     backward = [c for c in calls if scopes.FLASH_BWD in c]
-    assert len(forward) == len(backward) == 1
+    assert len(forward) == len(backward) == full
     assert not any(scopes.REMATTED in c for c in forward)
     # q and k normed and turned by one call each, forward, again, backward
     # (PR 48): no float32 array of their size, heads apart or together.
-    assert sum(scopes.ROPE in c for c in calls) == 6
+    assert sum(scopes.ROPE in c for c in calls) == 6 * full
     assert all(scopes.QK_NORM in c for c in calls if scopes.ROPE in c)
     for gone in ("f32[2,8192,16,256]", "f32[2,8192,2,256]"):
         assert gone not in text, gone
-    assert sum(scopes.GDN_CONV in c for c in calls) == 27
+    assert sum(scopes.GDN_CONV in c for c in calls) == 9 * linear
     # The slabs' systems (512 matrices: four grid steps) by the solve's call,
     # forward, again, and in the backward slab's preparation, a linear
     # layer; each states no limit and takes half the default scoped VMEM.
     solves = [c for c in calls if scopes.GDN_SOLVE in c]
-    assert len(solves) == 9 and all(scopes.GDN_SCAN in c for c in solves)
-    assert sum(scopes.REMATTED in c for c in solves) == 3
+    assert len(solves) == 3 * linear
+    assert all(scopes.GDN_SCAN in c for c in solves)
+    assert sum(scopes.REMATTED in c for c in solves) == linear
     assert "f32[64,64,512]" in solves[0]
     assert max(int(_USED.search(c)[1]) for c in solves) <= 8 * 2 ** 20
     ours = [c for c in calls if scopes.RAGGED_DOT_PREFIX not in c]
-    assert len(ours) == 2 + 6 + 27 + 9
+    assert len(ours) == (2 + 6) * full + (9 + 3) * linear
     assert scopes.RAGGED_DOT_PREFIX in text
     for scope in (scopes.GDN_HEADS, scopes.GDN_SCAN, scopes.GDN_GATES,
                   scopes.ATTN_GATE, scopes.MOE_SHARED, scopes.MOE_ROUTE):
         assert scope in text, scope
     assert not re.findall(rf"\w+\[(?:\d+,)*{S},{S}\]", text)
     memory = compiled.memory_analysis()
-    print(f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB + "
-          f"temporaries {memory.temp_size_in_bytes / 1e9:.3f} GB")
-    assert memory.argument_size_in_bytes == pytest.approx(8.759e9, abs=0.1e9)
-    assert memory.temp_size_in_bytes == pytest.approx(4.027e9, abs=0.1e9)
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes) < HBM
+    print(f"arguments {memory.argument_size_in_bytes} + "
+          f"temporaries {memory.temp_size_in_bytes}")
+    # Read at these two layers (all four: 8.759 GB and 4.027 GB).
+    assert memory.argument_size_in_bytes == pytest.approx(4.879e9, abs=0.1e9)
+    assert memory.temp_size_in_bytes == pytest.approx(3.608e9, abs=0.1e9)
