@@ -15,8 +15,16 @@ Frontends (mirroring ``horovod.tensorflow`` / ``horovod.torch`` /
 SURVEY.md §7 steps 5-6.)
 """
 
-from horovod_tpu import elastic
-from horovod_tpu.common import (
+import time as _time
+
+#: The clock (``time.perf_counter``) at the first line of the package's
+#: import: where the compile log's span ``import horovod_tpu.jax`` begins,
+#: and the origin of every ``began`` the log gives out
+#: (``common/compile_cache.py``).
+IMPORT_BEGAN = _time.perf_counter()
+
+from horovod_tpu import elastic  # noqa: E402
+from horovod_tpu.common import (  # noqa: E402
     epoch,
     fleet_stats,
     init,
@@ -28,7 +36,7 @@ from horovod_tpu.common import (
     shutdown,
     size,
 )
-from horovod_tpu.version import __version__
+from horovod_tpu.version import __version__  # noqa: E402
 
 __all__ = [
     "__version__",

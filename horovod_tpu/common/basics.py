@@ -30,6 +30,8 @@ import os
 import threading
 from typing import Optional, Sequence
 
+from horovod_tpu.common import scopes as _scopes
+
 __all__ = ["HorovodBasics", "basics"]
 
 # Env vars understood for rank discovery, in priority order.  The OMPI/PMI
@@ -231,56 +233,19 @@ class HorovodBasics:
                 # A retried init() after a failure elsewhere finds the JAX
                 # runtime already up — that is fine.
                 if not jax.distributed.is_initialized():
-                    jax.distributed.initialize(
-                        coordinator_address=jaddr,
-                        num_processes=size,
-                        process_id=rank,
-                    )
+                    with _scopes.span(_scopes.INIT_DISTRIBUTED):
+                        jax.distributed.initialize(
+                            coordinator_address=jaddr,
+                            num_processes=size,
+                            process_id=rank,
+                        )
             self._rank = rank
             self._size = size
             self._local_rank = local_rank
             self._local_size = local_size
 
-            self._load_native()
-            if self._lib is not None:
-                if os.environ.get("HOROVOD_AUTOTUNE", "0") not in ("", "0"):
-                    # Warm start for the WIRING-time knobs: the state
-                    # file's probed channels/drivers must land in the env
-                    # before horovod_init wires the rings (explicit user
-                    # env values win inside the helper).
-                    from horovod_tpu.autotune.store import (
-                        apply_wiring_warm_start,
-                    )
-
-                    apply_wiring_warm_start(os.environ)
-                addr = coordinator or os.environ.get("HOROVOD_COORDINATOR", "")
-                ret = self._lib.horovod_init(
-                    self._rank,
-                    self._size,
-                    self._local_rank,
-                    self._local_size,
-                    addr.encode(),
-                )
-                if ret != 0:
-                    try:
-                        detail = self._lib.horovod_last_error().decode()
-                    except Exception:
-                        detail = ""
-                    raise RuntimeError(
-                        f"native horovod_init failed with code {ret}"
-                        + (f": {detail}" if detail else "")
-                    )
-                # Adopt the COMMITTED identity: under elastic membership
-                # (HOROVOD_ELASTIC=1) the coordinator may have re-formed
-                # the world around the survivors — contiguous re-ranked,
-                # smaller (or re-grown) size — so the env-pinned identity
-                # is only the join candidacy, not the final word.  Gated
-                # on the elastic flag: outside it the engine never
-                # reassigns, and the process-wide engine singleton may
-                # predate this (test-local) HorovodBasics instance.
-                if os.environ.get("HOROVOD_ELASTIC", "") not in ("", "0"):
-                    self._rank = int(self._lib.horovod_rank())
-                    self._size = int(self._lib.horovod_size())
+            with _scopes.span(_scopes.INIT_NATIVE):
+                self._start_native(coordinator)
             self._initialized = True
             self._maybe_start_autotuner()
             self._maybe_start_monitor()
@@ -288,6 +253,50 @@ class HorovodBasics:
                 # Reference registers shutdown via atexit (common/__init__.py:69).
                 atexit.register(self.shutdown)
                 self._atexit_registered = True
+
+    def _start_native(self, coordinator: Optional[str]) -> None:
+        """Find (or build) and load the C++ core, and start its engine
+        with this process's identity: the span ``hvd.init.native``."""
+        self._load_native()
+        if self._lib is not None:
+            if os.environ.get("HOROVOD_AUTOTUNE", "0") not in ("", "0"):
+                # Warm start for the WIRING-time knobs: the state
+                # file's probed channels/drivers must land in the env
+                # before horovod_init wires the rings (explicit user
+                # env values win inside the helper).
+                from horovod_tpu.autotune.store import (
+                    apply_wiring_warm_start,
+                )
+
+                apply_wiring_warm_start(os.environ)
+            addr = coordinator or os.environ.get("HOROVOD_COORDINATOR", "")
+            ret = self._lib.horovod_init(
+                self._rank,
+                self._size,
+                self._local_rank,
+                self._local_size,
+                addr.encode(),
+            )
+            if ret != 0:
+                try:
+                    detail = self._lib.horovod_last_error().decode()
+                except Exception:
+                    detail = ""
+                raise RuntimeError(
+                    f"native horovod_init failed with code {ret}"
+                    + (f": {detail}" if detail else "")
+                )
+            # Adopt the COMMITTED identity: under elastic membership
+            # (HOROVOD_ELASTIC=1) the coordinator may have re-formed
+            # the world around the survivors — contiguous re-ranked,
+            # smaller (or re-grown) size — so the env-pinned identity
+            # is only the join candidacy, not the final word.  Gated
+            # on the elastic flag: outside it the engine never
+            # reassigns, and the process-wide engine singleton may
+            # predate this (test-local) HorovodBasics instance.
+            if os.environ.get("HOROVOD_ELASTIC", "") not in ("", "0"):
+                self._rank = int(self._lib.horovod_rank())
+                self._size = int(self._lib.horovod_size())
 
     def _maybe_start_autotuner(self) -> None:
         """Start the online autotuner thread on the coordinator when

@@ -34,18 +34,49 @@ program, and was it tracing, lowering, compiling or reading the cache) and
 "which step recompiled" (a second ``trace`` record of one program).  JAX
 calls a listener only when it compiles, so a step that runs from its
 compiled program costs the log nothing.  Nothing is printed.
+
+The spans
+---------
+JAX's events stop at a program's edge: ``trace`` of ``hvd_train_step``,
+12.8 s, full stop.  What ran INSIDE is the program's own Python, and that
+the log times itself: ``span(name)`` is a context manager that keeps the
+name, the start and the end on the records' clock, the thread, and the
+span that was open on that thread when it began.  The program opens one
+wherever it enters a scope of ``common/scopes.py`` (``scopes.scope``: the
+``jax.named_scope`` and the span under ONE name, so a trace's seconds and
+a step's device time are told by the same words), around every Mosaic
+call's bind (``mosaic.<kernel>``, no ``named_scope``: what tracing the
+kernels' bodies costs a trace), inside ``hvd.init()`` (``hvd.init``,
+``hvd.init.native``, ``hvd.init.distributed``, ``hvd.init.cache``) and,
+from two stamps of the clock, around the package's import (``import
+horovod_tpu.jax`` and its child ``import horovod_tpu.models``).
+``compile_spans()`` gives them out, ``compile_spans(program)`` those that
+lie inside ``program``'s ``trace`` records: the tree under "tracing
+``hvd_train_step`` took 12.8 s".  Spans are recorded from the package's
+import on, whether or not ``enable_compile_log()`` has run (the import and
+``hvd.init`` come before it), kept in memory up to ``MAX_SPANS``, and
+written out by nobody.  A span costs its two clock reads and an append
+when the Python around it RUNS, which under ``jit`` is while JAX traces: a
+step that runs from its compiled program runs none.  While it is open a
+span also holds a ``jax.profiler.TraceAnnotation`` of its name (a flag
+test while no profiler runs), so a profile taken across a recompile shows
+the spans on the host thread's line, on the clock of the device's
+operations.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
+import sys
 import threading
 import time
 from typing import Optional
 
 __all__ = ["default_cache_dir", "enable_compile_cache",
-           "enable_compile_log", "compile_log", "CompileLog"]
+           "enable_compile_log", "compile_log", "compile_spans", "span",
+           "add_span", "CompileLog"]
 
 _ENV = "JAX_COMPILATION_CACHE_DIR"
 
@@ -73,9 +104,10 @@ def enable_compile_cache() -> Optional[str]:
 
 
 class CompileLog:
-    """What JAX reported about its compilations, in the order it came.
+    """What JAX reported about its compilations, in the order it came, and
+    the spans the program opened itself.
 
-    A record is ``{"program", "event", "seconds"}``:
+    A record is ``{"program", "event", "seconds", "began"}``:
 
     ===================  ==================================================
     ``event``            ``seconds``
@@ -100,9 +132,19 @@ class CompileLog:
     backend compilation, whose record follows them, so they take that
     record's program (two threads that compile at once can swap theirs).
     The log keeps its newest ``MAX_RECORDS`` records.
+
+    A span is ``{"name", "path", "began", "seconds", "self_seconds"}`` and
+    whatever flags it was given: ``path`` its ancestors' names and its own
+    joined by ``/`` (the spans that were open on its thread when it began,
+    outermost first), ``self_seconds`` its duration less what its children
+    cover.  ``began``, of a span and of a record, is in seconds from the
+    log's origin (the first line of the package's import), so both order
+    on one axis.  The log keeps the ``MAX_SPANS`` spans that ended last; a
+    span that is still open is in no list.
     """
 
     MAX_RECORDS = 4096
+    MAX_SPANS = 16384
     DURATIONS = {
         "/jax/core/compile/jaxpr_trace_duration": "trace",
         "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
@@ -114,9 +156,12 @@ class CompileLog:
         "/jax/compilation_cache/cache_hits": "cache_hit",
     }
 
-    def __init__(self) -> None:
+    def __init__(self, origin: Optional[float] = None) -> None:
         self._lock = threading.Lock()
         self._records: list = []       # of _Record, by time of arrival
+        self._spans = collections.deque(maxlen=self.MAX_SPANS)  # by end
+        self._open = threading.local()  # .stack: this thread's open spans
+        self._origin = time.perf_counter() if origin is None else origin
         self._listening = False
 
     def listen(self) -> None:
@@ -169,8 +214,50 @@ class CompileLog:
         names = (program, f"jit({program})")
         with self._lock:
             return [{"program": r.program, "event": r.event,
-                     "seconds": r.seconds} for r in self._records
+                     "seconds": r.seconds, "began": r.began - self._origin}
+                    for r in self._records
                     if program is None or r.program in names]
+
+    def span(self, name: str, **flags) -> "_Span":
+        """A context manager that times what runs inside it as the span
+        ``name``, a child of the span open on this thread."""
+        return _Span(self, name, flags)
+
+    def add_span(self, name: str, began: float, ended: float,
+                 parent: Optional["_Span"] = None, **flags) -> "_Span":
+        """Record a span that is over, from two readings of
+        ``time.perf_counter`` (what ran before this module could be
+        imported); ``parent`` is a span this call returned earlier."""
+        span = _Span(self, name, flags)
+        span.thread = threading.get_ident()
+        span.began, span.ended = began, ended
+        if parent is not None:
+            span.path = parent.path + "/" + name
+            parent.covered += ended - began
+        with self._lock:
+            self._spans.append(span)
+        return span
+
+    def spans(self, program: Optional[str] = None) -> list:
+        """The spans that are over as dictionaries (copies), by their
+        start; with ``program``, those that lie inside one of its
+        ``trace`` records on that record's thread: what ran while JAX
+        traced the function of that name."""
+        with self._lock:
+            spans = list(self._spans)
+            traces = None if program is None else [
+                (r.thread, r.began, r.at) for r in self._records
+                if r.event == "trace" and r.program == program]
+        if traces is not None:
+            spans = [s for s in spans if any(
+                s.thread == thread and began <= s.began and s.ended <= at
+                for thread, began, at in traces)]
+        spans.sort(key=lambda s: s.began)
+        return [{**s.flags, "name": s.name, "path": s.path,
+                 "began": s.began - self._origin,
+                 "seconds": s.ended - s.began,
+                 "self_seconds": max(s.ended - s.began - s.covered, 0.0)}
+                for s in spans]
 
 
 @dataclasses.dataclass
@@ -186,7 +273,59 @@ class _Record:
         return self.at - (self.seconds or 0.0)
 
 
-_LOG = CompileLog()
+class _Span:
+    """One span of a :class:`CompileLog`, and the context manager that
+    times it: entered, it is the top of its thread's stack; left, it is in
+    the log and its seconds are in its parent's ``covered``."""
+
+    __slots__ = ("log", "name", "flags", "path", "thread", "began", "ended",
+                 "covered", "parent", "annotation")
+
+    def __init__(self, log: CompileLog, name: str, flags: dict) -> None:
+        self.log, self.name, self.flags = log, name, flags
+        self.path, self.covered, self.parent = name, 0.0, None
+
+    def __enter__(self) -> "_Span":
+        try:
+            stack = self.log._open.stack
+        except AttributeError:
+            stack = self.log._open.stack = []
+        if stack:
+            self.parent = stack[-1]
+            self.path = self.parent.path + "/" + self.name
+        stack.append(self)
+        self.thread = threading.get_ident()
+        # On the profiler's clock too, where JAX is in the process and a
+        # profile is being taken; no JAX is imported for it.
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self.annotation = profiler and profiler.TraceAnnotation(self.name)
+        if self.annotation:
+            self.annotation.__enter__()
+        self.began = time.perf_counter()
+        return self
+
+    def __exit__(self, *error) -> None:
+        self.ended = time.perf_counter()
+        if self.annotation:
+            self.annotation.__exit__(*error)
+        stack = self.log._open.stack
+        if stack[-1] is self:
+            stack.pop()
+        else:                   # left out of order: a suspended generator
+            stack.remove(self)
+        if self.parent is not None:
+            self.parent.covered += self.ended - self.began
+            self.parent = None
+        with self.log._lock:
+            self.log._spans.append(self)
+
+
+# The origin of the process's log is the first line of the package's
+# import (``horovod_tpu/__init__.py`` stamps the clock there, before it
+# imports anything), so the import's own span begins at 0.
+from horovod_tpu import IMPORT_BEGAN  # noqa: E402
+
+_LOG = CompileLog(IMPORT_BEGAN)
 
 
 def enable_compile_log() -> None:
@@ -200,3 +339,23 @@ def compile_log(program: Optional[str] = None) -> list:
     ``hvd.compile_log(hvd.TRAIN_STEP_PROGRAM)`` picks the records of the
     step that ``make_train_step`` builds."""
     return _LOG.records(program)
+
+
+def span(name: str, **flags):
+    """``with span(name):`` times what runs inside it in the process's
+    log: see :class:`CompileLog`."""
+    return _LOG.span(name, **flags)
+
+
+def add_span(name: str, began: float, ended: float, parent=None, **flags):
+    """A finished span, from two ``time.perf_counter`` readings, into the
+    process's log: see :meth:`CompileLog.add_span`."""
+    return _LOG.add_span(name, began, ended, parent, **flags)
+
+
+def compile_spans(program: Optional[str] = None) -> list:
+    """The process's spans: see :class:`CompileLog`.
+    ``hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)`` gives what ran while JAX
+    traced the step that ``make_train_step`` builds, ``hvd.compile_spans()``
+    those and the start-up's (``import horovod_tpu.jax``, ``hvd.init``)."""
+    return _LOG.spans(program)

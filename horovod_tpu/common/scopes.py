@@ -5,7 +5,26 @@ and so do the documents and the benchmark's reader
 (``benchmark/scopes.py``): a name is spelled here and nowhere else.  A scope
 is compile-time metadata (it becomes part of each HLO operation's
 ``op_name``, which the profiler shows for every device operation), so it
-costs nothing when the step runs.  Plain Python, no JAX.
+costs nothing when the step runs.  Plain Python; JAX is imported by
+``scope()`` alone, when it is called.
+
+There is ONE way into a scope: ``with scope(NAME):``.  It enters
+``jax.named_scope(NAME)`` and the compile log's span of the same name
+(``common/compile_cache.py``), so the Python that JAX runs while it TRACES a
+step is timed under the words that tell the step's device time apart:
+``hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)`` is the tree under "tracing
+``hvd_train_step`` took 12.8 s", as a trace's ``op_name``s are the tree
+under "a step took 600 ms".  No ``jax.named_scope(`` stands anywhere else
+in the package.  A span costs two clock reads and an append, at trace time;
+a step that runs from its compiled program enters none.  While it is open a
+span also holds a ``jax.profiler.TraceAnnotation`` of its name, so a profile
+taken across a recompile shows these names on the host thread's line, on the
+clock of the device's operations (``docs/timeline.md``).  The ``MOSAIC_*``
+names (``mosaic.<kernel>``) are spans ALONE, around each Mosaic call's bind
+(``pl.pallas_call(...)(...)``, which traces the kernel's body to a jaxpr,
+once a call site): no ``named_scope``, nothing of them reaches the HLO.
+``INIT*`` and ``IMPORT*`` name the start-up's spans (``hvd.init()`` and its
+parts; the package's import from two stamps of the clock).
 
 An operation's ``op_name`` is a path, ``jit(hvd_train_step)/.../hvd.loss/
 .../mul``.  JAX wraps the components that differentiation goes through:
@@ -251,6 +270,8 @@ walks again.
 
 from __future__ import annotations
 
+import contextlib
+
 __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD", "ROPE",
@@ -262,7 +283,12 @@ __all__ = [
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
-    "TRAIN_STEP_PROGRAM", "allreduce_scope",
+    "TRAIN_STEP_PROGRAM", "allreduce_scope", "scope", "span",
+    "MOSAIC", "MOSAIC_FLASH_FWD", "MOSAIC_FLASH_BWD", "MOSAIC_ROPE",
+    "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
+    "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION",
+    "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
+    "IMPORT", "IMPORT_MODELS",
 ]
 
 LOSS = "hvd.loss"
@@ -305,6 +331,24 @@ FLASH_LSE_NAME = "hvd.flash.lse"
 SPARSE_SELECTED_NAME = "hvd.sparse.selected"
 SPARSE_INDEX_LOSS_NAME = "hvd.sparse.index_loss"
 
+# Spans of the compile log alone (no ``named_scope``): a Mosaic call's bind,
+# by kernel; ``hvd.init()`` and its parts; the package's import.
+MOSAIC = "mosaic."                   # a prefix: every name below starts so
+MOSAIC_FLASH_FWD = MOSAIC + "flash_fwd"
+MOSAIC_FLASH_BWD = MOSAIC + "flash_bwd"
+MOSAIC_ROPE = MOSAIC + "rope"
+MOSAIC_SHORT_CONV = MOSAIC + "short_conv"
+MOSAIC_GDN_SOLVE = MOSAIC + "gdn_solve"
+MOSAIC_SPARSE_SELECT = MOSAIC + "sparse_select"
+MOSAIC_INDEX_LOSS = MOSAIC + "index_loss"
+MOSAIC_PAGED_ATTENTION = MOSAIC + "paged_attention"
+INIT = "hvd.init"
+INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
+INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize
+INIT_CACHE = "hvd.init.cache"        # the compile cache and log switched on
+IMPORT = "import horovod_tpu.jax"
+IMPORT_MODELS = "import horovod_tpu.models"   # inside IMPORT
+
 #: The name JAX reports for the program ``make_train_step`` builds: in its
 #: monitoring events (``hvd.compile_log()``: tracing under this name,
 #: lowering and backend compilation under ``jit(hvd_train_step)``), as the
@@ -317,3 +361,23 @@ def allreduce_scope(axis_name) -> str:
     """``hvd.allreduce.data``; ``hvd.allreduce.data+fsdp`` for a tuple."""
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
     return ALLREDUCE + "." + "+".join(str(a) for a in axes)
+
+
+def span(name: str):
+    """The compile log's span ``name`` ALONE, for the names that must not
+    reach the HLO (``MOSAIC_*``, ``INIT*``)."""
+    from horovod_tpu.common import compile_cache
+
+    return compile_cache.span(name)
+
+
+@contextlib.contextmanager
+def scope(name: str):
+    """Enter ``jax.named_scope(name)`` and the compile log's span ``name``:
+    the one way into a scope of this table (see the module's docstring)."""
+    import jax
+
+    from horovod_tpu.common import compile_cache
+
+    with compile_cache.span(name), jax.named_scope(name):
+        yield

@@ -30,7 +30,13 @@ Typical use::
 from __future__ import annotations
 
 import functools
+import sys
+import time
 from typing import Callable, Optional
+
+# For the import's span (the end of this file): whether the process had
+# JAX before this package asked for it.
+_JAX_WAS_IMPORTED = "jax" in sys.modules
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +56,9 @@ from horovod_tpu.common import init as _init
 from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
 from horovod_tpu.common.compile_cache import (
+    add_span as _add_span,
     compile_log,
+    compile_spans,
     enable_compile_cache,
     enable_compile_log,
 )
@@ -83,7 +91,8 @@ __all__ = [
     "DistributedOptimizer", "allreduce_gradients", "update_counts",
     "broadcast_parameters", "broadcast_optimizer_state",
     "build_mesh", "data_parallel_mesh", "default_mesh", "use_mesh",
-    "make_train_step", "compile_log", "TRAIN_STEP_PROGRAM",
+    "make_train_step", "compile_log", "compile_spans",
+    "TRAIN_STEP_PROGRAM",
 ]
 
 
@@ -92,9 +101,12 @@ def init(*args, **kwargs) -> None:
     # The common init, then the persistent compile cache and the compile
     # log (common/compile_cache.py): this is the frontend whose programs
     # are jitted.  After, because the cache helper asks JAX for its backend.
-    _init(*args, **kwargs)
-    enable_compile_cache()
-    enable_compile_log()
+    # Each part under a span of the log's (``hvd.compile_spans()``).
+    with _scopes.span(_scopes.INIT):
+        _init(*args, **kwargs)
+        with _scopes.span(_scopes.INIT_CACHE):
+            enable_compile_cache()
+            enable_compile_log()
 
 
 def num_chips() -> int:
@@ -560,7 +572,7 @@ class DistributedOptimizer:
                 op=self._op,
                 compression=self._compression,
             )
-        with jax.named_scope(_scopes.OPTIMIZER):
+        with _scopes.scope(_scopes.OPTIMIZER):
             return self._inner.update(grads, state, params, **extra)
 
     # -- ZeRO-1 sharded path (host-driven; see docs/zero.md) --
@@ -893,26 +905,26 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
     import optax
 
     def _sharded_step(params, opt_state, batch):
-        with jax.named_scope(_scopes.LOSS):
+        with _scopes.scope(_scopes.LOSS):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        with jax.named_scope(_scopes.APPLY):
+        with _scopes.scope(_scopes.APPLY):
             params = optax.apply_updates(params, updates)
         loss = _cops.allreduce(loss, axis_name=axes, op=Average)
         return params, opt_state, loss
 
     def _sharded_step_aux(params, opt_state, aux_state, batch):
-        with jax.named_scope(_scopes.LOSS):
+        with _scopes.scope(_scopes.LOSS):
             (loss, aux_state), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, aux_state, batch)
-        with jax.named_scope(_scopes.AUX_ALLREDUCE):
+        with _scopes.scope(_scopes.AUX_ALLREDUCE):
             aux_state = jax.tree.map(
                 lambda x: _cops.allreduce(x, axis_name=axes, op=Average)
                 if _is_inexact(x) else x,
                 aux_state,
             )
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        with jax.named_scope(_scopes.APPLY):
+        with _scopes.scope(_scopes.APPLY):
             params = optax.apply_updates(params, updates)
         loss = _cops.allreduce(loss, axis_name=axes, op=Average)
         return params, opt_state, aux_state, loss
@@ -945,3 +957,16 @@ def _is_inexact(x) -> bool:
     import jax.numpy as jnp
 
     return jnp.issubdtype(jnp.asarray(x).dtype, jnp.inexact)
+
+
+# The import's two spans, from stamps of the clock: the first line of the
+# package's ``__init__`` to this one, the last of this file, and inside it
+# what ``parallel/seq.py`` pulled in (the model zoo, flax, Pallas).  Where
+# the process imported ``horovod_tpu`` earlier for another reason the outer
+# span holds what ran between, too.
+from horovod_tpu import IMPORT_BEGAN as _IMPORT_BEGAN  # noqa: E402
+from horovod_tpu.parallel.seq import MODELS_IMPORTED as _MODELS_IMPORTED  # noqa: E402,E501
+
+_add_span(_scopes.IMPORT_MODELS, *_MODELS_IMPORTED, parent=_add_span(
+    _scopes.IMPORT, _IMPORT_BEGAN, time.perf_counter(),
+    jax_was_imported=_JAX_WAS_IMPORTED))

@@ -870,20 +870,20 @@ class LlamaAttention(nn.Module):
                               scale=scale, eps=cfg.rms_eps)
 
         window = cfg.window_of(self.index)
-        with (jax.named_scope(_scopes.ATTN_WINDOW) if window is not None
+        with (_scopes.scope(_scopes.ATTN_WINDOW) if window is not None
               else contextlib.nullcontext()):
-            with (jax.named_scope(_scopes.QK_NORM) if cfg.qk_norm
+            with (_scopes.scope(_scopes.QK_NORM) if cfg.qk_norm
                   else contextlib.nullcontext()):
                 q = normed_and_turned(q, "q_norm")
                 k = normed_and_turned(k, "k_norm")
             out = self.attend(x, q, k, v, cos, sin)
         out = out.reshape(B, S, heads * D)
         if cfg.gating == "per-head":
-            with jax.named_scope(_scopes.ATTN_GATE):
+            with _scopes.scope(_scopes.ATTN_GATE):
                 out = _gated_heads(out, nn.Dense(
                     heads, use_bias=False, dtype=cfg.dtype, name="wg")(x))
         elif cfg.gating == "elementwise":
-            with jax.named_scope(_scopes.ATTN_GATE):
+            with _scopes.scope(_scopes.ATTN_GATE):
                 out = _gated_lanes(out, jnp.dot(
                     x_q, wq[:, :, 1].reshape(-1, heads * D)))
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
@@ -955,7 +955,7 @@ class SparseAttention(LlamaAttention):
         B, S, _ = x.shape
         n, d = cfg.index_heads, cfg.index_head_dim
         scale = (n * d) ** -0.5
-        with jax.named_scope(_scopes.SPARSE_INDEX):
+        with _scopes.scope(_scopes.SPARSE_INDEX):
             u = jax.lax.stop_gradient(x)
             half = (cos[:, :d // 2], sin[:, :d // 2])
             q_i = apply_rope(nn.Dense(
@@ -1051,7 +1051,7 @@ class LatentAttention(nn.Module):
         q = jnp.concatenate(
             [q[..., :d_n], apply_rope(q[..., d_n:], cos, sin,
                                       in_place=self.in_place)], axis=-1)
-        with jax.named_scope(_scopes.MLA_LATENT):
+        with _scopes.scope(_scopes.MLA_LATENT):
             latent = dense(rank + d_r, "wkv_a")(x)
             c_kv = RMSNorm(cfg.rms_eps, cfg.dtype,
                            name="kv_norm")(latent[..., :rank])
@@ -1183,7 +1183,7 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
     cell's step a second of tracing at every start (PERF.md §6, PR 43).
     Inlined, the operations keep the names and scopes they have without
     it."""
-    with jax.named_scope(_scopes.MOE_ROUTE):
+    with _scopes.scope(_scopes.MOE_ROUTE):
         assignments = jax.lax.dynamic_slice(order, (first,), (chunk,))
         position = inverse - first
         # An expert's rows that fall into this buffer.
@@ -1195,7 +1195,7 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
         # product leaves there is undefined, so it is cut off at
         # both ends (here for the gradient that comes back).
         rows = jnp.where(live, rows, 0)
-    with jax.named_scope(_scopes.MOE_EXPERTS):
+    with _scopes.scope(_scopes.MOE_EXPERTS):
         if act == "relu2":
             rows = _relu2(jax.lax.ragged_dot(rows, w_gu, sizes))
         else:
@@ -1203,7 +1203,7 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
                 jax.lax.ragged_dot(rows, w_gu, sizes), 2, axis=-1)
             rows = nn.silu(gate) * up
         rows = jax.lax.ragged_dot(rows, w_down, sizes)
-    with jax.named_scope(_scopes.MOE_COMBINE):
+    with _scopes.scope(_scopes.MOE_COMBINE):
         rows = jnp.where(live, rows, 0)
         return _weighted_rows_to_tokens(rows, weights, assignments,
                                         position, k)
@@ -1347,7 +1347,7 @@ class RoutedExperts(nn.Module):
                             (held, F, H)).astype(cfg.dtype)
         corrected = cfg.topk_method == "noaux_tc"
 
-        with jax.named_scope(_scopes.MOE_ROUTE):
+        with _scopes.scope(_scopes.MOE_ROUTE):
             router = nn.Dense(E, use_bias=False, dtype=jnp.float32,
                               name="router")(x.astype(jnp.float32))
             if cfg.scoring_func == "sigmoid":
@@ -1407,7 +1407,7 @@ class RoutedExperts(nn.Module):
         y = y.astype(cfg.dtype).reshape(B, S, H)
 
         if cfg.shared_experts:
-            with jax.named_scope(_scopes.MOE_SHARED):
+            with _scopes.scope(_scopes.MOE_SHARED):
                 width = (cfg.moe_shared_expert_intermediate_size
                          or cfg.shared_experts * F)
                 shared = (Relu2MLP if act == "relu2" else SwiGLU)(
@@ -1530,11 +1530,11 @@ class GatedDeltaNet(nn.Module):
         q, k, v = dense(h_k * d_k, "wq"), dense(h_k * d_k, "wk"), dense(
             h_v * d_v, "wv")
         z = dense(h_v * d_v, "wg")
-        with jax.named_scope(_scopes.GDN_CONV):
+        with _scopes.scope(_scopes.GDN_CONV):
             q = conv(q, "conv_q", h_k, d_k ** -0.5).reshape(B, S, h_k, d_k)
             k = conv(k, "conv_k", h_k, 1.0).reshape(B, S, h_k, d_k)
             v = conv(v, "conv_v", h_v, None).reshape(B, S, h_v, d_v)
-        with jax.named_scope(_scopes.GDN_GATES):
+        with _scopes.scope(_scopes.GDN_GATES):
             x32 = x.astype(jnp.float32)
             a = nn.Dense(h_v, use_bias=False, dtype=jnp.float32,
                          name="wa")(x32)
@@ -1548,9 +1548,9 @@ class GatedDeltaNet(nn.Module):
         if h_v != h_k:
             # Value head j reads key head j // (h_v / h_k): copied, for a
             # rule that takes as many of each.
-            with jax.named_scope(_scopes.GDN_HEADS):
+            with _scopes.scope(_scopes.GDN_HEADS):
                 q, k = (jnp.repeat(t, h_v // h_k, axis=2) for t in (q, k))
-        with jax.named_scope(_scopes.GDN_SCAN), calls_in_place(self.in_place):
+        with _scopes.scope(_scopes.GDN_SCAN), calls_in_place(self.in_place):
             o = gated_delta_rule(q, k, v, g, beta)
         if (self.is_mutable_collection("gdn_stats")
                 and not self.is_initializing()):
@@ -1563,7 +1563,7 @@ class GatedDeltaNet(nn.Module):
                         q, k, v, g, beta)))),
                     ("out_max", jnp.max(jnp.abs(o.astype(jnp.float32))))):
                 self.sow("gdn_stats", name, value)
-        with jax.named_scope(_scopes.GDN_GATES):
+        with _scopes.scope(_scopes.GDN_GATES):
             o = _gated_norm(o.reshape(z.shape), z, self.param(
                 "o_norm", nn.initializers.ones, (d_v,)), h_v, cfg.rms_eps)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
@@ -1631,7 +1631,7 @@ class Mamba2(nn.Module):
         z = projected[..., :inner]
         xbc = projected[..., inner:2 * inner + 2 * bc]
         dt = projected[..., 2 * inner + 2 * bc:]
-        with jax.named_scope(_scopes.SSD_CONV):
+        with _scopes.scope(_scopes.SSD_CONV):
             xbc = convolved(
                 xbc, self.param("conv_w", _conv_taps_init,
                                 (cfg.conv_kernel, inner + 2 * bc)),
@@ -1642,12 +1642,12 @@ class Mamba2(nn.Module):
             b = xbc[..., inner:inner + bc].reshape(B, S, groups, state)
             c = xbc[..., inner + bc:].reshape(B, S, groups, state)
         a_log = self.param("a_log", _mamba_a_log_init, (heads,))
-        with jax.named_scope(_scopes.SSD_GATES):
+        with _scopes.scope(_scopes.SSD_GATES):
             dt = jax.nn.softplus(dt.astype(jnp.float32) + self.param(
                 "dt_bias", _dt_bias_init, (heads,)))
-        with jax.named_scope(_scopes.SSD_SCAN):
+        with _scopes.scope(_scopes.SSD_SCAN):
             y = ssd_scan(u, dt, a_log, b, c, chunk=cfg.chunk_size)
-        with jax.named_scope(_scopes.SSD_GATES):
+        with _scopes.scope(_scopes.SSD_GATES):
             d = self.param("d", nn.initializers.ones, (heads,))
             y = (y.astype(jnp.float32) + d[:, None] * u.astype(jnp.float32)
                  ).astype(u.dtype)
@@ -1662,7 +1662,7 @@ class Mamba2(nn.Module):
                         u, dt, a_log, b, c, chunk=cfg.chunk_size)))),
                     ("out_max", jnp.max(jnp.abs(y.astype(jnp.float32))))):
                 self.sow("ssd_stats", name, value)
-        with jax.named_scope(_scopes.SSD_GATES):
+        with _scopes.scope(_scopes.SSD_GATES):
             y = _gate_then_norm(y.reshape(B, S, inner), z, self.param(
                 "norm", nn.initializers.ones, (inner,)), groups, cfg.rms_eps)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
@@ -1725,11 +1725,11 @@ class LlamaLayer(nn.Module):
         # Norm and residual add inside each block's scope: XLA fuses them
         # with the neighbouring products (common/scopes.py).
         if mixer is not None:
-            with jax.named_scope(_scopes.BLOCK_ATTN):
+            with _scopes.scope(_scopes.BLOCK_ATTN):
                 x = residual(x, mixer, "norm_attn" if kind is None
                              else "norm")
         if ffn is not None:
-            with jax.named_scope(_scopes.BLOCK_FFN):
+            with _scopes.scope(_scopes.BLOCK_FFN):
                 x = residual(x, ffn, "norm_mlp" if kind is None else "norm")
         return x
 
@@ -1797,12 +1797,12 @@ class LlamaModel(nn.Module):
 
         if cfg.total_ut_steps == 1:
             x = one_pass(self, x)
-            with jax.named_scope(_scopes.HEAD):
+            with _scopes.scope(_scopes.HEAD):
                 x = norm_f(self, x)
             return self.head(x)
 
         def norm_and_gate(mdl, x):
-            with jax.named_scope(_scopes.HEAD):
+            with _scopes.scope(_scopes.HEAD):
                 x = norm_f(mdl, x)
                 gate = nn.Dense(1, dtype=jnp.float32, name="exit_gate",
                                 kernel_init=nn.initializers.zeros,
@@ -1816,7 +1816,7 @@ class LlamaModel(nn.Module):
 
         def pass_and_exit(mdl, x, _):
             x = one_pass(mdl, x)
-            with jax.named_scope(_scopes.LOOP_EXIT):
+            with _scopes.scope(_scopes.LOOP_EXIT):
                 x, gate = norm_and_gate(mdl, x)
             return x, (x, gate)
 
@@ -1825,7 +1825,7 @@ class LlamaModel(nn.Module):
         # fuses the optimizer's update into ONE pass's weight-gradient
         # matmuls and holds their operands until every other pass has
         # contributed: 4.9 GB at 8192 tokens x 2048 (PERF.md, PR 26).
-        with jax.named_scope(_scopes.LOOP_PASS):
+        with _scopes.scope(_scopes.LOOP_PASS):
             _, (hidden, gate_logits) = nn.scan(
                 pass_and_exit, variable_broadcast="params",
                 split_rngs={"params": False},
@@ -1839,6 +1839,6 @@ class LlamaModel(nn.Module):
         """Normalised hidden states ``[..., H]`` -> logits ``[..., V]``:
         the one output head, which every exit shares."""
         cfg = self.config
-        with jax.named_scope(_scopes.HEAD):
+        with _scopes.scope(_scopes.HEAD):
             return nn.Dense(cfg.vocab_size, use_bias=False,
                             dtype=cfg.logits_dtype, name="lm_head")(hidden)

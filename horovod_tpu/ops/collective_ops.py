@@ -78,7 +78,7 @@ def axis_size(axis_name) -> int:
 
 
 def _reduce(tensor: jax.Array, axis_name, op: ReduceOp) -> jax.Array:
-    with jax.named_scope(_scopes.allreduce_scope(axis_name)):
+    with _scopes.scope(_scopes.allreduce_scope(axis_name)):
         if op is ReduceOp.SUM:
             return lax.psum(tensor, axis_name)
         if op is ReduceOp.AVERAGE:

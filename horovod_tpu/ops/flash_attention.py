@@ -540,7 +540,8 @@ def _fwd(q, k, v, causal, sm_scale, bias=None, seg=None, mask=None,
         interpret=_interpret(),
         **params,
     )
-    with jax.named_scope(_scopes.FLASH_FWD):
+    with (_scopes.scope(_scopes.FLASH_FWD),
+          _scopes.span(_scopes.MOSAIC_FLASH_FWD)):
         out, lse = call(q.reshape(_rows(q.shape, heads)),
                         k.reshape(_rows(k.shape, kv_heads)),
                         v.reshape(_rows(v.shape, kv_heads)), *arrays)
@@ -794,7 +795,8 @@ def _bwd_impl(causal, sm_scale, res, do, bias=None, seg=None, g_lse=None,
                 masked=mask is not None)),
         interpret=_interpret(),
     )
-    with jax.named_scope(_scopes.FLASH_BWD):
+    with (_scopes.scope(_scopes.FLASH_BWD),
+          _scopes.span(_scopes.MOSAIC_FLASH_BWD)):
         dq, dk, dv = (x.reshape(rows, s, -1) for x in call(
             q, k, v, do, lse, delta, *bias_inputs))
     if group > 1:

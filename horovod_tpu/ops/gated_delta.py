@@ -224,7 +224,7 @@ def _solve(a, interpret):
     count = math.prod(a.shape[:-2])
     lanes = jnp.transpose(a.reshape(count, chunk, chunk), (1, 2, 0))
     block = pl.BlockSpec((chunk, chunk, _LANES), lambda m: (0, 0, m))
-    t = pl.pallas_call(
+    call = pl.pallas_call(
         _solve_kernel,
         grid=(pl.cdiv(count, _LANES),),
         in_specs=[block],
@@ -233,7 +233,9 @@ def _solve(a, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(lanes)
+    )
+    with _scopes.span(_scopes.MOSAIC_GDN_SOLVE):
+        t = call(lanes)
     return jnp.transpose(t, (2, 0, 1)).reshape(a.shape)
 
 
@@ -242,7 +244,7 @@ def _tril_inverse(a, mosaic=False):
     """``(I + a)^-1`` for ``a [.., C, C]`` float32, zero on and above the
     diagonal, C a power of two; by ``_solve``'s call where ``mosaic``, else
     by the six merges."""
-    with jax.named_scope(_scopes.GDN_SOLVE):
+    with _scopes.scope(_scopes.GDN_SOLVE):
         if mosaic:
             return _solve(a, interpret=_interpret())
         return _tril_inverse_impl(a)
@@ -254,7 +256,7 @@ def _tril_inverse_fwd(a, mosaic):
 
 
 def _tril_inverse_bwd(mosaic, t, dt):
-    with jax.named_scope(_scopes.GDN_SOLVE):
+    with _scopes.scope(_scopes.GDN_SOLVE):
         tt = jnp.swapaxes(t, -1, -2)
         da = -jnp.matmul(tt, jnp.matmul(dt, tt, precision=_HIGHEST),
                          precision=_HIGHEST)

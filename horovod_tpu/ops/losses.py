@@ -113,7 +113,7 @@ def softmax_cross_entropy(logits, targets, *, where=None,
         raise ValueError(f"unknown reduction {reduction!r}")
     # Reverse-mode only: the custom_vjp that keeps the residuals bf16
     # forfeits forward-mode AD (jax.jvp/jax.hessian over this op raise).
-    with jax.named_scope(_scopes.HEAD):
+    with _scopes.scope(_scopes.HEAD):
         nll = _nll(logits, targets)
         if where is not None:
             nll = jnp.where(where, nll, 0.0)
@@ -147,7 +147,7 @@ def _weighted_exit_nll(head, consts, hidden, weights, targets):
     not differentiated."""
     def exit_nll(h):
         logits = head(h, *consts)
-        with jax.named_scope(_scopes.HEAD):
+        with _scopes.scope(_scopes.HEAD):
             return _nll_impl(logits, targets)[0]
 
     return jnp.sum(weights * jax.lax.map(exit_nll, hidden))
@@ -171,11 +171,11 @@ def _weighted_exit_nll_fwd(head, consts, hidden, weights, targets):
         # the pullback's products carry it from there: the scope is
         # entered beside them, never around them.
         logits, pullback = jax.vjp(lambda h, c: head(h, *c), h, consts)
-        with jax.named_scope(_scopes.HEAD):
+        with _scopes.scope(_scopes.HEAD):
             nll, lse = _nll_impl(logits, targets)
             d_logits, _ = _nll_bwd((logits, targets, lse), w)
         dh, d_consts = pullback(d_logits)
-        with jax.named_scope(_scopes.HEAD):
+        with _scopes.scope(_scopes.HEAD):
             head_grads = jax.tree.map(jnp.add, head_grads, d_consts)
         return head_grads, (dh, nll)
 
@@ -220,7 +220,7 @@ def expected_exit_loss(head, hidden, gate_logits, targets, *,
     walk left.  The gate's gradient flows through ``w`` and the entropy by
     plain autodiff.  Distribution, losses and entropy in float32.
     """
-    with jax.named_scope(_scopes.LOOP_EXIT):
+    with _scopes.scope(_scopes.LOOP_EXIT):
         log_p = exit_log_distribution(gate_logits)
         p = jnp.exp(log_p)
         # What ``head`` closes over (the parameters being differentiated)

@@ -67,6 +67,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.common import scopes as _scopes
+
 __all__ = ["paged_attention_decode"]
 
 _NEG_INF = -1e30  # matches ops/flash_attention.py (never -inf on TPU)
@@ -238,13 +240,15 @@ def _decode_pallas(q, pool_k, pool_v, tables, pos):
             pltpu.VMEM((Hkv, G, D), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=_interpret(),
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32),
-      q.reshape(B, Hq, D), pool_k, pool_v)
+    )
+    with _scopes.span(_scopes.MOSAIC_PAGED_ATTENTION):
+        out = call(tables.astype(jnp.int32), pos.astype(jnp.int32),
+                   q.reshape(B, Hq, D), pool_k, pool_v)
     return out.reshape(B, 1, Hq, D)
 
 

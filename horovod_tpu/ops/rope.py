@@ -160,7 +160,7 @@ def _rotate(x, cos, sin, interpret):
     d = 2 * cos.shape[-1]
     grid, block, table = _specs(b, s, width, d)
     cos, sin = _tables(cos, sin, s, d)
-    with jax.named_scope(_scopes.ROPE):
+    with _scopes.scope(_scopes.ROPE), _scopes.span(_scopes.MOSAIC_ROPE):
         return pl.pallas_call(
             _kernel,
             grid=grid,
@@ -287,7 +287,7 @@ def _norm_rotate(x, scale, cos, sin, eps, interpret):
     grid, block, table = _specs(b, s, width, d, x.dtype.itemsize)
     cos, sin = _tables(cos, sin, s, d)
     scale = scale.astype(jnp.float32).reshape(1, d)
-    with jax.named_scope(_scopes.ROPE):
+    with _scopes.scope(_scopes.ROPE), _scopes.span(_scopes.MOSAIC_ROPE):
         return pl.pallas_call(
             functools.partial(_norm_kernel, eps=eps),
             grid=grid,
@@ -307,8 +307,8 @@ def _norm_rotate_bwd(g, x, scale, cos, sin, eps, interpret):
     d = 2 * cos.shape[-1]
     grid, block, table = _specs(b, s, width, d, x.dtype.itemsize)
     cos, sin = _tables(cos, -sin, s, d)
-    with jax.named_scope(_scopes.ROPE):
-        dx, ds = pl.pallas_call(
+    with _scopes.scope(_scopes.ROPE):
+        call = pl.pallas_call(
             functools.partial(_norm_bwd_kernel, eps=eps),
             grid=grid,
             in_specs=[block, block, pl.BlockSpec((1, d), lambda i, j: (0, 0)),
@@ -319,7 +319,10 @@ def _norm_rotate_bwd(g, x, scale, cos, sin, eps, interpret):
                        jax.ShapeDtypeStruct(grid + (8, d), jnp.float32)],
             compiler_params=_PARALLEL,
             interpret=interpret,
-        )(g, x, scale.astype(jnp.float32).reshape(1, d), cos, sin)
+        )
+        with _scopes.span(_scopes.MOSAIC_ROPE):
+            dx, ds = call(g, x, scale.astype(jnp.float32).reshape(1, d),
+                          cos, sin)
         return dx, ds.sum(axis=(0, 1, 2)).astype(scale.dtype)
 
 

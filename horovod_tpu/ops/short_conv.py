@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
 
 __all__ = ["convolved", "short_conv", "body_counts", "NOT_IN_PLACE",
@@ -457,7 +458,7 @@ def _forward(y, taps, scale, heads, interpret):
     scratch = [] if heads is None else [
         pltpu.VMEM((rows, width), jnp.float32),
         pltpu.VMEM((3, rows, width), jnp.bfloat16)]
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_fwd_kernel, heads=heads),
         grid=(b, s // rows),
         in_specs=specs,
@@ -468,7 +469,9 @@ def _forward(y, taps, scale, heads, interpret):
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(*operands)
+    )
+    with _scopes.span(_scopes.MOSAIC_SHORT_CONV):
+        return call(*operands)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "interpret"))
@@ -484,7 +487,7 @@ def _backward(y, taps, g, scale, heads, interpret):
         pltpu.VMEM((6, n, width), jnp.bfloat16),
         pltpu.VMEM((n, width), jnp.float32),
         pltpu.VMEM((n, width), jnp.float32)]
-    dy, dtaps = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_kernel, heads=heads),
         grid=(b, s // rows),
         in_specs=specs,
@@ -497,7 +500,9 @@ def _backward(y, taps, g, scale, heads, interpret):
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(*operands)
+    )
+    with _scopes.span(_scopes.MOSAIC_SHORT_CONV):
+        dy, dtaps = call(*operands)
     dtaps = dtaps.reshape(b, k, _TILE, width).sum(axis=(0, 2))
     return dy, dtaps.astype(taps.dtype)
 

@@ -231,7 +231,8 @@ def _select_call(q_i, k_i, w, topk, scale):
             vmem_limit_bytes=_select_vmem_limit(s, bq, bk, n, d)),
         interpret=_flash._interpret(),
     )
-    with jax.named_scope(_scopes.SPARSE_SELECT):
+    with (_scopes.scope(_scopes.SPARSE_SELECT),
+          _scopes.span(_scopes.MOSAIC_SPARSE_SELECT)):
         mask, stats = call(q_i, k_i, w)
     return mask, stats[:, 0, :], stats[:, 1, :].astype(jnp.int32)
 
@@ -272,7 +273,7 @@ def select_keys(q_i, k_i, w, topk: int, *, scale: float, dense=None):
     if dense is None:
         dense = q_i.shape[1] % 128 != 0
     if dense:
-        with jax.named_scope(_scopes.SPARSE_SELECT):
+        with _scopes.scope(_scopes.SPARSE_SELECT):
             out = _select_dense(q_i, k_i, w, topk, scale)
     else:
         out = _select_call(q_i.transpose(0, 2, 1, 3), k_i,
@@ -427,7 +428,8 @@ def _loss_call(q, k, lse, q_i, k_i, w, mask, lse_i, sm_scale, scale):
                                               bq, bk)),
         interpret=_flash._interpret(),
     )
-    kl, dq_i, dw, dk_parts = call(q, k, lse, q_i, k_i, w, mask, lse_i)
+    with _scopes.span(_scopes.MOSAIC_INDEX_LOSS):
+        kl, dq_i, dw, dk_parts = call(q, k, lse, q_i, k_i, w, mask, lse_i)
     return kl[..., 0], dq_i, dw, jnp.sum(dk_parts, axis=1)
 
 
@@ -499,7 +501,7 @@ def index_loss(q, k, lse, q_i, k_i, w, selected, lse_i, *, sm_scale: float,
     q, k, lse, lse_i = map(jax.lax.stop_gradient, (q, k, lse, lse_i))
     if dense is None:
         dense = q.shape[1] % 128 != 0
-    with jax.named_scope(_scopes.SPARSE_INDEX):
+    with _scopes.scope(_scopes.SPARSE_INDEX):
         if dense:
             return _loss_dense(q, k, lse, q_i, k_i, w, selected, sm_scale,
                                scale)

@@ -12,6 +12,7 @@ parallelism); TPU-native new work.
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import jax
@@ -19,9 +20,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from horovod_tpu.models.llama import LlamaConfig, LlamaModel
-from horovod_tpu.ops.losses import softmax_cross_entropy
-from horovod_tpu.parallel.ring_attention import make_ring_attention_fn
+# This import is where ``import horovod_tpu.jax`` first asks for the model
+# zoo (and with it flax and Pallas); the two readings of the clock around it
+# become the compile log's span ``import horovod_tpu.models``
+# (``horovod_tpu/jax/__init__.py``, its last lines).
+_BEGAN = time.perf_counter()
+from horovod_tpu.models.llama import LlamaConfig, LlamaModel  # noqa: E402
+MODELS_IMPORTED = (_BEGAN, time.perf_counter())
+from horovod_tpu.ops.losses import softmax_cross_entropy  # noqa: E402
+from horovod_tpu.parallel.ring_attention import make_ring_attention_fn  # noqa: E402,E501
 
 __all__ = ["make_context_parallel_train_step"]
 

@@ -3,8 +3,11 @@
 on four virtual devices, and the compile log behind ``hvd.compile_log()``.
 No test here reads a clock."""
 
+import contextlib
+import functools
 import os
 import re
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -155,12 +158,12 @@ def test_flash_calls_have_their_scopes():
     assert {n for n in names if prefix in n} == set(forward) | set(backward)
 
 
-def _loss_rope(params, batch):
+def _loss_rope(params, batch, heads=2):
     from horovod_tpu.models.llama import apply_rope, rope_freqs
 
     x, _ = batch                                  # a shard's rows
     q = (x @ params["w"]).reshape(1, x.shape[0], 1, 16)
-    q = jnp.tile(q, (1, 128 // x.shape[0], 2, 8))  # S = 128, H = 2, D = 128
+    q = jnp.tile(q, (1, 128 // x.shape[0], heads, 8))  # S = 128, D = 128
     q = apply_rope(q, *rope_freqs(128, 128, 1e4), in_place=True)
     return jnp.mean(flash_attention(q, q, q, causal=True)) + _loss(params,
                                                                    batch)
@@ -205,8 +208,14 @@ def test_each_scope_name_is_spelled_in_one_place():
             with open(path) as f:
                 text = f.read()
             assert not spelled.search(text), path
-            scoped += re.findall(r"named_scope\(\s*_?scopes\.(\w+)", text)
-    # Every name of the table has a named_scope somewhere in the program.
+            # ``scopes.scope`` is the one way into a scope: the table's own
+            # file holds the one ``jax.named_scope(`` of the package.
+            assert "named_scope(" not in text, path
+            scoped += re.findall(r"_?scopes\.scope\(\s*_?scopes\.(\w+)",
+                                 text)
+    with open(table) as f:
+        assert f.read().count("jax.named_scope(name):") == 1
+    # Every name of the table is entered somewhere in the program.
     used = {getattr(scopes, attribute, None) for attribute in scoped}
     assert scopes.allreduce_scope("data").startswith(scopes.ALLREDUCE)
     assert set(TABLE) - {scopes.ALLREDUCE} <= used
@@ -286,7 +295,11 @@ def test_compile_log_gives_cache_events_their_program():
     _report(log, BACKEND, 0.3, fun_name="jit(step)")
     log._on_event("/jax/some/other/event")
     _report(log, "/jax/some/other/duration", 1.0, fun_name="x")
-    assert log.records("step") == [
+    records = log.records("step")
+    # ``began``: seconds from the log's origin, on the spans' axis.
+    began = [r.pop("began") for r in records]
+    assert all(b > -0.3 for b in began) and began[-1] == min(began)
+    assert records == [
         {"program": "jit(step)", "event": "cache_request", "seconds": None},
         {"program": "jit(step)", "event": "cache_hit", "seconds": None},
         {"program": "jit(step)", "event": "cache_retrieval",
@@ -304,3 +317,221 @@ def test_compile_log_is_bounded_and_gives_copies():
     assert records[-1]["program"] == f"jit(f{log.MAX_RECORDS + 9})"
     records[-1]["program"] = "changed"
     assert log.records()[-1]["program"] != "changed"
+
+
+# -- the spans ---------------------------------------------------------------
+
+def _by_path(spans) -> dict:
+    out = {}
+    for span in spans:
+        out.setdefault(span["path"], []).append(span)
+    return out
+
+
+def test_spans_nest_and_a_parent_covers_its_children():
+    log = compile_cache.CompileLog()
+    with log.span("outer", why="a flag"):
+        with log.span("a"):
+            with log.span("leaf"):
+                pass
+        with log.span("a"):
+            pass
+        assert [s["name"] for s in log.spans()] == ["a", "leaf", "a"]
+    spans = log.spans()
+    assert [s["path"] for s in spans] == [
+        "outer", "outer/a", "outer/a/leaf", "outer/a"]
+    assert spans[0]["why"] == "a flag" and "why" not in spans[1]
+    outer, first, leaf, second = spans
+    for parent, children in ((outer, [first, second]), (first, [leaf]),
+                             (leaf, []), (second, [])):
+        assert parent["self_seconds"] >= 0 and parent["seconds"] > 0
+        assert parent["self_seconds"] + sum(
+            c["seconds"] for c in children) == pytest.approx(
+                parent["seconds"], abs=1e-9)
+        for child in children:
+            assert parent["began"] <= child["began"]
+            assert (child["began"] + child["seconds"]
+                    <= parent["began"] + parent["seconds"] + 1e-9)
+    # By their start, on the axis of the records' ``began``.
+    assert [s["began"] for s in spans] == sorted(s["began"] for s in spans)
+    with log.span("later"):
+        pass
+    assert log.spans()[-1]["path"] == "later"
+
+
+def test_a_span_that_raises_is_closed_and_leaves_the_stack():
+    log = compile_cache.CompileLog()
+    with pytest.raises(KeyError):
+        with log.span("outer"):
+            with log.span("inner"):
+                raise KeyError("x")
+    with log.span("next"):
+        pass
+    assert [s["path"] for s in log.spans()] == [
+        "outer", "outer/inner", "next"]
+
+
+def test_a_finished_span_goes_in_from_two_stamps_and_can_be_a_parent():
+    log = compile_cache.CompileLog(origin=100.0)
+    whole = log.add_span("import", 100.0, 104.0, jax_was_imported=False)
+    log.add_span("models", 101.0, 103.5, parent=whole)
+    assert log.spans() == [
+        {"name": "import", "path": "import", "began": 0.0, "seconds": 4.0,
+         "self_seconds": 1.5, "jax_was_imported": False},
+        {"name": "models", "path": "import/models", "began": 1.0,
+         "seconds": 2.5, "self_seconds": 2.5}]
+
+
+def test_two_threads_keep_two_stacks():
+    log = compile_cache.CompileLog()
+    inside, leave = threading.Event(), threading.Event()
+
+    def other():
+        with log.span("theirs"):
+            inside.set()
+            leave.wait(30)
+            with log.span("their_child"):
+                pass
+
+    thread = threading.Thread(target=other)
+    with log.span("mine"):
+        thread.start()
+        assert inside.wait(30)
+        with log.span("my_child"):
+            pass
+        leave.set()
+        thread.join(30)
+    assert sorted(s["path"] for s in log.spans()) == [
+        "mine", "mine/my_child", "theirs", "theirs/their_child"]
+
+
+def test_the_spans_are_bounded_and_given_out_as_copies(monkeypatch):
+    monkeypatch.setattr(compile_cache.CompileLog, "MAX_SPANS", 64)
+    log = compile_cache.CompileLog()
+    for i in range(64 + 10):
+        with log.span(f"s{i}"):
+            pass
+    spans = log.spans()
+    assert len(spans) == 64 and spans[-1]["name"] == "s73"
+    spans[-1]["name"] = "changed"
+    del spans[:10]
+    assert len(log.spans()) == 64 and log.spans()[-1]["name"] == "s73"
+
+
+def _lowered(loss, has_aux=False, stats=()):
+    """The tiny step traced and lowered, never compiled: a new step an
+    entry, so JAX's cache of traces holds none of them."""
+    opt = hvd.DistributedOptimizer(optax.sgd(1e-2))
+    step = hvd.make_train_step(loss, opt, _mesh(), has_aux=has_aux)
+    params = _params()
+    return step.lower(params, opt.init(params), *stats, _batch())
+
+
+def test_the_steps_spans_are_the_steps_and_not_another_programs():
+    def make_state(x):
+        with scopes.scope(scopes.HEAD):
+            return x + 1
+
+    was = len(hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM))
+    assert hvd.compile_spans("make_state") == []
+    jax.jit(make_state).lower(jnp.zeros(()))
+    state, = hvd.compile_spans("make_state")
+    assert state["path"] == state["name"] == scopes.HEAD
+    assert len(hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)) == was
+
+    _lowered(_loss)
+    new = hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)[was:]
+    assert new[0]["path"] == scopes.LOSS
+    assert {s["path"] for s in new} == {
+        scopes.LOSS, scopes.allreduce_scope("data"), scopes.OPTIMIZER,
+        scopes.APPLY}
+    assert hvd.compile_spans("make_state") == [state]
+    # Inside the step's ``trace`` record, on the one axis.
+    trace = [r for r in hvd.compile_log(hvd.TRAIN_STEP_PROGRAM)
+             if r["event"] == "trace"][-1]
+    for span in new:
+        assert trace["began"] <= span["began"]
+        assert (span["began"] + span["seconds"]
+                <= trace["began"] + trace["seconds"])
+    assert state in hvd.compile_spans()
+    assert all(span in hvd.compile_spans() for span in new)
+
+
+def test_every_name_the_tiny_steps_enter_is_a_span():
+    """The rotation and the flash pair under ``hvd.loss``, forward where
+    the loss calls them and backward at its top (differentiation replays
+    jaxprs, and runs the backward rules' Python); each Mosaic call's bind
+    inside its scope's span."""
+    was = len(hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM))
+    # The rotation is an inlined ``jit`` that JAX traces once a shape and
+    # process: at a shape no other test rotates, its Python runs here.
+    _lowered(functools.partial(_loss_rope, heads=4))
+    stats = {"mean": jnp.zeros(()), "steps": jnp.zeros((), jnp.int32)}
+    _lowered(_loss_aux, has_aux=True, stats=(stats,))
+    spans = hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)[was:]
+    names = {s["name"] for s in spans}
+    assert set(TABLE) - {scopes.ALLREDUCE} <= names
+    assert scopes.allreduce_scope("data") in names
+    paths = _by_path(spans)
+    for scope, bind in ((scopes.ROPE, scopes.MOSAIC_ROPE),
+                        (scopes.FLASH_FWD, scopes.MOSAIC_FLASH_FWD),
+                        (scopes.FLASH_BWD, scopes.MOSAIC_FLASH_BWD)):
+        assert paths[f"{scopes.LOSS}/{scope}/{bind}"], sorted(paths)
+        assert bind.startswith(scopes.MOSAIC)
+    assert paths[f"{scopes.AUX_ALLREDUCE}/{scopes.allreduce_scope('data')}"]
+    assert {s["name"] for s in spans if s["name"].startswith(
+        scopes.MOSAIC)} == {scopes.MOSAIC_ROPE, scopes.MOSAIC_FLASH_FWD,
+                            scopes.MOSAIC_FLASH_BWD}
+
+
+def test_a_second_call_of_a_traced_step_adds_no_span():
+    mesh = _mesh()
+    opt = hvd.DistributedOptimizer(optax.sgd(1e-2))
+    step = hvd.make_train_step(_loss, opt, mesh)
+    replicated = NamedSharding(mesh, P())
+    params = jax.device_put(_params(), replicated)
+    state = jax.device_put(opt.init(params), replicated)
+    batch = jax.device_put(_batch(), NamedSharding(mesh, P("data")))
+    params, state, _ = step(params, state, batch)
+    spans = hvd.compile_spans()
+    assert spans[-1]["name"] == scopes.allreduce_scope("data")
+    params, state, loss = step(params, state, batch)
+    assert step._cache_size() == 1 and np.isfinite(float(loss))
+    assert hvd.compile_spans() == spans
+
+
+def test_the_lowered_step_is_the_same_without_the_logs_span(monkeypatch):
+    """``scopes.scope`` changes no ``op_name``, and a ``mosaic.*`` span
+    reaches no HLO: with the log's span a no-op the text is the same."""
+    texts, counts = [], []
+    for span in (compile_cache.span,
+                 lambda name, **flags: contextlib.nullcontext()):
+        monkeypatch.setattr(compile_cache, "span", span)
+        was = len(hvd.compile_spans())
+        # With the locations, which hold the op_names; from one line of
+        # this file, which they hold too.
+        texts.append(_lowered(_loss_rope).as_text(debug_info=True))
+        counts.append(len(hvd.compile_spans()) - was)
+    assert scopes.ROPE in texts[0] and scopes.MOSAIC not in texts[0]
+    assert texts[0] == texts[1]
+    assert counts[0] > 0 and counts[1] == 0
+
+
+def test_init_and_the_import_have_their_spans():
+    hvd.shutdown()
+    hvd.init()
+    spans = hvd.compile_spans()
+    whole, models = (next(s for s in spans if s["name"] == name)
+                     for name in (scopes.IMPORT, scopes.IMPORT_MODELS))
+    assert whole["began"] == 0.0 and whole["jax_was_imported"] is True
+    assert models["path"] == f"{scopes.IMPORT}/{scopes.IMPORT_MODELS}"
+    assert 0 <= models["seconds"] <= whole["seconds"]
+    assert whole["self_seconds"] == pytest.approx(
+        whole["seconds"] - models["seconds"])
+    init = [s for s in spans if s["name"] == scopes.INIT][-1]
+    parts = [s for s in spans if s["began"] >= init["began"]
+             and s["path"].startswith(scopes.INIT + "/")]
+    assert [s["name"] for s in parts] == [scopes.INIT_NATIVE,
+                                          scopes.INIT_CACHE]
+    assert sum(s["seconds"] for s in parts) <= init["seconds"]
+    assert "compile_spans" in hvd.__all__
