@@ -164,9 +164,10 @@ scope                 what falls under it
                       walks outward to it and counts the solve there
 ``hvd.ssd.conv``      a Mamba-2 layer's (``models/llama.py::Mamba2``) causal
                       depthwise convolution over its x, B and C channels,
-                      the filter's bias and the SiLU: the ``jnp`` body
-                      (``ops/short_conv.py::_convolved_plain``; its Mosaic
-                      pass takes no bias), forward, recomputed and backward
+                      the filter's bias and the SiLU:
+                      ``ops/short_conv.py``'s Mosaic calls where the
+                      model's ``attention_fn`` reads its operands in place,
+                      else its ``jnp`` body; forward, recomputed and backward
 ``hvd.ssd.gates``     the same layer's elementwise work around the scan:
                       ``softplus(dt + dt_bias)``; behind the scan the skip
                       ``D u``, the gate ``silu(z)`` and the grouped RMSNorm

@@ -1629,15 +1629,15 @@ class Mamba2(nn.Module):
         projected = nn.Dense(2 * inner + 2 * bc + heads, use_bias=False,
                              dtype=cfg.dtype, name="in_proj")(x)
         z = projected[..., :inner]
-        xbc = projected[..., inner:2 * inner + 2 * bc]
         dt = projected[..., 2 * inner + 2 * bc:]
         with _scopes.scope(_scopes.SSD_CONV):
+            # (The filter reads its channels where ``in_proj`` left them.)
             xbc = convolved(
-                xbc, self.param("conv_w", _conv_taps_init,
-                                (cfg.conv_kernel, inner + 2 * bc)),
+                projected, self.param("conv_w", _conv_taps_init,
+                                      (cfg.conv_kernel, inner + 2 * bc)),
                 1, None, self.in_place,
                 bias=self.param("conv_b", nn.initializers.zeros,
-                                (inner + 2 * bc,)))
+                                (inner + 2 * bc,)), first=inner)
             u = xbc[..., :inner].reshape(B, S, heads, width)
             b = xbc[..., inner:inner + bc].reshape(B, S, groups, state)
             c = xbc[..., inner + bc:].reshape(B, S, groups, state)

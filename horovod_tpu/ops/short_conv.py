@@ -1,10 +1,12 @@
-"""A linear-attention layer's short convolution, its SiLU and its heads' L2
-norm as one Mosaic pass forward and one backward.
+"""A linear-attention or state-space layer's short convolution, its bias,
+its SiLU and its heads' L2 norm as one Mosaic pass forward and one backward.
 
 ``models/llama.py::GatedDeltaNet`` sends q, k and v through a causal
-depthwise convolution of a few taps, a SiLU and (q and k) a per-head L2 norm:
-elementwise work on a row and the rows just before it (``convolved``, this
-module's one entry).  Written in ``jnp`` (``_convolved_plain``, which stays
+depthwise convolution of a few taps, a SiLU and (q and k) a per-head L2 norm;
+``Mamba2`` sends its x, B and C channels through one such convolution with a
+BIAS a channel, and no norm: elementwise work on a row and the rows just
+before it (``convolved``, this module's one entry).  Written in ``jnp``
+(``_convolved_plain``, which stays
 for every path that may hold no Mosaic call, and as the tests' yardstick)
 XLA:TPU runs it as chains of float32 fusions that write and read ``[B, S, channels]`` float32 arrays
 between them: 51 ms of a 594 ms step at 8192 x 11,520 channels, where the
@@ -18,8 +20,27 @@ block takes the whole last axis).
 of rows of y, all channels, and the sublane tile of rows just before it (the
 same operand a second time; zero before position 0 of every batch row).  In
 VMEM, in float32: K shifted multiply-adds (the shift is a sublane rotate of
-the block with its history in front), SiLU, each head's sum of squares,
-``rsqrt(. + 1e-6)``, the scale, one cast, one store.
+the block with its history in front), the bias, SiLU, each head's sum of
+squares, ``rsqrt(. + 1e-6)``, the scale, one cast, one store.
+
+**A bias or none.**  Whether the filter has a bias is what the trace sees
+(``bias is None``): with one it is one more whole operand, ``[1, C]``
+float32 beside the taps, added where ``c`` is formed (``_conv``), and its
+gradient eight rows more in the taps' output block.  Without one the two
+calls have no operand for it and no row for its gradient: they lower to
+what they lowered to before the pass took a bias (a linear layer runs nine
+such calls a step).
+
+**Channels where they lie.**  ``Mamba2``'s x, B and C are a run of channels
+in the middle of ``in_proj``'s output.  Cut out for the call they are a
+copy a pass and, as the backward call's residual, 201 MB beside the
+projection through the scan's backward pass (``peak_hbm_gb`` 12.707 ->
+12.876 in the nemotron cell: my chip runs, PR 53).  ``first`` names the
+channel they start at instead: y's three operands are then windows of the
+wider array placed by element (``pl.Element``; a lane tile's multiple), the
+residual is the projection itself, and the cotangent goes back padded with
+zeros, which XLA joins with the other channels' as it did.  ``first is
+None`` builds the blocked specs of every call before it.
 
 **A head's sum.**  Heads are runs of d lanes that straddle the 128-lane
 tiles, so a head's sum and its way back to the head's lanes are products
@@ -30,7 +51,8 @@ product with the 0/1 matrix is exact in the float32 accumulator, and the
 three are added.  Three passes where ``highest`` takes six.
 
 **Backward.**  The same walk.  It reads y with a tile of rows before AND
-after the block and the cotangent g with a tile after, makes ``c = conv(y)``,
+after the block and the cotangent g with a tile after, makes ``c = conv(y)
++ bias``,
 ``s = silu(c)`` and the norm again for the block's rows and the K - 1 behind
 them, and with ``u = s n`` (n the head's ``rsqrt``)::
 
@@ -38,13 +60,14 @@ them, and with ``u = s n`` (n the head's ``rsqrt``)::
     dc = ds sigma(c) (1 + c (1 - sigma(c)))
     dy[t] = sum_i taps[i] dc[t + K - 1 - i]          nothing from beyond the row's end
     dtaps[i] = sum_t dc[t] y[t - (K - 1) + i]
+    dbias = sum_t dc[t]                              the block's own rows alone
 
 The taps' gradient is a ``[K, 8, channels]`` float32 output whose block stays
 put while the grid walks a batch row's blocks (eight partial sums a tap: the
-adds stay on the VPU; XLA adds the eight and the batch rows).  No float32
-array of the activations' shape is written to HBM in either call, and the
-residuals are y and the taps: what ``jax.checkpoint`` kept for the plain
-body.
+adds stay on the VPU; XLA adds the eight and the batch rows); the bias's is
+a ``[K + 1]``-th such group in the same block.  No float32 array of the
+activations' shape is written to HBM in either call, and the residuals are
+y, the taps and the bias: what ``jax.checkpoint`` kept for the plain body.
 
 Which body a trace took is counted (``body_counts``), as
 ``ops/flash_attention.py::layout_counts`` counts layouts.  A Mosaic call is
@@ -67,8 +90,7 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
 
-__all__ = ["convolved", "short_conv", "body_counts", "NOT_IN_PLACE",
-           "HAS_BIAS"]
+__all__ = ["convolved", "short_conv", "body_counts", "NOT_IN_PLACE"]
 
 _LANES = 128
 _TILE = 8         # rows of a float32 sublane tile: the history the body uses
@@ -90,7 +112,7 @@ NOT_IN_PLACE = "the attention_fn does not read its operands in place"
 _NO_ROW_BLOCK = "no block of rows divides the sequence"
 _TOO_MANY_TAPS = "more taps than a sublane tile of history holds"
 _NOT_WHOLE_HEADS = "the channels are not whole heads"
-HAS_BIAS = "the filter has a bias, which the Mosaic pass does not take"
+_NOT_AT_A_TILE = "the channels are no run from a lane tile on in the array"
 
 
 def body_counts() -> dict:
@@ -114,11 +136,17 @@ def _pick_rows(s: int, width: int) -> int:
     return 0
 
 
-def _why_not(shape, taps_shape, heads: int):
+def _why_not(shape, taps_shape, heads: int, first=None):
     """None where ``short_conv`` takes ``y`` of ``shape [B, S, channels]``
-    with ``taps_shape [K, channels]``, else the reason it does not."""
-    if len(shape) != 3 or shape[2] % heads or taps_shape[1] != shape[2]:
+    with ``taps_shape [K, channels]`` (or, with ``first``, the channels
+    ``first : first + taps_shape[1]`` of a wider y), else the reason it
+    does not."""
+    width = taps_shape[1]
+    if len(shape) != 3 or width % heads or (
+            first is None and width != shape[2]):
         return _NOT_WHOLE_HEADS
+    if first is not None and (first % _LANES or first + width > shape[2]):
+        return _NOT_AT_A_TILE
     if taps_shape[0] - 1 > _TILE:
         return _TOO_MANY_TAPS
     if not _pick_rows(shape[1], shape[2]):
@@ -176,13 +204,22 @@ def _shifted(prev, cur, k):
     return [cur] + [pltpu.roll(ext, back, 0)[_TILE:] for back in range(1, k)]
 
 
-def _conv(seen, taps_ref, lanes):
-    """The causal convolution from ``_shifted``'s list."""
+def _conv(seen, taps_ref, bias_ref, lanes):
+    """The causal convolution from ``_shifted``'s list, and the filter's
+    bias where it has one (``bias_ref [1, C]``, else None)."""
     k = taps_ref.shape[0]
     c = seen[0] * taps_ref[k - 1:k, lanes]
     for back in range(1, k):
         c = c + seen[back] * taps_ref[k - 1 - back:k - back, lanes]
+    if bias_ref is not None:
+        c = c + bias_ref[:, lanes]
     return c
+
+
+def _bias_first(rest, biased: bool):
+    """A body's references behind the taps: the bias's (None where the
+    filter has none), and the others."""
+    return (rest[0], rest[1:]) if biased else (None, rest)
 
 
 def _pieces(x):
@@ -249,9 +286,10 @@ def _for_groups(n_groups: int, group) -> None:
     jax.lax.fori_loop(0, n_groups, step, 0)
 
 
-def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads):
+def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads, biased):
     # y_ref, o_ref: [rows, C]; before_ref: the _HALO rows before the block;
-    # taps_ref: [K, C] float32.  With a norm (heads is not None): scale_ref
+    # taps_ref: [K, C] float32; with a bias (biased) bias_ref [1, C] float32
+    # behind it.  With a norm (heads is not None): scale_ref
     # [1, 1] in SMEM (an operand, so that q's call and k's are one program),
     # to_head_ref [C, Kp] and to_lanes_ref [Kp, C], the heads' 0/1 indicator
     # (_indicators) and its transpose; scratch s_ref [rows, C] float32 and
@@ -259,6 +297,7 @@ def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads):
     rows, width = y_ref.shape
     k = taps_ref.shape[0]
     normed = heads is not None
+    bias_ref, rest = _bias_first(rest, biased)
     if normed:
         scale_ref, to_head_ref, to_lanes_ref, o_ref, s_ref, p_ref = rest
     else:
@@ -269,7 +308,7 @@ def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads):
         def group(j):
             prev = _before(y_ref, before_ref, j, lanes, at_start)
             cur = _rows_of(y_ref, j, lanes)
-            c = _conv(_shifted(prev, cur, k), taps_ref, lanes)
+            c = _conv(_shifted(prev, cur, k), taps_ref, bias_ref, lanes)
             s = c * _sigmoid(c)
             here = _group(j, rows)
             if not normed:
@@ -290,17 +329,19 @@ def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads):
 
 
 def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
-                *rest, heads):
+                *rest, heads, biased):
     # As _fwd_kernel, with the _HALO rows after the block of y and of the
     # cotangent g; dy_ref: [rows, C]; dtaps_ref: [K * _TILE, C] float32,
     # eight partial sums a tap, the same block for every step of a batch
-    # row.  With a norm, scratch over the block's rows AND the group after
+    # row; with a bias eight rows more behind them, its gradient's partial
+    # sums.  With a norm, scratch over the block's rows AND the group after
     # them: p_ref [6, n, C] bf16 (the pieces of s s and of g s), norm_ref and
     # back_ref [n, C] float32 (the two sums on their way back).
     rows, width = y_ref.shape
     k = taps_ref.shape[0]
     groups = rows // _GROUP    # and then the rows after
     normed = heads is not None
+    bias_ref, rest = _bias_first(rest, biased)
     if normed:
         (scale_ref, to_head_ref, to_lanes_ref, dy_ref, dtaps_ref,
          p_ref, norm_ref, back_ref) = rest
@@ -332,7 +373,7 @@ def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
         def chunk_sums(lanes, _):
             def sums(j):
                 cur, prev, g = operands(j, lanes)
-                c = _conv(_shifted(prev, cur, k), taps_ref, lanes)
+                c = _conv(_shifted(prev, cur, k), taps_ref, bias_ref, lanes)
                 s = c * _sigmoid(c)
                 here = _group(j, rows)
                 for piece, part in enumerate(_pieces(s * s) + _pieces(g * s)):
@@ -352,7 +393,7 @@ def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
         def dc_of(j):
             cur, prev, g = operands(j, lanes)
             seen = _shifted(prev, cur, k)
-            c = _conv(seen, taps_ref, lanes)
+            c = _conv(seen, taps_ref, bias_ref, lanes)
             sig = _sigmoid(c)
             if normed:
                 here = _group(j, rows)
@@ -374,19 +415,24 @@ def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
                 dy = dy + pltpu.roll(ext, ext.shape[0] - ahead, 0)[
                     :dc.shape[0]] * taps_ref[k - 1 - ahead:k - ahead, lanes]
             dy_ref[_group(j, rows), lanes] = dy.astype(dy_ref.dtype)
-            # dtaps[K-1-b] = sum_t dc[t] y[t - b], eight partial sums a tap.
+            # dtaps[K-1-b] = sum_t dc[t] y[t - b], eight partial sums a tap;
+            # behind the taps' (zip stops there) dbias = sum_t dc[t].
             sums = tuple(
                 acc + (dc * y_back).reshape(-1, _TILE, size).sum(axis=0)
-                for acc, y_back in zip(sums, seen))
+                for acc, y_back in zip(sums, seen)) + tuple(
+                acc + dc.reshape(-1, _TILE, size).sum(axis=0)
+                for acc in sums[k:])
             return dc[:_TILE], sums
 
         carry = (dc_of(_AFTER)[0][:_TILE],
-                 (jnp.zeros((_TILE, size), jnp.float32),) * k)
+                 (jnp.zeros((_TILE, size), jnp.float32),) * (k + biased))
         _, sums = jax.lax.fori_loop(
             0, groups, lambda t, carry: group(groups - 1 - t, carry), carry)
-        for back, acc in enumerate(sums):
+        for back, acc in enumerate(sums[:k]):
             at = (k - 1 - back) * _TILE
             dtaps_ref[at:at + _TILE, lanes] += acc
+        for acc in sums[k:]:
+            dtaps_ref[k * _TILE:, lanes] += acc
 
     _each_chunk(width, _BWD_LANES, chunk)
 
@@ -415,26 +461,45 @@ def _indicators(width: int, heads: int):
     return to_head, to_head.T
 
 
-def _specs(rows: int, width: int, n_blocks: int):
+def _specs(rows: int, width: int, n_blocks: int, first=None):
     """A block of rows, and the ``_HALO`` rows before and after it (the
-    sequence's first and last where there are none: the body masks them)."""
+    sequence's first and last where there are none: the body masks them).
+    With ``first`` the array is wider than the filter: the three are
+    windows of it from that channel on, placed by element."""
     per = rows // _HALO
+
+    def before_at(i):
+        return jnp.maximum(i * per - 1, 0)
+
+    def after_at(i):
+        return jnp.minimum((i + 1) * per, n_blocks * per - 1)
+
+    if first is not None:
+        def window(n, at):
+            return pl.BlockSpec((None, pl.Element(n), pl.Element(width)),
+                                lambda b, i: (b, at(i) * _HALO, first))
+
+        return (window(rows, lambda i: i * per), window(_HALO, before_at),
+                window(_HALO, after_at))
     block = pl.BlockSpec((None, rows, width), lambda b, i: (b, i, 0))
-    before = pl.BlockSpec((None, _HALO, width), lambda b, i: (
-        b, jnp.maximum(i * per - 1, 0), 0))
-    after = pl.BlockSpec((None, _HALO, width), lambda b, i: (
-        b, jnp.minimum((i + 1) * per, n_blocks * per - 1), 0))
+    before = pl.BlockSpec((None, _HALO, width),
+                          lambda b, i: (b, before_at(i), 0))
+    after = pl.BlockSpec((None, _HALO, width),
+                         lambda b, i: (b, after_at(i), 0))
     return block, before, after
 
 
-def _with_constants(operands, specs, taps, scale, heads, width):
-    """The operands every step sees whole: the taps in float32 and, with a
+def _with_constants(operands, specs, taps, bias, scale, heads, width):
+    """The operands every step sees whole: the taps in float32, the bias
+    ``[1, C]`` in float32 where there is one (None: no operand) and, with a
     norm, the scale (a scalar in SMEM) and the two indicator matrices."""
     def whole(x):
         operands.append(x)
         specs.append(pl.BlockSpec(x.shape, lambda b, i: (0, 0)))
 
     whole(taps.astype(jnp.float32))
+    if bias is not None:
+        whole(bias.astype(jnp.float32).reshape(1, width))
     if heads is not None:
         operands.append(scale.astype(jnp.float32).reshape(1, 1))
         specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -446,24 +511,26 @@ def _with_constants(operands, specs, taps, scale, heads, width):
 # forward pass, once for the recomputation and once backward, not once a
 # layer and call: a body is a few hundred operations, and 27 of them cost
 # the cell 10 s of set-up.  q's call and k's are one program: the scale is
-# an operand, and ``heads`` is None where there is no norm.  ``interpret``
-# is static, so the cached trace is of the mode asked for.)
-@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
-def _forward(y, taps, scale, heads, interpret):
-    b, s, width = y.shape
+# an operand, and ``heads`` is None where there is no norm.  ``bias`` is None
+# where the filter has none: another trace, with no operand for it; ``first``
+# is None where y is the filter's channels and no more.  ``interpret`` is
+# static, so the cached trace is of the mode asked for.)
+@functools.partial(jax.jit, static_argnames=("heads", "first", "interpret"))
+def _forward(y, taps, bias, scale, heads, first, interpret):
+    (b, s, _), width = y.shape, taps.shape[1]
     rows = _pick_rows(s, width)
-    block, before, _ = _specs(rows, width, s // rows)
-    operands, specs = [y, y], [block, before]
-    _with_constants(operands, specs, taps, scale, heads, width)
+    block = _specs(rows, width, s // rows)[0]
+    operands, specs = [y, y], list(_specs(rows, width, s // rows, first)[:2])
+    _with_constants(operands, specs, taps, bias, scale, heads, width)
     scratch = [] if heads is None else [
         pltpu.VMEM((rows, width), jnp.float32),
         pltpu.VMEM((3, rows, width), jnp.bfloat16)]
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads),
+        functools.partial(_fwd_kernel, heads=heads, biased=bias is not None),
         grid=(b, s // rows),
         in_specs=specs,
         out_specs=block,
-        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s, width), y.dtype),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
@@ -474,27 +541,29 @@ def _forward(y, taps, scale, heads, interpret):
         return call(*operands)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
-def _backward(y, taps, g, scale, heads, interpret):
-    b, s, width = y.shape
-    k = taps.shape[0]
+@functools.partial(jax.jit, static_argnames=("heads", "first", "interpret"))
+def _backward(y, taps, bias, g, scale, heads, first, interpret):
+    (b, s, total), (k, width) = y.shape, taps.shape
+    sums = k + (bias is not None)     # a tap's eight partial sums, the bias's
     rows = _pick_rows(s, width)
-    block, before, after = _specs(rows, width, s // rows)
-    operands, specs = [y, y, y, g, g], [block, before, after, block, after]
-    _with_constants(operands, specs, taps, scale, heads, width)
+    block, _, after = _specs(rows, width, s // rows)
+    operands = [y, y, y, g, g]
+    specs = [*_specs(rows, width, s // rows, first), block, after]
+    _with_constants(operands, specs, taps, bias, scale, heads, width)
     n = rows + _HALO                  # the block's rows and those after
     scratch = [] if heads is None else [
         pltpu.VMEM((6, n, width), jnp.bfloat16),
         pltpu.VMEM((n, width), jnp.float32),
         pltpu.VMEM((n, width), jnp.float32)]
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads),
+        functools.partial(_bwd_kernel, heads=heads, biased=bias is not None),
         grid=(b, s // rows),
         in_specs=specs,
-        out_specs=[block, pl.BlockSpec((None, k * _TILE, width),
+        out_specs=[block, pl.BlockSpec((None, sums * _TILE, width),
                                        lambda b, i: (b, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
-                   jax.ShapeDtypeStruct((b, k * _TILE, width), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct(g.shape, y.dtype),
+                   jax.ShapeDtypeStruct((b, sums * _TILE, width),
+                                        jnp.float32)],
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -503,8 +572,12 @@ def _backward(y, taps, g, scale, heads, interpret):
     )
     with _scopes.span(_scopes.MOSAIC_SHORT_CONV):
         dy, dtaps = call(*operands)
-    dtaps = dtaps.reshape(b, k, _TILE, width).sum(axis=(0, 2))
-    return dy, dtaps.astype(taps.dtype)
+    dtaps = dtaps.reshape(b, sums, _TILE, width).sum(axis=(0, 2))
+    if first is not None:             # nothing comes back to the others
+        dy = jnp.pad(dy, ((0, 0), (0, 0), (first, total - first - width)))
+    if bias is None:
+        return dy, dtaps.astype(taps.dtype), None
+    return dy, dtaps[:k].astype(taps.dtype), dtaps[k].astype(bias.dtype)
 
 
 def _norm_of(heads, scale):
@@ -514,26 +587,30 @@ def _norm_of(heads, scale):
     return jnp.float32(scale), heads
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def short_conv(y, taps, heads, scale):
-    """``silu(taps * y)`` for ``y [B, S, heads * d]`` and ``taps [K, heads *
-    d]`` (``*`` the causal depthwise convolution, zero history before
-    position 0 of every batch row), each head L2-normed (``rsqrt(sum of
-    squares + 1e-6)``) and multiplied by ``scale`` where that is not None;
-    float32 inside, the dtype of y out.  One Mosaic call, and one for both
-    gradients; ``_why_not`` says which shapes it takes."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 5))
+def short_conv(y, taps, heads, scale, bias=None, first=None):
+    """``silu(taps * y + bias)`` for ``y [B, S, heads * d]``, ``taps [K,
+    heads * d]`` and ``bias [heads * d]`` or None (``*`` the causal
+    depthwise convolution, zero history before position 0 of every batch
+    row), each head L2-normed (``rsqrt(sum of squares + 1e-6)``) and
+    multiplied by ``scale`` where that is not None; float32 inside, the
+    dtype of y out.  With ``first`` y is wider and the filter reads its
+    channels ``first : first + heads * d``.  One Mosaic call, and one for
+    all three gradients; ``_why_not`` says which shapes it takes."""
     scale, heads = _norm_of(heads, scale)
-    return _forward(y, taps, scale, heads=heads, interpret=_interpret())
+    return _forward(y, taps, bias, scale, heads=heads, first=first,
+                    interpret=_interpret())
 
 
-def _short_conv_fwd(y, taps, heads, scale):
-    return short_conv(y, taps, heads, scale), (y, taps)
+def _short_conv_fwd(y, taps, heads, scale, bias, first):
+    return short_conv(y, taps, heads, scale, bias, first), (y, taps, bias)
 
 
-def _short_conv_bwd(heads, scale, kept, g):
-    y, taps = kept
+def _short_conv_bwd(heads, scale, first, kept, g):
+    y, taps, bias = kept
     scale, heads = _norm_of(heads, scale)
-    return _backward(y, taps, g, scale, heads=heads, interpret=_interpret())
+    return _backward(y, taps, bias, g, scale, heads=heads, first=first,
+                     interpret=_interpret())
 
 
 short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
@@ -591,22 +668,24 @@ def _convolved_plain(y, taps, heads, scale, bias=None):
     return out.astype(y.dtype)
 
 
-def convolved(y, taps, heads, scale, in_place: bool, bias=None):
+def convolved(y, taps, heads, scale, in_place: bool, bias=None, first=None):
     """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
     head L2-normed and multiplied by ``scale`` where that is not None.
-    ``bias [heads * d]`` (a Mamba-2 layer's ``use_conv_bias``) is added
-    before the SiLU, in the plain body alone: the Mosaic pass takes none, so
-    that the calls without one lower to what they always did, and a filter
-    with a bias is counted under ``HAS_BIAS``.
+    ``bias [heads * d]`` (a Mamba-2 layer's ``use_conv_bias``) or None is
+    added before the SiLU, in either body; a call without one holds no
+    operand for it.  ``first`` (static) or None: y is ``[B, S, wider]`` and
+    the filter's channels are ``y[..., first : first + heads * d]``, which
+    the pass reads where they lie and the ``jnp`` body cuts out.
     ``in_place`` is the caller's word that this trace may hold Mosaic calls
     on operands where they lie: the chain is then ``short_conv``'s one pass
     forward and one backward, where the shape is one it takes
     (``_why_not``).  Elsewhere ``_convolved_plain``.  Which body a trace
     took, and why, ``body_counts()`` says."""
-    why = _why_not(y.shape, taps.shape, heads) if in_place else NOT_IN_PLACE
-    if bias is not None:
-        why = HAS_BIAS
+    why = (_why_not(y.shape, taps.shape, heads, first) if in_place
+           else NOT_IN_PLACE)
     _trace_counts.note(_BODY, why or _FUSED)
     if why is None:
-        return short_conv(y, taps, heads, scale)
+        return short_conv(y, taps, heads, scale, bias, first)
+    if first is not None:
+        y = y[..., first:first + taps.shape[1]]
     return _convolved_plain(y, taps, heads, scale, bias)

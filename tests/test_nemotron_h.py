@@ -233,6 +233,53 @@ def test_bias_update_sign_and_rate_over_two_steps(tiny):
     assert steps.max() <= 1.0 and np.abs(got2).max() <= 2 * rate + 1e-9
 
 
+# -- the filter's two bodies --------------------------------------------------
+
+def test_a_mamba_layer_in_place_is_the_layer_it_is_elsewhere(tiny):
+    """``Mamba2(in_place=True)`` sends its biased filter through
+    ``ops/short_conv.py``'s Mosaic pass (interpreted here), which reads x, B
+    and C where ``in_proj`` left them; ``in_place=False`` through the
+    ``jnp`` body, which the reference holds above.  On a Mamba layer of 8
+    heads of 16 (the filter's channels then start at a lane tile, 128, as
+    the cell's at 4096) moved off its start (``conv_b`` no zeros), two rows
+    of 96 tokens (three blocks of rows), the two give the same output and
+    the same gradient of every leaf: a bias the pass dropped shows in
+    ``conv_b``'s leaf, by name."""
+    from horovod_tpu.ops import short_conv
+
+    config = dataclasses.replace(tiny[0].llama, mamba_num_heads=8)
+    k_init, k_move, k_x = jax.random.split(jax.random.key(53), 3)
+    x = jax.random.normal(k_x, (2, 96, config.hidden_size))
+    layer = llama.Mamba2(config).init(k_init, x)["params"]
+    leaves, tree = jax.tree.flatten(layer)
+    layer = jax.tree.unflatten(tree, [
+        leaf + 0.1 * (jnp.std(leaf) or 1.0) * jax.random.normal(k, leaf.shape)
+        for leaf, k in zip(leaves, jax.random.split(k_move, len(leaves)))])
+    assert float(jnp.min(jnp.abs(layer["conv_b"]))) > 0
+
+    def both_ways(in_place):
+        module = llama.Mamba2(config, in_place=in_place)
+
+        def run(p, x):
+            out, vjp = jax.vjp(lambda p, x: module.apply({"params": p}, x),
+                               p, x)
+            return {"out": out, "grads": vjp(jnp.cos(out))}
+        return {jax.tree_util.keystr(path): leaf for path, leaf in
+                jax.tree_util.tree_leaves_with_path(jax.jit(run)(layer, x))}
+
+    before = short_conv.body_counts()
+    got = both_ways(True)
+    after = short_conv.body_counts()
+    assert after["fused"] == before["fused"] + 1
+    assert after["plain"] == before["plain"]
+    wanted = both_ways(False)
+    assert "['grads'][0]['conv_b']" in got and len(got) == len(
+        jax.tree.leaves(layer)) + 2
+    for name, want in wanted.items():
+        off = float(jnp.linalg.norm(got[name] - want) / jnp.linalg.norm(want))
+        assert off < 1e-5, (name, off)
+
+
 # -- the stack and the config -----------------------------------------------
 
 def test_one_norm_and_one_sublayer_a_layer(tiny):

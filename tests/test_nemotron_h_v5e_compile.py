@@ -3,10 +3,11 @@ described ``v5e:2x2`` (no chip attached), a LAYER at a time at the cell's
 size, beside ``tests/test_qwen3_next_v5e_compile.py`` and in its manner: the
 flash pair at 32 query heads over 2 key-value heads of 128 without a rotation;
 a routed layer's grouped products (relu2: two matrices an expert); the
-convolution over a Mamba-2 layer's 6,144 channels, whose bias keeps it in the
-plain body while the same shape without one is the Mosaic pass; and a Mamba-2
-layer whole, forward and backward.  The whole step at 2 x 8192 is compiled by
-the builder's study and on the chip, not here (it takes a minute)."""
+convolution over a Mamba-2 layer's 6,144 channels, which is the Mosaic pass
+with its bias as without one, on an array of their own or where they lie in
+``in_proj``'s output; and a Mamba-2 layer whole, forward and backward, whose
+only Mosaic calls are that filter's.  The whole step at 2 x 8192 is compiled
+by the builder's study and on the chip, not here (it takes a minute)."""
 
 import os
 import re
@@ -66,9 +67,9 @@ def _mosaic_calls(text):
     return [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
 
 
-def _layer_text(module, one_chip, hidden):
-    """The compiled text of ``module``'s forward and backward pass on
-    ``bf16[2, 8192, hidden]`` with parameters as it initialises them."""
+def _layer_compiled(module, one_chip, hidden):
+    """``module``'s forward and backward pass on ``bf16[2, 8192, hidden]``
+    with parameters as it initialises them, compiled."""
     def sds(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
@@ -82,8 +83,7 @@ def _layer_text(module, one_chip, hidden):
             {**variables, "params": p}, x).astype(jnp.float32)),
             argnums=(0, 1))(variables["params"], x)
 
-    return jax.jit(grads).lower(jax.tree.map(sds, variables),
-                                x).compile().as_text()
+    return jax.jit(grads).lower(jax.tree.map(sds, variables), x).compile()
 
 
 def test_the_flash_pair_compiles_at_32_over_2_heads_of_128(one_chip, config):
@@ -132,42 +132,74 @@ def test_a_routed_layers_grouped_products(one_chip, config):
     assert "2688x3712xbf16" in text          # the shared expert's own width
 
 
-@pytest.mark.parametrize("bias", [False, True])
-def test_the_filter_over_6144_channels(one_chip, bias):
-    """Without a bias ``short_conv``'s Mosaic pass takes ``[2, 8192, 6144]``
-    (48 lane tiles, one head, no norm): one call each way.  With one the
-    plain body runs, no Mosaic call, and the count says why."""
-    y = jax.ShapeDtypeStruct((B, S, 6144), jnp.bfloat16, sharding=one_chip)
+@pytest.mark.parametrize("bias, first", [
+    (False, None), (True, None), (True, 4096)],
+    ids=["no bias", "bias", "bias, in in_proj's output"])
+def test_the_filter_over_6144_channels(one_chip, bias, first):
+    """``short_conv``'s Mosaic pass takes ``[2, 8192, 6144]`` (48 lane
+    tiles, one head, no norm; blocks of 256 rows), with a Mamba-2 layer's
+    bias as without one: one call each way, and with a bias its gradient
+    ``f32[6144]`` among the results, summed from the backward call's
+    ``[B, (K + 1) * 8, C]`` partial sums (``[B, K * 8, C]`` without).  And
+    as the layer hands them over: channels 4096.. of ``in_proj``'s
+    ``[2, 8192, 10304]``, read by element windows with no cut before the
+    calls."""
+    y = jax.ShapeDtypeStruct((B, S, 6144 if first is None else 10304),
+                             jnp.bfloat16, sharding=one_chip)
     taps = jax.ShapeDtypeStruct((4, 6144), jnp.float32, sharding=one_chip)
     offset = jax.ShapeDtypeStruct((6144,), jnp.float32, sharding=one_chip)
 
     def grads(y, taps, offset):
         return jax.value_and_grad(lambda y, taps, offset: jnp.sum(
             short_conv.convolved(
-                y, taps, 1, None, True, bias=offset if bias else None
-            ).astype(jnp.float32)), argnums=(0, 1))(y, taps, offset)
+                y, taps, 1, None, True, bias=offset if bias else None,
+                first=first).astype(jnp.float32)),
+            argnums=(0, 1, 2))(y, taps, offset)
 
     before = short_conv.body_counts()
-    text = jax.jit(grads).lower(y, taps, offset).compile().as_text()
+    compiled = jax.jit(grads).lower(y, taps, offset).compile()
     after = short_conv.body_counts()
-    if bias:
-        assert after["plain"][short_conv.HAS_BIAS] == before["plain"].get(
-            short_conv.HAS_BIAS, 0) + 1
-        assert not _mosaic_calls(text)
-    else:
-        assert after["fused"] == before["fused"] + 1
-        assert len(_mosaic_calls(text)) == 2
+    assert after["fused"] == before["fused"] + 1
+    assert after["plain"] == before["plain"]
+    text = compiled.as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2
+    sums = f"f32[{B},{(4 + bias) * 8},6144]"
+    assert sum(sums in call for call in calls) == 1, sums
+    _, (d_y, _, d_offset) = compiled.out_info
+    assert d_y.shape == y.shape
+    assert d_offset.shape == (6144,) and d_offset.dtype == jnp.float32
+    if first is not None:
+        assert all(f"bf16[{B},{S},10304]" in call for call in calls)
+        assert not re.search(rf" = bf16\[{B},{S},6144\]\S* slice\(", text)
 
 
-def test_a_mamba_layer_compiles_with_no_mosaic_call(one_chip, config):
-    """A ``Mamba2`` layer at 2 x 8192 tokens, forward and backward: all XLA
-    (the scan is ``jax.numpy``, the filter has a bias), its three scopes in
-    the text, and no array of a chunk's ``[128, 128]`` for every chunk at
-    once (a slab of 8 chunks at a time: 2 x 8 x 64 heads)."""
-    text = _layer_text(llama.Mamba2(config, in_place=True), one_chip,
-                       config.hidden_size)
-    assert not _mosaic_calls(text)
+def test_a_mamba_layers_only_mosaic_calls_are_the_filters(one_chip, config):
+    """A ``Mamba2`` layer at 2 x 8192 tokens, forward and backward: its
+    biased filter is ``short_conv``'s pass, one call each way under
+    ``hvd.ssd.conv`` (where ``ssd_conv_ms`` reads them), and everything
+    else XLA (the scan is ``jax.numpy``); its three scopes in the text, and
+    no array of a chunk's ``[128, 128]`` for every chunk at once (a slab of
+    8 chunks at a time: 2 x 8 x 64 heads)."""
+    before = short_conv.body_counts()
+    compiled = _layer_compiled(llama.Mamba2(config, in_place=True), one_chip,
+                               config.hidden_size)
+    text = compiled.as_text()
+    after = short_conv.body_counts()
+    assert after["fused"] == before["fused"] + 1
+    # (``_layer_compiled`` initialises on 8 rows, which no block divides.)
+    assert {why for why, n in after["plain"].items()
+            if n != before["plain"].get(why, 0)} == {short_conv._NO_ROW_BLOCK}
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2
+    assert all(scopes.SSD_CONV in call for call in calls)
     for scope in (scopes.SSD_CONV, scopes.SSD_GATES, scopes.SSD_SCAN):
         assert scope in text, scope
     assert "f32[64,2,8,8,128,128]" not in text
     assert "f32[8,2,8,8,128,128]" in text
+    # The calls read the filter's channels in ``in_proj``'s output: no
+    # ``bf16[2, 8192, 6144]`` cut of them, which as the backward call's
+    # residual is 201 MB more (2.030 GB of temporaries here, 2.231 with it).
+    assert all(f"bf16[{B},{S},10304]" in call for call in calls)
+    assert not re.search(rf" = bf16\[{B},{S},6144\]\S* slice\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.13e9

@@ -8,6 +8,8 @@ squares is a product with the heads' 0/1 indicator in both, at ``highest``
 in the ``jnp`` body and as three bf16 pieces in the pass), so they agree to
 float32 rounding, and to one unit in the last place of a bfloat16 result."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -104,6 +106,116 @@ def test_the_pass_gives_the_jnp_bodys_values_and_gradients(dtype, scale):
     assert not changed[:ROWS - K + 1].any() and not changed[ROWS + 1:].any()
 
 
+# A filter with a bias (a Mamba-2 layer's): two batch rows, three blocks of
+# 32 rows, and 1152 lanes, which is one whole chunk of the forward walk's
+# 1024 and two of the backward walk's 512 with 128 left over in both; heads
+# of 192 straddle the lane tiles.
+BIASED_HEADS, BIASED_WIDTH = 6, 1152
+
+
+@pytest.mark.parametrize("first", [None, 256], ids=["whole", "window"])
+@pytest.mark.parametrize("scale", [None, 192 ** -0.5],
+                         ids=["plain", "normed"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_pass_takes_the_filters_bias(dtype, scale, first):
+    """``silu(taps * y + bias)``: the bias joins c inside the pass, forward
+    and where the backward call makes c again, and its gradient is the sum
+    of dc over every row of every batch row.  With ``first`` the filter's
+    channels are a run in the middle of a wider y (a Mamba-2 layer's x, B
+    and C in ``in_proj``'s output): the pass reads them where they lie, and
+    the other channels' cotangent is zero."""
+    ky, kt, kb, kg = jax.random.split(jax.random.key(5), 4)
+    shape = (B, S, BIASED_WIDTH)
+    y = jax.random.normal(ky, shape if first is None else (
+        B, S, first + BIASED_WIDTH + 128), jnp.float32).astype(dtype)
+    g = jax.random.normal(kg, shape, jnp.float32).astype(dtype)
+    taps = jax.random.uniform(kt, (K, BIASED_WIDTH), jnp.float32, -0.5, 0.5)
+    bias = jax.random.normal(kb, (BIASED_WIDTH,), jnp.float32)
+    assert short_conv._pick_rows(S, BIASED_WIDTH) == ROWS
+    assert BIASED_WIDTH % short_conv._FWD_LANES
+    assert BIASED_WIDTH % short_conv._BWD_LANES > 0 < (
+        BIASED_WIDTH // short_conv._BWD_LANES)
+
+    def both_ways(in_place):
+        def run(y, taps, bias, g):
+            out, vjp = jax.vjp(lambda *x: convolved(
+                x[0], x[1], BIASED_HEADS, scale, in_place, bias=x[2],
+                first=first), y, taps, bias)
+            return (out,) + vjp(g)
+        return jax.jit(run)
+
+    before = short_conv.body_counts()
+    got = both_ways(True)(y, taps, bias, g)
+    after = short_conv.body_counts()
+    assert after["fused"] == before["fused"] + 1
+    assert after["plain"] == before["plain"]
+    want = both_ways(False)(y, taps, bias, g)
+    assert got[0].shape == shape and got[1].shape == y.shape
+    if first is not None:
+        outside = np.r_[:first, first + BIASED_WIDTH:y.shape[2]]
+        assert not _f32(got[1])[..., outside].any()
+    for a, b, what in zip(got, want, ("out", "dy", "dtaps", "dbias")):
+        assert a.shape == b.shape and a.dtype == b.dtype, what
+        # The taps' and the bias's gradients are float32 sums over B * S
+        # rows either way.
+        _close(a, b, dtype if what in ("out", "dy") else jnp.float32, what)
+    # It is the bias that was added, before the SiLU: without it the values
+    # differ, and the last batch row's last rows count in its gradient.
+    bare = both_ways(True)(y, taps, jnp.zeros_like(bias), g)
+    assert not np.allclose(_f32(bare[0]), _f32(got[0]), atol=1e-2)
+    moved = both_ways(True)(y, taps, bias, g.at[B - 1, S - 1].add(1.0))
+    assert np.any(_f32(moved[3]) != _f32(got[3]))
+
+
+def _mosaic_calls_lowered(fn, *args):
+    """``(operands, results)`` of every Mosaic call in ``fn``'s text as it
+    is LOWERED for a TPU (no chip, and no compiler: the text alone)."""
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return [(len(call.group(1).split(",")), len(call.group(2).split(",")))
+            for call in re.finditer(
+                r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\).*"
+                r"-> \(?([^)\n]*)\)?$", text, flags=re.M)]
+
+
+def test_a_call_without_a_bias_lowers_to_the_operands_it_always_had(
+        monkeypatch):
+    """The bias is an operand only where there is one: a linear layer's nine
+    calls carry none, and neither a zero bias nor a third result for its
+    gradient.  Forward y, the rows before, the taps -> out; backward the
+    rows after, g and its own as well -> dy and the taps' partial sums;
+    three constants more with a norm.  With a bias: one operand more each
+    way, and its partial sums ride the taps' block."""
+    monkeypatch.setattr(short_conv, "_interpret", lambda: False)
+    # (A shape no other test of the file traces: ``_forward`` and
+    # ``_backward`` are jits, and a cached trace would be the interpreted.)
+    y = jax.ShapeDtypeStruct((1, 64, 512), jnp.bfloat16)
+    taps = jax.ShapeDtypeStruct((K, 512), jnp.float32)
+    bias = jax.ShapeDtypeStruct((512,), jnp.float32)
+
+    def both(scale, biased, first=None):
+        def run(y, taps, bias):
+            out, vjp = jax.vjp(lambda *x: convolved(
+                x[0], x[1], 4, scale, True, bias=x[2] if biased else None,
+                first=first), y, taps, bias)
+            return out, vjp(out)
+        return run
+
+    assert _mosaic_calls_lowered(both(None, False), y, taps, bias) == [
+        (3, 1), (6, 2)]
+    assert _mosaic_calls_lowered(both(1.0, False), y, taps, bias) == [
+        (6, 1), (9, 2)]
+    assert _mosaic_calls_lowered(both(None, True), y, taps, bias) == [
+        (4, 1), (7, 2)]
+    assert _mosaic_calls_lowered(both(1.0, True), y, taps, bias) == [
+        (7, 1), (10, 2)]
+    # A window of a wider y is the same operands, placed otherwise.
+    wider = jax.ShapeDtypeStruct((1, 64, 1024), jnp.bfloat16)
+    assert _mosaic_calls_lowered(both(None, True, 384), wider, taps, bias
+                                 ) == [(4, 1), (7, 2)]
+
+
 def test_each_pass_is_one_mosaic_call_on_the_projections_layout():
     y = jnp.zeros((B, S, H * D), jnp.bfloat16)
     taps = jnp.zeros((K, H * D), jnp.float32)
@@ -146,6 +258,23 @@ def test_a_refused_shape_takes_the_jnp_body_and_the_counter_says_why(
         y, jnp.zeros((K, H * D)), H, 1.0, True))(good)
     assert short_conv.body_counts()["fused"] == after["fused"] + 1
     assert short_conv.body_counts()["plain"] == after["plain"]
+
+
+def test_a_window_off_the_lane_tiles_is_cut_out_for_the_jnp_body():
+    """``first`` no multiple of 128 lanes: the channels are cut out and the
+    ``jnp`` body runs on them, counted by reason; the values are those of
+    the cut handed in whole."""
+    y = jax.random.normal(jax.random.key(0), (B, S, 64 + H * D + 64),
+                          jnp.bfloat16)
+    taps = jax.random.uniform(jax.random.key(1), (K, H * D))
+    before = short_conv.body_counts()
+    got = convolved(y, taps, H, 1.0, True, first=64)
+    after = short_conv.body_counts()
+    assert after["fused"] == before["fused"]
+    assert after["plain"][short_conv._NOT_AT_A_TILE] == before["plain"].get(
+        short_conv._NOT_AT_A_TILE, 0) + 1
+    want = convolved(y[..., 64:64 + H * D], taps, H, 1.0, False)
+    np.testing.assert_array_equal(_f32(got), _f32(want))
 
 
 @pytest.mark.parametrize("name, attention_fn, fused", [

@@ -70,16 +70,26 @@ def test_states_are_the_ones_each_chunk_starts_from():
 # -- the filter's bias --------------------------------------------------------
 
 @pytest.mark.parametrize("in_place", [False, True])
-def test_filter_bias_is_added_before_the_silu_in_the_plain_body(in_place):
+def test_filter_bias_is_added_before_the_silu_in_both_bodies(in_place):
+    """The plain body where the caller does not read its operands in place,
+    ``short_conv``'s Mosaic pass (interpreted here) where it does: the same
+    values and the same gradient of the bias, and the counter says which."""
     k = jax.random.split(jax.random.key(3), 3)
     y = jax.random.normal(k[0], (2, 32, 256))
     taps = jax.random.uniform(k[1], (4, 256), minval=-0.5, maxval=0.5)
     bias = jax.random.normal(k[2], (256,))
-    before = short_conv.body_counts()["plain"].get(short_conv.HAS_BIAS, 0)
+    before = short_conv.body_counts()
     got = short_conv.convolved(y, taps, 1, None, in_place, bias=bias)
     wanted = jax.nn.silu(reference.short_convolution(y, taps, bias))
     np.testing.assert_allclose(got, wanted, atol=1e-5)
-    assert short_conv.body_counts()["plain"][short_conv.HAS_BIAS] == before + 1
+    after = short_conv.body_counts()
+    if in_place:
+        assert after["fused"] == before["fused"] + 1
+        assert after["plain"] == before["plain"]
+    else:
+        assert after["fused"] == before["fused"]
+        assert after["plain"][short_conv.NOT_IN_PLACE] == before["plain"].get(
+            short_conv.NOT_IN_PLACE, 0) + 1
     g_bias = jax.grad(lambda b: jnp.sum(short_conv.convolved(
         y, taps, 1, None, in_place, bias=b) ** 2))(bias)
     w_bias = jax.grad(lambda b: jnp.sum(jax.nn.silu(
