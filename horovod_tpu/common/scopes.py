@@ -178,15 +178,48 @@ scope                 what falls under it
                       chunks that carries the state; forward, run again
                       under recomputation and backward; XLA operations, and
                       Mosaic calls should a later kernel replace part of it
+``hvd.sscan.conv``    a Mamba-1 layer's (``models/llama.py::Mamba1``) causal
+                      depthwise convolution over its u channels where
+                      ``in_proj`` left them, the filter's bias and the SiLU:
+                      ``ops/short_conv.py``'s Mosaic calls or its ``jnp``
+                      body, as ``hvd.ssd.conv``
+``hvd.sscan.gates``   the same layer's way from u to the scan's step, B and
+                      C: the ``[inner, rank + 2 N]`` projection, the rank's
+                      ``[rank, inner]`` projection, its bias and the
+                      softplus in float32; and behind the scan the gate
+                      ``y silu(z)``
+``hvd.sscan.scan``    the selective scan itself
+                      (``ops/selective_scan.py::selective_scan``): the
+                      Mosaic pair or the ``jnp`` body's chunks, forward, run
+                      again under recomputation and backward, and the sums
+                      over the backward call's partial gradients
+``hvd.gmu``           a gated memory unit whole (``models/llama.py::
+                      GatedMemory``): ``x W_1``, the gate on the shared
+                      memory ``m silu(x W_1)``, ``W_2``, and their
+                      gradients.  The whole unit, because XLA fuses the gate
+                      product into ``W_2``'s matmul and a fusion has one
+                      ``op_name`` (a scope around the product alone read
+                      nothing on the v5e, PR 54)
+``hvd.attn.diff``     differential attention's own work behind its two
+                      ``attention_fn`` calls
+                      (``models/llama.py::DifferentialAttention``): lambda
+                      from its four vectors, ``a1 - lambda a2``, the RMSNorm
+                      over a head pair's value lanes and the factor ``1 -
+                      lambda_init``; the calls themselves are
+                      ``hvd.flash.*`` (inside ``hvd.attn.window`` in a
+                      sliding layer), beside it
 ``hvd.block.attn``    a layer's mixer block whole
                       (``models/llama.py::LlamaLayer``): ``norm_attn``,
                       the mixer (``LlamaAttention``, ``LatentAttention``,
-                      ``SparseAttention``, ``GatedDeltaNet`` or ``Mamba2``:
+                      ``SparseAttention``, ``DifferentialAttention``,
+                      ``GatedDeltaNet``, ``Mamba2``, ``Mamba1`` or
+                      ``GatedMemory``:
                       projections, QK-norm, rotation, the ``attention_fn``
                       call or the rule, ``wo``) and the residual add.
                       ``hvd.flash.*``, ``hvd.rope``, ``hvd.attn.*``,
-                      ``hvd.mla.latent``, ``hvd.sparse.*``, ``hvd.gdn.*`` and
-                      ``hvd.ssd.*`` nest inside it.  In a stack whose
+                      ``hvd.mla.latent``, ``hvd.sparse.*``, ``hvd.gdn.*``,
+                      ``hvd.ssd.*``, ``hvd.sscan.*`` and ``hvd.gmu`` nest
+                      inside it.  In a stack whose
                       layers are ONE sublayer
                       (``LlamaConfig.hybrid_override_pattern``) a mixer
                       layer is this block alone, with the layer's one norm
@@ -281,13 +314,14 @@ __all__ = [
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
     "SSD_CONV", "SSD_GATES", "SSD_SCAN",
+    "SSCAN_CONV", "SSCAN_GATES", "SSCAN_SCAN", "GMU", "ATTN_DIFF",
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
     "TRAIN_STEP_PROGRAM", "allreduce_scope", "scope", "span",
     "MOSAIC", "MOSAIC_FLASH_FWD", "MOSAIC_FLASH_BWD", "MOSAIC_ROPE",
     "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
-    "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION",
+    "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION", "MOSAIC_SSCAN",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
 ]
@@ -322,6 +356,11 @@ GDN_SOLVE = "hvd.gdn.solve"       # inside GDN_SCAN
 SSD_CONV = "hvd.ssd.conv"
 SSD_GATES = "hvd.ssd.gates"
 SSD_SCAN = "hvd.ssd.scan"
+SSCAN_CONV = "hvd.sscan.conv"
+SSCAN_GATES = "hvd.sscan.gates"
+SSCAN_SCAN = "hvd.sscan.scan"
+GMU = "hvd.gmu"
+ATTN_DIFF = "hvd.attn.diff"
 BLOCK_ATTN = "hvd.block.attn"
 BLOCK_FFN = "hvd.block.ffn"
 HEAD = "hvd.head"
@@ -343,6 +382,7 @@ MOSAIC_GDN_SOLVE = MOSAIC + "gdn_solve"
 MOSAIC_SPARSE_SELECT = MOSAIC + "sparse_select"
 MOSAIC_INDEX_LOSS = MOSAIC + "index_loss"
 MOSAIC_PAGED_ATTENTION = MOSAIC + "paged_attention"
+MOSAIC_SSCAN = MOSAIC + "selective_scan"
 INIT = "hvd.init"
 INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
 INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize

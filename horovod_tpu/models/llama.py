@@ -22,8 +22,12 @@ TPU-first design:
   linear-attention mixer among softmax ones (``layer_types``,
   ``GatedDeltaNet``), or softmax layers that attend through a sliding
   window (``"sliding_attention"``) with a head count, a rotary table and
-  an output gate of their own; a dense SwiGLU or routed experts beside shared
-  ones (``num_experts > 1``, after ``first_dense_layers`` dense layers).
+  an output gate of their own, or a decoder-hybrid-decoder stack
+  (``mb_per_layer``: Mamba-1 selective scans and differential attention,
+  whose later half reads ONE scan's output and ONE layer's keys and values
+  through gated memory units and cross-attention); a dense SwiGLU or routed
+  experts beside shared ones (``num_experts > 1``, after
+  ``first_dense_layers`` dense layers).
   The routed layer is told which experts it holds, routes over all of
   them, gathers its own experts' rows sorted by expert -- none dropped --
   and runs grouped products over them (``RoutedExperts``).
@@ -53,6 +57,8 @@ from horovod_tpu.ops.gated_delta import (calls_in_place, gated_delta_rule,
                                          gated_delta_states)
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops import rope as _rope
+from horovod_tpu.ops.selective_scan import (selective_scan,
+                                            selective_scan_states)
 from horovod_tpu.ops.short_conv import convolved, over_heads
 from horovod_tpu.ops.ssd import ssd_scan, ssd_states
 from horovod_tpu.ops.sparse_index import index_loss, select_keys
@@ -87,6 +93,12 @@ LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention")
 # Mamba-2 layer, a routed feed-forward layer, a softmax attention layer.
 MAMBA, EXPERTS, ATTENTION = PATTERN_KINDS = ("M", "E", "*")
 DENSE_LAYER = "-"       # published too; no model here has one: refused
+# The mixers of a decoder-hybrid-decoder stack (``LlamaConfig.mixer_of``): a
+# Mamba-1 selective scan, differential attention over the layer's own keys, a
+# gated memory unit on the shared scan output, cross-attention to the shared
+# keys and values.
+SCAN, SELF_ATTENTION, MEMORY_GATE, CROSS_ATTENTION = SHARING_MIXERS = (
+    "mamba", "attention", "gated_memory", "cross_attention")
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -270,6 +282,27 @@ class LlamaConfig:
     ``moe_shared_expert_intermediate_size`` is the shared expert's own
     width.  Generation, the serve plane and the pipelined step refuse a
     pattern, state-space layers and a bias-corrected router by name.
+
+    ``mb_per_layer`` = 2 (the published key of the decoder-hybrid-decoder
+    stack, SambaY, arXiv:2507.06607) places a mixer by the layer's index i
+    among N = ``num_layers`` (N % 4 = 0; ``mixer_of``): even i a ``Mamba1``
+    selective scan (``ssm_state_size`` state entries a channel of
+    ``mamba_expand`` x hidden, a biased filter of ``conv_kernel`` taps, a
+    step through a projection of rank hidden / 16 rounded up;
+    ``ops/selective_scan.py``) up to i = N / 2, whose scan output is the
+    MEMORY, and a ``GatedMemory`` unit on that memory behind it; odd i
+    attention over the layer's own keys up to i = N / 2 + 1 (under
+    ``sliding_window`` before N / 2, full at N / 2 + 1, whose k and v are
+    SHARED), and cross-attention to those k and v behind it; every layer
+    with its SwiGLU.  ``attention_kind`` ``"differential"``
+    (``DifferentialAttention``, arXiv:2410.05258): adjacent heads pair, a
+    pair's two softmax maps are subtracted under a learned lambda and normed
+    over the pair's value lanes; its projections have biases and it does not
+    rotate (``rope_theta`` None).
+    ``layer_norm_eps`` (a float; None: ``RMSNorm`` with ``rms_eps``) makes
+    every norm of the stack a ``LayerNorm`` with a scale and a bias;
+    ``tie_word_embeddings`` makes the head the embedding's transpose.
+    Generation, the serve plane and the pipelined step refuse each by name.
     """
 
     vocab_size: int = 32000
@@ -329,6 +362,10 @@ class LlamaConfig:
     mlp_hidden_act: str = "silu"                  # or "relu2" (the experts')
     moe_shared_expert_intermediate_size: int = 0  # 0: shared_experts x F
     router_bias_update_rate: float = 1e-3
+    mb_per_layer: int = 0         # 2: a decoder-hybrid-decoder stack
+    mamba_expand: int = 2         # a Mamba-1 layer's channels over hidden
+    layer_norm_eps: Optional[float] = None    # a float: LayerNorm, not RMS
+    tie_word_embeddings: bool = False
     dtype: Any = jnp.bfloat16
     # Output-head compute dtype.  bf16 keeps every logits-sized tensor —
     # the forward residual AND the cross-entropy cotangent, 2 GB each in
@@ -346,9 +383,33 @@ class LlamaConfig:
         if self.remat != "none" and self.remat not in REMAT_POLICIES:
             raise ValueError(f"remat is {self.remat!r}: 'none' or one of "
                              f"{sorted(REMAT_POLICIES)}")
-        if self.attention_kind not in ("full", "latent", "sparse"):
+        if self.attention_kind not in ATTENTION_KINDS:
             raise ValueError(f"attention_kind is {self.attention_kind!r}: "
-                             f"'full', 'latent' or 'sparse'")
+                             f"'full', 'latent' or 'sparse', or "
+                             f"'differential' in a stack with mb_per_layer")
+        if self.attention_kind == "differential" and (
+                not self.mb_per_layer or self.rope_theta is not None
+                or self.num_heads % 2 or self.num_kv_heads % 2
+                or self.qk_norm or self.gating is not None):
+            raise ValueError(
+                "differential attention is built for a decoder-hybrid-decoder "
+                "stack (mb_per_layer): it pairs adjacent heads (an even "
+                "count of query and of key-value heads) and neither rotates "
+                "(rope_theta=None), norms nor gates them")
+        if self.mb_per_layer:
+            if (self.mb_per_layer != 2 or self.num_layers % 4
+                    or not self.ssm_state_size or self.sliding_window is None
+                    or self.layer_types is not None
+                    or self.hybrid_override_pattern is not None
+                    or self.norm_placement != "pre" or self.num_experts > 1
+                    or self.total_ut_steps != 1
+                    or self.attention_kind == "latent"):
+                raise ValueError(
+                    "mb_per_layer is 2 (every second layer a Mamba-kind "
+                    "mixer) or 0: the placement needs num_layers % 4 == 0, "
+                    "ssm_state_size and sliding_window, one pass over "
+                    "pre-norm dense layers, and neither layer_types nor "
+                    "hybrid_override_pattern, which it replaces")
         if self.attention_kind == "sparse" and not (
                 self.index_heads and self.index_head_dim
                 and self.index_topk):
@@ -390,15 +451,17 @@ class LlamaConfig:
                     "linear attention needs linear_num_key_heads, "
                     "linear_key_head_dim, linear_value_head_dim and "
                     "linear_num_value_heads, a multiple of the key heads")
-        sliding = "sliding_attention" in (self.layer_types or ())
+        sliding = ("sliding_attention" in (self.layer_types or ())
+                   or bool(self.mb_per_layer))
         if sliding != (self.sliding_window is not None) or (
-                sliding and (self.sliding_window < 1
-                             or self.attention_kind != "full")):
+                sliding and (self.sliding_window < 1 or self.attention_kind
+                             not in ("full", "differential"))):
             raise ValueError(
                 f"sliding_window is {self.sliding_window!r} and layer_types "
                 f"{self.layer_types!r}: a window of at least 1 goes with "
-                f"'sliding_attention' layers of attention_kind 'full', and "
-                f"with nothing else")
+                f"'sliding_attention' layers (or mb_per_layer's) of "
+                f"attention_kind 'full' or 'differential', and with nothing "
+                f"else")
         heads = self.num_attention_heads_per_layer
         if heads is not None and (
                 len(heads) != self.num_layers
@@ -524,8 +587,33 @@ class LlamaConfig:
                 and self.layer_types[layer] == "linear_attention")
 
     def layer_type(self, layer: int) -> str:
+        if self.mb_per_layer:
+            return ("sliding_attention" if layer < self.num_layers // 2
+                    else "full_attention")
         return ("full_attention" if self.layer_types is None
                 else self.layer_types[layer])
+
+    def mixer_of(self, layer: int) -> Optional[str]:
+        """``layer``'s mixer by the decoder-hybrid-decoder rule (one of
+        ``SHARING_MIXERS``); None: the stack shares nothing
+        (``mb_per_layer`` 0).  Layer N / 2 is the last scan, whose output is
+        the memory; layer N / 2 + 1 the last to project keys and values."""
+        if not self.mb_per_layer:
+            return None
+        half = self.num_layers // 2
+        if layer % self.mb_per_layer == 0:
+            return SCAN if layer <= half else MEMORY_GATE
+        return SELF_ATTENTION if layer <= half + 1 else CROSS_ATTENTION
+
+    @property
+    def scan_inner(self) -> int:
+        """Channels of a Mamba-1 layer's u, z, memory and output."""
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def dt_rank(self) -> int:
+        """Rank of a Mamba-1 layer's step projection: Mamba's ``"auto"``."""
+        return -(-self.hidden_size // 16)
 
     def heads_of(self, layer: int) -> int:
         """Query heads of ``layer``'s softmax mixer."""
@@ -551,6 +639,32 @@ class LlamaConfig:
     def refuse_new_kinds(self, who: str) -> None:
         """For the paths that keep a decoder layer of their own and have
         learned neither kind (ROADMAP.md D1): raise, naming the kind."""
+        if self.mb_per_layer:
+            half = self.num_layers // 2
+            raise NotImplementedError(
+                f"{who} has no path for a decoder-hybrid-decoder stack "
+                f"(mb_per_layer={self.mb_per_layer}): its cache would hold "
+                f"a [{self.scan_inner}, {self.ssm_state_size}] float32 "
+                f"state and the filter's last {self.conv_kernel - 1} inputs "
+                f"for each of the {half // 2 + 1} Mamba-1 layers, a window "
+                f"of {self.sliding_window} tokens for the layers before "
+                f"{half}, ONE set of keys and values (layer {half + 1}'s) "
+                f"for all {half // 2} layers that read it by "
+                f"cross-attention, and a prefill would end at layer {half}'s "
+                f"memory for the gated memory units; with differential "
+                f"attention a decode step would attend twice a head pair, "
+                f"keys {self.head_dim} wide and values {2 * self.head_dim}, "
+                f"subtract under lambda and norm the pair's lanes; not built")
+        if self.layer_norm_eps is not None:
+            raise NotImplementedError(
+                f"{who} has no path for LayerNorm (layer_norm_eps="
+                f"{self.layer_norm_eps}): its layers norm by the root mean "
+                f"square alone, without a mean or a bias; not built")
+        if self.tie_word_embeddings:
+            raise NotImplementedError(
+                f"{who} has no path for a head tied to the embedding "
+                f"(tie_word_embeddings=True): it keeps an lm_head of its "
+                f"own [{self.hidden_size}, {self.vocab_size}]; not built")
         pattern = self.hybrid_override_pattern
         if pattern is not None and MAMBA in pattern:
             raise NotImplementedError(
@@ -659,7 +773,9 @@ class RMSNorm(nn.Module):
     """``x / rms(x) * scale`` in float32, the scale from ones; with
     ``zero_centered`` (``LlamaConfig.zero_centered_norm``) ``x / rms(x) * (1
     + scale)``, the scale from zeros: the same function at initialisation,
-    another parameter under weight decay."""
+    another parameter under weight decay.  A stack's block norms and final
+    norm are this one unless its config states ``layer_norm_eps``
+    (``LayerNorm``, ``_stack_norm``)."""
 
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
@@ -678,6 +794,34 @@ class RMSNorm(nn.Module):
         x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
                                            keepdims=True) + self.eps)
         return (x32 * scale).astype(self.dtype)
+
+
+class LayerNorm(nn.Module):
+    """``(x - mean(x)) / sqrt(var(x) + eps) * scale + bias`` in float32, one
+    rounding out; the scale from ones, the bias from zeros: the norm of a
+    stack whose config states ``layer_norm_eps``."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
+                                           keepdims=True) + self.eps)
+        return (x32 * scale + bias).astype(self.dtype)
+
+
+def _stack_norm(cfg: "LlamaConfig", name: str, **kwargs):
+    """The norm of a block, or the final one, as ``cfg`` says: ``LayerNorm``
+    where it states ``layer_norm_eps``, else ``RMSNorm``."""
+    if cfg.layer_norm_eps is not None:
+        return LayerNorm(cfg.layer_norm_eps, cfg.dtype, name=name, **kwargs)
+    return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.zero_centered_norm, name=name,
+                   **kwargs)
 
 
 def rope_freqs(head_dim: int, seq_len: int, theta: float, offset=0,
@@ -976,6 +1120,76 @@ class SparseAttention(LlamaAttention):
             self.sow("sparse_stats", "keys_taken", taken)
             self.sow("sparse_stats", "selected", selected)
         return out
+
+
+def _lambda_init(key, shape, dtype=jnp.float32):
+    """N(0, 0.1): arXiv:2410.05258's start of lambda's four vectors."""
+    return 0.1 * jax.random.normal(key, shape, dtype)
+
+
+class DifferentialAttention(LlamaAttention):
+    """Differential attention (Ye et al., arXiv:2410.05258) over grouped
+    heads that do not rotate.  Adjacent heads pair: query pair j is heads
+    ``(2j, 2j + 1) = (q1, q2)``, key-value pair i heads ``(2i, 2i + 1) = (k1,
+    k2), (v1, v2)``, and query pair j reads key-value pair ``j // (query
+    pairs / key-value pairs)``.  With D = ``head_dim``::
+
+        a1 = softmax(q1 k1^T / sqrt(D)) [v1 | v2]        2 D lanes, causal,
+        a2 = softmax(q2 k2^T / sqrt(D)) [v1 | v2]        under the window
+        lambda = exp(l_q1 . l_k1) - exp(l_q2 . l_k2) + lambda_init
+        o = RMSNorm_2D(a1 - lambda a2) * g * (1 - lambda_init)
+
+    ``lambda_init = 0.8 - 0.6 exp(-0.3 index)``, the four ``l`` learned
+    ``[D]`` vectors, g a learned ``[2 D]`` scale.  Two ``attention_fn`` calls
+    a layer, keys D wide and values 2 D (inside ``hvd.attn.window`` in a
+    sliding layer); what follows them under ``hvd.attn.diff``.  Parameters:
+    ``wqkv [hidden, (heads + 2 kv heads) D]`` (q, then k, then v) and ``wo``,
+    each with a bias; ``lambda_q1 lambda_k1 lambda_q2
+    lambda_k2 [D]``, ``subln [2 D]``.
+
+    ``__call__(x, cos, sin, kv)`` returns ``(out, (k, v))``.  With ``kv``
+    given (another layer's k and v ``[B, S, kv heads * D]``, as its
+    projection left them) the layer projects a query alone (``wq``) and
+    attends to THOSE: cross-attention."""
+
+    @nn.compact
+    def __call__(self, x, cos, sin, kv=None):
+        cfg = self.config
+        B, S, _ = x.shape
+        D, heads, kv_heads = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+
+        def dense(width, name):
+            return nn.Dense(width, dtype=cfg.dtype, name=name)
+
+        if kv is None:
+            qkv = dense((heads + 2 * kv_heads) * D, "wqkv")(x)
+            q = qkv[..., :heads * D]
+            kv = (qkv[..., heads * D:(heads + kv_heads) * D],
+                  qkv[..., (heads + kv_heads) * D:])
+        else:
+            q = dense(heads * D, "wq")(x)
+        k, v = kv
+        q = q.reshape(B, S, heads // 2, 2, D)
+        k = k.reshape(B, S, kv_heads // 2, 2, D)
+        pair_v = v.reshape(B, S, kv_heads // 2, 2 * D)
+        with (_scopes.scope(_scopes.ATTN_WINDOW)
+              if cfg.window_of(self.index) is not None
+              else contextlib.nullcontext()):
+            a1, a2 = (self.attend(x, q[:, :, :, i], k[:, :, :, i], pair_v,
+                                  cos, sin) for i in range(2))
+        with _scopes.scope(_scopes.ATTN_DIFF):
+            lambda_init = 0.8 - 0.6 * math.exp(-0.3 * self.index)
+            l_q1, l_k1, l_q2, l_k2 = (
+                self.param(name, _lambda_init, (D,)) for name in (
+                    "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+            lam = (jnp.exp(jnp.sum(l_q1 * l_k1))
+                   - jnp.exp(jnp.sum(l_q2 * l_k2)) + lambda_init)
+            diff = a1.astype(jnp.float32) - lam * a2.astype(jnp.float32)
+            diff = diff * jax.lax.rsqrt(jnp.mean(
+                diff * diff, axis=-1, keepdims=True) + cfg.rms_eps)
+            out = (diff * self.param("subln", nn.initializers.ones, (2 * D,))
+                   * (1.0 - lambda_init)).astype(cfg.dtype)
+        return dense(cfg.hidden_size, "wo")(out.reshape(B, S, heads * D)), kv
 
 
 class SwiGLU(nn.Module):
@@ -1669,15 +1883,132 @@ class Mamba2(nn.Module):
                         name="out_proj")(y)
 
 
+def _state_a_log_init(key, shape, dtype=jnp.float32):
+    """``log A`` with ``A[d, n] = n + 1``: Mamba's S4D-real start."""
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)),
+                            shape)
+
+
+def _dt_kernel_init(key, shape, dtype=jnp.float32):
+    """Uniform in +- rank^-1/2: Mamba's ``dt_init="random"``."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _silu_gated(y, z):
+    """``y * silu(z)`` in float32, rounded once to the dtype of z."""
+    return (y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+            ).astype(z.dtype)
+
+
+class Mamba1(nn.Module):
+    """The Mamba selective-scan mixer (Gu & Dao, arXiv:2312.00752) of a
+    decoder-hybrid-decoder stack's ``"mamba"`` layer.  With I =
+    ``mamba_expand`` x hidden channels, N = ``ssm_state_size`` state entries a
+    channel, R = ``dt_rank``, x the block's normed input and ``*`` the causal
+    depthwise convolution of ``conv_kernel`` taps, with a bias::
+
+        [u' | z] = x W_in                   u', z [I]
+        u = silu(conv * u' + b_conv)
+        [r | B | C] = u W_x                 r [R], B, C [N]
+        D_t = softplus(r_t W_dt + b_dt)     [I], float32;  A = -exp(A_log)
+        h_t[d, n] = exp(D_t[d] A[d, n]) h_{t-1}[d, n] + D_t[d] B_t[n] u_t[d]
+        y_t[d] = sum_n C_t[n] h_t[d, n] + D[d] u_t[d]            h_0 = 0
+        out = (y * silu(z)) W_out
+
+    The recurrence is ``ops/selective_scan.py``'s (the state in float32, a
+    decay a channel and a state entry).  ``__call__`` returns ``(out, y)``:
+    y, with the skip and BEFORE the gate, is what the stack's last such
+    layer shares as the memory.  Parameters: ``in_proj [hidden, 2 I]``,
+    ``conv_w [K, I]``, ``conv_b``, ``x_proj [I, R + 2 N]``, ``dt_proj [R, I]``
+    with its bias, ``a_log [I, N]``, ``d [I]``, ``out_proj``.
+
+    Sown where the caller makes ``sscan_stats`` mutable: ``decay_min``,
+    ``decay_mean`` (of ``exp(D_t A)``), ``dt_mean``, ``state_max`` (the
+    largest |h| a chunk started from) and ``out_max`` (the largest |y|).
+    """
+
+    config: LlamaConfig
+    in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        inner, state, rank = cfg.scan_inner, cfg.ssm_state_size, cfg.dt_rank
+        projected = nn.Dense(2 * inner, use_bias=False, dtype=cfg.dtype,
+                             name="in_proj")(x)
+        z = projected[..., inner:]
+        with _scopes.scope(_scopes.SSCAN_CONV):
+            # (The filter reads u' where ``in_proj`` left it.)
+            u = convolved(
+                projected, self.param("conv_w", _conv_taps_init,
+                                      (cfg.conv_kernel, inner)),
+                1, None, self.in_place,
+                bias=self.param("conv_b", nn.initializers.zeros, (inner,)),
+                first=0)
+        with _scopes.scope(_scopes.SSCAN_GATES):
+            rbc = nn.Dense(rank + 2 * state, use_bias=False, dtype=cfg.dtype,
+                           name="x_proj")(u)
+            b, c = rbc[..., rank:rank + state], rbc[..., rank + state:]
+            delta = jax.nn.softplus(nn.Dense(
+                inner, dtype=jnp.float32, kernel_init=_dt_kernel_init,
+                bias_init=_dt_bias_init, name="dt_proj")(
+                    rbc[..., :rank].astype(jnp.float32)))
+        a_log = self.param("a_log", _state_a_log_init, (inner, state))
+        with _scopes.scope(_scopes.SSCAN_SCAN):
+            y = selective_scan(u, delta, a_log, b, c, self.param(
+                "d", nn.initializers.ones, (inner,)), self.in_place)
+        if (self.is_mutable_collection("sscan_stats")
+                and not self.is_initializing()):
+            rate = jnp.exp(a_log)
+            # The least decay is where the step and the rate are largest;
+            # the mean, a state entry at a time ([B, S, I, N] is never made).
+            for name, value in (
+                    ("decay_min", jnp.exp(-jnp.max(
+                        jnp.max(delta, axis=(0, 1)) * jnp.max(rate, axis=1)))),
+                    ("decay_mean", jnp.mean(jax.lax.map(
+                        lambda r: jnp.mean(jnp.exp(-delta * r)), rate.T))),
+                    ("dt_mean", jnp.mean(delta)),
+                    ("state_max", jnp.max(jnp.abs(selective_scan_states(
+                        u, delta, a_log, b, c)))),
+                    ("out_max", jnp.max(jnp.abs(y.astype(jnp.float32))))):
+                self.sow("sscan_stats", name, value)
+        with _scopes.scope(_scopes.SSCAN_GATES):
+            gated = _silu_gated(y, z)
+        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                        name="out_proj")(gated), y
+
+
+class GatedMemory(nn.Module):
+    """The gated memory unit of arXiv:2507.06607: ``(m * silu(x W_1)) W_2``,
+    m ``[B, S, I]`` the memory another layer's scan left (``Mamba1``'s y), x
+    this layer's normed input; ``in_proj [hidden, I]``, ``out_proj [I,
+    hidden]``, no bias.  The whole unit runs under ``hvd.gmu``: XLA fuses the
+    gate product into ``out_proj``'s matmul, and a fusion has one name."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, memory):
+        cfg = self.config
+        with _scopes.scope(_scopes.GMU):
+            gate = nn.Dense(memory.shape[-1], use_bias=False,
+                            dtype=cfg.dtype, name="in_proj")(x)
+            return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                            name="out_proj")(_silu_gated(memory, gate))
+
+
 ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention,
-                   "sparse": SparseAttention}
+                   "sparse": SparseAttention,
+                   "differential": DifferentialAttention}
 
 
 class LlamaLayer(nn.Module):
-    """A mixer and a feed-forward, both residual, each with one RMSNorm:
-    on the sublayer's input (``norm_placement`` ``"pre"``: ``x +
-    Mixer(Norm(x))``) or on its output inside the residual (``"post"``,
-    OLMo 2's: ``x + Norm(Mixer(x))``).  Which mixer and which
+    """A mixer and a feed-forward, both residual, each with one norm
+    (``_stack_norm``: an ``RMSNorm``, or a ``LayerNorm`` where the config
+    states ``layer_norm_eps``): on the sublayer's input (``norm_placement``
+    ``"pre"``: ``x + Mixer(Norm(x))``) or on its output inside the residual
+    (``"post"``, OLMo 2's: ``x + Norm(Mixer(x))``).  Which mixer and which
     feed-forward, the config says of the layer's ``index`` in the stack:
     ``LlamaConfig.is_linear`` (a ``GatedDeltaNet`` as ``"linear"``, else
     ``attention_kind``'s as ``"attn"``, which is told the index too: its
@@ -1689,35 +2020,66 @@ class LlamaLayer(nn.Module):
     (``LlamaConfig.kind_of``) the layer is ONE sublayer behind ONE norm
     (``"norm"``) with one residual add: a ``Mamba2`` (``"mamba"``) or
     ``attention_kind``'s mixer (``"attn"``) under ``hvd.block.attn``, or
-    ``RoutedExperts`` (``"moe"``) under ``hvd.block.ffn``."""
+    ``RoutedExperts`` (``"moe"``) under ``hvd.block.ffn``.
+
+    In a decoder-hybrid-decoder stack (``LlamaConfig.mixer_of``) the layer
+    takes and returns ``shared`` beside x, and this is the ONE place that
+    knows who writes it and who reads it: layer N / 2, the last ``Mamba1``
+    (``"mamba"``), writes its scan's output as ``shared["memory"]``, which
+    every ``GatedMemory`` (``"gmu"``) behind it reads; layer N / 2 + 1, the
+    last to project keys and values, writes them as ``shared["kv"]``, which
+    every later attention layer attends to in place of its own."""
 
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
     index: int = 0
 
     @nn.compact
-    def __call__(self, x, cos, sin):
+    def __call__(self, x, cos, sin, shared=None):
         cfg = self.config
         # The one reading of the rule: every mixer is handed the answer.
         in_place = _reads_in_place(self.attention_fn)
         kind = cfg.kind_of(self.index)
+        role = cfg.mixer_of(self.index)
+        half = cfg.num_layers // 2
+        wrote = {}
         mixer = ffn = None
-        if kind == MAMBA:
+
+        def attention():
+            return ATTENTION_KINDS[cfg.attention_kind](
+                cfg, attention_fn=self.attention_fn, index=self.index,
+                in_place=in_place, name="attn")
+
+        if role == SCAN:
+            def mixer(h):
+                out, y = Mamba1(cfg, in_place=in_place, name="mamba")(h)
+                if self.index == half:
+                    wrote["memory"] = y
+                return out
+        elif role == MEMORY_GATE:
+            mixer = functools.partial(GatedMemory(cfg, name="gmu"),
+                                      memory=shared["memory"])
+        elif role in (SELF_ATTENTION, CROSS_ATTENTION):
+            def mixer(h):
+                out, kv = attention()(
+                    h, cos, sin, shared["kv"] if role == CROSS_ATTENTION
+                    else None)
+                if self.index == half + 1:
+                    wrote["kv"] = kv
+                return out
+        elif kind == MAMBA:
             mixer = Mamba2(cfg, in_place=in_place, name="mamba")
         elif cfg.is_linear(self.index):
             mixer = GatedDeltaNet(cfg, in_place=in_place, name="linear")
         elif kind != EXPERTS:
-            mixer = functools.partial(ATTENTION_KINDS[cfg.attention_kind](
-                cfg, attention_fn=self.attention_fn, index=self.index,
-                in_place=in_place, name="attn"), cos=cos, sin=sin)
+            mixer = functools.partial(attention(), cos=cos, sin=sin)
         if cfg.is_routed(self.index):
             ffn = RoutedExperts(cfg, name="moe")
         elif kind is None:
             ffn = SwiGLU(cfg, name="mlp")
 
         def residual(x, sublayer, norm):
-            norm = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.zero_centered_norm,
-                           name=norm)
+            norm = _stack_norm(cfg, norm)
             if cfg.norm_placement == "pre":
                 return x + sublayer(norm(x))
             return x + norm(sublayer(x))
@@ -1731,7 +2093,7 @@ class LlamaLayer(nn.Module):
         if ffn is not None:
             with _scopes.scope(_scopes.BLOCK_FFN):
                 x = residual(x, ffn, "norm_mlp" if kind is None else "norm")
-        return x
+        return x if role is None else (x, {**shared, **wrote})
 
 
 class LlamaModel(nn.Module):
@@ -1741,7 +2103,8 @@ class LlamaModel(nn.Module):
 
     T > 1 (Ouro / LoopLM; Zhu et al., arXiv:2510.25741).  With E the
     embedding, Stack the ``num_layers`` layers in order, N the final
-    RMSNorm, W the untied head::
+    norm, W the head (its own matrix, or the embedding's transpose where
+    ``tie_word_embeddings``)::
 
         h(0) = E[tokens];   h(t) = N(Stack(h(t-1)))        t = 1..T
         g(t) = h(t) . w_g + b_g                            (float32)
@@ -1784,16 +2147,23 @@ class LlamaModel(nn.Module):
                                  policy=REMAT_POLICIES[cfg.remat])
 
         def one_pass(mdl, x):
-            """Stack(x), its modules made under ``mdl`` by name."""
+            """Stack(x), its modules made under ``mdl`` by name.  What a
+            decoder-hybrid-decoder stack's layers share goes from layer to
+            layer beside x, through ``nn.remat`` as a layer's input and
+            output (kept, like x: a reader's gradient reaches its writer
+            through them)."""
+            shared = {} if cfg.mb_per_layer else None
             for i in range(cfg.num_layers):
-                x = layer_cls(cfg, attention_fn=self.attention_fn, index=i,
-                              name=f"layer_{i}", parent=mdl)(
-                                  x, *tables[cfg.rope_of(i)])
+                layer = layer_cls(cfg, attention_fn=self.attention_fn,
+                                  index=i, name=f"layer_{i}", parent=mdl)
+                if shared is None:
+                    x = layer(x, *tables[cfg.rope_of(i)])
+                else:
+                    x, shared = layer(x, *tables[cfg.rope_of(i)], shared)
             return x
 
         def norm_f(mdl, x):
-            return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.zero_centered_norm,
-                           name="norm_f", parent=mdl)(x)
+            return _stack_norm(cfg, "norm_f", parent=mdl)(x)
 
         if cfg.total_ut_steps == 1:
             x = one_pass(self, x)
@@ -1840,5 +2210,12 @@ class LlamaModel(nn.Module):
         the one output head, which every exit shares."""
         cfg = self.config
         with _scopes.scope(_scopes.HEAD):
+            if cfg.tie_word_embeddings:
+                # logits = hidden E^T: the embedding's own leaf, read again.
+                table = self.get_variable("params", "tok_emb")["embedding"]
+                return jax.lax.dot_general(
+                    hidden.astype(cfg.logits_dtype),
+                    table.astype(cfg.logits_dtype),
+                    (((hidden.ndim - 1,), (1,)), ((), ())))
             return nn.Dense(cfg.vocab_size, use_bias=False,
                             dtype=cfg.logits_dtype, name="lm_head")(hidden)

@@ -219,6 +219,41 @@ TINY.setdefault("ssm_moe_lm", {
     "traffic": {"sequence": 64, "batch_per_chip": 2},
 })
 
+TINY.setdefault("sambay_lm", {
+    # Hidden 64; FOUR layers, the least the placement rule takes (Mamba,
+    # window, Mamba + memory, full + shared k and v: the cross-decoder is
+    # empty, and the suite is at its time limit; all five kinds at eight
+    # layers run in ``tests/test_phi4_flash.py`` and on the chip): scans over
+    # 128 channels of 16 state entries with a rank-4 step, differential
+    # attention of 4 query heads over 2 key-value heads of 32 under a window
+    # of 32.
+    "config": {"hidden_size": 64, "num_hidden_layers": 4,
+               "num_attention_heads": 4, "num_key_value_heads": 2,
+               "head_dim": 32, "intermediate_size": 128, "vocab_size": 512,
+               "sliding_window": 32,
+               # No layer recomputed: a third less to compile here, and
+               # ``tests/test_phi4_flash.py`` compares ``remat`` on and off.
+               "training": {"optimizer": "adamw", "learning_rate": 3e-4,
+                            "warmup_steps": 2000,
+                            "compute_dtype": "bfloat16",
+                            "master_dtype": "float32", "remat": "none"},
+               "assumed": {"d_state": 16, "d_conv": 4, "expand": 2,
+                           "dt_rank": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up, as ``hybrid_moe_lm``.
+                          "loss_must_fall": False,
+                          # bf16 at these widths; float32 through the same
+                          # code agrees to 1e-4 (tests/test_phi4_flash.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    # One row a step: the interpreted kernels make a step slow, and the
+    # traced test needs three steps inside its one-second window.
+    "traffic": {"sequence": 64, "batch_per_chip": 1},
+})
+
 
 # The files that take over 100 s of the driver's command
 # (``/root/TESTS_LAST_RUN.json``: six workers, ``--dist loadfile``), longest
@@ -250,6 +285,7 @@ LONGEST_FIRST = (
     "tests/test_nemotron_h.py",                         # 118 s
     "tests/test_laguna_v5e_compile.py",                 # 116 s
     "tests/test_olmo_hybrid_v5e_compile.py",            # 108 s
+    "tests/test_phi4_flash.py",                         # 105 s (PR 54)
     "tests/test_keye_sparse_v5e_compile.py",            # 105 s
     "tests/test_models.py",                             # 100 s
 )
@@ -343,6 +379,10 @@ _MANIFEST_THEN = {
         ("laguna-s-2.1.train-s8k", "attn_gate_ms"),
     "test_benchmark_gdn_solve.py::test_the_manifests_one_new_entry":
         ("qwen3-next-80b-a3b.train-s8k-b2", "gdn_solve_ms"),
+    # (This one reads the cells at import, from the file as it is: its cut
+    # keeps every cell, the newest named here, and ends the metrics at its.)
+    "test_benchmark_startup_spans.py::test_the_manifests_ten_entries":
+        ("phi-4-mini-flash.train-s8k", "trace_loss_self_ms"),
 }
 
 
@@ -352,7 +392,8 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
     entries`` (PR 32) and its namesakes in ``test_benchmark_sparse.py``
     (PR 34) and ``test_benchmark_hybrid.py`` (PR 38) pin their PR's entries
     (``test_benchmark_window.py``'s, PR 42, the cells its new metrics list;
-    ``test_benchmark_gdn_solve.py``'s, PR 47, its one metric)
+    ``test_benchmark_gdn_solve.py``'s, PR 47, its one metric;
+    ``test_benchmark_startup_spans.py``'s, PR 52, its ten)
     as the LAST of every list of
     ``BENCHMARK.json`` and count the cells, and a later PR may neither
     edit those files nor put its entries anywhere but last.  So each of
