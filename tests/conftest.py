@@ -7,6 +7,7 @@ multi-device mesh simulated on CPU via
 before first JAX use so the suite never needs (or takes) a chip.
 """
 
+import contextlib
 import os
 import sys
 
@@ -17,11 +18,91 @@ sys.path.insert(0, REPO)
 
 N_DEVICES = 8
 
+
+def suite_cache_dir() -> str:
+    """Where the suite keeps JAX's persistent compilation cache: under the
+    temporary directory (never in the tree, which travels to other machines:
+    ``horovod_tpu/common/compile_cache.py``), named by what makes an XLA:CPU
+    executable unfit elsewhere (the user, the library, the machine, the
+    CPU's features) and by nothing that moves: no pid, no clock, no
+    ``tmp_path``.  A directory that moves never hits."""
+    import hashlib
+    import importlib.metadata
+    import platform
+    import tempfile
+
+    features = ""
+    try:
+        with open("/proc/cpuinfo") as cpuinfo:
+            features = next((line for line in cpuinfo
+                             if line.startswith(("flags", "Features"))), "")
+    except OSError:
+        pass
+    fit = hashlib.sha256(features.encode()).hexdigest()[:12]
+    return os.path.join(
+        tempfile.gettempdir(),
+        f"horovod_tpu_tests_jax_cache-u{os.getuid()}"
+        f"-jaxlib{importlib.metadata.version('jaxlib')}"
+        f"-{platform.machine()}-{fit}")
+
+
+# The suite's environment, set before ``import jax`` and in ``os.environ``
+# (not by ``jax.config.update``): the multi-process tests' workers
+# (``tests/*_worker.py``, the launcher's children) inherit the environment
+# and not the config.  A caller's own value of any of them wins.
+#
+# * Eight virtual devices.
+# * XLA:CPU's backend at optimisation level 0, by JAX's own
+#   ``jax_disable_most_optimizations`` ("useful if the cost of optimization
+#   is greater than that of running a less-optimized program").  Three fifths
+#   of a CPU-program test's wall clock was LLVM optimising host code for
+#   programs that then run for milliseconds (ROADMAP.md D9); the suite checks
+#   what a program computes, and how fast an x86 runs it is nobody's concern.
+#   XLA's HLO passes still run, so a compiled program's text is what it was;
+#   the deviceless compiles for the v5e are what they were to the byte.  JAX's
+#   option and not ``--xla_backend_optimization_level=0`` in ``XLA_FLAGS``,
+#   which is read once a process: a step of a tiny model is three times
+#   slower unoptimised, and the tests that TIME a window of steps get their
+#   optimised code back (``_timed_windows_run_optimised_code`` below).  A
+#   caller's ``XLA_FLAGS`` that names the level is left to decide.
+# * JAX's persistent compilation cache, on for every program however small,
+#   in ``suite_cache_dir()``: one directory for the six workers, their
+#   subprocesses and the next run.  JAX's key holds the program, the compile
+#   options (the level among them), ``XLA_FLAGS`` and the library's version,
+#   so a changed program misses and nothing is invalidated by hand; delete the
+#   directory to read a cold run.  Six writers are safe: with no size bound
+#   (``jax/_src/lru_cache.py``, ``max_size == -1``) an entry is one file named
+#   by its key, written once (``put`` returns where the file exists) and never
+#   evicted or rewritten, so two workers that compile one program write the
+#   same bytes; a reader that meets a file half written fails to decompress
+#   it, and ``jax/_src/compiler.py::_cache_read`` turns that into a warning and
+#   a compile, never into another program's executable.  (A size bound,
+#   ``JAX_COMPILATION_CACHE_MAX_SIZE``, would take a file lock around every
+#   read and write and walk the directory at every write.)
+# * XLA's own log lines at FATAL alone.  Every executable read back from the
+#   cache logs two ERROR lines of 330 bytes (``cpu_aot_loader.cc``: the LLVM
+#   tuning features ``+prefer-no-gather`` and ``+prefer-no-scatter``, which
+#   the compile names and the host's list of ISA features does not, on the
+#   machine that compiled it), 10 MB a warm run.  They would fill every failed
+#   test's captured stderr, and they fill the stderr PIPE of a worker that
+#   ``run_workers`` (``tests/test_native_engine.py``) reads only after its
+#   peer's: 64 KiB, a hundred hits, and the pair hangs to its timeout
+#   (``tests/test_fsdp.py::test_fsdp_jax_bitwise_parity``, PR 55).  What XLA
+#   refuses still arrives as the exception's text.  A caller who sets the
+#   level lower gets the lines back, and sets
+#   ``JAX_ENABLE_COMPILATION_CACHE=0`` beside it.
 flags = os.environ.get("XLA_FLAGS", "")
+if "xla_backend_optimization_level" not in flags:
+    os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={N_DEVICES}"
     ).strip()
+os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = suite_cache_dir()
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 
 import jax  # noqa: E402
 
@@ -112,7 +193,7 @@ TINY.setdefault("hybrid_lm", {
                           "reference": {"parameters": "initial",
                                         "loss_abs": 0.02,
                                         "grad_rel": 0.2}}},
-    "traffic": {"sequence": 256, "batch_per_chip": 2},
+    "traffic": {"sequence": 128, "batch_per_chip": 2},
 })
 
 TINY.setdefault("window_moe_lm", {
@@ -149,7 +230,7 @@ TINY.setdefault("window_moe_lm", {
                           "reference": {"parameters": "initial",
                                         "loss_abs": 0.02,
                                         "grad_rel": 0.1}}},
-    "traffic": {"sequence": 256, "batch_per_chip": 2},
+    "traffic": {"sequence": 128, "batch_per_chip": 2},
 })
 
 TINY.setdefault("hybrid_moe_lm", {
@@ -182,7 +263,7 @@ TINY.setdefault("hybrid_moe_lm", {
                           "reference": {"parameters": "initial",
                                         "loss_abs": 0.02,
                                         "grad_rel": 0.2}}},
-    "traffic": {"sequence": 256, "batch_per_chip": 2},
+    "traffic": {"sequence": 128, "batch_per_chip": 1},
 })
 
 TINY.setdefault("ssm_moe_lm", {
@@ -431,6 +512,114 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
     monkeypatch.setattr(manifest, "cell",
                         lambda workload, listed=None: real_cell(
                             workload, listed or then))
+
+
+class CompileCounts:
+    """What share of a run was compilation, from JAX's own events (the ones
+    ``horovod_tpu/common/compile_cache.py``'s log listens to): requests to
+    the persistent cache, its hits, and the seconds inside the backend,
+    compiling a program or reading it back.  Of this process alone: what a
+    test's subprocesses compile is not in it."""
+
+    REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+    HIT = "/jax/compilation_cache/cache_hits"
+    BACKEND = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.requests = self.hits = self.programs = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+
+    def _on_event(self, event, **_):
+        self.requests += event == self.REQUEST
+        self.hits += event == self.HIT
+
+    def _on_duration(self, event, seconds, **_):
+        if event == self.BACKEND:
+            self.programs += 1
+            self.seconds += seconds
+
+    def row(self) -> dict:
+        return {"requests": self.requests, "hits": self.hits,
+                "programs": self.programs, "seconds": round(self.seconds, 1)}
+
+
+_COMPILE_COUNTS = CompileCounts()
+_WORKERS_COUNTS = {}            # on xdist's controller: worker id -> row
+
+
+def pytest_sessionfinish(session):
+    # An xdist worker has no terminal: its row travels to the controller.
+    if hasattr(session.config, "workeroutput"):
+        session.config.workeroutput["compile_counts"] = _COMPILE_COUNTS.row()
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_testnodedown(node, error):
+    row = getattr(node, "workeroutput", {}).get("compile_counts")
+    if row:
+        _WORKERS_COUNTS[node.gateway.id] = row
+
+
+def pytest_terminal_summary(terminalreporter):
+    rows = dict(sorted(_WORKERS_COUNTS.items())) or {
+        "main": _COMPILE_COUNTS.row()}
+    if len(rows) > 1:
+        rows["all"] = {k: round(sum(row[k] for row in rows.values()), 1)
+                       for k in next(iter(rows.values()))}
+    terminalreporter.section("XLA compilation in the test processes")
+    terminalreporter.line(
+        f"persistent cache at {jax.config.jax_compilation_cache_dir}; "
+        f"jax_disable_most_optimizations="
+        f"{jax.config.read('jax_disable_most_optimizations')}, "
+        f"XLA_FLAGS={os.environ['XLA_FLAGS']}")
+    for worker, row in rows.items():
+        share = row["hits"] / row["requests"] if row["requests"] else 0.0
+        terminalreporter.line(
+            f"{worker}: {row['programs']} programs, {row['seconds']} s in "
+            f"the backend (compiling or reading back); persistent cache "
+            f"{row['hits']} hits / {row['requests']} requests = {share:.3f}")
+
+
+@contextlib.contextmanager
+def optimised():
+    """What is compiled inside is compiled as a user's program would be, not
+    at the suite's cheap level."""
+    cheap = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_disable_most_optimizations", cheap)
+
+
+@pytest.fixture
+def optimised_code():
+    """For the test that is about the optimised program: one that runs its
+    programs for longer than they take to compile, or that states two
+    programs' results equal to the last bit (unoptimised, a loop's body and
+    the same body beside the loop round differently).  A file asks for it by
+    ``pytestmark = pytest.mark.usefixtures("optimised_code")``."""
+    with optimised():
+        yield
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _timed_windows_run_optimised_code():
+    """The one place where the suite cares how fast an x86 runs a program:
+    ``benchmark/run.py::run`` counts the steps that complete inside a window
+    of one second and refuses under three.  Unoptimised, a tiny cell's step is
+    2.4 to 4.5 times slower (PR 55), and under the suite's load some cells
+    complete three or four steps as it is.  So whatever ``run`` compiles it
+    compiles under ``optimised``; everything else in the process keeps the
+    suite's level."""
+    from benchmark import run as harness
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(harness, "run", optimised()(harness.run))
+        yield
 
 
 @pytest.fixture(scope="session")

@@ -3,11 +3,14 @@ smoke refuses to run without a TPU, the compile cache can be placed from
 outside, state placed on the mesh compiles the step once, and a native
 library is trusted only with a stamp that names its sources.  Since PR 51
 also the order in which the suite's files start (``tests/conftest.py::
-LONGEST_FIRST``)."""
+LONGEST_FIRST``), and since PR 55 the environment its XLA:CPU programs are
+compiled in."""
 
+import json
 import os
 import subprocess
 import sys
+import tempfile
 import types
 
 import jax
@@ -161,7 +164,8 @@ def test_native_lib_rebuilt_when_stamp_mismatches(monkeypatch, tmp_path):
     assert lib.read_text() == "v2\n"
 
 
-# -- the order in which the suite's files start (PR 51) -----------------------
+# -- the suite's own harness: the order in which its files start (PR 51), and
+# -- the environment its programs are compiled in (PR 55) ---------------------
 
 @pytest.fixture
 def harness(request):
@@ -169,6 +173,88 @@ def harness(request):
     ``conftest`` of its own, so the module's bare name may be either)."""
     return request.config.pluginmanager.get_plugin(
         os.path.join(REPO, "tests", "conftest.py"))
+
+
+_SUITE_ENVIRONMENT = ("XLA_FLAGS", "JAX_DISABLE_MOST_OPTIMIZATIONS",
+                      "TF_CPP_MIN_LOG_LEVEL", "JAX_COMPILATION_CACHE_DIR",
+                      "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                      "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES")
+
+
+def _environment_after_conftest(cwd, **callers):
+    """What a fresh interpreter started in ``cwd`` with the caller's
+    variables ``callers``, and none of the suite's, holds once it has
+    imported ``tests/conftest.py``."""
+    env = {k: v for k, v in os.environ.items() if k not in _SUITE_ENVIRONMENT}
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import json, os, sys\n"
+         f"sys.path.insert(0, {os.path.join(REPO, 'tests')!r})\n"
+         "import conftest\n"
+         f"print(json.dumps({{k: os.environ.get(k) "
+         f"for k in {_SUITE_ENVIRONMENT!r}}}))"],
+        env=dict(env, JAX_PLATFORMS="cpu", **callers), cwd=cwd,
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("callers", ["nothing", "level", "directory",
+                                     "a_timed_window"])
+def test_the_suites_environment(harness, tmp_path, callers):
+    """The suite compiles its XLA:CPU programs at backend level 0 (JAX's
+    ``jax_disable_most_optimizations``) and keeps them in ONE directory
+    outside the checkout that does not move: the same string from any working
+    directory, in any process, at any time.  A caller's own level and
+    directory win, and so does a test that times a window of steps
+    (``benchmark/run.py::run``): its programs are optimised."""
+    if callers == "a_timed_window":
+        from benchmark import run
+
+        def level_is_cheap():
+            return jax.config.read("jax_disable_most_optimizations")
+
+        assert level_is_cheap() == (
+            os.environ.get("JAX_DISABLE_MOST_OPTIMIZATIONS") == "1")
+        with harness.optimised():
+            assert level_is_cheap() is False
+        assert level_is_cheap() == (
+            os.environ.get("JAX_DISABLE_MOST_OPTIMIZATIONS") == "1")
+        # The harness's entry is wrapped in it for the session, and in
+        # nothing else.
+        assert run.run.__wrapped__.__code__.co_filename == os.path.join(
+            REPO, "benchmark", "run.py")
+        return
+    level, devices = ("--xla_backend_optimization_level=",
+                      "--xla_force_host_platform_device_count=8")
+    if callers == "nothing":
+        one, other = (_environment_after_conftest(cwd)
+                      for cwd in (tmp_path, REPO))
+        assert one == other                 # two pids, two times, two places
+        cache = one["JAX_COMPILATION_CACHE_DIR"]
+        assert cache == harness.suite_cache_dir()
+        assert os.path.dirname(cache) == tempfile.gettempdir()
+        assert not os.path.realpath(cache).startswith(
+            os.path.realpath(REPO) + os.sep)
+        assert one["XLA_FLAGS"] == devices
+        assert one["JAX_DISABLE_MOST_OPTIMIZATIONS"] == "1"    # level 0
+        # Every program goes in, however small (JAX's defaults keep out
+        # what compiles in under a second: most of the suite's).
+        assert one["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
+        assert one["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] == "-1"
+        # And a hit's two log lines fill no worker's unread pipe.
+        assert one["TF_CPP_MIN_LOG_LEVEL"] == "3"
+    elif callers == "level":
+        got = _environment_after_conftest(tmp_path, XLA_FLAGS=level + "2")
+        assert got["XLA_FLAGS"].split() == [level + "2", devices]
+        assert got["JAX_DISABLE_MOST_OPTIMIZATIONS"] is None
+        assert got["JAX_COMPILATION_CACHE_DIR"] == harness.suite_cache_dir()
+    else:
+        mine = str(tmp_path / "mine")
+        got = _environment_after_conftest(
+            tmp_path, JAX_COMPILATION_CACHE_DIR=mine)
+        assert got["JAX_COMPILATION_CACHE_DIR"] == mine
+        assert got["JAX_DISABLE_MOST_OPTIMIZATIONS"] == "1"
 
 
 def test_every_row_of_longest_first_is_a_file_that_collects(harness):

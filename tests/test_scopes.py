@@ -8,6 +8,7 @@ import functools
 import os
 import re
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+import horovod_tpu
 import horovod_tpu.jax as hvd
 from horovod_tpu.common import compile_cache, scopes
 from horovod_tpu.ops.flash_attention import flash_attention
@@ -427,25 +429,42 @@ def _lowered(loss, has_aux=False, stats=()):
     return step.lower(params, opt.init(params), *stats, _batch())
 
 
+def _now():
+    """This moment on the axis of the log's ``began``."""
+    return time.perf_counter() - horovod_tpu.IMPORT_BEGAN
+
+
+def _spans_since(mark, program=None):
+    """The spans that began after ``mark``.  By their time and not by their
+    place in the list: the log keeps its newest records and spans, so on a
+    worker that compiled a few thousand programs before this file the list
+    loses old entries at its front while it gains these at its end (PR 55:
+    the files' order moved, and ``[was:]`` was empty)."""
+    return [s for s in hvd.compile_spans(program) if s["began"] >= mark]
+
+
 def test_the_steps_spans_are_the_steps_and_not_another_programs():
-    def make_state(x):
+    # A name no program of the repository has: ``benchmark/run.py`` jits a
+    # ``make_state`` of its own, whose spans a worker that ran a cell before
+    # this file has in the process's log (PR 55: the files' order moved).
+    def scopes_test_state(x):
         with scopes.scope(scopes.HEAD):
             return x + 1
 
-    was = len(hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM))
-    assert hvd.compile_spans("make_state") == []
-    jax.jit(make_state).lower(jnp.zeros(()))
-    state, = hvd.compile_spans("make_state")
+    began = _now()
+    assert hvd.compile_spans("scopes_test_state") == []
+    jax.jit(scopes_test_state).lower(jnp.zeros(()))
+    state, = hvd.compile_spans("scopes_test_state")
     assert state["path"] == state["name"] == scopes.HEAD
-    assert len(hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)) == was
+    assert _spans_since(began, hvd.TRAIN_STEP_PROGRAM) == []
 
     _lowered(_loss)
-    new = hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)[was:]
+    new = _spans_since(began, hvd.TRAIN_STEP_PROGRAM)
     assert new[0]["path"] == scopes.LOSS
     assert {s["path"] for s in new} == {
         scopes.LOSS, scopes.allreduce_scope("data"), scopes.OPTIMIZER,
         scopes.APPLY}
-    assert hvd.compile_spans("make_state") == [state]
+    assert hvd.compile_spans("scopes_test_state") == [state]
     # Inside the step's ``trace`` record, on the one axis.
     trace = [r for r in hvd.compile_log(hvd.TRAIN_STEP_PROGRAM)
              if r["event"] == "trace"][-1]
@@ -462,13 +481,13 @@ def test_every_name_the_tiny_steps_enter_is_a_span():
     the loss calls them and backward at its top (differentiation replays
     jaxprs, and runs the backward rules' Python); each Mosaic call's bind
     inside its scope's span."""
-    was = len(hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM))
+    began = _now()
     # The rotation is an inlined ``jit`` that JAX traces once a shape and
     # process: at a shape no other test rotates, its Python runs here.
     _lowered(functools.partial(_loss_rope, heads=4))
     stats = {"mean": jnp.zeros(()), "steps": jnp.zeros((), jnp.int32)}
     _lowered(_loss_aux, has_aux=True, stats=(stats,))
-    spans = hvd.compile_spans(hvd.TRAIN_STEP_PROGRAM)[was:]
+    spans = _spans_since(began, hvd.TRAIN_STEP_PROGRAM)
     names = {s["name"] for s in spans}
     assert set(TABLE) - {scopes.ALLREDUCE} <= names
     assert scopes.allreduce_scope("data") in names
@@ -507,11 +526,11 @@ def test_the_lowered_step_is_the_same_without_the_logs_span(monkeypatch):
     for span in (compile_cache.span,
                  lambda name, **flags: contextlib.nullcontext()):
         monkeypatch.setattr(compile_cache, "span", span)
-        was = len(hvd.compile_spans())
+        began = _now()
         # With the locations, which hold the op_names; from one line of
         # this file, which they hold too.
         texts.append(_lowered(_loss_rope).as_text(debug_info=True))
-        counts.append(len(hvd.compile_spans()) - was)
+        counts.append(len(_spans_since(began)))
     assert scopes.ROPE in texts[0] and scopes.MOSAIC not in texts[0]
     assert texts[0] == texts[1]
     assert counts[0] > 0 and counts[1] == 0
