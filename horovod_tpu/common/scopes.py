@@ -193,6 +193,16 @@ scope                 what falls under it
                       Mosaic pair or the ``jnp`` body's chunks, forward, run
                       again under recomputation and backward, and the sums
                       over the backward call's partial gradients
+``hvd.lconv.proj``    a double-gated short-convolution layer's
+                      (``models/llama.py::GatedShortConv``) two projections:
+                      ``in_proj [hidden, 3 hidden]`` to the gates B, C and
+                      the filter's input z, and ``out_proj``; and their
+                      gradient products
+``hvd.lconv.conv``    the same layer's gate, filter and gate, ``C (taps * (B
+                      z))``: ``ops/short_conv.py``'s gated Mosaic calls where
+                      the model's ``attention_fn`` reads its operands in
+                      place, else its ``jnp`` body; forward, recomputed and
+                      backward, Mosaic calls and XLA operations alike
 ``hvd.gmu``           a gated memory unit whole (``models/llama.py::
                       GatedMemory``): ``x W_1``, the gate on the shared
                       memory ``m silu(x W_1)``, ``W_2``, and their
@@ -212,14 +222,14 @@ scope                 what falls under it
                       (``models/llama.py::LlamaLayer``): ``norm_attn``,
                       the mixer (``LlamaAttention``, ``LatentAttention``,
                       ``SparseAttention``, ``DifferentialAttention``,
-                      ``GatedDeltaNet``, ``Mamba2``, ``Mamba1`` or
-                      ``GatedMemory``:
+                      ``GatedDeltaNet``, ``Mamba2``, ``Mamba1``,
+                      ``GatedShortConv`` or ``GatedMemory``:
                       projections, QK-norm, rotation, the ``attention_fn``
                       call or the rule, ``wo``) and the residual add.
                       ``hvd.flash.*``, ``hvd.rope``, ``hvd.attn.*``,
                       ``hvd.mla.latent``, ``hvd.sparse.*``, ``hvd.gdn.*``,
-                      ``hvd.ssd.*``, ``hvd.sscan.*`` and ``hvd.gmu`` nest
-                      inside it.  In a stack whose
+                      ``hvd.ssd.*``, ``hvd.sscan.*``, ``hvd.lconv.*`` and
+                      ``hvd.gmu`` nest inside it.  In a stack whose
                       layers are ONE sublayer
                       (``LlamaConfig.hybrid_override_pattern``) a mixer
                       layer is this block alone, with the layer's one norm
@@ -314,7 +324,8 @@ __all__ = [
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
     "SSD_CONV", "SSD_GATES", "SSD_SCAN",
-    "SSCAN_CONV", "SSCAN_GATES", "SSCAN_SCAN", "GMU", "ATTN_DIFF",
+    "SSCAN_CONV", "SSCAN_GATES", "SSCAN_SCAN", "LCONV_PROJ", "LCONV_CONV",
+    "GMU", "ATTN_DIFF",
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
     "RAGGED_DOT_PREFIX", "REMATTED", "FLASH_OUT_NAME", "FLASH_LSE_NAME",
     "SPARSE_SELECTED_NAME", "SPARSE_INDEX_LOSS_NAME",
@@ -359,6 +370,8 @@ SSD_SCAN = "hvd.ssd.scan"
 SSCAN_CONV = "hvd.sscan.conv"
 SSCAN_GATES = "hvd.sscan.gates"
 SSCAN_SCAN = "hvd.sscan.scan"
+LCONV_PROJ = "hvd.lconv.proj"
+LCONV_CONV = "hvd.lconv.conv"
 GMU = "hvd.gmu"
 ATTN_DIFF = "hvd.attn.diff"
 BLOCK_ATTN = "hvd.block.attn"

@@ -88,7 +88,8 @@ REMAT_POLICIES = {
 # load moves and no gradient does (``RoutedExperts``).
 ROUTER_STATE = "router_state"
 
-LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention")
+LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention",
+               "conv")
 # ``hybrid_override_pattern``'s characters, as Nemotron-H publishes them: a
 # Mamba-2 layer, a routed feed-forward layer, a softmax attention layer.
 MAMBA, EXPERTS, ATTENTION = PATTERN_KINDS = ("M", "E", "*")
@@ -283,6 +284,17 @@ class LlamaConfig:
     width.  Generation, the serve plane and the pipelined step refuse a
     pattern, state-space layers and a bias-corrected router by name.
 
+    ``"conv"`` in ``layer_types`` (LFM2's published name) is a
+    ``GatedShortConv``: the layer's mixer is a causal depthwise convolution
+    of ``conv_L_cache`` taps (the published key: the filter's length, which
+    is also what a decode cache would hold) between two multiplicative
+    gates, ``C (taps * (B z))`` with B, C and z the thirds of one ``[hidden,
+    3 hidden]`` projection; no softmax, no recurrence, no activation
+    (``ops/short_conv.py``'s gated pass).  ``conv_bias`` (published, false)
+    would give the two projections and the filter a bias: true is refused
+    until a configuration has it.  Generation, the serve plane and the
+    pipelined step refuse a ``"conv"`` layer by name.
+
     ``mb_per_layer`` = 2 (the published key of the decoder-hybrid-decoder
     stack, SambaY, arXiv:2507.06607) places a mixer by the layer's index i
     among N = ``num_layers`` (N % 4 = 0; ``mixer_of``): even i a ``Mamba1``
@@ -342,6 +354,8 @@ class LlamaConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False
+    conv_L_cache: int = 3         # taps of a "conv" layer's filter
+    conv_bias: bool = False
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -451,6 +465,13 @@ class LlamaConfig:
                     "linear attention needs linear_num_key_heads, "
                     "linear_key_head_dim, linear_value_head_dim and "
                     "linear_num_value_heads, a multiple of the key heads")
+        if self.conv_bias:
+            raise ValueError(
+                "conv_bias is True: a \"conv\" layer's projections and filter "
+                "are built without a bias (LFM2 publishes false); not built")
+        if self.has_conv_layers and self.conv_L_cache < 1:
+            raise ValueError(f"conv_L_cache is {self.conv_L_cache}: a "
+                             f"\"conv\" layer's filter has at least one tap")
         sliding = ("sliding_attention" in (self.layer_types or ())
                    or bool(self.mb_per_layer))
         if sliding != (self.sliding_window is not None) or (
@@ -523,7 +544,7 @@ class LlamaConfig:
         if self.rope_parameters is not None:
             kinds = {kind for kind, _ in self.rope_parameters}
             used = set(self.layer_types or ("full_attention",)) - {
-                "linear_attention"}
+                "linear_attention", "conv"}
             if not used <= kinds <= set(LAYER_TYPES) or any(
                     not 0.0 < r.partial_rotary_factor <= 1.0
                     or int(r.partial_rotary_factor * self.head_dim) % 2
@@ -585,6 +606,14 @@ class LlamaConfig:
         """Whether ``layer``'s mixer is the gated delta rule."""
         return (self.layer_types is not None
                 and self.layer_types[layer] == "linear_attention")
+
+    @property
+    def has_conv_layers(self) -> bool:
+        return "conv" in (self.layer_types or ())
+
+    def is_conv(self, layer: int) -> bool:
+        """Whether ``layer``'s mixer is the double-gated short convolution."""
+        return self.layer_type(layer) == "conv"
 
     def layer_type(self, layer: int) -> str:
         if self.mb_per_layer:
@@ -665,6 +694,14 @@ class LlamaConfig:
                 f"{who} has no path for a head tied to the embedding "
                 f"(tie_word_embeddings=True): it keeps an lm_head of its "
                 f"own [{self.hidden_size}, {self.vocab_size}]; not built")
+        if self.has_conv_layers:
+            raise NotImplementedError(
+                f"{who} has no path for double-gated short-convolution "
+                f"layers (layer_types holds 'conv'): such a layer has no keys "
+                f"or values, its cache would hold the filter's last "
+                f"{self.conv_L_cache - 1} gated inputs B z, "
+                f"{self.hidden_size} wide, and a decode step would shift "
+                f"them in place; not built")
         pattern = self.hybrid_override_pattern
         if pattern is not None and MAMBA in pattern:
             raise NotImplementedError(
@@ -1784,6 +1821,44 @@ class GatedDeltaNet(nn.Module):
                         name="wo")(o)
 
 
+class GatedShortConv(nn.Module):
+    """The double-gated short convolution of a ``"conv"`` layer (LFM2; Liquid
+    AI's technical report and the published ``lfm2`` modelling code).  With x
+    the block's normed input, H = ``hidden_size`` and ``*`` the causal
+    depthwise convolution of ``conv_L_cache`` taps, one filter a channel and
+    zero history before position 0 of a row::
+
+        [B | C | z] = x W_in            three thirds of H, in that order
+        y = C (taps * (B z))            the two gates are the non-linearity
+        out = y W_out
+
+    No activation, no norm, no bias.  The gates and the filter are
+    ``ops/short_conv.py::convolved``'s gated form: one Mosaic pass each way
+    over ``in_proj``'s output where it lies, where the model's
+    ``attention_fn`` reads its operands in place, else its ``jnp`` body
+    (under ``hvd.lconv.conv``; the two projections under
+    ``hvd.lconv.proj``).  Parameters: ``in_proj [H, 3 H]``, ``conv_w [K,
+    H]``, ``out_proj [H, H]``."""
+
+    config: LlamaConfig
+    in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        hidden = cfg.hidden_size
+        with _scopes.scope(_scopes.LCONV_PROJ):
+            bcz = nn.Dense(3 * hidden, use_bias=False, dtype=cfg.dtype,
+                           name="in_proj")(x)
+        with _scopes.scope(_scopes.LCONV_CONV):
+            y = convolved(bcz, self.param("conv_w", _conv_taps_init,
+                                          (cfg.conv_L_cache, hidden)),
+                          1, None, self.in_place, gated=True)
+        with _scopes.scope(_scopes.LCONV_PROJ):
+            return nn.Dense(hidden, use_bias=False, dtype=cfg.dtype,
+                            name="out_proj")(y)
+
+
 def _mamba_a_log_init(key, shape, dtype=jnp.float32):
     """``log A`` with A uniform in (1, 16): Mamba-2's ``A_init_range``."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
@@ -2010,9 +2085,10 @@ class LlamaLayer(nn.Module):
     ``"pre"``: ``x + Mixer(Norm(x))``) or on its output inside the residual
     (``"post"``, OLMo 2's: ``x + Norm(Mixer(x))``).  Which mixer and which
     feed-forward, the config says of the layer's ``index`` in the stack:
-    ``LlamaConfig.is_linear`` (a ``GatedDeltaNet`` as ``"linear"``, else
+    ``LlamaConfig.is_linear`` (a ``GatedDeltaNet`` as ``"linear"``),
+    ``LlamaConfig.is_conv`` (a ``GatedShortConv`` as ``"conv"``), else
     ``attention_kind``'s as ``"attn"``, which is told the index too: its
-    head count, window and gate may differ by layer) and
+    head count, window and gate may differ by layer, and
     ``LlamaConfig.is_routed``.  ``cos``, ``sin`` are the tables of this
     layer's type.
 
@@ -2071,6 +2147,8 @@ class LlamaLayer(nn.Module):
             mixer = Mamba2(cfg, in_place=in_place, name="mamba")
         elif cfg.is_linear(self.index):
             mixer = GatedDeltaNet(cfg, in_place=in_place, name="linear")
+        elif cfg.is_conv(self.index):
+            mixer = GatedShortConv(cfg, in_place=in_place, name="conv")
         elif kind != EXPERTS:
             mixer = functools.partial(attention(), cos=cos, sin=sin)
         if cfg.is_routed(self.index):

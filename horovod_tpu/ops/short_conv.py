@@ -1,5 +1,6 @@
 """A linear-attention or state-space layer's short convolution, its bias,
-its SiLU and its heads' L2 norm as one Mosaic pass forward and one backward.
+its SiLU and its heads' L2 norm (or a double-gated layer's two gates around
+it) as one Mosaic pass forward and one backward.
 
 ``models/llama.py::GatedDeltaNet`` sends q, k and v through a causal
 depthwise convolution of a few taps, a SiLU and (q and k) a per-head L2 norm;
@@ -30,6 +31,19 @@ gradient eight rows more in the taps' output block.  Without one the two
 calls have no operand for it and no row for its gradient: they lower to
 what they lowered to before the pass took a bias (a linear layer runs nine
 such calls a step).
+
+**Gates or none.**  ``models/llama.py::GatedShortConv`` (LFM2's ``"conv"``
+layer) has no SiLU and no norm: one projection gives B, C and z, and the
+mixer is ``C (taps * (B z))``, two multiplicative gates around a filter of 3
+taps.  ``gated`` is what the trace sees (static, like a bias or a norm): y
+is then ``[B, S, 3 C]``, the projection's output where it lies, a block of
+rows holds all three thirds (each starts at a lane tile), and the same two
+walks form ``B z`` where they read y, leave the SiLU out and multiply by C;
+backward ``dc = g C`` takes the place of the SiLU's derivative, and the one
+result beside the taps' partial sums is the ``[B, S, 3 C]`` cotangent, ``dB
+= dp z``, ``dC = g c`` and ``dz = dp B`` each where its third is (dp the
+filter's transpose on dc).  The calls' operand counts are a call's without
+gates, and a call without gates lowers to what it lowered to before.
 
 **Channels where they lie.**  ``Mamba2``'s x, B and C are a run of channels
 in the middle of ``in_proj``'s output.  Cut out for the call they are a
@@ -136,15 +150,18 @@ def _pick_rows(s: int, width: int) -> int:
     return 0
 
 
-def _why_not(shape, taps_shape, heads: int, first=None):
+def _why_not(shape, taps_shape, heads: int, first=None, gated=False):
     """None where ``short_conv`` takes ``y`` of ``shape [B, S, channels]``
     with ``taps_shape [K, channels]`` (or, with ``first``, the channels
-    ``first : first + taps_shape[1]`` of a wider y), else the reason it
+    ``first : first + taps_shape[1]`` of a wider y; with ``gated`` three
+    times the channels, each third from a lane tile on), else the reason it
     does not."""
     width = taps_shape[1]
     if len(shape) != 3 or width % heads or (
-            first is None and width != shape[2]):
+            first is None and (3 * width if gated else width) != shape[2]):
         return _NOT_WHOLE_HEADS
+    if gated and width % _LANES:
+        return _NOT_AT_A_TILE
     if first is not None and (first % _LANES or first + width > shape[2]):
         return _NOT_AT_A_TILE
     if taps_shape[0] - 1 > _TILE:
@@ -194,6 +211,22 @@ def _group(j, rows: int):
 
 def _rows_of(ref, j, lanes):
     return ref[_group(j, ref.shape[0]), lanes].astype(jnp.float32)
+
+
+def _moved(lanes, by: int):
+    """A chunk's ``lanes`` (``_each_chunk``'s: a slice, or a ``pl.ds`` from a
+    lane tile's multiple) ``by`` lanes on, ``by`` whole lane tiles."""
+    if isinstance(lanes, slice):
+        return slice(lanes.start + by, lanes.stop + by)
+    return pl.ds(pl.multiple_of(lanes.start + by, _LANES), lanes.size)
+
+
+def _filter_reads(read, gated: bool, width: int):
+    """What the filter reads at a chunk's lanes, through ``read(lanes)`` of y:
+    y itself, or with gates ``B z``, y's first third times its last."""
+    if not gated:
+        return read
+    return lambda lanes: read(lanes) * read(_moved(lanes, 2 * width))
 
 
 def _shifted(prev, cur, k):
@@ -286,16 +319,17 @@ def _for_groups(n_groups: int, group) -> None:
     jax.lax.fori_loop(0, n_groups, step, 0)
 
 
-def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads, biased):
+def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads, biased, gated):
     # y_ref, o_ref: [rows, C]; before_ref: the _HALO rows before the block;
     # taps_ref: [K, C] float32; with a bias (biased) bias_ref [1, C] float32
     # behind it.  With a norm (heads is not None): scale_ref
     # [1, 1] in SMEM (an operand, so that q's call and k's are one program),
     # to_head_ref [C, Kp] and to_lanes_ref [Kp, C], the heads' 0/1 indicator
     # (_indicators) and its transpose; scratch s_ref [rows, C] float32 and
-    # p_ref [3, rows, C] bf16.
-    rows, width = y_ref.shape
-    k = taps_ref.shape[0]
+    # p_ref [3, rows, C] bf16.  With gates (gated) y_ref and before_ref are
+    # [.., 3 C], the thirds B, C and z: o = C conv(B z), no SiLU.
+    rows = y_ref.shape[0]
+    k, width = taps_ref.shape
     normed = heads is not None
     bias_ref, rest = _bias_first(rest, biased)
     if normed:
@@ -306,9 +340,15 @@ def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads, biased):
 
     def chunk(lanes, _):
         def group(j):
-            prev = _before(y_ref, before_ref, j, lanes, at_start)
-            cur = _rows_of(y_ref, j, lanes)
+            prev = _filter_reads(lambda at: _before(
+                y_ref, before_ref, j, at, at_start), gated, width)(lanes)
+            cur = _filter_reads(lambda at: _rows_of(y_ref, j, at), gated,
+                                width)(lanes)
             c = _conv(_shifted(prev, cur, k), taps_ref, bias_ref, lanes)
+            if gated:
+                o_ref[_group(j, rows), lanes] = (_rows_of(
+                    y_ref, j, _moved(lanes, width)) * c).astype(o_ref.dtype)
+                return
             s = c * _sigmoid(c)
             here = _group(j, rows)
             if not normed:
@@ -329,16 +369,18 @@ def _fwd_kernel(y_ref, before_ref, taps_ref, *rest, heads, biased):
 
 
 def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
-                *rest, heads, biased):
+                *rest, heads, biased, gated):
     # As _fwd_kernel, with the _HALO rows after the block of y and of the
     # cotangent g; dy_ref: [rows, C]; dtaps_ref: [K * _TILE, C] float32,
     # eight partial sums a tap, the same block for every step of a batch
     # row; with a bias eight rows more behind them, its gradient's partial
     # sums.  With a norm, scratch over the block's rows AND the group after
     # them: p_ref [6, n, C] bf16 (the pieces of s s and of g s), norm_ref and
-    # back_ref [n, C] float32 (the two sums on their way back).
-    rows, width = y_ref.shape
-    k = taps_ref.shape[0]
+    # back_ref [n, C] float32 (the two sums on their way back).  With gates
+    # y_ref, its two halos and dy_ref are [.., 3 C], the thirds B, C and z
+    # and their cotangents; g_ref is [rows, C].
+    rows = y_ref.shape[0]
+    k, width = taps_ref.shape
     groups = rows // _GROUP    # and then the rows after
     normed = heads is not None
     bias_ref, rest = _bias_first(rest, biased)
@@ -359,12 +401,16 @@ def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
         of g; ``_AFTER`` is the ``_HALO`` rows after the block, from where no
         cotangent comes back at a batch row's end."""
         if j is _AFTER:
-            cur = after_ref[:, lanes].astype(jnp.float32)
+            cur = _filter_reads(lambda at: after_ref[:, at].astype(
+                jnp.float32), gated, width)(lanes)
             g = jnp.where(at_end, 0.0,
                           g_after_ref[:, lanes].astype(jnp.float32))
-            return cur, _rows_of(y_ref, groups - 1, lanes)[_TILE:], g
-        return (_rows_of(y_ref, j, lanes),
-                _before(y_ref, before_ref, j, lanes, at_start),
+            return cur, _filter_reads(lambda at: _rows_of(
+                y_ref, groups - 1, at), gated, width)(lanes)[_TILE:], g
+        return (_filter_reads(lambda at: _rows_of(y_ref, j, at), gated,
+                              width)(lanes),
+                _filter_reads(lambda at: _before(
+                    y_ref, before_ref, j, at, at_start), gated, width)(lanes),
                 _rows_of(g_ref, j, lanes))
 
     if normed:
@@ -394,6 +440,12 @@ def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
             cur, prev, g = operands(j, lanes)
             seen = _shifted(prev, cur, k)
             c = _conv(seen, taps_ref, bias_ref, lanes)
+            if gated:
+                # y = C c: dc = g C, and dC = g c beside it.
+                of_gate = _moved(lanes, width)
+                gate = (after_ref[:, of_gate].astype(jnp.float32)
+                        if j is _AFTER else _rows_of(y_ref, j, of_gate))
+                return g * gate, seen, g * c
             sig = _sigmoid(c)
             if normed:
                 here = _group(j, rows)
@@ -401,20 +453,28 @@ def _bwd_kernel(y_ref, before_ref, after_ref, g_ref, g_after_ref, taps_ref,
                                         - c * sig * back_ref[here, lanes])
             else:
                 ds = g
-            return ds * sig * (1.0 + c * (1.0 - sig)), seen
+            return ds * sig * (1.0 + c * (1.0 - sig)), seen, None
 
         def group(j, carry):
             # From the block's last group to its first: dc of the rows
             # behind a group is the carry.  dy[t] = sum_a taps[K-1-a] dc[t+a].
             behind, sums = carry
-            dc, seen = dc_of(j)
+            dc, seen, d_gate = dc_of(j)
             ext = jnp.concatenate([dc, behind], axis=0)
             dy = dc * taps_ref[k - 1:k, lanes]
             for ahead in range(1, k):
                 # roll(x, n - a)[t] = x[t + a]: the row a behind.
                 dy = dy + pltpu.roll(ext, ext.shape[0] - ahead, 0)[
                     :dc.shape[0]] * taps_ref[k - 1 - ahead:k - ahead, lanes]
-            dy_ref[_group(j, rows), lanes] = dy.astype(dy_ref.dtype)
+            if gated:
+                # dy is d(B z): dB = dy z, dz = dy B, each where its third is.
+                here, of_z = _group(j, rows), _moved(lanes, 2 * width)
+                for at, d in ((lanes, dy * _rows_of(y_ref, j, of_z)),
+                              (_moved(lanes, width), d_gate),
+                              (of_z, dy * _rows_of(y_ref, j, lanes))):
+                    dy_ref[here, at] = d.astype(dy_ref.dtype)
+            else:
+                dy_ref[_group(j, rows), lanes] = dy.astype(dy_ref.dtype)
             # dtaps[K-1-b] = sum_t dc[t] y[t - b], eight partial sums a tap;
             # behind the taps' (zip stops there) dbias = sum_t dc[t].
             sums = tuple(
@@ -515,18 +575,21 @@ def _with_constants(operands, specs, taps, bias, scale, heads, width):
 # where the filter has none: another trace, with no operand for it; ``first``
 # is None where y is the filter's channels and no more.  ``interpret`` is
 # static, so the cached trace is of the mode asked for.)
-@functools.partial(jax.jit, static_argnames=("heads", "first", "interpret"))
-def _forward(y, taps, bias, scale, heads, first, interpret):
+@functools.partial(jax.jit, static_argnames=("heads", "first", "interpret",
+                                             "gated"))
+def _forward(y, taps, bias, scale, heads, first, interpret, gated=False):
     (b, s, _), width = y.shape, taps.shape[1]
-    rows = _pick_rows(s, width)
+    across = 3 * width if gated else width      # of y's blocks: B, C and z
+    rows = _pick_rows(s, across)
     block = _specs(rows, width, s // rows)[0]
-    operands, specs = [y, y], list(_specs(rows, width, s // rows, first)[:2])
+    operands, specs = [y, y], list(_specs(rows, across, s // rows, first)[:2])
     _with_constants(operands, specs, taps, bias, scale, heads, width)
     scratch = [] if heads is None else [
         pltpu.VMEM((rows, width), jnp.float32),
         pltpu.VMEM((3, rows, width), jnp.bfloat16)]
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, biased=bias is not None),
+        functools.partial(_fwd_kernel, heads=heads, biased=bias is not None,
+                          gated=gated),
         grid=(b, s // rows),
         in_specs=specs,
         out_specs=block,
@@ -541,14 +604,16 @@ def _forward(y, taps, bias, scale, heads, first, interpret):
         return call(*operands)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "first", "interpret"))
-def _backward(y, taps, bias, g, scale, heads, first, interpret):
+@functools.partial(jax.jit, static_argnames=("heads", "first", "interpret",
+                                             "gated"))
+def _backward(y, taps, bias, g, scale, heads, first, interpret, gated=False):
     (b, s, total), (k, width) = y.shape, taps.shape
     sums = k + (bias is not None)     # a tap's eight partial sums, the bias's
-    rows = _pick_rows(s, width)
+    across = 3 * width if gated else width
+    rows = _pick_rows(s, across)
     block, _, after = _specs(rows, width, s // rows)
     operands = [y, y, y, g, g]
-    specs = [*_specs(rows, width, s // rows, first), block, after]
+    specs = [*_specs(rows, across, s // rows, first), block, after]
     _with_constants(operands, specs, taps, bias, scale, heads, width)
     n = rows + _HALO                  # the block's rows and those after
     scratch = [] if heads is None else [
@@ -556,12 +621,15 @@ def _backward(y, taps, bias, g, scale, heads, first, interpret):
         pltpu.VMEM((n, width), jnp.float32),
         pltpu.VMEM((n, width), jnp.float32)]
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads, biased=bias is not None),
+        functools.partial(_bwd_kernel, heads=heads, biased=bias is not None,
+                          gated=gated),
         grid=(b, s // rows),
         in_specs=specs,
-        out_specs=[block, pl.BlockSpec((None, sums * _TILE, width),
-                                       lambda b, i: (b, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(g.shape, y.dtype),
+        out_specs=[specs[0] if gated else block,
+                   pl.BlockSpec((None, sums * _TILE, width),
+                                lambda b, i: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(y.shape if gated else g.shape,
+                                        y.dtype),
                    jax.ShapeDtypeStruct((b, sums * _TILE, width),
                                         jnp.float32)],
         scratch_shapes=scratch,
@@ -587,30 +655,33 @@ def _norm_of(heads, scale):
     return jnp.float32(scale), heads
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 5))
-def short_conv(y, taps, heads, scale, bias=None, first=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 5, 6))
+def short_conv(y, taps, heads, scale, bias=None, first=None, gated=False):
     """``silu(taps * y + bias)`` for ``y [B, S, heads * d]``, ``taps [K,
     heads * d]`` and ``bias [heads * d]`` or None (``*`` the causal
     depthwise convolution, zero history before position 0 of every batch
     row), each head L2-normed (``rsqrt(sum of squares + 1e-6)``) and
     multiplied by ``scale`` where that is not None; float32 inside, the
     dtype of y out.  With ``first`` y is wider and the filter reads its
-    channels ``first : first + heads * d``.  One Mosaic call, and one for
-    all three gradients; ``_why_not`` says which shapes it takes."""
+    channels ``first : first + heads * d``.  With ``gated`` y is ``[B, S, 3
+    C]``, its thirds B, C and z, and the result ``C (taps * (B z))``: two
+    multiplicative gates and no SiLU, bias or norm.  One Mosaic call, and
+    one for all the gradients; ``_why_not`` says which shapes it takes."""
     scale, heads = _norm_of(heads, scale)
     return _forward(y, taps, bias, scale, heads=heads, first=first,
-                    interpret=_interpret())
+                    interpret=_interpret(), gated=gated)
 
 
-def _short_conv_fwd(y, taps, heads, scale, bias, first):
-    return short_conv(y, taps, heads, scale, bias, first), (y, taps, bias)
+def _short_conv_fwd(y, taps, heads, scale, bias, first, gated):
+    return (short_conv(y, taps, heads, scale, bias, first, gated),
+            (y, taps, bias))
 
 
-def _short_conv_bwd(heads, scale, first, kept, g):
+def _short_conv_bwd(heads, scale, first, gated, kept, g):
     y, taps, bias = kept
     scale, heads = _norm_of(heads, scale)
     return _backward(y, taps, bias, g, scale, heads=heads, first=first,
-                     interpret=_interpret())
+                     interpret=_interpret(), gated=gated)
 
 
 short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
@@ -668,7 +739,17 @@ def _convolved_plain(y, taps, heads, scale, bias=None):
     return out.astype(y.dtype)
 
 
-def convolved(y, taps, heads, scale, in_place: bool, bias=None, first=None):
+@jax.checkpoint
+def _gated_plain(y, taps):
+    """``convolved``'s gated form in ``jnp``: ``C (taps * (B z))`` of y's
+    thirds B, C and z, float32 inside; under a checkpoint as
+    ``_convolved_plain``."""
+    b, c, z = (t.astype(jnp.float32) for t in jnp.split(y, 3, axis=-1))
+    return (c * _short_convolution(b * z, taps)).astype(y.dtype)
+
+
+def convolved(y, taps, heads, scale, in_place: bool, bias=None, first=None,
+              gated: bool = False):
     """``silu(taps * y)``, ``[B, S, heads * d]`` in the dtype of y; each
     head L2-normed and multiplied by ``scale`` where that is not None.
     ``bias [heads * d]`` (a Mamba-2 layer's ``use_conv_bias``) or None is
@@ -676,16 +757,27 @@ def convolved(y, taps, heads, scale, in_place: bool, bias=None, first=None):
     operand for it.  ``first`` (static) or None: y is ``[B, S, wider]`` and
     the filter's channels are ``y[..., first : first + heads * d]``, which
     the pass reads where they lie and the ``jnp`` body cuts out.
+    ``gated`` (static; an LFM2 layer's double-gated filter, ``models/llama.py
+    ::GatedShortConv``): y is ``[B, S, 3 C]``, the thirds B, C and z as one
+    projection left them, and the result ``C (taps * (B z))``, ``[B, S, C]``
+    with no SiLU; ``heads`` 1 and neither ``scale``, ``bias`` nor ``first``
+    go with it.
     ``in_place`` is the caller's word that this trace may hold Mosaic calls
     on operands where they lie: the chain is then ``short_conv``'s one pass
     forward and one backward, where the shape is one it takes
     (``_why_not``).  Elsewhere ``_convolved_plain``.  Which body a trace
     took, and why, ``body_counts()`` says."""
-    why = (_why_not(y.shape, taps.shape, heads, first) if in_place
+    if gated and not (heads == 1 and all(
+            option is None for option in (scale, bias, first))):
+        raise ValueError("a gated filter is C (taps * (B z)) alone: heads 1, "
+                         "and no scale, bias or first")
+    why = (_why_not(y.shape, taps.shape, heads, first, gated) if in_place
            else NOT_IN_PLACE)
     _trace_counts.note(_BODY, why or _FUSED)
     if why is None:
-        return short_conv(y, taps, heads, scale, bias, first)
+        return short_conv(y, taps, heads, scale, bias, first, gated)
+    if gated:
+        return _gated_plain(y, taps)
     if first is not None:
         y = y[..., first:first + taps.shape[1]]
     return _convolved_plain(y, taps, heads, scale, bias)

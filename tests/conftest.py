@@ -335,6 +335,36 @@ TINY.setdefault("sambay_lm", {
     "traffic": {"sequence": 64, "batch_per_chip": 1},
 })
 
+TINY.setdefault("lconv_moe_lm", {
+    # Hidden 128 (a lane tile: the gated filter's thirds then start at one,
+    # as the cell's at 2048, and ``flash_attention_fn``'s model takes the
+    # interpreted Mosaic pass); THREE layers, one of each combination the
+    # stack has (a dense conv layer, a routed attention layer, a routed conv
+    # layer: the suite is at its time limit; the cell's five run in
+    # ``tests/test_lfm2.py`` and on the chip): a 3-tap filter, 2 query heads
+    # over 1 key-value head of 64, experts 4 to 7 of 16 held, 3 choices a
+    # token.
+    "config": {"hidden_size": 128, "num_hidden_layers": 3,
+               "layer_types": ["conv", "full_attention", "conv"],
+               "num_attention_heads": 2, "num_key_value_heads": 1,
+               "intermediate_size": 256, "moe_intermediate_size": 32,
+               "vocab_size": 512, "num_experts": 4,
+               "num_experts_per_tok": 3,
+               "deployment": {"num_experts_published": 16,
+                              "first_held_expert": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up, as ``hybrid_moe_lm``.
+                          "loss_must_fall": False,
+                          # bf16 at these widths; float32 through the same
+                          # code agrees to 1e-4 (tests/test_lfm2.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    "traffic": {"sequence": 64, "batch_per_chip": 2},
+})
+
 
 # The files that take over 100 s of the driver's command
 # (``/root/TESTS_LAST_RUN.json``: six workers, ``--dist loadfile``), longest
@@ -463,7 +493,9 @@ _MANIFEST_THEN = {
     # (This one reads the cells at import, from the file as it is: its cut
     # keeps every cell, the newest named here, and ends the metrics at its.)
     "test_benchmark_startup_spans.py::test_the_manifests_ten_entries":
-        ("phi-4-mini-flash.train-s8k", "trace_loss_self_ms"),
+        ("lfm2-24b-a2b.train-s8k-b2", "trace_loss_self_ms"),
+    "test_benchmark_qk_norm.py::test_the_manifests_one_new_entry":
+        ("phi-4-mini-flash.train-s8k", "diff_attn_ms"),
 }
 
 
@@ -474,7 +506,9 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
     (PR 34) and ``test_benchmark_hybrid.py`` (PR 38) pin their PR's entries
     (``test_benchmark_window.py``'s, PR 42, the cells its new metrics list;
     ``test_benchmark_gdn_solve.py``'s, PR 47, its one metric;
-    ``test_benchmark_startup_spans.py``'s, PR 52, its ten)
+    ``test_benchmark_startup_spans.py``'s, PR 52, its ten;
+    ``test_benchmark_qk_norm.py``'s, PR 48, the cells that norm q and k a
+    head)
     as the LAST of every list of
     ``BENCHMARK.json`` and count the cells, and a later PR may neither
     edit those files nor put its entries anywhere but last.  So each of
