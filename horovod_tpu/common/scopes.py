@@ -256,7 +256,10 @@ small ``ragged-dot-metadata`` call beside it turns the group sizes into
 tiles), with no scope of the program's in it (read from a step compiled for
 a described v5e, PR 32).  ``RAGGED_DOT_PREFIX`` is that prefix: a reader of
 ``hvd.moe.experts`` counts operations so named as the scope's, since the
-routed layer is the program's only user of ``ragged_dot``.
+routed layer is the program's only user of ``ragged_dot``.  Where
+``ops/grouped_matmul.py`` takes its Mosaic body in its place, the calls are
+the program's own (``gmm``, ``tgmm``) and carry ``hvd.moe.experts`` like any
+other operation.
 
 ``FUSION_PACK`` and ``FUSION_UNPACK`` named the copies of a trace-time
 gradient packer that is gone.  The constants stay because the benchmark's
@@ -333,6 +336,7 @@ __all__ = [
     "MOSAIC", "MOSAIC_FLASH_FWD", "MOSAIC_FLASH_BWD", "MOSAIC_ROPE",
     "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
     "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION", "MOSAIC_SSCAN",
+    "MOSAIC_GROUPED_MATMUL",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
 ]
@@ -396,6 +400,7 @@ MOSAIC_SPARSE_SELECT = MOSAIC + "sparse_select"
 MOSAIC_INDEX_LOSS = MOSAIC + "index_loss"
 MOSAIC_PAGED_ATTENTION = MOSAIC + "paged_attention"
 MOSAIC_SSCAN = MOSAIC + "selective_scan"
+MOSAIC_GROUPED_MATMUL = MOSAIC + "grouped_matmul"
 INIT = "hvd.init"
 INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
 INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize

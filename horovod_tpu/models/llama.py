@@ -55,6 +55,7 @@ from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
 from horovod_tpu.ops.gated_delta import (calls_in_place, gated_delta_rule,
                                          gated_delta_states)
+from horovod_tpu.ops.grouped_matmul import grouped_matmul
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops import rope as _rope
 from horovod_tpu.ops.selective_scan import (selective_scan,
@@ -1414,9 +1415,10 @@ def _row_chunk(assignments: int, share: float) -> int:
     return min(-(-int(2 * share * assignments) // 512) * 512, assignments)
 
 
-@functools.partial(jax.jit, static_argnums=(9, 10, 12), inline=True)
+@functools.partial(jax.jit, static_argnums=(9, 10, 12, 13), inline=True)
 def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
-                rows_per_expert, n_rows, chunk, k, first=0, act="silu"):
+                rows_per_expert, n_rows, chunk, k, first=0, act="silu",
+                in_place=False):
     """The part of a routed layer's y that the sorted rows ``first`` to
     ``first + chunk`` give: gathered, through their experts, and back to
     their tokens under the gates.  ``[T, H]`` float32; all zeros, and so is
@@ -1426,6 +1428,12 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
     ``"silu"``: ``w_gu`` is ``[held, H, 2F]``, a SwiGLU's gate and up;
     ``"relu2"``: it is ``w_up [held, H, F]`` and the rows between the two
     products are ``relu(.)`` squared, no gate.
+
+    The two grouped products are ``ops/grouped_matmul.py``'s: XLA's
+    ``ragged_dot``, or, where ``in_place`` (the trace is not partitioned)
+    and the experts' width is no whole number of lane tiles, the Mosaic
+    grouped matmul at stated tiles, which reads the matrices as they are:
+    nothing is padded, in the step or in the state.
 
     Under an inlined ``jit`` so that it is traced once a shape: a step
     calls it ten times a routed layer (the first buffer and the loop's, in
@@ -1448,12 +1456,12 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
         rows = jnp.where(live, rows, 0)
     with _scopes.scope(_scopes.MOE_EXPERTS):
         if act == "relu2":
-            rows = _relu2(jax.lax.ragged_dot(rows, w_gu, sizes))
+            rows = _relu2(grouped_matmul(rows, w_gu, sizes, in_place))
         else:
             gate, up = jnp.split(
-                jax.lax.ragged_dot(rows, w_gu, sizes), 2, axis=-1)
+                grouped_matmul(rows, w_gu, sizes, in_place), 2, axis=-1)
             rows = nn.silu(gate) * up
-        rows = jax.lax.ragged_dot(rows, w_down, sizes)
+        rows = grouped_matmul(rows, w_down, sizes, in_place)
     with _scopes.scope(_scopes.MOE_COMBINE):
         rows = jnp.where(live, rows, 0)
         return _weighted_rows_to_tokens(rows, weights, assignments,
@@ -1471,9 +1479,10 @@ def _over_live_buffers(of_buffer, n_rows, chunk):
         of_buffer(np.int32(0)))     # of the loop's type: one trace serves
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12))
 def _live_buffers(tokens, w_gu, w_down, weights, order, inverse, last,
-                  rows_per_expert, n_rows, chunk, k, act="silu"):
+                  rows_per_expert, n_rows, chunk, k, act="silu",
+                  in_place=False):
     """``_one_buffer`` summed over ``ceil(n_rows / chunk)`` buffers, forward
     and backward: a chip's share of the experts gets a fraction of the worst
     case's rows and pays for what it gets.
@@ -1489,19 +1498,20 @@ def _live_buffers(tokens, w_gu, w_down, weights, order, inverse, last,
     return _over_live_buffers(
         lambda first: _one_buffer(tokens, w_gu, w_down, weights, order,
                                   inverse, last, rows_per_expert, n_rows,
-                                  chunk, k, first, act), n_rows, chunk)
+                                  chunk, k, first, act, in_place),
+        n_rows, chunk)
 
 
 def _live_buffers_fwd(*inputs):
     return _live_buffers(*inputs), inputs[:9]
 
 
-def _live_buffers_bwd(chunk, k, act, inputs, g):
+def _live_buffers_bwd(chunk, k, act, in_place, inputs, g):
     differentiable, indices = inputs[:4], inputs[4:]
 
     def cotangents(first):
         return jax.vjp(lambda *operands: _one_buffer(
-            *operands, *indices, chunk, k, first, act),
+            *operands, *indices, chunk, k, first, act, in_place),
             *differentiable)[1](g)
 
     return (*_over_live_buffers(cotangents, indices[-1], chunk),
@@ -1533,9 +1543,16 @@ class RoutedExperts(nn.Module):
     Static shapes and no dropped row, whatever the imbalance: the T * K
     assignments are sorted by held expert (absent ones last), the tokens'
     rows gathered in that order into a row buffer, and two grouped
-    products (``jax.lax.ragged_dot``, group sizes the held experts' row
-    counts) run over the rows that are there: XLA:TPU makes each a Mosaic
-    call that visits only tiles that hold rows.  Each token then takes its
+    products (``ops/grouped_matmul.py``, group sizes the held experts' row
+    counts) run over the rows that are there: ``jax.lax.ragged_dot``, which
+    XLA:TPU makes a Mosaic call of its own that visits only tiles that hold
+    rows; or, where ``in_place`` (``LlamaLayer``'s word that the trace is
+    not partitioned) and F is no whole number of lane tiles (Nemotron-3's
+    1856 = 14.5, which XLA's calls run at a tenth of the MXU's peak), the
+    Mosaic grouped matmul JAX ships, at tiles that module states.  Either
+    reads the matrices as they are: nothing is padded, in the step or in
+    the state, and parameters, gradients and optimizer state keep the
+    published shapes.  Each token then takes its
     rows back by the inverse permutation and adds them up under its gates:
     slot by slot, K gathers of ``[T, H]`` into a float32 sum, and so does
     the gradient that comes back to the tokens (``_rows_to_tokens``; no
@@ -1579,6 +1596,7 @@ class RoutedExperts(nn.Module):
     """
 
     config: LlamaConfig
+    in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
 
     @nn.compact
     def __call__(self, x):
@@ -1652,9 +1670,9 @@ class RoutedExperts(nn.Module):
                     jnp.cumsum(rows_per_expert), rows_per_expert, n_rows,
                     chunk, K)
         if n_chunks == 1:
-            y = _one_buffer(*operands, 0, act)
+            y = _one_buffer(*operands, 0, act, self.in_place)
         else:
-            y = _live_buffers(*operands, act)
+            y = _live_buffers(*operands, act, self.in_place)
         y = y.astype(cfg.dtype).reshape(B, S, H)
 
         if cfg.shared_experts:
@@ -2152,7 +2170,7 @@ class LlamaLayer(nn.Module):
         elif kind != EXPERTS:
             mixer = functools.partial(attention(), cos=cos, sin=sin)
         if cfg.is_routed(self.index):
-            ffn = RoutedExperts(cfg, name="moe")
+            ffn = RoutedExperts(cfg, in_place=in_place, name="moe")
         elif kind is None:
             ffn = SwiGLU(cfg, name="mlp")
 

@@ -7,8 +7,10 @@ convolution, SiLU and L2 norm as one Mosaic pass each way) and
 ``gated_delta`` (the chunkwise gated delta rule: ``jax.numpy`` but for its
 chunks' triangular systems, solved in one Mosaic call a slab), ``ssd``
 (Mamba-2's chunked state-space scan, ``jax.numpy``), ``chunking`` (the
-chunks and slabs the two recurrences share) and ``selective_scan`` (Mamba-1's
-recurrence: a Mosaic call each way, or chunks in ``jax.numpy``).  None of
+chunks and slabs the two recurrences share), ``selective_scan`` (Mamba-1's
+recurrence: a Mosaic call each way, or chunks in ``jax.numpy``) and
+``grouped_matmul`` (a routed layer's grouped products: XLA's ``ragged_dot``,
+or JAX's Mosaic grouped matmul at stated tiles).  None of
 them is imported or exported here (``models/llama.py`` imports each beside
 the mixer it serves), so ``import horovod_tpu.ops`` pays for no kernel."""
 

@@ -3,8 +3,9 @@
 ``tests/test_phi4_flash_v5e_compile.py`` and in its manner: the gated short
 convolution's Mosaic pair over ``bf16[2, 8192, 3 x 2048]``; a
 ``GatedShortConv`` layer whole, forward and backward, whose Mosaic calls are
-that pair under its scope; and the flash calls at 32 query heads over 8
-key-value heads of 64.  The whole step at 2 x 8192 is compiled by the
+that pair under its scope; the flash calls at 32 query heads over 8
+key-value heads of 64; and a routed layer's grouped products, which at this
+cell's widths stay XLA's.  The whole step at 2 x 8192 is compiled by the
 builder's study and on the chip, not here (it takes most of a minute)."""
 
 import os
@@ -19,7 +20,7 @@ from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.models import llama
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import short_conv
+from horovod_tpu.ops import grouped_matmul, short_conv
 
 CELL = "lfm2-24b-a2b.train-s8k-b2"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
@@ -43,7 +44,7 @@ def one_chip(topo, monkeypatch):
     deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for module in (fa, short_conv):
+    for module in (fa, short_conv, grouped_matmul):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -141,3 +142,34 @@ def test_the_flash_calls_at_32_heads_over_8_of_64(one_chip, config):
     assert sum(scopes.FLASH_FWD in c for c in calls) == 1
     assert sum(scopes.FLASH_BWD in c for c in calls) == 1
     assert not re.findall(rf"\w+\[(?:\d+,)*{S},{S}\]", text)
+
+
+def test_a_routed_layers_grouped_products_stay_xlas(one_chip, config):
+    """16 of 64 SwiGLU experts at 2 x 8192 tokens, 4 choices a token, IN
+    PLACE: hidden 2048 and the experts' 1536 are whole lane tiles (16 and
+    12), so ``ops/grouped_matmul.py`` leaves every grouped product to
+    ``ragged_dot`` on the parameters as they are (``w_gate_up [16, 2048,
+    3072]``, ``w_down [16, 1536, 2048]``) and the layer holds no Mosaic
+    call: the text the cell's step had before that module came.  LOWERED,
+    not compiled (XLA's grouped kernels take most of a minute)."""
+    module = llama.RoutedExperts(config, in_place=True)
+    variables = jax.eval_shape(
+        lambda k: module.init(k, jnp.zeros((1, 8, 2048), jnp.bfloat16)),
+        jax.random.key(0))
+    x = jax.ShapeDtypeStruct((B, S, 2048), jnp.bfloat16, sharding=one_chip)
+    before = grouped_matmul.body_counts()
+    llama._one_buffer.clear_cache()     # it keeps its traces by shape
+    text = jax.jit(module.apply).lower(jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip),
+        variables), x).as_text()
+    after = grouped_matmul.body_counts()
+    assert after["mosaic"] == before["mosaic"]
+    assert after["xla"][grouped_matmul.WHOLE_TILES] > before["xla"].get(
+        grouped_matmul.WHOLE_TILES, 0)
+    products = [line for line in text.splitlines() if "ragged_dot" in line]
+    assert products and "tpu_custom_call" not in text
+    for line in products:
+        assert ("16x2048x3072xbf16" in line) != ("16x1536x2048xbf16" in line)
+    assert "stablehlo.pad" not in "".join(
+        line for line in text.splitlines() if "16x2048x" in line
+        or "16x1536x" in line)

@@ -19,6 +19,7 @@ from benchmark import manifest
 from benchmark.reference import nemotron_h as reference
 from horovod_tpu.models import LlamaConfig, LlamaModel
 from horovod_tpu.models import llama
+from horovod_tpu.ops import grouped_matmul
 from tiny_sizes import TINY
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -231,6 +232,158 @@ def test_bias_update_sign_and_rate_over_two_steps(tiny):
     steps = np.abs(got2 - got) / rate
     np.testing.assert_allclose(steps, np.round(steps), atol=1e-4)
     assert steps.max() <= 1.0 and np.abs(got2).max() <= 2 * rate + 1e-9
+
+
+# -- the widths the grouped products see --------------------------------------
+
+def _off_tile_layer(act, width, lift, hidden=64):
+    """A routed layer of 2 held experts (ids 4 and 5) of 16, two
+    choices a token of 1,024: four row buffers of 512, of which the router
+    as it is drawn fills one, and all four where feature 0 lifts the held
+    experts' logits by ``lift``.  ``(config, params, x)``."""
+    cfg = LlamaConfig(
+        vocab_size=64, hidden_size=hidden, num_layers=1, num_heads=2,
+        num_kv_heads=2, intermediate_size=64, max_seq_len=1024,
+        num_experts=16, experts_per_token=2, moe_intermediate_size=width,
+        shared_experts=0, held_experts=2, first_held_expert=4,
+        norm_topk_prob=False, mlp_hidden_act=act, dtype=jnp.float32,
+        logits_dtype=jnp.float32)
+    x = jax.random.normal(jax.random.key(57), (1, 1024, hidden)).at[..., 0].set(1)
+    params = llama.RoutedExperts(cfg).init(jax.random.key(7), x)["params"]
+    params["router"]["kernel"] = params["router"]["kernel"].at[0].set(
+        jnp.where((jnp.arange(16) >= 4) & (jnp.arange(16) < 6), lift, 0.0))
+    return cfg, params, x
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The Mosaic grouped matmul wherever a TPU would take it, interpreted
+    (``grouped_matmul._why_not``'s last reason lifted)."""
+    rule = grouped_matmul._why_not
+
+    def lifted(*shape_and_place):
+        why = rule(*shape_and_place)
+        return None if why == grouped_matmul.NO_TPU else why
+
+    assert rule(512, 128, 72, True) == grouped_matmul.NO_TPU
+    monkeypatch.setattr(grouped_matmul, "_why_not", lifted)
+    llama._one_buffer.clear_cache()     # it keeps its traces by shape
+    yield
+    llama._one_buffer.clear_cache()
+
+
+def _dense_loop(cfg, params, x):
+    """The layer as a loop over the held experts, every token through every
+    one of them and the gates of those that did not choose it zero."""
+    scores = jax.nn.softmax(x @ params["router"]["kernel"], axis=-1)
+    gates, chosen = jax.lax.top_k(scores, cfg.experts_per_token)
+    y = 0.0
+    for held in range(cfg.experts_held):
+        up = x @ params["w_gate_up" if cfg.mlp_hidden_act == "silu"
+                        else "w_up"][held]
+        if cfg.mlp_hidden_act == "silu":
+            gate, up = jnp.split(up, 2, axis=-1)
+            rows = jax.nn.silu(gate) * up
+        else:
+            rows = jnp.square(jax.nn.relu(up))
+        gate_of = jnp.sum(jnp.where(
+            chosen == held + cfg.first_held_expert, gates, 0.0), axis=-1)
+        y = y + gate_of[..., None] * (rows @ params["w_down"][held])
+    return y
+
+
+@pytest.mark.parametrize("buffers", ["one buffer", "several buffers"])
+@pytest.mark.parametrize("act", ["relu2", "silu"])
+def test_experts_off_the_lane_tile_are_the_dense_loop(act, buffers,
+                                                      interpreted):
+    """F = 72 is no whole lane tile: in place the grouped products are the
+    Mosaic grouped matmul (interpreted here; a gated expert's first one 144
+    wide, the split in the middle), on the matrices as they are, and output,
+    the tokens' gradient and every gradient leaf, at the parameters' own
+    shapes, are the plain loop's."""
+    assert 72 % grouped_matmul.LANES
+    cfg, params, x = _off_tile_layer(act, 72, 8.0 if "several" in buffers
+                                     else 0.0)
+    module = llama.RoutedExperts(cfg, in_place=True)
+    _, sown = module.apply({"params": params}, x, mutable=["moe_stats"])
+    run = int(sown["moe_stats"]["row_buffers_run"][0])
+    assert (run > 1) == ("several" in buffers), run
+    assert int(sown["moe_stats"]["rows_dropped"][0]) == 0
+    weight = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def both(layer):
+        return jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(
+            layer(p, x) * weight), argnums=(0, 1)))(params, x)
+
+    before = grouped_matmul.body_counts()
+    with jax.default_matmul_precision("highest"):
+        y, (grads, dx) = both(lambda p, x: module.apply({"params": p}, x))
+        y_loop, (grads_loop, dx_loop) = both(
+            lambda p, x: _dense_loop(cfg, p, x))
+    assert grouped_matmul.body_counts()["xla"] == before["xla"]
+    np.testing.assert_allclose(y, y_loop, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(dx, dx_loop, rtol=2e-4, atol=2e-4)
+    assert jax.tree.map(jnp.shape, grads) == jax.tree.map(jnp.shape, params)
+    assert grads["w_down"].shape == (2, 72, 64)
+    assert grads["w_gate_up" if act == "silu" else "w_up"].shape == (
+        2, 64, 144 if act == "silu" else 72)
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                 jax.tree.leaves(grads_loop)):
+        assert float(jnp.max(jnp.abs(want))) > 1e-3, path
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("width, in_place, way", [
+    (72, True, "mosaic"), (200, True, "mosaic"),
+    (128, True, grouped_matmul.WHOLE_TILES),
+    (256, True, grouped_matmul.WHOLE_TILES),
+    (72, False, grouped_matmul.NOT_IN_PLACE)],
+    ids=["72", "200", "128", "256", "72, not in place"])
+@pytest.mark.parametrize("act", ["relu2", "silu"])
+def test_the_widths_alone_say_which_grouped_product_runs(
+        act, width, in_place, way, interpreted):
+    """``width % LANES`` (hidden 128) of a trace that may hold Mosaic calls: at whole
+    tiles, and in a trace that may not, every grouped product is
+    ``ragged_dot`` on the parameters as they are and no Mosaic call is
+    traced; off them every one is a Mosaic call and none is
+    ``ragged_dot``.  Nothing is padded either way.
+    ``grouped_matmul.body_counts()`` says which way each traced product
+    went."""
+    cfg, params, x = _off_tile_layer(act, width, 0.0, hidden=128)
+    before = grouped_matmul.body_counts()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(
+        llama.RoutedExperts(cfg, in_place=in_place).apply(
+            {"params": p}, x))))(params, x).jaxpr
+    after = grouped_matmul.body_counts()
+    moved = {"mosaic": after["mosaic"] - before["mosaic"], **{
+        why: n - before["xla"].get(why, 0) for why, n in after["xla"].items()}}
+    assert [why for why, n in moved.items() if n] == [way], moved
+    xla = _equations(jaxpr, "ragged_dot_general")
+    mosaic = _equations(jaxpr, "pallas_call")
+    # (the first buffer and the loop's, forward, again and backward)
+    assert (len(xla), len(mosaic)) == ((0, 16) if way == "mosaic"
+                                       else (16, 0))
+    halves = 2 if act == "silu" else 1
+    for eqn in xla + mosaic:
+        # (the scalars a Mosaic call prefetches aside; the rows' gradients
+        # multiply with the matrices as they are, read transposed)
+        shapes = {v.aval.shape for v in (*eqn.invars, *eqn.outvars)
+                  if v.aval.ndim > 1}
+        assert shapes <= {(512, 128), (512, width), (512, halves * width),
+                          (2, 128, halves * width), (2, width, 128),
+                          (2, halves * width, 128), (2, 128, width)}, shapes
+    assert not [eqn for eqn in _equations(jaxpr, "pad")
+                if eqn.invars[0].aval.ndim > 1]
+
+
+def _equations(jaxpr, primitive):
+    """The equations of that primitive, sub-jaxprs walked."""
+    found = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == primitive]
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _equations(sub, primitive)
+    return found
 
 
 # -- the filter's two bodies --------------------------------------------------

@@ -21,7 +21,7 @@ from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.models import llama
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import short_conv
+from horovod_tpu.ops import grouped_matmul, short_conv
 
 CELL = "nemotron-3-nano-30b-a3b.train-s8k-b2"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
@@ -45,7 +45,7 @@ def one_chip(topo, monkeypatch):
     deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for module in (fa, short_conv):
+    for module in (fa, short_conv, grouped_matmul):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -111,24 +111,53 @@ def test_the_flash_pair_compiles_at_32_over_2_heads_of_128(one_chip, config):
     assert not re.findall(rf"\w+\[(?:\d+,)*{S},{S}\]", text)
 
 
-def test_a_routed_layers_grouped_products(one_chip, config):
+@pytest.mark.parametrize("in_place", [False, True],
+                         ids=["a partitioned trace", "in place"])
+def test_a_routed_layers_grouped_products(one_chip, config, in_place):
     """8 of 128 relu2 experts at 2 x 8192 tokens, 6 choices a token: 98,304
     assignments through buffers of 12,288 rows (``_live_buffers``: the first
-    buffer and the loop's body), forward: two ``ragged_dot`` s a buffer where
+    buffer and the loop's body), forward: two grouped products a buffer where
     a SwiGLU expert has two as well, over ``w_up [8, 2688, 1856]``, and no
-    gate's split.  LOWERED for the described chip, not compiled: XLA:TPU's
-    own grouped kernels take 23 s to compile, which the suite has not."""
-    module = llama.RoutedExperts(config)
+    gate's split.  1856 is 14.5 lane tiles: in a trace that may be
+    partitioned the four are ``ragged_dot``, in place they are the Mosaic
+    grouped matmul (``ops/grouped_matmul.py``: ``gmm`` in blocks of 256 rows
+    with the contracted width whole), and either way they read the matrices
+    at the parameters' shapes: no padded copy is made.  LOWERED for the
+    described chip, not compiled: XLA:TPU's own grouped kernels take 23 s to
+    compile, which the suite has not."""
+    module = llama.RoutedExperts(config, in_place=in_place)
     variables = jax.eval_shape(
         lambda k: module.init(k, jnp.zeros((1, 8, 2688), jnp.bfloat16)),
         jax.random.key(0))
     x = jax.ShapeDtypeStruct((B, S, 2688), jnp.bfloat16, sharding=one_chip)
-    text = jax.jit(module.apply).lower(jax.tree.map(
+    before = grouped_matmul.body_counts()
+    llama._one_buffer.clear_cache()     # it keeps its traces by shape
+    lowered = jax.jit(module.apply).lower(jax.tree.map(
         lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip),
-        variables), x).as_text()
-    lines = [line for line in text.splitlines() if "ragged_dot" in line]
-    assert len(lines) == 4, len(lines)
+        variables), x)
+    text = lowered.as_text()
+    after = grouped_matmul.body_counts()
+    xla = [line for line in text.splitlines() if "ragged_dot" in line]
+    mosaic = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert (len(xla), len(mosaic)) == ((0, 4) if in_place else (4, 0))
+    if in_place:
+        assert after["mosaic"] == before["mosaic"] + 4
+        assert after["xla"] == before["xla"]
+        # The program's own calls compile in seconds, and carry the scope
+        # that ``moe_experts_ms`` reads.
+        calls = _mosaic_calls(lowered.compile().as_text())
+        assert len(calls) == 4
+        assert all(scopes.MOE_EXPERTS in call for call in calls)
+    else:
+        assert after["mosaic"] == before["mosaic"]
+        assert after["xla"][grouped_matmul.NOT_IN_PLACE] == before["xla"].get(
+            grouped_matmul.NOT_IN_PLACE, 0) + 4
+    for line in xla + mosaic:
+        assert ("8x2688x1856xbf16" in line) != ("8x1856x2688xbf16" in line)
+        assert "12288x2688xbf16" in line and "12288x1856xbf16" in line
     assert "8x2688x1856xbf16" in text and "8x2688x3712xbf16" not in text
+    assert "stablehlo.pad" not in "".join(
+        line for line in text.splitlines() if "x1856x" in line)
     assert "2688x3712xbf16" in text          # the shared expert's own width
 
 
