@@ -16,18 +16,12 @@ TPU-first design:
 * SwiGLU MLP with fused gate+up projection (one [H, 2F] matmul).
 * Pluggable ``attention_fn`` — ``horovod_tpu.parallel.ring_attention``
   substitutes a ppermute-ring blockwise kernel for sequence parallelism.
-* One layer stack whose layers take their mixer and their feed-forward
-  by kind from the config: full attention or latent attention (MLA,
-  ``attention_kind``), or, a layer at a time, a gated delta-rule
-  linear-attention mixer among softmax ones (``layer_types``,
-  ``GatedDeltaNet``), or softmax layers that attend through a sliding
-  window (``"sliding_attention"``) with a head count, a rotary table and
-  an output gate of their own, or a decoder-hybrid-decoder stack
-  (``mb_per_layer``: Mamba-1 selective scans and differential attention,
-  whose later half reads ONE scan's output and ONE layer's keys and values
-  through gated memory units and cross-attention); a dense SwiGLU or routed
-  experts beside shared ones (``num_experts > 1``, after
-  ``first_dense_layers`` dense layers).
+* One layer stack whose layers are what their ``LayerSpec`` says
+  (``LlamaConfig.layers``, resolved once from the config's published keys):
+  one of seven mixers (``MIXERS``: softmax attention of four classes, over
+  the layer's own keys or another layer's; the gated delta rule; a gated
+  short convolution; Mamba-2; Mamba-1; a gated memory unit), a dense SwiGLU
+  or routed experts beside shared ones, or one of the two alone.
   The routed layer is told which experts it holds, routes over all of
   them, gathers its own experts' rows sorted by expert -- none dropped --
   and runs grouped products over them (``RoutedExperts``).
@@ -89,18 +83,24 @@ REMAT_POLICIES = {
 # load moves and no gradient does (``RoutedExperts``).
 ROUTER_STATE = "router_state"
 
-LAYER_TYPES = ("full_attention", "linear_attention", "sliding_attention",
-               "conv")
 # ``hybrid_override_pattern``'s characters, as Nemotron-H publishes them: a
 # Mamba-2 layer, a routed feed-forward layer, a softmax attention layer.
 MAMBA, EXPERTS, ATTENTION = PATTERN_KINDS = ("M", "E", "*")
 DENSE_LAYER = "-"       # published too; no model here has one: refused
 # The mixers of a decoder-hybrid-decoder stack (``LlamaConfig.mixer_of``): a
-# Mamba-1 selective scan, differential attention over the layer's own keys, a
-# gated memory unit on the shared scan output, cross-attention to the shared
-# keys and values.
+# Mamba-1 selective scan, attention over the layer's own keys, a gated memory
+# unit on the shared scan output, cross-attention to the shared keys and
+# values.  With the three below, every mixer a ``LayerSpec`` can name.
 SCAN, SELF_ATTENTION, MEMORY_GATE, CROSS_ATTENTION = SHARING_MIXERS = (
     "mamba", "attention", "gated_memory", "cross_attention")
+DELTA_RULE, SHORT_CONV, MAMBA2 = "linear_attention", "conv", "mamba2"
+# ``layer_types``' published names, and the mixer each gives its layer.
+TYPE_MIXERS = {"full_attention": SELF_ATTENTION,
+               "linear_attention": DELTA_RULE,
+               "sliding_attention": SELF_ATTENTION, "conv": SHORT_CONV}
+LAYER_TYPES = tuple(TYPE_MIXERS)
+DENSE, ROUTED = "dense", "routed"       # a ``LayerSpec``'s feed-forwards
+ONE_NORM, TWO_NORMS = ("norm",), ("norm_attn", "norm_mlp")
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -163,160 +163,293 @@ class RopeParameters:
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one layer of the stack IS: ``LlamaConfig.layers[i]``, resolved
+    once a config by ``_layer_specs``; ``LlamaLayer`` and the accessors read
+    it and derive nothing.
+
+    ``mixer`` names the layer's mixer (a key of ``MIXERS``) or is None (a
+    feed-forward alone); ``ffn`` is ``DENSE`` (a ``SwiGLU``), ``ROUTED``
+    (``RoutedExperts``) or None.  ``heads`` query heads, the ``window`` of
+    keys a query sees behind it (itself included; None: all of them) and
+    the rotation ``rope`` of q and k (None: they do not turn) are those of
+    the layer's published ``type`` (one of ``LAYER_TYPES``) whatever its
+    mixer; an attention mixer reads them.  ``norms`` names the norms, one a
+    sublayer: ``ONE_NORM``, or ``TWO_NORMS``, a mixer's and a feed-forward's.
+    ``reads`` is the tensor of ``shared`` the mixer is handed beside its
+    input and ``writes`` the one it leaves there for later layers
+    (``"memory"``: a scan's output, ``"kv"``: keys and values), or None."""
+
+    mixer: Optional[str]
+    ffn: Optional[str]
+    heads: int
+    window: Optional[int]
+    rope: Optional[RopeParameters]
+    type: str = "full_attention"
+    norms: tuple = TWO_NORMS
+    reads: Optional[str] = None
+    writes: Optional[str] = None
+
+
+def _layer_specs(cfg: "LlamaConfig") -> tuple:
+    """``cfg.layers``: the one reading of the fields that say what layer i
+    is (``LlamaConfig``'s docstring has them, by ``LayerSpec`` field).
+    Raises what they cannot mean: a mechanism's own values, what it needs of
+    the other fields, and what does not go with it."""
+    n = cfg.num_layers
+    types, pattern = cfg.layer_types, cfg.hybrid_override_pattern
+    naming = [name for name in ("layer_types", "hybrid_override_pattern",
+                                "mb_per_layer") if getattr(cfg, name)]
+    if len(naming) > 1:
+        raise ValueError(
+            f"{' and '.join(naming)} each name every layer's kind: at most "
+            f"one of layer_types, hybrid_override_pattern and mb_per_layer")
+    heads = cfg.num_attention_heads_per_layer
+    if heads is None:
+        heads = (cfg.num_heads,) * n
+    elif len(heads) != n or any(h < 1 or h % cfg.num_kv_heads
+                                for h in heads):
+        raise ValueError(
+            f"num_attention_heads_per_layer is {heads!r}: a multiple of "
+            f"the {cfg.num_kv_heads} key-value heads for each of {n} layers")
+    if cfg.rope_parameters is None:
+        ropes = dict.fromkeys(
+            LAYER_TYPES, None if cfg.rope_theta is None
+            else RopeParameters(cfg.rope_theta, cfg.rope_scaling))
+    else:
+        ropes = dict(cfg.rope_parameters)
+
+    def spec(i, mixer, ffn, kind="full_attention", **wiring):
+        window = cfg.sliding_window if kind == "sliding_attention" else None
+        return LayerSpec(mixer, ffn, heads[i], window, ropes.get(kind), kind,
+                         **wiring)
+
+    if cfg.attention_kind == "differential" and (
+            not cfg.mb_per_layer or cfg.rope_theta is not None
+            or cfg.num_heads % 2 or cfg.num_kv_heads % 2
+            or cfg.qk_norm or cfg.gating is not None):
+        raise ValueError(
+            "differential attention is built for a decoder-hybrid-decoder "
+            "stack (mb_per_layer): it pairs adjacent heads (an even "
+            "count of query and of key-value heads) and neither rotates "
+            "(rope_theta=None), norms nor gates them")
+    if cfg.mb_per_layer:
+        if (cfg.mb_per_layer != 2 or n % 4 or not cfg.ssm_state_size
+                or cfg.sliding_window is None or cfg.norm_placement != "pre"
+                or cfg.num_experts > 1 or cfg.total_ut_steps != 1
+                or cfg.attention_kind == "latent"):
+            raise ValueError(
+                "mb_per_layer is 2 (every second layer a Mamba-kind mixer) "
+                "or 0: the placement needs num_layers % 4 == 0, "
+                "ssm_state_size and sliding_window, and one pass over "
+                "pre-norm dense layers")
+        # Layer N / 2 is the last scan, whose output is the memory; layer
+        # N / 2 + 1 the last to project keys and values.
+        half = n // 2
+        mixers = [
+            (SCAN if i <= half else MEMORY_GATE) if i % 2 == 0
+            else (SELF_ATTENTION if i <= half + 1 else CROSS_ATTENTION)
+            for i in range(n)]
+        specs = [spec(
+            i, mixer, DENSE,
+            "sliding_attention" if i < half else "full_attention",
+            reads={MEMORY_GATE: "memory", CROSS_ATTENTION: "kv"}.get(mixer),
+            writes={half: "memory", half + 1: "kv"}.get(i))
+            for i, mixer in enumerate(mixers)]
+    elif pattern is not None:
+        if DENSE_LAYER in pattern:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r} holds a dense "
+                f"feed-forward layer ({DENSE_LAYER!r}): a layer that is "
+                f"a dense MLP alone is not built")
+        if len(pattern) != n or set(pattern) - set(PATTERN_KINDS):
+            raise ValueError(
+                f"hybrid_override_pattern is {pattern!r}: one of "
+                f"{PATTERN_KINDS} for each of {n} layers")
+        if cfg.norm_placement != "pre" or cfg.first_dense_layers:
+            raise ValueError(
+                "hybrid_override_pattern names every layer's ONE sublayer "
+                "behind a pre-norm: norm_placement='post' and "
+                "first_dense_layers do not go with it")
+        if EXPERTS in pattern and cfg.num_experts < 2:
+            raise ValueError("an 'E' layer needs num_experts > 1")
+        if MAMBA in pattern and (
+                not (cfg.mamba_num_heads and cfg.mamba_head_dim
+                     and cfg.ssm_state_size)
+                or cfg.mamba_num_heads % cfg.n_groups
+                or cfg.mamba_num_heads * cfg.mamba_head_dim % cfg.n_groups):
+            raise ValueError(
+                "an 'M' layer needs mamba_num_heads, mamba_head_dim and "
+                "ssm_state_size, the heads a multiple of n_groups")
+        sublayer = {MAMBA: (MAMBA2, None), EXPERTS: (None, ROUTED),
+                    ATTENTION: (SELF_ATTENTION, None)}
+        specs = [spec(i, *sublayer[kind], norms=ONE_NORM)
+                 for i, kind in enumerate(pattern)]
+    else:
+        if types is not None and (len(types) != n
+                                  or set(types) - set(LAYER_TYPES)):
+            raise ValueError(
+                f"layer_types is {types!r}: one of {LAYER_TYPES} for each "
+                f"of {n} layers")
+        routed_from = cfg.first_dense_layers if cfg.num_experts > 1 else n
+        specs = [spec(i, TYPE_MIXERS[kind],
+                      ROUTED if i >= routed_from else DENSE, kind)
+                 for i, kind in enumerate(types or ("full_attention",) * n)]
+        if DELTA_RULE in (types or ()) and (
+                not (cfg.linear_num_key_heads and cfg.linear_key_head_dim
+                     and cfg.linear_value_head_dim)
+                or cfg.linear_num_value_heads % cfg.linear_num_key_heads):
+            raise ValueError(
+                "linear attention needs linear_num_key_heads, "
+                "linear_key_head_dim, linear_value_head_dim and "
+                "linear_num_value_heads, a multiple of the key heads")
+        if SHORT_CONV in (types or ()) and cfg.conv_L_cache < 1:
+            raise ValueError(f"conv_L_cache is {cfg.conv_L_cache}: a "
+                             f"\"conv\" layer's filter has at least one tap")
+    in_use = {spec.type for spec in specs}
+    sliding = "sliding_attention" in in_use
+    if sliding != (cfg.sliding_window is not None) or (
+            sliding and (cfg.sliding_window < 1 or cfg.attention_kind
+                         not in ("full", "differential"))):
+        raise ValueError(
+            f"sliding_window is {cfg.sliding_window!r} and layer_types "
+            f"{types!r}: a window of at least 1 goes with "
+            f"'sliding_attention' layers (or mb_per_layer's) of "
+            f"attention_kind 'full' or 'differential', and with nothing "
+            f"else")
+    used = in_use - {"linear_attention", "conv"}
+    if cfg.rope_parameters is not None and (
+            not used <= set(ropes) <= set(LAYER_TYPES) or any(
+                not 0.0 < r.partial_rotary_factor <= 1.0
+                or int(r.partial_rotary_factor * cfg.head_dim) % 2
+                for r in ropes.values())):
+        raise ValueError(
+            f"rope_parameters names {sorted(ropes)}: an entry for "
+            f"each softmax layer type in use ({sorted(used)}), each "
+            f"turning a whole number of pairs of a head")
+    return tuple(specs)
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     """Sizes and options of ``LlamaModel``.
 
-    ``total_ut_steps`` (the published key of Ouro's ``config.json``) is the
-    number of weight-shared passes over the layer stack.  1 is the plain
-    decoder: one walk over the layers, logits out.  With T > 1 the same
-    ``num_layers`` modules (one parameter set) are applied T times, the
-    final norm ends every pass, an exit gate reads every pass's output,
-    and the model returns the T normalised hidden states and gate logits
-    in place of logits (``LlamaModel``; the loss is
-    ``ops.losses.expected_exit_loss``).  ``models/generation.py`` and the
-    serve plane refuse T > 1 (a served looped model keeps a cache a pass),
-    and so does the pipelined step, which walks the stack once.
-    ``ring_attention`` as ``attention_fn`` and the MoE block are untested
-    with T > 1.
+    What layer i IS is ``layers[i]``, a ``LayerSpec`` resolved from the
+    fields below once a config (``_layer_specs``), which the model and the
+    accessors (``kind_of`` ... ``rope_of``) read.  At most one of
+    ``layer_types`` (the published key of hybrid stacks),
+    ``hybrid_override_pattern`` (Nemotron-H's: a string or a tuple, one
+    character a layer) and ``mb_per_layer`` (the decoder-hybrid-decoder
+    stack's, SambaY, arXiv:2507.06607) names the layers' kinds; with none of
+    them every layer is ``attention_kind``'s mixer and a feed-forward.  Each
+    class's docstring has its arithmetic; this one, which key sets what.
 
-    ``remat`` names what the backward pass recomputes: ``"none"``;
-    ``"layer"`` (each layer application keeps its input alone);
-    ``"layer_keep_attention"`` (and the flash kernel's output and row
-    statistics); ``"layer_keep_selection"`` (and a sparse layer's
-    selection and indexer loss).  A linear-attention layer keeps its
-    input alone under each of them (its rule's chunk states are made again:
-    at the size they were built for there is no room to keep them, PERF.md
-    section 4).  Four passes hold four times one pass's activations, so a
-    looped model at a long sequence needs one of these.
-
-    ``attention_kind`` is ``"full"`` (``LlamaAttention``) or ``"latent"``
-    (``LatentAttention``, DeepSeek-V2's MLA): then ``kv_lora_rank``,
+    ``LayerSpec.mixer``.  The attention class is the stack's,
+    ``attention_kind``: ``"full"`` (``LlamaAttention``); ``"latent"``
+    (``LatentAttention``, DeepSeek-V2's MLA: ``kv_lora_rank``,
     ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim`` are the
-    published keys, ``num_kv_heads`` is not read, and ``rope_scaling`` (a
-    ``YarnScaling``) sets the rotary frequencies and the softmax scale.
-    ``"sparse"`` (``SparseAttention``, DeepSeek-V3.2-Exp's DSA over
-    grouped-query heads): an indexer of ``index_heads`` heads of
-    ``index_head_dim`` and one key a token picks ``index_topk`` of each
-    query's causal keys, the heads attend over those alone, and the
-    indexer learns from a loss of its own.  ``qk_norm`` puts an RMSNorm
-    with a learned scale over each head of q and k before the rotation
-    (full and sparse attention).
+    published keys, ``num_kv_heads`` is not read, and ``rope_scaling``, a
+    ``YarnScaling``, sets the rotary frequencies and the softmax scale);
+    ``"sparse"`` (``SparseAttention``, DeepSeek-V3.2-Exp's DSA: an indexer of
+    ``index_heads`` heads of ``index_head_dim`` picks ``index_topk`` of each
+    query's causal keys); ``"differential"`` (``DifferentialAttention``,
+    arXiv:2410.05258, built for a stack with ``mb_per_layer``: adjacent heads
+    pair and it does not rotate).  ``qk_norm`` puts an RMSNorm with a learned
+    scale before the rotation (full and sparse) over what ``qk_norm_over``
+    says: each ``"head"`` of q and k (a ``[head_dim]`` scale) or ``"all"``
+    heads of a token together (OLMo 2's: a ``[heads * head_dim]`` scale).
+    ``gating`` (``"per-head"`` or ``"elementwise"``, arXiv:2505.06708)
+    multiplies the attention output by a sigmoid gate before W_o.
+    A name in ``layer_types`` gives the layer its mixer: ``"full_attention"``
+    and ``"sliding_attention"`` ``attention_kind``'s; ``"linear_attention"``
+    a ``GatedDeltaNet`` (sized by the six ``linear_*`` keys); ``"conv"``
+    (LFM2's name) a ``GatedShortConv`` of ``conv_L_cache`` taps (the filter's
+    length, which is also what a decode cache would hold; ``conv_bias``,
+    published false, would give its projections and filter a bias: true is
+    refused until a configuration has it).  A character of
+    ``hybrid_override_pattern`` likewise: ``"M"`` a ``Mamba2``
+    (``mamba_num_heads``, ``mamba_head_dim``, ``ssm_state_size``,
+    ``n_groups``, ``conv_kernel``, ``chunk_size``), ``"*"``
+    ``attention_kind``'s, ``"E"`` none; ``"-"``, a dense feed-forward alone,
+    is refused.  ``mb_per_layer`` = 2 places a mixer by the layer's index i
+    among N = ``num_layers`` (N % 4 = 0): even i a ``Mamba1`` selective scan
+    (``ssm_state_size``, ``mamba_expand``, ``conv_kernel``) up to i = N / 2
+    and a ``GatedMemory`` unit behind it; odd i attention over the layer's
+    own keys up to i = N / 2 + 1 and cross-attention behind it.
 
-    ``num_experts`` > 1 makes every layer from ``first_dense_layers`` on a
-    routed one (``RoutedExperts``): a router over all ``num_experts``,
-    ``experts_per_token`` choices a token, experts of width
-    ``moe_intermediate_size`` (``intermediate_size`` where 0) and
-    ``shared_experts`` always-on experts of that width, as one SwiGLU.
-    ``held_experts`` > 0 says this program holds that many of them, ids
-    ``first_held_expert`` onwards (one chip's share under expert
-    parallelism): only they have weights here, and what the absent ones
-    would add to a token is left out.  ``norm_topk_prob`` renormalises a
-    token's gate weights to sum to one.  ``balance_over`` says over what
-    a routed layer's balance loss counts its assignments: each
+    ``LayerSpec.ffn``.  A dense ``SwiGLU`` of ``intermediate_size``; with
+    ``num_experts`` > 1, from layer ``first_dense_layers`` on (under a
+    pattern: in the ``"E"`` layers, which are that alone),
+    ``RoutedExperts``, whose docstring says what ``experts_per_token``,
+    ``moe_intermediate_size`` (0: ``intermediate_size``), ``shared_experts``,
+    ``moe_shared_expert_intermediate_size``, ``shared_expert_gate``,
+    ``norm_topk_prob``, ``routed_scaling_factor``, ``scoring_func``,
+    ``topk_method``, ``router_bias_update_rate`` and ``mlp_hidden_act``
+    make of it.  ``held_experts`` > 0 says this program holds that many of
+    the experts, ids ``first_held_expert`` onwards (one chip's share under
+    expert parallelism): only they have weights here.  ``balance_over`` says
+    over what a routed layer's balance loss counts its assignments: each
     ``"sequence"`` (DeepSeek-V2's ``seq_aux``) or the whole ``"batch"``.
-    Generation, the serve plane and the pipelined step refuse latent
-    attention, sparse attention and routed layers by name.
 
-    ``layer_types`` (the published key of hybrid stacks; None: every layer
-    is of ``attention_kind``) names the mixer a layer: ``"full_attention"``
-    is ``attention_kind``'s, ``"linear_attention"`` a ``GatedDeltaNet``
-    (the gated delta rule, ``ops/gated_delta.py``) of
-    ``linear_num_key_heads`` heads of ``linear_key_head_dim`` for q and k
-    and ``linear_num_value_heads`` of ``linear_value_head_dim`` for v, a
-    causal depthwise convolution of ``linear_conv_kernel_dim`` taps before
-    each, and beta in (0, 2) where ``linear_allow_neg_eigval`` (else in
-    (0, 1)).  ``norm_placement`` is ``"pre"`` (each sublayer reads the
-    normed state: ``x + Mixer(Norm(x))``) or ``"post"`` (OLMo 2's: the norm
-    is on each sublayer's OUTPUT inside the residual, ``x +
-    Norm(Mixer(x))``).  ``qk_norm_over`` says what ``qk_norm`` normalises:
-    each ``"head"`` of q and k (a ``[head_dim]`` scale) or ``"all"`` heads
-    of a token together (OLMo 2's: a ``[heads * head_dim]`` scale).
-    ``rope_theta`` None: the softmax layers do not rotate (position comes
-    from the linear layers' recurrence and convolutions).  Generation, the
-    serve plane and the pipelined step refuse all four by name too.
-
-    ``"sliding_attention"`` in ``layer_types`` is a softmax layer of
-    ``attention_kind`` ``"full"`` whose query t attends the keys ``0 <= t -
-    s < sliding_window`` (the published key; ``attention_fn`` is handed
-    ``window=``).  ``num_attention_heads_per_layer`` (a tuple; None:
-    ``num_heads`` everywhere) gives each layer its own count of query heads
-    over the same ``num_kv_heads``; ``gating`` ``"per-head"`` multiplies
-    each head's attention output by ``sigmoid(x W_g)``, ``W_g`` hidden x
-    heads (the head-wise output gate of arXiv:2505.06708); ``rope_parameters``
-    (pairs ``(layer type, RopeParameters)``; None: ``rope_theta`` and
+    ``LayerSpec.heads``, ``window``, ``rope``, ``type``.
+    ``num_attention_heads_per_layer`` (a tuple; None: ``num_heads``
+    everywhere) gives each layer its own count of query heads over the same
+    ``num_kv_heads``.  A layer of type ``"sliding_attention"`` (in
+    ``layer_types``; the layers before N / 2 under ``mb_per_layer``) is a
+    softmax layer of ``attention_kind`` ``"full"`` or ``"differential"`` whose
+    query t attends the keys ``0 <= t - s < sliding_window`` (the published
+    key; ``attention_fn`` is handed ``window=``).  ``rope_parameters`` (pairs
+    ``(layer type, RopeParameters)``; None: ``rope_theta`` and
     ``rope_scaling`` for every layer) gives each layer type its own theta,
-    YaRN scaling and ``partial_rotary_factor``; ``routed_scaling_factor``
-    multiplies the routed experts' renormalised gates.  A layer's kind,
-    head count, window and table follow from its index (``heads_of``,
-    ``window_of``, ``rope_of``); generation, the serve plane and the
-    pipelined step refuse each by name.
+    YaRN scaling and ``partial_rotary_factor``; ``rope_theta`` None: the
+    softmax layers do not rotate (position comes from the other layers'
+    recurrences and convolutions).
 
-    ``gating`` ``"elementwise"`` is the other output gate of that paper, a
-    lane at a time: ``wq`` is ``[hidden, heads * 2 * head_dim]``, each head's
-    outputs a query and then a gate of ``head_dim`` lanes, and the head's
-    attention output is multiplied by ``sigmoid(gate)`` lane by lane before
-    W_o.  ``zero_centered_norm`` makes every ``RMSNorm`` of the stack (the
-    block norms, the final norm, the QK-norms; not the delta rule's output
-    norm) ``x / rms(x) * (1 + scale)`` with the scale from zeros: under a
-    weight decay on every leaf that is another model, the decay pulls the
-    multiplier to 1 and not to 0.  ``shared_expert_gate`` multiplies the
-    shared experts' output by ``sigmoid(x w_s)``, ``w_s`` hidden x 1.
-    Generation, the serve plane and the pipelined step refuse the three by
-    name.
+    ``LayerSpec.norms``, ``reads``, ``writes``.  A layer is a mixer behind
+    ``"norm_attn"`` and a feed-forward behind ``"norm_mlp"``, each residual;
+    ``norm_placement`` is ``"pre"`` (each sublayer reads the normed state:
+    ``x + Mixer(Norm(x))``) or ``"post"`` (OLMo 2's: the norm is on each
+    sublayer's OUTPUT inside the residual, ``x + Norm(Mixer(x))``).  Under
+    ``hybrid_override_pattern`` it is ONE sublayer behind one pre-norm
+    (``"norm"``) with one residual add: ``num_layers`` then counts
+    sublayers, and ``remat``'s ``layer`` policies checkpoint one sublayer.
+    Under ``mb_per_layer`` layer N / 2's scan output is the MEMORY that every
+    ``GatedMemory`` behind it reads, and layer N / 2 + 1 (the one full
+    attention layer that projects) leaves its keys and values SHARED: every
+    cross-attention behind it attends to them.  The norms themselves:
+    ``zero_centered_norm`` makes every ``RMSNorm`` of the stack (the block
+    norms, the final norm, the QK-norms; not the delta rule's output norm)
+    ``x / rms(x) * (1 + scale)`` with the scale from zeros: under a weight
+    decay on every leaf that is another model, the decay pulls the
+    multiplier to 1 and not to 0.  ``layer_norm_eps`` (a float; None:
+    ``RMSNorm`` with ``rms_eps``) makes every norm of the stack a
+    ``LayerNorm`` with a scale and a bias; ``tie_word_embeddings`` makes the
+    head the embedding's transpose.
 
-    ``hybrid_override_pattern`` (Nemotron-H's published key: a string, or a
-    tuple, of one character a layer) makes every layer ONE sublayer behind
-    one norm with one residual add: ``"M"`` a ``Mamba2`` state-space mixer
-    (``mamba_num_heads`` heads of ``mamba_head_dim``, ``ssm_state_size``
-    state entries a lane, B and C shared by ``n_groups`` groups, a biased
-    filter of ``conv_kernel`` taps, the recurrence in chunks of
-    ``chunk_size``: ``ops/ssd.py``), ``"E"`` ``RoutedExperts`` and ``"*"``
-    ``attention_kind``'s mixer; ``"-"``, a dense feed-forward alone, is
-    refused.  ``num_layers`` then counts sublayers, and ``remat``'s
-    ``layer`` policies checkpoint one sublayer.  ``scoring_func``
-    ``"sigmoid"`` scores the router's logits one by one in place of a
-    softmax; ``topk_method`` ``"noaux_tc"`` (DeepSeek-V3's name) adds a bias
-    to the scores for the CHOICE alone, kept in the ``router_state``
-    collection and moved by ``router_bias_update_rate`` against each
-    expert's load where the caller makes that collection mutable, never by
-    a gradient; ``mlp_hidden_act`` ``"relu2"`` makes the experts and the
-    shared expert ``relu(x W_up)^2 W_down``, two matrices and no gate;
-    ``moe_shared_expert_intermediate_size`` is the shared expert's own
-    width.  Generation, the serve plane and the pipelined step refuse a
-    pattern, state-space layers and a bias-corrected router by name.
+    The stack as a whole.  ``total_ut_steps`` (the published key of Ouro's
+    ``config.json``) is the number of weight-shared passes over it.  1 is the
+    plain decoder: one walk over the layers, logits out.  With T > 1 the same
+    ``num_layers`` modules (one parameter set) are applied T times, the final
+    norm ends every pass, an exit gate reads every pass's output, and the
+    model returns the T normalised hidden states and gate logits in place of
+    logits (``LlamaModel``; the loss is ``ops.losses.expected_exit_loss``).
+    ``ring_attention`` as ``attention_fn`` and the MoE block are untested
+    with T > 1.  ``remat`` names what the backward pass recomputes:
+    ``"none"``; ``"layer"`` (each layer application keeps its input alone);
+    ``"layer_keep_attention"`` (and the flash kernel's output and row
+    statistics); ``"layer_keep_selection"`` (and a sparse layer's selection
+    and indexer loss).  A linear-attention layer keeps its input alone under
+    each of them (its rule's chunk states are made again: at the size they
+    were built for there is no room to keep them, PERF.md section 4).  Four
+    passes hold four times one pass's activations, so a looped model at a
+    long sequence needs one of these.
 
-    ``"conv"`` in ``layer_types`` (LFM2's published name) is a
-    ``GatedShortConv``: the layer's mixer is a causal depthwise convolution
-    of ``conv_L_cache`` taps (the published key: the filter's length, which
-    is also what a decode cache would hold) between two multiplicative
-    gates, ``C (taps * (B z))`` with B, C and z the thirds of one ``[hidden,
-    3 hidden]`` projection; no softmax, no recurrence, no activation
-    (``ops/short_conv.py``'s gated pass).  ``conv_bias`` (published, false)
-    would give the two projections and the filter a bias: true is refused
-    until a configuration has it.  Generation, the serve plane and the
-    pipelined step refuse a ``"conv"`` layer by name.
-
-    ``mb_per_layer`` = 2 (the published key of the decoder-hybrid-decoder
-    stack, SambaY, arXiv:2507.06607) places a mixer by the layer's index i
-    among N = ``num_layers`` (N % 4 = 0; ``mixer_of``): even i a ``Mamba1``
-    selective scan (``ssm_state_size`` state entries a channel of
-    ``mamba_expand`` x hidden, a biased filter of ``conv_kernel`` taps, a
-    step through a projection of rank hidden / 16 rounded up;
-    ``ops/selective_scan.py``) up to i = N / 2, whose scan output is the
-    MEMORY, and a ``GatedMemory`` unit on that memory behind it; odd i
-    attention over the layer's own keys up to i = N / 2 + 1 (under
-    ``sliding_window`` before N / 2, full at N / 2 + 1, whose k and v are
-    SHARED), and cross-attention to those k and v behind it; every layer
-    with its SwiGLU.  ``attention_kind`` ``"differential"``
-    (``DifferentialAttention``, arXiv:2410.05258): adjacent heads pair, a
-    pair's two softmax maps are subtracted under a learned lambda and normed
-    over the pair's value lanes; its projections have biases and it does not
-    rotate (``rope_theta`` None).
-    ``layer_norm_eps`` (a float; None: ``RMSNorm`` with ``rms_eps``) makes
-    every norm of the stack a ``LayerNorm`` with a scale and a bias;
-    ``tie_word_embeddings`` makes the head the embedding's transpose.
-    Generation, the serve plane and the pipelined step refuse each by name.
-    """
+    ``models/generation.py``, the serve plane and the pipelined step keep a
+    decoder layer of their own, the plain one: full attention that rotates,
+    normed a head if at all, and a dense SwiGLU, both pre-norm, in one pass
+    (a served looped model would keep a cache a pass; the pipelined step
+    walks the stack once).  They refuse T > 1 and, by name, every other
+    value of the fields above (``refuse_new_kinds``)."""
 
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -402,29 +535,6 @@ class LlamaConfig:
             raise ValueError(f"attention_kind is {self.attention_kind!r}: "
                              f"'full', 'latent' or 'sparse', or "
                              f"'differential' in a stack with mb_per_layer")
-        if self.attention_kind == "differential" and (
-                not self.mb_per_layer or self.rope_theta is not None
-                or self.num_heads % 2 or self.num_kv_heads % 2
-                or self.qk_norm or self.gating is not None):
-            raise ValueError(
-                "differential attention is built for a decoder-hybrid-decoder "
-                "stack (mb_per_layer): it pairs adjacent heads (an even "
-                "count of query and of key-value heads) and neither rotates "
-                "(rope_theta=None), norms nor gates them")
-        if self.mb_per_layer:
-            if (self.mb_per_layer != 2 or self.num_layers % 4
-                    or not self.ssm_state_size or self.sliding_window is None
-                    or self.layer_types is not None
-                    or self.hybrid_override_pattern is not None
-                    or self.norm_placement != "pre" or self.num_experts > 1
-                    or self.total_ut_steps != 1
-                    or self.attention_kind == "latent"):
-                raise ValueError(
-                    "mb_per_layer is 2 (every second layer a Mamba-kind "
-                    "mixer) or 0: the placement needs num_layers % 4 == 0, "
-                    "ssm_state_size and sliding_window, one pass over "
-                    "pre-norm dense layers, and neither layer_types nor "
-                    "hybrid_override_pattern, which it replaces")
         if self.attention_kind == "sparse" and not (
                 self.index_heads and self.index_head_dim
                 and self.index_topk):
@@ -450,48 +560,10 @@ class LlamaConfig:
         if self.qk_norm_over not in ("head", "all"):
             raise ValueError(f"qk_norm_over is {self.qk_norm_over!r}: "
                              f"'head' or 'all'")
-        if self.layer_types is not None:
-            if (len(self.layer_types) != self.num_layers
-                    or set(self.layer_types) - set(LAYER_TYPES)):
-                raise ValueError(
-                    f"layer_types is {self.layer_types!r}: one of "
-                    f"{LAYER_TYPES} for each of {self.num_layers} layers")
-            if self.has_linear_layers and (
-                    not (self.linear_num_key_heads
-                         and self.linear_key_head_dim
-                         and self.linear_value_head_dim)
-                    or self.linear_num_value_heads
-                    % self.linear_num_key_heads):
-                raise ValueError(
-                    "linear attention needs linear_num_key_heads, "
-                    "linear_key_head_dim, linear_value_head_dim and "
-                    "linear_num_value_heads, a multiple of the key heads")
         if self.conv_bias:
             raise ValueError(
                 "conv_bias is True: a \"conv\" layer's projections and filter "
                 "are built without a bias (LFM2 publishes false); not built")
-        if self.has_conv_layers and self.conv_L_cache < 1:
-            raise ValueError(f"conv_L_cache is {self.conv_L_cache}: a "
-                             f"\"conv\" layer's filter has at least one tap")
-        sliding = ("sliding_attention" in (self.layer_types or ())
-                   or bool(self.mb_per_layer))
-        if sliding != (self.sliding_window is not None) or (
-                sliding and (self.sliding_window < 1 or self.attention_kind
-                             not in ("full", "differential"))):
-            raise ValueError(
-                f"sliding_window is {self.sliding_window!r} and layer_types "
-                f"{self.layer_types!r}: a window of at least 1 goes with "
-                f"'sliding_attention' layers (or mb_per_layer's) of "
-                f"attention_kind 'full' or 'differential', and with nothing "
-                f"else")
-        heads = self.num_attention_heads_per_layer
-        if heads is not None and (
-                len(heads) != self.num_layers
-                or any(n < 1 or n % self.num_kv_heads for n in heads)):
-            raise ValueError(
-                f"num_attention_heads_per_layer is {heads!r}: a multiple of "
-                f"the {self.num_kv_heads} key-value heads for each of "
-                f"{self.num_layers} layers")
         if self.gating not in (None, "per-head", "elementwise"):
             raise ValueError(f"gating is {self.gating!r}: 'per-head', "
                              f"'elementwise' or None")
@@ -512,48 +584,9 @@ class LlamaConfig:
         if self.mlp_hidden_act not in ("silu", "relu2"):
             raise ValueError(f"mlp_hidden_act is {self.mlp_hidden_act!r}: "
                              f"'silu' (gated) or 'relu2' (not gated)")
-        pattern = self.hybrid_override_pattern
-        if pattern is not None:
-            if DENSE_LAYER in pattern:
-                raise ValueError(
-                    f"hybrid_override_pattern {pattern!r} holds a dense "
-                    f"feed-forward layer ({DENSE_LAYER!r}): a layer that is "
-                    f"a dense MLP alone is not built")
-            if (len(pattern) != self.num_layers
-                    or set(pattern) - set(PATTERN_KINDS)):
-                raise ValueError(
-                    f"hybrid_override_pattern is {pattern!r}: one of "
-                    f"{PATTERN_KINDS} for each of {self.num_layers} layers")
-            if (self.layer_types is not None or self.norm_placement != "pre"
-                    or self.first_dense_layers):
-                raise ValueError(
-                    "hybrid_override_pattern names every layer's ONE "
-                    "sublayer behind a pre-norm: layer_types, "
-                    "norm_placement='post' and first_dense_layers do not "
-                    "go with it")
-            if EXPERTS in pattern and self.num_experts < 2:
-                raise ValueError("an 'E' layer needs num_experts > 1")
-            if MAMBA in pattern and (
-                    not (self.mamba_num_heads and self.mamba_head_dim
-                         and self.ssm_state_size)
-                    or self.mamba_num_heads % self.n_groups
-                    or self.mamba_num_heads * self.mamba_head_dim
-                    % self.n_groups):
-                raise ValueError(
-                    "an 'M' layer needs mamba_num_heads, mamba_head_dim and "
-                    "ssm_state_size, the heads a multiple of n_groups")
-        if self.rope_parameters is not None:
-            kinds = {kind for kind, _ in self.rope_parameters}
-            used = set(self.layer_types or ("full_attention",)) - {
-                "linear_attention", "conv"}
-            if not used <= kinds <= set(LAYER_TYPES) or any(
-                    not 0.0 < r.partial_rotary_factor <= 1.0
-                    or int(r.partial_rotary_factor * self.head_dim) % 2
-                    for _, r in self.rope_parameters):
-                raise ValueError(
-                    f"rope_parameters names {sorted(kinds)}: an entry for "
-                    f"each softmax layer type in use ({sorted(used)}), each "
-                    f"turning a whole number of pairs of a head")
+        # What each layer is, decided (or refused) once.  Not a field: configs
+        # are equal, and ``dataclasses.replace`` copies, by the fields alone.
+        object.__setattr__(self, "layers", _layer_specs(self))
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -583,16 +616,25 @@ class LlamaConfig:
     def experts_held(self) -> int:
         return self.held_experts or self.num_experts
 
+    def _holds(self, mixer: str) -> bool:
+        return any(spec.mixer == mixer for spec in self.layers)
+
+    @property
+    def layers_share(self) -> bool:
+        """Whether some layer reads what another left beside its output
+        (``LayerSpec.reads``, ``writes``): ``shared`` goes layer to layer."""
+        return any(spec.reads or spec.writes for spec in self.layers)
+
     def kind_of(self, layer: int) -> Optional[str]:
-        """``layer``'s one sublayer where ``hybrid_override_pattern`` names
-        it (``"M"``, ``"E"`` or ``"*"``); None: a mixer and a feed-forward."""
-        pattern = self.hybrid_override_pattern
-        return None if pattern is None else pattern[layer]
+        """``layer``'s one sublayer under ``hybrid_override_pattern``'s name
+        for it (``"M"``, ``"E"`` or ``"*"``); None: a mixer and a
+        feed-forward."""
+        spec = self.layers[layer]
+        return {MAMBA2: MAMBA, SELF_ATTENTION: ATTENTION, None: EXPERTS}[
+            spec.mixer] if spec.norms == ONE_NORM else None
 
     def is_routed(self, layer: int) -> bool:
-        if self.hybrid_override_pattern is not None:
-            return self.kind_of(layer) == EXPERTS
-        return self.num_experts > 1 and layer >= self.first_dense_layers
+        return self.layers[layer].ffn == ROUTED
 
     @property
     def mamba_inner(self) -> int:
@@ -601,39 +643,27 @@ class LlamaConfig:
 
     @property
     def has_linear_layers(self) -> bool:
-        return "linear_attention" in (self.layer_types or ())
+        return self._holds(DELTA_RULE)
 
     def is_linear(self, layer: int) -> bool:
         """Whether ``layer``'s mixer is the gated delta rule."""
-        return (self.layer_types is not None
-                and self.layer_types[layer] == "linear_attention")
+        return self.layers[layer].mixer == DELTA_RULE
 
     @property
     def has_conv_layers(self) -> bool:
-        return "conv" in (self.layer_types or ())
+        return self._holds(SHORT_CONV)
 
     def is_conv(self, layer: int) -> bool:
         """Whether ``layer``'s mixer is the double-gated short convolution."""
-        return self.layer_type(layer) == "conv"
+        return self.layers[layer].mixer == SHORT_CONV
 
     def layer_type(self, layer: int) -> str:
-        if self.mb_per_layer:
-            return ("sliding_attention" if layer < self.num_layers // 2
-                    else "full_attention")
-        return ("full_attention" if self.layer_types is None
-                else self.layer_types[layer])
+        return self.layers[layer].type
 
     def mixer_of(self, layer: int) -> Optional[str]:
-        """``layer``'s mixer by the decoder-hybrid-decoder rule (one of
-        ``SHARING_MIXERS``); None: the stack shares nothing
-        (``mb_per_layer`` 0).  Layer N / 2 is the last scan, whose output is
-        the memory; layer N / 2 + 1 the last to project keys and values."""
-        if not self.mb_per_layer:
-            return None
-        half = self.num_layers // 2
-        if layer % self.mb_per_layer == 0:
-            return SCAN if layer <= half else MEMORY_GATE
-        return SELF_ATTENTION if layer <= half + 1 else CROSS_ATTENTION
+        """``layer``'s mixer in a stack whose layers share tensors (one of
+        ``SHARING_MIXERS``); None: the stack shares nothing."""
+        return self.layers[layer].mixer if self.layers_share else None
 
     @property
     def scan_inner(self) -> int:
@@ -647,29 +677,22 @@ class LlamaConfig:
 
     def heads_of(self, layer: int) -> int:
         """Query heads of ``layer``'s softmax mixer."""
-        if self.num_attention_heads_per_layer is None:
-            return self.num_heads
-        return self.num_attention_heads_per_layer[layer]
+        return self.layers[layer].heads
 
     def window_of(self, layer: int) -> Optional[int]:
         """The keys a query of ``layer`` sees behind it, itself included;
         None: all of them."""
-        return (self.sliding_window
-                if self.layer_type(layer) == "sliding_attention" else None)
+        return self.layers[layer].window
 
     def rope_of(self, layer: int) -> Optional[RopeParameters]:
         """The rotation of ``layer``'s q and k; None: they do not turn (a
         linear layer has no entry in ``rope_parameters``)."""
-        if self.rope_parameters is not None:
-            return dict(self.rope_parameters).get(self.layer_type(layer))
-        if self.rope_theta is None:
-            return None
-        return RopeParameters(self.rope_theta, self.rope_scaling)
+        return self.layers[layer].rope
 
     def refuse_new_kinds(self, who: str) -> None:
         """For the paths that keep a decoder layer of their own and have
         learned neither kind (ROADMAP.md D1): raise, naming the kind."""
-        if self.mb_per_layer:
+        if self.layers_share:
             half = self.num_layers // 2
             raise NotImplementedError(
                 f"{who} has no path for a decoder-hybrid-decoder stack "
@@ -704,7 +727,7 @@ class LlamaConfig:
                 f"{self.hidden_size} wide, and a decode step would shift "
                 f"them in place; not built")
         pattern = self.hybrid_override_pattern
-        if pattern is not None and MAMBA in pattern:
+        if self._holds(MAMBA2):
             raise NotImplementedError(
                 f"{who} has no path for Mamba-2 state-space layers "
                 f"(hybrid_override_pattern holds {MAMBA!r}): beside K and V "
@@ -991,13 +1014,13 @@ class _Kernel(nn.Module):
 
 
 class LlamaAttention(nn.Module):
-    """Softmax attention over grouped-query heads.  The layer's ``index``
-    in the stack decides what the config lets differ by layer: the count
-    of query heads (``LlamaConfig.heads_of``), the window its queries see
-    (``window_of``: handed to ``attention_fn`` as ``window=``, under
-    ``hvd.attn.window``) and, with ``gating`` ``"per-head"``, the gate
-    ``sigmoid(x W_g)`` a head on the attention's output (under
-    ``hvd.attn.gate``); ``cos``, ``sin`` are the layer's own tables.
+    """Softmax attention over grouped-query heads.  What differs by layer
+    the config says of the layer's ``index`` (``heads_of``, ``window_of``:
+    views of its ``LayerSpec``): the count of query heads and the window its
+    queries see (handed to ``attention_fn`` as ``window=``, under
+    ``hvd.attn.window``); ``cos``, ``sin`` are the layer's own tables.  With
+    ``gating`` ``"per-head"`` the gate ``sigmoid(x W_g)`` a head multiplies
+    the attention's output (under ``hvd.attn.gate``).
 
     With ``gating`` ``"elementwise"`` ``wq`` is ``[hidden, heads * 2 * D]``,
     a head's columns its query and then its gate, and the output is
@@ -1017,7 +1040,7 @@ class LlamaAttention(nn.Module):
         cfg = self.config
         B, S, _ = x.shape
         D = cfg.head_dim
-        heads = cfg.heads_of(self.index)
+        heads, window = cfg.heads_of(self.index), cfg.window_of(self.index)
         if cfg.gating == "elementwise":
             wq = _Kernel((x.shape[-1], heads * 2 * D), cfg.dtype,
                          name="wq")().reshape(-1, heads, 2, D)
@@ -1051,7 +1074,6 @@ class LlamaAttention(nn.Module):
             return apply_rope(x, cos, sin, in_place=self.in_place,
                               scale=scale, eps=cfg.rms_eps)
 
-        window = cfg.window_of(self.index)
         with (_scopes.scope(_scopes.ATTN_WINDOW) if window is not None
               else contextlib.nullcontext()):
             with (_scopes.scope(_scopes.QK_NORM) if cfg.qk_norm
@@ -2080,6 +2102,7 @@ class GatedMemory(nn.Module):
     gate product into ``out_proj``'s matmul, and a fusion has one name."""
 
     config: LlamaConfig
+    in_place: bool = False      # every mixer is told; this one has no pass
 
     @nn.compact
     def __call__(self, x, memory):
@@ -2094,35 +2117,27 @@ class GatedMemory(nn.Module):
 ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention,
                    "sparse": SparseAttention,
                    "differential": DifferentialAttention}
+# A ``LayerSpec.mixer``'s name under its layer and its class; None:
+# ``attention_kind``'s, which is handed the layer's tables too.
+MIXERS = {SELF_ATTENTION: ("attn", None), CROSS_ATTENTION: ("attn", None),
+          DELTA_RULE: ("linear", GatedDeltaNet),
+          SHORT_CONV: ("conv", GatedShortConv), MAMBA2: ("mamba", Mamba2),
+          SCAN: ("mamba", Mamba1), MEMORY_GATE: ("gmu", GatedMemory)}
 
 
 class LlamaLayer(nn.Module):
-    """A mixer and a feed-forward, both residual, each with one norm
-    (``_stack_norm``: an ``RMSNorm``, or a ``LayerNorm`` where the config
-    states ``layer_norm_eps``): on the sublayer's input (``norm_placement``
-    ``"pre"``: ``x + Mixer(Norm(x))``) or on its output inside the residual
-    (``"post"``, OLMo 2's: ``x + Norm(Mixer(x))``).  Which mixer and which
-    feed-forward, the config says of the layer's ``index`` in the stack:
-    ``LlamaConfig.is_linear`` (a ``GatedDeltaNet`` as ``"linear"``),
-    ``LlamaConfig.is_conv`` (a ``GatedShortConv`` as ``"conv"``), else
-    ``attention_kind``'s as ``"attn"``, which is told the index too: its
-    head count, window and gate may differ by layer, and
-    ``LlamaConfig.is_routed``.  ``cos``, ``sin`` are the tables of this
-    layer's type.
+    """Layer ``index`` of the stack, as ``config.layers[index]`` (a
+    ``LayerSpec``) says: its mixer (``MIXERS``) under ``hvd.block.attn`` and
+    its feed-forward (a ``SwiGLU`` as ``"mlp"``, ``RoutedExperts`` as
+    ``"moe"``) under ``hvd.block.ffn``, those it has, each residual with one
+    of the spec's ``norms`` (``_stack_norm``): on the sublayer's input or,
+    with ``norm_placement`` ``"post"``, on its output inside the residual.
+    ``cos``, ``sin`` are the tables of the spec's ``rope``.
 
-    Where ``hybrid_override_pattern`` names the layer's kind
-    (``LlamaConfig.kind_of``) the layer is ONE sublayer behind ONE norm
-    (``"norm"``) with one residual add: a ``Mamba2`` (``"mamba"``) or
-    ``attention_kind``'s mixer (``"attn"``) under ``hvd.block.attn``, or
-    ``RoutedExperts`` (``"moe"``) under ``hvd.block.ffn``.
-
-    In a decoder-hybrid-decoder stack (``LlamaConfig.mixer_of``) the layer
-    takes and returns ``shared`` beside x, and this is the ONE place that
-    knows who writes it and who reads it: layer N / 2, the last ``Mamba1``
-    (``"mamba"``), writes its scan's output as ``shared["memory"]``, which
-    every ``GatedMemory`` (``"gmu"``) behind it reads; layer N / 2 + 1, the
-    last to project keys and values, writes them as ``shared["kv"]``, which
-    every later attention layer attends to in place of its own."""
+    Where layers share tensors (``LlamaConfig.layers_share``) the layer
+    takes and returns ``shared`` beside x: its mixer is handed
+    ``shared[spec.reads]`` beside the normed state, and what the mixer
+    returns beside its output is left as ``shared[spec.writes]``."""
 
     config: LlamaConfig
     attention_fn: Callable = staticmethod(causal_attention)
@@ -2131,48 +2146,33 @@ class LlamaLayer(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin, shared=None):
         cfg = self.config
+        spec = cfg.layers[self.index]
         # The one reading of the rule: every mixer is handed the answer.
         in_place = _reads_in_place(self.attention_fn)
-        kind = cfg.kind_of(self.index)
-        role = cfg.mixer_of(self.index)
-        half = cfg.num_layers // 2
         wrote = {}
         mixer = ffn = None
+        if spec.mixer is not None:
+            name, cls = MIXERS[spec.mixer]
+            if cls is None:
+                module = ATTENTION_KINDS[cfg.attention_kind](
+                    cfg, attention_fn=self.attention_fn, index=self.index,
+                    in_place=in_place, name=name)
+                beside = (cos, sin)
+            else:
+                module, beside = cls(cfg, in_place=in_place, name=name), ()
+            if spec.reads is not None:
+                beside += (shared[spec.reads],)
 
-        def attention():
-            return ATTENTION_KINDS[cfg.attention_kind](
-                cfg, attention_fn=self.attention_fn, index=self.index,
-                in_place=in_place, name="attn")
-
-        if role == SCAN:
             def mixer(h):
-                out, y = Mamba1(cfg, in_place=in_place, name="mamba")(h)
-                if self.index == half:
-                    wrote["memory"] = y
+                out = module(h, *beside)
+                if isinstance(out, tuple):      # and what others may read
+                    out, made = out
+                    if spec.writes is not None:
+                        wrote[spec.writes] = made
                 return out
-        elif role == MEMORY_GATE:
-            mixer = functools.partial(GatedMemory(cfg, name="gmu"),
-                                      memory=shared["memory"])
-        elif role in (SELF_ATTENTION, CROSS_ATTENTION):
-            def mixer(h):
-                out, kv = attention()(
-                    h, cos, sin, shared["kv"] if role == CROSS_ATTENTION
-                    else None)
-                if self.index == half + 1:
-                    wrote["kv"] = kv
-                return out
-        elif kind == MAMBA:
-            mixer = Mamba2(cfg, in_place=in_place, name="mamba")
-        elif cfg.is_linear(self.index):
-            mixer = GatedDeltaNet(cfg, in_place=in_place, name="linear")
-        elif cfg.is_conv(self.index):
-            mixer = GatedShortConv(cfg, in_place=in_place, name="conv")
-        elif kind != EXPERTS:
-            mixer = functools.partial(attention(), cos=cos, sin=sin)
-        if cfg.is_routed(self.index):
-            ffn = RoutedExperts(cfg, in_place=in_place, name="moe")
-        elif kind is None:
-            ffn = SwiGLU(cfg, name="mlp")
+        if spec.ffn is not None:
+            ffn = (RoutedExperts(cfg, in_place=in_place, name="moe")
+                   if spec.ffn == ROUTED else SwiGLU(cfg, name="mlp"))
 
         def residual(x, sublayer, norm):
             norm = _stack_norm(cfg, norm)
@@ -2184,12 +2184,11 @@ class LlamaLayer(nn.Module):
         # with the neighbouring products (common/scopes.py).
         if mixer is not None:
             with _scopes.scope(_scopes.BLOCK_ATTN):
-                x = residual(x, mixer, "norm_attn" if kind is None
-                             else "norm")
+                x = residual(x, mixer, spec.norms[0])
         if ffn is not None:
             with _scopes.scope(_scopes.BLOCK_FFN):
-                x = residual(x, ffn, "norm_mlp" if kind is None else "norm")
-        return x if role is None else (x, {**shared, **wrote})
+                x = residual(x, ffn, spec.norms[-1])
+        return x if shared is None else (x, {**shared, **wrote})
 
 
 class LlamaModel(nn.Module):
@@ -2229,8 +2228,7 @@ class LlamaModel(nn.Module):
         # One table a distinct rotation, made once and handed to the layers
         # of its type (a stack with one rotation: one table, as before).
         tables = {None: (None, None)}
-        for i in range(cfg.num_layers):
-            rope = cfg.rope_of(i)
+        for rope in map(cfg.rope_of, range(cfg.num_layers)):
             if rope not in tables:
                 tables[rope] = rope_freqs(
                     cfg.rope_dim, S, rope.rope_theta,
@@ -2248,7 +2246,7 @@ class LlamaModel(nn.Module):
             layer beside x, through ``nn.remat`` as a layer's input and
             output (kept, like x: a reader's gradient reaches its writer
             through them)."""
-            shared = {} if cfg.mb_per_layer else None
+            shared = {} if cfg.layers_share else None
             for i in range(cfg.num_layers):
                 layer = layer_cls(cfg, attention_fn=self.attention_fn,
                                   index=i, name=f"layer_{i}", parent=mdl)

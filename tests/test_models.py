@@ -1,5 +1,7 @@
 """Model zoo shape/forward tests (CPU, tiny configs)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,7 @@ from horovod_tpu.models import (
     SkipGramModel,
     nce_loss,
 )
+from horovod_tpu.models.llama import LayerSpec, RopeParameters
 
 
 def test_mnist_convnet_forward():
@@ -159,3 +162,101 @@ def test_rms_norm_gradient_is_the_formulas(dtype):
     np.testing.assert_allclose(np.asarray(dscale, np.float64), want_dscale,
                                rtol=2e-2 if loose else 1e-5,
                                atol=5e-2 if loose else 1e-4)
+
+
+# -- what a decoder layer is: ``LlamaConfig.layers`` ---------------------------
+
+def _spec(mixer="attention", ffn="dense", heads=4, window=None,
+          rope=RopeParameters(10000.0), type="full_attention", **wiring):
+    return LayerSpec(mixer, ffn, heads, window, rope, type, **wiring)
+
+
+def _tiny(**changes):
+    return dataclasses.replace(LlamaConfig.tiny(), **changes)
+
+
+_HALF_TURN = RopeParameters(5e5, None, 0.5)
+_ONE, _STILL = dict(norms=("norm",)), dict(rope=None)
+
+
+@pytest.mark.parametrize("cfg, specs", [
+    pytest.param(_tiny(), (_spec(), _spec()), id="plain"),
+    pytest.param(
+        _tiny(num_layers=3, num_experts=4, first_dense_layers=1),
+        (_spec(), _spec(ffn="routed"), _spec(ffn="routed")),
+        id="first_dense_layers"),
+    pytest.param(
+        _tiny(num_layers=4, sliding_window=16, layer_types=(
+            "sliding_attention", "linear_attention", "conv",
+            "full_attention"), linear_num_key_heads=2,
+            linear_num_value_heads=2, linear_key_head_dim=8,
+            linear_value_head_dim=8),
+        (_spec(window=16, type="sliding_attention"),
+         _spec("linear_attention", type="linear_attention"),
+         _spec("conv", type="conv"), _spec()),
+        id="layer_types"),
+    pytest.param(
+        _tiny(num_layers=5, hybrid_override_pattern="MEM*E", num_experts=4,
+              mamba_num_heads=4, mamba_head_dim=16, ssm_state_size=16),
+        (_spec("mamba2", None, **_ONE), _spec(None, "routed", **_ONE),
+         _spec("mamba2", None, **_ONE), _spec("attention", None, **_ONE),
+         _spec(None, "routed", **_ONE)),
+        id="hybrid_override_pattern"),
+    pytest.param(
+        _tiny(num_layers=8, mb_per_layer=2, sliding_window=16,
+              ssm_state_size=8, rope_theta=None,
+              attention_kind="differential"),
+        (_spec("mamba", window=16, type="sliding_attention", **_STILL),
+         _spec("attention", window=16, type="sliding_attention", **_STILL),
+         _spec("mamba", window=16, type="sliding_attention", **_STILL),
+         _spec("attention", window=16, type="sliding_attention", **_STILL),
+         _spec("mamba", writes="memory", **_STILL),
+         _spec("attention", writes="kv", **_STILL),
+         _spec("gated_memory", reads="memory", **_STILL),
+         _spec("cross_attention", reads="kv", **_STILL)),
+        id="mb_per_layer"),
+    pytest.param(
+        _tiny(num_layers=3, attention_head_dim=16, sliding_window=8,
+              layer_types=("full_attention",) + ("sliding_attention",) * 2,
+              num_attention_heads_per_layer=(4, 6, 6), rope_parameters=(
+                  ("full_attention", _HALF_TURN),
+                  ("sliding_attention", RopeParameters(1e4)))),
+        (_spec(rope=_HALF_TURN),
+         _spec(heads=6, window=8, type="sliding_attention"),
+         _spec(heads=6, window=8, type="sliding_attention")),
+        id="heads_and_rope_a_layer"),
+])
+def test_layer_specs(cfg, specs):
+    """One mechanism a case: the whole stack as ``_layer_specs`` resolves
+    it, and the nine accessors as nothing but views of it."""
+    assert cfg.layers == specs
+    assert cfg.num_layers == len(specs)
+    shares = any(s.reads or s.writes for s in specs)
+    published = {("mamba2", None): "M", (None, "routed"): "E",
+                 ("attention", None): "*"}
+    for i, s in enumerate(specs):
+        assert (cfg.kind_of(i), cfg.is_routed(i), cfg.is_linear(i),
+                cfg.is_conv(i), cfg.layer_type(i), cfg.mixer_of(i),
+                cfg.heads_of(i), cfg.window_of(i), cfg.rope_of(i)) == (
+            published[s.mixer, s.ffn] if s.norms == ("norm",) else None,
+            s.ffn == "routed", s.mixer == "linear_attention",
+            s.mixer == "conv", s.type, s.mixer if shares else None,
+            s.heads, s.window, s.rope), i
+    assert cfg.has_linear_layers == any(
+        s.mixer == "linear_attention" for s in specs)
+    assert cfg.has_conv_layers == any(s.mixer == "conv" for s in specs)
+    # No field: a copy resolves its own, and equality, hashing and what
+    # ``dataclasses.replace`` hands to ``__init__`` are the fields' alone.
+    assert "layers" not in {f.name for f in dataclasses.fields(cfg)}
+    copy = dataclasses.replace(cfg, vocab_size=cfg.vocab_size)
+    assert copy.layers == specs and copy == cfg and hash(copy) == hash(cfg)
+
+
+@pytest.mark.parametrize("two", [
+    dict(layer_types=("full_attention",) * 2, hybrid_override_pattern="**"),
+    dict(layer_types=("full_attention",) * 2, mb_per_layer=2),
+    dict(hybrid_override_pattern="**", mb_per_layer=2),
+])
+def test_one_mechanism_names_the_layers_kinds(two):
+    with pytest.raises(ValueError, match=" and ".join(two) + " each name"):
+        _tiny(**two)
