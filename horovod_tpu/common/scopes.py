@@ -171,7 +171,9 @@ scope                 what falls under it
 ``hvd.ssd.gates``     the same layer's elementwise work around the scan:
                       ``softplus(dt + dt_bias)``; behind the scan the skip
                       ``D u``, the gate ``silu(z)`` and the grouped RMSNorm
-                      BEHIND the gate
+                      BEHIND the gate (``ops/gated_norm.py``: its Mosaic
+                      calls where the model's ``attention_fn`` reads its
+                      operands in place, else its ``jnp`` body)
 ``hvd.ssd.scan``      the chunked state-space recurrence itself
                       (``ops/ssd.py::ssd_scan``): the log-decays, cutting
                       into chunks, every chunk's products, the walk over the
@@ -336,7 +338,7 @@ __all__ = [
     "MOSAIC", "MOSAIC_FLASH_FWD", "MOSAIC_FLASH_BWD", "MOSAIC_ROPE",
     "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
     "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION", "MOSAIC_SSCAN",
-    "MOSAIC_GROUPED_MATMUL",
+    "MOSAIC_GROUPED_MATMUL", "MOSAIC_GATED_NORM",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
 ]
@@ -401,6 +403,7 @@ MOSAIC_INDEX_LOSS = MOSAIC + "index_loss"
 MOSAIC_PAGED_ATTENTION = MOSAIC + "paged_attention"
 MOSAIC_SSCAN = MOSAIC + "selective_scan"
 MOSAIC_GROUPED_MATMUL = MOSAIC + "grouped_matmul"
+MOSAIC_GATED_NORM = MOSAIC + "gated_norm"
 INIT = "hvd.init"
 INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
 INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize

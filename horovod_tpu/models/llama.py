@@ -49,6 +49,7 @@ from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
 from horovod_tpu.ops.gated_delta import (calls_in_place, gated_delta_rule,
                                          gated_delta_states)
+from horovod_tpu.ops.gated_norm import gated_norm, skipped
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops import rope as _rope
@@ -1904,19 +1905,6 @@ def _mamba_a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4))
-def _gate_then_norm(y, z, scale, groups, eps):
-    """``rms_norm_g(y * silu(z)) * scale``: the gate FIRST, then the norm
-    over each of ``groups`` runs of lanes (Mamba-2's ``norm_before_gate=
-    False``); y, z ``[B, S, C]``, ``scale [C]``; float32 inside, the dtype
-    of z out, and under a checkpoint as ``_gated_norm``."""
-    y = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
-    grouped = y.reshape(*y.shape[:-1], groups, -1)
-    grouped = grouped * jax.lax.rsqrt(
-        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
-    return (grouped.reshape(y.shape) * scale).astype(z.dtype)
-
-
 class Mamba2(nn.Module):
     """The Mamba-2 state-space mixer (state-space duality; Dao & Gu,
     arXiv:2405.21060) of an ``"M"`` layer, as Nemotron-H sizes it.  With
@@ -1935,9 +1923,12 @@ class Mamba2(nn.Module):
 
     The gate multiplies BEFORE the norm, which is over each group's ``H P /
     G`` lanes.  The recurrence runs chunk by chunk (``ops/ssd.py``,
-    ``chunk_size`` rows).  Parameters: ``in_proj [hidden, 2 H P + 2 G N +
-    H]``, ``conv_w [K, H P + 2 G N]``, ``conv_b``, ``a_log dt_bias d [H]``,
-    ``norm [H P]``, ``out_proj``.
+    ``chunk_size`` rows); the skip, the gate and the norm are
+    ``ops/gated_norm.py``'s (one Mosaic pass each way on u and z where the
+    filter and ``in_proj`` left them, where the model's ``attention_fn``
+    reads its operands in place, else its ``jnp`` body).  Parameters:
+    ``in_proj [hidden, 2 H P + 2 G N + H]``, ``conv_w [K, H P + 2 G N]``,
+    ``conv_b``, ``a_log dt_bias d [H]``, ``norm [H P]``, ``out_proj``.
 
     Sown where the caller makes ``ssd_stats`` mutable: ``decay_min``,
     ``decay_mean`` (of a_t), ``dt_mean``, ``state_max`` (the largest |S| a
@@ -1957,7 +1948,6 @@ class Mamba2(nn.Module):
         bc = groups * state
         projected = nn.Dense(2 * inner + 2 * bc + heads, use_bias=False,
                              dtype=cfg.dtype, name="in_proj")(x)
-        z = projected[..., :inner]
         dt = projected[..., 2 * inner + 2 * bc:]
         with _scopes.scope(_scopes.SSD_CONV):
             # (The filter reads its channels where ``in_proj`` left them.)
@@ -1976,10 +1966,8 @@ class Mamba2(nn.Module):
                 "dt_bias", _dt_bias_init, (heads,)))
         with _scopes.scope(_scopes.SSD_SCAN):
             y = ssd_scan(u, dt, a_log, b, c, chunk=cfg.chunk_size)
-        with _scopes.scope(_scopes.SSD_GATES):
-            d = self.param("d", nn.initializers.ones, (heads,))
-            y = (y.astype(jnp.float32) + d[:, None] * u.astype(jnp.float32)
-                 ).astype(u.dtype)
+        y = y.reshape(B, S, inner)
+        d = self.param("d", nn.initializers.ones, (heads,))
         if (self.is_mutable_collection("ssd_stats")
                 and not self.is_initializing()):
             decay = jnp.exp(-jnp.exp(a_log) * dt)
@@ -1989,11 +1977,15 @@ class Mamba2(nn.Module):
                     ("dt_mean", jnp.mean(dt)),
                     ("state_max", jnp.max(jnp.abs(ssd_states(
                         u, dt, a_log, b, c, chunk=cfg.chunk_size)))),
-                    ("out_max", jnp.max(jnp.abs(y.astype(jnp.float32))))):
+                    ("out_max", jnp.max(jnp.abs(skipped(
+                        y, u.reshape(y.shape), d).astype(jnp.float32))))):
                 self.sow("ssd_stats", name, value)
         with _scopes.scope(_scopes.SSD_GATES):
-            y = _gate_then_norm(y.reshape(B, S, inner), z, self.param(
-                "norm", nn.initializers.ones, (inner,)), groups, cfg.rms_eps)
+            # (The skip, the gate and the norm read u and z where the
+            # filter and ``in_proj`` left them: their first channels.)
+            y = gated_norm(y, xbc, projected, d, self.param(
+                "norm", nn.initializers.ones, (inner,)), groups, cfg.rms_eps,
+                self.in_place)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="out_proj")(y)
 

@@ -19,7 +19,7 @@ from benchmark import manifest
 from benchmark.reference import nemotron_h as reference
 from horovod_tpu.models import LlamaConfig, LlamaModel
 from horovod_tpu.models import llama
-from horovod_tpu.ops import grouped_matmul
+from horovod_tpu.ops import gated_norm, grouped_matmul
 from tiny_sizes import TINY
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -101,7 +101,7 @@ def test_a_wrong_version_fails_the_comparison(tiny, monkeypatch, variant):
     job, _, params, bias, batch, wanted = tiny
     wrong = params
     if variant == "norm_before_gate":
-        monkeypatch.setattr(llama, "_gate_then_norm", _norm_then_gate)
+        monkeypatch.setattr(gated_norm, "_gate_then_norm", _norm_then_gate)
     elif variant == "no_skip_d":
         wrong = _without(params, "d")
     elif variant == "no_filter_bias":
@@ -388,7 +388,12 @@ def _equations(jaxpr, primitive):
 
 # -- the filter's two bodies --------------------------------------------------
 
-def test_a_mamba_layer_in_place_is_the_layer_it_is_elsewhere(tiny):
+@pytest.mark.parametrize("width, gates", [(16, "plain"), (32, "mosaic")],
+                         ids=["heads of 16: the filter", "heads of 32: and "
+                              "the gates"])
+def test_a_mamba_layer_in_place_is_the_layer_it_is_elsewhere(tiny, width,
+                                                             gates,
+                                                             monkeypatch):
     """``Mamba2(in_place=True)`` sends its biased filter through
     ``ops/short_conv.py``'s Mosaic pass (interpreted here), which reads x, B
     and C where ``in_proj`` left them; ``in_place=False`` through the
@@ -397,10 +402,17 @@ def test_a_mamba_layer_in_place_is_the_layer_it_is_elsewhere(tiny):
     the cell's at 4096) moved off its start (``conv_b`` no zeros), two rows
     of 96 tokens (three blocks of rows), the two give the same output and
     the same gradient of every leaf: a bias the pass dropped shows in
-    ``conv_b``'s leaf, by name."""
+    ``conv_b``'s leaf, by name.  With heads of 32 a norm group is a lane
+    tile, and the skip, the gate and the norm are ``ops/gated_norm.py``'s
+    pair too (its rule's last reason lifted: a TPU's answer), on the
+    filter's result and ``in_proj``'s output where they lie."""
     from horovod_tpu.ops import short_conv
 
-    config = dataclasses.replace(tiny[0].llama, mamba_num_heads=8)
+    rule = gated_norm._why_not
+    monkeypatch.setattr(gated_norm, "_why_not", lambda *a: (
+        None if rule(*a) == gated_norm.NO_TPU else rule(*a)))
+    config = dataclasses.replace(tiny[0].llama, mamba_num_heads=8,
+                                 mamba_head_dim=width)
     k_init, k_move, k_x = jax.random.split(jax.random.key(53), 3)
     x = jax.random.normal(k_x, (2, 96, config.hidden_size))
     layer = llama.Mamba2(config).init(k_init, x)["params"]
@@ -420,11 +432,14 @@ def test_a_mamba_layer_in_place_is_the_layer_it_is_elsewhere(tiny):
         return {jax.tree_util.keystr(path): leaf for path, leaf in
                 jax.tree_util.tree_leaves_with_path(jax.jit(run)(layer, x))}
 
-    before = short_conv.body_counts()
+    before = short_conv.body_counts(), gated_norm.body_counts()
     got = both_ways(True)
-    after = short_conv.body_counts()
-    assert after["fused"] == before["fused"] + 1
-    assert after["plain"] == before["plain"]
+    after = short_conv.body_counts(), gated_norm.body_counts()
+    assert after[0]["fused"] == before[0]["fused"] + 1
+    assert after[0]["plain"] == before[0]["plain"]
+    assert after[1]["mosaic"] - before[1]["mosaic"] == (gates == "mosaic")
+    assert after[1]["plain"].get(gated_norm.GROUP_OFF_THE_TILE, 0) - before[
+        1]["plain"].get(gated_norm.GROUP_OFF_THE_TILE, 0) == (gates == "plain")
     wanted = both_ways(False)
     assert "['grads'][0]['conv_b']" in got and len(got) == len(
         jax.tree.leaves(layer)) + 2

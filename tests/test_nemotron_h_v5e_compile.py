@@ -6,7 +6,8 @@ a routed layer's grouped products (relu2: two matrices an expert); the
 convolution over a Mamba-2 layer's 6,144 channels, which is the Mosaic pass
 with its bias as without one, on an array of their own or where they lie in
 ``in_proj``'s output; and a Mamba-2 layer whole, forward and backward, whose
-only Mosaic calls are that filter's.  The whole step at 2 x 8192 is compiled
+only Mosaic calls are that filter's and the gates' (``ops/gated_norm.py``: the
+skip, the gate and the grouped norm, one call each way on operands in place).  The whole step at 2 x 8192 is compiled
 by the builder's study and on the chip, not here (it takes a minute)."""
 
 import os
@@ -21,7 +22,7 @@ from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.models import llama
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import grouped_matmul, short_conv
+from horovod_tpu.ops import gated_norm, grouped_matmul, short_conv
 
 CELL = "nemotron-3-nano-30b-a3b.train-s8k-b2"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
@@ -45,7 +46,7 @@ def one_chip(topo, monkeypatch):
     deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for module in (fa, short_conv, grouped_matmul):
+    for module in (fa, short_conv, grouped_matmul, gated_norm):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -203,32 +204,100 @@ def test_the_filter_over_6144_channels(one_chip, bias, first):
         assert not re.search(rf" = bf16\[{B},{S},6144\]\S* slice\(", text)
 
 
-def test_a_mamba_layers_only_mosaic_calls_are_the_filters(one_chip, config):
+_LAYER = {}     # the one compile of a Mamba-2 layer, for the tests that read it
+
+
+@pytest.fixture
+def mamba_layer(one_chip, config):
+    """A ``Mamba2`` layer at 2 x 8192 tokens and the published widths
+    (``inner`` 4096, 8 groups, ``in_proj`` 10304 wide), in place, forward and
+    backward: its compiled text, its temporaries' bytes, and the two op
+    modules' ``body_counts()`` before and after the trace.  Compiled by the
+    first test that asks."""
+    if not _LAYER:
+        before = short_conv.body_counts(), gated_norm.body_counts()
+        compiled = _layer_compiled(llama.Mamba2(config, in_place=True),
+                                   one_chip, config.hidden_size)
+        _LAYER.update(
+            text=compiled.as_text(),
+            temporaries=compiled.memory_analysis().temp_size_in_bytes,
+            conv=(before[0], short_conv.body_counts()),
+            gates=(before[1], gated_norm.body_counts()))
+    return _LAYER
+
+
+def test_a_mamba_layers_mosaic_calls_are_the_filters_and_the_gates(
+        mamba_layer):
     """A ``Mamba2`` layer at 2 x 8192 tokens, forward and backward: its
     biased filter is ``short_conv``'s pass, one call each way under
-    ``hvd.ssd.conv`` (where ``ssd_conv_ms`` reads them), and everything
-    else XLA (the scan is ``jax.numpy``); its three scopes in the text, and
-    no array of a chunk's ``[128, 128]`` for every chunk at once (a slab of
-    8 chunks at a time: 2 x 8 x 64 heads)."""
-    before = short_conv.body_counts()
-    compiled = _layer_compiled(llama.Mamba2(config, in_place=True), one_chip,
-                               config.hidden_size)
-    text = compiled.as_text()
-    after = short_conv.body_counts()
+    ``hvd.ssd.conv`` (where ``ssd_conv_ms`` reads them), its skip, gate and
+    norm ``gated_norm``'s, one each way under ``hvd.ssd.gates``, and
+    everything else XLA (the scan is ``jax.numpy``); its three scopes in the
+    text, and no array of a chunk's ``[128, 128]`` for every chunk at once
+    (a slab of 8 chunks at a time: 2 x 8 x 64 heads)."""
+    text = mamba_layer["text"]
+    before, after = mamba_layer["conv"]
     assert after["fused"] == before["fused"] + 1
     # (``_layer_compiled`` initialises on 8 rows, which no block divides.)
     assert {why for why, n in after["plain"].items()
             if n != before["plain"].get(why, 0)} == {short_conv._NO_ROW_BLOCK}
     calls = _mosaic_calls(text)
-    assert len(calls) == 2
-    assert all(scopes.SSD_CONV in call for call in calls)
+    assert len(calls) == 4
+    assert sum(scopes.SSD_CONV in call for call in calls) == 2
+    assert sum(scopes.SSD_GATES in call for call in calls) == 2
     for scope in (scopes.SSD_CONV, scopes.SSD_GATES, scopes.SSD_SCAN):
         assert scope in text, scope
     assert "f32[64,2,8,8,128,128]" not in text
     assert "f32[8,2,8,8,128,128]" in text
-    # The calls read the filter's channels in ``in_proj``'s output: no
+    # The filter's calls read its channels in ``in_proj``'s output: no
     # ``bf16[2, 8192, 6144]`` cut of them, which as the backward call's
-    # residual is 201 MB more (2.030 GB of temporaries here, 2.231 with it).
+    # residual is 201 MB more.  (Temporaries: 1.493 GB; 2.030 before the
+    # gates were a pass of their own, 2.231 with the cut.)
     assert all(f"bf16[{B},{S},10304]" in call for call in calls)
     assert not re.search(rf" = bf16\[{B},{S},6144\]\S* slice\(", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.13e9
+    assert mamba_layer["temporaries"] < 1.6e9
+
+
+def test_a_mamba_layers_gates_are_one_call_each_way_on_operands_in_place(
+        mamba_layer):
+    """Under ``hvd.ssd.gates`` exactly ``ops/gated_norm.py``'s two calls:
+    forward ``(y, u, z, D, w) -> out``, backward ``(.., go) -> dy, du, dz``
+    and the partial sums of dw and dD.  u is the filter's ``[2, 8192,
+    6144]`` result and z ``in_proj``'s ``[2, 8192, 10304]`` output, the
+    arrays themselves (a block of rows is their first 4096 lanes): no
+    ``[2, 8192, 4096]`` cut of ``in_proj``'s output is made anywhere, no
+    float32 view of the norm's groups (``f32[.., 8, 512]``: 13 ms of
+    re-tiling a step before, PERF.md §5) and no float32 array of the
+    activations' shape.  The calls fit the VMEM they ask for (the compile
+    refuses one that does not), which is the module's stated limit."""
+    text = mamba_layer["text"]
+    before, after = mamba_layer["gates"]
+    assert after["mosaic"] == before["mosaic"] + 1
+    assert {why for why, n in after["plain"].items()
+            if n != before["plain"].get(why, 0)} == {gated_norm.NO_ROW_BLOCK}
+    calls = [call for call in _mosaic_calls(text) if scopes.SSD_GATES in call]
+    assert len(calls) == 2
+    wide = (f"bf16[{B},{S},4096]{{2,1,0}}, bf16[{B},{S},6144]{{2,1,0}}, "
+            f"bf16[{B},{S},10304]{{2,1,0}}, ")
+    activation = rf"bf16\[{B},{S},4096\]\S*"
+    forward, = (call for call in calls if "jit(_forward)" in call)
+    backward, = (call for call in calls if "jit(_backward)" in call)
+    assert "transpose(" not in forward and "transpose(" in backward
+    assert f"operand_layout_constraints={{{wide}f32[1,4096]" in forward
+    assert re.match(rf"\s*%\S+ = {activation} custom-call\(", forward)
+    assert (f"operand_layout_constraints={{{wide}bf16[{B},{S},4096]{{2,1,0}}, "
+            "f32[1,4096]") in backward
+    assert re.match(rf"\s*%\S+ = \({activation}, {activation}, {activation}, "
+                    rf"f32\[{B},16,4096\]\S*\) custom-call\(", backward)
+    sliced = re.findall(rf" = bf16\[{B},{S},4096\]\S* slice\((%[\w.\-]+)\)",
+                        text)
+    made = {name: line for line in text.splitlines()
+            for name in re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = ", line)}
+    assert all(f"bf16[{B},{S},10304]" not in made[name].split(" = ")[1].split(
+        "(")[0] for name in sliced), sliced
+    assert not re.search(r"f32\[[\d,]+,8,512\]", text)
+    assert not re.search(rf"f32\[{B},{S},4096\]", text)
+    for call in calls:
+        stated = re.search(r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                           r'"offset":"0","size":"(\d+)"', call)
+        assert int(stated.group(1)) == gated_norm._VMEM_LIMIT <= 2 ** 27
