@@ -180,6 +180,11 @@ scope                 what falls under it
                       chunks that carries the state; forward, run again
                       under recomputation and backward; XLA operations, and
                       Mosaic calls should a later kernel replace part of it
+``hvd.ssd.proj``      the same layer's two projections: ``in_proj [hidden,
+                      2 H P + 2 G N + H]`` to z, x, B, C and dt, and
+                      ``out_proj``; and their gradient products (a name
+                      alone: nothing else tells them from attention's
+                      projections inside ``hvd.block.attn``)
 ``hvd.sscan.conv``    a Mamba-1 layer's (``models/llama.py::Mamba1``) causal
                       depthwise convolution over its u channels where
                       ``in_proj`` left them, the filter's bias and the SiLU:
@@ -328,7 +333,7 @@ __all__ = [
     "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
-    "SSD_CONV", "SSD_GATES", "SSD_SCAN",
+    "SSD_CONV", "SSD_GATES", "SSD_SCAN", "SSD_PROJ",
     "SSCAN_CONV", "SSCAN_GATES", "SSCAN_SCAN", "LCONV_PROJ", "LCONV_CONV",
     "GMU", "ATTN_DIFF",
     "BLOCK_ATTN", "BLOCK_FFN", "HEAD",
@@ -373,6 +378,7 @@ GDN_SOLVE = "hvd.gdn.solve"       # inside GDN_SCAN
 SSD_CONV = "hvd.ssd.conv"
 SSD_GATES = "hvd.ssd.gates"
 SSD_SCAN = "hvd.ssd.scan"
+SSD_PROJ = "hvd.ssd.proj"
 SSCAN_CONV = "hvd.sscan.conv"
 SSCAN_GATES = "hvd.sscan.gates"
 SSCAN_SCAN = "hvd.sscan.scan"

@@ -95,10 +95,15 @@ DENSE_LAYER = "-"       # published too; no model here has one: refused
 SCAN, SELF_ATTENTION, MEMORY_GATE, CROSS_ATTENTION = SHARING_MIXERS = (
     "mamba", "attention", "gated_memory", "cross_attention")
 DELTA_RULE, SHORT_CONV, MAMBA2 = "linear_attention", "conv", "mamba2"
-# ``layer_types``' published names, and the mixer each gives its layer.
+# ``layer_types``' published names, and the mixer each gives its layer.  The
+# TYPE ``"mamba"`` (Granite-4.0-H's name) is a Mamba-2 layer, ``MAMBA2``; the
+# MIXER named ``"mamba"``, ``SCAN``, is Mamba-1's, which no type names.
 TYPE_MIXERS = {"full_attention": SELF_ATTENTION,
                "linear_attention": DELTA_RULE,
-               "sliding_attention": SELF_ATTENTION, "conv": SHORT_CONV}
+               "sliding_attention": SELF_ATTENTION, "conv": SHORT_CONV,
+               "mamba": MAMBA2, "attention": SELF_ATTENTION}
+# The types whose layers have no softmax, so no entry in ``rope_parameters``.
+NO_SOFTMAX_TYPES = ("linear_attention", "conv", "mamba")
 LAYER_TYPES = tuple(TYPE_MIXERS)
 DENSE, ROUTED = "dense", "routed"       # a ``LayerSpec``'s feed-forwards
 ONE_NORM, TWO_NORMS = ("norm",), ("norm_attn", "norm_mlp")
@@ -274,14 +279,6 @@ def _layer_specs(cfg: "LlamaConfig") -> tuple:
                 "first_dense_layers do not go with it")
         if EXPERTS in pattern and cfg.num_experts < 2:
             raise ValueError("an 'E' layer needs num_experts > 1")
-        if MAMBA in pattern and (
-                not (cfg.mamba_num_heads and cfg.mamba_head_dim
-                     and cfg.ssm_state_size)
-                or cfg.mamba_num_heads % cfg.n_groups
-                or cfg.mamba_num_heads * cfg.mamba_head_dim % cfg.n_groups):
-            raise ValueError(
-                "an 'M' layer needs mamba_num_heads, mamba_head_dim and "
-                "ssm_state_size, the heads a multiple of n_groups")
         sublayer = {MAMBA: (MAMBA2, None), EXPERTS: (None, ROUTED),
                     ATTENTION: (SELF_ATTENTION, None)}
         specs = [spec(i, *sublayer[kind], norms=ONE_NORM)
@@ -307,6 +304,16 @@ def _layer_specs(cfg: "LlamaConfig") -> tuple:
         if SHORT_CONV in (types or ()) and cfg.conv_L_cache < 1:
             raise ValueError(f"conv_L_cache is {cfg.conv_L_cache}: a "
                              f"\"conv\" layer's filter has at least one tap")
+    # A Mamba-2 layer's sizes, however the layer was named.
+    if any(spec.mixer == MAMBA2 for spec in specs) and (
+            not (cfg.mamba_num_heads and cfg.mamba_head_dim
+                 and cfg.ssm_state_size)
+            or cfg.mamba_num_heads % cfg.n_groups
+            or cfg.mamba_num_heads * cfg.mamba_head_dim % cfg.n_groups):
+        raise ValueError(
+            "an 'M' layer (a pattern's) or a \"mamba\" layer (layer_types') "
+            "needs mamba_num_heads, mamba_head_dim and ssm_state_size, the "
+            "heads a multiple of n_groups")
     in_use = {spec.type for spec in specs}
     sliding = "sliding_attention" in in_use
     if sliding != (cfg.sliding_window is not None) or (
@@ -318,7 +325,7 @@ def _layer_specs(cfg: "LlamaConfig") -> tuple:
             f"'sliding_attention' layers (or mb_per_layer's) of "
             f"attention_kind 'full' or 'differential', and with nothing "
             f"else")
-    used = in_use - {"linear_attention", "conv"}
+    used = in_use - set(NO_SOFTMAX_TYPES)
     if cfg.rope_parameters is not None and (
             not used <= set(ropes) <= set(LAYER_TYPES) or any(
                 not 0.0 < r.partial_rotary_factor <= 1.0
@@ -367,8 +374,10 @@ class LlamaConfig:
     (LFM2's name) a ``GatedShortConv`` of ``conv_L_cache`` taps (the filter's
     length, which is also what a decode cache would hold; ``conv_bias``,
     published false, would give its projections and filter a bias: true is
-    refused until a configuration has it).  A character of
-    ``hybrid_override_pattern`` likewise: ``"M"`` a ``Mamba2``
+    refused until a configuration has it); ``"mamba"`` and ``"attention"``
+    (Granite-4.0-H's names) a ``Mamba2`` sized by the keys below, each WITH a
+    feed-forward behind a norm of its own, and ``attention_kind``'s.  A
+    character of ``hybrid_override_pattern`` likewise: ``"M"`` a ``Mamba2``
     (``mamba_num_heads``, ``mamba_head_dim``, ``ssm_state_size``,
     ``n_groups``, ``conv_kernel``, ``chunk_size``), ``"*"``
     ``attention_kind``'s, ``"E"`` none; ``"-"``, a dense feed-forward alone,
@@ -426,6 +435,17 @@ class LlamaConfig:
     ``RMSNorm`` with ``rms_eps``) makes every norm of the stack a
     ``LayerNorm`` with a scale and a bias; ``tie_word_embeddings`` makes the
     head the embedding's transpose.
+
+    Granite's four multipliers, each read in one place and each an identity
+    at its default, so that a config without them traces what it traced:
+    ``embedding_multiplier`` multiplies the embedding's output
+    (``LlamaModel.__call__``); ``attention_multiplier`` (None: ``head_dim **
+    -0.5``) is the softmax's scale IN PLACE of that root, handed to
+    ``attention_fn`` as ``scale=`` (``LlamaAttention.attend``;
+    ``attention_kind`` ``"full"`` alone); ``residual_multiplier`` multiplies
+    each sublayer's output before it is added to the stream (``LlamaLayer``:
+    both sublayers of every layer); ``logits_scaling`` DIVIDES the logits
+    (``LlamaModel.head``).
 
     The stack as a whole.  ``total_ut_steps`` (the published key of Ouro's
     ``config.json``) is the number of weight-shared passes over it.  1 is the
@@ -515,6 +535,10 @@ class LlamaConfig:
     mamba_expand: int = 2         # a Mamba-1 layer's channels over hidden
     layer_norm_eps: Optional[float] = None    # a float: LayerNorm, not RMS
     tie_word_embeddings: bool = False
+    embedding_multiplier: float = 1.0     # Granite's four; identities here
+    attention_multiplier: Optional[float] = None      # None: 1 / sqrt(D)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     dtype: Any = jnp.bfloat16
     # Output-head compute dtype.  bf16 keeps every logits-sized tensor —
     # the forward residual AND the cross-entropy cotangent, 2 GB each in
@@ -582,6 +606,12 @@ class LlamaConfig:
         if self.topk_method not in ("greedy", "noaux_tc"):
             raise ValueError(f"topk_method is {self.topk_method!r}: 'greedy' "
                              f"or 'noaux_tc' (a bias corrects the choice)")
+        if self.attention_multiplier is not None and (
+                self.attention_kind != "full"):
+            raise ValueError(
+                f"attention_multiplier is {self.attention_multiplier}: the "
+                f"softmax scale of attention_kind 'full' alone "
+                f"({self.attention_kind!r} sets its own)")
         if self.mlp_hidden_act not in ("silu", "relu2"):
             raise ValueError(f"mlp_hidden_act is {self.mlp_hidden_act!r}: "
                              f"'silu' (gated) or 'relu2' (not gated)")
@@ -729,9 +759,11 @@ class LlamaConfig:
                 f"them in place; not built")
         pattern = self.hybrid_override_pattern
         if self._holds(MAMBA2):
+            named = (f"hybrid_override_pattern holds {MAMBA!r}"
+                     if pattern is not None else "layer_types holds 'mamba'")
             raise NotImplementedError(
                 f"{who} has no path for Mamba-2 state-space layers "
-                f"(hybrid_override_pattern holds {MAMBA!r}): beside K and V "
+                f"({named}): beside K and V "
                 f"its cache would hold a [{self.mamba_head_dim}, "
                 f"{self.ssm_state_size}] float32 state for each of "
                 f"{self.mamba_num_heads} heads and the filter's last "
@@ -809,6 +841,17 @@ class LlamaConfig:
                 f"(gating={self.gating!r}): its layer would project a gate "
                 f"a head from the normed state and scale the attention "
                 f"output before W_o; not built")
+        multipliers = {
+            name: getattr(self, name) for name, identity in (
+                ("embedding_multiplier", 1.0), ("attention_multiplier", None),
+                ("residual_multiplier", 1.0), ("logits_scaling", 1.0))
+            if getattr(self, name) != identity}
+        if multipliers:
+            raise NotImplementedError(
+                f"{who} has no path for Granite's multipliers "
+                f"({multipliers}): its layer adds a sublayer's output as it "
+                f"is, scales the scores by 1 / sqrt(head_dim), and neither "
+                f"scales the embedding nor divides the logits; not built")
         if self.zero_centered_norm:
             raise NotImplementedError(
                 f"{who} has no path for zero-centred norms "
@@ -1095,10 +1138,13 @@ class LlamaAttention(nn.Module):
                         name="wo")(out)
 
     def attend(self, x, q, k, v, cos, sin):
-        window = self.config.window_of(self.index)
-        if window is None:
-            return self.attention_fn(q, k, v)
-        return self.attention_fn(q, k, v, window=window)
+        # (A keyword only where the config states it: a caller's
+        # ``attention_fn`` need take neither.)
+        stated = {"window": self.config.window_of(self.index),
+                  "scale": self.config.attention_multiplier}
+        return self.attention_fn(q, k, v, **{
+            name: value for name, value in stated.items()
+            if value is not None})
 
 
 def _gated_lanes(out, logits):
@@ -1907,7 +1953,9 @@ def _mamba_a_log_init(key, shape, dtype=jnp.float32):
 
 class Mamba2(nn.Module):
     """The Mamba-2 state-space mixer (state-space duality; Dao & Gu,
-    arXiv:2405.21060) of an ``"M"`` layer, as Nemotron-H sizes it.  With
+    arXiv:2405.21060) of a pattern's ``"M"`` layer (Nemotron-H's: the layer's
+    one sublayer, 8 groups) and of a ``"mamba"`` layer of ``layer_types``
+    (Granite-4.0-H's: a SwiGLU behind it, ONE group, chunks of 256).  With
     H = ``mamba_num_heads`` heads of P = ``mamba_head_dim``, a state of N =
     ``ssm_state_size`` a lane, B and C shared by the heads of each of G =
     ``n_groups`` groups (head h reads group ``h // (H / G)``), x the block's
@@ -1922,8 +1970,9 @@ class Mamba2(nn.Module):
         out = (RMSNorm_G(y * silu(z)) * w) W_out
 
     The gate multiplies BEFORE the norm, which is over each group's ``H P /
-    G`` lanes.  The recurrence runs chunk by chunk (``ops/ssd.py``,
-    ``chunk_size`` rows); the skip, the gate and the norm are
+    G`` lanes (all of them at G = 1).  The recurrence runs chunk by chunk
+    (``ops/ssd.py``, ``chunk_size`` rows); the two projections are under
+    ``hvd.ssd.proj``; the skip, the gate and the norm are
     ``ops/gated_norm.py``'s (one Mosaic pass each way on u and z where the
     filter and ``in_proj`` left them, where the model's ``attention_fn``
     reads its operands in place, else its ``jnp`` body).  Parameters:
@@ -1946,8 +1995,9 @@ class Mamba2(nn.Module):
                                cfg.ssm_state_size)
         groups, inner = cfg.n_groups, cfg.mamba_inner
         bc = groups * state
-        projected = nn.Dense(2 * inner + 2 * bc + heads, use_bias=False,
-                             dtype=cfg.dtype, name="in_proj")(x)
+        with _scopes.scope(_scopes.SSD_PROJ):
+            projected = nn.Dense(2 * inner + 2 * bc + heads, use_bias=False,
+                                 dtype=cfg.dtype, name="in_proj")(x)
         dt = projected[..., 2 * inner + 2 * bc:]
         with _scopes.scope(_scopes.SSD_CONV):
             # (The filter reads its channels where ``in_proj`` left them.)
@@ -1986,8 +2036,9 @@ class Mamba2(nn.Module):
             y = gated_norm(y, xbc, projected, d, self.param(
                 "norm", nn.initializers.ones, (inner,)), groups, cfg.rms_eps,
                 self.in_place)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-                        name="out_proj")(y)
+        with _scopes.scope(_scopes.SSD_PROJ):
+            return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                            name="out_proj")(y)
 
 
 def _state_a_log_init(key, shape, dtype=jnp.float32):
@@ -2168,9 +2219,11 @@ class LlamaLayer(nn.Module):
 
         def residual(x, sublayer, norm):
             norm = _stack_norm(cfg, norm)
-            if cfg.norm_placement == "pre":
-                return x + sublayer(norm(x))
-            return x + norm(sublayer(x))
+            out = (sublayer(norm(x)) if cfg.norm_placement == "pre"
+                   else norm(sublayer(x)))
+            if cfg.residual_multiplier != 1.0:
+                out = out * cfg.residual_multiplier
+            return x + out
 
         # Norm and residual add inside each block's scope: XLA fuses them
         # with the neighbouring products (common/scopes.py).
@@ -2217,6 +2270,8 @@ class LlamaModel(nn.Module):
         B, S = input_ids.shape
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      name="tok_emb")(input_ids)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         # One table a distinct rotation, made once and handed to the layers
         # of its type (a stack with one rotation: one table, as before).
         tables = {None: (None, None)}
@@ -2299,9 +2354,14 @@ class LlamaModel(nn.Module):
             if cfg.tie_word_embeddings:
                 # logits = hidden E^T: the embedding's own leaf, read again.
                 table = self.get_variable("params", "tok_emb")["embedding"]
-                return jax.lax.dot_general(
+                logits = jax.lax.dot_general(
                     hidden.astype(cfg.logits_dtype),
                     table.astype(cfg.logits_dtype),
                     (((hidden.ndim - 1,), (1,)), ((), ())))
-            return nn.Dense(cfg.vocab_size, use_bias=False,
-                            dtype=cfg.logits_dtype, name="lm_head")(hidden)
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                                  dtype=cfg.logits_dtype,
+                                  name="lm_head")(hidden)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
+            return logits

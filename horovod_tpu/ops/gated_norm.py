@@ -29,7 +29,17 @@ zeros, which XLA joins with the other channels' as it did.
 
 **Forward.**  A grid over (batch row, block of rows).  A step walks its
 block in pieces that stay in vector registers (``ops/short_conv.py``'s
-reason): 64 rows, and of them one norm group's lanes at a time.
+reason): 64 rows, and of them one norm group's lanes at a time, where a
+group is at most 512 lanes (Nemotron-H's 8 groups of 4096 channels).  A
+WIDER group (Granite-4.0-H's ONE group of 4096 lanes: 256 vector registers a
+tensor at 64 rows, of the v5e's 64) is a shape the pass takes too, in two
+sweeps over the group's pieces of at most 512 lanes (``_pieces``: the widest
+piece of whole lane tiles that divides the group, and as many rows as keep a
+step at 64 x 512 elements): first the sum of squares, then g made again from
+the block in VMEM and scaled; backward the two group means first (of g g,
+and of ``dn n = r go w g``), then the gradients piece by piece.  The same
+mathematics in the same precision; the sigmoid is evaluated twice and no
+float32 copy of g is stored between the sweeps.
 float32 inside, rounded ONCE, where the result is stored: the rounding of t
 to bf16 that the ``jnp`` body has between the skip and the gate is not made
 here, which is more precision, not less.  A group's sum of squares is a sum
@@ -91,6 +101,11 @@ _ROWS = (256, 128, 64, 32, 16)
 # backward), with no gate and no norm at all still 0.99 at 16 (my chip run,
 # PR 59; PERF.md §5).
 _WALK = 64
+# Lanes of a norm group that a walk's step holds at once.  A wider group
+# (Granite-4.0-H's ONE group of 4096: 256 vector registers a tensor at 64
+# rows, where the v5e has 64) is taken in two sweeps over pieces of at most
+# this many lanes (``_pieces``).
+_GROUP_LANES = 512
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 _BODY = "gated_norm.body"
@@ -134,10 +149,10 @@ def _why_not(shape, groups: int, in_place: bool):
 
 # -- the two bodies' walk ----------------------------------------------------
 
-def _walked(rows: int, step, carry):
-    """``carry = step(which rows, carry)`` for each ``_WALK`` rows of a
+def _walked(rows: int, step, carry, walk: int = _WALK):
+    """``carry = step(which rows, carry)`` for each ``walk`` rows of a
     block of ``rows`` (the whole of a smaller block): the last carry."""
-    walk = min(rows, _WALK)
+    walk = min(rows, walk)
     return jax.lax.fori_loop(
         0, rows // walk, lambda j, carry: step(
             pl.ds(pl.multiple_of(j * walk, walk), walk), carry), carry)
@@ -166,6 +181,126 @@ def _fwd_kernel(y_ref, u_ref, z_ref, d_ref, w_ref, o_ref, *, groups, eps):
         _walked(rows, step, 0)
 
     _each_chunk(width, width // groups, chunk)    # a norm group's lanes
+
+
+def _pieces(rows: int, per: int):
+    """``(rows a step, lanes a piece)`` of the walk over a group of ``per``
+    lanes that is wider than ``_GROUP_LANES``: the widest piece of whole
+    lane tiles that divides the group, and as many rows as keep a step at
+    ``_WALK`` rows of ``_GROUP_LANES`` lanes (of a block's ``rows``)."""
+    piece = next(lanes for lanes in range(_GROUP_LANES, 0, -_LANES)
+                 if per % lanes == 0)
+    return min(rows, _WALK * (_GROUP_LANES // piece)), piece
+
+
+def _piece(lanes, j, piece: int):
+    """Piece ``j`` (traced) of ``piece`` lanes of a group's ``lanes``
+    (``_each_chunk``'s: a slice, or a ``pl.ds``)."""
+    return pl.ds(pl.multiple_of(lanes.start + j * piece, _LANES), piece)
+
+
+def _fwd_kernel_wide(y_ref, u_ref, z_ref, d_ref, w_ref, o_ref, *, groups,
+                     eps):
+    # As _fwd_kernel for a group wider than a step holds: a step's rows
+    # take the group's pieces twice, for the sum of squares and then for
+    # the scaling, g made again from the block in VMEM (the sigmoid twice:
+    # no float32 copy of g is stored between the sweeps).
+    rows, width = y_ref.shape
+    per = width // groups
+    walk, piece = _pieces(rows, per)
+
+    def group(lanes, _):
+        def gated(here, at):
+            y, u, z = (ref[here, at].astype(jnp.float32)
+                       for ref in (y_ref, u_ref, z_ref))
+            return (y + d_ref[:, at] * u) * (z * _sigmoid(z))
+
+        def step(here, carry):
+            def squares(j, total):
+                g = gated(here, _piece(lanes, j, piece))
+                return total + jnp.sum(g * g, axis=-1, keepdims=True)
+
+            r = jax.lax.rsqrt(jax.lax.fori_loop(
+                0, per // piece, squares, jnp.zeros((walk, 1), jnp.float32))
+                * (1.0 / per) + eps)
+
+            def scaled(j, carry):
+                at = _piece(lanes, j, piece)
+                o_ref[here, at] = (gated(here, at) * r * w_ref[:, at]
+                                   ).astype(o_ref.dtype)
+                return carry
+
+            return jax.lax.fori_loop(0, per // piece, scaled, carry)
+
+        _walked(rows, step, 0, walk)
+
+    _each_chunk(width, per, group)
+
+
+def _bwd_kernel_wide(y_ref, u_ref, z_ref, go_ref, d_ref, w_ref, dy_ref,
+                     du_ref, dz_ref, sums_ref, *, groups, eps):
+    # As _bwd_kernel for a group wider than a step holds: the two group
+    # means first (of g g, and of dn n = r go w g), then the gradients piece
+    # by piece; a piece's partial sums of dw and dD go straight to sums_ref.
+    rows, width = y_ref.shape
+    per = width // groups
+    walk, piece = _pieces(rows, per)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    def group(lanes, _):
+        def read(here, at):
+            return (ref[here, at].astype(jnp.float32)
+                    for ref in (y_ref, u_ref, z_ref, go_ref))
+
+        def step(here, carry):
+            def means(j, totals):
+                at = _piece(lanes, j, piece)
+                y, u, z, go = read(here, at)
+                g = (y + d_ref[:, at] * u) * (z * _sigmoid(z))
+                return tuple(
+                    total + jnp.sum(x, axis=-1, keepdims=True) for total, x
+                    in zip(totals, (g * g, go * w_ref[:, at] * g)))
+
+            squares, dn_g = jax.lax.fori_loop(
+                0, per // piece, means,
+                (jnp.zeros((walk, 1), jnp.float32),) * 2)
+            r = jax.lax.rsqrt(squares * (1.0 / per) + eps)
+            dn_n = r * dn_g * (1.0 / per)          # mean_group(dn n)
+
+            def grads(j, carry):
+                at = _piece(lanes, j, piece)
+                y, u, z, go = read(here, at)
+                t = y + d_ref[:, at] * u
+                sig = _sigmoid(z)
+                s = z * sig
+                n = t * s * r
+                dg = r * (go * w_ref[:, at] - n * dn_n)
+                dt = dg * s
+                dy_ref[here, at] = dt.astype(dy_ref.dtype)
+                du_ref[here, at] = (d_ref[:, at] * dt).astype(du_ref.dtype)
+                dz_ref[here, at] = (dg * t * sig * (1.0 + z * (1.0 - sig))
+                                    ).astype(dz_ref.dtype)
+                sums_ref[:_TILE, at] += (go * n).reshape(
+                    -1, _TILE, piece).sum(axis=0)
+                sums_ref[_TILE:, at] += (dt * u).reshape(
+                    -1, _TILE, piece).sum(axis=0)
+                return carry
+
+            return jax.lax.fori_loop(0, per // piece, grads, carry)
+
+        _walked(rows, step, 0, walk)
+
+    _each_chunk(width, per, group)
+
+
+def _kernels(width: int, groups: int):
+    """The two bodies for ``width`` channels normed in ``groups`` runs."""
+    if width // groups > _GROUP_LANES:
+        return _fwd_kernel_wide, _bwd_kernel_wide
+    return _fwd_kernel, _bwd_kernel
 
 
 def _bwd_kernel(y_ref, u_ref, z_ref, go_ref, d_ref, w_ref, dy_ref, du_ref,
@@ -231,7 +366,7 @@ def _forward(y, u, z, d, w, groups, eps, interpret):
     rows = _pick_rows(s)
     block, whole = _specs(rows, width)
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, groups=groups, eps=eps),
+        functools.partial(_kernels(width, groups)[0], groups=groups, eps=eps),
         grid=(b, s // rows),
         in_specs=[block, block, block, whole, whole],
         out_specs=block,
@@ -251,7 +386,7 @@ def _backward(y, u, z, d, w, go, groups, eps, interpret):
     rows = _pick_rows(s)
     block, whole = _specs(rows, width)
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, groups=groups, eps=eps),
+        functools.partial(_kernels(width, groups)[1], groups=groups, eps=eps),
         grid=(b, s // rows),
         in_specs=[block, block, block, block, whole, whole],
         out_specs=[block, block, block,

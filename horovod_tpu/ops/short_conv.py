@@ -162,7 +162,9 @@ def _why_not(shape, taps_shape, heads: int, first=None, gated=False):
         return _NOT_WHOLE_HEADS
     if gated and width % _LANES:
         return _NOT_AT_A_TILE
-    if first is not None and (first % _LANES or first + width > shape[2]):
+    # (A window of a wider array is whole lane tiles from a lane tile on.)
+    if first is not None and (first % _LANES or width % _LANES
+                              or first + width > shape[2]):
         return _NOT_AT_A_TILE
     if taps_shape[0] - 1 > _TILE:
         return _TOO_MANY_TAPS

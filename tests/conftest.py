@@ -365,6 +365,34 @@ TINY.setdefault("lconv_moe_lm", {
     "traffic": {"sequence": 64, "batch_per_chip": 2},
 })
 
+TINY.setdefault("ssm_lm", {
+    # Hidden 64; TWO layers, one of each kind (the suite is near its time
+    # limit; three Mamba layers to one run in ``tests/test_granite_hybrid.py``
+    # and nine on the chip): a Mamba-2 mixer of 4 heads of 32 (an inner width
+    # of ``mamba_expand`` 2 x 64) with a state of 16 in ONE group, chunks of
+    # 16; 2 query heads over 1 key-value head of 32; each with a SwiGLU of
+    # 128.  The four multipliers stay the published ones.
+    "config": {"hidden_size": 64, "num_hidden_layers": 2,
+               "layer_types": ["mamba", "attention"],
+               "mamba_n_heads": 4, "mamba_d_head": 32, "mamba_d_state": 16,
+               "mamba_chunk_size": 16,
+               "num_attention_heads": 2, "num_key_value_heads": 1,
+               "shared_intermediate_size": 128, "vocab_size": 512,
+               "head_dim": 32,
+               "checks": {"first_loss_is_ln_vocab_plus": 0.0078125,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up, as ``hybrid_moe_lm``.
+                          "loss_must_fall": False,
+                          # bf16 at these widths; float32 through the same
+                          # code agrees to 1e-4
+                          # (tests/test_granite_hybrid.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    "traffic": {"sequence": 64, "batch_per_chip": 2},
+})
+
 
 # The files that take over 100 s of the driver's command
 # (``/root/TESTS_LAST_RUN.json``: six workers, ``--dist loadfile``), longest
@@ -493,9 +521,11 @@ _MANIFEST_THEN = {
     # (This one reads the cells at import, from the file as it is: its cut
     # keeps every cell, the newest named here, and ends the metrics at its.)
     "test_benchmark_startup_spans.py::test_the_manifests_ten_entries":
-        ("lfm2-24b-a2b.train-s8k-b2", "trace_loss_self_ms"),
+        ("granite-4.0-h-micro.train-s8k", "trace_loss_self_ms"),
     "test_benchmark_qk_norm.py::test_the_manifests_one_new_entry":
         ("phi-4-mini-flash.train-s8k", "diff_attn_ms"),
+    "test_benchmark_ssm_moe.py::test_the_manifests_new_entries":
+        ("lfm2-24b-a2b.train-s8k-b2", "lconv_conv_roofline"),
 }
 
 
@@ -508,7 +538,8 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
     ``test_benchmark_gdn_solve.py``'s, PR 47, its one metric;
     ``test_benchmark_startup_spans.py``'s, PR 52, its ten;
     ``test_benchmark_qk_norm.py``'s, PR 48, the cells that norm q and k a
-    head)
+    head; ``test_benchmark_ssm_moe.py``'s, PR 50, the one cell its four
+    ``ssd_*`` metrics listed)
     as the LAST of every list of
     ``BENCHMARK.json`` and count the cells, and a later PR may neither
     edit those files nor put its entries anywhere but last.  So each of
