@@ -47,15 +47,16 @@ from jax.ad_checkpoint import checkpoint_policies
 
 from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
-from horovod_tpu.ops.gated_delta import (calls_in_place, gated_delta_rule,
+from horovod_tpu.ops.gated_delta import (CHUNK, calls_in_place,
+                                         gated_delta_rule,
                                          gated_delta_states)
-from horovod_tpu.ops.gated_norm import gated_norm, skipped
+from horovod_tpu.ops.gated_norm import gated_norm, norm_gated, skipped
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops import rope as _rope
 from horovod_tpu.ops.selective_scan import (selective_scan,
                                             selective_scan_states)
-from horovod_tpu.ops.short_conv import convolved, over_heads
+from horovod_tpu.ops.short_conv import convolved
 from horovod_tpu.ops.ssd import ssd_scan, ssd_states
 from horovod_tpu.ops.sparse_index import index_loss, select_keys
 
@@ -1783,19 +1784,6 @@ class RoutedExperts(nn.Module):
                 mean - counts.astype(jnp.float32))
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4))
-def _gated_norm(o, z, scale, heads, eps):
-    """``rms_norm(o) * scale * silu(z)``: o and z ``[B, S, heads * d_v]``,
-    o normed a head, ``scale [d_v]`` shared by the heads; float32 inside,
-    the dtype of z out, and under a checkpoint as
-    ``ops/short_conv.py::convolved``'s plain body."""
-    o = o.astype(jnp.float32)
-    squares, spread = over_heads(o * o, heads)
-    o = o * spread(jax.lax.rsqrt(squares * (heads / o.shape[-1]) + eps))
-    return (o * jnp.tile(scale, heads) * nn.silu(z.astype(jnp.float32))
-            ).astype(z.dtype)
-
-
 def _conv_taps_init(key, shape, dtype=jnp.float32):
     """Uniform in +-1 / sqrt(K): a depthwise filter's fan-in is its K taps
     (what ``torch.nn.Conv1d`` draws)."""
@@ -1832,7 +1820,13 @@ class GatedDeltaNet(nn.Module):
         y   = (RMSNorm(o) * silu(x W_g)) W_o        one learned [d_v] scale for all heads
 
     The recurrence runs chunk by chunk (``ops/gated_delta.py``), its state
-    ``[d_v, d_k]`` a head in float32.  With more value heads than key
+    ``[d_v, d_k]`` a head in float32.  The output norm and its gate are
+    ``ops/gated_norm.py::norm_gated`` (under ``hvd.gdn.gates``, beside the
+    ``wa`` / ``wb`` projections, which stay XLA's): one Mosaic pass each way
+    where the model's ``attention_fn`` reads its operands in place and one,
+    two or four heads are whole lane tiles (heads of 128: four a step; of
+    192: two, the shared tile under a mask), else its ``jnp`` body, which is
+    what this class held (``_gated_norm``).  With more value heads than key
     heads a key head serves ``value / key`` of them (q and k copied to
     the value heads under ``hvd.gdn.heads``).  Parameters: ``wq wk
     [H, key heads * d_k]``, ``wv wg [H, value heads * d_v]``, ``wa wb [H,
@@ -1902,8 +1896,9 @@ class GatedDeltaNet(nn.Module):
                     ("out_max", jnp.max(jnp.abs(o.astype(jnp.float32))))):
                 self.sow("gdn_stats", name, value)
         with _scopes.scope(_scopes.GDN_GATES):
-            o = _gated_norm(o.reshape(z.shape), z, self.param(
-                "o_norm", nn.initializers.ones, (d_v,)), h_v, cfg.rms_eps)
+            o = norm_gated(o.reshape(z.shape), z, self.param(
+                "o_norm", nn.initializers.ones, (d_v,)), h_v, cfg.rms_eps,
+                self.in_place, chunk=CHUNK)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="wo")(o)
 

@@ -1,6 +1,13 @@
-"""What a Mamba-2 layer does between its scan and ``out_proj``, the skip,
-the gate and the grouped RMS norm, as one Mosaic pass forward and one
-backward.
+"""A recurrent mixer's output norm and its gate as one Mosaic pass forward and
+one backward, in both orders: what a Mamba-2 layer does between its scan and
+``out_proj`` (the skip, the gate, THEN the grouped RMS norm: ``gated_norm``)
+and what a gated delta-rule layer does between its rule and ``wo`` (a head's
+RMS norm, THEN the gate: ``norm_gated``, at the end of this text).  One
+module: one rule (``_why_not``), one counter (``body_counts``), one walk
+(``_walked``, ``_each_chunk``, ``_specs``, ``_pick_rows``, ``_call``), and a
+pair of bodies an order, picked by which layer calls and by the shape.
+
+**The gate first (Mamba-2).**
 
 With y the scan's output, u the filter's, z the gate's channels, D one
 scalar a head (``d``, spread to the head's lanes) and w the norm's weight,
@@ -61,12 +68,62 @@ batch row's blocks, as ``short_conv``'s taps); XLA adds the eight, the batch
 rows and, for D, a head's lanes.  No float32 array of the activations' shape
 is written to HBM in either call.
 
-Which body a trace took is counted (``body_counts``).  The Mosaic pass is
-taken where the caller says ``in_place`` (the trace is not partitioned:
-PERF.md §3.3), the channels are whole lane tiles and a group a whole number
-of them, a block of rows divides the sequence and the backend is a TPU
-(interpreted, a call is many times slower than XLA:CPU's fusions, and every
-tiny CPU model would run it; the tests run the pair so by calling it).
+**The norm first (Gated DeltaNet).**  With o the rule's output ``[B, S, H
+d_v]``, z the gate's projection and w ONE weight ``[d_v]`` for all H heads::
+
+    r = rsqrt(mean_head(o o) + eps)     n = o r     s = silu(z)
+    out = n w s
+
+and backward, again on the inputs alone (o, z, go)::
+
+    dn = go w s                         dw = sum_rows,heads(go n s)
+    do = r (dn - n mean_head(dn n))
+    dz = go w n sigma(z) (1 + z (1 - sigma(z)))
+
+No skip, no u, no D; w is tiled to the lanes outside (``_on_every_head``) and
+dw leaves as eight partial sums a lane, which XLA adds over the eight, the
+batch rows and a weight's lane of every head.  The same walk (``norm_gate``'s
+two bodies, ``_norm_gate_fwd_kernel`` and ``_norm_gate_bwd_kernel``); what
+differs is the way to a HEAD's mean, read from the shape (``_heads_a_step``,
+``_on_heads``):
+
+- a head that is whole lane tiles (Qwen3-Next's 32 heads of 128): a step
+  takes as many heads as are 512 lanes (four) at 64 rows, and each head's
+  mean is the sum of its tiles and one reduction across lanes;
+- a head that straddles tiles where two or four heads do not (Olmo-Hybrid's
+  30 heads of 192: a tile and a half, two are three tiles, 15 such pieces):
+  a step takes such a piece, the shared tile enters each neighbour's sum
+  under a lane mask, and gets both heads' roots back under the same mask.
+  (The other way, the squares' three bf16 pieces times the heads' 0/1
+  indicator on the MXU as ``short_conv._head_sums``, was timed alone and is
+  slower: PERF.md §5, PR 61.)
+
+A head wider than a step holds (over 512 lanes), or heads of which no one,
+two or four are whole tiles (160 lanes), keep the ``jnp`` body,
+``_norm_then_gate``: what ``models/llama.py::_gated_norm`` was.
+
+**Where o lies.**  The rule's result is ``[N, B, H, chunk, d_v]`` before
+its last transposition makes it ``[B, S, H, d_v]``, and the ``jnp`` body's
+float32 fusion used to carry that transposition.  Where a head is whole lane
+tiles and a step's rows are one chunk (``_rule_chunks``: heads of 128 at
+chunks of 64) the calls take o, and give ``do``, AS THE RULE LEFT IT
+(``_as_the_rule_left`` undoes the transposition and XLA cancels the two: in
+the compiled step the operand is a bitcast of the rule's result), a grid
+step's block being its chunks' every head, ``[rows / chunk, H, chunk,
+d_v]`` beside z's ``[rows, C]``; a step of the walk puts the heads' tiles
+side by side (``_heads_rows``).  At 192 the rule's last dimension pads to
+256 lanes and a head's lanes are not where z's are: o then reaches the call
+as rows, through XLA's transposing copy and reshape in bf16 (0.8 ms a pass
+and layer at ``[1, 8192, 5760]``: PERF.md §5, PR 61).  The layer says which
+by ``chunk``; a result never depends on it.
+
+Which body a trace took is counted (``body_counts``), both orders under one
+kind.  The Mosaic pass is taken where the caller says ``in_place`` (the
+trace is not partitioned: PERF.md §3.3), the channels are whole lane tiles
+and a group a whole number of them (the norm first: one, two or four heads),
+a block of rows divides the sequence and the backend is a TPU (interpreted,
+a call is many times slower than XLA:CPU's fusions, and every tiny CPU model
+would run it; the tests run the pairs so by calling them).
 """
 
 from __future__ import annotations
@@ -81,11 +138,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
-from horovod_tpu.ops.short_conv import _each_chunk, _sigmoid
+from horovod_tpu.ops.short_conv import (_each_chunk, _sigmoid,
+                                        over_heads)
 
-__all__ = ["gated_norm", "skip_gate_norm", "skipped", "body_counts",
-           "NOT_IN_PLACE", "OFF_THE_LANE_TILE", "GROUP_OFF_THE_TILE",
-           "NO_ROW_BLOCK", "NO_TPU"]
+__all__ = ["gated_norm", "skip_gate_norm", "skipped", "norm_gated",
+           "norm_gate", "body_counts", "NOT_IN_PLACE", "OFF_THE_LANE_TILE",
+           "GROUP_OFF_THE_TILE", "HEADS_OFF_THE_TILE", "NO_ROW_BLOCK",
+           "NO_TPU"]
 
 _LANES = 128
 _TILE = 8          # rows of a float32 sublane tile: a partial sum's
@@ -113,6 +172,8 @@ _MOSAIC = "one Mosaic pass each way"
 NOT_IN_PLACE = "the attention_fn does not read its operands in place"
 OFF_THE_LANE_TILE = "the channels are no whole lane tiles"
 GROUP_OFF_THE_TILE = "a group is no whole number of lane tiles"
+HEADS_OFF_THE_TILE = ("no one, two or four heads are whole lane tiles that a "
+                      "step holds")
 NO_ROW_BLOCK = "no block of rows divides the sequence"
 NO_TPU = "no TPU: the calls would run interpreted"
 
@@ -133,14 +194,33 @@ def _pick_rows(s: int) -> int:
     return next((rows for rows in _ROWS if s % rows == 0), 0)
 
 
-def _why_not(shape, groups: int, in_place: bool):
+def _heads_a_step(heads: int, per: int) -> int:
+    """How many heads of ``per`` lanes a step of the norm-first bodies' walk
+    takes: the fewest of one, two or four that are whole lane tiles (a head
+    of 192 lanes is one and a half, two are three), doubled while they
+    divide the heads and stay within ``_GROUP_LANES`` (four heads of 128:
+    a step's chain wants that many lanes to hide behind, ``_WALK``); 0
+    where no such few heads are."""
+    few = next((k for k in (1, 2, 4) if heads % k == 0
+                and (k * per) % _LANES == 0 and k * per <= _GROUP_LANES), 0)
+    while few and heads % (2 * few) == 0 and 2 * few * per <= _GROUP_LANES:
+        few *= 2
+    return few
+
+
+def _why_not(shape, groups: int, in_place: bool, norm_first: bool = False):
     """None where the Mosaic pass takes ``y`` of ``shape [B, S, C]`` normed
-    in ``groups`` groups, else the reason it does not."""
+    in ``groups`` groups (``norm_first``: heads, normed BEFORE the gate),
+    else the reason it does not."""
     if not in_place:
         return NOT_IN_PLACE
     if len(shape) != 3 or shape[2] % _LANES:
         return OFF_THE_LANE_TILE
-    if shape[2] % groups or (shape[2] // groups) % _LANES:
+    if norm_first:
+        if shape[2] % groups or not _heads_a_step(groups,
+                                                  shape[2] // groups):
+            return HEADS_OFF_THE_TILE
+    elif shape[2] % groups or (shape[2] // groups) % _LANES:
         return GROUP_OFF_THE_TILE
     if not _pick_rows(shape[1]):
         return NO_ROW_BLOCK
@@ -342,6 +422,144 @@ def _bwd_kernel(y_ref, u_ref, z_ref, go_ref, d_ref, w_ref, dy_ref, du_ref,
     _each_chunk(width, width // groups, chunk)    # a norm group's lanes
 
 
+# -- the norm FIRST, then the gate: a gated delta-rule layer's ---------------
+#
+# out = o rsqrt(mean_head(o o) + eps) w silu(z), the heads' ONE weight w
+# [d_v] on every head's lanes.  A step of the walk takes ``_heads_a_step``
+# heads' lanes and forms each head's mean from the step's lane tiles.
+
+def _on_heads(x, per: int, then=None):
+    """For ``x [rows, L]`` of ``L / per`` heads, L whole lane tiles: each
+    head's mean over its ``per`` lanes (``then`` applied to it, ``[rows,
+    1]``), ON the head's lanes ``[rows, L]``.  A head that is whole lane
+    tiles is the sum of its tiles and one reduction across lanes; one that
+    shares a tile with its neighbour (192 lanes: a tile and a half) takes
+    its lanes of the shared tile under a mask, and the tile gets both
+    heads' values back under the same mask."""
+    rows, width = x.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    # A tile's heads: (head, its first lane of the tile, the lane behind).
+    owners = [[(h, max(h * per - t, 0), min((h + 1) * per - t, _LANES))
+               for h in range(t // per, (t + _LANES - 1) // per + 1)]
+              for t in range(0, width, _LANES)]
+    sums = {}
+    for t, heads in enumerate(owners):
+        tile = x[:, t * _LANES:(t + 1) * _LANES]
+        for h, lo, hi in heads:
+            part = tile if (lo, hi) == (0, _LANES) else jnp.where(
+                (lane >= lo) & (lane < hi), tile, 0.0)
+            sums[h] = part if h not in sums else sums[h] + part
+    means = {h: jnp.sum(total, axis=-1, keepdims=True) * (1.0 / per)
+             for h, total in sums.items()}
+    if then is not None:
+        means = {h: then(mean) for h, mean in means.items()}
+    spread = []
+    for heads in owners:
+        on = jnp.broadcast_to(means[heads[-1][0]], (rows, _LANES))
+        for h, _, hi in reversed(heads[:-1]):
+            on = jnp.where(lane < hi, means[h], on)
+        spread.append(on)
+    return spread[0] if len(spread) == 1 else jnp.concatenate(spread, axis=1)
+
+
+def _step_rows(rows: int, lanes: int) -> int:
+    """Rows a step of the norm-first walk takes at ``lanes`` lanes: as
+    many as keep it at no less than ``_WALK`` rows of ``_GROUP_LANES`` lanes
+    (384 lanes: 128 rows, which read 3 % faster backward than 64 alone on
+    the v5e, and 32 rows 27 % slower: my chip run, PR 61)."""
+    return min(rows, _WALK * -(-_GROUP_LANES // lanes))
+
+
+def _rule_chunks(shape, heads: int, chunk: int) -> bool:
+    """Whether the norm-first bodies read o (and write do) where the gated
+    delta rule leaves it, ``[N, B, H, chunk, d_v]``: a head is whole lane
+    tiles (at 192 the last dimension pads to 256 and a head's lanes are not
+    where z's are) and ONE chunk is a step's rows."""
+    per = shape[2] // heads
+    rows = _pick_rows(shape[1])
+    return bool(chunk and per % _LANES == 0 and rows % chunk == 0
+                and chunk == _step_rows(rows, _heads_a_step(heads, per) * per))
+
+
+def _heads_rows(ref, here, at, lanes: int):
+    """``ref[here, at]`` of a block of rows ``[rows, C]``; of a block as
+    the rule leaves it, ``[chunks, H, rows a chunk, d_v]``, the same rows
+    and ``lanes`` lanes: ``here`` is ONE chunk's rows, ``at`` whole heads'
+    lanes, and the heads' ``[rows, d_v]`` tiles are put side by side."""
+    if len(ref.shape) == 2:
+        return ref[here, at]
+    _, _, chunk, per = ref.shape
+    heads = ref[here.start // chunk, pl.ds(at.start // per, lanes // per)]
+    return jnp.concatenate(list(heads), axis=1)
+
+
+def _store_heads_rows(ref, here, at, value):
+    """``ref[here, at] = value``, for either block of ``_heads_rows``."""
+    if len(ref.shape) == 2:
+        ref[here, at] = value
+        return
+    _, _, chunk, per = ref.shape
+    which, first = here.start // chunk, at.start // per
+    for k in range(value.shape[1] // per):
+        ref[which, first + k] = value[:, k * per:(k + 1) * per]
+
+
+def _norm_gate_fwd_kernel(o_ref, z_ref, w_ref, out_ref, *, per, lanes, eps):
+    # z_ref, out_ref: [rows, C]; o_ref as they, or as the rule leaves it
+    # (``_heads_rows``); w_ref (the [d_v] weight on every head's lanes):
+    # [1, C] float32.  ``lanes`` a step: whole heads of per.
+    rows, width = z_ref.shape
+
+    def chunk(at, _):
+        def step(here, carry):
+            o = _heads_rows(o_ref, here, at, lanes).astype(jnp.float32)
+            z = z_ref[here, at].astype(jnp.float32)
+            r = _on_heads(o * o, per, lambda m: jax.lax.rsqrt(m + eps))
+            out_ref[here, at] = (o * r * w_ref[:, at] * (z * _sigmoid(z))
+                                 ).astype(out_ref.dtype)
+            return carry
+
+        _walked(rows, step, 0, _step_rows(rows, lanes))
+
+    _each_chunk(width, lanes, chunk)
+
+
+def _norm_gate_bwd_kernel(o_ref, z_ref, go_ref, w_ref, do_ref, dz_ref,
+                          sums_ref, *, per, lanes, eps):
+    # As _norm_gate_fwd_kernel, with the cotangent go_ref and the two
+    # results (do_ref a block as o_ref is); sums_ref: [_TILE, C] float32,
+    # eight partial sums a lane of dw's (a head's lanes' owners are added
+    # up outside), the same block for every step of a batch row.
+    rows, width = z_ref.shape
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    def chunk(at, size):
+        def step(here, d_w):
+            o = _heads_rows(o_ref, here, at, lanes).astype(jnp.float32)
+            z, go = (ref[here, at].astype(jnp.float32)
+                     for ref in (z_ref, go_ref))
+            sig = _sigmoid(z)
+            s = z * sig
+            r = _on_heads(o * o, per, lambda m: jax.lax.rsqrt(m + eps))
+            n = o * r
+            gw = go * w_ref[:, at]
+            dn = gw * s
+            _store_heads_rows(do_ref, here, at, (
+                r * (dn - n * _on_heads(dn * n, per))).astype(do_ref.dtype))
+            dz_ref[here, at] = (gw * n * sig * (1.0 + z * (1.0 - sig))
+                                ).astype(dz_ref.dtype)
+            return d_w + (go * n * s).reshape(-1, _TILE, size).sum(axis=0)
+
+        sums_ref[:, at] += _walked(
+            rows, step, jnp.zeros((_TILE, size), jnp.float32),
+            _step_rows(rows, lanes))
+
+    _each_chunk(width, lanes, chunk)
+
+
 # -- the two calls -----------------------------------------------------------
 
 def _specs(rows: int, width: int):
@@ -351,10 +569,55 @@ def _specs(rows: int, width: int):
             pl.BlockSpec((1, width), lambda b, i: (0, 0)))
 
 
+def _chunks_spec(rows: int, shape):
+    """The same block of rows of an array ``[N, B, H, chunk, d_v]``: a
+    batch row's ``rows / chunk`` chunks, every head."""
+    _, _, heads, chunk, per = shape
+    return pl.BlockSpec((rows // chunk, None, heads, chunk, per),
+                        lambda b, i: (i, b, 0, 0, 0))
+
+
 def _constants(d, w, width: int):
     """D on its head's lanes and the norm's weight, ``[1, C]`` float32."""
     return (jnp.repeat(d.astype(jnp.float32), width // d.shape[0])[None],
             w.astype(jnp.float32)[None])
+
+
+def _call(kernel, shape, blocks, wholes, results, sums: int, interpret):
+    """One pass of ``kernel`` over the grid (batch row, block of rows) of
+    ``shape [B, S, C]``: ``blocks`` are read a block of rows of their first
+    C channels (an array of five dimensions: of the rule's chunks,
+    ``_chunks_spec``), ``wholes`` ``[1, C]`` seen whole by every step;
+    ``results`` (``ShapeDtypeStruct``s) leave the same way and, where
+    ``sums`` is not 0, a float32 ``[B, sums, C]`` whose block stays put
+    while the grid walks a batch row's blocks."""
+    b, s, width = shape
+    rows = _pick_rows(s)
+    block, whole = _specs(rows, width)
+
+    def spec(x):
+        return block if x.ndim == 3 else _chunks_spec(rows, x.shape)
+
+    out_specs = [spec(x) for x in results]
+    out_shape = list(results)
+    if sums:
+        out_specs.append(pl.BlockSpec((None, sums, width),
+                                      lambda b, i: (b, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, sums, width), jnp.float32))
+    call = pl.pallas_call(
+        kernel,
+        grid=(b, s // rows),
+        in_specs=[spec(x) for x in blocks] + [whole] * len(wholes),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",
+                                 "arbitrary" if sums else "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )
+    with _scopes.span(_scopes.MOSAIC_GATED_NORM):
+        return call(*blocks, *wholes)
 
 
 # (Jits, as ``short_conv``'s: a step traces each body once a shape, not
@@ -362,47 +625,21 @@ def _constants(d, w, width: int):
 # the mode asked for.)
 @functools.partial(jax.jit, static_argnames=("groups", "eps", "interpret"))
 def _forward(y, u, z, d, w, groups, eps, interpret):
-    b, s, width = y.shape
-    rows = _pick_rows(s)
-    block, whole = _specs(rows, width)
-    call = pl.pallas_call(
+    width = y.shape[2]
+    return _call(
         functools.partial(_kernels(width, groups)[0], groups=groups, eps=eps),
-        grid=(b, s // rows),
-        in_specs=[block, block, block, whole, whole],
-        out_specs=block,
-        out_shape=jax.ShapeDtypeStruct(y.shape, z.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-    )
-    with _scopes.span(_scopes.MOSAIC_GATED_NORM):
-        return call(y, u, z, *_constants(d, w, width))
+        y.shape, (y, u, z), _constants(d, w, width),
+        (jax.ShapeDtypeStruct(y.shape, z.dtype),), 0, interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "eps", "interpret"))
 def _backward(y, u, z, d, w, go, groups, eps, interpret):
-    b, s, width = y.shape
-    rows = _pick_rows(s)
-    block, whole = _specs(rows, width)
-    call = pl.pallas_call(
+    b, _, width = y.shape
+    dy, du, dz, sums = _call(
         functools.partial(_kernels(width, groups)[1], groups=groups, eps=eps),
-        grid=(b, s // rows),
-        in_specs=[block, block, block, block, whole, whole],
-        out_specs=[block, block, block,
-                   pl.BlockSpec((None, 2 * _TILE, width),
-                                lambda b, i: (b, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
-                   jax.ShapeDtypeStruct(y.shape, u.dtype),
-                   jax.ShapeDtypeStruct(y.shape, z.dtype),
-                   jax.ShapeDtypeStruct((b, 2 * _TILE, width), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-    )
-    with _scopes.span(_scopes.MOSAIC_GATED_NORM):
-        dy, du, dz, sums = call(y, u, z, go, *_constants(d, w, width))
+        y.shape, (y, u, z, go), _constants(d, w, width),
+        [jax.ShapeDtypeStruct(y.shape, x.dtype) for x in (y, u, z)],
+        2 * _TILE, interpret)
     # Nothing comes back to the channels behind the first C.
     du, dz = (jnp.pad(x, ((0, 0), (0, 0), (0, wide.shape[2] - width)))
               for x, wide in ((du, u), (dz, z)))
@@ -410,6 +647,57 @@ def _backward(y, u, z, d, w, go, groups, eps, interpret):
     return (dy, du, dz,
             sums[1].reshape(d.shape[0], -1).sum(axis=1).astype(d.dtype),
             sums[0].astype(w.dtype))
+
+
+def _norm_gate_kernel(kernel, width: int, heads: int, eps: float):
+    per = width // heads
+    return functools.partial(kernel, per=per, eps=eps,
+                             lanes=_heads_a_step(heads, per) * per)
+
+
+def _on_every_head(w, heads: int):
+    """The heads' one weight ``[d_v]`` on every head's lanes, ``[1, C]``
+    float32."""
+    return jnp.tile(w.astype(jnp.float32), heads)[None]
+
+
+def _as_the_rule_left(o, heads: int, chunk: int):
+    """``o [B, S, H d_v]`` as the calls take it: where ``_rule_chunks``
+    allows ``[N, B, H, chunk, d_v]``, the inverse of what
+    ``ops/gated_delta.py::gated_delta_rule`` does last, so that XLA cancels
+    the two and the call reads the rule's result where it lies; else o."""
+    if not _rule_chunks(o.shape, heads, chunk):
+        return o
+    b, s, width = o.shape
+    return o.reshape(b, s // chunk, chunk, heads, width // heads).transpose(
+        1, 0, 3, 2, 4)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "eps", "chunk", "interpret"))
+def _norm_gate_forward(o, z, w, heads, eps, chunk, interpret):
+    return _call(
+        _norm_gate_kernel(_norm_gate_fwd_kernel, o.shape[2], heads, eps),
+        o.shape, (_as_the_rule_left(o, heads, chunk), z),
+        (_on_every_head(w, heads),),
+        (jax.ShapeDtypeStruct(o.shape, z.dtype),), 0, interpret)[0]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "eps", "chunk", "interpret"))
+def _norm_gate_backward(o, z, w, go, heads, eps, chunk, interpret):
+    shape = o.shape
+    o = _as_the_rule_left(o, heads, chunk)
+    d_o, dz, sums = _call(
+        _norm_gate_kernel(_norm_gate_bwd_kernel, shape[2], heads, eps),
+        shape, (o, z, go), (_on_every_head(w, heads),),
+        (jax.ShapeDtypeStruct(o.shape, o.dtype),
+         jax.ShapeDtypeStruct(shape, z.dtype)), _TILE, interpret)
+    if d_o.ndim == 5:               # as o lay: back the way it came
+        d_o = d_o.transpose(1, 0, 3, 2, 4).reshape(shape)
+    # The eight partial sums, the batch rows and a weight's lane of every
+    # head.
+    return d_o, dz, sums.reshape(-1, w.shape[0]).sum(axis=0).astype(w.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -434,6 +722,32 @@ def _skip_gate_norm_bwd(groups, eps, kept, go):
 
 
 skip_gate_norm.defvjp(_skip_gate_norm_fwd, _skip_gate_norm_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def norm_gate(o, z, w, heads, eps, chunk=0):
+    """``rms_norm_head(o) w silu(z)`` for o and z ``[B, S, heads * d_v]``
+    and the heads' one weight ``w [d_v]``, the norm over each head's lanes;
+    float32 inside, rounded once, to the dtype of z.  ``chunk`` (static):
+    o is the gated delta rule's result, made in chunks of that many rows
+    ``[N, B, H, chunk, d_v]`` before it was ``[B, S, ..]``, and the calls
+    read it (and write its cotangent) as the rule left it where
+    ``_rule_chunks`` allows; 0: as rows.  One Mosaic call, and one for all
+    three gradients; ``_why_not`` says which shapes it takes."""
+    return _norm_gate_forward(o, z, w, heads=heads, eps=eps, chunk=chunk,
+                              interpret=_interpret())
+
+
+def _norm_gate_fwd(o, z, w, heads, eps, chunk):
+    return norm_gate(o, z, w, heads, eps, chunk), (o, z, w)
+
+
+def _norm_gate_bwd(heads, eps, chunk, kept, go):
+    return _norm_gate_backward(*kept, go, heads=heads, eps=eps, chunk=chunk,
+                               interpret=_interpret())
+
+
+norm_gate.defvjp(_norm_gate_fwd, _norm_gate_bwd)
 
 
 # -- the plain body, and the one entry ----------------------------------------
@@ -481,3 +795,37 @@ def gated_norm(y, u, z, d, w, groups: int, eps: float, in_place: bool):
     width = y.shape[-1]
     return _gate_then_norm(skipped(y, u[..., :width], d), z[..., :width], w,
                            groups, eps)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _norm_then_gate(o, z, scale, heads, eps):
+    """``rms_norm(o) * scale * silu(z)``: o and z ``[B, S, heads * d_v]``,
+    o normed a head, ``scale [d_v]`` shared by the heads (Gated DeltaNet's
+    output norm: the norm FIRST, then the gate); float32 inside, the dtype
+    of z out, and under a checkpoint as ``_gate_then_norm``.  A head's mean
+    through ``short_conv.over_heads``'s indicator products: a reshape to
+    ``[.., 30, 192]`` has XLA:TPU relay the float32 tensor."""
+    o = o.astype(jnp.float32)
+    squares, spread = over_heads(o * o, heads)
+    o = o * spread(jax.lax.rsqrt(squares * (heads / o.shape[-1]) + eps))
+    return (o * jnp.tile(scale, heads) * nn.silu(z.astype(jnp.float32))
+            ).astype(z.dtype)
+
+
+def norm_gated(o, z, w, heads: int, eps: float, in_place: bool,
+               chunk: int = 0):
+    """A gated delta-rule layer between its rule and ``wo``:
+    ``rms_norm_head(o) w silu(z)``, ``[B, S, C]`` in the dtype of z.  o and
+    z ``[B, S, C = heads * d_v]``, ``w [d_v]`` the heads' one weight;
+    ``heads`` and ``eps`` static.  ``in_place`` as ``gated_norm``'s: the
+    chain is then ``norm_gate``'s one pass forward and one backward, where
+    one, two or four heads are whole lane tiles (``_why_not``) and the
+    backend a TPU; ``chunk`` (static) is the caller's word that o is the
+    rule's result, made in chunks of that many rows (``norm_gate``).
+    Elsewhere ``_norm_then_gate``.  Counted with ``gated_norm``'s traces in
+    ``body_counts()``."""
+    why = _why_not(o.shape, heads, in_place, norm_first=True)
+    _trace_counts.note(_BODY, why or _MOSAIC)
+    if why is None:
+        return norm_gate(o, z, w, heads, eps, chunk)
+    return _norm_then_gate(o, z, w, heads, eps)

@@ -6,8 +6,10 @@ train step at one layer of each kind with every head of both mixers (that
 all four layers fit the chip so, which ISSUE 38 made the condition of
 halving the heads, is since PR 51 the chip's ``peak_hbm_gb`` to say), whose
 linear layer's convolutions are ``ops/short_conv.py``'s Mosaic calls and
-whose chunk systems are solved by ``ops/gated_delta.py``'s (PR 47); and a hybrid
-model under the GSPMD step over all four chips, which holds none.  Since
+whose chunk systems are solved by ``ops/gated_delta.py``'s (PR 47) and whose
+output norm and gate are ``ops/gated_norm.py``'s norm-first pair at heads of
+192 lanes (PR 61); and a hybrid model under the GSPMD step over all four
+chips, which holds none.  Since
 PR 44 also where each weight's optimizer update sits in the compiled step of
 this cell and of ``ouro-2.6b.train-s2k``: alone behind its gradient's matmul
 for a leaf of ``hvd.ALONE_FROM_ELEMENTS`` elements or more, inside the
@@ -30,6 +32,7 @@ from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta
+from horovod_tpu.ops import gated_norm
 from horovod_tpu.ops import rope
 from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.gated_delta import CHUNK, gated_delta_rule
@@ -63,12 +66,12 @@ def topo():
 
 @contextlib.contextmanager
 def _compiling_for_the_chip():
-    """The four kernels' non-interpreted bodies, and no persistent cache
+    """The five kernels' non-interpreted bodies, and no persistent cache
     (a deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     patch = pytest.MonkeyPatch()
-    for module in (fa, rope, short_conv, gated_delta):
+    for module in (fa, rope, short_conv, gated_delta, gated_norm):
         patch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -125,8 +128,9 @@ def _compiled_step(topo, workload, layer_types):
     """The cell's step at the layers ``layer_types`` names (one of each
     kind the cell has; its widths, sequence, batch and remat) compiled for
     one described chip, the job, and what the trace counted: the
-    convolutions' bodies, who solved the rule's systems, and the update's
-    split (``hvd.update_counts``)."""
+    convolutions' bodies, who solved the rule's systems, which body the
+    output norm and gate took, and the update's split
+    (``hvd.update_counts``)."""
     cell = manifest.cell(workload)
     config = {**cell["config"], "num_hidden_layers": len(layer_types),
               "layer_types": list(layer_types)}
@@ -142,13 +146,17 @@ def _compiled_step(topo, workload, layer_types):
     assert set(state[0]) == {"params"}
     batch = jax.eval_shape(job.make_batch, jax.random.key(0))
     step = hvd.make_train_step(job.loss_fn, job.optimizer, mesh)
-    before = short_conv.body_counts(), gated_delta.solve_counts()
+    before = (short_conv.body_counts(), gated_delta.solve_counts(),
+              gated_norm.body_counts())
     compiled = step.lower(*described(state), described(batch)).compile()
-    after = short_conv.body_counts(), gated_delta.solve_counts()
+    after = (short_conv.body_counts(), gated_delta.solve_counts(),
+             gated_norm.body_counts())
     bodies = {"fused": after[0]["fused"] - before[0]["fused"],
               "plain": after[0]["plain"] == before[0]["plain"],
               "solved": after[1]["mosaic"] - before[1]["mosaic"],
-              "merged": after[1]["plain"] != before[1]["plain"]}
+              "merged": after[1]["plain"] != before[1]["plain"],
+              "normed": after[2]["mosaic"] - before[2]["mosaic"],
+              "normed plain": after[2]["plain"] != before[2]["plain"]}
     return (compiled, job, jax.tree.leaves(state[0]), bodies,
             hvd.update_counts())
 
@@ -202,7 +210,11 @@ def test_the_cells_whole_step_fits_with_every_head(hybrid_step):
     that scope; and since PR 47 three under ``hvd.gdn.solve`` inside
     ``hvd.gdn.scan``, the slab's systems forward, again, and in the
     backward slab's ``jax.vjp`` of its preparation, each inside the default
-    scoped VMEM.  XLA's own rematerialisation pass still runs one gate-up
+    scoped VMEM; and since PR 61 three under ``hvd.gdn.gates``, the output
+    norm and gate forward, again, and backward (``gated_norm.norm_gate``:
+    ONE trace a layer took the Mosaic pass, none the ``jnp`` body), and no
+    float32 array of the activations' shape is left under that scope.
+    XLA's own rematerialisation pass still runs one gate-up
     product a third time (PERF.md, Open question 39): one ``.remat``
     instruction, as at the parent."""
     compiled, job, _, bodies, _ = hybrid_step
@@ -213,7 +225,8 @@ def test_the_cells_whole_step_fits_with_every_head(hybrid_step):
     # plain body for none.
     linear = sum(map(job.llama.is_linear, range(job.llama.num_layers)))
     assert bodies == {"fused": 3 * linear, "plain": True, "solved": linear,
-                      "merged": False} and linear == 1
+                      "merged": False, "normed": linear,
+                      "normed plain": False} and linear == 1
     text = compiled.as_text()
     calls = [line for line in text.splitlines() if _MOSAIC_CALL.search(line)]
     assert sum(scopes.FLASH_FWD in c for c in calls) == 1
@@ -226,8 +239,14 @@ def test_the_cells_whole_step_fits_with_every_head(hybrid_step):
     used = [int(_USED.search(c)[1]) for c in solves]
     assert max(used) <= DEFAULT_SCOPED_VMEM // 2, used
     assert not any(scopes.GDN_SCAN in c for c in calls if c not in solves)
+    gates = [c for c in calls if scopes.GDN_GATES in c]
+    assert len(gates) == 3 * linear
+    assert sum(scopes.REMATTED in c for c in gates) == linear
+    # (The backward call: do, dz and dw's partial sums.)
+    assert sum(" = (" in c for c in gates) == linear
     convolutions = [c for c in calls if scopes.GDN_CONV in c]
-    assert len(convolutions) == len(calls) - len(solves) - 2 == 9 * linear
+    assert len(convolutions) == (len(calls) - len(solves) - len(gates) - 2
+                                 ) == 9 * linear
     again = [c for c in convolutions if scopes.REMATTED in c]
     # A backward call gives two results: dy and the taps' partial sums.
     backward = [c for c in convolutions if " = (" in c]
@@ -238,6 +257,9 @@ def test_the_cells_whole_step_fits_with_every_head(hybrid_step):
         assert not [line for line in text.splitlines()
                     if scopes.GDN_CONV in line
                     and f" = f32[1,{seq},{width}]" in line], width
+    assert not [line for line in text.splitlines()
+                if scopes.GDN_GATES in line
+                and f" = f32[1,{seq},5760]" in line]
     assert not re.findall(rf"\w+\[(?:\d+,)*{seq},{seq}\]", text)
     remats = [line.split(" = ")[0].strip() for line in text.splitlines()
               if _REMAT.search(line)]
@@ -324,9 +346,9 @@ def test_gspmd_step_of_a_hybrid_model_holds_no_mosaic_call(one_chip, topo):
     """``parallel/api.py::make_parallel_train_step`` over all four chips
     with a hybrid model and its default dense attention, at a sequence the
     convolutions' pass would take: a Mosaic call cannot be partitioned
-    automatically, so the linear layers' convolutions are the ``jnp`` body
-    unless the ``attention_fn`` the model was given reads its operands in
-    place (``tests/test_flash_v5e_compile.py`` has the same for the
+    automatically, so the linear layers' convolutions and their output norm
+    and gate are the ``jnp`` bodies unless the ``attention_fn`` the model
+    was given reads its operands in place (``tests/test_flash_v5e_compile.py`` has the same for the
     rotation)."""
     import flax.linen as nn
     import numpy as np
@@ -350,6 +372,12 @@ def test_gspmd_step_of_a_hybrid_model_holds_no_mosaic_call(one_chip, topo):
         jax.ShapeDtypeStruct((8, 64, 2 * 96), jnp.bfloat16),
         jax.ShapeDtypeStruct((4, 2 * 96), jnp.float32))
     assert short_conv.body_counts()["fused"] == taken + 1
+    normed = gated_norm.body_counts()["mosaic"]
+    jax.eval_shape(
+        lambda o, z, w: gated_norm.norm_gated(o, z, w, 2, 1e-6, True),
+        *(jax.ShapeDtypeStruct((8, 64, 2 * 192), jnp.bfloat16),) * 2,
+        jax.ShapeDtypeStruct((192,), jnp.float32))
+    assert gated_norm.body_counts()["mosaic"] == normed + 1
     mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2),
                 ("data", "fsdp", "tensor"))
     model = LlamaModel(config)
@@ -368,10 +396,14 @@ def test_gspmd_step_of_a_hybrid_model_holds_no_mosaic_call(one_chip, topo):
     tokens = jax.ShapeDtypeStruct((8, 65), jnp.int32,
                                   sharding=NamedSharding(mesh, P("fsdp")))
     before = short_conv.body_counts()
+    gates = gated_norm.body_counts()
     step = make_parallel_train_step(model, optimizer, mesh)
     compiled = step.lower(params, state, tokens).compile()
     after = short_conv.body_counts()
     assert not _MOSAIC_CALL.search(compiled.as_text())
+    assert gated_norm.body_counts() == {"mosaic": gates["mosaic"], "plain": {
+        **gates["plain"], gated_norm.NOT_IN_PLACE: gates["plain"].get(
+            gated_norm.NOT_IN_PLACE, 0) + 1}}
     assert "all-reduce" in compiled.as_text()
     assert after["fused"] == before["fused"]
     assert after["plain"][short_conv.NOT_IN_PLACE] == before["plain"].get(

@@ -24,6 +24,7 @@ from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import gated_delta
+from horovod_tpu.ops import gated_norm
 from horovod_tpu.ops import rope
 from horovod_tpu.ops import short_conv
 
@@ -49,11 +50,11 @@ def topo():
 
 @pytest.fixture
 def one_chip(topo, monkeypatch):
-    """The four kernels' non-interpreted bodies, and no persistent cache
+    """The five kernels' non-interpreted bodies, and no persistent cache
     (a deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for module in (fa, rope, short_conv, gated_delta):
+    for module in (fa, rope, short_conv, gated_delta, gated_norm):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -203,8 +204,10 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     twice; ``none`` to 8.54 GB of temporaries, 17.3 GB in all: no room).
     The full layer is two flash calls (the policy keeps the forward call's
     output) and six rotations; a linear layer's convolutions are nine
-    Mosaic calls and its chunk systems' solves three (PR 47); the grouped
-    products are XLA:TPU's own.  That the cell's four layers (8.76 GB of
+    Mosaic calls, its chunk systems' solves three (PR 47) and its output
+    norm and gate three (PR 61: ``gated_norm.norm_gate`` at 32 heads of 128
+    lanes, forward, again, backward; one trace a layer took the Mosaic pass
+    and none the ``jnp`` body); the grouped products are XLA:TPU's own.  That the cell's four layers (8.76 GB of
     state, 4.03 GB of temporaries) fit the chip is no longer summed here:
     the chip's ``peak_hbm_gb`` in this cell says it in every PR, and
     ``tests/benchmark/test_benchmark_reference.py::
@@ -231,10 +234,12 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     assert batch.shape == (2, 8193)
     step = hvd.make_train_step(job.loss_fn, job.optimizer, mesh)
     before = (fa.layout_counts(), short_conv.body_counts(),
-              gated_delta.solve_counts())
+              gated_delta.solve_counts(), gated_norm.body_counts())
     compiled = step.lower(*described(state), described(batch)).compile()
     after = (fa.layout_counts(), short_conv.body_counts(),
-             gated_delta.solve_counts())
+             gated_delta.solve_counts(), gated_norm.body_counts())
+    assert after[3]["mosaic"] - before[3]["mosaic"] == linear
+    assert after[3]["plain"] == before[3]["plain"]
     assert after[2]["mosaic"] - before[2]["mosaic"] == linear
     assert after[2]["plain"] == before[2]["plain"]
     assert after[0]["in_place"] - before[0]["in_place"] == full
@@ -262,8 +267,28 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     assert sum(scopes.REMATTED in c for c in solves) == linear
     assert "f32[64,64,512]" in solves[0]
     assert max(int(_USED.search(c)[1]) for c in solves) <= 8 * 2 ** 20
+    # The output norm and gate: forward, again, backward (do, dz and the
+    # weight's partial sums), and no float32 array of the activations' shape
+    # under their scope.
+    gates = [c for c in calls if scopes.GDN_GATES in c]
+    assert len(gates) == 3 * linear
+    assert sum(scopes.REMATTED in c for c in gates) == linear
+    assert sum(" = (" in c for c in gates) == linear
+    assert not [line for line in text.splitlines()
+                if scopes.GDN_GATES in line and " = f32[2,8192,4096]" in line]
+    # o is read, and do written, where the rule leaves them (a head is one
+    # lane tile): the calls' first operand is [N, B, H, 64, 128] and no
+    # transposing copy or reshape of the activations' size is left under
+    # the scope.
+    assert all("operand_layout_constraints={bf16[128,2,32,64,128]" in c
+               for c in gates)
+    assert sum(" = (bf16[128,2,32,64,128]" in c for c in gates) == linear
+    assert not [line for line in text.splitlines()
+                if scopes.GDN_GATES in line and " = bf16[2,8192,4096]" in line
+                and any(op in line for op in (" reshape(", " copy(",
+                                              " transpose("))]
     ours = [c for c in calls if scopes.RAGGED_DOT_PREFIX not in c]
-    assert len(ours) == (2 + 6) * full + (9 + 3) * linear
+    assert len(ours) == (2 + 6) * full + (9 + 3 + 3) * linear
     assert scopes.RAGGED_DOT_PREFIX in text
     for scope in (scopes.GDN_HEADS, scopes.GDN_SCAN, scopes.GDN_GATES,
                   scopes.ATTN_GATE, scopes.MOE_SHARED, scopes.MOE_ROUTE):
