@@ -175,11 +175,16 @@ scope                 what falls under it
                       calls where the model's ``attention_fn`` reads its
                       operands in place, else its ``jnp`` body)
 ``hvd.ssd.scan``      the chunked state-space recurrence itself
-                      (``ops/ssd.py::ssd_scan``): the log-decays, cutting
-                      into chunks, every chunk's products, the walk over the
-                      chunks that carries the state; forward, run again
-                      under recomputation and backward; XLA operations, and
-                      Mosaic calls should a later kernel replace part of it
+                      (``ops/ssd.py::ssd_scan_rows``): where the model's
+                      ``attention_fn`` reads its operands in place, ONE
+                      Mosaic call forward and one backward that walk the
+                      chunks with the state in VMEM (``scan_rows``), and
+                      beside them XLA's few operations on ``[B, S, H]``
+                      arrays (the log-decays' sums, their pieces) and the
+                      joining of the cotangent's channels; else the ``jnp``
+                      body: the log-decays, cutting into chunks, every
+                      chunk's products, the walk as a ``while``; forward,
+                      run again under recomputation and backward
 ``hvd.ssd.proj``      the same layer's two projections: ``in_proj [hidden,
                       2 H P + 2 G N + H]`` to z, x, B, C and dt, and
                       ``out_proj``; and their gradient products (a name
@@ -343,7 +348,7 @@ __all__ = [
     "MOSAIC", "MOSAIC_FLASH_FWD", "MOSAIC_FLASH_BWD", "MOSAIC_ROPE",
     "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
     "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION", "MOSAIC_SSCAN",
-    "MOSAIC_GROUPED_MATMUL", "MOSAIC_GATED_NORM",
+    "MOSAIC_GROUPED_MATMUL", "MOSAIC_GATED_NORM", "MOSAIC_SSD_SCAN",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
 ]
@@ -410,6 +415,7 @@ MOSAIC_PAGED_ATTENTION = MOSAIC + "paged_attention"
 MOSAIC_SSCAN = MOSAIC + "selective_scan"
 MOSAIC_GROUPED_MATMUL = MOSAIC + "grouped_matmul"
 MOSAIC_GATED_NORM = MOSAIC + "gated_norm"
+MOSAIC_SSD_SCAN = MOSAIC + "ssd_scan"
 INIT = "hvd.init"
 INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
 INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize

@@ -57,7 +57,7 @@ from horovod_tpu.ops import rope as _rope
 from horovod_tpu.ops.selective_scan import (selective_scan,
                                             selective_scan_states)
 from horovod_tpu.ops.short_conv import convolved
-from horovod_tpu.ops.ssd import ssd_scan, ssd_states
+from horovod_tpu.ops.ssd import ssd_scan_rows, ssd_states
 from horovod_tpu.ops.sparse_index import index_loss, select_keys
 
 __all__ = ["LlamaConfig", "LlamaModel", "RMSNorm", "RopeParameters",
@@ -1966,11 +1966,15 @@ class Mamba2(nn.Module):
 
     The gate multiplies BEFORE the norm, which is over each group's ``H P /
     G`` lanes (all of them at G = 1).  The recurrence runs chunk by chunk
-    (``ops/ssd.py``, ``chunk_size`` rows); the two projections are under
+    (``ops/ssd.py``, ``chunk_size`` rows: where the model's ``attention_fn``
+    reads its operands in place and the shapes are ones it takes, one
+    Mosaic call each way that walks the chunks with the state in VMEM, reads
+    u, B and C where the filter left them and leaves y as rows; else its
+    ``jnp`` body on the three cut out); the two projections are under
     ``hvd.ssd.proj``; the skip, the gate and the norm are
     ``ops/gated_norm.py``'s (one Mosaic pass each way on u and z where the
-    filter and ``in_proj`` left them, where the model's ``attention_fn``
-    reads its operands in place, else its ``jnp`` body).  Parameters:
+    filter and ``in_proj`` left them, under the same condition, else its
+    ``jnp`` body).  Parameters:
     ``in_proj [hidden, 2 H P + 2 G N + H]``, ``conv_w [K, H P + 2 G N]``,
     ``conv_b``, ``a_log dt_bias d [H]``, ``norm [H P]``, ``out_proj``.
 
@@ -2002,28 +2006,31 @@ class Mamba2(nn.Module):
                 1, None, self.in_place,
                 bias=self.param("conv_b", nn.initializers.zeros,
                                 (inner + 2 * bc,)), first=inner)
-            u = xbc[..., :inner].reshape(B, S, heads, width)
-            b = xbc[..., inner:inner + bc].reshape(B, S, groups, state)
-            c = xbc[..., inner + bc:].reshape(B, S, groups, state)
         a_log = self.param("a_log", _mamba_a_log_init, (heads,))
         with _scopes.scope(_scopes.SSD_GATES):
             dt = jax.nn.softplus(dt.astype(jnp.float32) + self.param(
                 "dt_bias", _dt_bias_init, (heads,)))
         with _scopes.scope(_scopes.SSD_SCAN):
-            y = ssd_scan(u, dt, a_log, b, c, chunk=cfg.chunk_size)
-        y = y.reshape(B, S, inner)
+            # (The scan reads u, B and C where the filter left them, and
+            # leaves y as rows, which is what the gates read.)
+            y = ssd_scan_rows(xbc, dt, a_log, heads, groups, state,
+                              self.in_place, chunk=cfg.chunk_size)
         d = self.param("d", nn.initializers.ones, (heads,))
         if (self.is_mutable_collection("ssd_stats")
                 and not self.is_initializing()):
             decay = jnp.exp(-jnp.exp(a_log) * dt)
+            u = xbc[..., :inner]
+            b, c = (xbc[..., at:at + bc].reshape(B, S, groups, state)
+                    for at in (inner, inner + bc))
             for name, value in (
                     ("decay_min", jnp.min(decay)),
                     ("decay_mean", jnp.mean(decay)),
                     ("dt_mean", jnp.mean(dt)),
                     ("state_max", jnp.max(jnp.abs(ssd_states(
-                        u, dt, a_log, b, c, chunk=cfg.chunk_size)))),
+                        u.reshape(B, S, heads, width), dt, a_log, b, c,
+                        chunk=cfg.chunk_size)))),
                     ("out_max", jnp.max(jnp.abs(skipped(
-                        y, u.reshape(y.shape), d).astype(jnp.float32))))):
+                        y, u, d).astype(jnp.float32))))):
                 self.sow("ssd_stats", name, value)
         with _scopes.scope(_scopes.SSD_GATES):
             # (The skip, the gate and the norm read u and z where the

@@ -284,7 +284,10 @@ def one_chip(topo, monkeypatch):
     deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(gated_norm, "_interpret", lambda: False)
+    from horovod_tpu.ops import short_conv
+
+    for module in (gated_norm, short_conv, ssd):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -311,6 +314,59 @@ def test_the_wide_pair_compiles_for_a_v5e_at_the_cells_shape(one_chip):
         sds(4096, jnp.float32, ()), sds(4096)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "f32[1,8192,4096]" not in text
+
+
+def test_a_mamba_layer_compiles_for_a_v5e_at_the_cells_shape(one_chip):
+    """The cell's ``Mamba2`` mixer at 1 x 8192 tokens and the published
+    widths (``inner`` 4096 in ONE group, chunks of 256, ``in_proj`` 8512
+    wide), in place, forward and backward: six Mosaic calls, one each way
+    under each of ``hvd.ssd.conv``, ``hvd.ssd.scan`` and ``hvd.ssd.gates``;
+    the scan's pair takes the shape (one group is eight steps of eight
+    heads: B's and C's cotangents are summed inside the call) and leaves
+    nothing of the ``jnp`` scan in the text -- no ``while``, no
+    ``reduce-window``, no ``[256, 256]`` array a slab (``f32[8,1,1,64,256,
+    256]``, 134 MB each)."""
+    import re
+
+    from horovod_tpu.common import scopes
+
+    cell = manifest.cell(CELL)
+    config = manifest.load_job(cell["config"]["job"]).build(
+        cell["config"], cell["traffic"], 1).llama
+    assert (config.mamba_num_heads, config.mamba_head_dim, config.n_groups,
+            config.ssm_state_size, config.chunk_size) == (64, 64, 1, 128, 256)
+    module = llama.Mamba2(config, in_place=True)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    x = jax.ShapeDtypeStruct((1, 8192, config.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    variables = jax.eval_shape(lambda k: module.init(k, jnp.zeros(
+        (1, 256, config.hidden_size), jnp.bfloat16)), jax.random.key(0))
+
+    def grads(variables, x):
+        return jax.grad(lambda p, x: jnp.sum(module.apply(
+            {**variables, "params": p}, x).astype(jnp.float32)),
+            argnums=(0, 1))(variables["params"], x)
+
+    before = ssd.body_counts()
+    compiled = jax.jit(grads).lower(jax.tree.map(sds, variables), x).compile()
+    after = ssd.body_counts()
+    assert after["mosaic"] == before["mosaic"] + 1
+    assert after["plain"] == before["plain"]
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 6
+    for scope in (scopes.SSD_CONV, scopes.SSD_SCAN, scopes.SSD_GATES):
+        assert sum(scope in call for call in calls) == 2, scope
+    assert " while(" not in text and "reduce-window" not in text
+    assert not re.search(r"f32\[[\d,]*256,256\]", text)
+    scan = [call for call in calls if scopes.SSD_SCAN in call]
+    assert all(call.count("bf16[1,8192,4352]{2,1,0}") == 3 for call in scan)
+    # (1.6 GB is the cell's whole step's; a layer's own: 0.49.)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 # -- the scan at the published chunk ----------------------------------------------
@@ -427,11 +483,14 @@ def test_the_other_paths_refuse_by_name(who, changes, says):
 
 # -- what the other stacks trace ---------------------------------------------------
 
-# sha256 of the StableHLO text (no locations) that the PARENT of this change
-# (commit 68d333b, JAX 0.9.0) lowers for the function below: a tiny Nemotron
-# stack MEM*E under ``hybrid_override_pattern``, value and gradient.
+# sha256 of the StableHLO text (no locations) lowered for the function below
+# (JAX 0.9.0): a tiny Nemotron stack MEM*E under ``hybrid_override_pattern``,
+# value and gradient.  PR 60 pinned its parent's (commit 68d333b: b49b8244..);
+# PR 62 changed what a Mamba-2 layer traces on the CPU (``ops/ssd.py``: the
+# log-decays' sums are a triangular product, no ``cumsum``; u, B and C are cut
+# from the filter's result inside ``ssd_scan_rows``) and pinned its own.
 PARENTS_TEXT = (
-    "b49b82442ddaf4814d5f9dc3743de35e6f6c369ab2259d96d6181583ea7a4de6")
+    "a2d788f45bf2ddd4152ae35b556c1d6ea7cb393f75d7086e4c3ff9935a3f280a")
 
 
 def test_a_pattern_stack_with_identity_multipliers_lowers_as_it_did():

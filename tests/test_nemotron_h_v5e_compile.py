@@ -6,9 +6,12 @@ a routed layer's grouped products (relu2: two matrices an expert); the
 convolution over a Mamba-2 layer's 6,144 channels, which is the Mosaic pass
 with its bias as without one, on an array of their own or where they lie in
 ``in_proj``'s output; and a Mamba-2 layer whole, forward and backward, whose
-only Mosaic calls are that filter's and the gates' (``ops/gated_norm.py``: the
-skip, the gate and the grouped norm, one call each way on operands in place).  The whole step at 2 x 8192 is compiled
-by the builder's study and on the chip, not here (it takes a minute)."""
+Mosaic calls are that filter's, the scan's (``ops/ssd.py``: the chunks walked
+with the state in VMEM, u, B and C read where the filter left them) and the
+gates' (``ops/gated_norm.py``: the skip, the gate and the grouped norm), one
+call each way each, on operands in place.  The whole step at 2 x 8192 is
+compiled by the builder's study and on the chip, not here (it takes a
+minute)."""
 
 import os
 import re
@@ -22,7 +25,7 @@ from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.models import llama
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import gated_norm, grouped_matmul, short_conv
+from horovod_tpu.ops import gated_norm, grouped_matmul, short_conv, ssd
 
 CELL = "nemotron-3-nano-30b-a3b.train-s8k-b2"
 _MOSAIC_CALL = re.compile(r' = .*custom_call_target="tpu_custom_call"')
@@ -46,7 +49,7 @@ def one_chip(topo, monkeypatch):
     deviceless executable cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for module in (fa, short_conv, grouped_matmul, gated_norm):
+    for module in (fa, short_conv, grouped_matmul, gated_norm, ssd):
         monkeypatch.setattr(module, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -215,47 +218,75 @@ def mamba_layer(one_chip, config):
     modules' ``body_counts()`` before and after the trace.  Compiled by the
     first test that asks."""
     if not _LAYER:
-        before = short_conv.body_counts(), gated_norm.body_counts()
+        before = (short_conv.body_counts(), gated_norm.body_counts(),
+                  ssd.body_counts())
         compiled = _layer_compiled(llama.Mamba2(config, in_place=True),
                                    one_chip, config.hidden_size)
         _LAYER.update(
             text=compiled.as_text(),
             temporaries=compiled.memory_analysis().temp_size_in_bytes,
             conv=(before[0], short_conv.body_counts()),
-            gates=(before[1], gated_norm.body_counts()))
+            gates=(before[1], gated_norm.body_counts()),
+            scan=(before[2], ssd.body_counts()))
     return _LAYER
 
 
 def test_a_mamba_layers_mosaic_calls_are_the_filters_and_the_gates(
         mamba_layer):
-    """A ``Mamba2`` layer at 2 x 8192 tokens, forward and backward: its
-    biased filter is ``short_conv``'s pass, one call each way under
-    ``hvd.ssd.conv`` (where ``ssd_conv_ms`` reads them), its skip, gate and
-    norm ``gated_norm``'s, one each way under ``hvd.ssd.gates``, and
-    everything else XLA (the scan is ``jax.numpy``); its three scopes in the
-    text, and no array of a chunk's ``[128, 128]`` for every chunk at once
-    (a slab of 8 chunks at a time: 2 x 8 x 64 heads)."""
+    """A ``Mamba2`` layer at 2 x 8192 tokens, forward and backward: six
+    Mosaic calls, one each way under each of ``hvd.ssd.conv`` (the biased
+    filter, ``short_conv``'s pass, where ``ssd_conv_ms`` reads them),
+    ``hvd.ssd.scan`` (``ops/ssd.py``'s pair: the chunks walked with the state
+    in VMEM) and ``hvd.ssd.gates`` (``gated_norm``'s).  Of the ``jnp`` scan
+    nothing is left in the text: no ``while``, no ``reduce-window`` (the
+    log-decays' ``cumsum`` was one), no array of a chunk's ``[128, 128]`` a
+    slab (``f32[8,2,8,8,128,128]``, 67 MB each); and y goes from the scan's
+    forward call to the gates' as the rows it is, with no transposing
+    ``copy`` between the two."""
     text = mamba_layer["text"]
     before, after = mamba_layer["conv"]
     assert after["fused"] == before["fused"] + 1
     # (``_layer_compiled`` initialises on 8 rows, which no block divides.)
     assert {why for why, n in after["plain"].items()
             if n != before["plain"].get(why, 0)} == {short_conv._NO_ROW_BLOCK}
+    before, after = mamba_layer["scan"]
+    assert after["mosaic"] == before["mosaic"] + 2      # the init's 8 rows,
+    assert after["plain"] == before["plain"]            # padded, and these
     calls = _mosaic_calls(text)
-    assert len(calls) == 4
-    assert sum(scopes.SSD_CONV in call for call in calls) == 2
-    assert sum(scopes.SSD_GATES in call for call in calls) == 2
-    for scope in (scopes.SSD_CONV, scopes.SSD_GATES, scopes.SSD_SCAN):
-        assert scope in text, scope
-    assert "f32[64,2,8,8,128,128]" not in text
-    assert "f32[8,2,8,8,128,128]" in text
+    assert len(calls) == 6
+    for scope in (scopes.SSD_CONV, scopes.SSD_SCAN, scopes.SSD_GATES):
+        assert sum(scope in call for call in calls) == 2, scope
+    assert " while(" not in text and "reduce-window" not in text
+    assert not re.search(r"f32\[\d+,2,8,8,128,128\]", text)
+    # The scan's calls read u, B and C in the filter's result and write
+    # rows; the forward call's y is the gates' operand itself.
+    scan = [call for call in calls if scopes.SSD_SCAN in call]
+    forward, = (call for call in scan if "jit(_forward)" in call)
+    backward, = (call for call in scan if "jit(_backward)" in call)
+    assert forward.count(f"bf16[{B},{S},6144]{{2,1,0}}") == 3
+    assert backward.count(f"bf16[{B},{S},6144]{{2,1,0}}") == 3
+    y = re.match(r"\s*(%[\w.\-]+) = ", forward).group(1)
+    made = {name: line for line in text.splitlines()
+            for name in re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = ", line)}
+    gates, = (call for call in calls
+              if scopes.SSD_GATES in call and "jit(_forward)" in call)
+    operand = re.search(r"custom-call\((%[\w.\-]+)", gates).group(1)
+    while " get-tuple-element(" in made[operand] or " bitcast(" in made[
+            operand]:
+        operand = re.search(r"(?:get-tuple-element|bitcast)\((%[\w.\-]+)",
+                            made[operand]).group(1)
+    assert operand == y, (operand, y)
+    assert not re.search(rf" = bf16\[{B},{S},4096\]\S* copy\(", text)
     # The filter's calls read its channels in ``in_proj``'s output: no
     # ``bf16[2, 8192, 6144]`` cut of them, which as the backward call's
-    # residual is 201 MB more.  (Temporaries: 1.493 GB; 2.030 before the
-    # gates were a pass of their own, 2.231 with the cut.)
-    assert all(f"bf16[{B},{S},10304]" in call for call in calls)
+    # residual is 201 MB more.  (Temporaries: 1.483 GB, of which the
+    # chunks' starting states that the scan's backward call reads are 268
+    # MB; 1.493 with the ``jnp`` scan, 2.030 before the gates were a pass of
+    # their own, 2.231 with the cut.)
+    assert all(f"bf16[{B},{S},10304]" in call for call in calls
+               if scopes.SSD_SCAN not in call)
     assert not re.search(rf" = bf16\[{B},{S},6144\]\S* slice\(", text)
-    assert mamba_layer["temporaries"] < 1.6e9
+    assert mamba_layer["temporaries"] < 1.49e9
 
 
 def test_a_mamba_layers_gates_are_one_call_each_way_on_operands_in_place(
