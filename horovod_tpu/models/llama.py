@@ -331,11 +331,18 @@ def _layer_specs(cfg: "LlamaConfig") -> tuple:
             not used <= set(ropes) <= set(LAYER_TYPES) or any(
                 not 0.0 < r.partial_rotary_factor <= 1.0
                 or int(r.partial_rotary_factor * cfg.head_dim) % 2
-                for r in ropes.values())):
+                for r in ropes.values() if r is not None)):
         raise ValueError(
             f"rope_parameters names {sorted(ropes)}: an entry for "
             f"each softmax layer type in use ({sorted(used)}), each "
-            f"turning a whole number of pairs of a head")
+            f"turning a whole number of pairs of a head, or None (the "
+            f"type's layers do not rotate)")
+    if cfg.router_input == "layer" and not all(
+            spec.mixer is not None for spec in specs if spec.ffn == ROUTED):
+        raise ValueError(
+            "router_input is 'layer': the router reads the input of a layer "
+            "that is a mixer AND routed experts, ahead of the mixer; a "
+            "routed layer of hybrid_override_pattern is that sublayer alone")
     return tuple(specs)
 
 
@@ -395,10 +402,15 @@ class LlamaConfig:
     ``moe_intermediate_size`` (0: ``intermediate_size``), ``shared_experts``,
     ``moe_shared_expert_intermediate_size``, ``shared_expert_gate``,
     ``norm_topk_prob``, ``routed_scaling_factor``, ``scoring_func``,
-    ``topk_method``, ``router_bias_update_rate`` and ``mlp_hidden_act``
-    make of it.  ``held_experts`` > 0 says this program holds that many of
-    the experts, ids ``first_held_expert`` onwards (one chip's share under
-    expert parallelism): only they have weights here.  ``balance_over`` says
+    ``topk_method``, ``router_bias_update_rate``, ``mlp_hidden_act``
+    (``"silu"``: SwiGLU experts; ``"relu"``: the gated ReLU, ReGLU;
+    ``"relu2"``: ``relu(.)`` squared and no gate) and ``router_input``
+    (``"experts"``: the router reads what the experts read, the
+    feed-forward's normed input; ``"layer"``: the LAYER's input, ahead of the
+    mixer and its norm, SmallThinker's router before attention) make of it.
+    ``held_experts`` > 0 says this program holds that many of the experts,
+    ids ``first_held_expert`` onwards (one chip's share under expert
+    parallelism): only they have weights here.  ``balance_over`` says
     over what a routed layer's balance loss counts its assignments: each
     ``"sequence"`` (DeepSeek-V2's ``seq_aux``) or the whole ``"batch"``.
 
@@ -412,9 +424,11 @@ class LlamaConfig:
     key; ``attention_fn`` is handed ``window=``).  ``rope_parameters`` (pairs
     ``(layer type, RopeParameters)``; None: ``rope_theta`` and
     ``rope_scaling`` for every layer) gives each layer type its own theta,
-    YaRN scaling and ``partial_rotary_factor``; ``rope_theta`` None: the
-    softmax layers do not rotate (position comes from the other layers'
-    recurrences and convolutions).
+    YaRN scaling and ``partial_rotary_factor``, or None in place of the
+    ``RopeParameters``: the layers of that type do not rotate beside those of
+    a type that does (SmallThinker's global layers, NoPE, between window
+    layers that turn); ``rope_theta`` None: no softmax layer rotates
+    (position comes from the other layers' recurrences and convolutions).
 
     ``LayerSpec.norms``, ``reads``, ``writes``.  A layer is a mixer behind
     ``"norm_attn"`` and a feed-forward behind ``"norm_mlp"``, each residual;
@@ -529,7 +543,8 @@ class LlamaConfig:
     chunk_size: int = 128
     scoring_func: str = "softmax"                 # or "sigmoid"
     topk_method: str = "greedy"                   # or "noaux_tc"
-    mlp_hidden_act: str = "silu"                  # or "relu2" (the experts')
+    mlp_hidden_act: str = "silu"      # or "relu", "relu2" (the experts')
+    router_input: str = "experts"     # or "layer": the layer's own input
     moe_shared_expert_intermediate_size: int = 0  # 0: shared_experts x F
     router_bias_update_rate: float = 1e-3
     mb_per_layer: int = 0         # 2: a decoder-hybrid-decoder stack
@@ -613,9 +628,24 @@ class LlamaConfig:
                 f"attention_multiplier is {self.attention_multiplier}: the "
                 f"softmax scale of attention_kind 'full' alone "
                 f"({self.attention_kind!r} sets its own)")
-        if self.mlp_hidden_act not in ("silu", "relu2"):
+        if self.mlp_hidden_act not in ("silu", "relu", "relu2"):
             raise ValueError(f"mlp_hidden_act is {self.mlp_hidden_act!r}: "
-                             f"'silu' (gated) or 'relu2' (not gated)")
+                             f"'silu' or 'relu' (gated) or 'relu2' (not "
+                             f"gated)")
+        if self.mlp_hidden_act == "relu" and (
+                self.num_experts < 2 or self.shared_experts
+                or self.first_dense_layers):
+            raise ValueError(
+                "mlp_hidden_act 'relu' gates the routed experts' products: a "
+                "shared expert or a dense layer beside them would be a "
+                "SiLU-gated SwiGLU (shared_experts and first_dense_layers "
+                "are 0 with it, num_experts > 1); not built")
+        if self.router_input not in ("experts", "layer") or (
+                self.router_input == "layer" and self.num_experts < 2):
+            raise ValueError(
+                f"router_input is {self.router_input!r}: 'experts' (the "
+                f"router reads what the experts read) or, with num_experts "
+                f"> 1, 'layer' (the layer's input, ahead of its mixer)")
         # What each layer is, decided (or refused) once.  Not a field: configs
         # are equal, and ``dataclasses.replace`` copies, by the fields alone.
         object.__setattr__(self, "layers", _layer_specs(self))
@@ -795,6 +825,18 @@ class LlamaConfig:
                 f"indexer's {self.index_head_dim}-wide key a token beside "
                 f"K and V, and a decode step would pick {self.index_topk} "
                 f"of the cached keys before it attends; not built")
+        if self.router_input != "experts":
+            raise NotImplementedError(
+                f"{who} has no path for a router ahead of the mixer "
+                f"(router_input={self.router_input!r}): its layer would "
+                f"score the [{self.num_experts}] experts from the residual "
+                f"stream as the layer receives it and carry the choice past "
+                f"attention to the feed-forward; not built")
+        if self.mlp_hidden_act == "relu":
+            raise NotImplementedError(
+                f"{who} has no path for ReLU-gated experts "
+                f"(mlp_hidden_act='relu'): its feed-forward is a SiLU-gated "
+                f"SwiGLU in every layer; not built")
         if self.shared_expert_gate:
             raise NotImplementedError(
                 f"{who} has no path for a gated shared expert "
@@ -859,6 +901,14 @@ class LlamaConfig:
                 f"(zero_centered_norm=True): its layers multiply the "
                 f"normed state by a scale from ones, not by 1 + a scale "
                 f"from zeros; not built")
+        still = [kind for kind, rope in self.rope_parameters or ()
+                 if rope is None]
+        if still:
+            raise NotImplementedError(
+                f"{who} has no path for a layer type that does not rotate "
+                f"beside one that does (rope_parameters gives {still} None): "
+                f"it turns q and k by one table in every layer, and a cached "
+                f"key of such a layer would be kept unturned; not built")
         if self.rope_parameters is not None:
             raise NotImplementedError(
                 f"{who} has no path for a rotation a layer type or a "
@@ -1496,6 +1546,7 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
     assignments sorted by held expert, ``inverse`` each assignment's place
     among them, ``last`` the running sum of ``rows_per_expert``.  ``act``
     ``"silu"``: ``w_gu`` is ``[held, H, 2F]``, a SwiGLU's gate and up;
+    ``"relu"``: the same matrices and ``relu(gate) * up``, the gated ReLU;
     ``"relu2"``: it is ``w_up [held, H, F]`` and the rows between the two
     products are ``relu(.)`` squared, no gate.
 
@@ -1530,7 +1581,7 @@ def _one_buffer(tokens, w_gu, w_down, weights, order, inverse, last,
         else:
             gate, up = jnp.split(
                 grouped_matmul(rows, w_gu, sizes, in_place), 2, axis=-1)
-            rows = nn.silu(gate) * up
+            rows = (nn.relu if act == "relu" else nn.silu)(gate) * up
         rows = grouped_matmul(rows, w_down, sizes, in_place)
     with _scopes.scope(_scopes.MOE_COMBINE):
         rows = jnp.where(live, rows, 0)
@@ -1654,6 +1705,15 @@ class RoutedExperts(nn.Module):
     ``mlp_hidden_act`` ``"relu2"``: E_e and S are ``relu(x W_up)^2 W_down``
     (``w_up [held, H, F]`` in place of ``w_gate_up``; S a ``Relu2MLP``), and
     S is ``moe_shared_expert_intermediate_size`` wide where that is set.
+    ``"relu"``: E_e is the gated ReLU, ``(relu(x W_gate) * (x W_up))
+    W_down`` over the same ``w_gate_up`` (ReGLU; no S goes with it).
+
+    ``__call__(x, router_x=None)``: the router's scores are ``router_x W_r``
+    where the caller hands another tensor than the experts' x for them
+    (``router_input`` ``"layer"``: ``LlamaLayer`` hands the layer's input,
+    ahead of the mixer and its norm, so the router's gradient enters the
+    stream before attention); choice, gates, balance loss and counters are
+    made from them in this one call, as from x's.
 
     Outside ``init`` it sows, where the caller makes the collection
     mutable: ``moe_stats/assignments_per_expert [num_experts]``,
@@ -1669,9 +1729,11 @@ class RoutedExperts(nn.Module):
     in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_x=None):
         cfg = self.config
         B, S, H = x.shape
+        if router_x is None:
+            router_x = x
         E, K, held = cfg.num_experts, cfg.experts_per_token, cfg.experts_held
         F = cfg.moe_intermediate_size or cfg.intermediate_size
         T = B * S
@@ -1688,7 +1750,7 @@ class RoutedExperts(nn.Module):
 
         with _scopes.scope(_scopes.MOE_ROUTE):
             router = nn.Dense(E, use_bias=False, dtype=jnp.float32,
-                              name="router")(x.astype(jnp.float32))
+                              name="router")(router_x.astype(jnp.float32))
             if cfg.scoring_func == "sigmoid":
                 scores = jax.nn.sigmoid(router)                    # [B,S,E]
             else:
@@ -2177,7 +2239,9 @@ class LlamaLayer(nn.Module):
     ``"moe"``) under ``hvd.block.ffn``, those it has, each residual with one
     of the spec's ``norms`` (``_stack_norm``): on the sublayer's input or,
     with ``norm_placement`` ``"post"``, on its output inside the residual.
-    ``cos``, ``sin`` are the tables of the spec's ``rope``.
+    ``cos``, ``sin`` are the tables of the spec's ``rope``.  With
+    ``router_input`` ``"layer"`` the routed experts' router is handed x as
+    the layer received it, beside the feed-forward's normed input.
 
     Where layers share tensors (``LlamaConfig.layers_share``) the layer
     takes and returns ``shared`` beside x: its mixer is handed
@@ -2218,6 +2282,9 @@ class LlamaLayer(nn.Module):
         if spec.ffn is not None:
             ffn = (RoutedExperts(cfg, in_place=in_place, name="moe")
                    if spec.ffn == ROUTED else SwiGLU(cfg, name="mlp"))
+            if spec.ffn == ROUTED and cfg.router_input == "layer":
+                # The router reads the stream as the layer received it.
+                ffn = functools.partial(ffn, router_x=x)
 
         def residual(x, sublayer, norm):
             norm = _stack_norm(cfg, norm)

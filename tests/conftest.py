@@ -393,6 +393,36 @@ TINY.setdefault("ssm_lm", {
     "traffic": {"sequence": 64, "batch_per_chip": 2},
 })
 
+TINY.setdefault("prerouted_moe_lm", {
+    # Hidden 128 (a lane tile, so ``flash_attention_fn``'s model takes the
+    # interpreted Mosaic passes); TWO layers, one of each kind (the suite is
+    # near its time limit; the cell's period of four runs in
+    # ``tests/test_smallthinker.py`` and on the chip): a global layer that
+    # does not rotate and a layer that does under a window of 64 keys, 2
+    # query heads over 1 key-value head of 64; experts 4 to 7 of 16 held, 3
+    # choices a token, ReGLU of 32, the router on the layer's input.
+    "config": {"hidden_size": 128, "num_hidden_layers": 2,
+               "rope_layout": [0, 1], "sliding_window_layout": [0, 1],
+               "sliding_window_size": 64,
+               "num_attention_heads": 2, "num_key_value_heads": 1,
+               "head_dim": 64, "moe_ffn_hidden_size": 32,
+               "vocab_size": 512, "moe_num_primary_experts": 4,
+               "moe_num_active_primary_experts": 3,
+               "deployment": {"num_experts_published": 16,
+                              "first_held_expert": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up, as ``hybrid_moe_lm``.
+                          "loss_must_fall": False,
+                          # bf16 at these widths; float32 through the same
+                          # code agrees to 1e-4 (tests/test_smallthinker.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    "traffic": {"sequence": 128, "batch_per_chip": 2},
+})
+
 
 # The files that take over 100 s of the driver's command
 # (``/root/TESTS_LAST_RUN.json``: six workers, ``--dist loadfile``), longest
@@ -419,6 +449,7 @@ LONGEST_FIRST = (
     "tests/benchmark/test_benchmark_sparse.py",         # 176 s
     "tests/test_deepseek_model.py",                     # 160 s
     "tests/test_flash_v5e_compile.py",                  # 157 s
+    "tests/test_smallthinker.py",                       # 155 s (PR 63)
     "tests/test_looped_llama.py",                       # 146 s
     "tests/benchmark/test_benchmark_moe.py",            # 131 s
     "tests/test_nemotron_h.py",                         # 118 s
@@ -521,7 +552,7 @@ _MANIFEST_THEN = {
     # (This one reads the cells at import, from the file as it is: its cut
     # keeps every cell, the newest named here, and ends the metrics at its.)
     "test_benchmark_startup_spans.py::test_the_manifests_ten_entries":
-        ("granite-4.0-h-micro.train-s8k", "trace_loss_self_ms"),
+        ("smallthinker-21b-a3b.train-s16k", "trace_loss_self_ms"),
     "test_benchmark_qk_norm.py::test_the_manifests_one_new_entry":
         ("phi-4-mini-flash.train-s8k", "diff_attn_ms"),
     "test_benchmark_ssm_moe.py::test_the_manifests_new_entries":
