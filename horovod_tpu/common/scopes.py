@@ -152,9 +152,9 @@ scope                 what falls under it
                       the sequence into chunks, the products and the
                       triangular solve of every chunk, the walk over the
                       chunks that carries the state, forward, run again
-                      under recomputation and backward; Mosaic calls and
-                      XLA operations alike, should a later kernel replace
-                      part of it
+                      under recomputation and backward; Mosaic calls
+                      (``walk_rows``' where a head is whole lane tiles,
+                      PR 64) and XLA operations alike
 ``hvd.gdn.solve``     INSIDE ``hvd.gdn.scan``: the inverse of every chunk's
                       unit lower-triangular system
                       (``ops/gated_delta.py::_tril_inverse``: the Mosaic
@@ -349,6 +349,7 @@ __all__ = [
     "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
     "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION", "MOSAIC_SSCAN",
     "MOSAIC_GROUPED_MATMUL", "MOSAIC_GATED_NORM", "MOSAIC_SSD_SCAN",
+    "MOSAIC_GDN_SCAN",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
 ]
@@ -416,6 +417,7 @@ MOSAIC_SSCAN = MOSAIC + "selective_scan"
 MOSAIC_GROUPED_MATMUL = MOSAIC + "grouped_matmul"
 MOSAIC_GATED_NORM = MOSAIC + "gated_norm"
 MOSAIC_SSD_SCAN = MOSAIC + "ssd_scan"
+MOSAIC_GDN_SCAN = MOSAIC + "gdn_scan"
 INIT = "hvd.init"
 INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
 INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize

@@ -49,7 +49,8 @@ from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
 from horovod_tpu.ops.gated_delta import (CHUNK, calls_in_place,
                                          gated_delta_rule,
-                                         gated_delta_states)
+                                         gated_delta_states,
+                                         key_heads_copied, walks_rows)
 from horovod_tpu.ops.gated_norm import gated_norm, norm_gated, skipped
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
@@ -1881,8 +1882,10 @@ class GatedDeltaNet(nn.Module):
         o_t = S_t q_t
         y   = (RMSNorm(o) * silu(x W_g)) W_o        one learned [d_v] scale for all heads
 
-    The recurrence runs chunk by chunk (``ops/gated_delta.py``), its state
-    ``[d_v, d_k]`` a head in float32.  The output norm and its gate are
+    The recurrence runs chunk by chunk (``ops/gated_delta.py``: Mosaic
+    calls on the rows of q, k and v where the heads are whole lane tiles
+    and the ``attention_fn`` reads in place, else its ``jnp`` walk), its
+    state ``[d_v, d_k]`` a head in float32.  The output norm and its gate are
     ``ops/gated_norm.py::norm_gated`` (under ``hvd.gdn.gates``, beside the
     ``wa`` / ``wb`` projections, which stay XLA's): one Mosaic pass each way
     where the model's ``attention_fn`` reads its operands in place and one,
@@ -1939,11 +1942,16 @@ class GatedDeltaNet(nn.Module):
             g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
             beta = jax.nn.sigmoid(b) * (2.0 if cfg.linear_allow_neg_eigval
                                         else 1.0)
+        # (Where the rule's walk is the Mosaic calls it reads q, k and v, and
+        # writes o, as rows: the key heads' copies and the norm's read
+        # follow it.)
+        rows = walks_rows(d_k, d_v, h_v, self.in_place)
         if h_v != h_k:
             # Value head j reads key head j // (h_v / h_k): copied, for a
             # rule that takes as many of each.
             with _scopes.scope(_scopes.GDN_HEADS):
-                q, k = (jnp.repeat(t, h_v // h_k, axis=2) for t in (q, k))
+                q, k = (key_heads_copied(t, h_v // h_k, rows)
+                        for t in (q, k))
         with _scopes.scope(_scopes.GDN_SCAN), calls_in_place(self.in_place):
             o = gated_delta_rule(q, k, v, g, beta)
         if (self.is_mutable_collection("gdn_stats")
@@ -1960,7 +1968,7 @@ class GatedDeltaNet(nn.Module):
         with _scopes.scope(_scopes.GDN_GATES):
             o = norm_gated(o.reshape(z.shape), z, self.param(
                 "o_norm", nn.initializers.ones, (d_v,)), h_v, cfg.rms_eps,
-                self.in_place, chunk=CHUNK)
+                self.in_place, chunk=0 if rows else CHUNK)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                         name="wo")(o)
 

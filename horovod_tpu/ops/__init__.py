@@ -4,9 +4,11 @@ The kernels are modules of their own, imported where they are used:
 ``flash_attention``, ``paged_attention``, ``sparse_index``, ``rope`` (the
 rotation as one Mosaic pass), ``short_conv`` (a linear-attention layer's
 convolution, SiLU and L2 norm as one Mosaic pass each way) and
-``gated_delta`` (the chunkwise gated delta rule: ``jax.numpy`` but for its
-chunks' triangular systems, solved in one Mosaic call a slab), ``ssd``
-(Mamba-2's chunked state-space scan, ``jax.numpy``), ``chunking`` (the
+``gated_delta`` (the chunkwise gated delta rule: Mosaic calls over the rows
+where a head is whole lane tiles, else ``jax.numpy`` but for its chunks'
+triangular systems, solved in one Mosaic call a slab), ``ssd`` (Mamba-2's
+chunked state-space scan: a Mosaic call each way, or ``jax.numpy``),
+``chunking`` (the
 chunks and slabs the two recurrences share), ``selective_scan`` (Mamba-1's
 recurrence: a Mosaic call each way, or chunks in ``jax.numpy``) and
 ``grouped_matmul`` (a routed layer's grouped products: XLA's ``ragged_dot``,

@@ -234,10 +234,14 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     assert batch.shape == (2, 8193)
     step = hvd.make_train_step(job.loss_fn, job.optimizer, mesh)
     before = (fa.layout_counts(), short_conv.body_counts(),
-              gated_delta.solve_counts(), gated_norm.body_counts())
+              gated_delta.solve_counts(), gated_norm.body_counts(),
+              gated_delta.walk_counts())
     compiled = step.lower(*described(state), described(batch)).compile()
     after = (fa.layout_counts(), short_conv.body_counts(),
-             gated_delta.solve_counts(), gated_norm.body_counts())
+             gated_delta.solve_counts(), gated_norm.body_counts(),
+             gated_delta.walk_counts())
+    assert after[4]["mosaic"] - before[4]["mosaic"] == linear
+    assert after[4]["plain"] == before[4]["plain"]
     assert after[3]["mosaic"] - before[3]["mosaic"] == linear
     assert after[3]["plain"] == before[3]["plain"]
     assert after[2]["mosaic"] - before[2]["mosaic"] == linear
@@ -258,15 +262,30 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     for gone in ("f32[2,8192,16,256]", "f32[2,8192,2,256]"):
         assert gone not in text, gone
     assert sum(scopes.GDN_CONV in c for c in calls) == 9 * linear
-    # The slabs' systems (512 matrices: four grid steps) by the solve's call,
-    # forward, again, and in the backward slab's preparation, a linear
-    # layer; each states no limit and takes half the default scoped VMEM.
+    # Every chunk's system (8192 matrices, two heads' side by side on the
+    # middle axis: 2 x 32 grid steps) by the solve's call, forward and again,
+    # a linear layer (the backward call of the walk makes A's cotangent
+    # itself: PR 64); each states no limit and takes half the default scoped
+    # VMEM.
     solves = [c for c in calls if scopes.GDN_SOLVE in c]
-    assert len(solves) == 3 * linear
+    assert len(solves) == 2 * linear
     assert all(scopes.GDN_SCAN in c for c in solves)
     assert sum(scopes.REMATTED in c for c in solves) == linear
-    assert "f32[64,64,512]" in solves[0]
+    assert "f32[64,128,4096]" in solves[0]
     assert max(int(_USED.search(c)[1]) for c in solves) <= 8 * 2 ** 20
+    # The chunks' walk (PR 64): the systems' call and the forward call,
+    # each forward and again, and ONE backward call, a linear layer; q, k, v
+    # read and o, dq, dk, dv written as rows; no ``while`` is left under the
+    # scope, and each call takes less than the default scoped VMEM.
+    walks = [c for c in calls if scopes.GDN_SCAN in c
+             and scopes.GDN_SOLVE not in c]
+    assert len(walks) == 5 * linear
+    assert sum(scopes.REMATTED in c for c in walks) == 2 * linear
+    assert all("bf16[2,8192,4096]" in c for c in walks)
+    assert max(int(_USED.search(c)[1]) for c in walks) < (
+        fa._DEFAULT_SCOPED_VMEM)
+    assert not [line for line in text.splitlines()
+                if scopes.GDN_SCAN in line and " while(" in line]
     # The output norm and gate: forward, again, backward (do, dz and the
     # weight's partial sums), and no float32 array of the activations' shape
     # under their scope.
@@ -276,19 +295,23 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     assert sum(" = (" in c for c in gates) == linear
     assert not [line for line in text.splitlines()
                 if scopes.GDN_GATES in line and " = f32[2,8192,4096]" in line]
-    # o is read, and do written, where the rule leaves them (a head is one
-    # lane tile): the calls' first operand is [N, B, H, 64, 128] and no
-    # transposing copy or reshape of the activations' size is left under
-    # the scope.
-    assert all("operand_layout_constraints={bf16[128,2,32,64,128]" in c
+    # o is read, and do written, as the rows the walk's calls write and
+    # read: no transposing copy or reshape of the activations' size is left
+    # under the scope.
+    assert all("operand_layout_constraints={bf16[2,8192,4096]" in c
                for c in gates)
-    assert sum(" = (bf16[128,2,32,64,128]" in c for c in gates) == linear
     assert not [line for line in text.splitlines()
                 if scopes.GDN_GATES in line and " = bf16[2,8192,4096]" in line
                 and any(op in line for op in (" reshape(", " copy(",
                                               " transpose("))]
     ours = [c for c in calls if scopes.RAGGED_DOT_PREFIX not in c]
-    assert len(ours) == (2 + 6) * full + (9 + 3 + 3) * linear
+    # The key heads' copies to their value heads, made in the rows (PR 64):
+    # q's and k's forward, again, and their cotangents' sums.
+    heads = [c for c in calls if scopes.GDN_HEADS in c]
+    assert len(heads) == 6 * linear
+    assert sum("bf16[2,8192,2048]{" in c.split(" custom-call(")[0]
+               for c in heads) == 2 * linear
+    assert len(ours) == (2 + 6) * full + (9 + 2 + 5 + 3 + 6) * linear
     assert scopes.RAGGED_DOT_PREFIX in text
     for scope in (scopes.GDN_HEADS, scopes.GDN_SCAN, scopes.GDN_GATES,
                   scopes.ATTN_GATE, scopes.MOE_SHARED, scopes.MOE_ROUTE):
@@ -297,6 +320,6 @@ def test_the_cells_whole_step_fits_with_the_chosen_remat(topo, one_chip):
     memory = compiled.memory_analysis()
     print(f"arguments {memory.argument_size_in_bytes} + "
           f"temporaries {memory.temp_size_in_bytes}")
-    # Read at these two layers (all four: 8.759 GB and 4.027 GB).
+    # Read at these two layers (all four: 8.759 GB and 3.906 GB since PR 64).
     assert memory.argument_size_in_bytes == pytest.approx(4.879e9, abs=0.1e9)
-    assert memory.temp_size_in_bytes == pytest.approx(3.608e9, abs=0.1e9)
+    assert memory.temp_size_in_bytes == pytest.approx(3.434e9, abs=0.1e9)
