@@ -131,7 +131,10 @@ class CompileLog:
     JAX names no program in the three cache events; they come inside a
     backend compilation, whose record follows them, so they take that
     record's program (two threads that compile at once can swap theirs).
-    The log keeps its newest ``MAX_RECORDS`` records.
+    The log keeps its newest ``MAX_RECORDS`` records: enough that a
+    benchmark run's comparison (one record a function JAX traces: 2,600 for
+    the ``xing4.0-29b-a4b`` cell's reference, PR 65) does not push out the
+    train step's, which are the oldest.
 
     A span is ``{"name", "path", "began", "seconds", "self_seconds"}`` and
     whatever flags it was given: ``path`` its ancestors' names and its own
@@ -143,7 +146,7 @@ class CompileLog:
     span that is still open is in no list.
     """
 
-    MAX_RECORDS = 4096
+    MAX_RECORDS = 16384
     MAX_SPANS = 16384
     DURATIONS = {
         "/jax/core/compile/jaxpr_trace_duration": "trace",

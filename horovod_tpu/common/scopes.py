@@ -100,7 +100,28 @@ scope                 what falls under it
                       up-projection, the rotation of the one shared rotary
                       key, its broadcast over the heads and the
                       concatenation -- what a plain attention layer does
-                      not have
+                      not have; and, with a query latent (``q_lora_rank``),
+                      the query's down-projection, its norm and its
+                      up-projection in place of ``wq`` (the rotation of
+                      q's rotary part stays outside, as without one)
+``hvd.hc.map``        a hyper-connected sublayer's three maps
+                      (``models/llama.py::HyperConnection``, a stack with
+                      ``hc_mult`` > 1): the RMS over a token's ``hc_mult``
+                      streams, the ``[hc_mult * hidden, hc_mult * (hc_mult
+                      + 2)]`` product, gains and biases, the two sigmoids
+                      and the Sinkhorn steps on ``exp`` of the clamped
+                      residual logits -- tokens on the lanes, float32 --
+                      forward, run again under recomputation and backward.
+                      Inside ``hvd.block.attn`` or ``hvd.block.ffn``, the
+                      sublayer's own block
+``hvd.hc.mix``        the same sublayer's two mixes of the streams
+                      (``models/llama.py::_hc_read``, ``_hc_write``): the
+                      read ``h_pre X`` ahead of the sublayer's norm and the
+                      write ``H_res X + h_post^T y`` behind the sublayer,
+                      and their gradients (the streams', the sublayer
+                      output's and the three maps').  Beside ``hvd.hc.map``
+                      in the block's scope; a stack with ``hc_mult`` 1
+                      enters neither
 ``hvd.moe.route``     the routed layer (``RoutedExperts``) before its
                       products: router, softmax, top-k, the balance loss,
                       the sort by expert and the gather of the held
@@ -240,15 +261,16 @@ scope                 what falls under it
                       call or the rule, ``wo``) and the residual add.
                       ``hvd.flash.*``, ``hvd.rope``, ``hvd.attn.*``,
                       ``hvd.mla.latent``, ``hvd.sparse.*``, ``hvd.gdn.*``,
-                      ``hvd.ssd.*``, ``hvd.sscan.*``, ``hvd.lconv.*`` and
-                      ``hvd.gmu`` nest inside it.  In a stack whose
-                      layers are ONE sublayer
+                      ``hvd.ssd.*``, ``hvd.sscan.*``, ``hvd.lconv.*``,
+                      ``hvd.gmu`` and ``hvd.hc.*`` nest inside it.  In a
+                      stack whose layers are ONE sublayer
                       (``LlamaConfig.hybrid_override_pattern``) a mixer
                       layer is this block alone, with the layer's one norm
 ``hvd.block.ffn``     a layer's feed-forward block whole: ``norm_mlp``,
-                      ``SwiGLU`` or ``RoutedExperts`` (``hvd.moe.*`` nest
-                      inside it) and the residual add; in a stack of
-                      one-sublayer layers a routed layer is this block alone
+                      ``SwiGLU`` or ``RoutedExperts`` (``hvd.moe.*`` and
+                      ``hvd.hc.*`` nest inside it) and the residual add; in
+                      a stack of one-sublayer layers a routed layer is this
+                      block alone
 ``hvd.head``          what turns the stack's output into a loss: the final
                       norm (with a looped model's exit gate, nested in
                       ``hvd.loop.exit``), ``LlamaModel.head``'s product,
@@ -335,7 +357,8 @@ __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
     "OPTIMIZER", "APPLY", "FLASH_FWD", "FLASH_BWD", "ROPE",
     "ATTN_WINDOW", "ATTN_GATE", "QK_NORM",
-    "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "MOE_ROUTE", "MOE_EXPERTS",
+    "LOOP_PASS", "LOOP_EXIT", "MLA_LATENT", "HC_MAP", "HC_MIX",
+    "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
     "SSD_CONV", "SSD_GATES", "SSD_SCAN", "SSD_PROJ",
@@ -370,6 +393,8 @@ QK_NORM = "hvd.attn.qknorm"
 LOOP_PASS = "hvd.loop.pass"
 LOOP_EXIT = "hvd.loop.exit"
 MLA_LATENT = "hvd.mla.latent"
+HC_MAP = "hvd.hc.map"
+HC_MIX = "hvd.hc.mix"
 MOE_ROUTE = "hvd.moe.route"
 MOE_EXPERTS = "hvd.moe.experts"
 MOE_COMBINE = "hvd.moe.combine"

@@ -25,6 +25,11 @@ TPU-first design:
   The routed layer is told which experts it holds, routes over all of
   them, gathers its own experts' rows sorted by expert -- none dropped --
   and runs grouped products over them (``RoutedExperts``).
+* Optional residual STREAMS (``hc_mult`` > 1, manifold-constrained
+  hyper-connections): a token's state is ``hc_mult`` vectors that every
+  sublayer reads as a learned mix and rewrites as another
+  (``HyperConnection``); wiring around the sublayers, which stay what they
+  are.
 * Optional weight-shared passes over the stack (``total_ut_steps > 1``,
   the looped LM of Ouro / LoopLM) with a per-token exit gate, and
   recomputation of each layer in the backward pass (``remat``); see
@@ -365,8 +370,10 @@ class LlamaConfig:
     ``attention_kind``: ``"full"`` (``LlamaAttention``); ``"latent"``
     (``LatentAttention``, DeepSeek-V2's MLA: ``kv_lora_rank``,
     ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim`` are the
-    published keys, ``num_kv_heads`` is not read, and ``rope_scaling``, a
-    ``YarnScaling``, sets the rotary frequencies and the softmax scale);
+    published keys, ``num_kv_heads`` is not read, ``rope_scaling``, a
+    ``YarnScaling``, sets the rotary frequencies and the softmax scale, and
+    ``q_lora_rank`` (None: one matrix ``wq``) makes the queries from a latent
+    of that width with a norm of its own, DeepSeek-V3's);
     ``"sparse"`` (``SparseAttention``, DeepSeek-V3.2-Exp's DSA: an indexer of
     ``index_heads`` heads of ``index_head_dim`` picks ``index_topk`` of each
     query's causal keys); ``"differential"`` (``DifferentialAttention``,
@@ -463,6 +470,27 @@ class LlamaConfig:
     both sublayers of every layer); ``logits_scaling`` DIVIDES the logits
     (``LlamaModel.head``).
 
+    The residual path.  ``hc_mult`` n (the published key of manifold-
+    constrained hyper-connections, mHC, arXiv:2512.24880, on hyper-connections,
+    arXiv:2409.19606) is the number of residual STREAMS.  1 is the plain
+    decoder: one ``[B, S, H]`` state and ``x + F(N(x))``, with no parameter,
+    no scope and no operation of the streams' in the trace (a test pins the
+    jaxpr).  With n > 1 a token's state is X ``[n, H]``: the embedding is
+    copied into the n streams, every sublayer F with its pre-norm N reads
+    ``x_in = h_pre X`` and writes ``X' = H_res X + h_post^T F(N(x_in))`` under
+    three maps of its OWN that it makes from X token by token
+    (``HyperConnection``, whose docstring has the equations:
+    ``hc_sinkhorn_iters`` steps project ``exp`` of the residual logits,
+    clamped to ``hc_res_clamp``, onto the doubly stochastic matrices;
+    ``hc_eps`` is the epsilon of the maps' RMS and of Sinkhorn's divisions),
+    and behind the last layer the streams are summed ahead of the final norm
+    (hyper-connections' own convention at both ends).  The layers carry
+    ``[B, S, n, H]``, which is also what ``remat`` keeps of a layer.  The
+    streams are wiring: ``layers`` says what each sublayer IS exactly as
+    without them.  They go with pre-norm sublayers in one pass over a stack
+    that shares nothing and routes on the feed-forward's input; the rest is
+    refused until a configuration has it.
+
     The stack as a whole.  ``total_ut_steps`` (the published key of Ouro's
     ``config.json``) is the number of weight-shared passes over it.  1 is the
     plain decoder: one walk over the layers, logits out.  With T > 1 the same
@@ -534,7 +562,12 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: Optional[int] = None     # None: no query latent
     rope_scaling: Optional[YarnScaling] = None
+    hc_mult: int = 1              # residual streams; 1: the plain decoder
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)   # of the residual map's logits
     hybrid_override_pattern: Optional[str] = None     # "MEMEM*E.." or a tuple
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
@@ -590,6 +623,30 @@ class LlamaConfig:
                 and self.qk_rope_head_dim and self.v_head_dim):
             raise ValueError("latent attention needs kv_lora_rank, "
                              "qk_nope_head_dim, qk_rope_head_dim, v_head_dim")
+        if self.q_lora_rank is not None and (
+                self.attention_kind != "latent" or self.q_lora_rank < 1):
+            raise ValueError(
+                f"q_lora_rank is {self.q_lora_rank!r}: the width of latent "
+                f"attention's query latent (attention_kind='latent'), or "
+                f"None")
+        low, high = self.hc_res_clamp
+        if self.hc_mult < 1 or (self.hc_mult > 1 and (
+                self.hc_sinkhorn_iters < 1 or not self.hc_eps > 0
+                or not low < high)):
+            raise ValueError(
+                f"hc_mult is {self.hc_mult}: at least one residual stream, "
+                f"and more than one with hc_sinkhorn_iters >= 1, hc_eps > 0 "
+                f"and hc_res_clamp (low, high), low < high")
+        if self.hc_mult > 1 and (
+                self.norm_placement != "pre" or self.total_ut_steps != 1
+                or self.mb_per_layer or self.router_input != "experts"):
+            raise ValueError(
+                f"hc_mult is {self.hc_mult}: residual streams are built "
+                f"around pre-norm sublayers (x_in = h_pre X is what the norm "
+                f"reads) in one pass over a stack whose layers share nothing "
+                f"and whose routers read the feed-forward's input; "
+                f"norm_placement='post', total_ut_steps > 1, mb_per_layer "
+                f"and router_input='layer' do not go with them; not built")
         if not 0 <= self.first_held_expert <= (
                 self.num_experts - self.experts_held):
             raise ValueError(
@@ -813,6 +870,24 @@ class LlamaConfig:
                 f"(hybrid_override_pattern={pattern!r}): its layer is "
                 f"always a mixer AND a feed-forward with two norms, and a "
                 f"stage's layers share one shape; not built")
+        if self.hc_mult > 1:
+            raise NotImplementedError(
+                f"{who} has no path for residual streams (hc_mult="
+                f"{self.hc_mult}): its layer adds a sublayer's output to ONE "
+                f"[B, S, {self.hidden_size}] state; it would carry "
+                f"{self.hc_mult} streams a token, make three maps a sublayer "
+                f"from them ({self.hc_sinkhorn_iters} Sinkhorn steps), read "
+                f"and rewrite the streams around attention and the "
+                f"feed-forward, and a decode step or a pipeline stage would "
+                f"hand on {self.hc_mult} times the state; not built")
+        if self.q_lora_rank is not None:
+            raise NotImplementedError(
+                f"{who} has no path for a query latent (q_lora_rank="
+                f"{self.q_lora_rank}): its layer makes q by one matrix wq; "
+                f"it would project x to {self.q_lora_rank} lanes, norm them "
+                f"and project up to the heads, beside latent attention's "
+                f"keys and values, which it has no path for either; not "
+                f"built")
         if self.attention_kind == "latent":
             raise NotImplementedError(
                 f"{who} has no path for latent attention "
@@ -1397,9 +1472,17 @@ class LatentAttention(nn.Module):
         scores = [q_n | q_r] . [k_n | k_r] (d_n + d_r)^(-1/2) m^2
         out = softmax_causal(scores) v W_o
 
-    m squared is ``YarnScaling.softmax_scale``.  No query latent
-    (``q_lora_rank`` null, as DeepSeek-V2-Lite).  ``attention_fn`` is
+    m squared is ``YarnScaling.softmax_scale``.  ``attention_fn`` is
     handed keys ``d_n + d_r`` wide, values ``d_v`` wide and the scale.
+
+    With a query latent of ``r_q = q_lora_rank`` lanes (DeepSeek-V3,
+    arXiv:2412.19437; None, DeepSeek-V2-Lite's null: the one matrix above)::
+
+        q = RMSNorm(x W_qa) W_qb     W_qa [H, r_q], a scale [r_q], W_qb
+                                     [r_q, n (d_n + d_r)]
+
+    as ``wq_a``, ``q_norm`` and ``wq_b`` in place of ``wq``, under
+    ``hvd.mla.latent`` like the keys' latent.
     """
 
     config: LlamaConfig
@@ -1419,8 +1502,14 @@ class LatentAttention(nn.Module):
             return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
                             name=name)
 
-        q = dense(heads * (d_n + d_r), "wq")(x).reshape(
-            B, S, heads, d_n + d_r)
+        if cfg.q_lora_rank is None:
+            q = dense(heads * (d_n + d_r), "wq")(x)
+        else:
+            with _scopes.scope(_scopes.MLA_LATENT):
+                c_q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(
+                    dense(cfg.q_lora_rank, "wq_a")(x))
+                q = dense(heads * (d_n + d_r), "wq_b")(c_q)
+        q = q.reshape(B, S, heads, d_n + d_r)
         q = jnp.concatenate(
             [q[..., :d_n], apply_rope(q[..., d_n:], cos, sin,
                                       in_place=self.in_place)], axis=-1)
@@ -2229,6 +2318,118 @@ class GatedMemory(nn.Module):
                             name="out_proj")(_silu_gated(memory, gate))
 
 
+class HyperConnection(nn.Module):
+    """The three maps of ONE hyper-connected sublayer (manifold-constrained
+    hyper-connections, mHC, arXiv:2512.24880), made from a token's n =
+    ``hc_mult`` streams X ``[n, H]``, in float32::
+
+        u      = vec(X) / sqrt(mean(vec(X)^2) + hc_eps)       [n H]
+        a_pre  = g_pre  (u Phi_pre)  + b_pre                  [n]
+        a_post = g_post (u Phi_post) + b_post                 [n]
+        A_res  = g_res  mat(u Phi_res) + B_res                [n, n]
+        h_pre  = sigmoid(a_pre);    h_post = 2 sigmoid(a_post)
+        M_0    = exp(clip(A_res, hc_res_clamp))
+        M_t    = rows(cols(M_{t-1})),  t = 1..hc_sinkhorn_iters
+        H_res  = M_last             rows sum to 1, columns nearly
+
+    ``cols`` divides every column by its sum + ``hc_eps``, ``rows`` every
+    row: Sinkhorn's projection onto the doubly stochastic matrices, a convex
+    mix of the streams that neither grows nor shrinks their sum.  ``g_*`` are
+    learned scalars; there is no learned scale in the RMS (Phi absorbs one).
+    ``LlamaLayer`` then reads ``x_in = h_pre X`` (``_hc_read``), runs its
+    sublayer F behind its norm N, and writes ``X' = H_res X + h_post^T
+    F(N(x_in))`` (``_hc_write``).
+
+    ``__call__(x [B, S, n, H])`` returns ``(h_pre [n, T], h_post [n, T],
+    H_res [n, n, T])`` over the T = B S tokens: TOKENS ON THE LANES.  A ``[T,
+    n, n]`` float32 tensor pads each token's n * n numbers to a tile of 8 x
+    128, 64 times their bytes, and the 2 * ``hc_sinkhorn_iters``
+    normalisations would walk that; as n * n rows of tokens every step is a
+    few whole vector registers: a sum over a LEADING axis, which adds
+    registers, and a division.  The steps are a ``lax.scan`` of fixed
+    length that the COMPILER unrolls (``unroll``): traced once, no loop on
+    the device.  (Unrolled in Python, their 1,300 equations a sublayer, their
+    transposes and the layer's recomputation cost 100 s of every run's
+    start-up; left a loop, 5 ms of a 459 ms step: PERF.md, PR 65.)  The one product is a float32
+    product of X as it lies, cast up on the way in (as the router's): the
+    RMS's factor is a number a token and multiplies the product's OUTPUT, so
+    no normed copy of X is made.
+
+    Leaves: ``phi_pre``, ``phi_post`` ``[n H, n]`` and ``phi_res`` ``[n H, n
+    n]`` (LeCun normal: u has unit RMS, so the products have unit variance);
+    ``b_pre``, ``b_post`` ``[n]``, ``b_res`` ``[n n]`` (zeros) and the gains
+    ``g_pre``, ``g_post``, ``g_res`` (rank 0, ones), all created float32.
+    This is the builder's initialisation, at which the dynamic part of every
+    map carries signal from the first step; the published recipe is understood
+    to start from small gains (0.01) and biases that make H_res the
+    identity, a state a caller reaches by setting these leaves."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        B, S, n, H = x.shape
+        widths = (("pre", n), ("post", n), ("res", n * n))
+        phi = jnp.concatenate(
+            [self.param(f"phi_{name}", nn.initializers.lecun_normal(),
+                        (n * H, width)) for name, width in widths], axis=1)
+        bias = jnp.concatenate(
+            [self.param(f"b_{name}", nn.initializers.zeros, (width,))
+             for name, width in widths])
+        gain = jnp.concatenate(
+            [jnp.broadcast_to(self.param(f"g_{name}", nn.initializers.ones,
+                                         ()), (width,))
+             for name, width in widths])
+        with _scopes.scope(_scopes.HC_MAP):
+            # Each reader of x casts it up for itself: its cotangent is then
+            # rounded to x's dtype where it is made, and no float32 tensor
+            # of x's size is kept to add them up in.
+            flat = x.reshape(B * S, n * H)
+            scale = jax.lax.rsqrt(jnp.mean(
+                jnp.square(flat.astype(jnp.float32)), axis=-1) + cfg.hc_eps)
+            logits = jnp.einsum("tk,km->mt", flat.astype(jnp.float32),
+                                phi.astype(jnp.float32))
+            logits = (gain.astype(jnp.float32)[:, None] * (logits * scale)
+                      + bias.astype(jnp.float32)[:, None])
+            h_pre = jax.nn.sigmoid(logits[:n])
+            h_post = 2.0 * jax.nn.sigmoid(logits[n:2 * n])
+
+            def step(m, _):     # m [i, j, T]: columns, then rows
+                m = m / (jnp.sum(m, axis=0, keepdims=True) + cfg.hc_eps)
+                return m / (jnp.sum(m, axis=1, keepdims=True)
+                            + cfg.hc_eps), None
+
+            h_res, _ = jax.lax.scan(
+                step, jnp.exp(jnp.clip(logits[2 * n:].reshape(n, n, B * S),
+                                       *cfg.hc_res_clamp)),
+                None, length=cfg.hc_sinkhorn_iters,
+                unroll=cfg.hc_sinkhorn_iters)
+        return h_pre, h_post, h_res
+
+
+def _hc_read(x, h_pre):
+    """``x_in = h_pre X``: ``x [B, S, n, H]``, ``h_pre [n, B S]`` -> ``[B, S,
+    H]`` in x's dtype, summed in float32."""
+    B, S, n, H = x.shape
+    with _scopes.scope(_scopes.HC_MIX):
+        return jnp.einsum("jt,tjc->tc", h_pre, x.reshape(B * S, n, H).astype(
+            jnp.float32)).astype(x.dtype).reshape(B, S, H)
+
+
+def _hc_write(x, y, h_post, h_res):
+    """``X' = H_res X + h_post^T y``: the streams mixed and the sublayer's
+    output ``y [B, S, H]`` written over all of them; ``[B, S, n, H]`` in x's
+    dtype, summed in float32 and rounded once."""
+    B, S, n, H = x.shape
+    with _scopes.scope(_scopes.HC_MIX):
+        mixed = jnp.einsum("ijt,tjc->tic", h_res,
+                           x.reshape(B * S, n, H).astype(jnp.float32))
+        wrote = jnp.einsum("it,tc->tic", h_post,
+                           y.reshape(B * S, H).astype(jnp.float32))
+        return (mixed + wrote).astype(x.dtype).reshape(x.shape)
+
+
 ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention,
                    "sparse": SparseAttention,
                    "differential": DifferentialAttention}
@@ -2250,6 +2451,15 @@ class LlamaLayer(nn.Module):
     ``cos``, ``sin`` are the tables of the spec's ``rope``.  With
     ``router_input`` ``"layer"`` the routed experts' router is handed x as
     the layer received it, beside the feed-forward's normed input.
+
+    With ``hc_mult`` n > 1 x is the token's n streams ``[B, S, n, H]`` and
+    each residual ``x + F(N(x))`` becomes read, sublayer, write, inside the
+    sublayer's own block scope: a ``HyperConnection`` of the sublayer's own
+    (``"hc_attn"``, ``"hc_mlp"``; ``"hc"`` in a one-sublayer layer) makes
+    ``h_pre``, ``h_post`` and ``H_res`` from x, F and N see ``x_in = h_pre x``
+    ``[B, S, H]`` exactly as they see x without streams, and the layer
+    returns ``H_res x + h_post^T F(N(x_in))``.  With n = 1 none of this is
+    traced.
 
     Where layers share tensors (``LlamaConfig.layers_share``) the layer
     takes and returns ``shared`` beside x: its mixer is handed
@@ -2295,11 +2505,18 @@ class LlamaLayer(nn.Module):
                 ffn = functools.partial(ffn, router_x=x)
 
         def residual(x, sublayer, norm):
+            streams = x
+            if cfg.hc_mult > 1:
+                h_pre, h_post, h_res = HyperConnection(
+                    cfg, name="hc" + norm[len("norm"):])(streams)
+                x = _hc_read(streams, h_pre)
             norm = _stack_norm(cfg, norm)
             out = (sublayer(norm(x)) if cfg.norm_placement == "pre"
                    else norm(sublayer(x)))
             if cfg.residual_multiplier != 1.0:
                 out = out * cfg.residual_multiplier
+            if cfg.hc_mult > 1:
+                return _hc_write(streams, out, h_post, h_res)
             return x + out
 
         # Norm and residual add inside each block's scope: XLA fuses them
@@ -2316,7 +2533,10 @@ class LlamaLayer(nn.Module):
 class LlamaModel(nn.Module):
     """Decoder-only LM; with ``config.total_ut_steps`` T > 1 a looped one.
 
-    T = 1: ``tokens [B, S] -> logits [B, S, V]``.
+    T = 1: ``tokens [B, S] -> logits [B, S, V]``.  With ``config.hc_mult``
+    n > 1 the embedding is copied into n residual streams, the layers carry
+    ``[B, S, n, H]`` (``LlamaLayer``), and the final norm reads the streams'
+    sum.
 
     T > 1 (Ouro / LoopLM; Zhu et al., arXiv:2510.25741).  With E the
     embedding, Stack the ``num_layers`` layers in order, N the final
@@ -2384,8 +2604,14 @@ class LlamaModel(nn.Module):
             return _stack_norm(cfg, "norm_f", parent=mdl)(x)
 
         if cfg.total_ut_steps == 1:
+            if cfg.hc_mult > 1:
+                x = jnp.broadcast_to(x[:, :, None, :],
+                                     (B, S, cfg.hc_mult, cfg.hidden_size))
             x = one_pass(self, x)
             with _scopes.scope(_scopes.HEAD):
+                if cfg.hc_mult > 1:
+                    x = jnp.sum(x, axis=2, dtype=jnp.float32).astype(
+                        cfg.dtype)
                 x = norm_f(self, x)
             return self.head(x)
 

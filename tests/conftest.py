@@ -113,7 +113,7 @@ import pytest  # noqa: E402
 # The tiny sizes at which tests/benchmark runs the jobs of the
 # ``deepseek-v2-lite``, ``keye-vl-2.0-30b-a3b``, ``olmo-hybrid-7b``,
 # ``laguna-s-2.1``, ``qwen3-next-80b-a3b`` and ``nemotron-3-nano-30b-a3b``
-# configurations on the CPU.  They belong beside
+# configurations (and of every one since) on the CPU.  They belong beside
 # ``tests/benchmark/tiny_sizes.py``'s, whose table every test of that
 # directory reads by the job's name; the files there are the accepted
 # benchmark's, which a PR that adds a cell may not edit, so the entry is
@@ -423,6 +423,36 @@ TINY.setdefault("prerouted_moe_lm", {
     "traffic": {"sequence": 128, "batch_per_chip": 2},
 })
 
+TINY.setdefault("hc_moe_lm", {
+    # Hidden 128 (a lane tile, so ``flash_attention_fn``'s model takes the
+    # interpreted Mosaic passes) in 4 streams; a dense layer and ONE routed
+    # layer (the suite is near its time limit; two routed layers run in
+    # ``tests/test_xing4.py`` and four on the chip): 2 heads, keys 64 + 64
+    # wide and values 64 from a latent of 32, queries from a latent of 48;
+    # experts 4 to 7 of 16 held, 3 choices a token, one shared expert; 5
+    # Sinkhorn steps.
+    "config": {"hidden_size": 128, "num_attention_heads": 2,
+               "num_key_value_heads": 2, "qk_nope_head_dim": 64,
+               "qk_rope_head_dim": 64, "v_head_dim": 64, "kv_lora_rank": 32,
+               "q_lora_rank": 48, "hc_sinkhorn_iters": 5,
+               "intermediate_size": 128, "moe_intermediate_size": 32,
+               "vocab_size": 512, "num_hidden_layers": 2,
+               "n_routed_experts": 4, "num_experts_per_tok": 3,
+               "deployment": {"n_routed_experts_published": 16,
+                              "first_held_expert": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up, as ``hybrid_moe_lm``.
+                          "loss_must_fall": False,
+                          # bf16 at these widths; float32 through the same
+                          # code agrees to 1e-4 (tests/test_xing4.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    "traffic": {"sequence": 128, "batch_per_chip": 2},
+})
+
 
 # The files that take over 100 s of the driver's command
 # (``/root/TESTS_LAST_RUN.json``: six workers, ``--dist loadfile``), longest
@@ -450,6 +480,7 @@ LONGEST_FIRST = (
     "tests/test_deepseek_model.py",                     # 160 s
     "tests/test_flash_v5e_compile.py",                  # 157 s
     "tests/test_smallthinker.py",                       # 155 s (PR 63)
+    "tests/test_xing4.py",                              # 150 s (PR 65)
     "tests/test_looped_llama.py",                       # 146 s
     "tests/benchmark/test_benchmark_moe.py",            # 131 s
     "tests/test_nemotron_h.py",                         # 118 s
@@ -552,7 +583,7 @@ _MANIFEST_THEN = {
     # (This one reads the cells at import, from the file as it is: its cut
     # keeps every cell, the newest named here, and ends the metrics at its.)
     "test_benchmark_startup_spans.py::test_the_manifests_ten_entries":
-        ("smallthinker-21b-a3b.train-s16k", "trace_loss_self_ms"),
+        ("xing4.0-29b-a4b.train-s8k", "trace_loss_self_ms"),
     "test_benchmark_qk_norm.py::test_the_manifests_one_new_entry":
         ("phi-4-mini-flash.train-s8k", "diff_attn_ms"),
     "test_benchmark_ssm_moe.py::test_the_manifests_new_entries":

@@ -32,11 +32,11 @@ def _patched(job, owner, name, value):
     """The job's loss while ``owner.name`` is ``value(what it was)``."""
     right = type(job).loss_fn
 
-    def loss_fn(params, batch):
+    def loss_fn(*args):     # (params, batch), or with the state beside them
         original = getattr(owner, name)
         setattr(owner, name, value(original))
         try:
-            return right(job, params, batch)
+            return right(job, *args)
         finally:
             setattr(owner, name, original)
     return loss_fn
@@ -70,12 +70,12 @@ def _float8(job, dtype):
             return dot(lhs, rhs, *args, **kwargs)
         return wrapped
 
-    def loss_fn(params, batch):
+    def loss_fn(*args):
         was = jax.lax.dot_general, llama.grouped_matmul
         jax.lax.dot_general = over(was[0])
         llama.grouped_matmul = lambda rows, w, *rest: was[1](rounded(rows), rounded(w), *rest)
         try:
-            return right(job, params, batch)
+            return right(job, *args)
         finally:
             jax.lax.dot_general, llama.grouped_matmul = was
     return loss_fn
@@ -104,11 +104,12 @@ def versions(job) -> dict:
     }
 
 
-def judge(job, reference, config, mesh, state, sample, name) -> dict:
-    """``compare.against_reference``'s line for version ``name`` of the job, with ``correct``."""
+def judge(job, reference, config, mesh, state, sample, name, table=None) -> dict:
+    """``compare.against_reference``'s line for version ``name`` of the job, with ``correct``; ``table``:
+    another job's ``versions`` (``tools/xing4_wrong_versions.py``)."""
     from benchmark import compare
     started = time.perf_counter()
-    job.loss_fn = versions(job)[name]()
+    job.loss_fn = (table or versions)(job)[name]()
     try:
         found = compare.against_reference(job, reference, config, mesh, state, sample)
     finally:
