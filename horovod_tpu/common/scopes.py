@@ -112,16 +112,25 @@ scope                 what falls under it
                       and the Sinkhorn steps on ``exp`` of the clamped
                       residual logits -- tokens on the lanes, float32 --
                       forward, run again under recomputation and backward.
-                      Inside ``hvd.block.attn`` or ``hvd.block.ffn``, the
+                      Where the streams' Mosaic calls run
+                      (``ops/hyper_connection.py``) the RMS's sum of
+                      squares and the product are one call over rows of X
+                      (F1); their transposes are in the call that writes
+                      X's cotangent, under ``hvd.hc.mix``.  Inside
+                      ``hvd.block.attn`` or ``hvd.block.ffn``, the
                       sublayer's own block
 ``hvd.hc.mix``        the same sublayer's two mixes of the streams
                       (``models/llama.py::_hc_read``, ``_hc_write``): the
                       read ``h_pre X`` ahead of the sublayer's norm and the
                       write ``H_res X + h_post^T y`` behind the sublayer,
                       and their gradients (the streams', the sublayer
-                      output's and the three maps').  Beside ``hvd.hc.map``
-                      in the block's scope; a stack with ``hc_mult`` 1
-                      enters neither
+                      output's and the three maps').  As Mosaic calls:
+                      the read, the write, the write's transpose, the
+                      read's on ``h_pre``, and the one call that writes X's
+                      whole cotangent (the read's, the maps' product's and
+                      the RMS's parts added to the write's).  Beside
+                      ``hvd.hc.map`` in the block's scope; a stack with
+                      ``hc_mult`` 1 enters neither
 ``hvd.moe.route``     the routed layer (``RoutedExperts``) before its
                       products: router, softmax, top-k, the balance loss,
                       the sort by expert and the gather of the held
@@ -372,7 +381,7 @@ __all__ = [
     "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
     "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION", "MOSAIC_SSCAN",
     "MOSAIC_GROUPED_MATMUL", "MOSAIC_GATED_NORM", "MOSAIC_SSD_SCAN",
-    "MOSAIC_GDN_SCAN",
+    "MOSAIC_GDN_SCAN", "MOSAIC_HC_STREAMS",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
 ]
@@ -443,6 +452,7 @@ MOSAIC_GROUPED_MATMUL = MOSAIC + "grouped_matmul"
 MOSAIC_GATED_NORM = MOSAIC + "gated_norm"
 MOSAIC_SSD_SCAN = MOSAIC + "ssd_scan"
 MOSAIC_GDN_SCAN = MOSAIC + "gdn_scan"
+MOSAIC_HC_STREAMS = MOSAIC + "hc_streams"
 INIT = "hvd.init"
 INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
 INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize

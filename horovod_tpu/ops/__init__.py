@@ -10,9 +10,11 @@ triangular systems, solved in one Mosaic call a slab), ``ssd`` (Mamba-2's
 chunked state-space scan: a Mosaic call each way, or ``jax.numpy``),
 ``chunking`` (the
 chunks and slabs the two recurrences share), ``selective_scan`` (Mamba-1's
-recurrence: a Mosaic call each way, or chunks in ``jax.numpy``) and
+recurrence: a Mosaic call each way, or chunks in ``jax.numpy``),
 ``grouped_matmul`` (a routed layer's grouped products: XLA's ``ragged_dot``,
-or JAX's Mosaic grouped matmul at stated tiles).  None of
+or JAX's Mosaic grouped matmul at stated tiles) and ``hyper_connection``
+(hyper-connected residual streams: the maps' product, the read, the write
+and their transposes as Mosaic passes over rows of X).  None of
 them is imported or exported here (``models/llama.py`` imports each beside
 the mixer it serves), so ``import horovod_tpu.ops`` pays for no kernel."""
 

@@ -5,9 +5,10 @@ keys 192 wide and values 128, 8,192 positions: K padded to 256 lanes, whole
 and held twice, passes the compiler's default limit, so the forward call
 states the one it computes), and the cell's train step at one layer of each
 kind -- the dense one and a routed one, four streams through both -- with its
-arguments, its temporaries, the two new scopes around both sublayers of both
-layers, the query latent under ``hvd.mla.latent``, and the maps with tokens on
-the lanes.  That the cell's depth fits the chip is the chip's to say
+arguments, its temporaries, the streams' Mosaic calls
+(``ops/hyper_connection.py``) under the two scopes around both sublayers of
+both layers, the query latent under ``hvd.mla.latent``, the maps with tokens
+on the lanes, and the streams as rows from the embedding to the head.  That the cell's depth fits the chip is the chip's to say
 (``peak_hbm_gb``, every PR); deviceless at the cell's five layers the step
 reads 10.63 GB of arguments (759,346,190 parameters at 14 bytes) and 4.30 GB
 of temporaries under ``layer_keep_attention`` (PR 65; 82 s of compiling where
@@ -26,6 +27,7 @@ import horovod_tpu.jax as hvd
 from benchmark import manifest
 from horovod_tpu.common import scopes
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import hyper_connection as hc
 from horovod_tpu.ops import rope
 
 CELL = "xing4.0-29b-a4b.train-s8k"
@@ -56,6 +58,7 @@ def one_chip(topo, monkeypatch):
 
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(rope, "_interpret", lambda: False)
+    monkeypatch.setattr(hc, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -116,11 +119,10 @@ def test_the_cells_whole_step_carries_four_streams_through_both_kinds(
     1 x 8,192 tokens.  Each layer is two flash calls (the policy keeps the
     forward call's output); both sublayers of both layers make their maps
     under ``hvd.hc.map`` and mix under ``hvd.hc.mix``, inside their block's
-    scope; the query's latent is under ``hvd.mla.latent``; nothing under
-    ``hvd.hc.map`` is a float32 tensor of T rows whose minor axes are the
-    4 x 4 of a token's map (32 MB where the data are 0.5), and the module
-    holds such a tensor once a sublayer alone, where the write's backward
-    reduction leaves H_res's cotangent before it is turned to the lanes."""
+    scope, by the streams' Mosaic calls (``body_counts``: every sublayer,
+    none by the ``jnp`` bodies); the query's latent is under
+    ``hvd.mla.latent``; the streams are rows ``[T, 4 x 3584]`` in bf16
+    wherever they lie."""
     cell = manifest.cell(CELL)
     config = {**cell["config"], "num_hidden_layers": LAYERS}
     assert cell["config"]["num_hidden_layers"] == 5
@@ -139,6 +141,8 @@ def test_the_cells_whole_step_carries_four_streams_through_both_kinds(
     step = hvd.make_train_step(job.loss_fn, job.optimizer, mesh,
                                has_aux=True)
     before = fa.layout_counts()
+    mosaic = hc.body_counts()["mosaic"]
+    plain = sum(hc.body_counts()["plain"].values())
     compiled = step.lower(*described(state), described(batch)).compile()
     after = fa.layout_counts()
     # Keys of 192 lanes are no whole number of lane tiles: the flat layout,
@@ -155,32 +159,47 @@ def test_the_cells_whole_step_carries_four_streams_through_both_kinds(
     assert not any(scopes.REMATTED in c for c in forward)
     assert set(_scoped_vmem(forward, "scoped_memory_configs")) == {
         20_709_376}
+    # A sublayer's calls, in its block's scope: the statistics under the
+    # maps' scope, forward and again under the layer's recomputation (2);
+    # under the mixes' the read (2), its transpose and the one call that
+    # writes x's whole cotangent, all inside the sublayer's module, and
+    # beside the module the write (again only where a sublayer follows it
+    # in the layer: the first) and its transpose.
+    assert sum(hc.body_counts()["plain"].values()) == plain
+    assert hc.body_counts()["mosaic"] == mosaic + 2 * LAYERS
     for layer in ("layer_0", "layer_1"):
-        for block, module in ((scopes.BLOCK_ATTN, "hc_attn"),
-                              (scopes.BLOCK_FFN, "hc_mlp")):
+        for block, module, writes in ((scopes.BLOCK_ATTN, "hc_attn", 2),
+                                      (scopes.BLOCK_FFN, "hc_mlp", 1)):
+            inside = [c for c in calls if f"{layer}/{block}/{module}/" in c]
+            assert len([c for c in inside if scopes.HC_MAP in c]) == 2
+            assert len([c for c in inside if scopes.HC_MIX in c]) == 4
+            assert len([c for c in calls
+                        if f"{layer}/{block}/{scopes.HC_MIX}" in c]
+                       ) == writes + 1, (layer, block)
             assert any(f"{layer}/{block}/{module}/{scopes.HC_MAP}" in line
-                       for line in lines), (layer, module)
-            assert any(f"{layer}/{block}/{scopes.HC_MIX}" in line
-                       for line in lines), (layer, block)
+                       and "exponential" in line for line in lines)
         assert any(scopes.MLA_LATENT in line and "wq_a" in line
                    and layer in line for line in lines)
     assert any(scopes.MOE_ROUTE in line and "layer_1" in line
                for line in lines)
     assert not any(scopes.MOE_ROUTE in line and "layer_0" in line
                    for line in lines)
-    token_major_map = re.compile(rf"f32\[(?:\d+,)*{SEQ},4,4\]")
-    assert not [line for line in lines if scopes.HC_MAP in line
-                and token_major_map.search(line.split(" = ")[-1][:80])]
-    # In the scheduled program itself (the entry computation) such a tensor
-    # is made twice a sublayer, both times by the write's backward pass:
-    # H_res's cotangent as its reduction leaves it, and its copy on the way
-    # to the lanes.
-    made = [line for line in text[text.index("\nENTRY "):].splitlines()
-            if re.match(rf"\s*(ROOT )?%?[\w.\-]+ = f32\[(\d+,)*{SEQ},4,4\]",
-                        line)]
-    assert len(made) == 2 * 2 * LAYERS, len(made)
-    assert all(scopes.HC_MIX in line and "transpose(" in line
-               for line in made)
+    # The streams are rows from the embedding to the head: no float32
+    # tensor of their size, by either shape; no array of theirs in tiles of
+    # four rows (how XLA lays ``[.., 4, 3584]`` out), so no relaying copy on
+    # the way into a call or out of one; and a token's 4 x 4 map is nowhere
+    # the two minor axes of a tensor of T rows (32 MB where the data are
+    # 0.5): the write's transpose leaves the maps' cotangents as lanes of
+    # ``[T, 128]``.
+    # (In the scheduled program itself, the entry computation: what a
+    # fusion holds inside is no array.)
+    entry = text[text.index("\nENTRY "):]
+    assert not re.findall(rf"f32\[(?:\d+,)*{SEQ},(?:4,3584|14336)\]", entry)
+    assert not re.findall(r"bf16\[[\d,]*4,3584\]\{[\d,]*:T\(4,128\)", entry)
+    assert not [line for line in entry.splitlines() if " copy(" in line
+                and re.search(r"bf16\[1024,8,4,3584\]", line)]
+    assert not re.findall(rf"f32\[(?:\d+,)*{SEQ},4,4\]", entry)
+    assert re.findall(rf"f32\[4,4,{SEQ}\]", entry)
     # No score matrix: the only [.., 8192, 8192] is W_kvb's output, 32 heads
     # of 128 + 128 lanes a token.
     assert not re.findall(rf"\w+\[(?:\d+,)*{HEADS},{SEQ},{SEQ}\]", text)
@@ -190,6 +209,6 @@ def test_the_cells_whole_step_carries_four_streams_through_both_kinds(
           f"temporaries {memory.temp_size_in_bytes}")
     assert memory.argument_size_in_bytes == pytest.approx(
         14 * PARAMETERS, rel=1e-3)
-    # 3.34 GB at this depth; at the cell's five layers 4.30 GB beside 10.63
-    # of arguments, 14.93 of a chip's 15.75.
-    assert memory.temp_size_in_bytes <= 3.6e9
+    # 2.71 GB at this depth (3.34 before the streams' calls, PR 66); at the
+    # cell's five layers 3.79 GB (4.30) beside 10.63 of arguments.
+    assert memory.temp_size_in_bytes <= 3.0e9
