@@ -46,7 +46,11 @@ wherever it enters a scope of ``common/scopes.py`` (``scopes.scope``: the
 ``jax.named_scope`` and the span under ONE name, so a trace's seconds and
 a step's device time are told by the same words), around every Mosaic
 call's bind (``mosaic.<kernel>``, no ``named_scope``: what tracing the
-kernels' bodies costs a trace), inside ``hvd.init()`` (``hvd.init``,
+kernels' bodies costs a trace), around every layer's call
+(``layer.<mixer>.<ffn>``) and every differentiation rule of a ``custom_vjp``
+(``rule.<op>.fwd`` / ``.bwd``; spans alone both, whose SELF time is what
+JAX's interpreters did on that piece's behalf; ``hvd.loss`` also carries the
+flag ``forward_seconds``, ``stamp``), inside ``hvd.init()`` (``hvd.init``,
 ``hvd.init.native``, ``hvd.init.distributed``, ``hvd.init.cache``) and,
 from two stamps of the clock, around the package's import (``import
 horovod_tpu.jax`` and its child ``import horovod_tpu.models``).
@@ -75,8 +79,8 @@ import time
 from typing import Optional
 
 __all__ = ["default_cache_dir", "enable_compile_cache",
-           "enable_compile_log", "compile_log", "compile_spans", "span",
-           "add_span", "CompileLog"]
+           "enable_compile_log", "compile_log", "compile_spans",
+           "compile_evicted", "span", "stamp", "add_span", "CompileLog"]
 
 _ENV = "JAX_COMPILATION_CACHE_DIR"
 
@@ -144,6 +148,10 @@ class CompileLog:
     log's origin (the first line of the package's import), so both order
     on one axis.  The log keeps the ``MAX_SPANS`` spans that ended last; a
     span that is still open is in no list.
+
+    What either limit pushed out is COUNTED (``evicted()``): a sum over a
+    log that lost its oldest part is no sum of the start, and a reader that
+    finds a count above 0 says so and gives no number.
     """
 
     MAX_RECORDS = 16384
@@ -163,6 +171,7 @@ class CompileLog:
         self._lock = threading.Lock()
         self._records: list = []       # of _Record, by time of arrival
         self._spans = collections.deque(maxlen=self.MAX_SPANS)  # by end
+        self._evicted = {"records": 0, "spans": 0}
         self._open = threading.local()  # .stack: this thread's open spans
         self._origin = time.perf_counter() if origin is None else origin
         self._listening = False
@@ -199,7 +208,10 @@ class CompileLog:
                         break
                     r.program = program
             records.append(new)
-            del records[:-self.MAX_RECORDS]
+            over = len(records) - self.MAX_RECORDS
+            if over > 0:
+                self._evicted["records"] += over
+                del records[:over]
 
     def _on_event(self, event: str, **_) -> None:
         if event in self.COUNTS:
@@ -221,10 +233,32 @@ class CompileLog:
                     for r in self._records
                     if program is None or r.program in names]
 
+    def evicted(self) -> dict:
+        """``{"records", "spans"}``: how many of each the log has dropped
+        to stay within ``MAX_RECORDS`` and ``MAX_SPANS``, the oldest
+        first."""
+        with self._lock:
+            return dict(self._evicted)
+
+    def _keep(self, span: "_Span") -> None:
+        with self._lock:
+            self._evicted["spans"] += len(self._spans) == self._spans.maxlen
+            self._spans.append(span)
+
     def span(self, name: str, **flags) -> "_Span":
         """A context manager that times what runs inside it as the span
         ``name``, a child of the span open on this thread."""
         return _Span(self, name, flags)
+
+    def stamp(self, flag: str) -> None:
+        """Keep, as the flag ``flag`` of the innermost span open on this
+        thread, the seconds it has run so far (``hvd.loss``'s
+        ``forward_seconds``: where its forward half ended).  No span open:
+        nothing is kept."""
+        now = time.perf_counter()
+        stack = getattr(self._open, "stack", None)
+        if stack:
+            stack[-1].flags[flag] = now - stack[-1].began
 
     def add_span(self, name: str, began: float, ended: float,
                  parent: Optional["_Span"] = None, **flags) -> "_Span":
@@ -237,8 +271,7 @@ class CompileLog:
         if parent is not None:
             span.path = parent.path + "/" + name
             parent.covered += ended - began
-        with self._lock:
-            self._spans.append(span)
+        self._keep(span)
         return span
 
     def spans(self, program: Optional[str] = None) -> list:
@@ -319,8 +352,7 @@ class _Span:
         if self.parent is not None:
             self.parent.covered += self.ended - self.began
             self.parent = None
-        with self.log._lock:
-            self.log._spans.append(self)
+        self.log._keep(self)
 
 
 # The origin of the process's log is the first line of the package's
@@ -350,10 +382,24 @@ def span(name: str, **flags):
     return _LOG.span(name, **flags)
 
 
+def stamp(flag: str) -> None:
+    """The seconds the innermost open span of the process's log has run so
+    far, kept as its flag ``flag``: see :meth:`CompileLog.stamp`."""
+    _LOG.stamp(flag)
+
+
 def add_span(name: str, began: float, ended: float, parent=None, **flags):
     """A finished span, from two ``time.perf_counter`` readings, into the
     process's log: see :meth:`CompileLog.add_span`."""
     return _LOG.add_span(name, began, ended, parent, **flags)
+
+
+def compile_evicted() -> dict:
+    """``{"records", "spans"}`` the process's log has dropped to stay within
+    its limits (:meth:`CompileLog.evicted`): 0 and 0, or the oldest part of
+    ``hvd.compile_log()`` / ``hvd.compile_spans()`` is gone and a sum over
+    them is no sum of the start."""
+    return _LOG.evicted()
 
 
 def compile_spans(program: Optional[str] = None) -> list:

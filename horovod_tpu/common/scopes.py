@@ -26,16 +26,68 @@ once a call site): no ``named_scope``, nothing of them reaches the HLO.
 ``INIT*`` and ``IMPORT*`` name the start-up's spans (``hvd.init()`` and its
 parts; the package's import from two stamps of the clock).
 
+Two more families are spans ALONE, and name what JAX does ON BEHALF of a
+piece of the program while it traces a step (the seconds its own
+interpreters run between the program's scopes, which no scope can hold):
+
+==========================  ================================================
+span                        what falls under it
+==========================  ================================================
+``layer.<mixer>.<ffn>``     ONE call of a layer by ``LlamaModel`` (plain,
+(``LAYER``, by               under ``nn.remat``, inside a looped model's
+``layer_span``)              pass), named from its ``LayerSpec``
+                            (``models/llama.py``): the mixer as ``MIXERS``
+                            spells it, ``+window`` behind it where the
+                            layer has a window, the feed-forward ``dense``
+                            or ``routed``, ``none`` for the one a
+                            one-sublayer layer lacks (``layer.attention.
+                            dense``, ``layer.attention+window.routed``,
+                            ``layer.linear_attention.routed``,
+                            ``layer.mamba2.none``, ``layer.none.routed``);
+                            ``layer.resnet.stage<n>`` a stage of
+                            ``models/resnet.py``.  The block scopes nest
+                            inside; its SELF time is what JAX did for that
+                            layer outside the program's Python:
+                            ``jax.checkpoint`` tracing and staging it, the
+                            JVP and the partial evaluation of its jaxpr
+``rule.<op>.fwd``,          a ``custom_vjp``'s forward and backward rule
+``rule.<op>.bwd``           whenever JAX calls it (``rules``, handed to
+(``RULE``, by ``rules``)     every ``defvjp`` of the package; ``RULES`` is
+                            the registry).  ``<op>`` is the function's name
+                            (``_flash``, ``rotate_pairs``, ``_live_buffers``,
+                            ..; ``grouped_matmul._mosaic`` and
+                            ``selective_scan._mosaic`` by their modules).
+                            The scopes a rule enters nest inside; its SELF
+                            time is the rule's own Python and what JAX ran
+                            because the rule asked (``jax.vjp`` of a routed
+                            layer's buffer and its pullback's call inside
+                            ``rule._live_buffers.bwd``).  A forward rule
+                            runs inside its layer's span (the JVP of what
+                            ``jax.checkpoint`` staged); a backward rule at
+                            the top of ``hvd.loss``, or inside the backward
+                            rule that called JAX back for it
+==========================  ================================================
+
+``hvd.loss`` carries one FLAG, ``forward_seconds`` (``FORWARD_SECONDS``, by
+``stamp``): the seconds from its start to the moment ``make_train_step`` had
+the loss's ``jax.vjp`` and was about to call the pullback.  What began
+before it is the forward half (the model's Python, staging, linearisation);
+what began behind it the backward half (transposition, the backward rules).
+No span brackets a half, so ``hvd.loss``'s self time keeps its meaning:
+under ``hvd.loss`` and under no other span.
+
 An operation's ``op_name`` is a path, ``jit(hvd_train_step)/.../hvd.loss/
 .../mul``.  JAX wraps the components that differentiation goes through:
 what the forward pass runs sits under ``jvp(...)``, what the backward pass
-runs under ``transpose(jvp(...))``.  So one scope around
-``jax.value_and_grad`` splits forward from backward.
+runs under ``transpose(jvp(...))``.  So one scope around the loss and its
+gradient splits forward from backward.
 
 ====================  ====================================================
 scope                 what falls under it
 ====================  ====================================================
-``hvd.loss``          ``jax.value_and_grad(loss_fn)`` in ``make_train_step``
+``hvd.loss``          ``loss_fn`` and its gradient in ``make_train_step``:
+                      ``jax.vjp`` and the pullback on one, the pair that
+                      ``jax.value_and_grad`` is (flag ``forward_seconds``)
 ``hvd.fusion.pack``   nothing: no code of the program enters these two
 ``hvd.fusion.unpack`` since PR 30 (see below)
 ``hvd.allreduce.<a>`` every traced all-reduce over mesh axes ``<a>``
@@ -361,6 +413,7 @@ walks again.
 from __future__ import annotations
 
 import contextlib
+import functools
 
 __all__ = [
     "LOSS", "FUSION_PACK", "FUSION_UNPACK", "ALLREDUCE", "AUX_ALLREDUCE",
@@ -384,6 +437,7 @@ __all__ = [
     "MOSAIC_GDN_SCAN", "MOSAIC_HC_STREAMS",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
+    "LAYER", "RULE", "RULES", "FORWARD_SECONDS", "layer_span", "rules", "stamp",
 ]
 
 LOSS = "hvd.loss"
@@ -453,6 +507,9 @@ MOSAIC_GATED_NORM = MOSAIC + "gated_norm"
 MOSAIC_SSD_SCAN = MOSAIC + "ssd_scan"
 MOSAIC_GDN_SCAN = MOSAIC + "gdn_scan"
 MOSAIC_HC_STREAMS = MOSAIC + "hc_streams"
+LAYER = "layer."                     # a prefix: layer_span() completes it
+RULE = "rule."                       # a prefix: rules() completes it
+FORWARD_SECONDS = "forward_seconds"  # a flag of the LOSS span, no span
 INIT = "hvd.init"
 INIT_NATIVE = "hvd.init.native"      # the C++ engine: found, loaded, started
 INIT_DISTRIBUTED = "hvd.init.distributed"   # jax.distributed.initialize
@@ -476,10 +533,56 @@ def allreduce_scope(axis_name) -> str:
 
 def span(name: str):
     """The compile log's span ``name`` ALONE, for the names that must not
-    reach the HLO (``MOSAIC_*``, ``INIT*``)."""
+    reach the HLO (``MOSAIC_*``, ``INIT*``, ``LAYER``'s and ``RULE``'s)."""
     from horovod_tpu.common import compile_cache
 
     return compile_cache.span(name)
+
+
+def stamp(flag: str) -> None:
+    """Keep the seconds the innermost open span has run so far as its flag
+    ``flag`` (``FORWARD_SECONDS`` of ``LOSS``)."""
+    from horovod_tpu.common import compile_cache
+
+    compile_cache.stamp(flag)
+
+
+def layer_span(mixer, ffn=None, window=None):
+    """The span around ONE call of a layer: ``layer.<mixer>.<ffn>``, the
+    two as the layer's ``LayerSpec`` names them (``models/llama.py``: a key
+    of ``MIXERS``, ``DENSE`` or ``ROUTED``), ``+window`` behind the mixer
+    of a layer that has a window and ``none`` for a sublayer it lacks;
+    ``layer.resnet.stage2`` for a stage of ``models/resnet.py``.  A span
+    alone."""
+    kind = f"{mixer or 'none'}{'+window' if window else ''}"
+    return span(f"{LAYER}{kind}.{ffn or 'none'}")
+
+
+#: ``{op: (fwd, bwd)}`` of every pair of rules that ``rules`` wrapped: one
+#: entry a ``custom_vjp`` of the package (``tests/test_rule_spans.py`` counts
+#: the sources' ``defvjp`` calls against it).
+RULES: dict = {}
+
+
+def rules(op: str, fwd, bwd) -> tuple:
+    """A ``custom_vjp``'s two differentiation rules, each inside a span of
+    its own whenever JAX calls it: ``f.defvjp`` of ``*rules("f", f_fwd,
+    f_bwd)`` gives ``rule.f.fwd`` and ``rule.f.bwd``.  Spans alone; what else of the
+    program calls ``f_fwd`` or ``f_bwd`` by name enters neither, and
+    ``functools.wraps`` keeps each rule's name and signature for JAX."""
+    if op in RULES:
+        raise ValueError(f"two custom_vjp's rules are both named {op!r}")
+
+    def timed(fn, name):
+        @functools.wraps(fn)
+        def rule(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+
+        return rule
+
+    RULES[op] = (timed(fwd, f"{RULE}{op}.fwd"), timed(bwd, f"{RULE}{op}.bwd"))
+    return RULES[op]
 
 
 @contextlib.contextmanager

@@ -57,6 +57,7 @@ from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.common import trace_counts as _trace_counts
 from horovod_tpu.common.compile_cache import (
     add_span as _add_span,
+    compile_evicted,
     compile_log,
     compile_spans,
     enable_compile_cache,
@@ -91,7 +92,7 @@ __all__ = [
     "DistributedOptimizer", "allreduce_gradients", "update_counts",
     "broadcast_parameters", "broadcast_optimizer_state",
     "build_mesh", "data_parallel_mesh", "default_mesh", "use_mesh",
-    "make_train_step", "compile_log", "compile_spans",
+    "make_train_step", "compile_log", "compile_spans", "compile_evicted",
     "TRAIN_STEP_PROGRAM",
 ]
 
@@ -904,9 +905,24 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
 
     import optax
 
+    def _loss_and_grads(params, *rest):
+        """``jax.value_and_grad(loss_fn, has_aux=has_aux)(params, *rest)``
+        as the pair it is, the forward pass and its pullback on one, with
+        the open span (``hvd.loss``) told when the first was traced: its
+        flag ``forward_seconds`` (``common/scopes.py``)."""
+        loss, pullback, *aux = jax.vjp(
+            lambda p: loss_fn(p, *rest), params, has_aux=has_aux)
+        _scopes.stamp(_scopes.FORWARD_SECONDS)
+        if jnp.shape(loss) or not jnp.issubdtype(loss.dtype, jnp.floating):
+            raise TypeError(
+                f"loss_fn must return a real scalar loss, and gave "
+                f"{jax.typeof(loss).str_short()}")
+        (grads,) = pullback(jnp.ones_like(loss))
+        return (loss, *aux), grads
+
     def _sharded_step(params, opt_state, batch):
         with _scopes.scope(_scopes.LOSS):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            (loss,), grads = _loss_and_grads(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         with _scopes.scope(_scopes.APPLY):
             params = optax.apply_updates(params, updates)
@@ -915,8 +931,8 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Optional[Mesh] = None,
 
     def _sharded_step_aux(params, opt_state, aux_state, batch):
         with _scopes.scope(_scopes.LOSS):
-            (loss, aux_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, aux_state, batch)
+            (loss, aux_state), grads = _loss_and_grads(
+                params, aux_state, batch)
         with _scopes.scope(_scopes.AUX_ALLREDUCE):
             aux_state = jax.tree.map(
                 lambda x: _cops.allreduce(x, axis_name=axes, op=Average)

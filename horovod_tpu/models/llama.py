@@ -1558,7 +1558,8 @@ def _rows_of_tokens_bwd(k, res, g):
             _float0(assignments), _float0(position))
 
 
-_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+_rows_of_tokens.defvjp(*_scopes.rules(
+    "_rows_of_tokens", _rows_of_tokens_fwd, _rows_of_tokens_bwd))
 
 
 def _rows_to_tokens(rows, position, k, weights=None):
@@ -1610,8 +1611,9 @@ def _weighted_rows_to_tokens_bwd(k, res, g):
     return d_rows, d_weights, _float0(assignments), _float0(position)
 
 
-_weighted_rows_to_tokens.defvjp(_weighted_rows_to_tokens_fwd,
-                                _weighted_rows_to_tokens_bwd)
+_weighted_rows_to_tokens.defvjp(*_scopes.rules(
+    "_weighted_rows_to_tokens", _weighted_rows_to_tokens_fwd,
+    _weighted_rows_to_tokens_bwd))
 
 
 def _relu2(x):
@@ -1730,7 +1732,8 @@ def _live_buffers_bwd(chunk, k, act, in_place, inputs, g):
             *map(_float0, indices))
 
 
-_live_buffers.defvjp(_live_buffers_fwd, _live_buffers_bwd)
+_live_buffers.defvjp(*_scopes.rules(
+    "_live_buffers", _live_buffers_fwd, _live_buffers_bwd))
 
 
 class RoutedExperts(nn.Module):
@@ -2639,10 +2642,14 @@ class LlamaModel(nn.Module):
             for i in range(cfg.num_layers):
                 layer = layer_cls(cfg, attention_fn=self.attention_fn,
                                   index=i, name=f"layer_{i}", parent=mdl)
-                if shared is None:
-                    x = layer(x, *tables[cfg.rope_of(i)])
-                else:
-                    x, shared = layer(x, *tables[cfg.rope_of(i)], shared)
+                # A span alone, by the layer's kind: its self time is what
+                # JAX did for the layer outside the program's Python.
+                spec = cfg.layers[i]
+                with _scopes.layer_span(spec.mixer, spec.ffn, spec.window):
+                    if shared is None:
+                        x = layer(x, *tables[cfg.rope_of(i)])
+                    else:
+                        x, shared = layer(x, *tables[cfg.rope_of(i)], shared)
             return x
 
         def norm_f(mdl, x):

@@ -28,6 +28,8 @@ from typing import Any, Callable, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from horovod_tpu.common import scopes as _scopes
+
 __all__ = ["ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101"]
 
 
@@ -108,10 +110,14 @@ class ResNet(nn.Module):
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for stage, n_blocks in enumerate(self.stage_sizes):
-            for block in range(n_blocks):
-                strides = 2 if stage > 0 and block == 0 else 1
-                x = self.block_cls(self.width * 2 ** stage, strides=strides,
-                                   dtype=self.dtype)(x, train=train)
+            # A span alone a stage (``layer.resnet.stage0``, ...): what
+            # tracing and differentiating the stage's blocks takes.
+            with _scopes.layer_span("resnet", f"stage{stage}"):
+                for block in range(n_blocks):
+                    strides = 2 if stage > 0 and block == 0 else 1
+                    x = self.block_cls(
+                        self.width * 2 ** stage, strides=strides,
+                        dtype=self.dtype)(x, train=train)
         x = jnp.mean(x, axis=(1, 2))  # global average pool
         x = nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(x)
         return x

@@ -873,7 +873,8 @@ def _flash_bwd(causal, sm_scale, heads, window, res, cts):
             no_gradient(mask))
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd, symbolic_zeros=True)
+_flash.defvjp(*_scopes.rules(
+    "_flash", _flash_fwd, _flash_bwd), symbolic_zeros=True)
 
 
 def _flat_layout(q, k, v):

@@ -357,7 +357,8 @@ def _tril_inverse_bwd(mosaic, t, dt):
         return (jnp.tril(da, -1),)
 
 
-_tril_inverse.defvjp(_tril_inverse_fwd, _tril_inverse_bwd)
+_tril_inverse.defvjp(*_scopes.rules(
+    "_tril_inverse", _tril_inverse_fwd, _tril_inverse_bwd))
 
 
 def _dot(spec, x, y):
@@ -493,7 +494,7 @@ def _rule_bwd(mosaic, res, d_o):
     return tuple(x.reshape(-1, *x.shape[2:]) for x in grads)
 
 
-_rule.defvjp(_rule_fwd, _rule_bwd)
+_rule.defvjp(*_scopes.rules("_rule", _rule_fwd, _rule_bwd))
 
 
 # -- the walk as one Mosaic call each way -------------------------------------
@@ -928,7 +929,7 @@ def _walk_rows_bwd(kept, d_o):
     return _backward(*kept, d_o, interpret=_interpret())
 
 
-walk_rows.defvjp(_walk_rows_fwd, _walk_rows_bwd)
+walk_rows.defvjp(*_scopes.rules("walk_rows", _walk_rows_fwd, _walk_rows_bwd))
 
 
 def _rule_rows(q, k, v, g, beta):
@@ -1070,7 +1071,8 @@ def _heads_copied_bwd(heads, times, _, d_rows):
                         interpret=_interpret()),)
 
 
-_heads_copied.defvjp(_heads_copied_fwd, _heads_copied_bwd)
+_heads_copied.defvjp(*_scopes.rules(
+    "_heads_copied", _heads_copied_fwd, _heads_copied_bwd))
 
 
 def key_heads_copied(x, times: int, rows: bool):

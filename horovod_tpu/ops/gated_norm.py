@@ -721,7 +721,8 @@ def _skip_gate_norm_bwd(groups, eps, kept, go):
                      interpret=_interpret())
 
 
-skip_gate_norm.defvjp(_skip_gate_norm_fwd, _skip_gate_norm_bwd)
+skip_gate_norm.defvjp(*_scopes.rules(
+    "skip_gate_norm", _skip_gate_norm_fwd, _skip_gate_norm_bwd))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -747,7 +748,7 @@ def _norm_gate_bwd(heads, eps, chunk, kept, go):
                                interpret=_interpret())
 
 
-norm_gate.defvjp(_norm_gate_fwd, _norm_gate_bwd)
+norm_gate.defvjp(*_scopes.rules("norm_gate", _norm_gate_fwd, _norm_gate_bwd))
 
 
 # -- the plain body, and the one entry ----------------------------------------

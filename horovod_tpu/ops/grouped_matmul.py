@@ -147,7 +147,8 @@ def _mosaic_bwd(res, g):
     return d_rows, d_w, np.zeros(sizes.shape, jax.dtypes.float0)
 
 
-_mosaic.defvjp(_mosaic_fwd, _mosaic_bwd)
+_mosaic.defvjp(*_scopes.rules(
+    "grouped_matmul._mosaic", _mosaic_fwd, _mosaic_bwd))
 
 
 def grouped_matmul(rows, w, sizes, in_place: bool = False):

@@ -531,7 +531,7 @@ def _streams_bwd(maps, kept, cotangents):
             dgain, dbias)
 
 
-streams.defvjp(_streams_fwd, _streams_bwd)
+streams.defvjp(*_scopes.rules("streams", _streams_fwd, _streams_bwd))
 
 
 @jax.custom_vjp
@@ -558,4 +558,4 @@ def _write_rule(kept, g):
     return dx.reshape(x.shape), dy.reshape(y.shape), dh_post, dh_res
 
 
-write.defvjp(_write_fwd, _write_rule)
+write.defvjp(*_scopes.rules("write", _write_fwd, _write_rule))

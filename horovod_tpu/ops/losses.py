@@ -96,7 +96,7 @@ def _nll_bwd(res, g):
     return d.astype(logits.dtype), None
 
 
-_nll.defvjp(_nll_fwd, _nll_bwd)
+_nll.defvjp(*_scopes.rules("_nll", _nll_fwd, _nll_bwd))
 
 
 def softmax_cross_entropy(logits, targets, *, where=None,
@@ -194,7 +194,8 @@ def _weighted_exit_nll_bwd(head, res, g):
     return jax.tree.map(scaled, head_grads), scaled(dh), g * nll, None
 
 
-_weighted_exit_nll.defvjp(_weighted_exit_nll_fwd, _weighted_exit_nll_bwd)
+_weighted_exit_nll.defvjp(*_scopes.rules(
+    "_weighted_exit_nll", _weighted_exit_nll_fwd, _weighted_exit_nll_bwd))
 
 
 def expected_exit_loss(head, hidden, gate_logits, targets, *,

@@ -193,7 +193,7 @@ def _rotate_bwd(tables, g):
             jnp.zeros_like(cos), jnp.zeros_like(sin))
 
 
-rotate_pairs.defvjp(_rotate_fwd, _rotate_bwd)
+rotate_pairs.defvjp(*_scopes.rules("rotate_pairs", _rotate_fwd, _rotate_bwd))
 
 
 # -- the norm in the pass ------------------------------------------------------
@@ -349,7 +349,8 @@ def _norm_rotate_pairs_bwd(eps, kept, g):
     return dx, ds, jnp.zeros_like(cos), jnp.zeros_like(sin)
 
 
-norm_rotate_pairs.defvjp(_norm_rotate_pairs_fwd, _norm_rotate_pairs_bwd)
+norm_rotate_pairs.defvjp(*_scopes.rules(
+    "norm_rotate_pairs", _norm_rotate_pairs_fwd, _norm_rotate_pairs_bwd))
 
 
 def _norm_plain(x, scale, eps):

@@ -401,7 +401,8 @@ def _mosaic_bwd(interpret, kept, g):
             dc.astype(c.dtype), dd.astype(d.dtype))
 
 
-_mosaic.defvjp(_mosaic_fwd, _mosaic_bwd)
+_mosaic.defvjp(*_scopes.rules(
+    "selective_scan._mosaic", _mosaic_fwd, _mosaic_bwd))
 
 
 # -- the one entry ------------------------------------------------------------

@@ -686,7 +686,8 @@ def _short_conv_bwd(heads, scale, first, gated, kept, g):
                      interpret=_interpret(), gated=gated)
 
 
-short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+short_conv.defvjp(*_scopes.rules(
+    "short_conv", _short_conv_fwd, _short_conv_bwd))
 
 
 # -- the plain body, and the one entry --------------------------------------

@@ -486,7 +486,8 @@ def _index_loss_bwd(sm_scale, scale, grads, g):
     return (None, None, None, *map(scaled, grads), None, None)
 
 
-_index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
+_index_loss.defvjp(*_scopes.rules(
+    "_index_loss", _index_loss_fwd, _index_loss_bwd))
 
 
 def index_loss(q, k, lse, q_i, k_i, w, selected, lse_i, *, sm_scale: float,

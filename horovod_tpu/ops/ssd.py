@@ -631,7 +631,7 @@ def _scan_rows_bwd(heads, groups, chunk, kept, dy):
                      groups=groups, chunk=chunk, interpret=_interpret())
 
 
-scan_rows.defvjp(_scan_rows_fwd, _scan_rows_bwd)
+scan_rows.defvjp(*_scopes.rules("scan_rows", _scan_rows_fwd, _scan_rows_bwd))
 
 
 # -- the plain body -----------------------------------------------------------

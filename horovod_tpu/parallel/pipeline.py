@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.common import scopes as _scopes
 from horovod_tpu.ops.losses import softmax_cross_entropy
 
 __all__ = [
@@ -74,8 +75,9 @@ def _broadcast_from_last_bwd(axis_name, mask, g):
     return (g * mask, jnp.zeros_like(mask))
 
 
-_broadcast_from_last.defvjp(_broadcast_from_last_fwd,
-                            _broadcast_from_last_bwd)
+_broadcast_from_last.defvjp(*_scopes.rules(
+    "_broadcast_from_last", _broadcast_from_last_fwd,
+    _broadcast_from_last_bwd))
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, *,

@@ -588,6 +588,10 @@ _MANIFEST_THEN = {
         ("phi-4-mini-flash.train-s8k", "diff_attn_ms"),
     "test_benchmark_ssm_moe.py::test_the_manifests_new_entries":
         ("lfm2-24b-a2b.train-s8k-b2", "lconv_conv_roofline"),
+    # (It holds every list that names its cell to be its own PR's: PR 67's
+    # nine start-up metrics list all sixteen cells.)
+    "test_benchmark_hc.py::test_the_manifests_entries_are_the_issues":
+        ("xing4.0-29b-a4b.train-s8k", "hc_mix_roofline"),
 }
 
 
@@ -601,7 +605,8 @@ def _manifest_as_its_test_knew_it(request, monkeypatch):
     ``test_benchmark_startup_spans.py``'s, PR 52, its ten;
     ``test_benchmark_qk_norm.py``'s, PR 48, the cells that norm q and k a
     head; ``test_benchmark_ssm_moe.py``'s, PR 50, the one cell its four
-    ``ssd_*`` metrics listed)
+    ``ssd_*`` metrics listed; ``test_benchmark_hc.py``'s, PR 65, every
+    list that names its cell)
     as the LAST of every list of
     ``BENCHMARK.json`` and count the cells, and a later PR may neither
     edit those files nor put its entries anywhere but last.  So each of

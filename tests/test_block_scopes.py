@@ -173,8 +173,9 @@ def test_the_rotation_nests_in_the_attention_block_and_in_no_flash_scope(
 def test_the_table_its_constants_and_all_agree():
     constants = {name: value for name, value in vars(scopes).items()
                  if name.isupper() and isinstance(value, str)}
-    assert set(constants) | {"allreduce_scope", "scope", "span"} == set(
-        scopes.__all__)
+    assert set(constants) | {
+        "allreduce_scope", "scope", "span", "stamp", "layer_span", "rules",
+        "RULES"} == set(scopes.__all__)
     assert len(set(constants.values())) == len(constants)
     # The docstring's table: a row starts with the name in double
     # backquotes; ``hvd.allreduce.<a>`` is the prefix's row.

@@ -492,10 +492,14 @@ def test_every_name_the_tiny_steps_enter_is_a_span():
     assert set(TABLE) - {scopes.ALLREDUCE} <= names
     assert scopes.allreduce_scope("data") in names
     paths = _by_path(spans)
-    for scope, bind in ((scopes.ROPE, scopes.MOSAIC_ROPE),
-                        (scopes.FLASH_FWD, scopes.MOSAIC_FLASH_FWD),
-                        (scopes.FLASH_BWD, scopes.MOSAIC_FLASH_BWD)):
-        assert paths[f"{scopes.LOSS}/{scope}/{bind}"], sorted(paths)
+    # Each inside the span of the differentiation rule that entered it
+    # (``rule.<op>.<pass>``, PR 67: ``tests/test_rule_spans.py``).
+    for rule, scope, bind in (
+            ("rotate_pairs.fwd", scopes.ROPE, scopes.MOSAIC_ROPE),
+            ("_flash.fwd", scopes.FLASH_FWD, scopes.MOSAIC_FLASH_FWD),
+            ("_flash.bwd", scopes.FLASH_BWD, scopes.MOSAIC_FLASH_BWD)):
+        assert paths[f"{scopes.LOSS}/{scopes.RULE}{rule}/{scope}/{bind}"], (
+            sorted(paths))
         assert bind.startswith(scopes.MOSAIC)
     assert paths[f"{scopes.AUX_ALLREDUCE}/{scopes.allreduce_scope('data')}"]
     assert {s["name"] for s in spans if s["name"].startswith(
