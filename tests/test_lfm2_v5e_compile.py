@@ -4,9 +4,10 @@
 convolution's Mosaic pair over ``bf16[2, 8192, 3 x 2048]``; a
 ``GatedShortConv`` layer whole, forward and backward, whose Mosaic calls are
 that pair under its scope; the flash calls at 32 query heads over 8
-key-value heads of 64; and a routed layer's grouped products, which at this
-cell's widths stay XLA's.  The whole step at 2 x 8192 is compiled by the
-builder's study and on the chip, not here (it takes most of a minute)."""
+key-value heads of 64; and a routed layer's grouped products, Mosaic calls
+at this cell's widths too since PR 68.  The whole step at 2 x 8192 is
+compiled by the builder's study and on the chip, not here (it takes most of
+a minute)."""
 
 import os
 import re
@@ -144,14 +145,18 @@ def test_the_flash_calls_at_32_heads_over_8_of_64(one_chip, config):
     assert not re.findall(rf"\w+\[(?:\d+,)*{S},{S}\]", text)
 
 
-def test_a_routed_layers_grouped_products_stay_xlas(one_chip, config):
+def test_a_routed_layers_grouped_products_are_mosaic_calls(one_chip, config):
     """16 of 64 SwiGLU experts at 2 x 8192 tokens, 4 choices a token, IN
-    PLACE: hidden 2048 and the experts' 1536 are whole lane tiles (16 and
-    12), so ``ops/grouped_matmul.py`` leaves every grouped product to
-    ``ragged_dot`` on the parameters as they are (``w_gate_up [16, 2048,
-    3072]``, ``w_down [16, 1536, 2048]``) and the layer holds no Mosaic
-    call: the text the cell's step had before that module came.  LOWERED,
-    not compiled (XLA's grouped kernels take most of a minute)."""
+    PLACE: 65,536 assignments through buffers of 32,768 rows (the first
+    buffer and the loop's body), forward.  Hidden 2048 and the experts' 1536
+    are whole lane tiles (16 and 12), which kept every grouped product with
+    ``ragged_dot`` until PR 68; alone at this call the Mosaic grouped matmul
+    takes 0.69 of its time (PERF.md §5), so the four are Mosaic calls
+    (``ops/grouped_matmul.py``: ``gmm`` in blocks of 256 rows with the
+    contracted width whole) on the parameters as they are (``w_gate_up [16,
+    2048, 3072]``, ``w_down [16, 1536, 2048]``): no padded copy, no
+    ``ragged_dot``, and the calls carry the scope ``moe_experts_ms``
+    reads."""
     module = llama.RoutedExperts(config, in_place=True)
     variables = jax.eval_shape(
         lambda k: module.init(k, jnp.zeros((1, 8, 2048), jnp.bfloat16)),
@@ -159,17 +164,24 @@ def test_a_routed_layers_grouped_products_stay_xlas(one_chip, config):
     x = jax.ShapeDtypeStruct((B, S, 2048), jnp.bfloat16, sharding=one_chip)
     before = grouped_matmul.body_counts()
     llama._one_buffer.clear_cache()     # it keeps its traces by shape
-    text = jax.jit(module.apply).lower(jax.tree.map(
+    lowered = jax.jit(module.apply).lower(jax.tree.map(
         lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip),
-        variables), x).as_text()
+        variables), x)
+    text = lowered.as_text()
     after = grouped_matmul.body_counts()
-    assert after["mosaic"] == before["mosaic"]
-    assert after["xla"][grouped_matmul.WHOLE_TILES] > before["xla"].get(
-        grouped_matmul.WHOLE_TILES, 0)
-    products = [line for line in text.splitlines() if "ragged_dot" in line]
-    assert products and "tpu_custom_call" not in text
+    assert after["mosaic"] == before["mosaic"] + 4
+    assert after["xla"] == before["xla"]
+    assert "ragged_dot" not in text
+    products = [line for line in text.splitlines()
+                if "tpu_custom_call" in line]
+    assert len(products) == 4
     for line in products:
         assert ("16x2048x3072xbf16" in line) != ("16x1536x2048xbf16" in line)
+        assert "32768x2048xbf16" in line
+        assert ("32768x3072xbf16" in line) != ("32768x1536xbf16" in line)
     assert "stablehlo.pad" not in "".join(
         line for line in text.splitlines() if "16x2048x" in line
         or "16x1536x" in line)
+    calls = _mosaic_calls(lowered.compile().as_text())
+    assert len(calls) == 4
+    assert all(scopes.MOE_EXPERTS in call for call in calls)

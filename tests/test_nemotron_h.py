@@ -265,7 +265,7 @@ def interpreted(monkeypatch):
         why = rule(*shape_and_place)
         return None if why == grouped_matmul.NO_TPU else why
 
-    assert rule(512, 128, 72, True) == grouped_matmul.NO_TPU
+    assert rule(512, True) == grouped_matmul.NO_TPU
     monkeypatch.setattr(grouped_matmul, "_why_not", lifted)
     llama._one_buffer.clear_cache()     # it keeps its traces by shape
     yield
@@ -336,20 +336,22 @@ def test_experts_off_the_lane_tile_are_the_dense_loop(act, buffers,
 
 @pytest.mark.parametrize("width, in_place, way", [
     (72, True, "mosaic"), (200, True, "mosaic"),
-    (128, True, grouped_matmul.WHOLE_TILES),
-    (256, True, grouped_matmul.WHOLE_TILES),
-    (72, False, grouped_matmul.NOT_IN_PLACE)],
-    ids=["72", "200", "128", "256", "72, not in place"])
+    (128, True, "mosaic"), (256, True, "mosaic"),
+    (72, False, grouped_matmul.NOT_IN_PLACE),
+    (128, False, grouped_matmul.NOT_IN_PLACE)],
+    ids=["72", "200", "128", "256", "72, not in place",
+         "128, not in place"])
 @pytest.mark.parametrize("act", ["relu2", "silu"])
 def test_the_widths_alone_say_which_grouped_product_runs(
         act, width, in_place, way, interpreted):
-    """``width % LANES`` (hidden 128) of a trace that may hold Mosaic calls: at whole
-    tiles, and in a trace that may not, every grouped product is
-    ``ragged_dot`` on the parameters as they are and no Mosaic call is
-    traced; off them every one is a Mosaic call and none is
-    ``ragged_dot``.  Nothing is padded either way.
-    ``grouped_matmul.body_counts()`` says which way each traced product
-    went."""
+    """The widths decide nothing (hidden 128; experts off the lane tile, 72
+    and 200 wide, and on it, 128 and 256, which kept XLA's body until PR 68
+    by a rule that no measurement of theirs bore out): in a trace that may
+    hold Mosaic calls every grouped product is a Mosaic call and none is
+    ``ragged_dot``; in a trace that may not, every one is ``ragged_dot`` on
+    the parameters as they are and no Mosaic call is traced.  Nothing is
+    padded either way.  ``grouped_matmul.body_counts()`` says which way each
+    traced product went."""
     cfg, params, x = _off_tile_layer(act, width, 0.0, hidden=128)
     before = grouped_matmul.body_counts()
     jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(
