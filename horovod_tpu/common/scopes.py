@@ -244,6 +244,25 @@ scope                 what falls under it
                       the ``jnp`` body's products) and its transpose's two
                       products; a reader that knows ``hvd.gdn.scan`` alone
                       walks outward to it and counts the solve there
+``hvd.kda.conv``      a Kimi Delta Attention layer's way from its projections
+                      to the rule's q, k and v
+                      (``models/llama.py::KimiDeltaAttention``): the three
+                      causal depthwise filters, their SiLU, the L2 norms of
+                      q and k and q's scale, by ``ops/short_conv.py`` as
+                      ``hvd.gdn.conv``'s
+``hvd.kda.gates``     the same layer's gates: the low-rank projection to a
+                      log-decay a key CHANNEL (two products through
+                      ``head_dim`` lanes), its softplus and ``A_log``, beta,
+                      all in float32; and behind the rule the per-head
+                      RMSNorm of its output under the SIGMOID of the second
+                      low-rank projection (``ops/gated_norm.py``)
+``hvd.kda.scan``      the chunkwise rule with a decay a channel
+                      (``ops/kda.py::kda_rule``): the chunks' systems A and
+                      P by halves (six products a chunk), the solve
+                      (``hvd.gdn.solve`` nests inside, the scalar rule's),
+                      the walk that carries the state; forward, run again
+                      under recomputation and backward; Mosaic calls and
+                      XLA operations alike
 ``hvd.ssd.conv``      a Mamba-2 layer's (``models/llama.py::Mamba2``) causal
                       depthwise convolution over its x, B and C channels,
                       the filter's bias and the SiLU:
@@ -316,13 +335,14 @@ scope                 what falls under it
                       (``models/llama.py::LlamaLayer``): ``norm_attn``,
                       the mixer (``LlamaAttention``, ``LatentAttention``,
                       ``SparseAttention``, ``DifferentialAttention``,
-                      ``GatedDeltaNet``, ``Mamba2``, ``Mamba1``,
+                      ``GatedDeltaNet``, ``KimiDeltaAttention``, ``Mamba2``,
+                      ``Mamba1``,
                       ``GatedShortConv`` or ``GatedMemory``:
                       projections, QK-norm, rotation, the ``attention_fn``
                       call or the rule, ``wo``) and the residual add.
                       ``hvd.flash.*``, ``hvd.rope``, ``hvd.attn.*``,
                       ``hvd.mla.latent``, ``hvd.sparse.*``, ``hvd.gdn.*``,
-                      ``hvd.ssd.*``, ``hvd.sscan.*``, ``hvd.lconv.*``,
+                      ``hvd.kda.*``, ``hvd.ssd.*``, ``hvd.sscan.*``, ``hvd.lconv.*``,
                       ``hvd.gmu`` and ``hvd.hc.*`` nest inside it.  In a
                       stack whose layers are ONE sublayer
                       (``LlamaConfig.hybrid_override_pattern``) a mixer
@@ -423,6 +443,7 @@ __all__ = [
     "MOE_ROUTE", "MOE_EXPERTS",
     "MOE_COMBINE", "MOE_SHARED", "SPARSE_INDEX", "SPARSE_SELECT",
     "GDN_CONV", "GDN_GATES", "GDN_SCAN", "GDN_HEADS", "GDN_SOLVE",
+    "KDA_CONV", "KDA_GATES", "KDA_SCAN",
     "SSD_CONV", "SSD_GATES", "SSD_SCAN", "SSD_PROJ",
     "SSCAN_CONV", "SSCAN_GATES", "SSCAN_SCAN", "LCONV_PROJ", "LCONV_CONV",
     "GMU", "ATTN_DIFF",
@@ -434,7 +455,7 @@ __all__ = [
     "MOSAIC_SHORT_CONV", "MOSAIC_GDN_SOLVE", "MOSAIC_SPARSE_SELECT",
     "MOSAIC_INDEX_LOSS", "MOSAIC_PAGED_ATTENTION", "MOSAIC_SSCAN",
     "MOSAIC_GROUPED_MATMUL", "MOSAIC_GATED_NORM", "MOSAIC_SSD_SCAN",
-    "MOSAIC_GDN_SCAN", "MOSAIC_HC_STREAMS",
+    "MOSAIC_GDN_SCAN", "MOSAIC_HC_STREAMS", "MOSAIC_KDA_SCAN",
     "INIT", "INIT_NATIVE", "INIT_DISTRIBUTED", "INIT_CACHE",
     "IMPORT", "IMPORT_MODELS",
     "LAYER", "RULE", "RULES", "FORWARD_SECONDS", "layer_span", "rules", "stamp",
@@ -468,7 +489,10 @@ GDN_CONV = "hvd.gdn.conv"
 GDN_GATES = "hvd.gdn.gates"
 GDN_SCAN = "hvd.gdn.scan"
 GDN_HEADS = "hvd.gdn.heads"
-GDN_SOLVE = "hvd.gdn.solve"       # inside GDN_SCAN
+GDN_SOLVE = "hvd.gdn.solve"       # inside GDN_SCAN (and KDA_SCAN)
+KDA_CONV = "hvd.kda.conv"
+KDA_GATES = "hvd.kda.gates"
+KDA_SCAN = "hvd.kda.scan"
 SSD_CONV = "hvd.ssd.conv"
 SSD_GATES = "hvd.ssd.gates"
 SSD_SCAN = "hvd.ssd.scan"
@@ -507,6 +531,7 @@ MOSAIC_GATED_NORM = MOSAIC + "gated_norm"
 MOSAIC_SSD_SCAN = MOSAIC + "ssd_scan"
 MOSAIC_GDN_SCAN = MOSAIC + "gdn_scan"
 MOSAIC_HC_STREAMS = MOSAIC + "hc_streams"
+MOSAIC_KDA_SCAN = MOSAIC + "kda_scan"
 LAYER = "layer."                     # a prefix: layer_span() completes it
 RULE = "rule."                       # a prefix: rules() completes it
 FORWARD_SECONDS = "forward_seconds"  # a flag of the LOSS span, no span

@@ -58,6 +58,7 @@ from horovod_tpu.ops.gated_delta import (CHUNK, called_in_place,
                                          gated_delta_states,
                                          key_heads_copied, walks_rows)
 from horovod_tpu.ops.gated_norm import gated_norm, norm_gated, skipped
+from horovod_tpu.ops import kda as _kda
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
 from horovod_tpu.ops.losses import batch_balance_loss, sequence_balance_loss
 from horovod_tpu.ops import rope as _rope
@@ -103,6 +104,9 @@ DENSE_LAYER = "-"       # published too; no model here has one: refused
 SCAN, SELF_ATTENTION, MEMORY_GATE, CROSS_ATTENTION = SHARING_MIXERS = (
     "mamba", "attention", "gated_memory", "cross_attention")
 DELTA_RULE, SHORT_CONV, MAMBA2 = "linear_attention", "conv", "mamba2"
+# Kimi Delta Attention: the delta rule whose decay is a number a channel.  No
+# layer type names it: ``linear_attn_config`` lists its layers.
+KDA = "kda"
 # ``layer_types``' published names, and the mixer each gives its layer.  The
 # TYPE ``"mamba"`` (Granite-4.0-H's name) is a Mamba-2 layer, ``MAMBA2``; the
 # MIXER named ``"mamba"``, ``SCAN``, is Mamba-1's, which no type names.
@@ -218,6 +222,10 @@ def _layer_specs(cfg: "LlamaConfig") -> tuple:
         raise ValueError(
             f"{' and '.join(naming)} each name every layer's kind: at most "
             f"one of layer_types, hybrid_override_pattern and mb_per_layer")
+    if cfg.linear_attn_config is not None and naming:
+        raise ValueError(
+            f"linear_attn_config lists every layer's mixer: "
+            f"{' and '.join(naming)} do not go with it")
     heads = cfg.num_attention_heads_per_layer
     if heads is None:
         heads = (cfg.num_heads,) * n
@@ -232,6 +240,10 @@ def _layer_specs(cfg: "LlamaConfig") -> tuple:
             else RopeParameters(cfg.rope_theta, cfg.rope_scaling))
     else:
         ropes = dict(cfg.rope_parameters)
+    if cfg.mla_use_nope:
+        # Latent attention whose shared lanes do not turn: no layer of the
+        # stack rotates, and no table is made for it.
+        ropes = dict.fromkeys(LAYER_TYPES, None)
 
     def spec(i, mixer, ffn, kind="full_attention", **wiring):
         window = cfg.sliding_window if kind == "sliding_attention" else None
@@ -270,6 +282,28 @@ def _layer_specs(cfg: "LlamaConfig") -> tuple:
             reads={MEMORY_GATE: "memory", CROSS_ATTENTION: "kv"}.get(mixer),
             writes={half: "memory", half + 1: "kv"}.get(i))
             for i, mixer in enumerate(mixers)]
+    elif cfg.linear_attn_config is not None:
+        # Kimi Linear's published key: the layers of each mixer by their
+        # 1-indexed position, and the delta-rule layers' sizes.
+        sizes = dict(cfg.linear_attn_config)
+        kda, full = (tuple(sizes.get(key, ())) for key in (
+            "kda_layers", "full_attn_layers"))
+        if (set(sizes) - {"kda_layers", "full_attn_layers", "num_heads",
+                          "head_dim", "short_conv_kernel_size"}
+                or sorted(kda + full) != list(range(1, n + 1))
+                or min(sizes.get(key, 0) for key in (
+                    "num_heads", "head_dim", "short_conv_kernel_size")) < 1):
+            raise ValueError(
+                f"linear_attn_config is {cfg.linear_attn_config!r}: pairs "
+                f"(key, value) with kda_layers and full_attn_layers, which "
+                f"between them name each of the layers 1 to {n} once, and "
+                f"num_heads, head_dim and short_conv_kernel_size of the "
+                f"delta-rule layers, each at least 1")
+        routed_from = cfg.first_dense_layers if cfg.num_experts > 1 else n
+        specs = [spec(i, KDA if i + 1 in kda else SELF_ATTENTION,
+                      ROUTED if i >= routed_from else DENSE,
+                      "linear_attention" if i + 1 in kda
+                      else "full_attention") for i in range(n)]
     elif pattern is not None:
         if DENSE_LAYER in pattern:
             raise ValueError(
@@ -375,6 +409,9 @@ class LlamaConfig:
     ``YarnScaling``, sets the rotary frequencies and the softmax scale, and
     ``q_lora_rank`` (None: one matrix ``wq``) makes the queries from a latent
     of that width with a norm of its own, DeepSeek-V3's);
+    with ``mla_use_nope`` (Kimi Linear's key) the ``qk_rope_head_dim`` shared
+    lanes are NOT rotated, position coming from the other layers' recurrences:
+    no layer of the stack turns and no table is made;
     ``"sparse"`` (``SparseAttention``, DeepSeek-V3.2-Exp's DSA: an indexer of
     ``index_heads`` heads of ``index_head_dim`` picks ``index_topk`` of each
     query's causal keys); ``"differential"`` (``DifferentialAttention``,
@@ -403,6 +440,13 @@ class LlamaConfig:
     (``ssm_state_size``, ``mamba_expand``, ``conv_kernel``) up to i = N / 2
     and a ``GatedMemory`` unit behind it; odd i attention over the layer's
     own keys up to i = N / 2 + 1 and cross-attention behind it.
+
+    ``linear_attn_config`` (Kimi Linear's published key, as pairs ``(key,
+    value)``) names every layer's mixer in place of ``layer_types``: the
+    1-indexed ``kda_layers`` a ``KimiDeltaAttention`` of ``num_heads`` heads of
+    ``head_dim`` | ``head_dim`` behind filters of ``short_conv_kernel_size``
+    taps, the ``full_attn_layers`` ``attention_kind``'s; a feed-forward
+    behind each as without it.
 
     ``LayerSpec.ffn``.  A dense ``SwiGLU`` of ``intermediate_size``; with
     ``num_experts`` > 1, from layer ``first_dense_layers`` on (under a
@@ -564,6 +608,8 @@ class LlamaConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     q_lora_rank: Optional[int] = None     # None: no query latent
+    mla_use_nope: bool = False            # latent attention does not rotate
+    linear_attn_config: Optional[tuple] = None    # ((key, value), ..)
     rope_scaling: Optional[YarnScaling] = None
     hc_mult: int = 1              # residual streams; 1: the plain decoder
     hc_sinkhorn_iters: int = 20
@@ -630,6 +676,13 @@ class LlamaConfig:
                 f"q_lora_rank is {self.q_lora_rank!r}: the width of latent "
                 f"attention's query latent (attention_kind='latent'), or "
                 f"None")
+        if self.mla_use_nope and (
+                self.attention_kind != "latent" or self.rope_scaling
+                is not None or self.rope_parameters is not None):
+            raise ValueError(
+                "mla_use_nope leaves latent attention's shared lanes "
+                "unrotated (attention_kind='latent'): a rotation's scaling "
+                "(rope_scaling, rope_parameters) does not go with it")
         low, high = self.hc_res_clamp
         if self.hc_mult < 1 or (self.hc_mult > 1 and (
                 self.hc_sinkhorn_iters < 1 or not self.hc_eps > 0
@@ -771,6 +824,14 @@ class LlamaConfig:
         return self.layers[layer].mixer == DELTA_RULE
 
     @property
+    def has_kda_layers(self) -> bool:
+        return self._holds(KDA)
+
+    def is_kda(self, layer: int) -> bool:
+        """Whether ``layer``'s mixer is Kimi Delta Attention."""
+        return self.layers[layer].mixer == KDA
+
+    @property
     def has_conv_layers(self) -> bool:
         return self._holds(SHORT_CONV)
 
@@ -839,6 +900,25 @@ class LlamaConfig:
                 f"{who} has no path for a head tied to the embedding "
                 f"(tie_word_embeddings=True): it keeps an lm_head of its "
                 f"own [{self.hidden_size}, {self.vocab_size}]; not built")
+        if self.has_kda_layers:
+            sizes = dict(self.linear_attn_config)
+            raise NotImplementedError(
+                f"{who} has no path for Kimi Delta Attention layers "
+                f"(linear_attn_config names kda_layers): such a layer has no "
+                f"keys or values to cache, its cache would hold a recurrent "
+                f"state of {sizes['head_dim']} x {sizes['head_dim']} float32 "
+                f"for each of {sizes['num_heads']} heads, decayed a key "
+                f"channel at a time, and the three filters' last "
+                f"{sizes['short_conv_kernel_size'] - 1} inputs, and a decode "
+                f"step would update them in place; not built")
+        if self.mla_use_nope:
+            raise NotImplementedError(
+                f"{who} has no path for latent attention that does not "
+                f"rotate (mla_use_nope=True): it turns q and k by one table "
+                f"in every layer, and its cache would hold the "
+                f"{self.kv_lora_rank}-wide latent beside "
+                f"{self.qk_rope_head_dim} shared lanes kept unturned; not "
+                f"built")
         if self.has_conv_layers:
             raise NotImplementedError(
                 f"{who} has no path for double-gated short-convolution "
@@ -1484,6 +1564,10 @@ class LatentAttention(nn.Module):
 
     as ``wq_a``, ``q_norm`` and ``wq_b`` in place of ``wq``, under
     ``hvd.mla.latent`` like the keys' latent.
+
+    With ``mla_use_nope`` (Kimi Linear's key) nothing turns: the scores are
+    ``[q_n | q_r] . [k_n | k_r] (d_n + d_r)^(-1/2)`` on the lanes as the
+    projections left them, k_r still one key for all heads.
     """
 
     config: LlamaConfig
@@ -1511,15 +1595,17 @@ class LatentAttention(nn.Module):
                     dense(cfg.q_lora_rank, "wq_a")(x))
                 q = dense(heads * (d_n + d_r), "wq_b")(c_q)
         q = q.reshape(B, S, heads, d_n + d_r)
-        q = jnp.concatenate(
-            [q[..., :d_n], apply_rope(q[..., d_n:], cos, sin,
-                                      in_place=self.in_place)], axis=-1)
+        if not cfg.mla_use_nope:
+            q = jnp.concatenate(
+                [q[..., :d_n], apply_rope(q[..., d_n:], cos, sin,
+                                          in_place=self.in_place)], axis=-1)
         with _scopes.scope(_scopes.MLA_LATENT):
             latent = dense(rank + d_r, "wkv_a")(x)
             c_kv = RMSNorm(cfg.rms_eps, cfg.dtype,
                            name="kv_norm")(latent[..., :rank])
-            k_r = apply_rope(latent[..., None, rank:], cos, sin,
-                             in_place=self.in_place)
+            k_r = latent[..., None, rank:]
+            if not cfg.mla_use_nope:
+                k_r = apply_rope(k_r, cos, sin, in_place=self.in_place)
             kv = dense(heads * (d_n + d_v), "wkv_b")(c_kv).reshape(
                 B, S, heads, d_n + d_v)
             k = jnp.concatenate(
@@ -2068,6 +2154,86 @@ class GatedDeltaNet(nn.Module):
                         name="wo")(o)
 
 
+class KimiDeltaAttention(nn.Module):
+    """The Kimi Delta Attention mixer (Kimi Linear, arXiv:2510.26692) of a
+    layer that ``linear_attn_config.kda_layers`` names: the delta rule with a
+    decay a key CHANNEL.  A head of H = ``num_heads`` heads of d =
+    ``head_dim`` (keys and values alike), x the block's normed input, ``*``
+    the causal depthwise filter of ``short_conv_kernel_size`` taps::
+
+        q = l2norm(silu(conv_q * (x W_q))) d^-1/2    k = l2norm(silu(conv_k * (x W_k)))
+        v = silu(conv_v * (x W_v))
+        g_t    = -exp(A_log[h]) softplus((x_t W_fa) W_fb + dt_bias)    [H, d] float32
+        beta_t = sigmoid(x_t W_b)                                       [H]
+        S_t = S_{t-1} Diag(exp(g_t)) (I - beta_t k_t k_t^T) + beta_t v_t k_t^T    S_0 = 0
+        o_t = S_t q_t
+        y   = (RMSNorm(o) w * sigmoid((x W_ga) W_gb)) W_o        one [d] scale for all heads
+
+    The filters, SiLU and L2 norms are ``ops/short_conv.py::convolved`` (under
+    ``hvd.kda.conv``); the rule ``ops/kda.py::kda_rule`` (``hvd.kda.scan``);
+    the two low-rank projections (each through d lanes), softplus and beta,
+    and behind the rule the output norm under its SIGMOID gate
+    (``ops/gated_norm.py::norm_gated``), ``hvd.kda.gates``.  Parameters: ``wq
+    wk wv [C, H d]``, ``conv_q conv_k conv_v [K, H d]``, ``a_log [H]``,
+    ``dt_bias [H d]``, ``f_a g_a [C, d]``, ``f_b g_b [d, H d]`` (no bias),
+    ``wb [C, H]``, ``o_norm [d]``, ``wo``.
+
+    Sown where the caller makes ``kda_stats`` mutable: ``alpha_min`` (over
+    channels), ``state_max`` (the largest |S| a chunk started from) and
+    ``out_max`` (the largest |o|).
+    """
+
+    config: LlamaConfig
+    in_place: bool = False      # ``LlamaLayer``'s reading of attention_fn
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        B, S, _ = x.shape
+        sizes = dict(cfg.linear_attn_config)
+        heads, d = sizes["num_heads"], sizes["head_dim"]
+        taps = sizes["short_conv_kernel_size"]
+
+        def dense(width, name, y=x, dtype=cfg.dtype):
+            return nn.Dense(width, use_bias=False, dtype=dtype, name=name)(y)
+
+        def conv(y, name, scale):
+            return convolved(y, self.param(
+                name, _conv_taps_init, (taps, y.shape[-1])), heads, scale,
+                self.in_place).reshape(B, S, heads, d)
+
+        q, k, v = (dense(heads * d, name) for name in ("wq", "wk", "wv"))
+        z = dense(heads * d, "g_b", dense(d, "g_a"))
+        with _scopes.scope(_scopes.KDA_CONV):
+            q = conv(q, "conv_q", d ** -0.5)
+            k = conv(k, "conv_k", 1.0)
+            v = conv(v, "conv_v", None)
+        with _scopes.scope(_scopes.KDA_GATES):
+            x32 = x.astype(jnp.float32)
+            f = dense(heads * d, "f_b", dense(d, "f_a", x32, jnp.float32),
+                      jnp.float32)
+            a_log = self.param("a_log", _a_log_init, (heads,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (heads * d,))
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                f + dt_bias).reshape(B, S, heads, d)
+            beta = jax.nn.sigmoid(dense(heads, "wb", x32, jnp.float32))
+        with _scopes.scope(_scopes.KDA_SCAN), calls_in_place(self.in_place):
+            o = _kda.kda_rule(q, k, v, g, beta)
+        if (self.is_mutable_collection("kda_stats")
+                and not self.is_initializing()):
+            for name, value in (
+                    ("alpha_min", jnp.exp(jnp.min(g))),
+                    ("state_max", jnp.max(jnp.abs(_kda.kda_states(
+                        q, k, v, g, beta)))),
+                    ("out_max", jnp.max(jnp.abs(o.astype(jnp.float32))))):
+                self.sow("kda_stats", name, value)
+        with _scopes.scope(_scopes.KDA_GATES):
+            o = norm_gated(o.reshape(z.shape), z, self.param(
+                "o_norm", nn.initializers.ones, (d,)), heads, cfg.rms_eps,
+                self.in_place, chunk=CHUNK, sigmoid=True)
+        return dense(cfg.hidden_size, "wo", o)
+
+
 class GatedShortConv(nn.Module):
     """The double-gated short convolution of a ``"conv"`` layer (LFM2; Liquid
     AI's technical report and the published ``lfm2`` modelling code).  With x
@@ -2476,6 +2642,7 @@ ATTENTION_KINDS = {"full": LlamaAttention, "latent": LatentAttention,
 # ``attention_kind``'s, which is handed the layer's tables too.
 MIXERS = {SELF_ATTENTION: ("attn", None), CROSS_ATTENTION: ("attn", None),
           DELTA_RULE: ("linear", GatedDeltaNet),
+          KDA: ("kda", KimiDeltaAttention),
           SHORT_CONV: ("conv", GatedShortConv), MAMBA2: ("mamba", Mamba2),
           SCAN: ("mamba", Mamba1), MEMORY_GATE: ("gmu", GatedMemory)}
 

@@ -504,10 +504,12 @@ def _store_heads_rows(ref, here, at, value):
         ref[which, first + k] = value[:, k * per:(k + 1) * per]
 
 
-def _norm_gate_fwd_kernel(o_ref, z_ref, w_ref, out_ref, *, per, lanes, eps):
+def _norm_gate_fwd_kernel(o_ref, z_ref, w_ref, out_ref, *, per, lanes, eps,
+                          sigmoid=False):
     # z_ref, out_ref: [rows, C]; o_ref as they, or as the rule leaves it
     # (``_heads_rows``); w_ref (the [d_v] weight on every head's lanes):
-    # [1, C] float32.  ``lanes`` a step: whole heads of per.
+    # [1, C] float32.  ``lanes`` a step: whole heads of per.  ``sigmoid``:
+    # the gate is sigmoid(z), not silu(z).
     rows, width = z_ref.shape
 
     def chunk(at, _):
@@ -515,7 +517,8 @@ def _norm_gate_fwd_kernel(o_ref, z_ref, w_ref, out_ref, *, per, lanes, eps):
             o = _heads_rows(o_ref, here, at, lanes).astype(jnp.float32)
             z = z_ref[here, at].astype(jnp.float32)
             r = _on_heads(o * o, per, lambda m: jax.lax.rsqrt(m + eps))
-            out_ref[here, at] = (o * r * w_ref[:, at] * (z * _sigmoid(z))
+            gate = _sigmoid(z) if sigmoid else z * _sigmoid(z)
+            out_ref[here, at] = (o * r * w_ref[:, at] * gate
                                  ).astype(out_ref.dtype)
             return carry
 
@@ -525,7 +528,7 @@ def _norm_gate_fwd_kernel(o_ref, z_ref, w_ref, out_ref, *, per, lanes, eps):
 
 
 def _norm_gate_bwd_kernel(o_ref, z_ref, go_ref, w_ref, do_ref, dz_ref,
-                          sums_ref, *, per, lanes, eps):
+                          sums_ref, *, per, lanes, eps, sigmoid=False):
     # As _norm_gate_fwd_kernel, with the cotangent go_ref and the two
     # results (do_ref a block as o_ref is); sums_ref: [_TILE, C] float32,
     # eight partial sums a lane of dw's (a head's lanes' owners are added
@@ -542,15 +545,15 @@ def _norm_gate_bwd_kernel(o_ref, z_ref, go_ref, w_ref, do_ref, dz_ref,
             z, go = (ref[here, at].astype(jnp.float32)
                      for ref in (z_ref, go_ref))
             sig = _sigmoid(z)
-            s = z * sig
+            s = sig if sigmoid else z * sig
             r = _on_heads(o * o, per, lambda m: jax.lax.rsqrt(m + eps))
             n = o * r
             gw = go * w_ref[:, at]
             dn = gw * s
             _store_heads_rows(do_ref, here, at, (
                 r * (dn - n * _on_heads(dn * n, per))).astype(do_ref.dtype))
-            dz_ref[here, at] = (gw * n * sig * (1.0 + z * (1.0 - sig))
-                                ).astype(dz_ref.dtype)
+            slope = (1.0 - sig) if sigmoid else (1.0 + z * (1.0 - sig))
+            dz_ref[here, at] = (gw * n * sig * slope).astype(dz_ref.dtype)
             return d_w + (go * n * s).reshape(-1, _TILE, size).sum(axis=0)
 
         sums_ref[:, at] += _walked(
@@ -649,10 +652,12 @@ def _backward(y, u, z, d, w, go, groups, eps, interpret):
             sums[0].astype(w.dtype))
 
 
-def _norm_gate_kernel(kernel, width: int, heads: int, eps: float):
+def _norm_gate_kernel(kernel, width: int, heads: int, eps: float,
+                      sigmoid: bool = False):
     per = width // heads
     return functools.partial(kernel, per=per, eps=eps,
-                             lanes=_heads_a_step(heads, per) * per)
+                             lanes=_heads_a_step(heads, per) * per,
+                             sigmoid=sigmoid)
 
 
 def _on_every_head(w, heads: int):
@@ -673,23 +678,26 @@ def _as_the_rule_left(o, heads: int, chunk: int):
         1, 0, 3, 2, 4)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("heads", "eps", "chunk", "interpret"))
-def _norm_gate_forward(o, z, w, heads, eps, chunk, interpret):
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "eps", "chunk", "interpret", "sigmoid"))
+def _norm_gate_forward(o, z, w, heads, eps, chunk, interpret, sigmoid=False):
     return _call(
-        _norm_gate_kernel(_norm_gate_fwd_kernel, o.shape[2], heads, eps),
+        _norm_gate_kernel(_norm_gate_fwd_kernel, o.shape[2], heads, eps,
+                          sigmoid),
         o.shape, (_as_the_rule_left(o, heads, chunk), z),
         (_on_every_head(w, heads),),
         (jax.ShapeDtypeStruct(o.shape, z.dtype),), 0, interpret)[0]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("heads", "eps", "chunk", "interpret"))
-def _norm_gate_backward(o, z, w, go, heads, eps, chunk, interpret):
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "eps", "chunk", "interpret", "sigmoid"))
+def _norm_gate_backward(o, z, w, go, heads, eps, chunk, interpret,
+                        sigmoid=False):
     shape = o.shape
     o = _as_the_rule_left(o, heads, chunk)
     d_o, dz, sums = _call(
-        _norm_gate_kernel(_norm_gate_bwd_kernel, shape[2], heads, eps),
+        _norm_gate_kernel(_norm_gate_bwd_kernel, shape[2], heads, eps,
+                          sigmoid),
         shape, (o, z, go), (_on_every_head(w, heads),),
         (jax.ShapeDtypeStruct(o.shape, o.dtype),
          jax.ShapeDtypeStruct(shape, z.dtype)), _TILE, interpret)
@@ -725,9 +733,10 @@ skip_gate_norm.defvjp(*_scopes.rules(
     "skip_gate_norm", _skip_gate_norm_fwd, _skip_gate_norm_bwd))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def norm_gate(o, z, w, heads, eps, chunk=0):
-    """``rms_norm_head(o) w silu(z)`` for o and z ``[B, S, heads * d_v]``
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def norm_gate(o, z, w, heads, eps, chunk=0, sigmoid=False):
+    """``rms_norm_head(o) w silu(z)`` (``sigmoid``, static: ``w sigmoid(z)``,
+    Kimi Delta Attention's gate) for o and z ``[B, S, heads * d_v]``
     and the heads' one weight ``w [d_v]``, the norm over each head's lanes;
     float32 inside, rounded once, to the dtype of z.  ``chunk`` (static):
     o is the gated delta rule's result, made in chunks of that many rows
@@ -736,16 +745,16 @@ def norm_gate(o, z, w, heads, eps, chunk=0):
     ``_rule_chunks`` allows; 0: as rows.  One Mosaic call, and one for all
     three gradients; ``_why_not`` says which shapes it takes."""
     return _norm_gate_forward(o, z, w, heads=heads, eps=eps, chunk=chunk,
-                              interpret=_interpret())
+                              interpret=_interpret(), sigmoid=sigmoid)
 
 
-def _norm_gate_fwd(o, z, w, heads, eps, chunk):
-    return norm_gate(o, z, w, heads, eps, chunk), (o, z, w)
+def _norm_gate_fwd(o, z, w, heads, eps, chunk, sigmoid):
+    return norm_gate(o, z, w, heads, eps, chunk, sigmoid), (o, z, w)
 
 
-def _norm_gate_bwd(heads, eps, chunk, kept, go):
+def _norm_gate_bwd(heads, eps, chunk, sigmoid, kept, go):
     return _norm_gate_backward(*kept, go, heads=heads, eps=eps, chunk=chunk,
-                               interpret=_interpret())
+                               interpret=_interpret(), sigmoid=sigmoid)
 
 
 norm_gate.defvjp(*_scopes.rules("norm_gate", _norm_gate_fwd, _norm_gate_bwd))
@@ -798,9 +807,10 @@ def gated_norm(y, u, z, d, w, groups: int, eps: float, in_place: bool):
                            groups, eps)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4))
-def _norm_then_gate(o, z, scale, heads, eps):
-    """``rms_norm(o) * scale * silu(z)``: o and z ``[B, S, heads * d_v]``,
+@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5))
+def _norm_then_gate(o, z, scale, heads, eps, sigmoid=False):
+    """``rms_norm(o) * scale * silu(z)`` (``sigmoid``: ``* sigmoid(z)``): o
+    and z ``[B, S, heads * d_v]``,
     o normed a head, ``scale [d_v]`` shared by the heads (Gated DeltaNet's
     output norm: the norm FIRST, then the gate); float32 inside, the dtype
     of z out, and under a checkpoint as ``_gate_then_norm``.  A head's mean
@@ -809,14 +819,16 @@ def _norm_then_gate(o, z, scale, heads, eps):
     o = o.astype(jnp.float32)
     squares, spread = over_heads(o * o, heads)
     o = o * spread(jax.lax.rsqrt(squares * (heads / o.shape[-1]) + eps))
-    return (o * jnp.tile(scale, heads) * nn.silu(z.astype(jnp.float32))
+    gate = nn.sigmoid if sigmoid else nn.silu
+    return (o * jnp.tile(scale, heads) * gate(z.astype(jnp.float32))
             ).astype(z.dtype)
 
 
 def norm_gated(o, z, w, heads: int, eps: float, in_place: bool,
-               chunk: int = 0):
+               chunk: int = 0, sigmoid: bool = False):
     """A gated delta-rule layer between its rule and ``wo``:
-    ``rms_norm_head(o) w silu(z)``, ``[B, S, C]`` in the dtype of z.  o and
+    ``rms_norm_head(o) w silu(z)`` (``sigmoid``, static: ``w sigmoid(z)``, a
+    Kimi Delta Attention layer's), ``[B, S, C]`` in the dtype of z.  o and
     z ``[B, S, C = heads * d_v]``, ``w [d_v]`` the heads' one weight;
     ``heads`` and ``eps`` static.  ``in_place`` as ``gated_norm``'s: the
     chain is then ``norm_gate``'s one pass forward and one backward, where
@@ -828,5 +840,5 @@ def norm_gated(o, z, w, heads: int, eps: float, in_place: bool,
     why = _why_not(o.shape, heads, in_place, norm_first=True)
     _trace_counts.note(_BODY, why or _MOSAIC)
     if why is None:
-        return norm_gate(o, z, w, heads, eps, chunk)
-    return _norm_then_gate(o, z, w, heads, eps)
+        return norm_gate(o, z, w, heads, eps, chunk, sigmoid)
+    return _norm_then_gate(o, z, w, heads, eps, sigmoid)

@@ -453,6 +453,41 @@ TINY.setdefault("hc_moe_lm", {
     "traffic": {"sequence": 128, "batch_per_chip": 2},
 })
 
+TINY.setdefault("kda_moe_lm", {
+    # Hidden 128; three layers: a KDA layer over the dense SwiGLU, a latent
+    # layer and a KDA layer over routed experts (the published period is
+    # three KDA to one latent; the five layers of the cell run in
+    # ``tests/test_kimi_linear.py`` and on the chip).  KDA: 2 heads of 64 |
+    # 64 behind 4 taps (a lane tile of channels); latent attention: 2 heads,
+    # keys 64 + 64 unrotated and values 64 from a latent of 32; experts 4 to
+    # 7 of 16 held, 3 choices a token, one shared expert.
+    "config": {"hidden_size": 128, "num_attention_heads": 2,
+               "num_key_value_heads": 2, "head_dim": 64,
+               "qk_nope_head_dim": 64, "qk_rope_head_dim": 64,
+               "v_head_dim": 64, "kv_lora_rank": 32,
+               "intermediate_size": 128, "moe_intermediate_size": 32,
+               "vocab_size": 512, "num_hidden_layers": 3,
+               "linear_attn_config": {
+                   "kda_layers": [1, 3], "full_attn_layers": [2],
+                   "num_heads": 2, "head_dim": 64,
+                   "short_conv_kernel_size": 4},
+               "num_experts": 4, "num_experts_per_token": 3,
+               "deployment": {"num_experts_published": 16,
+                              "first_held_expert": 4},
+               "checks": {"first_loss_is_ln_vocab_plus": 0.5,
+                          "first_loss_tolerance": 0.3,
+                          # A dozen steps at the start of a 2000-step
+                          # warm-up, as ``hybrid_moe_lm``.
+                          "loss_must_fall": False,
+                          # bf16 at these widths, as ``hybrid_moe_lm``'s;
+                          # float32 through the same code agrees to 2e-3
+                          # (tests/test_kimi_linear.py).
+                          "reference": {"parameters": "initial",
+                                        "loss_abs": 0.02,
+                                        "grad_rel": 0.2}}},
+    "traffic": {"sequence": 128, "batch_per_chip": 1},
+})
+
 
 # The files that take over 100 s of the driver's command
 # (``/root/TESTS_LAST_RUN.json``: six workers, ``--dist loadfile``), longest
@@ -470,6 +505,7 @@ LONGEST_FIRST = (
     "tests/benchmark/test_benchmark_hybrid_moe.py",     # 273 s
     "tests/test_laguna.py",                             # 271 s
     "tests/test_flash_walks.py",                        # 235 s
+    "tests/test_kimi_linear.py",                        # 230 s (PR 69)
     "tests/benchmark/test_benchmark_window.py",         # 232 s
     "tests/test_olmo_hybrid.py",                        # 222 s
     "tests/test_flash_attention.py",                    # 204 s
@@ -478,6 +514,7 @@ LONGEST_FIRST = (
     "tests/test_serve.py",                              # 181 s
     "tests/benchmark/test_benchmark_sparse.py",         # 176 s
     "tests/test_deepseek_model.py",                     # 160 s
+    "tests/benchmark/test_benchmark_kda.py",            # 160 s (PR 69)
     "tests/test_flash_v5e_compile.py",                  # 157 s
     "tests/test_smallthinker.py",                       # 155 s (PR 63)
     "tests/test_xing4.py",                              # 150 s (PR 65)
@@ -583,7 +620,7 @@ _MANIFEST_THEN = {
     # (This one reads the cells at import, from the file as it is: its cut
     # keeps every cell, the newest named here, and ends the metrics at its.)
     "test_benchmark_startup_spans.py::test_the_manifests_ten_entries":
-        ("xing4.0-29b-a4b.train-s8k", "trace_loss_self_ms"),
+        ("kimi-linear-48b-a3b.train-s8k-b2", "trace_loss_self_ms"),
     "test_benchmark_qk_norm.py::test_the_manifests_one_new_entry":
         ("phi-4-mini-flash.train-s8k", "diff_attn_ms"),
     "test_benchmark_ssm_moe.py::test_the_manifests_new_entries":

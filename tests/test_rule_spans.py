@@ -28,8 +28,9 @@ for _module in pkgutil.iter_modules(ops_package.__path__):
     importlib.import_module("horovod_tpu.ops." + _module.name)
 
 from horovod_tpu.ops import (flash_attention, gated_delta, gated_norm,  # noqa: E402,E501
-                             grouped_matmul, hyper_connection, losses, rope,
-                             selective_scan, short_conv, sparse_index, ssd)
+                             grouped_matmul, hyper_connection, kda, losses,
+                             rope, selective_scan, short_conv, sparse_index,
+                             ssd)
 
 PACKAGE = os.path.dirname(os.path.abspath(horovod_tpu.__file__))
 F32 = jnp.float32
@@ -136,6 +137,14 @@ CASES = {
         lambda *a: gated_delta.gated_delta_rule(*a, in_place=False),
         (_ones(1, 64, 1, 16), _ones(1, 64, 1, 16), _ones(1, 64, 1, 16),
          -_ones(1, 64, 1), _ones(1, 64, 1))),
+    "kda._rule": lambda: (       # the rule with a decay a channel (PR 69)
+        lambda *a: kda.kda_rule(*a, in_place=False),
+        (_ones(1, 64, 1, 16), _ones(1, 64, 1, 16), _ones(1, 64, 1, 16),
+         -_ones(1, 64, 1, 16), _ones(1, 64, 1))),
+    "kda.systems_call": lambda: (   # a slab's [n, B, H, C, d_k], interpreted
+        kda.systems_call,
+        (_ones(1, 1, 1, 8, 128), _ones(1, 1, 1, 8, 128),
+         -_ones(1, 1, 1, 8, 128))),
     "walk_rows": lambda: (        # heads go in pairs, each whole lane tiles
         gated_delta.walk_rows,
         (_ones(1, 64, 256), _ones(1, 64, 256), _ones(1, 64, 256),
